@@ -13,7 +13,7 @@ can leak to the host or a trace can silently re-specialize.  Rules:
 - ``jax-host-roundtrip`` — a value pulled to the host with
   ``np.asarray`` and then re-uploaded (``jnp.asarray``/``jnp.array``/
   ``device_put``) in the same hot-path function: two wire crossings
-  (a full RTT each on a tunnel-attached chip) for work the device
+  (a full round trip each) for work the device
   could do in place.
 - ``jax-donate-missing`` — a jitted function takes ring-buffer-style
   arguments (``ref_*``/``prev_*``/``carry``/``ring*``) but declares no

@@ -71,7 +71,7 @@ def env_knobs() -> Dict[str, str]:
             if k.startswith(ENV_PREFIXES)}
 
 
-def _topology() -> dict:
+def topology() -> dict:
     """Backend + chip topology from the live jax runtime; degrades to
     {"backend": "unavailable"} where jax is not importable (the
     tripwire CLI, doc builds)."""
@@ -114,7 +114,7 @@ def provenance_block() -> dict:
         "ts_unix": round(time.time(), 3),
         "git_sha": git_sha(),
         "versions": versions,
-        "topology": _topology(),
+        "topology": topology(),
         "host": {
             "cores": os.cpu_count(),
             "platform": platform.platform(),

@@ -753,6 +753,18 @@ def encode_intra_cavlc_frame_yuv(y, cb, cr, hdr_vals, hdr_lens, qp: int,
     return _finish_cavlc(levels, hdr_vals, hdr_lens, with_recon, qp)
 
 
+#: The same stage with ``qp`` TRACED (tune="off" only): one compiled
+#: program serves every qp the rate ladder can ask for.  With qp static a
+#: 1080p program costs about a minute of host compile for the TPU and the
+#: served CBR ladder has 15 qps — tens of minutes cold, and the compiler
+#: of the installed libtpu does not survive several of those compiles
+#: side by side (PERF.md Findings, PR 22).  Byte-identical to the
+#: static-qp program at every qp (tests/test_h264_inter.py).
+encode_intra_cavlc_frame_yuv_dynqp = jax.jit(
+    encode_intra_cavlc_frame_yuv.__wrapped__,
+    static_argnames=("with_recon", "i16_modes", "tune"))
+
+
 def _finish_cavlc(levels, hdr_vals, hdr_lens, with_recon: bool,
                   slice_qp: int = None):
     recon = (levels["recon_y"], levels["recon_cb"], levels["recon_cr"])

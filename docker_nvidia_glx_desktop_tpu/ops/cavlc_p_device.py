@@ -405,6 +405,13 @@ def encode_p_cavlc_frame(y, cb, cr, ref_y, ref_cb, ref_cr,
     return _finish_p(out, hdr_vals, hdr_lens, slice_qp=qp)
 
 
+#: qp-traced twin (tune="off" only) — see
+#: cavlc_device.encode_intra_cavlc_frame_yuv_dynqp.
+encode_p_cavlc_frame_dynqp = jax.jit(
+    encode_p_cavlc_frame.__wrapped__,
+    static_argnames=("tune", "p_intra"), donate_argnames=RING_DONATE)
+
+
 def encode_p_cavlc_frame_padded(y, cb, cr, ref_y_pad, ref_cb_pad,
                                 ref_cr_pad, hdr_vals, hdr_lens, qp: int,
                                 tune: str = "off", next_y=None,

@@ -135,8 +135,10 @@ def _filter_lines(p, q, bs, alpha, beta, tc0_row, chroma: bool):
     aq = jnp.abs(q2 - q0) < beta
 
     # --- bS < 4 normal filter ---
-    t0 = jnp.where(bs <= 1, int(tc0_row[0]),
-                   jnp.where(bs == 2, int(tc0_row[1]), int(tc0_row[2])))
+    if isinstance(tc0_row, np.ndarray):     # static qp: folded constants
+        tc0_row = [int(v) for v in tc0_row]
+    t0 = jnp.where(bs <= 1, tc0_row[0],
+                   jnp.where(bs == 2, tc0_row[1], tc0_row[2]))
     tc = t0 + (1 if chroma
                else 0) + (0 if chroma
                           else ap.astype(jnp.int32) + aq.astype(jnp.int32))
@@ -248,9 +250,19 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
     from . import quant as _q
 
     alpha_t, beta_t, tc0_t = load_tables()
-    qp_c = _q.chroma_qp(qp)
-    a_l, b_l, t_l = int(alpha_t[qp]), int(beta_t[qp]), tc0_t[qp]
-    a_c, b_c, t_c = int(alpha_t[qp_c]), int(beta_t[qp_c]), tc0_t[qp_c]
+    if _q._is_static_qp(qp):
+        qp_c = _q.chroma_qp(qp)
+        a_l, b_l, t_l = int(alpha_t[qp]), int(beta_t[qp]), tc0_t[qp]
+        a_c, b_c, t_c = (int(alpha_t[qp_c]), int(beta_t[qp_c]),
+                         tc0_t[qp_c])
+    else:
+        # traced slice qp (deblock_frame_dynqp): the thresholds are
+        # table gathers instead of folded constants — same integers
+        qp_c = _q.chroma_qp_v(qp)
+        alpha_a, beta_a = jnp.asarray(alpha_t), jnp.asarray(beta_t)
+        tc0_a = jnp.asarray(tc0_t)
+        a_l, b_l, t_l = alpha_a[qp], beta_a[qp], tc0_a[qp]
+        a_c, b_c, t_c = alpha_a[qp_c], beta_a[qp_c], tc0_a[qp_c]
     H, W = y.shape
     nr, nc = H // 16, W // 16
     intra = nnz_blk is None
@@ -384,6 +396,12 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
     cr_out = assemble(cro6, crlf, carry[2][..., 2:], 2)
     clip = lambda p: jnp.clip(p, 0, 255).astype(jnp.uint8)
     return clip(y_out), clip(cb_out), clip(cr_out)
+
+
+#: qp-traced twin: one program for every slice qp (see
+#: cavlc_device.encode_intra_cavlc_frame_yuv_dynqp).
+deblock_frame_dynqp = _jax.jit(deblock_frame.__wrapped__,
+                               static_argnames=("_group",))
 
 
 def _filter_line(p, q, bs, alpha, beta, tc0_row, chroma):

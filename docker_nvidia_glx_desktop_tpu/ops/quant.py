@@ -125,6 +125,20 @@ def chroma_qp_v(qp_y):
     return jnp.asarray(QPC_TABLE)[q]
 
 
+def chroma_qp_any(qp_y):
+    """Table 8-15 for either kind of qp: a Python int stays a Python
+    int (the compile-time-constant path), a traced scalar or per-MB
+    array goes through the table gather."""
+    return chroma_qp(qp_y) if _is_static_qp(qp_y) else chroma_qp_v(qp_y)
+
+
+def require_static_qp_unless_off(qp, tune: str) -> None:
+    """A traced slice qp serves tune="off" only: the hq tiers derive
+    compile-time floats (lambda) and the AQ plane from a Python qp."""
+    if tune != "off" and not _is_static_qp(qp):
+        raise TypeError(f"tune={tune!r} needs a static (Python int) qp")
+
+
 def _is_static_qp(qp) -> bool:
     """True for a Python/numpy scalar qp (the compile-time-constant path
     every pre-tune caller uses; kept byte-for-byte identical).  Traced

@@ -247,9 +247,12 @@ class BatchStreamManager:
         ndev = len(jax.devices())
         total = int(np.prod(shape))
         if total > ndev or len(shape) > 2:
-            log.warning("TPU_MESH %s needs %d devices, have %d; using 1",
-                        shape, total, ndev)
-            shape = (1, 1)
+            # a mesh that was asked for and cannot be built is a
+            # start-up failure: serving on (1, 1) instead is exactly how
+            # "everything on the first chip" looks from outside
+            raise ValueError(
+                f"TPU_MESH {cfg.tpu_mesh!r} needs {total} device(s) on "
+                f"at most 2 axes; jax.devices() shows {ndev}")
         if len(shape) == 1:
             shape = (shape[0], 1)
         if len(sources) % shape[0] != 0:

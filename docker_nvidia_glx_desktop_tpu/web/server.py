@@ -761,7 +761,7 @@ def make_app(cfg: Config, session=None,
         return ws
 
     # A wedged device RPC leaves the encode thread alive but frameless —
-    # the exact failure a liveness probe must catch on a tunnel/flaky
+    # the exact failure a liveness probe must catch on a flaky
     # interconnect — so health = thread alive AND frames not stale.
     # (Before the first frame the codec may still be jit-compiling;
     # that window is covered by the probe's initialDelaySeconds.)

@@ -24,6 +24,18 @@ def main() -> None:
     # skip every compile a previous process already did.
     from ..utils.jaxcache import setup_compile_cache
     setup_compile_cache()
+    # Which device this process serves from, once, before anything
+    # compiles: a chip that is silently absent (a CPU fallback, a mesh
+    # on the first chip only) must be readable from the first log lines
+    # — chip_smoke.py gates on this line.
+    import json
+
+    from ..native import lib as native_lib
+    from ..obs.provenance import topology
+    from ..ops.h264_inter import RING_DONATE
+    log.info("device: %s", json.dumps(
+        {**topology(), "ring_donate": list(RING_DONATE),
+         "native_entropy": native_lib.available()}, sort_keys=True))
 
     async def run():
         from .clock import MediaClock

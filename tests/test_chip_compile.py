@@ -1,0 +1,165 @@
+"""The served path's programs, compiled at 1920x1080 for a DESCRIBED v5e
+chip (``jax.experimental.topologies``; no chip attached, nothing runs).
+
+What the TPU compiler would refuse on the chip machine — a program that
+does not fit 16 GB, a donation it cannot alias, a sharding it cannot
+partition — it refuses here, at no chip time.  The shapes are the real
+call shapes: the intra step as ``H264Encoder._submit_device`` issues it
+(recon kept for the GOP, qp a traced scalar), the P step as
+``_submit_p_device`` issues it (reference ring donated, as it resolves
+under ``JAX_PLATFORMS=tpu``),
+the in-loop deblock of a P frame with the TPU's column group (the
+``jax.default_backend()`` branch would pick the CPU's group here, so the
+test passes ``_group=8`` itself), and the (4,1) session-mesh step of
+``TPU_SESSIONS``/``TPU_MESH`` on a ``Mesh`` of the four described
+devices.
+
+Tier-1 on purpose (not in conftest's ``_SLOW_MODULES``).  Only one
+process may hold the TPU library, so everything that touches the
+topology lives in module-scoped fixtures of THIS file — nothing at
+import, in a ``skipif``, a ``parametrize`` argument or ``conftest.py`` —
+and the compiles run in this process, two at a time on threads (an XLA
+compile releases the GIL), with the persistent cache off around them (a
+described-topology entry can be written but never read back).
+"""
+
+import os
+from concurrent.futures import ThreadPoolExecutor
+
+import pytest
+
+H, W, QP = 1088, 1920, 26          # 1080p padded to MB rows; the base qp
+HBM_BYTES = 16e9                   # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def programs(topo, no_persistent_cache):
+    """name -> compiled program (or the exception its compile raised)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import (NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+
+    from docker_nvidia_glx_desktop_tpu.ops import (cavlc_device,
+                                                   cavlc_p_device,
+                                                   h264_deblock)
+    from docker_nvidia_glx_desktop_tpu.parallel import batch
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+
+    y = jax.ShapeDtypeStruct((H, W), jnp.uint8, sharding=one)
+    c = jax.ShapeDtypeStruct((H // 2, W // 2), jnp.uint8, sharding=one)
+    hv_np, hl_np = cavlc_device.slice_header_slots(
+        H // 16, W // 16, frame_num=0, idr_pic_id=0, qp_delta=0,
+        deblocking_idc=2)
+    hv, hl = on_chip((jax.ShapeDtypeStruct(hv_np.shape, hv_np.dtype),
+                      jax.ShapeDtypeStruct(hl_np.shape, hl_np.dtype)))
+
+    qp = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)   # traced
+    lowered = {}
+    # H264Encoder._submit_device: host-converted planes, recon kept
+    lowered["intra"] = cavlc_device.encode_intra_cavlc_frame_yuv_dynqp.lower(
+        y, c, c, hv, hl, qp, with_recon=True, i16_modes="auto", tune="off")
+    # H264Encoder._submit_p_device with the ring donated (RING_DONATE is
+    # resolved from JAX_PLATFORMS at import and is () in this CPU-held
+    # process, so the donated jit is rebuilt here from the same body)
+    p_body = cavlc_p_device.encode_p_cavlc_frame.__wrapped__
+    p_args = (y, c, c, y, c, c, hv, hl, qp, "off", None, False)
+    lowered["p"] = jax.jit(
+        p_body, static_argnames=("tune", "p_intra"),
+        donate_argnames=("ref_y", "ref_cb", "ref_cr")).lower(*p_args)
+    _flat, ry, rcb, rcr, mv, nnz, _lv = on_chip(
+        jax.eval_shape(lambda *a: p_body(*a, "off", None, False),
+                       *p_args[:9]))
+    lowered["deblock_p"] = h264_deblock.deblock_frame_dynqp.lower(
+        ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv, _group=8)
+    # web/multisession: four 1080p sessions, one per chip
+    mesh = batch.make_mesh((4, 1), topo.devices)
+    sess = NamedSharding(mesh, P("session", "spatial", None))
+    step, _rows = batch.h264_batch_encode_step(mesh, H, W, qp=QP)
+    lowered["mesh41"] = jax.jit(step).lower(
+        jax.ShapeDtypeStruct((4, H, W), jnp.uint8, sharding=sess),
+        jax.ShapeDtypeStruct((4, H // 2, W // 2), jnp.uint8, sharding=sess),
+        jax.ShapeDtypeStruct((4, H // 2, W // 2), jnp.uint8, sharding=sess))
+
+    def compile_one(item):
+        name, low = item
+        try:
+            return name, low.compile()
+        except Exception as e:          # re-raised by the test that owns it
+            return name, e
+
+    # Two at a time, longest first, and never more: six TPU compiles
+    # side by side overflow the stack of the installed libtpu's
+    # compiler (SIGSEGV in TpuBroadcastRewriter; PERF.md Findings,
+    # PR 22), and a worker that dies takes the whole file with it.
+    order = ("mesh41", "p", "intra", "deblock_p")
+    with ThreadPoolExecutor(2) as ex:
+        return dict(ex.map(compile_one, ((k, lowered[k]) for k in order)))
+
+
+def _compiled(programs, name):
+    prog = programs[name]
+    if isinstance(prog, Exception):
+        raise prog
+    return prog
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.generated_code_size_in_bytes + m.temp_size_in_bytes
+            + m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes)
+
+
+def test_intra_step_compiles_for_v5e(programs):
+    c = _compiled(programs, "intra")
+    assert 0 < _device_bytes(c) < HBM_BYTES
+
+
+def test_p_step_compiles_and_donates_the_ring(programs):
+    c = _compiled(programs, "p")
+    assert 0 < _device_bytes(c) < HBM_BYTES
+    # the recon is written in place of the donated reference planes
+    assert c.memory_analysis().alias_size_in_bytes >= H * W * 3 // 2
+
+
+def test_p_deblock_compiles_with_tpu_column_group(programs):
+    c = _compiled(programs, "deblock_p")
+    assert 0 < _device_bytes(c) < HBM_BYTES
+
+
+def test_session_mesh_step_fits_each_chip(programs):
+    c = _compiled(programs, "mesh41")
+    # memory_analysis() of a partitioned program is per device
+    assert 0 < _device_bytes(c) < HBM_BYTES
+    assert len(c.input_shardings[0][0].device_set) == 4

@@ -1,6 +1,8 @@
 """Config surface + codec factory: env parity chains and the
 fail-loudly contract for unimplemented codecs (VERDICT round-1 weak #8)."""
 
+import os
+
 import pytest
 
 from docker_nvidia_glx_desktop_tpu.models import make_encoder
@@ -63,3 +65,54 @@ class TestCodecFactory:
         assert from_env({"TPU_MESH": "2x4"}).mesh_shape == (2, 4)
         assert from_env({"TPU_MESH": "8"}).mesh_shape == (8,)
         assert from_env({"TPU_MESH": "junk"}).mesh_shape == (1,)
+
+
+class TestCompileCachePlacement:
+    """utils/jaxcache: the cache lives where JAX_COMPILATION_CACHE_DIR
+    says, exactly as given, and otherwise at ONE fixed directory inside
+    the checkout — never /tmp, a pid, a time or the backend's name."""
+
+    @pytest.mark.parametrize("given", [
+        None, "/some/dir", "/some/dir-with-suffix-cpu", "relative/dir"])
+    def test_cache_dir_placement(self, monkeypatch, given):
+        import pathlib
+
+        import jax
+
+        from docker_nvidia_glx_desktop_tpu.utils import jaxcache
+
+        if given is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", given)
+        updates = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.append((k, v)))
+        first = jaxcache.setup_compile_cache()
+        second = jaxcache.setup_compile_cache()
+        assert first == second == jaxcache.cache_dir()
+        dirs = [v for k, v in updates if k == "jax_compilation_cache_dir"]
+        if given is None:
+            root = pathlib.Path(jaxcache.__file__).resolve().parents[2]
+            assert pathlib.Path(first) == root / ".jax_cache"
+            assert "/tmp" not in first and str(os.getpid()) not in first
+            assert set(dirs) == {first}
+        else:
+            # the operator's directory verbatim; JAX read the variable
+            # itself, so the helper sets no directory in code at all
+            assert first == given
+            assert dirs == []
+        # beyond the directory the helper touches only the two
+        # what-gets-cached thresholds
+        assert {k for k, _ in updates} <= {
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes"}
+
+    def test_private_spellings_are_gone(self, monkeypatch):
+        from docker_nvidia_glx_desktop_tpu.utils import jaxcache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("JAX_COMPILE_CACHE_DIR", "/old/one")
+        monkeypatch.setenv("JAX_TEST_COMPILE_CACHE", "/old/two")
+        assert jaxcache.cache_dir() == jaxcache.DEFAULT_CACHE_DIR

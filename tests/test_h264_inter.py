@@ -395,7 +395,7 @@ class TestServingLatencyFixes:
     def test_pull_guess_tracks_recent_max_not_last_frame(self):
         """Alternating big/small P frames must not flip the pull guess
         down after a small frame — a too-small prefix costs a serial
-        second device pull (a full RTT on a tunnel link)."""
+        second device pull (a full host<->device round trip)."""
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         enc = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="device",
@@ -417,8 +417,10 @@ class TestServingLatencyFixes:
         assert enc._p_pull_guess < big_guess
 
     def test_prewarm_compiles_ladder_qps(self):
-        """prewarm() must hit the REAL serving jit-cache keys: the
-        static-qp executable count grows by exactly the qps warmed."""
+        """prewarm() must hit the REAL serving jit-cache keys.  On the
+        served default (tune=off, device CAVLC) qp is a traced scalar:
+        the ladder has nothing to compile, and warming two explicit qps
+        adds at most ONE executable."""
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
         from docker_nvidia_glx_desktop_tpu.ops import cavlc_p_device
 
@@ -426,22 +428,73 @@ class TestServingLatencyFixes:
                           gop=60, bitrate_kbps=500)
         qps = enc.ladder_qps()
         base = {min(51, max(0, 26 + s)) for s in type(enc._rate).STEPS}
-        # the ladder also pre-compiles the degradation bias variants
-        # (resilience qp_up rung must never cold-compile under load)
+        # the ladder also covers the degradation bias variants
         expected = set(base)
         for off in enc.DEGRADE_QP_OFFSETS:
             expected |= {min(51, q + off) for q in base}
         assert qps[0] == 26 and set(qps) == expected
-        before = cavlc_p_device.encode_p_cavlc_frame._cache_size()
-        # odd qps: the even-stepped ladder around every other test's base
-        # qp never compiles these, so the entries are new even when this
-        # test runs after rate-controlled tests in the same process
-        warmed = enc.prewarm(qps=[21, 23])
-        assert warmed == 2
-        after = cavlc_p_device.encode_p_cavlc_frame._cache_size()
-        assert after >= before + 2
+        assert enc._dyn_qp and enc.prewarm() == 0
+        # (the static-qp and the qp-traced jit wrap one function and
+        # jax counts their executables in one cache)
+        before = cavlc_p_device.encode_p_cavlc_frame_dynqp._cache_size()
+        assert enc.prewarm(qps=[21, 23]) == 2
+        assert cavlc_p_device.encode_p_cavlc_frame_dynqp._cache_size() \
+            <= before + 1
         # the serving encoder's own state was never touched
         assert enc._ref is None and enc.frame_index == 0
+
+    def test_prewarm_static_qp_tier_compiles_per_qp(self):
+        """The hq tiers keep qp static (lambda decisions are compile-time
+        floats): there the executable count still grows by the qps
+        warmed."""
+        from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
+        from docker_nvidia_glx_desktop_tpu.ops import cavlc_p_device
+
+        enc = H264Encoder(64, 48, qp=26, mode="cavlc", entropy="device",
+                          gop=60, bitrate_kbps=500, tune="hq")
+        assert not enc._dyn_qp
+        before = cavlc_p_device.encode_p_cavlc_frame._cache_size()
+        # odd qps: the even-stepped ladder around every other test's base
+        # qp never compiles these
+        assert enc.prewarm(qps=[21, 23]) == 2
+        assert cavlc_p_device.encode_p_cavlc_frame._cache_size() \
+            >= before + 2
+
+    @pytest.mark.parametrize("qp", [4, 11, 12, 26, 47])
+    def test_traced_qp_is_byte_identical_to_static_qp(self, qp):
+        """The qp-traced programs the per-frame path serves from (intra,
+        P, in-loop deblock) emit the bytes of the static-qp programs at
+        every qp — across both luma-DC dequant branches (qp < 12) and
+        the chroma-qp table's bend (qp >= 30)."""
+        from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
+
+        class StaticQp(H264Encoder):
+            _dyn_qp = False
+
+        frames = [np.ascontiguousarray(np.roll(
+            conftest.make_test_frame(64, 96, seed=3), 3 * i, axis=1))
+            for i in range(3)]
+        outs = []
+        for cls in (H264Encoder, StaticQp):
+            enc = cls(96, 64, qp=qp, mode="cavlc", entropy="device",
+                      host_color=True, gop=60, deblock=True)
+            assert enc._dyn_qp is (cls is H264Encoder)
+            outs.append([enc.encode(f).data for f in frames])
+        assert outs[0] == outs[1]
+
+    def test_device_entropy_overflow_is_counted(self):
+        """The device coder's fallback to the host coder is no longer
+        silent: every overflowed frame lands on
+        dngd_encoder_entropy_overflow_total (chip_smoke.py requires 0)."""
+        from docker_nvidia_glx_desktop_tpu.models import h264 as m
+
+        frame = conftest.make_test_frame(64, 96, seed=3)
+        enc = m.H264Encoder(96, 64, qp=4, mode="cavlc", entropy="device",
+                            host_color=True, gop=60, deblock=True)
+        before = m._M_ENTROPY_OVERFLOW.value
+        au = enc.encode(frame).data       # noise band at qp 4: MB cap
+        assert m._M_ENTROPY_OVERFLOW.value == before + 1
+        assert len(au) > 0
 
     def test_prewarm_forwards_intra_modes(self):
         """ADVICE r4 (medium): with ENCODER_INTRA_MODES=full the scratch
