@@ -24,6 +24,7 @@ import numpy as np
 from ..bitstream import h264 as syn
 from ..bitstream.bitwriter import BitWriter
 from ..obs import metrics as obsm
+from ..obs import trace as obst
 from ..obs.profile import PROFILER
 from ..ops import color
 from ..utils.mathutil import round_up
@@ -31,6 +32,11 @@ from .base import EncodedFrame, Encoder
 
 log = logging.getLogger(__name__)
 
+_M_PULL_EXTRA = obsm.counter(
+    "dngd_encoder_pull_extra_total",
+    "Frames whose bitstream outgrew the guessed pull prefix and paid a "
+    "second device->host round trip (the first time a length is seen "
+    "it is also a compile of the slice)")
 _M_ENTROPY_OVERFLOW = obsm.counter(
     "dngd_encoder_entropy_overflow_total",
     "Frames whose device CAVLC buffer overflowed and were entropy-coded "
@@ -224,6 +230,7 @@ class RateController:
 
 
 @functools.partial(jax.jit, static_argnames=("pad_h", "pad_w"))
+@jax.named_scope("dngd.colour")
 def _yuv_stage(rgb, pad_h: int, pad_w: int):
     """RGB -> studio-range YUV 4:2:0 uint8 planes, padded to MB multiples."""
     h, w = rgb.shape[0], rgb.shape[1]
@@ -499,11 +506,14 @@ class H264Encoder(Encoder):
 
     # -- dispatch accounting (obs/budget 'dispatch' stage) -------------
 
-    def _count_dispatch(self, t0: float) -> None:
+    def _count_dispatch(self, t0: float = None, ms: float = 0.0) -> None:
         """One Python -> device crossing; ``t0`` = the submit path's
-        entry, so the accumulated gap is the submit-to-launch cost."""
+        entry, so the accumulated gap is the submit-to-launch cost.  The
+        served per-frame paths pass ``ms``, their ``dispatch`` stage
+        span's own duration, instead."""
         self._disp_count += 1
-        self._disp_gap_ms += (time.perf_counter() - t0) * 1e3
+        self._disp_gap_ms += (ms if t0 is None
+                              else (time.perf_counter() - t0) * 1e3)
 
     def pop_dispatch_sample(self):
         """(crossings, gap_ms) accrued since the last pop — the
@@ -1301,53 +1311,54 @@ class H264Encoder(Encoder):
 
         if self._spatial_nx > 1:
             return self._sp_submit_intra(rgb, idr_pic_id)
-        t0 = time.perf_counter()
         qp = self._eff_qp()
-        hv, hl = self._hdr_slots(idr_pic_id, qp_delta=qp - self.qp)
         with_recon = self.keep_recon or self.gop > 1
-        planes = self._host_yuv420(rgb) if self.host_color else None
-        if planes is not None and self._dyn_qp:
-            out = cavlc_device.encode_intra_cavlc_frame_yuv_dynqp(
-                *planes, hv, hl, np.int32(qp), with_recon=with_recon,
-                i16_modes=self.i16_modes, tune="off")
-        elif planes is not None:
-            out = cavlc_device.encode_intra_cavlc_frame_yuv(
-                *planes, hv, hl, qp, with_recon=with_recon,
-                i16_modes=self.i16_modes, tune=self._ktune)
-        else:
-            out = cavlc_device.encode_intra_cavlc_frame(
-                jnp.asarray(rgb), hv, hl,
-                self.pad_h, self.pad_w, qp, with_recon=with_recon,
-                i16_modes=self.i16_modes, tune=self._ktune)
-        self._count_dispatch(t0)
-        if with_recon:
-            flat, recon = out
-        else:
-            flat, recon = out, None
-        if recon is not None and self.gop > 1:
-            # advance the reference at SUBMIT time (device futures): a
-            # pipelined P frame submitted before this IDR is collected
-            # must see it.  With deblocking on, the reference is the
-            # loop-filtered picture — exactly what the decoder predicts
-            # from.
-            if self.deblock:
-                self._ref = self._deblock(*recon, qp)
+        with obst.stage("colour"):
+            planes = self._host_yuv420(rgb) if self.host_color else None
+        with obst.stage("dispatch") as span:
+            hv, hl = self._hdr_slots(idr_pic_id, qp_delta=qp - self.qp)
+            if planes is not None and self._dyn_qp:
+                out = cavlc_device.encode_intra_cavlc_frame_yuv_dynqp(
+                    *planes, hv, hl, np.int32(qp), with_recon=with_recon,
+                    i16_modes=self.i16_modes, tune="off")
+            elif planes is not None:
+                out = cavlc_device.encode_intra_cavlc_frame_yuv(
+                    *planes, hv, hl, qp, with_recon=with_recon,
+                    i16_modes=self.i16_modes, tune=self._ktune)
             else:
-                self._ref = tuple(recon)
-        # content stats ride this submit's crossing (extra jit calls in
-        # the same event are free — _count_dispatch counts events)
-        self._content_submit(
-            planes[0] if planes is not None
-            else _yuv_stage(jnp.asarray(rgb), self.pad_h, self.pad_w)[0],
-            recon_y=recon[0] if recon is not None else None,
-            frame_type="intra")
-        if recon is not None and self.keep_recon:
-            # pull NOW: with deblock off these arrays become the next P
-            # submit's DONATED refs — dead by collect time in a pipeline
-            recon = tuple(np.asarray(p) for p in recon)
-        guess = getattr(self, "_pull_guess", 4 * self._PULL_BUCKET)
-        prefix = flat[:cavlc_device.META_WORDS * 4 + guess]
-        _prefetch_host(prefix)
+                out = cavlc_device.encode_intra_cavlc_frame(
+                    jnp.asarray(rgb), hv, hl,
+                    self.pad_h, self.pad_w, qp, with_recon=with_recon,
+                    i16_modes=self.i16_modes, tune=self._ktune)
+            if with_recon:
+                flat, recon = out
+            else:
+                flat, recon = out, None
+            if recon is not None and self.gop > 1:
+                # advance the reference at SUBMIT time (device futures): a
+                # pipelined P frame submitted before this IDR is collected
+                # must see it.  With deblocking on, the reference is the
+                # loop-filtered picture — exactly what the decoder predicts
+                # from.
+                if self.deblock:
+                    self._ref = self._deblock(*recon, qp)
+                else:
+                    self._ref = tuple(recon)
+            # content stats ride this submit's crossing (extra jit calls in
+            # the same event are free — _count_dispatch counts events)
+            self._content_submit(
+                planes[0] if planes is not None
+                else _yuv_stage(jnp.asarray(rgb), self.pad_h, self.pad_w)[0],
+                recon_y=recon[0] if recon is not None else None,
+                frame_type="intra")
+            if recon is not None and self.keep_recon:
+                # pull NOW: with deblock off these arrays become the next P
+                # submit's DONATED refs — dead by collect time in a pipeline
+                recon = tuple(np.asarray(p) for p in recon)
+            guess = getattr(self, "_pull_guess", 4 * self._PULL_BUCKET)
+            prefix = flat[:cavlc_device.META_WORDS * 4 + guess]
+            _prefetch_host(prefix)
+        self._count_dispatch(ms=span.ms)
         return (rgb, idr_pic_id, qp, planes, flat, prefix, recon)
 
     def _collect_device(self, submitted, in_pipeline: bool = False) -> bytes:
@@ -1361,16 +1372,18 @@ class H264Encoder(Encoder):
         if recon is not None and self.keep_recon:
             self.last_recon = tuple(np.asarray(p) for p in recon)
         base = cavlc_device.META_WORDS * 4
-        buf = np.asarray(prefix)
+        with obst.stage("pull"):
+            buf = np.asarray(prefix)
         meta = cavlc_device.FlatMeta(buf, self.mb_h)
         if meta.overflow:
             _note_entropy_overflow("intra")
             # Reuse the exact device inputs (planes + rate-controlled qp)
             # so the fallback's recon matches what later pipelined frames
             # already referenced; never clobber an advanced ref chain.
-            return self._encode_host_entropy(
-                rgb, idr_pic_id, planes=planes, qp=qp,
-                update_ref=not in_pipeline)
+            with obst.stage("assemble", more=True):
+                return self._encode_host_entropy(
+                    rgb, idr_pic_id, planes=planes, qp=qp,
+                    update_ref=not in_pipeline)
         self._note_qp_sum(meta.qp_sum)
         need = 4 * meta.total_words
         # Next frame's pull guess = decaying max of recent needs, ceiled
@@ -1381,8 +1394,12 @@ class H264Encoder(Encoder):
         self._pull_guess = -(-max(self._pull_hist) // bucket) * bucket
         if need > len(buf) - base:
             extra = -(-need // bucket) * bucket
-            buf = np.asarray(flat[:base + extra])
-        return cavlc_device.assemble_annexb(buf, meta, headers=self.headers())
+            _M_PULL_EXTRA.inc()
+            with obst.stage("pull_extra"):
+                buf = np.asarray(flat[:base + extra])
+        with obst.stage("assemble", more=True):
+            return cavlc_device.assemble_annexb(buf, meta,
+                                                headers=self.headers())
 
     # ------------------------------------------------------------------
     # CABAC serving path: device transform+quant with device-side
@@ -1853,10 +1870,11 @@ class H264Encoder(Encoder):
 
     def _planes_device(self, rgb):
         """Current frame as padded YUV planes (host cv2 or device jit)."""
-        planes = self._host_yuv420(rgb) if self.host_color else None
-        if planes is not None:
-            return planes
-        return _yuv_stage(jnp.asarray(rgb), self.pad_h, self.pad_w)
+        with obst.stage("colour"):
+            planes = self._host_yuv420(rgb) if self.host_color else None
+            if planes is not None:
+                return planes
+            return _yuv_stage(jnp.asarray(rgb), self.pad_h, self.pad_w)
 
     def _encode_p(self, rgb) -> bytes:
         qp = self._eff_qp(keyframe=False)
@@ -1906,40 +1924,40 @@ class H264Encoder(Encoder):
         if plan is not None and not plan.full:
             return self._submit_p_masked(y, cb, cr, qp, frame_num,
                                          next_y, plan)
-        t0 = time.perf_counter()
-        frame_num = self._frame_num if frame_num is None else frame_num
-        hv, hl = self._p_hdr_slots(frame_num, qp - self.qp)
-        if self._dyn_qp:      # tune=off: no lookahead luma, no I16-in-P
-            flat, ry, rcb, rcr, mv, nnz, levels = \
-                cavlc_p_device.encode_p_cavlc_frame_dynqp(
-                    jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr),
-                    *self._ref, hv, hl, np.int32(qp), "off", None, False)
-        else:
-            flat, ry, rcb, rcr, mv, nnz, levels = \
-                cavlc_p_device.encode_p_cavlc_frame(
-                    jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr),
-                    *self._ref, hv, hl, qp, self._ktune, next_y,
-                    self._p_intra)
-        self._count_dispatch(t0)
-        recon = (ry, rcb, rcr)
-        self._content_submit(
-            jnp.asarray(y), recon_y=ry, mv=mv,
-            resid=(levels["luma"], levels["cb_dc"], levels["cb_ac"],
-                   levels["cr_dc"], levels["cr_ac"]),
-            mb_intra=levels.get("mb_intra"))
-        if self.deblock:
-            self._ref = self._deblock(ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv)
-        else:
-            self._ref = recon
-        if self.keep_recon:
-            # pull NOW: with deblock off these arrays ARE the next
-            # submit's (donated) refs — by collect time they may be dead
-            recon = tuple(np.asarray(p) for p in recon)
-            mv = np.asarray(mv)
-        base = cavlc_device.META_WORDS * 4
-        guess = getattr(self, "_p_pull_guess", 2 * self._PULL_BUCKET)
-        prefix = flat[:base + guess]
-        _prefetch_host(prefix)
+        with obst.stage("dispatch") as span:
+            frame_num = self._frame_num if frame_num is None else frame_num
+            hv, hl = self._p_hdr_slots(frame_num, qp - self.qp)
+            if self._dyn_qp:      # tune=off: no lookahead luma, no I16-in-P
+                flat, ry, rcb, rcr, mv, nnz, levels = \
+                    cavlc_p_device.encode_p_cavlc_frame_dynqp(
+                        jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr),
+                        *self._ref, hv, hl, np.int32(qp), "off", None, False)
+            else:
+                flat, ry, rcb, rcr, mv, nnz, levels = \
+                    cavlc_p_device.encode_p_cavlc_frame(
+                        jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr),
+                        *self._ref, hv, hl, qp, self._ktune, next_y,
+                        self._p_intra)
+            recon = (ry, rcb, rcr)
+            self._content_submit(
+                jnp.asarray(y), recon_y=ry, mv=mv,
+                resid=(levels["luma"], levels["cb_dc"], levels["cb_ac"],
+                       levels["cr_dc"], levels["cr_ac"]),
+                mb_intra=levels.get("mb_intra"))
+            if self.deblock:
+                self._ref = self._deblock(ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv)
+            else:
+                self._ref = recon
+            if self.keep_recon:
+                # pull NOW: with deblock off these arrays ARE the next
+                # submit's (donated) refs — by collect time they may be dead
+                recon = tuple(np.asarray(p) for p in recon)
+                mv = np.asarray(mv)
+            base = cavlc_device.META_WORDS * 4
+            guess = getattr(self, "_p_pull_guess", 2 * self._PULL_BUCKET)
+            prefix = flat[:base + guess]
+            _prefetch_host(prefix)
+        self._count_dispatch(ms=span.ms)
         return (qp, frame_num, levels, recon, flat, prefix, mv)
 
     def _collect_p_device(self, submitted) -> bytes:
@@ -1953,7 +1971,8 @@ class H264Encoder(Encoder):
             return self._collect_p_masked(submitted)
         qp, frame_num, levels, recon, flat, prefix, mv = submitted
         base = cavlc_device.META_WORDS * 4
-        buf = np.asarray(prefix)
+        with obst.stage("pull"):
+            buf = np.asarray(prefix)
         meta = cavlc_device.FlatMeta(buf, self.mb_h)
         if self.keep_recon:
             # THIS frame's recon (pulled at submit) — self._ref may
@@ -1967,15 +1986,16 @@ class H264Encoder(Encoder):
             # inter stage — it is literally the same tensors), so the
             # stream stays bit-consistent and the already-advanced
             # reference chain needs no rewind.
-            pulled = {k: np.asarray(v) for k, v in levels.items()}
-            pulled["mv"] = np.asarray(mv)
-            self.last_mv = pulled["mv"]
-            qp_map = pulled.pop("qp_map", None)
-            self._note_qp_map(qp_map, levels=pulled, slice_qp=qp)
-            return h264_entropy.encode_p_picture(
-                pulled, frame_num=frame_num, qp_delta=qp - self.qp,
-                deblocking_idc=self._deblock_idc,
-                qp_map=qp_map, slice_qp=qp)
+            with obst.stage("assemble", more=True):
+                pulled = {k: np.asarray(v) for k, v in levels.items()}
+                pulled["mv"] = np.asarray(mv)
+                self.last_mv = pulled["mv"]
+                qp_map = pulled.pop("qp_map", None)
+                self._note_qp_map(qp_map, levels=pulled, slice_qp=qp)
+                return h264_entropy.encode_p_picture(
+                    pulled, frame_num=frame_num, qp_delta=qp - self.qp,
+                    deblocking_idc=self._deblock_idc,
+                    qp_map=qp_map, slice_qp=qp)
         self._note_qp_sum(meta.qp_sum)
         need = 4 * meta.total_words
         bucket = self._PULL_BUCKET
@@ -1983,9 +2003,12 @@ class H264Encoder(Encoder):
         self._p_pull_guess = -(-max(self._p_pull_hist) // bucket) * bucket
         if need > len(buf) - base:
             extra = -(-need // bucket) * bucket
-            buf = np.asarray(flat[:base + extra])
-        return cavlc_device.assemble_annexb(
-            buf, meta, nal_type=syn.NAL_SLICE, ref_idc=2)
+            _M_PULL_EXTRA.inc()
+            with obst.stage("pull_extra"):
+                buf = np.asarray(flat[:base + extra])
+        with obst.stage("assemble", more=True):
+            return cavlc_device.assemble_annexb(
+                buf, meta, nal_type=syn.NAL_SLICE, ref_idc=2)
 
     # ------------------------------------------------------------------
     # Damage-driven encode (ops/damage_mask, ROADMAP item 3): the

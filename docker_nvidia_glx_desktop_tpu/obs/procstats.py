@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import logging
 import resource
+import threading
 
 from . import metrics as obsm
 from ..utils.env import env_float
@@ -37,6 +38,23 @@ _JAX_CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": "hits",
     "/jax/compilation_cache/compile_requests_use_cache": "requests",
 }
+
+# jax.monitoring compile-phase events -> the set-up counter each feeds.
+# They arrive as time spans that nest (a traced function traces the jitted
+# functions it calls; an eager operation inside a trace is a whole compile
+# of its own), so each counter takes a span's OWN time: the span less the
+# spans inside it on its thread.  The backend-compile span brackets the
+# cache look-up too, which is only a duration and is taken off it.
+_JAX_SPAN_COUNTERS = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        "dngd_jax_trace_lower_seconds_total",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        "dngd_jax_trace_lower_seconds_total",
+    "/jax/core/compile/backend_compile_duration":
+        "dngd_jax_backend_compile_seconds_total",
+}
+_JAX_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_SPAN_STACK_MAX = 256      # closed spans kept for a parent still open
 
 _listener_registered = False
 
@@ -141,6 +159,17 @@ def register_process_gauges(registry=None) -> None:
                "persistent cache (requests - hits, scrape time)",
                registry=reg).set_function(
         lambda: max(requests.value - hits.value, 0.0))
+    obsm.counter("dngd_jax_trace_lower_seconds_total",
+                 "Seconds spent tracing functions to jaxprs and lowering "
+                 "them to MLIR modules", registry=reg)
+    obsm.counter("dngd_jax_backend_compile_seconds_total",
+                 "Seconds in the XLA backend's compile step, less what a "
+                 "request the persistent cache served spent loading (for "
+                 "such a request what is left is the hashing of its key)",
+                 registry=reg)
+    obsm.counter("dngd_jax_cache_load_seconds_total",
+                 "Seconds spent fetching and deserialising executables "
+                 "from the persistent compile cache", registry=reg)
 
 
 def register_jax_cache_listener() -> bool:
@@ -158,6 +187,13 @@ def register_jax_cache_listener() -> bool:
     hits = obsm.REGISTRY.get("jax_compile_cache_hits_total")
     requests = obsm.REGISTRY.get("jax_compile_cache_requests_total")
 
+    span_counters = {event: obsm.REGISTRY.get(name)
+                     for event, name in _JAX_SPAN_COUNTERS.items()}
+    load = obsm.REGISTRY.get("dngd_jax_cache_load_seconds_total")
+    local = threading.local()   # .spans: closed (start, seconds) not yet
+    #                             inside a parent; .load: a retrieval not
+    #                             yet taken off its backend-compile span
+
     def on_event(event: str, **kwargs) -> None:
         kind = _JAX_CACHE_EVENTS.get(event)
         if kind == "hits":
@@ -165,8 +201,28 @@ def register_jax_cache_listener() -> bool:
         elif kind == "requests":
             requests.inc()
 
+    def on_duration(event: str, duration: float, **kwargs) -> None:
+        if event == _JAX_CACHE_LOAD_EVENT:
+            load.inc(duration)
+            local.load = getattr(local, "load", 0.0) + duration
+
+    def on_time_span(event: str, start: float, end: float,
+                     **kwargs) -> None:
+        counter = span_counters.get(event)
+        if counter is None:
+            return
+        spans = local.__dict__.setdefault("spans", [])
+        inside = local.__dict__.pop("load", 0.0)
+        while spans and spans[-1][0] >= start:
+            inside += spans.pop()[1]
+        spans.append((start, end - start))
+        del spans[:-_SPAN_STACK_MAX]
+        counter.inc(max(end - start - inside, 0.0))
+
     try:
         monitoring.register_event_listener(on_event)
+        monitoring.register_event_duration_secs_listener(on_duration)
+        monitoring.register_event_time_span_listener(on_time_span)
     except Exception:
         return False
     _listener_registered = True

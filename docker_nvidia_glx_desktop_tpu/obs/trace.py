@@ -25,10 +25,20 @@ oldest entry) and a listener raising out of its flush both count into
 asserts the counter stays 0 over its window (obs consumers see every
 span through the listener hook, so a non-zero count means the budget
 ledger's view is incomplete).
+
+Stage spans (:func:`stage`) name the work INSIDE a frame's marks —
+capture, colour conversion, dispatch, pull, assembly — where it happens.
+A stage span is a ``jax.profiler.TraceAnnotation("dngd.<name>")`` for its
+duration, so whenever a profiler session is open it lies on the device
+trace's clock, and on exit it observes its milliseconds into an
+unlabelled histogram of its own, ``dngd_stage_<name>_ms``.  It never
+touches a recorder: the per-frame marks, their ring and their listeners
+are as they were.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -39,7 +49,8 @@ from . import metrics as obsm
 
 __all__ = ["TraceRecorder", "tracer", "tracers", "next_frame_id",
            "export_chrome_trace", "set_enabled", "enabled",
-           "dropped_total", "DEFAULT_CAPACITY"]
+           "dropped_total", "DEFAULT_CAPACITY", "stage", "STAGES",
+           "STAGE_BUCKETS_MS", "M_WS_SEND_MS"]
 
 DEFAULT_CAPACITY = 4096      # spans per recorder (ring; oldest evicted)
 
@@ -52,10 +63,9 @@ _M_DROPPED = obsm.counter(
     "flush listener raised and its view of that entry is gone",
     ("tracer", "reason"))
 
-# Master switch for the A/B overhead gate (bench --quick
-# trace_overhead_pct): False turns record_span/record_marks into
-# early returns so the full-tracing vs no-tracing fps delta is
-# measurable on the identical serving path.
+# Master switch: False turns record_span/record_marks and stage spans
+# into early returns, so tracing on against tracing off is measurable on
+# the identical serving path.
 _ENABLED = True
 
 
@@ -72,6 +82,105 @@ def dropped_total() -> float:
     """Sum of dngd_trace_dropped_total over all children (the
     serving-budget smoke gate)."""
     return sum(child.value for _, child in _M_DROPPED.series())
+
+
+# -- stage spans ------------------------------------------------------
+
+# Fine enough for a p50 of a stage that takes 0.3 to 30 ms of a frame;
+# the last three tell a late frame from a compile in the serving thread.
+STAGE_BUCKETS_MS: Tuple[float, ...] = (
+    0.25, 0.5, 0.75, 1.0, 1.5, 2.0, 3.0, 4.0, 5.0, 6.0, 8.0, 10.0, 12.0,
+    15.0, 20.0, 25.0, 30.0, 40.0, 50.0, 100.0, 250.0, 1000.0)
+
+# The served per-frame path's stages (web/session.py, models/h264.py);
+# registered at import, so each family renders from the first scrape.
+STAGES = ("capture", "encode_submit", "encode_collect", "colour",
+          "dispatch", "pull", "pull_extra", "assemble")
+
+_stage_defs: Dict[str, tuple] = {}     # name -> (histogram, span name)
+_annotation = None                     # jax.profiler.TraceAnnotation, lazily
+_carry = threading.local()             # a split stage's first part, in ms
+
+
+def _load_annotation():
+    global _annotation
+    try:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    except Exception:
+        # obs/ works where JAX cannot be imported: a span of nothing
+        _annotation = contextlib.nullcontext
+    return _annotation
+
+
+def _stage_def(name: str) -> tuple:
+    st = _stage_defs.get(name)
+    if st is None:
+        st = _stage_defs[name] = (
+            obsm.histogram(f"dngd_stage_{name}_ms",
+                           f"Milliseconds in the frame stage '{name}' "
+                           "(one sample a frame; obs/trace.stage)",
+                           buckets=STAGE_BUCKETS_MS),
+            "dngd." + name)
+    return st
+
+
+class _StageSpan:
+    """One use of :func:`stage`.  ``ms`` is the span's duration once it
+    has closed, and 0.0 under ``set_enabled(False)``."""
+
+    __slots__ = ("_hist", "_span_name", "_more", "_ann", "_t0", "ms")
+
+    def __init__(self, hist, span_name: str, more: bool) -> None:
+        self._hist = hist
+        self._span_name = span_name
+        self._more = more
+        self._ann = None
+        self.ms = 0.0
+
+    def __enter__(self):
+        if _ENABLED:
+            ann = self._ann = (_annotation or _load_annotation())(
+                self._span_name)
+            ann.__enter__()
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        ann = self._ann
+        if ann is not None:
+            self.ms = ms = (time.perf_counter() - self._t0) * 1e3
+            ann.__exit__(*exc)
+            carried = _carry.__dict__
+            if self._more:
+                carried[self._span_name] = ms
+            else:
+                self._hist.observe(ms + carried.pop(self._span_name, 0.0))
+        return False
+
+
+def stage(name: str, more: bool = False) -> _StageSpan:
+    """``with stage("pull"): ...`` — the block is the profiler span
+    ``dngd.pull`` and one sample of ``dngd_stage_pull_ms``; an early
+    return under ``set_enabled(False)``.  ``more=True`` is for a stage
+    whose work lies in two places on one thread (``assemble``: the
+    encoder's Annex-B assembly, then the session's muxer): the first part
+    is its own profiler span and hands its milliseconds to the part that
+    closes the stage, which takes the frame's one sample."""
+    return _StageSpan(*_stage_def(name), more)
+
+
+for _name in STAGES:
+    _stage_def(_name)
+
+# The one stage that crosses threads, so it is no profiler span: stamped
+# by StreamSession._post on the encode thread, closed by web/server.py's
+# media pump once ``ws.send_bytes`` has returned.
+M_WS_SEND_MS = obsm.histogram(
+    "dngd_ws_publish_to_send_ms",
+    "Encode thread's publish to the websocket write's return, per "
+    "fragment and client: thread hand-off + event-loop queue + socket "
+    "write", buckets=STAGE_BUCKETS_MS)
 
 
 def next_frame_id() -> int:

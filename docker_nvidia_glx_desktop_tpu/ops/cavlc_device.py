@@ -731,8 +731,9 @@ def encode_intra_cavlc_frame(rgb, hdr_vals, hdr_lens, pad_h: int, pad_w: int,
     """
     from . import h264_device
 
-    levels = h264_device.encode_intra_frame.__wrapped__(
-        rgb, pad_h, pad_w, qp, i16_modes, tune, next_y)
+    with jax.named_scope("dngd.intra"):
+        levels = h264_device.encode_intra_frame.__wrapped__(
+            rgb, pad_h, pad_w, qp, i16_modes, tune, next_y)
     return _finish_cavlc(levels, hdr_vals, hdr_lens, with_recon, qp)
 
 
@@ -748,8 +749,9 @@ def encode_intra_cavlc_frame_yuv(y, cb, cr, hdr_vals, hdr_lens, qp: int,
     h264_device.encode_intra_frame_yuv)."""
     from . import h264_device
 
-    levels = h264_device.encode_intra_frame_yuv.__wrapped__(
-        y, cb, cr, qp, i16_modes, tune, next_y)
+    with jax.named_scope("dngd.intra"):
+        levels = h264_device.encode_intra_frame_yuv.__wrapped__(
+            y, cb, cr, qp, i16_modes, tune, next_y)
     return _finish_cavlc(levels, hdr_vals, hdr_lens, with_recon, qp)
 
 
@@ -768,10 +770,12 @@ encode_intra_cavlc_frame_yuv_dynqp = jax.jit(
 def _finish_cavlc(levels, hdr_vals, hdr_lens, with_recon: bool,
                   slice_qp: int = None):
     recon = (levels["recon_y"], levels["recon_cb"], levels["recon_cr"])
-    values, lengths, syn_vals, syn_lens, qp_sum = frame_block_slots(
-        levels, slice_qp)
-    flat, _ = pack_frame(values, lengths, syn_vals, syn_lens,
-                         hdr_vals, hdr_lens, qp_sum=qp_sum)
+    with jax.named_scope("dngd.slots"):
+        values, lengths, syn_vals, syn_lens, qp_sum = frame_block_slots(
+            levels, slice_qp)
+    with jax.named_scope("dngd.pack"):
+        flat, _ = pack_frame(values, lengths, syn_vals, syn_lens,
+                             hdr_vals, hdr_lens, qp_sum=qp_sum)
     if with_recon:
         return flat, recon
     return flat

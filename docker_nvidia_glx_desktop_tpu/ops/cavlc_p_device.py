@@ -431,35 +431,39 @@ def encode_p_cavlc_frame_padded(y, cb, cr, ref_y_pad, ref_cb_pad,
 
 def _finish_p(out: dict, hdr_vals, hdr_lens, slice_qp: int = None):
     import jax.numpy as jnp
-
-    values, lengths, cbp, mv = p_frame_block_slots(out)
-    mb_intra = out.get("mb_intra")
-    qp_se = None
-    qp_sum = None
-    if "qp_map" in out:
-        from . import aq
-        codes = cbp > 0            # skip MBs have cbp == 0 too
-        if mb_intra is not None:   # I_16x16 always codes mb_qp_delta
-            codes = codes | jnp.asarray(mb_intra, bool)
-        eff, delta = aq.qp_chain(out["qp_map"], codes, int(slice_qp))
-        from .cavlc_device import se_slots
-        sv, sl = se_slots(delta)
-        qp_se = (sv, jnp.where(codes, sl, 0))
-        qp_sum = jnp.sum(eff).astype(jnp.uint32)
-    hv6, hl6, tv, tl, _skip = p_mb_header_slots(mv, cbp, qp_se=qp_se,
-                                                mb_intra=mb_intra)
-    flat, _ = pack_p_frame(values, lengths, hv6, hl6, tv, tl,
-                           hdr_vals, hdr_lens, qp_sum=qp_sum)
-    # per-4x4 coded-coefficient flags in raster [by][bx] order — the
-    # deblocking bS=2 input (ops/h264_deblock.p_bs)
-    luma = out["luma"]                                  # (R,C,16blk,16)
-    nnz_idx = jnp.any(luma != 0, axis=-1)               # blkIdx order
-    nr, nc = nnz_idx.shape[:2]
-    from .h264_device import LUMA_BLOCK_ORDER
     import numpy as np
-    nnz = jnp.zeros((nr, nc, 4, 4), bool)
-    nnz = nnz.at[:, :, np.asarray(LUMA_BLOCK_ORDER[:, 1]),
-                 np.asarray(LUMA_BLOCK_ORDER[:, 0])].set(nnz_idx)
+
+    from .h264_device import LUMA_BLOCK_ORDER
+
+    with jax.named_scope("dngd.slots"):
+        values, lengths, cbp, mv = p_frame_block_slots(out)
+        mb_intra = out.get("mb_intra")
+        qp_se = None
+        qp_sum = None
+        if "qp_map" in out:
+            from . import aq
+            codes = cbp > 0            # skip MBs have cbp == 0 too
+            if mb_intra is not None:   # I_16x16 always codes mb_qp_delta
+                codes = codes | jnp.asarray(mb_intra, bool)
+            eff, delta = aq.qp_chain(out["qp_map"], codes, int(slice_qp))
+            from .cavlc_device import se_slots
+            sv, sl = se_slots(delta)
+            qp_se = (sv, jnp.where(codes, sl, 0))
+            qp_sum = jnp.sum(eff).astype(jnp.uint32)
+        hv6, hl6, tv, tl, _skip = p_mb_header_slots(mv, cbp, qp_se=qp_se,
+                                                    mb_intra=mb_intra)
+    with jax.named_scope("dngd.pack"):
+        flat, _ = pack_p_frame(values, lengths, hv6, hl6, tv, tl,
+                               hdr_vals, hdr_lens, qp_sum=qp_sum)
+    with jax.named_scope("dngd.deblock_bs"):
+        # per-4x4 coded-coefficient flags in raster [by][bx] order — the
+        # deblocking bS=2 input (ops/h264_deblock.p_bs)
+        luma = out["luma"]                              # (R,C,16blk,16)
+        nnz_idx = jnp.any(luma != 0, axis=-1)           # blkIdx order
+        nr, nc = nnz_idx.shape[:2]
+        nnz = jnp.zeros((nr, nc, 4, 4), bool)
+        nnz = nnz.at[:, :, np.asarray(LUMA_BLOCK_ORDER[:, 1]),
+                     np.asarray(LUMA_BLOCK_ORDER[:, 0])].set(nnz_idx)
     # residual levels for the host-entropy overflow fallback (mv rides
     # separately); pulled only when the flat cap overflowed.  The
     # tune=hq qp plane rides along: the fallback must re-emit the SAME

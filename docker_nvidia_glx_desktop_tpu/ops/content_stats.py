@@ -149,6 +149,7 @@ def _frame_vec(y, prev_y, recon_y, mv, resid, mb_intra, thr_sad: int):
 
 
 @functools.partial(jax.jit, static_argnames=("thr_sad",))
+@jax.named_scope("dngd.frame_stats")
 # NOT donated on purpose: prev_y is the PREVIOUS frame's ingest luma,
 # which the encoder keeps alive across frames (next frame's stats diff
 # against it) — donating it would invalidate the caller's held buffer.
@@ -163,6 +164,7 @@ def frame_stats(y, prev_y, recon_y, mv, resid, mb_intra, thr_sad: int):
 
 
 @functools.partial(jax.jit, static_argnames=("thr_sad",))
+@jax.named_scope("dngd.frame_stats")
 # NOT donated on purpose: prev_y (the previous chunk's last ingest) and
 # the staged ys stack stay owned by the encoder's ring across chunks.
 # dngd: ignore[jax-donate-missing]

@@ -249,58 +249,60 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
 
     from . import quant as _q
 
-    alpha_t, beta_t, tc0_t = load_tables()
-    if _q._is_static_qp(qp):
-        qp_c = _q.chroma_qp(qp)
-        a_l, b_l, t_l = int(alpha_t[qp]), int(beta_t[qp]), tc0_t[qp]
-        a_c, b_c, t_c = (int(alpha_t[qp_c]), int(beta_t[qp_c]),
-                         tc0_t[qp_c])
-    else:
-        # traced slice qp (deblock_frame_dynqp): the thresholds are
-        # table gathers instead of folded constants — same integers
-        qp_c = _q.chroma_qp_v(qp)
-        alpha_a, beta_a = jnp.asarray(alpha_t), jnp.asarray(beta_t)
-        tc0_a = jnp.asarray(tc0_t)
-        a_l, b_l, t_l = alpha_a[qp], beta_a[qp], tc0_a[qp]
-        a_c, b_c, t_c = alpha_a[qp_c], beta_a[qp_c], tc0_a[qp_c]
-    H, W = y.shape
-    nr, nc = H // 16, W // 16
-    intra = nnz_blk is None
+    with jax.named_scope("dngd.deblock_bs"):
+        alpha_t, beta_t, tc0_t = load_tables()
+        if _q._is_static_qp(qp):
+            qp_c = _q.chroma_qp(qp)
+            a_l, b_l, t_l = int(alpha_t[qp]), int(beta_t[qp]), tc0_t[qp]
+            a_c, b_c, t_c = (int(alpha_t[qp_c]), int(beta_t[qp_c]),
+                             tc0_t[qp_c])
+        else:
+            # traced slice qp (deblock_frame_dynqp): the thresholds are
+            # table gathers instead of folded constants — same integers
+            qp_c = _q.chroma_qp_v(qp)
+            alpha_a, beta_a = jnp.asarray(alpha_t), jnp.asarray(beta_t)
+            tc0_a = jnp.asarray(tc0_t)
+            a_l, b_l, t_l = alpha_a[qp], beta_a[qp], tc0_a[qp]
+            a_c, b_c, t_c = alpha_a[qp_c], beta_a[qp_c], tc0_a[qp_c]
+        H, W = y.shape
+        nr, nc = H // 16, W // 16
+        intra = nnz_blk is None
 
-    if not intra:
-        nnz16y = jnp.repeat(nnz_blk.astype(jnp.int32), 4, axis=2)
-        # (R, C, 16 lines, 4 bx) — per-line nnz along vertical edges
-        bs_v_int = jnp.stack(
-            [(nnz16y[:, :, :, bx - 1] | nnz16y[:, :, :, bx]) * 2
-             for bx in (1, 2, 3)], axis=2)                 # (R, C, 3, 16)
-        left_nnz = jnp.concatenate(
-            [jnp.zeros((nr, 1, 16), jnp.int32), nnz16y[:, :-1, :, 3]],
-            axis=1)
-        mvd = jnp.concatenate(
-            [jnp.zeros((nr, 1), bool),
-             (jnp.abs(mv[:, 1:] - mv[:, :-1]) >= 4).any(-1)], axis=1)
-        bs_mb0 = jnp.where((left_nnz | nnz16y[:, :, :, 0]) > 0, 2,
-                           jnp.where(mvd[:, :, None], 1, 0))
-        bs_mb0 = bs_mb0.at[:, 0].set(0)
-        nnz16x = jnp.repeat(nnz_blk.astype(jnp.int32), 4, axis=3)
-        bs_h_int = jnp.stack(
-            [(nnz16x[:, :, by - 1] | nnz16x[:, :, by]) * 2
-             for by in (1, 2, 3)], axis=2)                 # (R, C, 3, 16)
-        # scan-major layouts (C leading)
-        bs_v_int = jnp.moveaxis(bs_v_int, 1, 0)            # (C, R, 3, 16)
-        bs_mb0 = jnp.moveaxis(bs_mb0, 1, 0)                # (C, R, 16)
-        bs_h_int = jnp.moveaxis(bs_h_int, 1, 0)
+        if not intra:
+            nnz16y = jnp.repeat(nnz_blk.astype(jnp.int32), 4, axis=2)
+            # (R, C, 16 lines, 4 bx) — per-line nnz along vertical edges
+            bs_v_int = jnp.stack(
+                [(nnz16y[:, :, :, bx - 1] | nnz16y[:, :, :, bx]) * 2
+                 for bx in (1, 2, 3)], axis=2)                 # (R, C, 3, 16)
+            left_nnz = jnp.concatenate(
+                [jnp.zeros((nr, 1, 16), jnp.int32), nnz16y[:, :-1, :, 3]],
+                axis=1)
+            mvd = jnp.concatenate(
+                [jnp.zeros((nr, 1), bool),
+                 (jnp.abs(mv[:, 1:] - mv[:, :-1]) >= 4).any(-1)], axis=1)
+            bs_mb0 = jnp.where((left_nnz | nnz16y[:, :, :, 0]) > 0, 2,
+                               jnp.where(mvd[:, :, None], 1, 0))
+            bs_mb0 = bs_mb0.at[:, 0].set(0)
+            nnz16x = jnp.repeat(nnz_blk.astype(jnp.int32), 4, axis=3)
+            bs_h_int = jnp.stack(
+                [(nnz16x[:, :, by - 1] | nnz16x[:, :, by]) * 2
+                 for by in (1, 2, 3)], axis=2)                 # (R, C, 3, 16)
+            # scan-major layouts (C leading)
+            bs_v_int = jnp.moveaxis(bs_v_int, 1, 0)            # (C, R, 3, 16)
+            bs_mb0 = jnp.moveaxis(bs_mb0, 1, 0)                # (C, R, 16)
+            bs_h_int = jnp.moveaxis(bs_h_int, 1, 0)
 
-    # MB-tiled planes, scan axis (MB column) leading
-    ymbs = jnp.moveaxis(
-        y.astype(jnp.int32).reshape(nr, 16, nc, 16).transpose(0, 2, 1, 3),
-        1, 0)                                              # (C, R, 16, 16)
-    cbm = jnp.moveaxis(
-        cb.astype(jnp.int32).reshape(nr, 8, nc, 8).transpose(0, 2, 1, 3),
-        1, 0)
-    crm = jnp.moveaxis(
-        cr.astype(jnp.int32).reshape(nr, 8, nc, 8).transpose(0, 2, 1, 3),
-        1, 0)
+    with jax.named_scope("dngd.deblock_tile"):
+        # MB-tiled planes, scan axis (MB column) leading
+        ymbs = jnp.moveaxis(
+            y.astype(jnp.int32).reshape(nr, 16, nc, 16).transpose(0, 2, 1, 3),
+            1, 0)                                              # (C, R, 16, 16)
+        cbm = jnp.moveaxis(
+            cb.astype(jnp.int32).reshape(nr, 8, nc, 8).transpose(0, 2, 1, 3),
+            1, 0)
+        crm = jnp.moveaxis(
+            cr.astype(jnp.int32).reshape(nr, 8, nc, 8).transpose(0, 2, 1, 3),
+            1, 0)
 
     # Auto group: the wavefront amortizes the PER-STEP cost of a scan
     # iteration (fusion dispatch + carry shuffling), which is what the
@@ -332,23 +334,29 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
         # --- luma: x=0 MB edge spans the carry (p) and this MB (q);
         # the H pass covers only THIS MB's 16 columns (the carry's H
         # edges were filtered in the previous step) ---
-        wide = jnp.concatenate([yl, ymb], axis=-1)         # (R, 16, 20)
-        wide = _edge_v_mb(wide, 4, bs0, a_l, b_l, t_l, False)
-        for e, x in enumerate((4, 8, 12)):
-            wide = _edge_v_mb(wide, 4 + x, bsv[e], a_l, b_l, t_l, False)
-        left_fin = wide[..., :4]        # left MB cols 12..15, FINAL
-        own = wide[..., 4:]
-        for e, yy_ in enumerate((4, 8, 12)):
-            own = _edge_h_mb(own, yy_, bsh[e], a_l, b_l, t_l, False)
+        with jax.named_scope("dngd.deblock_v"):
+            wide = jnp.concatenate([yl, ymb], axis=-1)     # (R, 16, 20)
+            wide = _edge_v_mb(wide, 4, bs0, a_l, b_l, t_l, False)
+            for e, x in enumerate((4, 8, 12)):
+                wide = _edge_v_mb(wide, 4 + x, bsv[e], a_l, b_l, t_l,
+                                  False)
+            left_fin = wide[..., :4]    # left MB cols 12..15, FINAL
+            own = wide[..., 4:]
+        with jax.named_scope("dngd.deblock_h"):
+            for e, yy_ in enumerate((4, 8, 12)):
+                own = _edge_h_mb(own, yy_, bsh[e], a_l, b_l, t_l, False)
 
         # --- chroma: MB edge + internal x=4 (luma x=8), h y=4 (luma 8) --
         def chroma_mb(mbp, left):
-            w2 = jnp.concatenate([left, mbp], axis=-1)     # (R, 8, 12)
-            w2 = _edge_v_mb(w2, 4, bs0[:, 0::2], a_c, b_c, t_c, True)
-            w2 = _edge_v_mb(w2, 8, bsv[1][:, 0::2], a_c, b_c, t_c, True)
-            lf, ownp = w2[..., :4], w2[..., 4:]
-            ownp = _edge_h_mb(ownp, 4, bsh[1][:, 0::2], a_c, b_c, t_c,
-                              True)
+            with jax.named_scope("dngd.deblock_v"):
+                w2 = jnp.concatenate([left, mbp], axis=-1)  # (R, 8, 12)
+                w2 = _edge_v_mb(w2, 4, bs0[:, 0::2], a_c, b_c, t_c, True)
+                w2 = _edge_v_mb(w2, 8, bsv[1][:, 0::2], a_c, b_c, t_c,
+                                True)
+                lf, ownp = w2[..., :4], w2[..., 4:]
+            with jax.named_scope("dngd.deblock_h"):
+                ownp = _edge_h_mb(ownp, 4, bsh[1][:, 0::2], a_c, b_c, t_c,
+                                  True)
             return lf, ownp
 
         cbl_fin, cb_own = chroma_mb(cbmb, cbl)
@@ -370,18 +378,22 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
         return carry, tuple(jnp.stack(parts, 0)
                             for parts in zip(*outs))
 
-    init = (jnp.zeros((nr, 16, 4), jnp.int32),
-            jnp.zeros((nr, 8, 4), jnp.int32),
-            jnp.zeros((nr, 8, 4), jnp.int32))
-    if intra:
-        xs = (ymbs, cbm, crm, jnp.arange(nc, dtype=jnp.int32))
-    else:
-        xs = (ymbs, cbm, crm, bs_v_int, bs_mb0, bs_h_int,
-              jnp.arange(nc, dtype=jnp.int32))
-    xs = tuple(x.reshape((nc // group, group) + x.shape[1:]) for x in xs)
-    carry, outs = jax.lax.scan(step, init, xs)
-    outs = tuple(o.reshape((nc,) + o.shape[2:]) for o in outs)
-    lf3, own13, cblf, cbo6, crlf, cro6 = outs
+    # the scan is one ``while`` on the device; the edges it filters carry
+    # the two scopes inside it
+    with jax.named_scope("dngd.deblock_edges"):
+        init = (jnp.zeros((nr, 16, 4), jnp.int32),
+                jnp.zeros((nr, 8, 4), jnp.int32),
+                jnp.zeros((nr, 8, 4), jnp.int32))
+        if intra:
+            xs = (ymbs, cbm, crm, jnp.arange(nc, dtype=jnp.int32))
+        else:
+            xs = (ymbs, cbm, crm, bs_v_int, bs_mb0, bs_h_int,
+                  jnp.arange(nc, dtype=jnp.int32))
+        xs = tuple(x.reshape((nc // group, group) + x.shape[1:])
+                   for x in xs)
+        carry, outs = jax.lax.scan(step, init, xs)
+        outs = tuple(o.reshape((nc,) + o.shape[2:]) for o in outs)
+        lf3, own13, cblf, cbo6, crlf, cro6 = outs
 
     def assemble(own_first, later_last, tailc, sub):
         """MB c's leading columns from step c, trailing columns from
@@ -391,11 +403,12 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
         full = jnp.moveaxis(mbs, 0, 1)                      # (R,C,s,s)
         return full.transpose(0, 2, 1, 3).reshape(H // sub, W // sub)
 
-    y_out = assemble(own13, lf3, carry[0][..., 1:], 1)
-    cb_out = assemble(cbo6, cblf, carry[1][..., 2:], 2)
-    cr_out = assemble(cro6, crlf, carry[2][..., 2:], 2)
-    clip = lambda p: jnp.clip(p, 0, 255).astype(jnp.uint8)
-    return clip(y_out), clip(cb_out), clip(cr_out)
+    with jax.named_scope("dngd.deblock_tile"):
+        y_out = assemble(own13, lf3, carry[0][..., 1:], 1)
+        cb_out = assemble(cbo6, cblf, carry[1][..., 2:], 2)
+        cr_out = assemble(cro6, crlf, carry[2][..., 2:], 2)
+        clip = lambda p: jnp.clip(p, 0, 255).astype(jnp.uint8)
+        return clip(y_out), clip(cb_out), clip(cr_out)
 
 
 #: qp-traced twin: one program for every slice qp (see

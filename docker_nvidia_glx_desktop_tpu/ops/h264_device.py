@@ -541,12 +541,14 @@ def encode_intra_frame(rgb, pad_h: int, pad_w: int, qp: int,
     R = pad_h//16 MB rows and C = pad_w//16 MB columns.
     """
     h, w = rgb.shape[0], rgb.shape[1]
-    rgb_p = jnp.pad(jnp.asarray(rgb), ((0, pad_h - h), (0, pad_w - w), (0, 0)),
-                    mode="edge")
-    yf, cbf, crf = color.rgb_to_yuv420(rgb_p, matrix="video")
-    y = jnp.clip(jnp.round(yf), 0, 255).astype(jnp.int32)
-    cb = jnp.clip(jnp.round(cbf), 0, 255).astype(jnp.int32)
-    cr = jnp.clip(jnp.round(crf), 0, 255).astype(jnp.int32)
+    with jax.named_scope("dngd.colour"):
+        rgb_p = jnp.pad(jnp.asarray(rgb),
+                        ((0, pad_h - h), (0, pad_w - w), (0, 0)),
+                        mode="edge")
+        yf, cbf, crf = color.rgb_to_yuv420(rgb_p, matrix="video")
+        y = jnp.clip(jnp.round(yf), 0, 255).astype(jnp.int32)
+        cb = jnp.clip(jnp.round(cbf), 0, 255).astype(jnp.int32)
+        cr = jnp.clip(jnp.round(crf), 0, 255).astype(jnp.int32)
     return encode_intra_frame_yuv.__wrapped__(y, cb, cr, qp, i16_modes,
                                               tune, next_y)
 

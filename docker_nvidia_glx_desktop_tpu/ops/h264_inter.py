@@ -286,14 +286,14 @@ def encode_p_frame(y, cb, cr, ref_y, ref_cb, ref_cr, qp: int,
 
     ``tune``/``next_y``: the ENCODER_TUNE=hq axis — see
     :func:`encode_p_frame_padded_ref`."""
-    ref_y = jnp.asarray(ref_y).astype(jnp.int32)
-    ref_cb = jnp.asarray(ref_cb).astype(jnp.int32)
-    ref_cr = jnp.asarray(ref_cr).astype(jnp.int32)
+    with jax.named_scope("dngd.ingest"):
+        # (the order of the parent's program: the persistent cache's key
+        # leaves metadata out, and so still serves what it held)
+        refs = [jnp.asarray(r).astype(jnp.int32)
+                for r in (ref_y, ref_cb, ref_cr)]
+        ref_pads = [jnp.pad(r, _PAD, mode="edge") for r in refs]
     return encode_p_frame_padded_ref(
-        y, cb, cr,
-        jnp.pad(ref_y, _PAD, mode="edge"),
-        jnp.pad(ref_cb, _PAD, mode="edge"),
-        jnp.pad(ref_cr, _PAD, mode="edge"), qp, refine=refine,
+        y, cb, cr, *ref_pads, qp, refine=refine,
         tune=tune, next_y=next_y, p_intra=p_intra)
 
 
@@ -337,468 +337,489 @@ def encode_p_frame_padded_ref(y, cb, cr, ref_y_pad, ref_cb_pad, ref_cr_pad,
     exactly what a conformant decoder derives.  Callers gate it off for
     entropy paths without I16-in-P plumbing (CABAC binarize, native C)
     and when the loop filter is on (intra bS rules are not modeled)."""
-    y = jnp.asarray(y).astype(jnp.int32)
-    cb = jnp.asarray(cb).astype(jnp.int32)
-    cr = jnp.asarray(cr).astype(jnp.int32)
-    ref_pad = jnp.asarray(ref_y_pad).astype(jnp.int32)
-    ref_cb_pad = jnp.asarray(ref_cb_pad).astype(jnp.int32)
-    ref_cr_pad = jnp.asarray(ref_cr_pad).astype(jnp.int32)
-    if tune not in ("off", "hq", "hq_noaq"):
-        raise ValueError(f"unknown tune {tune!r}")
-    quant.require_static_qp_unless_off(qp, tune)
-    pad_h, pad_w = y.shape
-    nr, nc = pad_h // 16, pad_w // 16
+    with jax.named_scope("dngd.ingest"):
+        y = jnp.asarray(y).astype(jnp.int32)
+        cb = jnp.asarray(cb).astype(jnp.int32)
+        cr = jnp.asarray(cr).astype(jnp.int32)
+        ref_pad = jnp.asarray(ref_y_pad).astype(jnp.int32)
+        ref_cb_pad = jnp.asarray(ref_cb_pad).astype(jnp.int32)
+        ref_cr_pad = jnp.asarray(ref_cr_pad).astype(jnp.int32)
+        if tune not in ("off", "hq", "hq_noaq"):
+            raise ValueError(f"unknown tune {tune!r}")
+        quant.require_static_qp_unless_off(qp, tune)
+        pad_h, pad_w = y.shape
+        nr, nc = pad_h // 16, pad_w // 16
 
-    qp_map = None
-    if tune == "off":
-        # qp may be a Python int (one program per qp) or a traced
-        # scalar (one program for every qp — the served per-frame path)
-        qp_q, qp_c = qp, quant.chroma_qp_any(qp)
-        lam_d = lam_v = None
-    else:
-        from . import aq
-        if tune == "hq":
-            qp_map = aq.qp_plane(y, qp, next_y)         # (R, C)
-            qp_q = qp_map
-            qp_c = quant.chroma_qp_v(qp_map)
-            lam_d = aq.lam_mode(qp_map)                 # (R, C) float32
-            lam_v = aq.lam_mv(qp_map)
+        qp_map = None
+        if tune == "off":
+            # qp may be a Python int (one program per qp) or a traced
+            # scalar (one program for every qp — the served per-frame path)
+            qp_q, qp_c = qp, quant.chroma_qp_any(qp)
+            lam_d = lam_v = None
         else:
-            qp_q, qp_c = qp, quant.chroma_qp(qp)
-            lam_d = jnp.float32(aq.lam_mode(qp))
-            lam_v = jnp.float32(aq.lam_mv(qp))
+            from . import aq
+            if tune == "hq":
+                qp_map = aq.qp_plane(y, qp, next_y)         # (R, C)
+                qp_q = qp_map
+                qp_c = quant.chroma_qp_v(qp_map)
+                lam_d = aq.lam_mode(qp_map)                 # (R, C) float32
+                lam_v = aq.lam_mv(qp_map)
+            else:
+                qp_q, qp_c = qp, quant.chroma_qp(qp)
+                lam_d = jnp.float32(aq.lam_mode(qp))
+                lam_v = jnp.float32(aq.lam_mv(qp))
 
-    # --- integer motion estimation: coarse grid ------------------------
-    # Alternate-line SAD (even rows only): half the abs-diff traffic and
-    # half the pooled rows for the map stage that evaluates 81 candidates
-    # — the classic encoder trade.  Under refine="alt" (default) the
-    # +-1/half/quarter refinement stages below score on the SAME
-    # alternate-line scale (biases halved with it); refine="full"
-    # re-ranks with full-line SADs at full-strength biases.  The zero-MV
-    # bias here is halved to match the half-sample magnitudes.
-    shifts = jnp.asarray(_candidate_shifts())              # (81, 2)
-    y_alt = y[0::2]
+    with jax.named_scope("dngd.me_int"):
+        # --- integer motion estimation: coarse grid ------------------------
+        # Alternate-line SAD (even rows only): half the abs-diff traffic and
+        # half the pooled rows for the map stage that evaluates 81 candidates
+        # — the classic encoder trade.  Under refine="alt" (default) the
+        # +-1/half/quarter refinement stages below score on the SAME
+        # alternate-line scale (biases halved with it); refine="full"
+        # re-ranks with full-line SADs at full-strength biases.  The zero-MV
+        # bias here is halved to match the half-sample magnitudes.
+        shifts = jnp.asarray(_candidate_shifts())              # (81, 2)
+        y_alt = y[0::2]
 
-    def sad_for(shift):
-        dy, dx = shift[0], shift[1]
-        shifted = jax.lax.dynamic_slice(
-            ref_pad, (_PAD + dy, _PAD + dx), (pad_h, pad_w))
-        return _block_sum_mm(jnp.abs(y_alt - shifted[0::2]), 8, 16)
+        def sad_for(shift):
+            dy, dx = shift[0], shift[1]
+            shifted = jax.lax.dynamic_slice(
+                ref_pad, (_PAD + dy, _PAD + dx), (pad_h, pad_w))
+            return _block_sum_mm(jnp.abs(y_alt - shifted[0::2]), 8, 16)
 
-    sads = jax.lax.map(sad_for, shifts)                    # (81, R, C)
-    zero_idx = shifts.shape[0] // 2                        # (0, 0) center
-    # tune=hq replaces the fixed skip-ability bonus with a lambda-scaled
-    # rate saving (~16 bits of mvd+cbp a zero-MV MB can skip), halved to
-    # the alternate-line SAD scale of this stage
-    if lam_v is None:
-        zb_coarse = ZERO_MV_BIAS // 2
-    else:
-        zb_coarse = (lam_v * (_RATE_ZERO_BITS / 2)).astype(jnp.int32)
-    sads = sads.at[zero_idx].add(-zb_coarse)
-    best = jnp.argmin(sads, axis=0)                        # (R, C)
-    mv_coarse = shifts[best]                               # (R, C, 2)
+        sads = jax.lax.map(sad_for, shifts)                    # (81, R, C)
+        zero_idx = shifts.shape[0] // 2                        # (0, 0) center
+        # tune=hq replaces the fixed skip-ability bonus with a lambda-scaled
+        # rate saving (~16 bits of mvd+cbp a zero-MV MB can skip), halved to
+        # the alternate-line SAD scale of this stage
+        if lam_v is None:
+            zb_coarse = ZERO_MV_BIAS // 2
+        else:
+            zb_coarse = (lam_v * (_RATE_ZERO_BITS / 2)).astype(jnp.int32)
+        sads = sads.at[zero_idx].add(-zb_coarse)
+        best = jnp.argmin(sads, axis=0)                        # (R, C)
+        mv_coarse = shifts[best]                               # (R, C, 2)
 
-    # --- interpolated planes (shared cropped domain, +2 base) ----------
-    b_pl, h_pl, j_pl = _halfpel_planes(ref_pad)
-    full_pl = ref_pad[2:-3, 2:-3]
+    with jax.named_scope("dngd.me_subpel"):
+        # --- interpolated planes (shared cropped domain, +2 base) ----------
+        b_pl, h_pl, j_pl = _halfpel_planes(ref_pad)
+        full_pl = ref_pad[2:-3, 2:-3]
 
-    cur_y = y.reshape(nr, 16, nc, 16).transpose(0, 2, 1, 3)
+        cur_y = y.reshape(nr, 16, nc, 16).transpose(0, 2, 1, 3)
 
-    neighbors = [(dy, dx) for dy in (-1, 0, 1) for dx in (-1, 0, 1)
-                 if (dy, dx) != (0, 0)]                    # static, 8
-    neighbors_j = jnp.asarray(neighbors, dtype=jnp.int32)
+        neighbors = [(dy, dx) for dy in (-1, 0, 1) for dx in (-1, 0, 1)
+                     if (dy, dx) != (0, 0)]                    # static, 8
+        neighbors_j = jnp.asarray(neighbors, dtype=jnp.int32)
 
-    # Per-MB overlapping spans of the four planes (base_y=1 in plane
-    # coords puts plane row r*16 + (_PAD-2) + t + i at span index
-    # 10 + t + i; span 36 covers t in [-10, 10] — the mv_int range plus
-    # the -1 of a half-pel floor AND the +1 right/below neighbor a
-    # frac-3 quarter sample averages with).
-    _SPAN = 36
-    tiles4 = [_tiles(p.astype(jnp.uint8), 1, 1, 16, _SPAN, nr, nc)
-              for p in (full_pl, b_pl, h_pl, j_pl)]        # (R,C,36,36) x4
+        # Per-MB overlapping spans of the four planes (base_y=1 in plane
+        # coords puts plane row r*16 + (_PAD-2) + t + i at span index
+        # 10 + t + i; span 36 covers t in [-10, 10] — the mv_int range plus
+        # the -1 of a half-pel floor AND the +1 right/below neighbor a
+        # frac-3 quarter sample averages with).
+        _SPAN = 36
+        tiles4 = [_tiles(p.astype(jnp.uint8), 1, 1, 16, _SPAN, nr, nc)
+                  for p in (full_pl, b_pl, h_pl, j_pl)]        # (R,C,36,36) x4
 
-    # --- +-1 integer refinement of the coarse grid ---------------------
-    # An 18-wide window aligned one pel above-left of mv_coarse holds all
-    # nine candidates (center included) as static slices.  Under
-    # refine="alt" the re-rank (and both subpel stages below) evaluates
-    # the residual window on EVERY OTHER luma line — the same scale as
-    # the coarse stage, so best_sad carries cleanly into the half-pel
-    # comparison and all biases halve with it; refine="full" keeps the
-    # full-line re-rank and full-strength biases (pre-round-6 behavior).
-    # The (0,0) displacement keeps the zero-MV bias — it is reachable
-    # only as the center of a zero coarse MV — so static content stays
-    # skippable.
-    alt = refine != "full"
-    srow = 2 if alt else 1
-    scale = srow
-    cur_cmp = cur_y[:, :, 0::srow, :]
+    with jax.named_scope("dngd.me_int"):
+        # --- +-1 integer refinement of the coarse grid ---------------------
+        # An 18-wide window aligned one pel above-left of mv_coarse holds all
+        # nine candidates (center included) as static slices.  Under
+        # refine="alt" the re-rank (and both subpel stages below) evaluates
+        # the residual window on EVERY OTHER luma line — the same scale as
+        # the coarse stage, so best_sad carries cleanly into the half-pel
+        # comparison and all biases halve with it; refine="full" keeps the
+        # full-line re-rank and full-strength biases (pre-round-6 behavior).
+        # The (0,0) displacement keeps the zero-MV bias — it is reachable
+        # only as the center of a zero coarse MV — so static content stays
+        # skippable.
+        alt = refine != "full"
+        srow = 2 if alt else 1
+        scale = srow
+        cur_cmp = cur_y[:, :, 0::srow, :]
 
-    w18 = _mb_windows(tiles4[0][:, :, 1:, 1:],
-                      mv_coarse[..., 0], mv_coarse[..., 1], 8, 18)
+        w18 = _mb_windows(tiles4[0][:, :, 1:, 1:],
+                          mv_coarse[..., 0], mv_coarse[..., 1], 8, 18)
 
-    def w_sad(win, oy, ox, size=16):
-        sl = win[:, :, 1 + oy: 1 + oy + size: srow,
-                 1 + ox: 1 + ox + size]
-        return jnp.abs(cur_cmp - sl.astype(jnp.int32)).sum(axis=(2, 3))
+        def w_sad(win, oy, ox, size=16):
+            sl = win[:, :, 1 + oy: 1 + oy + size: srow,
+                     1 + ox: 1 + ox + size]
+            return jnp.abs(cur_cmp - sl.astype(jnp.int32)).sum(axis=(2, 3))
 
-    cands = [(0, 0)] + neighbors
-    int_sads = jnp.stack([w_sad(w18, oy, ox) for oy, ox in cands])
-    is_zero = (mv_coarse[..., 0] == 0) & (mv_coarse[..., 1] == 0)
-    if lam_v is None:
-        zb_int = ZERO_MV_BIAS // scale
-    else:
-        zb_int = (lam_v * (_RATE_ZERO_BITS / scale)).astype(jnp.int32)
-    int_sads = int_sads.at[0].add(jnp.where(is_zero, -zb_int, 0))
-    best_int = jnp.argmin(int_sads, axis=0)                # (R, C)
-    best_sad = jnp.take_along_axis(int_sads, best_int[None], axis=0)[0]
-    mv_int = mv_coarse + jnp.asarray(cands, jnp.int32)[best_int]
+        cands = [(0, 0)] + neighbors
+        int_sads = jnp.stack([w_sad(w18, oy, ox) for oy, ox in cands])
+        is_zero = (mv_coarse[..., 0] == 0) & (mv_coarse[..., 1] == 0)
+        if lam_v is None:
+            zb_int = ZERO_MV_BIAS // scale
+        else:
+            zb_int = (lam_v * (_RATE_ZERO_BITS / scale)).astype(jnp.int32)
+        int_sads = int_sads.at[0].add(jnp.where(is_zero, -zb_int, 0))
+        best_int = jnp.argmin(int_sads, axis=0)                # (R, C)
+        best_sad = jnp.take_along_axis(int_sads, best_int[None], axis=0)[0]
+        mv_int = mv_coarse + jnp.asarray(cands, jnp.int32)[best_int]
 
-    # --- half-pel refinement (normative 6-tap planes, §8.4.2.2.1) ------
-    # 18-wide windows of all four planes aligned one pel above-left of
-    # mv_int (one pel of margin each side: the low side serves half-pel
-    # floors, the high side the +1 neighbors of frac-3 quarter samples):
-    # neighbor (oy, ox) is plane parity (oy&1, ox&1) sliced at
-    # (1 + (oy>>1), 1 + (ox>>1)) — floor semantics, matching mv>>1 of the
-    # half-pel mv mv_int*2 + off.
-    w17 = [_mb_windows(t, mv_int[..., 0], mv_int[..., 1], 9, 18)
-           for t in tiles4]
+    with jax.named_scope("dngd.me_subpel"):
+        # --- half-pel refinement (normative 6-tap planes, §8.4.2.2.1) ------
+        # 18-wide windows of all four planes aligned one pel above-left of
+        # mv_int (one pel of margin each side: the low side serves half-pel
+        # floors, the high side the +1 neighbors of frac-3 quarter samples):
+        # neighbor (oy, ox) is plane parity (oy&1, ox&1) sliced at
+        # (1 + (oy>>1), 1 + (ox>>1)) — floor semantics, matching mv>>1 of the
+        # half-pel mv mv_int*2 + off.
+        w17 = [_mb_windows(t, mv_int[..., 0], mv_int[..., 1], 9, 18)
+               for t in tiles4]
 
-    def wslice_s(p, ry, rx):
-        """SAD view of plane p's window at integer offset (ry, rx)
-        relative to mv_int — every ``srow``-th line."""
-        return w17[p][:, :, 1 + ry: 17 + ry: srow, 1 + rx: 17 + rx]
+        def wslice_s(p, ry, rx):
+            """SAD view of plane p's window at integer offset (ry, rx)
+            relative to mv_int — every ``srow``-th line."""
+            return w17[p][:, :, 1 + ry: 17 + ry: srow, 1 + rx: 17 + rx]
 
-    def half_slice_s(oy, ox):
-        """SAD view of the half-pel candidate mv_int*2 + off."""
-        p = (oy & 1) * 2 + (ox & 1)
-        return wslice_s(p, oy >> 1, ox >> 1)
+        def half_slice_s(oy, ox):
+            """SAD view of the half-pel candidate mv_int*2 + off."""
+            p = (oy & 1) * 2 + (ox & 1)
+            return wslice_s(p, oy >> 1, ox >> 1)
 
-    half_sads = jnp.stack([
-        jnp.abs(cur_cmp - half_slice_s(oy, ox).astype(jnp.int32)
-                ).sum(axis=(2, 3))
-        for oy, ox in neighbors])                          # (8, R, C)
-    best_half = jnp.argmin(half_sads, axis=0)              # (R, C)
-    half_min = jnp.take_along_axis(
-        half_sads, best_half[None], axis=0)[0]
-    if lam_v is None:
-        hb = HALF_BIAS // scale
-    else:
-        hb = (lam_v * (_RATE_HALF_BITS / scale)).astype(jnp.int32)
-    use_half = half_min + hb < best_sad                    # (R, C)
-    mv_h = mv_int * 2 + jnp.where(use_half[..., None],
-                                  neighbors_j[best_half], 0)  # half-pel
-    sad_h = jnp.where(use_half, half_min, best_sad)
+        half_sads = jnp.stack([
+            jnp.abs(cur_cmp - half_slice_s(oy, ox).astype(jnp.int32)
+                    ).sum(axis=(2, 3))
+            for oy, ox in neighbors])                          # (8, R, C)
+        best_half = jnp.argmin(half_sads, axis=0)              # (R, C)
+        half_min = jnp.take_along_axis(
+            half_sads, best_half[None], axis=0)[0]
+        if lam_v is None:
+            hb = HALF_BIAS // scale
+        else:
+            hb = (lam_v * (_RATE_HALF_BITS / scale)).astype(jnp.int32)
+        use_half = half_min + hb < best_sad                    # (R, C)
+        mv_h = mv_int * 2 + jnp.where(use_half[..., None],
+                                      neighbors_j[best_half], 0)  # half-pel
+        sad_h = jnp.where(use_half, half_min, best_sad)
 
-    # --- quarter-pel refinement (spec §8.4.2.2.1 a..s) -----------------
-    # Quarter samples are rounded averages of two full/half samples, so
-    # every candidate is (A + B + 1) >> 1 of two static window slices.
-    # The (plane, dy, dx) pairs per quarter fraction (fy, fx); the int
-    # part and fraction of candidate mv_h*2+qoff depend on the SIGNED
-    # half-pel offset hd = mv_h - 2*mv_int in {-1, 0, 1} per axis (parity
-    # alone would alias off=-1 onto off=+1, displacing the window a full
-    # pel), so each candidate one-hots over the nine (hy, hx) offsets —
-    # e = 2*hd + qoff in [-3, 3] maps to rel = e>>2, frac = e&3.
-    QPEL = {
-        (0, 0): ((0, 0, 0),),
-        (0, 1): ((0, 0, 0), (1, 0, 0)),       # a = (G + b + 1) >> 1
-        (0, 2): ((1, 0, 0),),                 # b
-        (0, 3): ((1, 0, 0), (0, 0, 1)),       # c = (b + H) — H right full
-        (1, 0): ((0, 0, 0), (2, 0, 0)),       # d
-        (1, 1): ((1, 0, 0), (2, 0, 0)),       # e = (b + h)
-        (1, 2): ((1, 0, 0), (3, 0, 0)),       # f = (b + j)
-        (1, 3): ((1, 0, 0), (2, 0, 1)),       # g = (b + m) — m right h
-        (2, 0): ((2, 0, 0),),                 # h
-        (2, 1): ((2, 0, 0), (3, 0, 0)),       # i = (h + j)
-        (2, 2): ((3, 0, 0),),                 # j
-        (2, 3): ((3, 0, 0), (2, 0, 1)),       # k = (j + m)
-        (3, 0): ((2, 0, 0), (0, 1, 0)),       # n = (h + M) — M below full
-        (3, 1): ((2, 0, 0), (1, 1, 0)),       # p = (h + s) — s below b
-        (3, 2): ((3, 0, 0), (1, 1, 0)),       # q = (j + s)
-        (3, 3): ((2, 0, 1), (1, 1, 0)),       # r = (m + s)
-    }
+        # --- quarter-pel refinement (spec §8.4.2.2.1 a..s) -----------------
+        # Quarter samples are rounded averages of two full/half samples, so
+        # every candidate is (A + B + 1) >> 1 of two static window slices.
+        # The (plane, dy, dx) pairs per quarter fraction (fy, fx); the int
+        # part and fraction of candidate mv_h*2+qoff depend on the SIGNED
+        # half-pel offset hd = mv_h - 2*mv_int in {-1, 0, 1} per axis (parity
+        # alone would alias off=-1 onto off=+1, displacing the window a full
+        # pel), so each candidate one-hots over the nine (hy, hx) offsets —
+        # e = 2*hd + qoff in [-3, 3] maps to rel = e>>2, frac = e&3.
+        QPEL = {
+            (0, 0): ((0, 0, 0),),
+            (0, 1): ((0, 0, 0), (1, 0, 0)),       # a = (G + b + 1) >> 1
+            (0, 2): ((1, 0, 0),),                 # b
+            (0, 3): ((1, 0, 0), (0, 0, 1)),      # c = (b + H) — H right full
+            (1, 0): ((0, 0, 0), (2, 0, 0)),       # d
+            (1, 1): ((1, 0, 0), (2, 0, 0)),       # e = (b + h)
+            (1, 2): ((1, 0, 0), (3, 0, 0)),       # f = (b + j)
+            (1, 3): ((1, 0, 0), (2, 0, 1)),       # g = (b + m) — m right h
+            (2, 0): ((2, 0, 0),),                 # h
+            (2, 1): ((2, 0, 0), (3, 0, 0)),       # i = (h + j)
+            (2, 2): ((3, 0, 0),),                 # j
+            (2, 3): ((3, 0, 0), (2, 0, 1)),       # k = (j + m)
+            (3, 0): ((2, 0, 0), (0, 1, 0)),      # n = (h + M) — M below full
+            (3, 1): ((2, 0, 0), (1, 1, 0)),       # p = (h + s) — s below b
+            (3, 2): ((3, 0, 0), (1, 1, 0)),       # q = (j + s)
+            (3, 3): ((2, 0, 1), (1, 1, 0)),       # r = (m + s)
+        }
 
-    def qpred_s(ry, rx, fy, fx):
-        """SAD view of the quarter-fraction prediction (every srow-th
-        line) — rounded average of two static window slices."""
-        parts = QPEL[(fy, fx)]
-        p0, dy0, dx0 = parts[0]
-        a = wslice_s(p0, ry + dy0, rx + dx0).astype(jnp.int32)
-        if len(parts) == 1:
-            return a
-        p1, dy1, dx1 = parts[1]
-        b = wslice_s(p1, ry + dy1, rx + dx1).astype(jnp.int32)
-        return (a + b + 1) >> 1
+        def qpred_s(ry, rx, fy, fx):
+            """SAD view of the quarter-fraction prediction (every srow-th
+            line) — rounded average of two static window slices."""
+            parts = QPEL[(fy, fx)]
+            p0, dy0, dx0 = parts[0]
+            a = wslice_s(p0, ry + dy0, rx + dx0).astype(jnp.int32)
+            if len(parts) == 1:
+                return a
+            p1, dy1, dx1 = parts[1]
+            b = wslice_s(p1, ry + dy1, rx + dx1).astype(jnp.int32)
+            return (a + b + 1) >> 1
 
-    hdy = mv_h[..., 0] - 2 * mv_int[..., 0]                # (R, C) in
-    hdx = mv_h[..., 1] - 2 * mv_int[..., 1]                # {-1, 0, 1}
-    q_sads_l = []
-    for qy, qx in neighbors:
-        pk = jnp.zeros(cur_cmp.shape, jnp.int32)
-        for hy in (-1, 0, 1):
-            ey = 2 * hy + qy
-            for hx in (-1, 0, 1):
-                ex = 2 * hx + qx
-                m = ((hdy == hy) & (hdx == hx))[..., None, None]
-                pk = pk + jnp.where(
-                    m, qpred_s(ey >> 2, ex >> 2, ey & 3, ex & 3), 0)
-        q_sads_l.append(jnp.abs(cur_cmp - pk).sum(axis=(2, 3)))
-    q_sads = jnp.stack(q_sads_l)                           # (8, R, C)
-    best_q = jnp.argmin(q_sads, axis=0)
-    q_min = jnp.take_along_axis(q_sads, best_q[None], axis=0)[0]
-    if lam_v is None:
-        qb = QUARTER_BIAS // scale
-    else:
-        qb = (lam_v * (_RATE_QUARTER_BITS / scale)).astype(jnp.int32)
-    use_q = q_min + qb < sad_h
-    mv = mv_h * 2 + jnp.where(use_q[..., None],
-                              neighbors_j[best_q], 0)      # QUARTER units
+        hdy = mv_h[..., 0] - 2 * mv_int[..., 0]                # (R, C) in
+        hdx = mv_h[..., 1] - 2 * mv_int[..., 1]                # {-1, 0, 1}
+        q_sads_l = []
+        for qy, qx in neighbors:
+            pk = jnp.zeros(cur_cmp.shape, jnp.int32)
+            for hy in (-1, 0, 1):
+                ey = 2 * hy + qy
+                for hx in (-1, 0, 1):
+                    ex = 2 * hx + qx
+                    m = ((hdy == hy) & (hdx == hx))[..., None, None]
+                    pk = pk + jnp.where(
+                        m, qpred_s(ey >> 2, ex >> 2, ey & 3, ex & 3), 0)
+            q_sads_l.append(jnp.abs(cur_cmp - pk).sum(axis=(2, 3)))
+        q_sads = jnp.stack(q_sads_l)                           # (8, R, C)
+        best_q = jnp.argmin(q_sads, axis=0)
+        q_min = jnp.take_along_axis(q_sads, best_q[None], axis=0)[0]
+        if lam_v is None:
+            qb = QUARTER_BIAS // scale
+        else:
+            qb = (lam_v * (_RATE_QUARTER_BITS / scale)).astype(jnp.int32)
+        use_q = q_min + qb < sad_h
+        mv = mv_h * 2 + jnp.where(use_q[..., None],
+                                  neighbors_j[best_q], 0)      # QUARTER units
 
-    # --- final luma MC: ONE full-height prediction at the chosen MV ----
-    # The refinement stages above only ever build half-height SAD views;
-    # the sole full-height prediction is assembled here.  Per axis
-    # e = mv - 4*mv_int lies in [-3, 3]; rel = e>>2 (in {-1, 0}) and
-    # frac = e&3 reproduce exactly the (window offset, fraction) mapping
-    # the candidate evaluation used — so this is the same normative
-    # §8.4.2.2.1 sample the winning candidate scored, for every
-    # integer/half/quarter outcome.  Narrow the four 18-wide planes by
-    # rel (two masked passes per axis), then one-hot over the 16 quarter
-    # fractions.
-    e_y = (mv[..., 0] - 4 * mv_int[..., 0])
-    e_x = (mv[..., 1] - 4 * mv_int[..., 1])
-    rel_y = (e_y >> 2)[..., None, None]
-    rel_x = (e_x >> 2)[..., None, None]
-    frac_y = (e_y & 3)[..., None, None]
-    frac_x = (e_x & 3)[..., None, None]
-    nw = []
-    for t in w17:
-        t = jnp.where(rel_y == -1, t[:, :, 0:17, :], t[:, :, 1:18, :])
-        t = jnp.where(rel_x == -1, t[..., 0:17], t[..., 1:18])
-        nw.append(t)                                       # (R, C, 17, 17)
+    with jax.named_scope("dngd.mc"):
+        # --- final luma MC: ONE full-height prediction at the chosen MV ----
+        # The refinement stages above only ever build half-height SAD views;
+        # the sole full-height prediction is assembled here.  Per axis
+        # e = mv - 4*mv_int lies in [-3, 3]; rel = e>>2 (in {-1, 0}) and
+        # frac = e&3 reproduce exactly the (window offset, fraction) mapping
+        # the candidate evaluation used — so this is the same normative
+        # §8.4.2.2.1 sample the winning candidate scored, for every
+        # integer/half/quarter outcome.  Narrow the four 18-wide planes by
+        # rel (two masked passes per axis), then one-hot over the 16 quarter
+        # fractions.
+        e_y = (mv[..., 0] - 4 * mv_int[..., 0])
+        e_x = (mv[..., 1] - 4 * mv_int[..., 1])
+        rel_y = (e_y >> 2)[..., None, None]
+        rel_x = (e_x >> 2)[..., None, None]
+        frac_y = (e_y & 3)[..., None, None]
+        frac_x = (e_x & 3)[..., None, None]
+        nw = []
+        for t in w17:
+            t = jnp.where(rel_y == -1, t[:, :, 0:17, :], t[:, :, 1:18, :])
+            t = jnp.where(rel_x == -1, t[..., 0:17], t[..., 1:18])
+            nw.append(t)                                       # (R, C, 17, 17)
 
-    def qpred_full(fy, fx):
-        parts = QPEL[(fy, fx)]
-        p0, dy0, dx0 = parts[0]
-        a = nw[p0][:, :, dy0: dy0 + 16, dx0: dx0 + 16].astype(jnp.int32)
-        if len(parts) == 1:
-            return a
-        p1, dy1, dx1 = parts[1]
-        b = nw[p1][:, :, dy1: dy1 + 16, dx1: dx1 + 16].astype(jnp.int32)
-        return (a + b + 1) >> 1
+        def qpred_full(fy, fx):
+            parts = QPEL[(fy, fx)]
+            p0, dy0, dx0 = parts[0]
+            a = nw[p0][:, :, dy0: dy0 + 16, dx0: dx0 + 16].astype(jnp.int32)
+            if len(parts) == 1:
+                return a
+            p1, dy1, dx1 = parts[1]
+            b = nw[p1][:, :, dy1: dy1 + 16, dx1: dx1 + 16].astype(jnp.int32)
+            return (a + b + 1) >> 1
 
-    pred_y = jnp.zeros(cur_y.shape, jnp.int32)
-    for fy in range(4):
-        for fx in range(4):
-            m = (frac_y == fy) & (frac_x == fx)
-            pred_y = pred_y + jnp.where(m, qpred_full(fy, fx), 0)
+        pred_y = jnp.zeros(cur_y.shape, jnp.int32)
+        for fy in range(4):
+            for fx in range(4):
+                m = (frac_y == fy) & (frac_x == fx)
+                pred_y = pred_y + jnp.where(m, qpred_full(fy, fx), 0)
 
-    # --- chroma MC: 1/8-pel bilinear (spec §8.4.2.2.2) -----------------
-    # quarter-luma pels ARE eighth-chroma pels: use mv directly
-    c_off = mv >> 3                                        # in [-5, 4]
-    c_frac = mv & 7
+        # --- chroma MC: 1/8-pel bilinear (spec §8.4.2.2.2) -----------------
+        # quarter-luma pels ARE eighth-chroma pels: use mv directly
+        c_off = mv >> 3                                        # in [-5, 4]
+        c_frac = mv & 7
 
-    def mc_chroma(rp):
-        # 9-wide windows aligned at the chroma integer offset (mv is in
-        # half-luma = quarter-chroma pels, so int_off = mv*2 >> 3 spans
-        # [-5, 4]): span index int_off + 5 + i = plane row
-        # r*8 + _PAD + int_off + i with base_y = _PAD - 5.
-        t = _tiles(rp.astype(jnp.uint8), _PAD - 5, _PAD - 5, 8, 19, nr, nc)
-        wc = _mb_windows(t, c_off[..., 0], c_off[..., 1], 5, 9)
-        wc = wc.astype(jnp.int32)
-        A = wc[:, :, :8, :8]
-        B = wc[:, :, :8, 1:9]
-        C = wc[:, :, 1:9, :8]
-        D = wc[:, :, 1:9, 1:9]
-        yf = c_frac[..., 0][..., None, None]
-        xf = c_frac[..., 1][..., None, None]
-        return ((8 - xf) * (8 - yf) * A + xf * (8 - yf) * B
-                + (8 - xf) * yf * C + xf * yf * D + 32) >> 6
+        def mc_chroma(rp):
+            # 9-wide windows aligned at the chroma integer offset (mv is in
+            # half-luma = quarter-chroma pels, so int_off = mv*2 >> 3 spans
+            # [-5, 4]): span index int_off + 5 + i = plane row
+            # r*8 + _PAD + int_off + i with base_y = _PAD - 5.
+            t = _tiles(rp.astype(jnp.uint8), _PAD - 5, _PAD - 5, 8, 19, nr, nc)
+            wc = _mb_windows(t, c_off[..., 0], c_off[..., 1], 5, 9)
+            wc = wc.astype(jnp.int32)
+            A = wc[:, :, :8, :8]
+            B = wc[:, :, :8, 1:9]
+            C = wc[:, :, 1:9, :8]
+            D = wc[:, :, 1:9, 1:9]
+            yf = c_frac[..., 0][..., None, None]
+            xf = c_frac[..., 1][..., None, None]
+            return ((8 - xf) * (8 - yf) * A + xf * (8 - yf) * B
+                    + (8 - xf) * yf * C + xf * yf * D + 32) >> 6
 
-    pred_cb = mc_chroma(ref_cb_pad)                        # (R, C, 8, 8)
-    pred_cr = mc_chroma(ref_cr_pad)
+        pred_cb = mc_chroma(ref_cb_pad)                        # (R, C, 8, 8)
+        pred_cr = mc_chroma(ref_cr_pad)
 
-    cur_cb = cb.reshape(nr, 8, nc, 8).transpose(0, 2, 1, 3)
-    cur_cr = cr.reshape(nr, 8, nc, 8).transpose(0, 2, 1, 3)
+        cur_cb = cb.reshape(nr, 8, nc, 8).transpose(0, 2, 1, 3)
+        cur_cr = cr.reshape(nr, 8, nc, 8).transpose(0, 2, 1, 3)
 
     # --- luma residual: 16 x 4x4, no DC split --------------------------
-    res = _blocks(cur_y - pred_y, 4)                       # (R,C,4,4,4,4)
-    w = fdct4x4(res)
-    lv = quant.h264_quantize_4x4(w, qp_q, intra=False)
-    wd = quant.h264_dequantize_4x4(lv, qp_q)
-    recon_y_mb = jnp.clip(pred_y + _unblocks(idct4x4(wd)), 0, 255)
+    with jax.named_scope("dngd.mc"):
+        res = _blocks(cur_y - pred_y, 4)                   # (R,C,4,4,4,4)
+    with jax.named_scope("dngd.tq"):
+        w = fdct4x4(res)
+        lv = quant.h264_quantize_4x4(w, qp_q, intra=False)
+    with jax.named_scope("dngd.recon"):
+        wd = quant.h264_dequantize_4x4(lv, qp_q)
+        recon_y_mb = jnp.clip(pred_y + _unblocks(idct4x4(wd)), 0, 255)
 
-    zz = jnp.asarray(ZIGZAG4)
-    blk = jnp.asarray(LUMA_BLOCK_ORDER)
-    luma_zz = lv.reshape(nr, nc, 4, 4, 16)[..., zz]        # (R,C,by,bx,16)
-    luma_zz = luma_zz[:, :, blk[:, 1], blk[:, 0], :]       # blkIdx order
+    with jax.named_scope("dngd.tq"):
+        zz = jnp.asarray(ZIGZAG4)
+        blk = jnp.asarray(LUMA_BLOCK_ORDER)
+        luma_zz = lv.reshape(nr, nc, 4, 4, 16)[..., zz]    # (R,C,by,bx,16)
+        luma_zz = luma_zz[:, :, blk[:, 1], blk[:, 0], :]   # blkIdx order
 
     # --- chroma residual: 2x2 DC Hadamard + AC -------------------------
     def chroma(cur, pred, qpc):
-        res = _blocks(cur - pred, 2)                       # (R,C,2,2,4,4)
-        w = fdct4x4(res)
-        dc = w[..., 0, 0]                                  # (R,C,2,2)
-        ac = quant.h264_quantize_4x4(w, qpc, intra=False)
-        ac = ac.at[..., 0, 0].set(0)
-        dcl = quant.h264_quantize_chroma_dc(
-            hadamard2x2(dc), qpc, intra=False)
-        fd = hadamard2x2(dcl)
-        dcc = quant.h264_dequantize_chroma_dc(fd, qpc)
-        wr = quant.h264_dequantize_4x4(ac, qpc)
-        wr = wr.at[..., 0, 0].set(dcc)
-        recon = jnp.clip(pred + _unblocks(idct4x4(wr)), 0, 255)
-        ac_zz = ac.reshape(ac.shape[:2] + (4, 16))[..., zz[1:]]  # (R,C,4,15)
-        return ac_zz, dcl.reshape(dcl.shape[:2] + (4,)), recon
+        with jax.named_scope("dngd.mc"):
+            res = _blocks(cur - pred, 2)                   # (R,C,2,2,4,4)
+        with jax.named_scope("dngd.tq"):
+            w = fdct4x4(res)
+            dc = w[..., 0, 0]                              # (R,C,2,2)
+            ac = quant.h264_quantize_4x4(w, qpc, intra=False)
+            ac = ac.at[..., 0, 0].set(0)
+            dcl = quant.h264_quantize_chroma_dc(
+                hadamard2x2(dc), qpc, intra=False)
+        with jax.named_scope("dngd.recon"):
+            fd = hadamard2x2(dcl)
+            dcc = quant.h264_dequantize_chroma_dc(fd, qpc)
+            wr = quant.h264_dequantize_4x4(ac, qpc)
+            wr = wr.at[..., 0, 0].set(dcc)
+            recon = jnp.clip(pred + _unblocks(idct4x4(wr)), 0, 255)
+        with jax.named_scope("dngd.tq"):
+            ac_zz = ac.reshape(
+                ac.shape[:2] + (4, 16))[..., zz[1:]]       # (R,C,4,15)
+            return ac_zz, dcl.reshape(dcl.shape[:2] + (4,)), recon
 
     cb_ac, cb_dc, recon_cb_mb = chroma(cur_cb, pred_cb, qp_c)
     cr_ac, cr_dc, recon_cr_mb = chroma(cur_cr, pred_cr, qp_c)
 
-    if lam_d is not None:
-        # --- Lagrangian forced-skip (tune=hq) --------------------------
-        # A zero-MV MB whose coded residual buys less SSD than
-        # lambda * its bits is coded as P_Skip: levels zeroed, the
-        # reconstruction IS the prediction (what a decoder does for a
-        # skipped MB), so the stream stays conformant by construction.
-        from .h264_device import _level_bits_est
+    with jax.named_scope("dngd.mode_decision"):
+        if lam_d is not None:
+            # --- Lagrangian forced-skip (tune=hq) --------------------------
+            # A zero-MV MB whose coded residual buys less SSD than
+            # lambda * its bits is coded as P_Skip: levels zeroed, the
+            # reconstruction IS the prediction (what a decoder does for a
+            # skipped MB), so the stream stays conformant by construction.
+            from .h264_device import _level_bits_est
 
-        zero_mv = jnp.all(mv == 0, axis=-1)                # (R, C)
-        bits_mb = (_level_bits_est(lv, (2, 3, 4, 5))
-                   + _level_bits_est(cb_ac, (2, 3))
-                   + _level_bits_est(cb_dc, (2,))
-                   + _level_bits_est(cr_ac, (2, 3))
-                   + _level_bits_est(cr_dc, (2,))).astype(jnp.float32)
+            zero_mv = jnp.all(mv == 0, axis=-1)                # (R, C)
+            bits_mb = (_level_bits_est(lv, (2, 3, 4, 5))
+                       + _level_bits_est(cb_ac, (2, 3))
+                       + _level_bits_est(cb_dc, (2,))
+                       + _level_bits_est(cr_ac, (2, 3))
+                       + _level_bits_est(cr_dc, (2,))).astype(jnp.float32)
 
-        def mb_ssd(a, b):
-            d = a - b
-            return (d * d).sum(axis=(2, 3)).astype(jnp.float32)
+            def mb_ssd(a, b):
+                d = a - b
+                return (d * d).sum(axis=(2, 3)).astype(jnp.float32)
 
-        d_coded = (mb_ssd(recon_y_mb, cur_y)
-                   + mb_ssd(recon_cb_mb, cur_cb)
-                   + mb_ssd(recon_cr_mb, cur_cr))
-        d_skip = (mb_ssd(pred_y, cur_y) + mb_ssd(pred_cb, cur_cb)
-                  + mb_ssd(pred_cr, cur_cr))
-        force = zero_mv & (
-            d_skip <= d_coded + lam_d * (bits_mb + _RATE_SKIP_SIG_BITS))
-        f2 = force[:, :, None, None]
-        luma_zz = jnp.where(f2, 0, luma_zz)
-        cb_ac = jnp.where(f2, 0, cb_ac)
-        cr_ac = jnp.where(f2, 0, cr_ac)
-        cb_dc = jnp.where(force[:, :, None], 0, cb_dc)
-        cr_dc = jnp.where(force[:, :, None], 0, cr_dc)
-        recon_y_mb = jnp.where(f2, pred_y, recon_y_mb)
-        recon_cb_mb = jnp.where(f2, pred_cb, recon_cb_mb)
-        recon_cr_mb = jnp.where(f2, pred_cr, recon_cr_mb)
+            d_coded = (mb_ssd(recon_y_mb, cur_y)
+                       + mb_ssd(recon_cb_mb, cur_cb)
+                       + mb_ssd(recon_cr_mb, cur_cr))
+            d_skip = (mb_ssd(pred_y, cur_y) + mb_ssd(pred_cb, cur_cb)
+                      + mb_ssd(pred_cr, cur_cr))
+            force = zero_mv & (
+                d_skip <= d_coded + lam_d * (bits_mb + _RATE_SKIP_SIG_BITS))
+            f2 = force[:, :, None, None]
+            luma_zz = jnp.where(f2, 0, luma_zz)
+            cb_ac = jnp.where(f2, 0, cb_ac)
+            cr_ac = jnp.where(f2, 0, cr_ac)
+            cb_dc = jnp.where(force[:, :, None], 0, cb_dc)
+            cr_dc = jnp.where(force[:, :, None], 0, cr_dc)
+            recon_y_mb = jnp.where(f2, pred_y, recon_y_mb)
+            recon_cb_mb = jnp.where(f2, pred_cb, recon_cb_mb)
+            recon_cr_mb = jnp.where(f2, pred_cr, recon_cr_mb)
 
     is_intra = None
-    if p_intra:
-        # --- I_16x16-in-P Lagrangian mode decision (tune=hq) -----------
-        # The intra escape for content ME cannot track (occlusions,
-        # non-translational drift): code the MB I_16x16/DC where
-        # SSD + lambda * bits beats BOTH the coded-inter and skip
-        # candidates.  Intra prediction in a P slice reads the left
-        # neighbor's final reconstruction (constrained_intra_pred_flag
-        # is 0), so the decision is run-parity gated below: an intra
-        # MB's left neighbor always stays inter, which makes the DC
-        # predictor computed HERE (from the skip-merged inter recon)
-        # exactly the sample set a conformant decoder derives.
-        if lam_d is None:
-            raise ValueError("p_intra requires tune=hq/hq_noaq")
-        from .h264_device import _chroma_step, _i16_candidate
+    with jax.named_scope("dngd.mode_decision"):
+        if p_intra:
+            # --- I_16x16-in-P Lagrangian mode decision (tune=hq) -----------
+            # The intra escape for content ME cannot track (occlusions,
+            # non-translational drift): code the MB I_16x16/DC where
+            # SSD + lambda * bits beats BOTH the coded-inter and skip
+            # candidates.  Intra prediction in a P slice reads the left
+            # neighbor's final reconstruction (constrained_intra_pred_flag
+            # is 0), so the decision is run-parity gated below: an intra
+            # MB's left neighbor always stays inter, which makes the DC
+            # predictor computed HERE (from the skip-merged inter recon)
+            # exactly the sample set a conformant decoder derives.
+            if lam_d is None:
+                raise ValueError("p_intra requires tune=hq/hq_noaq")
+            from .h264_device import _chroma_step, _i16_candidate
 
-        n = nr * nc
-        lam_f = jnp.broadcast_to(
-            jnp.asarray(lam_d, jnp.float32), (nr, nc)).reshape(n)
-        has_left = (jnp.arange(nc, dtype=jnp.int32) > 0)[None, :]
-        has_left_f = jnp.broadcast_to(has_left, (nr, nc)).reshape(n)
+            n = nr * nc
+            lam_f = jnp.broadcast_to(
+                jnp.asarray(lam_d, jnp.float32), (nr, nc)).reshape(n)
+            has_left = (jnp.arange(nc, dtype=jnp.int32) > 0)[None, :]
+            has_left_f = jnp.broadcast_to(has_left, (nr, nc)).reshape(n)
 
-        # luma candidate: DC from the left MB's reconstructed right col
-        lcol_y = jnp.concatenate(
-            [jnp.zeros((nr, 1, 16), jnp.int32),
-             recon_y_mb[:, :-1, :, 15]], axis=1).reshape(n, 16)
-        ymb_f = cur_y.reshape(n, 16, 16)
-        psum = (jnp.sum(lcol_y, axis=-1) + 8) >> 4
-        pred_dc = jnp.where(has_left_f, psum, 128)[:, None, None]
-        pred_dc = jnp.broadcast_to(pred_dc, ymb_f.shape)
-        if qp_map is None:
-            qp_i = qp
-        else:
-            qp_i = qp_map.reshape(n)
-        ac_i, dc_i, rec_i, bits_y = _i16_candidate(ymb_f, pred_dc, qp_i)
+            # luma candidate: DC from the left MB's reconstructed right col
+            lcol_y = jnp.concatenate(
+                [jnp.zeros((nr, 1, 16), jnp.int32),
+                 recon_y_mb[:, :-1, :, 15]], axis=1).reshape(n, 16)
+            ymb_f = cur_y.reshape(n, 16, 16)
+            psum = (jnp.sum(lcol_y, axis=-1) + 8) >> 4
+            pred_dc = jnp.where(has_left_f, psum, 128)[:, None, None]
+            pred_dc = jnp.broadcast_to(pred_dc, ymb_f.shape)
+            if qp_map is None:
+                qp_i = qp
+            else:
+                qp_i = qp_map.reshape(n)
+            ac_i, dc_i, rec_i, bits_y = _i16_candidate(ymb_f, pred_dc, qp_i)
 
-        # chroma candidate: per-quadrant DC from the left chroma column
-        qc_i = qp_c if qp_map is None else qp_c.reshape(n)
-        lcol_cb = jnp.concatenate(
-            [jnp.zeros((nr, 1, 8), jnp.int32),
-             recon_cb_mb[:, :-1, :, 7]], axis=1).reshape(n, 8)
-        lcol_cr = jnp.concatenate(
-            [jnp.zeros((nr, 1, 8), jnp.int32),
-             recon_cr_mb[:, :-1, :, 7]], axis=1).reshape(n, 8)
-        hl3 = has_left_f[:, None, None]
-        cbi_ac, cbi_dc, cbi_rec = _chroma_step(
-            cur_cb.reshape(n, 8, 8), lcol_cb, hl3, qc_i)
-        cri_ac, cri_dc, cri_rec = _chroma_step(
-            cur_cr.reshape(n, 8, 8), lcol_cr, hl3, qc_i)
+            # chroma candidate: per-quadrant DC from the left chroma column
+            qc_i = qp_c if qp_map is None else qp_c.reshape(n)
+            lcol_cb = jnp.concatenate(
+                [jnp.zeros((nr, 1, 8), jnp.int32),
+                 recon_cb_mb[:, :-1, :, 7]], axis=1).reshape(n, 8)
+            lcol_cr = jnp.concatenate(
+                [jnp.zeros((nr, 1, 8), jnp.int32),
+                 recon_cr_mb[:, :-1, :, 7]], axis=1).reshape(n, 8)
+            hl3 = has_left_f[:, None, None]
+            cbi_ac, cbi_dc, cbi_rec = _chroma_step(
+                cur_cb.reshape(n, 8, 8), lcol_cb, hl3, qc_i)
+            cri_ac, cri_dc, cri_rec = _chroma_step(
+                cur_cr.reshape(n, 8, 8), lcol_cr, hl3, qc_i)
 
-        from .h264_device import _level_bits_est as _lbe
+            from .h264_device import _level_bits_est as _lbe
 
-        bits_i = (bits_y + _lbe(cbi_ac, (1, 2, 3, 4)) + _lbe(cbi_dc, (1, 2))
-                  + _lbe(cri_ac, (1, 2, 3, 4))
-                  + _lbe(cri_dc, (1, 2))).astype(jnp.float32)
+            bits_i = (bits_y + _lbe(cbi_ac, (1, 2, 3, 4))
+                      + _lbe(cbi_dc, (1, 2))
+                      + _lbe(cri_ac, (1, 2, 3, 4))
+                      + _lbe(cri_dc, (1, 2))).astype(jnp.float32)
 
-        def flat_ssd(a, b):
-            d = a.reshape(n, -1) - b.reshape(n, -1)
-            return (d * d).sum(axis=1).astype(jnp.float32)
+            def flat_ssd(a, b):
+                d = a.reshape(n, -1) - b.reshape(n, -1)
+                return (d * d).sum(axis=1).astype(jnp.float32)
 
-        d_intra = (flat_ssd(rec_i, ymb_f) + flat_ssd(cbi_rec, cur_cb)
-                   + flat_ssd(cri_rec, cur_cr))
-        score_intra = (d_intra
-                       + lam_f * (bits_i + _RATE_I16_HDR_BITS))
-        score_inter = jnp.where(
-            force, d_skip + lam_d * 1.0,
-            d_coded + lam_d * (bits_mb + _RATE_SKIP_SIG_BITS))
-        want = score_intra.reshape(nr, nc) < score_inter       # (R, C)
+            d_intra = (flat_ssd(rec_i, ymb_f) + flat_ssd(cbi_rec, cur_cb)
+                       + flat_ssd(cri_rec, cur_cr))
+            score_intra = (d_intra
+                           + lam_f * (bits_i + _RATE_I16_HDR_BITS))
+            score_inter = jnp.where(
+                force, d_skip + lam_d * 1.0,
+                d_coded + lam_d * (bits_mb + _RATE_SKIP_SIG_BITS))
+            want = score_intra.reshape(nr, nc) < score_inter       # (R, C)
 
-        # run-parity gate: within each consecutive run of intra-wanting
-        # MBs keep the even positions only, so no intra MB has an intra
-        # left neighbor (whose recon the DC predictor above did not use)
-        idx = jnp.arange(nc, dtype=jnp.int32)[None, :]
-        last_not = jax.lax.cummax(jnp.where(~want, idx, -1), axis=1)
-        is_intra = want & ((idx - last_not - 1) % 2 == 0)
+            # run-parity gate: within each consecutive run of intra-wanting
+            # MBs keep the even positions only, so no intra MB has an intra
+            # left neighbor (whose recon the DC predictor above did not use)
+            idx = jnp.arange(nc, dtype=jnp.int32)[None, :]
+            last_not = jax.lax.cummax(jnp.where(~want, idx, -1), axis=1)
+            is_intra = want & ((idx - last_not - 1) % 2 == 0)
 
-        fI = is_intra[:, :, None, None]
-        fI3 = is_intra[:, :, None]
-        luma_zz = jnp.where(fI, 0, luma_zz)
-        mv = jnp.where(fI3, 0, mv)
-        cb_ac = jnp.where(fI, cbi_ac.reshape(n, 4, 16)[..., zz[1:]]
-                          .reshape(nr, nc, 4, 15), cb_ac)
-        cr_ac = jnp.where(fI, cri_ac.reshape(n, 4, 16)[..., zz[1:]]
-                          .reshape(nr, nc, 4, 15), cr_ac)
-        cb_dc = jnp.where(fI3, cbi_dc.reshape(nr, nc, 4), cb_dc)
-        cr_dc = jnp.where(fI3, cri_dc.reshape(nr, nc, 4), cr_dc)
-        recon_y_mb = jnp.where(fI, rec_i.reshape(nr, nc, 16, 16),
-                               recon_y_mb)
-        recon_cb_mb = jnp.where(fI, cbi_rec.reshape(nr, nc, 8, 8),
-                                recon_cb_mb)
-        recon_cr_mb = jnp.where(fI, cri_rec.reshape(nr, nc, 8, 8),
-                                recon_cr_mb)
-        i16_dc_zz = dc_i.reshape(n, 16)[:, zz].reshape(nr, nc, 16)
-        i16_ac_zz = ac_i.reshape(n, 4, 4, 16)[..., zz[1:]]
-        i16_ac_zz = i16_ac_zz[:, blk[:, 1], blk[:, 0], :]      # blkIdx
-        i16_ac_zz = i16_ac_zz.reshape(nr, nc, 16, 15)
-        i16_dc_zz = jnp.where(fI3, i16_dc_zz, 0)
-        i16_ac_zz = jnp.where(fI, i16_ac_zz, 0)
+            fI = is_intra[:, :, None, None]
+            fI3 = is_intra[:, :, None]
+            luma_zz = jnp.where(fI, 0, luma_zz)
+            mv = jnp.where(fI3, 0, mv)
+            cb_ac = jnp.where(fI, cbi_ac.reshape(n, 4, 16)[..., zz[1:]]
+                              .reshape(nr, nc, 4, 15), cb_ac)
+            cr_ac = jnp.where(fI, cri_ac.reshape(n, 4, 16)[..., zz[1:]]
+                              .reshape(nr, nc, 4, 15), cr_ac)
+            cb_dc = jnp.where(fI3, cbi_dc.reshape(nr, nc, 4), cb_dc)
+            cr_dc = jnp.where(fI3, cri_dc.reshape(nr, nc, 4), cr_dc)
+            recon_y_mb = jnp.where(fI, rec_i.reshape(nr, nc, 16, 16),
+                                   recon_y_mb)
+            recon_cb_mb = jnp.where(fI, cbi_rec.reshape(nr, nc, 8, 8),
+                                    recon_cb_mb)
+            recon_cr_mb = jnp.where(fI, cri_rec.reshape(nr, nc, 8, 8),
+                                    recon_cr_mb)
+            i16_dc_zz = dc_i.reshape(n, 16)[:, zz].reshape(nr, nc, 16)
+            i16_ac_zz = ac_i.reshape(n, 4, 4, 16)[..., zz[1:]]
+            i16_ac_zz = i16_ac_zz[:, blk[:, 1], blk[:, 0], :]      # blkIdx
+            i16_ac_zz = i16_ac_zz.reshape(nr, nc, 16, 15)
+            i16_dc_zz = jnp.where(fI3, i16_dc_zz, 0)
+            i16_ac_zz = jnp.where(fI, i16_ac_zz, 0)
 
     def plane(mb, mbsz, ph, pw):
         return mb.transpose(0, 2, 1, 3).reshape(ph, pw)
 
     i16 = lambda a: a.astype(jnp.int16)
-    out = {
-        "mv": mv.astype(jnp.int8),
-        "luma": i16(luma_zz),
-        "cb_dc": i16(cb_dc), "cb_ac": i16(cb_ac),
-        "cr_dc": i16(cr_dc), "cr_ac": i16(cr_ac),
-        "recon_y": plane(recon_y_mb, 16, pad_h, pad_w).astype(jnp.uint8),
-        "recon_cb": plane(recon_cb_mb, 8, pad_h // 2, pad_w // 2).astype(jnp.uint8),
-        "recon_cr": plane(recon_cr_mb, 8, pad_h // 2, pad_w // 2).astype(jnp.uint8),
-    }
+    with jax.named_scope("dngd.tq"):
+        out = {
+            "mv": mv.astype(jnp.int8),
+            "luma": i16(luma_zz),
+            "cb_dc": i16(cb_dc), "cb_ac": i16(cb_ac),
+            "cr_dc": i16(cr_dc), "cr_ac": i16(cr_ac),
+        }
+    with jax.named_scope("dngd.recon"):
+        ph2, pw2 = pad_h // 2, pad_w // 2
+        out["recon_y"] = plane(recon_y_mb, 16, pad_h, pad_w).astype(jnp.uint8)
+        out["recon_cb"] = plane(recon_cb_mb, 8, ph2, pw2).astype(jnp.uint8)
+        out["recon_cr"] = plane(recon_cr_mb, 8, ph2, pw2).astype(jnp.uint8)
     if qp_map is not None:
         out["qp_map"] = qp_map        # (R, C) absolute per-MB qp (tune=hq)
     if is_intra is not None:

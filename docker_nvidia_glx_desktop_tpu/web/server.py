@@ -42,6 +42,7 @@ from aiohttp import WSMsgType, web
 
 from ..obs.http import OBS_EXEMPT_PATHS, add_obs_routes
 from ..obs.metrics import REGISTRY
+from ..obs.trace import M_WS_SEND_MS
 # Imported for the metric-registration side effect: the dngd_sctp_* /
 # dngd_datachannel_* families (and the sctp_drop_burst/dcep_open_stall
 # fault points) must exist on /metrics from server start — a dashboard
@@ -936,6 +937,9 @@ async def _pump_media(ws: web.WebSocketResponse, queue,
                         probes.add(item[3])
                     await ws.send_json({"type": "fprobe", "id": item[3]})
                 await ws.send_bytes(data)
+                if kind == "frag" and len(item) > 4 and item[4]:
+                    M_WS_SEND_MS.observe(
+                        (time.perf_counter() - item[4]) * 1e3)
     except Exception:
         pass
 

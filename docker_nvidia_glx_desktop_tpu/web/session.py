@@ -26,6 +26,7 @@ from ..obs import budget as obsb
 from ..obs import events as obsev
 from ..obs import journey as obsj
 from ..obs import metrics as obsm
+from ..obs import trace as obst
 from ..obs.trace import next_frame_id, tracer
 from ..resilience import continuity as rcont
 from ..resilience import faults as rfaults
@@ -55,10 +56,12 @@ def keyframe_requester(session):
 # -- telemetry (obs registry; see obs/__init__ for the naming scheme) ----
 _M_SUBMIT_MS = obsm.histogram(
     "dngd_encoder_submit_ms",
-    "Capture + host color conversion + async device dispatch per frame")
+    "Capture + host color conversion + async device dispatch per frame",
+    buckets=obst.STAGE_BUCKETS_MS)
 _M_COLLECT_MS = obsm.histogram(
     "dngd_encoder_collect_ms",
-    "Device wait + bitstream pull + AU assembly per frame")
+    "Device wait + bitstream pull + AU assembly per frame",
+    buckets=obst.STAGE_BUCKETS_MS)
 _M_FRAMES = obsm.counter(
     "dngd_encoder_frames_total", "Encoded frames delivered to fan-out")
 _M_BYTES = obsm.counter(
@@ -565,12 +568,14 @@ class StreamSession:
             self._au_listeners.remove(fn)
 
     def _publish(self, fragment: bytes, keyframe: bool,
-                 fid: int = 0) -> None:
+                 fid: int = 0, t_post: float = 0.0) -> None:
         # the 4th tuple element is the frame-journey id: the websocket
         # pump probes sampled fids and the client's ack closes the
-        # journey (obs/journey)
-        if self._subscribers.publish(("frag", fragment, keyframe, fid),
-                                     keyframe=keyframe):
+        # journey (obs/journey); the 5th is _post's stamp, which the
+        # pump closes into dngd_ws_publish_to_send_ms (0.0: no stamp)
+        if self._subscribers.publish(
+                ("frag", fragment, keyframe, fid, t_post),
+                keyframe=keyframe):
             # A permanently stalled client would otherwise evict its
             # keyframe every queue-depth frames and storm the encoder
             # with IDR requests (IDRs cost every OTHER client
@@ -779,7 +784,8 @@ class StreamSession:
             try:
                 if rfaults.fire("xserver_gone") is not None:
                     raise ConnectionError("fault injection: xserver_gone")
-                rgb, seq = self.source.frame()
+                with obst.stage("capture"):
+                    rgb, seq = self.source.frame()
             except Exception:
                 # X server (or capture backend) gone: retry with capped
                 # backoff — the supervisor is restarting it; a long
@@ -838,7 +844,8 @@ class StreamSession:
                         raise RuntimeError(
                             "fault injection: device_preempt "
                             "(device revoked)")
-                    token = self.encoder.encode_submit(rgb)
+                    with obst.stage("encode_submit"):
+                        token = self.encoder.encode_submit(rgb)
                 except Exception:
                     # One failed submit drops one frame (nothing is in
                     # flight for it); a consecutive run — a device that
@@ -907,7 +914,8 @@ class StreamSession:
                         else:
                             raise TimeoutError(
                                 "fault injection: collect_timeout")
-                    ef = self.encoder.encode_collect(token)
+                    with obst.stage("encode_collect"):
+                        ef = self.encoder.encode_collect(token)
                 except Exception:
                     # Transient device/transfer failure: drop this frame,
                     # keep the session alive (supervisord-style resilience).
@@ -939,9 +947,12 @@ class StreamSession:
                         fn(ef.data, ef.keyframe, frame_pts)
                     except Exception:
                         log.exception("AU listener failed")
-                frag = (self.muxer.fragment(ef.data, keyframe=ef.keyframe,
-                                            pts_ms=frame_pts // 90)
-                        if self.muxer is not None else ef.data)
+                # closes the stage the encoder's Annex-B assembly opened
+                with obst.stage("assemble"):
+                    frag = (self.muxer.fragment(ef.data,
+                                                keyframe=ef.keyframe,
+                                                pts_ms=frame_pts // 90)
+                            if self.muxer is not None else ef.data)
                 marks.append(("bitstream", time.perf_counter()))
                 self.stats.record_frame(ef.encode_ms, len(frag))
                 _M_FRAMES.inc()
@@ -1013,11 +1024,12 @@ class StreamSession:
 
     def _post(self, fragment: bytes, keyframe: bool,
               fid: int = 0) -> None:
+        t_post = time.perf_counter() if obst.enabled() else 0.0
         if self.loop is not None:
             self.loop.call_soon_threadsafe(self._publish, fragment,
-                                           keyframe, fid)
+                                           keyframe, fid, t_post)
         else:
-            self._publish(fragment, keyframe, fid)
+            self._publish(fragment, keyframe, fid, t_post)
 
     def stats_summary(self) -> dict:
         s = self.stats.summary()

@@ -164,6 +164,123 @@ class TestTrace:
         assert len(rec.chrome_events()) == 8
 
 
+def _stage_counts(name):
+    """(_sum, _count) of ``dngd_stage_<name>_ms`` as /metrics renders
+    them, read the way benchmark/run.py:parse_metrics reads a family."""
+    out = {}
+    for line in obsm.REGISTRY.render().splitlines():
+        for suffix in ("_sum", "_count"):
+            if line.startswith(f"dngd_stage_{name}_ms{suffix} "):
+                out[suffix] = float(line.rpartition(" ")[2])
+    return out["_sum"], out["_count"]
+
+
+class TestStageSpans:
+    """obs/trace.stage: a profiler span and one histogram sample, and
+    nothing in any recorder."""
+
+    @pytest.mark.parametrize("name", obst.STAGES)
+    def test_stage_observes_into_its_own_unlabelled_family(self, name):
+        obst._carry.__dict__.clear()        # no split stage left open
+        s0, n0 = _stage_counts(name)        # registered at import: renders
+        with obst.stage(name) as span:
+            pass
+        s1, n1 = _stage_counts(name)
+        assert n1 == n0 + 1
+        assert span.ms > 0 and s1 - s0 == pytest.approx(span.ms)
+        fam = obsm.REGISTRY.get(f"dngd_stage_{name}_ms")
+        assert fam.kind == "histogram" and fam.labelnames == ()
+        assert fam.edges == obst.STAGE_BUCKETS_MS
+        assert fam.edges[0] == 0.25 and 50.0 in fam.edges
+
+    def test_a_new_name_gets_its_family_on_first_use(self):
+        with obst.stage("unit_test_only"):
+            pass
+        assert _stage_counts("unit_test_only")[1] == 1
+
+    def test_disabled_is_an_early_return(self):
+        _, n0 = _stage_counts("pull")
+        obst.set_enabled(False)
+        try:
+            with obst.stage("pull") as span:
+                pass
+            with obst.stage("assemble", more=True):
+                pass
+        finally:
+            obst.set_enabled(True)
+        assert span.ms == 0.0 and span._ann is None
+        assert _stage_counts("pull")[1] == n0
+        assert "dngd.assemble" not in obst._carry.__dict__
+
+    def test_a_split_stage_takes_one_sample_for_both_parts(self):
+        s0, n0 = _stage_counts("assemble")
+        with obst.stage("assemble", more=True) as first:
+            pass
+        assert _stage_counts("assemble") == (s0, n0)    # handed on
+        with obst.stage("assemble") as last:
+            pass
+        s1, n1 = _stage_counts("assemble")
+        assert n1 == n0 + 1
+        assert s1 - s0 == pytest.approx(first.ms + last.ms)
+        # the first part of a frame that never closes is dropped by the
+        # next frame's, not added to it
+        with obst.stage("assemble", more=True):
+            pass
+        with obst.stage("assemble", more=True) as first:
+            pass
+        with obst.stage("assemble") as last:
+            pass
+        assert _stage_counts("assemble")[0] - s1 == pytest.approx(
+            first.ms + last.ms)
+
+    def test_an_exception_closes_the_span_and_passes(self):
+        _, n0 = _stage_counts("dispatch")
+        with pytest.raises(RuntimeError):
+            with obst.stage("dispatch"):
+                raise RuntimeError("device gone")
+        assert _stage_counts("dispatch")[1] == n0 + 1
+
+    def test_the_span_is_a_profiler_annotation(self, monkeypatch):
+        seen = []
+
+        class Ann:
+            def __init__(self, name):
+                seen.append(("init", name))
+
+            def __enter__(self):
+                seen.append("enter")
+
+            def __exit__(self, *exc):
+                seen.append("exit")
+
+        monkeypatch.setattr(obst, "_annotation", Ann)
+        with obst.stage("colour"):
+            seen.append("body")
+        assert seen == [("init", "dngd.colour"), "enter", "body", "exit"]
+        monkeypatch.setattr(obst, "_annotation", None)
+        import jax
+        assert obst._load_annotation() is jax.profiler.TraceAnnotation
+
+    def test_10000_frames_of_stages_touch_no_recorder(self):
+        """The per-frame marks, their ring (4,096: an overwrite counts
+        as a drop) and their listeners are as they were: a stage span
+        records nothing there."""
+        rec = obst.tracer("pipeline")
+        entries = []
+        listener = lambda kind, entry: entries.append(kind)   # noqa: E731
+        rec.add_listener(listener)
+        try:
+            before, dropped = len(rec), obst.dropped_total()
+            for _ in range(10_000):
+                for name in obst.STAGES:
+                    with obst.stage(name):
+                        pass
+            assert len(rec) == before and entries == []
+            assert obst.dropped_total() == dropped
+        finally:
+            rec.remove_listener(listener)
+
+
 class DummySource:
     width, height = 64, 48
 

@@ -1,0 +1,339 @@
+"""Where the stage spans sit on the served per-frame path (obs/trace.stage
+in web/session.py and models/h264.py), the set-up counters beside the
+compile-cache listener, and the ``dngd.`` scopes in the device programs."""
+
+import re
+import threading
+
+import numpy as np
+import pytest
+
+from docker_nvidia_glx_desktop_tpu.obs import metrics as obsm
+from docker_nvidia_glx_desktop_tpu.obs import procstats
+from docker_nvidia_glx_desktop_tpu.obs import trace as obst
+from docker_nvidia_glx_desktop_tpu.utils.config import from_env
+
+W, H = 128, 96
+ENCODER_STAGES = ("colour", "dispatch", "pull", "pull_extra")
+MARKS = ("capture", "captured", "device-submit", "device-collect",
+         "bitstream", "publish")
+
+
+def counts() -> dict:
+    """Samples so far in every stage family and the extra-pull counter."""
+    out = {name: obsm.REGISTRY.get(f"dngd_stage_{name}_ms")._default.count
+           for name in obst.STAGES}
+    out["ws_send"] = obst.M_WS_SEND_MS._default.count
+    out["pull_extra_total"] = obsm.REGISTRY.get(
+        "dngd_encoder_pull_extra_total").value
+    return out
+
+
+def delta(before: dict) -> dict:
+    return {k: v - before[k] for k, v in counts().items()}
+
+
+def frame(c: int) -> np.ndarray:
+    """A soft texture panned by (c, 2c): motion search finds it, and an
+    intra frame of it stays under the device entropy coder's cap."""
+    yy, xx = np.mgrid[c:c + H, 2 * c:2 * c + W]
+    v = 128 + 60 * np.sin(xx / 9.0) * np.cos(yy / 7.0) + 30 * np.sin(
+        (xx + yy) / 3.0)
+    return np.stack([v, v * 0.8 + 20, 255 - v], axis=-1).astype(np.uint8)
+
+
+@pytest.fixture(scope="module")
+def encoder():
+    """The served encoder (device CAVLC, tune=off, qp traced) at 128x96,
+    one IDR already behind it."""
+    from docker_nvidia_glx_desktop_tpu.models import make_encoder
+
+    cfg = from_env({"PASSWD": "pw", "SIZEW": str(W), "SIZEH": str(H),
+                    "REFRESH": "30", "ENCODER_PREWARM": "false"})
+    enc, _ = make_encoder(cfg, W, H)
+    assert enc._dyn_qp and enc.entropy == "device"
+    enc.encode_collect(enc.encode_submit(frame(0)))
+    return enc
+
+
+@pytest.mark.parametrize("kind", ["idr", "p"])
+@pytest.mark.parametrize("short_guess", [False, True])
+def test_each_frame_is_one_sample_of_every_encoder_stage(encoder, kind,
+                                                         short_guess):
+    """colour, dispatch and pull once a frame; pull_extra (span and
+    counter) only when the guessed prefix was short."""
+    enc = encoder
+    if kind == "idr":
+        enc._force_idr = True
+    guess = "_pull_guess" if kind == "idr" else "_p_pull_guess"
+    if short_guess:
+        # the next frame's prefix holds the header and 16 bytes
+        setattr(enc, guess, 16)
+    before = counts()
+    ef = enc.encode_collect(enc.encode_submit(frame(3)))
+    got = delta(before)
+    assert ef.keyframe == (kind == "idr") and len(ef.data) > 16
+    extra = 1 if short_guess else 0
+    assert {k: got[k] for k in ENCODER_STAGES} == {
+        "colour": 1, "dispatch": 1, "pull": 1, "pull_extra": extra}
+    assert got["pull_extra_total"] == extra
+    # the encoder's assembly is the first part of a split stage: the
+    # session's muxer closes it (below), so no sample yet
+    assert got["assemble"] == 0
+    assert getattr(enc, guess) >= enc._PULL_BUCKET  # the guess recovered
+
+
+def test_dispatch_accounting_rides_the_dispatch_span(encoder):
+    """One crossing a frame, its gap the span's own milliseconds; still
+    one crossing, and no gap, with tracing off."""
+    enc = encoder
+    enc.pop_dispatch_sample()
+    hist = obsm.REGISTRY.get("dngd_stage_dispatch_ms")._default
+    s0 = hist.sum
+    enc.encode_collect(enc.encode_submit(frame(4)))
+    n, gap = enc.pop_dispatch_sample()
+    assert n == 1 and gap == pytest.approx(hist.sum - s0)
+    obst.set_enabled(False)
+    try:
+        enc.encode_collect(enc.encode_submit(frame(5)))
+    finally:
+        obst.set_enabled(True)
+    assert enc.pop_dispatch_sample() == (1, 0.0)
+
+
+@pytest.fixture(scope="module")
+def served():
+    """Frames served by a StreamSession at 128x96 (GOP 4: IDRs and P
+    frames), with what the stage families and the pipeline recorder saw."""
+    from docker_nvidia_glx_desktop_tpu.rfb.source import SyntheticSource
+    from docker_nvidia_glx_desktop_tpu.web.session import StreamSession
+
+    cfg = from_env({"PASSWD": "pw", "SIZEW": str(W), "SIZEH": str(H),
+                    "REFRESH": "30", "ENCODER_GOP": "4",
+                    "ENCODER_PREWARM": "false"})
+    sess = StreamSession(cfg, SyntheticSource(W, H, fps=30))
+    marks, posted, done = [], [], threading.Event()
+    listener = lambda kind, entry: marks.append((kind, entry))  # noqa: E731
+    obst.tracer("pipeline").add_listener(listener)
+
+    def post(frag, keyframe, fid=0):
+        posted.append(keyframe)
+        if len(posted) >= 9:
+            done.set()
+
+    sess._post = post
+    before, dropped = counts(), obst.dropped_total()
+    sess.start()
+    try:
+        assert done.wait(300), posted
+    finally:
+        sess.stop()
+        obst.tracer("pipeline").remove_listener(listener)
+    return {"stages": delta(before), "posted": list(posted),
+            "marks": marks, "dropped": obst.dropped_total() - dropped}
+
+
+@pytest.mark.parametrize("name", [n for n in obst.STAGES
+                                  if n != "pull_extra"])
+def test_a_served_frame_is_one_sample_of_every_stage(served, name):
+    frames = len(served["posted"])
+    assert True in served["posted"][1:] and False in served["posted"]
+    got = served["stages"][name]
+    # the loop stops with up to PIPELINE_DEPTH frames submitted and not
+    # yet collected, and polls the source once more
+    assert frames <= got <= frames + 3, (name, got, frames)
+    assert served["stages"]["pull_extra"] == 0
+    assert served["stages"]["pull_extra_total"] == 0
+
+
+def test_a_served_frames_marks_are_the_six_they_were(served):
+    kinds = {k for k, _ in served["marks"]}
+    assert kinds == {"marks"}
+    for _, (fid, marks, pts, meta) in served["marks"]:
+        assert tuple(stage for stage, _ in marks) == MARKS
+    assert len(served["marks"]) == len(served["posted"])
+    assert served["dropped"] == 0
+
+
+def test_ws_send_closes_at_the_pump_after_the_write():
+    """StreamSession._post stamps, the media pump observes once
+    ``send_bytes`` has returned; an unstamped fragment (0.0) is skipped."""
+    import asyncio
+    import time
+
+    from docker_nvidia_glx_desktop_tpu.web import server
+
+    sent = []
+
+    class Ws:
+        async def send_bytes(self, data):
+            await asyncio.sleep(0.002)
+            sent.append(data)
+
+        async def send_json(self, obj):
+            sent.append(obj)
+
+    async def go():
+        q = asyncio.Queue()
+        q.put_nowait(("frag", b"a", True, 0, time.perf_counter()))
+        q.put_nowait(("frag", b"b", False, 0, 0.0))
+        q.put_nowait(("frag", b"c", False))
+        task = asyncio.ensure_future(server._pump_media(Ws(), q))
+        while len(sent) < 3:
+            await asyncio.sleep(0.001)
+        task.cancel()
+
+    hist = obst.M_WS_SEND_MS._default
+    n0, s0 = hist.count, hist.sum
+    asyncio.new_event_loop().run_until_complete(asyncio.wait_for(go(), 30))
+    assert sent == [b"a", b"b", b"c"]
+    assert hist.count == n0 + 1 and hist.sum - s0 >= 2.0
+
+
+def test_post_stamps_only_while_tracing_is_on():
+    from docker_nvidia_glx_desktop_tpu.rfb.source import SyntheticSource
+    from docker_nvidia_glx_desktop_tpu.web.session import StreamSession
+
+    cfg = from_env({"PASSWD": "pw", "SIZEW": "64", "SIZEH": "48",
+                    "WEBRTC_ENCODER": "x264enc", "ENCODER_PREWARM": "false"})
+    sess = StreamSession(cfg, SyntheticSource(64, 48))
+    q = sess.subscribe()
+    while not q.empty():
+        q.get_nowait()
+    sess._post(b"x", True, 7)
+    obst.set_enabled(False)
+    try:
+        sess._post(b"y", False, 8)
+    finally:
+        obst.set_enabled(True)
+    on, off = q.get_nowait(), q.get_nowait()
+    assert on[:4] == ("frag", b"x", True, 7) and on[4] > 0.0
+    assert off == ("frag", b"y", False, 8, 0.0)
+
+
+# -- set-up counters ----------------------------------------------------------
+
+SETUP_COUNTERS = ("dngd_jax_trace_lower_seconds_total",
+                  "dngd_jax_backend_compile_seconds_total",
+                  "dngd_jax_cache_load_seconds_total")
+
+
+def test_setup_counters_split_build_from_load():
+    """The listeners beside the cache-hit listener: tracing and lowering,
+    the backend's compile, and the cache's retrieval.  Compile-phase spans
+    nest, and each counter takes a span's own time; the retrieval, which
+    the backend-compile span brackets, is taken off that span."""
+    from jax import monitoring
+
+    assert procstats.register_jax_cache_listener()
+    fam = {n: obsm.REGISTRY.get(n) for n in SETUP_COUNTERS}
+    assert all(f.kind == "counter" and f.labelnames == ()
+               for f in fam.values())
+    v0 = {n: f.value for n, f in fam.items()}
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    lower = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    backend = "/jax/core/compile/backend_compile_duration"
+    span = monitoring.record_event_time_span
+    import time
+    t = time.time() + 1000.0        # after every span this thread has seen
+    # an outer trace of 10 s that holds an inner trace of 2 s and an eager
+    # operation's whole compile (0.5 + 0.25 + 1 s); then its own lowering,
+    # and a backend compile that the cache serves in 2 of its 2.125 s
+    span(trace, t + 1.0, t + 3.0)
+    span(trace, t + 4.0, t + 4.5)
+    span(lower, t + 4.5, t + 4.75)
+    span(backend, t + 4.75, t + 5.75)
+    span(trace, t, t + 10.0)
+    span(lower, t + 10.0, t + 10.5)
+    monitoring.record_event_duration_secs(
+        "/jax/compilation_cache/cache_retrieval_time_sec", 2.0)
+    span(backend, t + 10.5, t + 12.625)
+    span("/jax/some/other_duration", t, t + 100.0)
+    monitoring.record_event_duration_secs(backend, 50.0)   # spans count
+    got = {n: f.value - v0[n] for n, f in fam.items()}
+    assert got == pytest.approx({
+        "dngd_jax_trace_lower_seconds_total": 10.0 - 1.0 + 0.5,
+        "dngd_jax_backend_compile_seconds_total": 1.0 + 0.125,
+        "dngd_jax_cache_load_seconds_total": 2.0})
+    assert sum(got.values()) == pytest.approx(12.625)      # wall clock
+
+
+# -- named scopes in the device programs --------------------------------------
+
+SCOPES = {
+    "p": ("dngd.ingest", "dngd.me_int", "dngd.me_subpel", "dngd.mc",
+          "dngd.tq", "dngd.recon", "dngd.slots", "dngd.pack",
+          "dngd.deblock_bs"),
+    "intra": ("dngd.intra", "dngd.slots", "dngd.pack"),
+    "deblock": ("dngd.deblock_bs", "dngd.deblock_tile",
+                "dngd.deblock_edges", "dngd.deblock_v", "dngd.deblock_h"),
+    "colour": ("dngd.colour",),
+    "frame_stats": ("dngd.frame_stats",),
+}
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """The served programs (the ``_dynqp`` twins: the bodies are shared):
+    the lowered text with debug info, and the operations' names in the
+    compiled program.  At a geometry no other test compiles: within a
+    process JAX hands a program it has compiled before the executable it
+    had then, and its persistent cache leaves metadata out of its key, so
+    an entry written by a tree without the scopes would be served here
+    without them.  For these compiles the key holds the metadata too."""
+    import jax
+
+    from docker_nvidia_glx_desktop_tpu.models.h264 import _yuv_stage
+    from docker_nvidia_glx_desktop_tpu.ops import (
+        cavlc_device, cavlc_p_device, content_stats, h264_deblock)
+
+    w, h = 176, 112
+    nr, nc = h // 16, w // 16
+    y = np.zeros((h, w), np.uint8)
+    c = np.zeros((h // 2, w // 2), np.uint8)
+    qp = np.int32(30)
+    hv, hl = cavlc_device.slice_header_slots(nr, nc, frame_num=0)
+    pv, pl = cavlc_device.slice_header_slots(
+        nr, nc, frame_num=1, slice_type=5, idr=False)
+    mv = np.zeros((nr, nc, 2), np.int8)
+    progs = {
+        "p": cavlc_p_device.encode_p_cavlc_frame_dynqp.lower(
+            y, c, c, y, c, c, pv, pl, qp, "off", None, False),
+        "intra": cavlc_device.encode_intra_cavlc_frame_yuv_dynqp.lower(
+            y, c, c, hv, hl, qp, with_recon=True, i16_modes="auto",
+            tune="off"),
+        "deblock": h264_deblock.deblock_frame_dynqp.lower(
+            y, c, c, qp, nnz_blk=np.zeros((nr, nc, 4, 4), bool), mv=mv),
+        "colour": _yuv_stage.lower(np.zeros((h, w, 3), np.uint8), h, w),
+        "frame_stats": content_stats.frame_stats.lower(
+            y, y, y, mv, (), None, 512),
+    }
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        return {k: (low.as_text(debug_info=True),
+                    re.findall(r'op_name="([^"]*)"',
+                               low.compile().as_text()))
+                for k, low in progs.items()}
+    finally:
+        jax.config.update(flag, was)
+
+
+@pytest.mark.parametrize("program,scope", [
+    (p, s) for p, scopes in SCOPES.items() for s in scopes])
+def test_lowered_program_carries_the_scope(lowered, program, scope):
+    # (a loop body's names start anew at the scope inside it)
+    assert re.search(rf'["/]{re.escape(scope)}/', lowered[program][0])
+
+
+@pytest.mark.parametrize("program", sorted(SCOPES))
+def test_every_operation_lies_inside_a_scope(lowered, program):
+    """In the compiled program every operation's name stack passes
+    through some ``dngd.`` scope."""
+    names = lowered[program][1]
+    assert len(names) > 10
+    # (a name with no stack at all is a parameter or the body of a
+    # reduction, which is no operation of its own)
+    bare = sorted({n for n in names if "dngd." not in n and "/" in n})
+    assert not bare, bare[:10]
