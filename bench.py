@@ -516,11 +516,10 @@ def main() -> None:
                 fourk["meets_4k30"] = rp4["step_ms"] <= 33.3
             except Exception as e:
                 fourk["p_error"] = f"{type(e).__name__}: {e}"[:200]
-            # --- round-6 per-stage profile: the two tentpole levers
-            # measured OLD vs NEW on this backend (alternate-line subpel
-            # SAD vs the round-5 full-line re-rank; wavefront deblock vs
-            # the per-column scan), plus the ME/deblock/entropy split
-            # wired into the serving-budget ledger as first-class
+            # --- round-6 per-stage profile: the sub-pel lever measured
+            # OLD vs NEW on this backend (alternate-line subpel SAD vs
+            # the round-5 full-line re-rank), plus the ME/deblock/entropy
+            # split wired into the serving-budget ledger as first-class
             # device spans (/debug/budget attribution).
             try:
                 prof = {}
@@ -537,24 +536,11 @@ def main() -> None:
                 db_new = devloop.measure_steady_state(
                     lambda k: np.asarray(devloop.deblock_loop(
                         *d, jnp.int32(k), qp)), budget_s=pb)
-                db_old = devloop.measure_steady_state(
-                    lambda k: np.asarray(devloop.deblock_loop(
-                        *d, jnp.int32(k), qp, group=1)), budget_s=pb)
-                # forced wavefront: reported on every backend so the
-                # grouped-vs-column comparison exists even where auto
-                # picks the column scan (CPU)
-                db_wf = devloop.measure_steady_state(
-                    lambda k: np.asarray(devloop.deblock_loop(
-                        *d, jnp.int32(k), qp, group=8)), budget_s=pb)
                 prof["me_step_ms"] = me_new["step_ms"]
                 prof["me_step_ms_r5_fullline"] = me_old["step_ms"]
                 prof["me_improvement_pct"] = round(
                     (1 - me_new["step_ms"] / me_old["step_ms"]) * 100, 1)
                 prof["deblock_step_ms"] = db_new["step_ms"]
-                prof["deblock_step_ms_r5_column"] = db_old["step_ms"]
-                prof["deblock_step_ms_wavefront_g8"] = db_wf["step_ms"]
-                prof["deblock_improvement_pct"] = round(
-                    (1 - db_new["step_ms"] / db_old["step_ms"]) * 100, 1)
                 if "p_step_ms" in fourk:
                     entropy = max(
                         fourk["p_step_ms"] - prof["me_step_ms"]

@@ -136,19 +136,16 @@ def inter_loop(y, cb, cr, ref_y, ref_cb, ref_cr, steps, qp: int,
     return out[0]
 
 
-@functools.partial(jax.jit, static_argnames=("qp", "group"))
-def deblock_loop(y, cb, cr, steps, qp: int, group: int = 0):
+@functools.partial(jax.jit, static_argnames=("qp",))
+def deblock_loop(y, cb, cr, steps, qp: int):
     """``steps`` loop-filter applications chained through their output
-    (intra bS pattern) — isolates the deblock stage so the round-6
-    wavefront grouping (group=0 auto) can be profiled against the
-    round-5 per-column scan (group=1)."""
+    (intra bS pattern) — isolates the deblock stage."""
     from . import h264_deblock
 
     def body(i, carry):
         acc, fy, fcb, fcr = carry
         fy, fcb, fcr = h264_deblock.deblock_frame(
-            _perturb(fy, i), _perturb(fcb, i), _perturb(fcr, i), qp,
-            _group=group)
+            _perturb(fy, i), _perturb(fcb, i), _perturb(fcr, i), qp)
         return acc + fy[0, 0].astype(jnp.uint32), fy, fcb, fcr
 
     out = lax.fori_loop(0, steps, body, (jnp.uint32(0), y, cb, cr))

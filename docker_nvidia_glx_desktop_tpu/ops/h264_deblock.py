@@ -10,9 +10,17 @@ streaming QPs).  This module implements it TPU-first:
   (x=0,4,8,12 of each MB) and the INTERNAL horizontal edges (y=4,8,12)
   are filtered.  Every MB row is independent; the only sequencing is the
   spec's left-to-right MB order inside a row (MB n's x=0 edge reads and
-  REWRITES the last columns of MB n-1 after n-1 finished), which maps to
-  the same 120-step `lax.scan` the intra encoder uses, vectorized over
-  all rows.
+  REWRITES the last columns of MB n-1 after n-1 finished): a chain of
+  ``W/16`` MB columns, each filtered for all MB rows at once.
+- **One arithmetic, two schedules, picked from the backend the code
+  sees**: on a TPU the whole chain is ONE Pallas kernel
+  (``dngd_deblock_edges``) with the MB rows on the lanes, so that an edge
+  is elementwise work on eight (16, MB rows) tiles and no edge is an
+  operation of its own; elsewhere it is a ``lax.scan`` over MB columns.
+  At 1920x1088 on a v5e chip the program went from 5.0 ms a frame (a
+  scan of 1,560 small edge filters on lane-sparse tiles) to 0.15 ms:
+  0.065 the kernel, 0.06 XLA's turning of the planes round it, 0.02 the
+  bS (PERF.md section 6, PR 26).
 - **Filter tables** (Table 8-16/8-17 alpha/beta/tc0 — ~160 bytes of
   constants not derivable from formulas) are recovered STRUCTURALLY from
   the system libx264 .rodata, the same oracle pattern as the VP8
@@ -24,7 +32,7 @@ streaming QPs).  This module implements it TPU-first:
   compound through every P frame.
 
 The numpy reference (`deblock_frame_ref`) implements the spec order
-literally; the device scan is byte-identity-tested against it.
+literally; both device schedules are byte-identity-tested against it.
 """
 
 from __future__ import annotations
@@ -114,136 +122,284 @@ def _clip3(lo, hi, x):
 
 
 # ---------------------------------------------------------------------------
-# Device implementation: one lax.scan over MB columns (the spec's
-# left-to-right order inside each row; all MB rows vectorized), edges
-# filtered as fully-vectorized line bundles.
+# Device implementation.  The spec's left-to-right order inside an MB row
+# is a true dependency (MB n's x=0 edge rewrites MB n-1's last columns
+# after n-1 finished), so the frame is filtered MB column by MB column,
+# all MB rows at once.  One edge arithmetic (`_filter_lines`), two
+# schedules: on the TPU one Pallas kernel runs the whole chain with the MB
+# rows on the lanes; elsewhere a `lax.scan` over MB columns does.
 # ---------------------------------------------------------------------------
 
-def _filter_lines(p, q, bs, alpha, beta, tc0_row, chroma: bool):
+def _filter_lines(p, q, bs, alpha, beta, tc0, chroma: bool):
     """Vectorized spec 8.7.2.3/8.7.2.4 over line bundles.
 
-    p, q: (..., 4) int32 with index 0 nearest the edge; bs: (...,) int32.
-    alpha/beta ints, tc0_row (3,).  Returns (p_new, q_new) with only
-    indices 0..2 possibly changed."""
+    p, q: four int32 arrays each, index 0 nearest the edge; bs: int32 of
+    the same shape.  alpha/beta and the three tc0 entries are ints or
+    int32 scalars.  Returns the samples that can change, nearest first:
+    ((p0, p1, p2), (q0, q1, q2)) for luma, ((p0,), (q0,)) for chroma."""
     import jax.numpy as jnp
 
-    p0, p1, p2, p3 = (p[..., i] for i in range(4))
-    q0, q1, q2, q3 = (q[..., i] for i in range(4))
+    p0, p1, p2, p3 = p
+    q0, q1, q2, q3 = q
     fil = ((jnp.abs(p0 - q0) < alpha) & (jnp.abs(p1 - p0) < beta)
            & (jnp.abs(q1 - q0) < beta) & (bs > 0))
+    bs4 = bs == 4
+    sel = lambda strong, normal, old: jnp.where(
+        fil, jnp.where(bs4, strong, normal), old)
+
+    t0 = jnp.where(bs <= 1, tc0[0], jnp.where(bs == 2, tc0[1], tc0[2]))
+    s_p0w = (2 * p1 + p0 + q1 + 2) >> 2
+    s_q0w = (2 * q1 + q0 + p1 + 2) >> 2
+    step = ((q0 - p0) * 4 + (p1 - q1) + 4) >> 3
+    if chroma:
+        delta = jnp.clip(step, -(t0 + 1), t0 + 1)
+        return ((sel(s_p0w, jnp.clip(p0 + delta, 0, 255), p0),),
+                (sel(s_q0w, jnp.clip(q0 - delta, 0, 255), q0),))
+
     ap = jnp.abs(p2 - p0) < beta
     aq = jnp.abs(q2 - q0) < beta
 
     # --- bS < 4 normal filter ---
-    if isinstance(tc0_row, np.ndarray):     # static qp: folded constants
-        tc0_row = [int(v) for v in tc0_row]
-    t0 = jnp.where(bs <= 1, tc0_row[0],
-                   jnp.where(bs == 2, tc0_row[1], tc0_row[2]))
-    tc = t0 + (1 if chroma
-               else 0) + (0 if chroma
-                          else ap.astype(jnp.int32) + aq.astype(jnp.int32))
-    delta = jnp.clip(((q0 - p0) * 4 + (p1 - q1) + 4) >> 3, -tc, tc)
+    tc = t0 + ap.astype(jnp.int32) + aq.astype(jnp.int32)
+    delta = jnp.clip(step, -tc, tc)
     n_p0 = jnp.clip(p0 + delta, 0, 255)
     n_q0 = jnp.clip(q0 - delta, 0, 255)
-    if chroma:
-        n_p1, n_q1, n_p2, n_q2 = p1, q1, p2, q2
-    else:
-        dp1 = jnp.clip((p2 + ((p0 + q0 + 1) >> 1) - 2 * p1) >> 1, -t0, t0)
-        dq1 = jnp.clip((q2 + ((p0 + q0 + 1) >> 1) - 2 * q1) >> 1, -t0, t0)
-        n_p1 = jnp.where(ap, p1 + dp1, p1)
-        n_q1 = jnp.where(aq, q1 + dq1, q1)
-        n_p2, n_q2 = p2, q2
+    avg = (p0 + q0 + 1) >> 1
+    n_p1 = jnp.where(ap, p1 + jnp.clip((p2 + avg - 2 * p1) >> 1, -t0, t0),
+                     p1)
+    n_q1 = jnp.where(aq, q1 + jnp.clip((q2 + avg - 2 * q1) >> 1, -t0, t0),
+                     q1)
 
     # --- bS == 4 strong filter ---
     strong = jnp.abs(p0 - q0) < ((alpha >> 2) + 2)
-    s_p0w = (2 * p1 + p0 + q1 + 2) >> 2
-    s_q0w = (2 * q1 + q0 + p1 + 2) >> 2
-    if chroma:
-        s_p0, s_p1, s_p2 = s_p0w, p1, p2
-        s_q0, s_q1, s_q2 = s_q0w, q1, q2
-    else:
-        use_p = strong & ap
-        use_q = strong & aq
-        s_p0 = jnp.where(use_p,
-                         (p2 + 2 * p1 + 2 * p0 + 2 * q0 + q1 + 4) >> 3,
-                         s_p0w)
-        s_p1 = jnp.where(use_p, (p2 + p1 + p0 + q0 + 2) >> 2, p1)
-        s_p2 = jnp.where(use_p,
-                         (2 * p3 + 3 * p2 + p1 + p0 + q0 + 4) >> 3, p2)
-        s_q0 = jnp.where(use_q,
-                         (q2 + 2 * q1 + 2 * q0 + 2 * p0 + p1 + 4) >> 3,
-                         s_q0w)
-        s_q1 = jnp.where(use_q, (q2 + q1 + q0 + p0 + 2) >> 2, q1)
-        s_q2 = jnp.where(use_q,
-                         (2 * q3 + 3 * q2 + q1 + q0 + p0 + 4) >> 3, q2)
+    use_p = strong & ap
+    use_q = strong & aq
+    s_p0 = jnp.where(use_p, (p2 + 2 * p1 + 2 * p0 + 2 * q0 + q1 + 4) >> 3,
+                     s_p0w)
+    s_p1 = jnp.where(use_p, (p2 + p1 + p0 + q0 + 2) >> 2, p1)
+    s_p2 = jnp.where(use_p, (2 * p3 + 3 * p2 + p1 + p0 + q0 + 4) >> 3, p2)
+    s_q0 = jnp.where(use_q, (q2 + 2 * q1 + 2 * q0 + 2 * p0 + p1 + 4) >> 3,
+                     s_q0w)
+    s_q1 = jnp.where(use_q, (q2 + q1 + q0 + p0 + 2) >> 2, q1)
+    s_q2 = jnp.where(use_q, (2 * q3 + 3 * q2 + q1 + q0 + p0 + 4) >> 3, q2)
 
-    bs4 = bs == 4
-    o_p0 = jnp.where(bs4, s_p0, n_p0)
-    o_p1 = jnp.where(bs4, s_p1, n_p1)
-    o_p2 = jnp.where(bs4, s_p2, n_p2)
-    o_q0 = jnp.where(bs4, s_q0, n_q0)
-    o_q1 = jnp.where(bs4, s_q1, n_q1)
-    o_q2 = jnp.where(bs4, s_q2, n_q2)
+    return ((sel(s_p0, n_p0, p0), sel(s_p1, n_p1, p1), sel(s_p2, p2, p2)),
+            (sel(s_q0, n_q0, q0), sel(s_q1, n_q1, q1), sel(s_q2, q2, q2)))
 
-    sel = lambda n, o: jnp.where(fil, n, o)
-    import jax.numpy as _j
-    p_new = _j.stack([sel(o_p0, p0), sel(o_p1, p1), sel(o_p2, p2), p3],
-                     axis=-1)
-    q_new = _j.stack([sel(o_q0, q0), sel(o_q1, q1), sel(o_q2, q2), q3],
-                     axis=-1)
-    return p_new, q_new
 
+# --- the TPU schedule: one kernel, MB rows on the lanes -------------------
+#
+# A plane reaches the kernel MB column by MB column, each a 2-D tile whose
+# row is ``x * 16 + line`` (x: pixel column inside the MB) and whose lane
+# is the MB row: pixel column x is the 16 sublanes [16x, 16x + 16), line l
+# the 16 sublanes l, l + 16, ... (a strided read).  Cb and Cr share their
+# thresholds and their bS, so they are ONE plane here: 8 pixel columns of
+# (Cb's 8 lines, Cr's 8 lines).  Every edge is then `_filter_lines` on
+# eight (16, MB rows) tiles, luma and chroma alike.
+#
+# The x=0 edge of MB c+1 is filtered at the end of MB c's turn ("next
+# edge"), so that a block of MB columns leaves the kernel final; its bS
+# is stored with MB c.  Rows of the bS tile of one MB column:
+_BS_NEXT, _BS_NEXT_C = 0, 16        # x=0 edge of the MB to the right
+_BS_V, _BS_V_C = 32, 80             # luma x=4,8,12; chroma x=4
+_BS_H, _BS_H_C = 96, 144            # luma y=4,8,12; chroma y=4
+_BS_ROWS = 160
+_COLS = 8                           # MB columns a grid step filters
+
+
+def _edges_kernel(thr, y_in, c_in, bs_in, y_ahead, c_ahead, y_out, c_out,
+                  yw, cw, y_left, c_left):
+    """One block of MB columns: copy it and the first four pixel columns
+    of the block to its right into the work tiles, run the chain, hand
+    the (now half-filtered) columns of the right neighbour to the next
+    grid step."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    n = y_in.shape[0]
+    lum = (thr[0], thr[1], (thr[2], thr[3], thr[4]), False)
+    chrm = (thr[5], thr[6], (thr[7], thr[8], thr[9]), True)
+    # work tile; pixel columns of an MB (and as many lines a plane);
+    # samples a side that an edge can move; bS rows; thresholds
+    planes = ((yw, 16, 3, _BS_V, _BS_H, _BS_NEXT, lum),
+              (cw, 8, 1, _BS_V_C, _BS_H_C, _BS_NEXT_C, chrm))
+
+    yw[:n] = y_in[...]
+    cw[:n] = c_in[...]
+    yw[n, :64] = y_ahead[0]
+    cw[n, :64] = c_ahead[0]
+
+    @pl.when(pl.program_id(1) > 0)     # not the first block of the chain
+    def _():
+        yw[0, :64] = y_left[...]
+        cw[0, :64] = c_left[...]
+
+    def column(g, _):
+        bs = bs_in.at[g]
+        for w, ncols, keep, at_v, at_h, at_next, par in planes:
+            mb, right = w.at[g], w.at[g + 1]
+            # vertical edges inside the MB, on pixel-column tiles
+            cols = [mb[pl.ds(x * 16, 16), :] for x in range(ncols)]
+            for e, x in enumerate(range(4, ncols, 4)):
+                pn, qn = _filter_lines(
+                    [cols[x - 1 - k] for k in range(4)], cols[x:x + 4],
+                    bs[pl.ds(at_v + 16 * e, 16), :], *par)
+                for k in range(keep):
+                    cols[x - 1 - k], cols[x + k] = pn[k], qn[k]
+            for x in range(4 - keep, ncols - 4 + keep):
+                mb[pl.ds(x * 16, 16), :] = cols[x]
+            # horizontal edges, on line tiles (every 16th row; chroma:
+            # every 8th, Cb's and Cr's line side by side)
+            lines = [mb[pl.ds(l, 16, stride=ncols), :]
+                     for l in range(ncols)]
+            for e, yy in enumerate(range(4, ncols, 4)):
+                pn, qn = _filter_lines(
+                    [lines[yy - 1 - k] for k in range(4)], lines[yy:yy + 4],
+                    bs[pl.ds(at_h + 16 * e, 16), :], *par)
+                for k in range(keep):
+                    lines[yy - 1 - k], lines[yy + k] = pn[k], qn[k]
+            for l in range(4 - keep, ncols - 4 + keep):
+                mb[pl.ds(l, 16, stride=ncols), :] = lines[l]
+            # the MB edge to the right neighbour
+            pn, qn = _filter_lines(
+                [mb[pl.ds((ncols - 1 - k) * 16, 16), :] for k in range(4)],
+                [right[pl.ds(k * 16, 16), :] for k in range(4)],
+                bs[pl.ds(at_next, 16), :], *par)
+            for k in range(keep):
+                mb[pl.ds((ncols - 1 - k) * 16, 16), :] = pn[k]
+                right[pl.ds(k * 16, 16), :] = qn[k]
+
+    jax.lax.fori_loop(0, n, column, None)
+    y_left[...] = yw[n, :64]
+    c_left[...] = cw[n, :64]
+    y_out[...] = yw[:n]
+    c_out[...] = cw[:n]
+
+
+def _bs_tiles(nnz_blk, mv, nr: int, nc: int):
+    """bS of every edge of a frame as the kernel reads it:
+    (nc, _BS_ROWS, nr) int32 (see the row map above)."""
+    import jax.numpy as jnp
+
+    if nnz_blk is None:                 # intra: 4 at MB edges, 3 inside
+        col = jnp.arange(nc, dtype=jnp.int32)[:, None, None]
+        row = jnp.arange(_BS_ROWS, dtype=jnp.int32)[None, :, None]
+        bs = jnp.where(row < _BS_V, jnp.where(col < nc - 1, 4, 0), 3)
+        return jnp.broadcast_to(bs, (nc, _BS_ROWS, nr))
+    n = nnz_blk.astype(jnp.int32).transpose(1, 2, 3, 0)   # (C, by, bx, R)
+    mvt = mv.transpose(1, 2, 0)                            # (C, 2, R)
+    per4 = lambda a: jnp.repeat(a, 4, axis=1)   # a 4x4 block's 4 lines
+    v_int = [per4((n[:, :, bx - 1] | n[:, :, bx]) * 2) for bx in (1, 2, 3)]
+    h_int = [per4((n[:, by - 1] | n[:, by]) * 2) for by in (1, 2, 3)]
+    mvd = (jnp.abs(mvt[1:] - mvt[:-1]) >= 4).any(axis=1)   # (C-1, R)
+    nxt = jnp.where((n[:-1, :, 3] | n[1:, :, 0]) > 0, 2,
+                    jnp.where(mvd[:, None], 1, 0))
+    nxt = per4(jnp.pad(nxt, ((0, 1), (0, 0), (0, 0))))     # none at the end
+    both = lambda a: jnp.concatenate([a[:, 0::2]] * 2, axis=1)  # (Cb, Cr)
+    return jnp.concatenate(
+        [nxt, both(nxt), *v_int, both(v_int[1]), *h_int,
+         jnp.repeat(h_int[1][:, 0::2], 2, axis=1)], axis=1)
+
+
+def _deblock_frame_kernel(y, cb, cr, lum, chrm, nnz_blk, mv):
+    """`deblock_frame` on the TPU: XLA lays planes and bS out (and back),
+    one Pallas kernel filters every edge.  The thresholds reach it as ten
+    scalars, so one kernel serves a static and a traced qp."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    H, W = y.shape
+    nr, nc = H // 16, W // 16
+    nb = -(-nc // _COLS)                # blocks of MB columns: the chain
+    nl = -(-nr // 128)                  # blocks of 128 MB rows: independent
+    pad = lambda a: jnp.pad(
+        a, ((0, nb * _COLS - nc), (0, 0), (0, nl * 128 - nr)))
+
+    with jax.named_scope("dngd.deblock_bs"):
+        thr = jnp.stack([jnp.asarray(v, jnp.int32) for v in
+                         (*lum[:2], *lum[2], *chrm[:2], *chrm[2])])
+        bs = pad(_bs_tiles(nnz_blk, mv, nr, nc))
+    with jax.named_scope("dngd.deblock_tile"):
+        # (MB row, line, pixel column) -> (pixel column, line, MB row)
+        turn = lambda p, n: (p.astype(jnp.int32).reshape(nr, n, -1)
+                             .transpose(2, 1, 0))
+        yt = pad(turn(y, 16).reshape(nc, 256, nr))
+        ct = pad(jnp.concatenate([turn(cb, 8), turn(cr, 8)], axis=1)
+                 .reshape(nc, 128, nr))
+
+    # Mosaic reads whole 128-lane tiles, so a block is 128 MB rows wide;
+    # the grid walks the column blocks of one row block, then the next's
+    blk = lambda rows: pl.BlockSpec((_COLS, rows, 128),
+                                    lambda r, i, thr: (i, 0, r))
+    # the first four pixel columns of the MB column right of a block (of
+    # the last MB column again behind the last block, where bS is 0)
+    ahead = pl.BlockSpec(
+        (1, 64, 128), lambda r, i, thr: (
+            jnp.minimum((i + 1) * _COLS, nb * _COLS - 1), 0, r))
+    with jax.named_scope("dngd.deblock_edges"):
+        yt, ct = pl.pallas_call(
+            _edges_kernel,
+            name="dngd_deblock_edges",
+            out_shape=(jax.ShapeDtypeStruct(yt.shape, jnp.int32),
+                       jax.ShapeDtypeStruct(ct.shape, jnp.int32)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(nl, nb),
+                in_specs=[blk(256), blk(128), blk(_BS_ROWS), ahead, ahead],
+                out_specs=(blk(256), blk(128)),
+                scratch_shapes=[
+                    pltpu.VMEM((_COLS + 1, 256, 128), jnp.int32),
+                    pltpu.VMEM((_COLS + 1, 128, 128), jnp.int32),
+                    pltpu.VMEM((64, 128), jnp.int32),
+                    pltpu.VMEM((64, 128), jnp.int32)]),
+        )(thr, yt, ct, bs, yt, ct)
+
+    with jax.named_scope("dngd.deblock_tile"):
+        back = lambda t: (t.astype(jnp.uint8).transpose(2, 1, 0)
+                          .reshape(-1, t.shape[0]))
+        ct = ct[:nc, :, :nr].reshape(W // 2, 16, nr)
+        return (back(yt[:nc, :, :nr].reshape(W, 16, nr)),
+                back(ct[:, :8]), back(ct[:, 8:]))
+
+
+# --- the scan: the same chain where there is no TPU -----------------------
 
 def _edge_v_mb(mb, x, bs, alpha, beta, tc0, chroma):
     """Filter the vertical edge at column ``x`` of (..., n, W) in place."""
-    import jax.numpy as jnp
-
-    p = jnp.stack([mb[..., x - 1 - k] for k in range(4)], axis=-1)
-    q = jnp.stack([mb[..., x + k] for k in range(4)], axis=-1)
-    p, q = _filter_lines(p, q, bs, alpha, beta, tc0, chroma)
-    for k in range(3):
-        mb = mb.at[..., x - 1 - k].set(p[..., k])
-        mb = mb.at[..., x + k].set(q[..., k])
+    pn, qn = _filter_lines([mb[..., x - 1 - k] for k in range(4)],
+                           [mb[..., x + k] for k in range(4)],
+                           bs, alpha, beta, tc0, chroma)
+    for k in range(len(pn)):
+        mb = mb.at[..., x - 1 - k].set(pn[k])
+        mb = mb.at[..., x + k].set(qn[k])
     return mb
 
 
 def _edge_h_mb(mb, y, bs, alpha, beta, tc0, chroma):
     """Filter the horizontal edge at row ``y`` of (..., H, W) in place."""
-    import jax.numpy as jnp
-
-    p = jnp.stack([mb[..., y - 1 - k, :] for k in range(4)], axis=-1)
-    q = jnp.stack([mb[..., y + k, :] for k in range(4)], axis=-1)
-    p, q = _filter_lines(p, q, bs, alpha, beta, tc0, chroma)
-    for k in range(3):
-        mb = mb.at[..., y - 1 - k, :].set(p[..., k])
-        mb = mb.at[..., y + k, :].set(q[..., k])
+    pn, qn = _filter_lines([mb[..., y - 1 - k, :] for k in range(4)],
+                           [mb[..., y + k, :] for k in range(4)],
+                           bs, alpha, beta, tc0, chroma)
+    for k in range(len(pn)):
+        mb = mb.at[..., y - 1 - k, :].set(pn[k])
+        mb = mb.at[..., y + k, :].set(qn[k])
     return mb
 
 
 import jax as _jax
 
 
-@functools.partial(_jax.jit, static_argnames=("qp", "_group"))
-def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
-                  _group: int = 0):
+@functools.partial(_jax.jit, static_argnames=("qp",))
+def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None):
     """Device loop filter for one frame (slice-per-row, idc=2 edges).
 
     y (H, W), cb/cr (H/2, W/2) uint8 recon planes.  Intra frames pass
     nnz_blk=None (static bS: 4 at MB edges, 3 internal); P frames pass
-    nnz_blk (R, C, 4, 4) bool and mv (R, C, 2) quarter-pel.  Returns
-    filtered uint8 planes.  Byte-identical to :func:`deblock_frame_ref`
-    (tested).
-
-    ``_group``: MB columns per scan step (0 = auto).  The left-to-right
-    MB order is a true sample dependency — MB n's x=0 edge rewrites MB
-    n-1's last columns AFTER n-1 finished — so the dependency chain is
-    irreducible, but each ``lax.scan`` step carries fixed overhead
-    (carry shuffling + fusion dispatch), and at 4K the two 120+-step
-    column scans cost ~8.7 ms (BENCH_r05).  The wavefront restructure
-    runs GROUPS of columns per step with the in-group chain statically
-    unrolled: the op sequence is identical (byte-exact, tested against
-    ``_group=1`` and the numpy reference), the fusions are group-times
-    wider, and the scan shrinks to nc/group steps."""
+    nnz_blk (R, C, 4, 4) bool and mv (R, C, 2) quarter-pel.  ``qp`` is
+    static here and traced in :data:`deblock_frame_dynqp`.  Returns
+    filtered uint8 planes, byte-identical to :func:`deblock_frame_ref`
+    on either schedule (tested)."""
     import jax
     import jax.numpy as jnp
 
@@ -253,9 +409,10 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
         alpha_t, beta_t, tc0_t = load_tables()
         if _q._is_static_qp(qp):
             qp_c = _q.chroma_qp(qp)
-            a_l, b_l, t_l = int(alpha_t[qp]), int(beta_t[qp]), tc0_t[qp]
+            a_l, b_l, t_l = (int(alpha_t[qp]), int(beta_t[qp]),
+                             [int(v) for v in tc0_t[qp]])
             a_c, b_c, t_c = (int(alpha_t[qp_c]), int(beta_t[qp_c]),
-                             tc0_t[qp_c])
+                             [int(v) for v in tc0_t[qp_c]])
         else:
             # traced slice qp (deblock_frame_dynqp): the thresholds are
             # table gathers instead of folded constants — same integers
@@ -264,10 +421,24 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
             tc0_a = jnp.asarray(tc0_t)
             a_l, b_l, t_l = alpha_a[qp], beta_a[qp], tc0_a[qp]
             a_c, b_c, t_c = alpha_a[qp_c], beta_a[qp_c], tc0_a[qp_c]
-        H, W = y.shape
-        nr, nc = H // 16, W // 16
-        intra = nnz_blk is None
+    lum, chrm = (a_l, b_l, t_l), (a_c, b_c, t_c)
+    if _jax.default_backend() == "tpu":
+        return _deblock_frame_kernel(y, cb, cr, lum, chrm, nnz_blk, mv)
+    return _deblock_frame_scan(y, cb, cr, lum, chrm, nnz_blk, mv)
 
+
+def _deblock_frame_scan(y, cb, cr, lum, chrm, nnz_blk, mv):
+    """`deblock_frame` as a `lax.scan` over MB columns, one column a step
+    (the CPU backend measured wider steps slower); ``lum`` / ``chrm`` are
+    (alpha, beta, tc0[3])."""
+    import jax
+    import jax.numpy as jnp
+
+    H, W = y.shape
+    nr, nc = H // 16, W // 16
+    intra = nnz_blk is None
+
+    with jax.named_scope("dngd.deblock_bs"):
         if not intra:
             nnz16y = jnp.repeat(nnz_blk.astype(jnp.int32), 4, axis=2)
             # (R, C, 16 lines, 4 bx) — per-line nnz along vertical edges
@@ -304,19 +475,6 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
             cr.astype(jnp.int32).reshape(nr, 8, nc, 8).transpose(0, 2, 1, 3),
             1, 0)
 
-    # Auto group: the wavefront amortizes the PER-STEP cost of a scan
-    # iteration (fusion dispatch + carry shuffling), which is what the
-    # ~8.7 ms column scans at 4K are made of on an accelerator backend.
-    # The CPU backend has no such per-step cost and measured the wider
-    # steps 1.5x SLOWER (BENCH_r06 profile), so auto keeps the column
-    # scan there; pass ``_group`` explicitly to override either way.
-    if _group:
-        group = _group
-    elif _jax.default_backend() == "cpu":
-        group = 1
-    else:
-        group = next(g for g in (8, 6, 5, 4, 3, 2, 1) if nc % g == 0)
-
     def col_step(carry, xs):
         yl, cbl, crl = carry            # left MB last-4 columns, post-H
         if intra:
@@ -336,27 +494,24 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
         # edges were filtered in the previous step) ---
         with jax.named_scope("dngd.deblock_v"):
             wide = jnp.concatenate([yl, ymb], axis=-1)     # (R, 16, 20)
-            wide = _edge_v_mb(wide, 4, bs0, a_l, b_l, t_l, False)
+            wide = _edge_v_mb(wide, 4, bs0, *lum, False)
             for e, x in enumerate((4, 8, 12)):
-                wide = _edge_v_mb(wide, 4 + x, bsv[e], a_l, b_l, t_l,
-                                  False)
+                wide = _edge_v_mb(wide, 4 + x, bsv[e], *lum, False)
             left_fin = wide[..., :4]    # left MB cols 12..15, FINAL
             own = wide[..., 4:]
         with jax.named_scope("dngd.deblock_h"):
             for e, yy_ in enumerate((4, 8, 12)):
-                own = _edge_h_mb(own, yy_, bsh[e], a_l, b_l, t_l, False)
+                own = _edge_h_mb(own, yy_, bsh[e], *lum, False)
 
         # --- chroma: MB edge + internal x=4 (luma x=8), h y=4 (luma 8) --
         def chroma_mb(mbp, left):
             with jax.named_scope("dngd.deblock_v"):
                 w2 = jnp.concatenate([left, mbp], axis=-1)  # (R, 8, 12)
-                w2 = _edge_v_mb(w2, 4, bs0[:, 0::2], a_c, b_c, t_c, True)
-                w2 = _edge_v_mb(w2, 8, bsv[1][:, 0::2], a_c, b_c, t_c,
-                                True)
+                w2 = _edge_v_mb(w2, 4, bs0[:, 0::2], *chrm, True)
+                w2 = _edge_v_mb(w2, 8, bsv[1][:, 0::2], *chrm, True)
                 lf, ownp = w2[..., :4], w2[..., 4:]
             with jax.named_scope("dngd.deblock_h"):
-                ownp = _edge_h_mb(ownp, 4, bsh[1][:, 0::2], a_c, b_c, t_c,
-                                  True)
+                ownp = _edge_h_mb(ownp, 4, bsh[1][:, 0::2], *chrm, True)
             return lf, ownp
 
         cbl_fin, cb_own = chroma_mb(cbmb, cbl)
@@ -367,16 +522,6 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
                cbl_fin[..., 2:], cb_own[..., :6],
                crl_fin[..., 2:], cr_own[..., :6])
         return carry, out
-
-    def step(carry, xs_g):
-        # one wavefront step: ``group`` columns chained in-body (the
-        # same per-column op sequence col_step always ran, unrolled)
-        outs = []
-        for g in range(group):
-            carry, out = col_step(carry, tuple(x[g] for x in xs_g))
-            outs.append(out)
-        return carry, tuple(jnp.stack(parts, 0)
-                            for parts in zip(*outs))
 
     # the scan is one ``while`` on the device; the edges it filters carry
     # the two scopes inside it
@@ -389,10 +534,7 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
         else:
             xs = (ymbs, cbm, crm, bs_v_int, bs_mb0, bs_h_int,
                   jnp.arange(nc, dtype=jnp.int32))
-        xs = tuple(x.reshape((nc // group, group) + x.shape[1:])
-                   for x in xs)
-        carry, outs = jax.lax.scan(step, init, xs)
-        outs = tuple(o.reshape((nc,) + o.shape[2:]) for o in outs)
+        carry, outs = jax.lax.scan(col_step, init, xs)
         lf3, own13, cblf, cbo6, crlf, cro6 = outs
 
     def assemble(own_first, later_last, tailc, sub):
@@ -413,8 +555,7 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None,
 
 #: qp-traced twin: one program for every slice qp (see
 #: cavlc_device.encode_intra_cavlc_frame_yuv_dynqp).
-deblock_frame_dynqp = _jax.jit(deblock_frame.__wrapped__,
-                               static_argnames=("_group",))
+deblock_frame_dynqp = _jax.jit(deblock_frame.__wrapped__)
 
 
 def _filter_line(p, q, bs, alpha, beta, tc0_row, chroma):

@@ -1,6 +1,6 @@
 """Round-6 tentpole coverage: device-side CABAC binarization + ctxIdx
 (ops/cabac_binarize -> engine-only host replay), alternate-line subpel
-SAD pick agreement, and the wavefront deblock scan restructure.
+SAD pick agreement, and the loop filter's kernel against its scan.
 
 Byte-identity is the acceptance bar throughout: the record stream must
 drive the arithmetic engine through EXACTLY the decision sequence the
@@ -228,41 +228,60 @@ class TestAlternateLineSad:
             assert dom == -16, (refine, dom)
 
 
-class TestWavefrontDeblock:
-    @pytest.mark.parametrize("qp", [10, 26, 40])
-    def test_grouped_scan_byte_equal(self, qp, rng):
-        """The wavefront (grouped-column) scan must be byte-identical
-        to the per-column scan AND the numpy spec-order reference, for
-        intra and P bS, across group divisors (nc=8 -> 8, nc=10 -> 5)."""
+class TestDeblockKernel:
+    @pytest.mark.parametrize("traced_qp", [False, True],
+                             ids=["static_qp", "traced_qp"])
+    @pytest.mark.parametrize("kind", ["intra", "p"])
+    @pytest.mark.parametrize(
+        "h,w,qp", [(96, 128, 26), (96, 160, 40), (2064, 32, 33)],
+        ids=["96x128", "96x160", "2064x32"])
+    def test_kernel_scan_and_reference_byte_equal(self, h, w, qp, kind,
+                                                  traced_qp, rng,
+                                                  monkeypatch):
+        """The TPU schedule (one Pallas kernel, here in interpret mode
+        and reached through ``deblock_frame``'s own backend test) must be
+        byte-identical to the CPU's scan AND to the numpy spec-order
+        reference: intra and P bS, a static and a traced qp, an ``nc``
+        that is a whole column block (8) and one that is not (10), and
+        more MB rows (129) than one block of lanes holds."""
+        import jax
         import jax.numpy as jnp
+        from jax.experimental.pallas import tpu as pltpu
 
         from docker_nvidia_glx_desktop_tpu.ops import h264_deblock as d
         from docker_nvidia_glx_desktop_tpu.ops.quant import chroma_qp
 
-        for h, w, grp in ((96, 128, 8), (96, 160, 5)):
-            nr, nc = h // 16, w // 16
-            y = rng.integers(0, 256, (h, w)).astype(np.uint8)
-            cb = rng.integers(0, 256, (h // 2, w // 2)).astype(np.uint8)
-            cr = rng.integers(0, 256, (h // 2, w // 2)).astype(np.uint8)
-            nnz = rng.integers(0, 2, (nr, nc, 4, 4)).astype(bool)
-            mv = rng.integers(-20, 21, (nr, nc, 2)).astype(np.int32)
-            for kw in ({}, {"nnz_blk": jnp.asarray(nnz),
-                            "mv": jnp.asarray(mv)}):
-                # force the wavefront grouping (auto picks 1 on the CPU
-                # backend) against the per-column scan
-                a = d.deblock_frame(y, cb, cr, qp, _group=grp, **kw)
-                b = d.deblock_frame(y, cb, cr, qp, _group=1, **kw)
-                for pa, pb in zip(a, b):
-                    np.testing.assert_array_equal(
-                        np.asarray(pa), np.asarray(pb))
-                if kw:
-                    bs_v, bs_h = d.p_bs(nnz, mv)
-                else:
-                    bs_v, bs_h = d.intra_bs(nr, nc)
-                ref = d.deblock_frame_ref(y, cb, cr, qp, chroma_qp(qp),
-                                          bs_v, bs_h)
-                for pa, pr in zip(a, ref):
-                    np.testing.assert_array_equal(np.asarray(pa), pr)
+        nr, nc = h // 16, w // 16
+        # smooth ramps under noise, so that the strong filter and both
+        # tc branches run, beside pure noise that mostly fails alpha
+        y = (np.add.outer(np.arange(h), np.arange(w)) // 3
+             + rng.integers(0, 7, (h, w))).astype(np.uint8)
+        y[: h // 2] = rng.integers(0, 256, (h // 2, w))
+        cb = rng.integers(100, 140, (h // 2, w // 2)).astype(np.uint8)
+        cr = rng.integers(0, 256, (h // 2, w // 2)).astype(np.uint8)
+        nnz = rng.integers(0, 2, (nr, nc, 4, 4)).astype(bool)
+        mv = rng.integers(-20, 21, (nr, nc, 2)).astype(np.int32)
+        if kind == "p":
+            kw = {"nnz_blk": jnp.asarray(nnz), "mv": jnp.asarray(mv)}
+            bs_v, bs_h = d.p_bs(nnz, mv)
+        else:
+            kw = {}
+            bs_v, bs_h = d.intra_bs(nr, nc)
+        ref = d.deblock_frame_ref(y, cb, cr, qp, chroma_qp(qp), bs_v, bs_h)
+        assert (ref[0] != y).mean() > 0.05      # the filter did work
+
+        scan = (d.deblock_frame_dynqp(y, cb, cr, jnp.int32(qp), **kw)
+                if traced_qp else d.deblock_frame(y, cb, cr, qp, **kw))
+        # a fresh jit of the same body: the one above is traced already
+        body = jax.jit(d.deblock_frame.__wrapped__,
+                       static_argnames=() if traced_qp else ("qp",))
+        with monkeypatch.context() as mp, pltpu.force_tpu_interpret_mode():
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            kernel = body(y, cb, cr, jnp.int32(qp) if traced_qp else qp,
+                          **kw)
+        for pk, ps, pr in zip(kernel, scan, ref):
+            np.testing.assert_array_equal(np.asarray(pk), pr)
+            np.testing.assert_array_equal(np.asarray(ps), pr)
 
 
 class TestMeshSharedDeblock:

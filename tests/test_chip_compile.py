@@ -8,9 +8,10 @@ call shapes: the intra step as ``H264Encoder._submit_device`` issues it
 (recon kept for the GOP, qp a traced scalar), the P step as
 ``_submit_p_device`` issues it (reference ring donated, as it resolves
 under ``JAX_PLATFORMS=tpu``),
-the in-loop deblock of a P frame with the TPU's column group (the
-``jax.default_backend()`` branch would pick the CPU's group here, so the
-test passes ``_group=8`` itself), and the (4,1) session-mesh step of
+the in-loop deblock of a P frame on the TPU's schedule, the Pallas
+kernel compiled by Mosaic (the ``jax.default_backend()`` branch would pick
+the CPU's scan here, so the test answers "tpu" while that one program is
+lowered), and the (4,1) session-mesh step of
 ``TPU_SESSIONS``/``TPU_MESH`` on a ``Mesh`` of the four described
 devices.
 
@@ -100,8 +101,14 @@ def programs(topo, no_persistent_cache):
     _flat, ry, rcb, rcr, mv, nnz, _lv = on_chip(
         jax.eval_shape(lambda *a: p_body(*a, "off", None, False),
                        *p_args[:9]))
-    lowered["deblock_p"] = h264_deblock.deblock_frame_dynqp.lower(
-        ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv, _group=8)
+    # H264Encoder._deblock as the served path calls it (traced qp), on
+    # the schedule a TPU backend picks; a jit of its own, so that no
+    # trace of the CPU's schedule is handed back
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        lowered["deblock_p"] = jax.jit(
+            h264_deblock.deblock_frame.__wrapped__).lower(
+                ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv)
     # web/multisession: four 1080p sessions, one per chip
     mesh = batch.make_mesh((4, 1), topo.devices)
     sess = NamedSharding(mesh, P("session", "spatial", None))
@@ -153,9 +160,13 @@ def test_p_step_compiles_and_donates_the_ring(programs):
     assert c.memory_analysis().alias_size_in_bytes >= H * W * 3 // 2
 
 
-def test_p_deblock_compiles_with_tpu_column_group(programs):
+def test_p_deblock_compiles_with_the_edge_kernel(programs):
     c = _compiled(programs, "deblock_p")
     assert 0 < _device_bytes(c) < HBM_BYTES
+    # the whole edge chain is one Mosaic kernel, and no scan is left
+    text = c.as_text()
+    assert "dngd_deblock_edges" in text and "tpu_custom_call" in text
+    assert " while(" not in text
 
 
 def test_session_mesh_step_fits_each_chip(programs):
