@@ -16,7 +16,9 @@ pull-size ladder, the client's join, until ``READY_AFTER`` fragments (two
 GOPs) have arrived.  Then the window of ``--seconds``; then the client and the
 session stop, and the stream is decoded and checked outside the window.  With
 ``--trace 1`` the profiler covers the last ``TRACE_S`` seconds of the window
-(``stop_trace`` takes minutes and falls after it) and the line carries the
+(``stop_trace`` takes minutes and falls after it), the trace is reduced once
+(``trace_reduce``: the device's totals; ``stage_reduce``: device time by
+program and named stage, idle gaps by host span) and the line carries the
 per-layer metrics and ``breakdown``; with ``--trace 0`` no profiler is loaded.
 The last line of standard output is the one JSON object of the contract.  No
 chip is an error, never XLA:CPU, except under ``--rehearse`` (for the tests),
@@ -224,20 +226,6 @@ def warm_pull_ladder(encoder, frames, buckets: int) -> None:
     encoder.import_state(first)
 
 
-def annotate(obj, attr: str, name: str) -> None:
-    """Wrap ``obj.attr`` in a profiler span of the benchmark's own (traced
-    runs only; no span is added inside the program)."""
-    import jax
-
-    fn = getattr(obj, attr)
-
-    def spanned(*a, **kw):
-        with jax.profiler.TraceAnnotation(name):
-            return fn(*a, **kw)
-
-    setattr(obj, attr, spanned)
-
-
 # -- the run ------------------------------------------------------------------
 
 async def serve_window(spec: dict, args, client,
@@ -273,10 +261,6 @@ async def serve_window(spec: dict, args, client,
             load_by_file("controls", args.control).apply(session)
             note(f"CONTROL {args.control!r} is in place: this run must come out "
                  "as not correct")
-        if args.trace:
-            annotate(session.encoder, "encode_submit", "bench.encode_submit")
-            annotate(session.encoder, "encode_collect", "bench.encode_collect")
-            annotate(display, "frame", "bench.source_frame")
         warm = [np.zeros((height, width, 3), np.uint8) for _ in range(2)]
         for c, buf in enumerate(warm):
             scene.render(c, buf)
@@ -411,10 +395,13 @@ def reduce_run(args, obs: dict, workdir: pathlib.Path) -> dict:
     seen = stats.delivered(zip(ks, stamps), t_start, t_end)
     lat = stats.latencies_ms(seen, display.t0, fps)
     arrived = {k for k in ks if k is not None}
-    taken = [k for k, t in display.handed if t_start <= t < t_end]
     in_window = [i for i, s in enumerate(stamps) if t_start <= s < t_end]
-    took_at = [t for _, t in display.handed if t_start <= t < t_end]
-    take_gaps_ms = [(b - a) * 1e3 for a, b in zip(took_at, took_at[1:])]
+    took = [(k, t) for k, t in display.handed if t_start <= t < t_end]
+    take_gaps_ms = [(b[1] - a[1]) * 1e3 for a, b in zip(took, took[1:])]
+    capture_age_ms = [(t - display.due(k)) * 1e3 for k, t in took]
+    taken_to_glass_ms = [(stamp - handed_at[k]) * 1e3
+                         for k, stamp in seen.items() if k in handed_at]
+    taken = [k for k, _ in took]
     note(f"window {args.seconds:g} s: {len(in_window)} fragments arrived, "
          f"{len(seen)} distinct frames delivered, {len(taken)} taken from "
          f"the display, {len(lat)} latency samples, {len(psnr)} PSNR "
@@ -428,6 +415,9 @@ def reduce_run(args, obs: dict, workdir: pathlib.Path) -> dict:
          f"window's {obs['display_refreshes']} refreshes and "
          f"{obs['display_skipped']} since its start (its process started "
          f"on {obs['display_cpus']} of {os.cpu_count()} CPUs)")
+    note(f"a frame's age when the session took it from the display: p50 "
+         f"{stats.percentile(capture_age_ms, 50):.3f} ms; from there to the "
+         f"client: p50 {stats.percentile(taken_to_glass_ms, 50):.3f} ms")
     for name, value in compared.items():
         note(f"compared: {name} = {value} (limit {LIMITS[name]})")
     for t, secs in BACKEND_COMPILES:
@@ -448,9 +438,10 @@ def reduce_run(args, obs: dict, workdir: pathlib.Path) -> dict:
         "counters_end": obs["counters_end"],
         "display_late_ms": obs["display_late_ms"],
         "display_skipped": obs["display_skipped"],
-        "take_gaps_ms": take_gaps_ms,
+        "take_gaps_ms": take_gaps_ms, "capture_age_ms": capture_age_ms,
+        "taken_to_glass_ms": taken_to_glass_ms,
         "bytes_in_window": sum(lens[i] for i in in_window),
-        "frames_delivered": len(seen), "trace": None,
+        "frames_delivered": len(seen), "trace": None, "stages": None,
     }
     return {"correct": correct, "compared": compared,
             "attempted": len(taken),
@@ -523,14 +514,18 @@ def main(argv=None) -> int:
         out = reduce_run(args, obs, workdir)
         run = out["run"]
         if args.trace:
-            from benchmark import trace_reduce
+            from benchmark import stage_reduce, trace_reduce
             found = sorted(glob.glob(os.path.join(
                 obs["trace_dir"], "plugins", "profile", "*", "*.xplane.pb")))
             if not found:
                 raise BenchFailure("the profiler wrote no .xplane.pb")
             if args.keep_trace:
                 shutil.copy(found[-1], args.keep_trace)
+            t_red = time.monotonic()
             run["trace"] = trace_reduce.reduce(found[-1])
+            run["stages"] = stage_reduce.reduce(found[-1])
+            note(f"the trace ({os.path.getsize(found[-1]) >> 20} MiB) was "
+                 f"reduced in {time.monotonic() - t_red:.1f} s")
         device["memory_peak_bytes"] = obs["memory_peak_bytes"]
         names = spec["manifest"]["per_layer" if args.trace else "end_to_end"]
         metrics = {}
@@ -561,10 +556,18 @@ def main(argv=None) -> int:
             if not tr["busy_s"] > 0:
                 raise BenchFailure("the trace shows no device operation")
             device["busy_s"], device["window_s"] = tr["busy_s"], tr["window_s"]
-            result["breakdown"] = {"device_ops": tr["device_ops"][:10],
-                                   "idle_gaps": tr["idle_gaps"][:10]}
+            result["breakdown"] = {
+                "device_ops": stage_reduce.device_ops(run["stages"])[:10],
+                "idle_gaps": run["stages"]["idle_gaps"][:10]}
         note(f"end to end: {json.dumps({k: v[0] for k, v in out['end_to_end'].items()})}")
+        # each number compared beside its limit: last in the line, and the
+        # last lines on standard error
+        result["compared"] = {n: {"value": v, "limit": LIMITS[n]}
+                              for n, v in out["compared"].items()}
         print(json.dumps(result), flush=True)
+        for n, c in result["compared"].items():
+            print(f"compared: {n} = {c['value']} (limit {c['limit']})",
+                  file=sys.stderr, flush=True)
         return 0
     except BenchFailure as e:
         note(f"FAILED: {e}")

@@ -2,8 +2,9 @@
 
     python3 -m benchmark.stage_reduce <file.xplane.pb>
 
-Nothing in ``run.py`` calls this yet (PERF.md section 7: a ``benchmark`` issue
-wires it); it reads what ``run.py --trace 1 --keep-trace <file>`` keeps.
+``run.py`` reduces every traced run with it (``run["stages"]``: the stage
+readers under ``layer_metrics/`` and the result's ``breakdown`` read that); by
+hand it reads what ``run.py --trace 1 --keep-trace <file>`` keeps.
 
 How a stage reaches the trace (looked at by hand on a v5e, PR 25).  The
 program wraps its stages in ``jax.named_scope("dngd.<stage>")``; XLA keeps the
@@ -218,6 +219,19 @@ def reduce_planes(planes: dict) -> dict:
 
 def reduce(path: str) -> dict:
     return reduce_planes(load(path))
+
+
+def device_ops(red: dict) -> list:
+    """[[name, seconds]], largest first: every program, and every
+    ``<program>/<scope>`` that is not all but the whole of its program.  The
+    names stay the same from compile to compile, which an operation's do
+    not."""
+    out = []
+    for name, p in red["programs"].items():
+        out.append([name, p["device_s"]])
+        out += [[f"{name}/{scope}", s] for scope, s in p["scopes"].items()
+                if s < 0.95 * p["device_s"]]
+    return sorted(out, key=lambda kv: -kv[1])
 
 
 def table(red: dict) -> str:

@@ -125,6 +125,48 @@ def test_a_later_pr_adds_files_and_entries_only(tmp_path):
     assert all(p.read_bytes() == data for p, data in before.items())
 
 
+def test_appended_entries_leave_the_manifest_tests_passing(tmp_path):
+    """What a ``model_config`` PR does to BENCHMARK.json, a configuration, a
+    cell and a per-layer metric with a ``workloads`` list APPENDED, in a
+    copy of the benchmark and of its tests: every test of the manifest
+    there still passes, so none pins a position in a list."""
+    for d in ("benchmark", "tests/benchmark"):
+        shutil.copytree(ROOT / d, tmp_path / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    b = tmp_path / "benchmark"
+    cfg = json.loads((b / "configs" / "desk1080.json").read_text())
+    cfg.update(name="later1080", source="https://example.org/later1080")
+    (b / "configs" / "later1080.json").write_text(json.dumps(cfg))
+    (b / "layer_metrics" / "later_engine_ms.py").write_text(
+        "def read(run):\n    return None\n")
+    manifest = json.loads(json.dumps(MANIFEST))
+    manifest["configs"].append(
+        {"name": "later1080", "source": cfg["source"],
+         "file": "benchmark/configs/later1080.json", "reduced": [],
+         "why": "a later PR's deployment"})
+    manifest["workloads"].append(
+        {"name": "later1080.desktop", "config": "later1080",
+         "traffic": "desktop", "chips": 1, "why": "a later PR's cell"})
+    manifest["per_layer"].append(
+        {"name": "later_engine_ms", "unit": "ms", "better": "lower",
+         "source": "program_span", "layer": "host entropy engine",
+         "moves": "g2g_p50_ms", "workloads": ["later1080.desktop"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:randomly", "-k", "manifest or every_cell_resolves",
+         "--deselect", "tests/benchmark/test_benchmark_manifest.py::"
+         "test_appended_entries_leave_the_manifest_tests_passing",
+         "tests/benchmark/test_benchmark_manifest.py",
+         "tests/benchmark/test_benchmark_stages.py"],
+        cwd=tmp_path, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    # the cells that were there and the new one, and the tests by name
+    assert " passed" in r.stdout and "failed" not in r.stdout
+    passed = int(re.search(r"(\d+) passed", r.stdout).group(1))
+    assert passed >= 4 + len(MANIFEST["workloads"]) + 9
+
+
 def test_the_multi_session_branch_is_a_stub():
     src = (ROOT / "benchmark" / "run.py").read_text()
     assert "tpu_sessions > 1" in src and "not built yet" in src

@@ -100,7 +100,14 @@ def _rehearse(*extra):
          "--rehearse", "--geometry", "320x240", *extra],
         capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
-    return json.loads(r.stdout.strip().splitlines()[-1]), r.stdout
+    line = json.loads(r.stdout.strip().splitlines()[-1])
+    # each number compared beside its limit: the line's last key, and the
+    # last lines of standard error
+    assert list(line)[-1] == "compared" and len(line["compared"]) == 5
+    assert r.stderr.strip().splitlines()[-5:] == [
+        f"compared: {n} = {c['value']} (limit {c['limit']})"
+        for n, c in line["compared"].items()]
+    return line, r.stdout
 
 
 def test_rehearsal_prints_the_contract_line_and_is_never_a_result():
@@ -110,6 +117,8 @@ def test_rehearsal_prints_the_contract_line_and_is_never_a_result():
     assert line["rehearsal"]["correct_before_override"] is True, out
     assert line["device"]["platform"] == "cpu"
     assert set(line["device"]) == {"platform", "kind", "count"}
+    assert line["compared"]["closed_loop_luma_maxdiff"] == {
+        "value": 0, "limit": 0}
     assert set(line["metrics"]) == {"delivered_fps", "g2g_p50_ms",
                                     "g2g_p95_ms", "psnr_p50_db", "setup_s"}
     assert line["attempted"] > 0 and line["failed"] == 0
@@ -123,8 +132,11 @@ def test_a_broken_timed_path_comes_out_as_not_correct():
     line, out = _rehearse("--trace", "1", "--control", "stale_frame")
     assert line["rehearsal"]["correct_before_override"] is False
     assert line["rehearsal"]["compared"]["frame_order_faults"] > 0
-    assert not {"device_ms_per_frame", "device_idle_pct"} & set(line["metrics"])
-    assert "busy_s" not in line["device"]
+    assert not {"device_ms_per_frame", "device_idle_pct", "me_subpel_ms",
+                "deblock_ms", "unscoped_ms"} & set(line["metrics"])
+    assert 0 <= line["metrics"]["capture_age_p50_ms"]["value"] < 100
+    assert 0 < line["metrics"]["taken_to_glass_p50_ms"]["value"] < 1000
+    assert "busy_s" not in line["device"] and "breakdown" not in line
 
 
 def test_no_chip_is_an_error_outside_a_rehearsal():

@@ -1,6 +1,7 @@
-"""The readers of the program's stage spans and set-up counters (PR 25), and
+"""The readers of the program's stage spans and set-up counters (PR 25),
 ``stage_reduce``: the reduction from a trace to device time by named stage,
-on a hand-made trace and on a piece of a recorded one."""
+on a hand-made trace and on a piece of a recorded one, and the readers of
+its stages (PR 27)."""
 
 import json
 import pathlib
@@ -44,6 +45,13 @@ COUNTER_READERS = {
                         "XLA compile", "setup_s", "program_counter"),
 }
 ALL_READERS = {**READERS, **COUNTER_READERS}
+# reader -> ms a frame on the recorded trace (its loop filter is the parent
+# of PR 26's, 5 ms; frame_stats ran twice in its one frame)
+STAGE_READERS = {
+    "me_subpel_ms": 3.4365, "me_int_ms": 1.3469, "slots_ms": 3.0029,
+    "pack_ms": 2.8764, "deblock_ms": 5.1583, "frame_stats_ms": 0.6299,
+    "other_stages_ms": 1.2528, "unscoped_ms": 0.2558,
+}
 
 
 def reader(name):
@@ -98,23 +106,41 @@ def test_setup_readers_read_the_counters_at_the_windows_start():
 
 
 def test_manifest_lists_the_nine_after_what_was_there():
+    """By name, wherever a later PR's entries put them in the lists."""
     manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
-    names = [m["name"] for m in manifest["per_layer"]]
-    assert names[9:] == ["capture_mean_ms", "colour_mean_ms",
-                         "dispatch_mean_ms", "pull_mean_ms",
-                         "assemble_mean_ms", "pull_extra_pct",
-                         "ws_send_mean_ms", "program_load_s",
-                         "program_build_s"]
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     for name, (_, layer, moves, source) in ALL_READERS.items():
         m = by_name[name]
         assert (m["layer"], m["moves"], m["source"], m["better"]) == (
             layer, moves, source, "lower")
         assert "workloads" not in m             # read in every cell
-    cell = manifest["workloads"][-1]
+    cell = {w["name"]: w for w in manifest["workloads"]}["desk1080.fulldamage"]
     assert cell == {"name": "desk1080.fulldamage", "config": "desk1080",
                     "traffic": "fulldamage", "chips": 1,
                     "why": cell["why"]} and len(cell["why"]) <= 200
+
+
+HOST_READERS = {"capture_age_p50_ms": "capture_age_ms",
+                "taken_to_glass_p50_ms": "taken_to_glass_ms"}
+
+
+@pytest.mark.parametrize("name", sorted(STAGE_READERS) + sorted(HOST_READERS))
+def test_manifest_lists_the_stage_readers(name):
+    """The halves of ``g2g_p50_ms`` are the harness's own and read in every
+    cell; a stage reader lists the cells whose programs carry its scopes (a
+    later configuration on other programs, CABAC's say, must not be held to
+    them: a traced run that lacks a metric it owes is refused)."""
+    manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
+    m = {m["name"]: m for m in manifest["per_layer"]}[name]
+    want = ((FRONT, "host_clock") if name in HOST_READERS
+            else ("device programs", "device_trace"))
+    assert (m["layer"], m["source"], m["moves"], m["better"], m["unit"]) == (
+        *want, "g2g_p50_ms", "lower", "ms")
+    if name in HOST_READERS:
+        assert "workloads" not in m
+    else:
+        assert set(m["workloads"]) >= {
+            "desk1080.desktop", "desk1080.fulldamage", "desk1600.fulldamage"}
 
 
 @pytest.mark.parametrize("name", sorted(ALL_READERS))
@@ -257,9 +283,89 @@ def test_stage_reduce_on_a_recorded_trace():
 
 
 def test_the_accepted_reduction_still_reads_the_same_trace():
-    """``trace_reduce`` did not move: it reads this trace too, and labels
-    its gaps with the benchmark's own ``bench.*`` spans."""
+    """``trace_reduce`` keeps the device's totals and gives them as the
+    reduction PR 24 was accepted with gave them (its numbers, from the parent
+    of PR 27 over the same file): the window's edges follow the program's
+    ``dngd.`` spans where they followed run.py's ``bench.`` wrappers."""
     red = trace_reduce.reduce(str(TRACE))
-    assert red["frames"] == 1 and red["busy_s"] > 0.017
-    assert all(name.startswith("bench.") or name == "between spans"
-               for name, _ in red["idle_gaps"])
+    assert red == {"busy_s": 0.018042657, "window_s": 0.018086263,
+                   "frames": 1, "devices": 1}
+    assert "dngd." in trace_reduce.SPAN_PREFIXES
+
+
+# -- the stage readers (PR 27) --------------------------------------------------
+
+@pytest.fixture(scope="module")
+def recorded():
+    return stage_reduce.reduce(str(TRACE))
+
+
+@pytest.mark.parametrize("name", sorted(STAGE_READERS))
+def test_stage_reader_on_the_recorded_trace(name, recorded):
+    got = reader(name).read({"stages": recorded})
+    assert got == pytest.approx(STAGE_READERS[name], abs=1e-4)
+
+
+def test_the_stage_readers_sum_to_the_device_time_of_a_frame(recorded):
+    """Every program's operations are under one reader and no reader counts
+    another's: the sum is ``device_ms_per_frame`` less the programs' time
+    between their operations."""
+    run = {"stages": recorded, "trace": trace_reduce.reduce(str(TRACE))}
+    total = sum(reader(name).read(run) for name in STAGE_READERS)
+    whole = reader("device_ms_per_frame").read(run)
+    assert whole - 0.1 < total <= whole
+
+
+@pytest.mark.parametrize("name", sorted(STAGE_READERS))
+def test_stage_reader_gives_nothing_under_a_stale_cache(name, recorded):
+    """Programs served by a compile cache that a tree without the scopes
+    filled read ``(no scope)``: no number, not a wrong one.  Nor without a
+    trace, nor from a trace without a frame."""
+    stale = dict(recorded, scoped_share=0.89)
+    assert reader(name).read({"stages": stale}) is None
+    assert reader(name).read({"stages": None}) is None
+    assert reader(name).read({"stages": dict(recorded, frames=0)}) is None
+    none = {"frames": 2, "scoped_share": 1.0, "programs": {
+        "jit_other": {"device_s": 0.002, "runs": 2, "scopes": {}}}}
+    assert reader(name).read({"stages": none}) is None      # never 0
+
+
+def test_stage_readers_over_programs_and_the_loop_filter_apart(tmp_path):
+    """``slots`` over two programs; the loop filter whole, and its scopes in
+    no other reader."""
+    red = stage_reduce.reduce(hand_made_trace(tmp_path))
+    red["scoped_share"] = 0.95
+    red["programs"]["jit_encode_intra_cavlc_frame_yuv"] = {
+        "device_s": 0.004, "runs": 1,
+        "scopes": {"dngd.slots": 0.001, "dngd.intra": 0.002,
+                   stage_reduce.NO_SCOPE: 0.0005}}
+    red["programs"]["jit_encode_p_cavlc_frame"]["scopes"]["dngd.slots"] = 0.003
+    run = {"stages": red}
+    assert reader("slots_ms").read(run) == pytest.approx(2.0)       # 4 ms / 2
+    assert reader("me_int_ms").read(run) == pytest.approx(8.0)
+    assert reader("deblock_ms").read(run) == pytest.approx(5.0)
+    assert reader("other_stages_ms").read(run) == pytest.approx(1.0)
+    assert reader("unscoped_ms").read(run) == pytest.approx(0.25)
+    assert reader("me_subpel_ms").read(run) is None
+
+
+@pytest.mark.parametrize("name", sorted(HOST_READERS))
+def test_the_two_halves_of_g2g_are_medians_over_the_window(name):
+    """``capture_age_p50_ms`` (taken minus due) and ``taken_to_glass_p50_ms``
+    (arrival minus taken), each from its list in ``run``."""
+    key = HOST_READERS[name]
+    assert reader(name).read({key: [3.0, 1.0, 16.0, 2.0]}) == 2.5
+    assert reader(name).read({key: []}) is None
+    assert reader(name).read({}) is None
+
+
+def test_breakdown_lists_programs_and_stages_by_name(recorded):
+    ops = stage_reduce.device_ops(recorded)
+    assert ops[0][0] == "jit_encode_p_cavlc_frame"
+    assert ops == sorted(ops, key=lambda kv: -kv[1])
+    names = [n for n, _ in ops]
+    assert "jit_encode_p_cavlc_frame/dngd.me_subpel" in names[:4]
+    assert "jit_frame_stats" in names
+    # a stage that is its whole program is listed once, as the program
+    assert "jit_frame_stats/dngd.frame_stats" not in names
+    assert not any("fusion" in n or "%" in n for n in names)

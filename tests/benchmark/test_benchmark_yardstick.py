@@ -93,46 +93,49 @@ def test_order_faults_counts_what_a_broken_stream_shows():
     assert check.order_faults([5, 6, 7], [1.05, 1.05, 1.25], handed) == 1
 
 
-def test_union_and_gap_attribution_on_made_up_planes():
+def test_union_busy_and_window_on_made_up_planes():
     assert trace_reduce.union([(5, 7), (0, 2), (1, 3)]) == [[0, 3], [5, 7]]
     planes = {
         "/device:TPU:0": {
             "XLA Modules": [("jit_encode_p(1)", 0.0, 10e6),
                             ("jit_deblock(2)", 10e6, 14e6),
-                            ("jit_encode_p(1)", 20e6, 30e6)],
-            "XLA Ops": [("%fusion.1 = s32[8] fusion(...)", 1e6, 9e6),
-                        ("%while.2 = s32[] while(...)", 10e6, 13e6)]},
-        "/host:CPU": {"python3": [("bench.encode_collect", 13e6, 19e6),
-                                  ("bench.encode_submit", 19e6, 21e6),
+                            ("jit_encode_p(1)", 20e6, 30e6)]},
+        "/host:CPU": {"python3": [("dngd.encode_collect", 13e6, 19e6),
+                                  ("dngd.encode_submit", 19e6, 21e6),
                                   ("other", 0.0, 40e6)]}}
     r = trace_reduce.reduce_planes(planes)
-    assert r["frames"] == 2 and r["devices"] == 1
-    assert r["busy_s"] == pytest.approx(0.024)
-    assert r["window_s"] == pytest.approx(0.030)
-    assert r["idle_gaps"] == [["bench.encode_collect", pytest.approx(0.006)]]
-    assert r["device_ops"][0] == ["program jit_encode_p", pytest.approx(0.02)]
-    assert ["jit_encode_p/%fusion.1", pytest.approx(0.008)] in r["device_ops"]
+    assert r == {"frames": 2, "devices": 1, "busy_s": pytest.approx(0.024),
+                 "window_s": pytest.approx(0.030)}
+    # a device that idles at the trace's edge while the host is in a stage
+    # is idle inside the window
+    planes["/host:CPU"]["python3"].append(("dngd.pull", 29e6, 32e6))
+    assert trace_reduce.reduce_planes(planes)["window_s"] == pytest.approx(
+        0.032)
+    assert trace_reduce.reduce_planes({}) == {
+        "frames": 0, "devices": 0, "busy_s": 0.0, "window_s": 0.0}
 
 
 def test_trace_reduction_on_a_recorded_v5e_trace():
+    """A trace of PR 24's harness (its host spans are run.py's ``bench.*``
+    wrappers): the numbers the accepted reduction gave."""
     r = trace_reduce.reduce(str(TRACE))
-    assert r["devices"] == 1 and r["frames"] == 7
-    assert r["busy_s"] == pytest.approx(0.1185, abs=1e-3)
-    assert r["window_s"] == pytest.approx(0.4014, abs=1e-3)
-    assert 0 < r["busy_s"] / r["window_s"] < 1
-    assert r["device_ops"][0][0] == "program jit_encode_p_cavlc_frame"
-    assert r["idle_gaps"][0][0] == "bench.encode_collect"
-    assert len(r["device_ops"]) <= 10 and len(r["idle_gaps"]) <= 10
+    assert r == {"devices": 1, "frames": 7, "busy_s": 0.118501651,
+                 "window_s": 0.40135692}
+    # it passes over the operations, which are nearly all of a trace
+    assert list(trace_reduce.load(str(TRACE), ops=False)["/device:TPU:0"]) \
+        == ["XLA Modules"]
+    assert "XLA Ops" in trace_reduce.load(str(TRACE))["/device:TPU:0"]
 
 
 def test_per_layer_readers_return_nothing_when_there_is_nothing_to_read():
     from benchmark.run import load_by_file
-    run = {"trace": None, "counters_start": {}, "counters_end": {},
-           "display_late_ms": [], "bytes_in_window": 2500000, "seconds": 20.0,
-           "take_gaps_ms": []}
+    run = {"trace": None, "stages": None, "counters_start": {},
+           "counters_end": {}, "display_late_ms": [], "capture_age_ms": [],
+           "bytes_in_window": 2500000, "seconds": 20.0, "take_gaps_ms": []}
     for name in ("device_ms_per_frame", "device_idle_pct", "submit_mean_ms",
                  "overflow_fallback_pct", "display_late_p95_ms",
-                 "loop_stall_max_ms"):
+                 "loop_stall_max_ms", "me_subpel_ms", "deblock_ms",
+                 "unscoped_ms", "capture_age_p50_ms"):
         assert load_by_file("layer_metrics", name).read(run) is None
     assert load_by_file("layer_metrics", "kbps").read(run) == 1000.0
     run["counters_start"] = {"dngd_encoder_submit_ms_sum": 10.0,
