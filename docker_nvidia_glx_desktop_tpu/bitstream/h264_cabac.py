@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..obs import trace as obst
 from . import h264 as syn
 from .bitwriter import BitWriter
 from .cabac import _BLK_XY, CabacEncoder, SliceCoder, _MbCtx
@@ -50,20 +51,21 @@ def _native_slices(symbol: str, table_idx: int, arrays, nr, nc_mb, qp):
     cache = getattr(_TLS, "bufs", None)
     if cache is None:
         cache = _TLS.bufs = {}
-    for scale in (1, 4):
-        cap = (2048 + nc_mb * 1536) * scale
-        key = (symbol, nr, cap)
-        out = cache.get(key)
-        if out is None:
-            if len(cache) > 8:
-                cache.clear()
-            out = cache[key] = np.empty(nr * cap, np.uint8)
-        lens = np.zeros(nr, np.int64)
-        rc = fn(*arrays, nr, nc_mb, int(qp), ctx, rng, tmps, tlps,
-                out, lens, cap)
-        if rc == 0:
-            return [out[r * cap:r * cap + lens[r]].tobytes()
-                    for r in range(nr)]
+    with obst.stage("engine"):      # binarization and engine, in C
+        for scale in (1, 4):
+            cap = (2048 + nc_mb * 1536) * scale
+            key = (symbol, nr, cap)
+            out = cache.get(key)
+            if out is None:
+                if len(cache) > 8:
+                    cache.clear()
+                out = cache[key] = np.empty(nr * cap, np.uint8)
+            lens = np.zeros(nr, np.int64)
+            rc = fn(*arrays, nr, nc_mb, int(qp), ctx, rng, tmps, tlps,
+                    out, lens, cap)
+            if rc == 0:
+                return [out[r * cap:r * cap + lens[r]].tobytes()
+                        for r in range(nr)]
     logging.getLogger(__name__).warning(
         "native CABAC row overflow at 4x cap; falling back to the "
         "Python coder for this picture")
@@ -160,7 +162,8 @@ def encode_intra_from_binstream(buf: np.ndarray, *, nr: int, nc_mb: int,
                                 deblocking_idc: int = 1):
     """IDR access unit from a device-binarized record stream, or None
     when the transport flagged overflow (caller re-encodes dense)."""
-    payloads = _engine_rows(buf, nr, nc_mb, 0, qp)
+    with obst.stage("engine"):
+        payloads = _engine_rows(buf, nr, nc_mb, 0, qp)
     if payloads is None:
         return None
     out = bytearray()
@@ -184,7 +187,8 @@ def encode_p_from_binstream(buf: np.ndarray, *, nr: int, nc_mb: int,
                             cabac_init_idc: int = 0):
     """P access unit from a device-binarized record stream, or None on
     the transport overflow flag."""
-    payloads = _engine_rows(buf, nr, nc_mb, 1 + cabac_init_idc, qp)
+    with obst.stage("engine"):
+        payloads = _engine_rows(buf, nr, nc_mb, 1 + cabac_init_idc, qp)
     if payloads is None:
         return None
     out = bytearray()

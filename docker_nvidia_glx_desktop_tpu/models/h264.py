@@ -29,19 +29,35 @@ from ..obs.profile import PROFILER
 from ..ops import color
 from ..utils.mathutil import round_up
 from .base import EncodedFrame, Encoder
+from .prefix_pull import M_PULL_EXTRA as _M_PULL_EXTRA
+from .prefix_pull import PrefixPull, prefetch_host as _prefetch_host
 
 log = logging.getLogger(__name__)
 
-_M_PULL_EXTRA = obsm.counter(
-    "dngd_encoder_pull_extra_total",
-    "Frames whose bitstream outgrew the guessed pull prefix and paid a "
-    "second device->host round trip (the first time a length is seen "
-    "it is also a compile of the slice)")
 _M_ENTROPY_OVERFLOW = obsm.counter(
     "dngd_encoder_entropy_overflow_total",
     "Frames whose device CAVLC buffer overflowed and were entropy-coded "
     "on the host instead (pathological content; a device coder that "
     "gives way on every frame is not serving from the device)")
+_M_CABAC_FALLBACK = obsm.counter(
+    "dngd_encoder_cabac_fallback_total",
+    "CABAC frames the native engine did not code from the transport "
+    "buffer: dense = the packed stream or the engine's cap overflowed and "
+    "the level tensors were pulled whole; python = no native engine was "
+    "built and the Python coder (about 100x slower) coded the frame",
+    ("kind",))
+_M_CABAC_DENSE = _M_CABAC_FALLBACK.labels("dense")
+_M_CABAC_PYTHON = _M_CABAC_FALLBACK.labels("python")
+
+
+def _note_cabac_dense() -> None:
+    """A CABAC frame's transport or engine cap overflowed and its level
+    tensors were pulled whole: counted, and said."""
+    _M_CABAC_DENSE.inc()
+    n = int(_M_CABAC_DENSE.value)
+    if n == 1 or n % 100 == 0:
+        log.warning("CABAC transport overflow: level tensors pulled "
+                    "dense for this frame (%d so far)", n)
 
 
 def _note_entropy_overflow(what: str) -> None:
@@ -251,14 +267,13 @@ def _stack_luma(rgbs, pad_h: int, pad_w: int):
     return jax.vmap(lambda f: _yuv_stage(f, pad_h, pad_w)[0])(rgbs)
 
 
-def _prefetch_host(arr) -> None:
-    """Start the device->host copy of a pull-prefix at SUBMIT time.
-
-    The pipelined serving loop collects frames with a synchronous
-    ``np.asarray`` — one host<->device round-trip per frame.
-    ``copy_to_host_async`` lets the pulls of in-flight frames overlap
-    each other and the next frame's dispatch."""
-    arr.copy_to_host_async()
+@jax.jit
+@jax.named_scope("dngd.deblock_bs")
+def _cabac_bs_inputs(luma, mv):
+    """The loop filter's bS inputs from the P stage's levels, as ONE
+    device program (the CAVLC program computes them inside itself)."""
+    from ..ops.h264_device import nnz_blocks_raster
+    return nnz_blocks_raster(luma), mv.astype(jnp.int32)
 
 
 def _mb_tiles(plane: np.ndarray, size: int) -> np.ndarray:
@@ -411,8 +426,26 @@ class H264Encoder(Encoder):
             # a misconfigured deployment dies at startup instead of going
             # unhealthy frame-by-frame inside the serving loop.
             from ..bitstream import cabac_tables
+            from ..native import lib as native_lib
+            from ..ops import level_pack
             cabac_tables.engine_tables()
             cabac_tables.context_init_tables()
+            # one pull helper a kind of frame, for either transport
+            hdrw = level_pack.header_words(self.mb_h)
+            self._cabac_pull = {"intra": PrefixPull(hdrw, 8),
+                                "p": PrefixPull(hdrw, 4)}
+            # never silently: without the compiled engine every frame is
+            # coded by the Python one, and counted as such
+            self._cabac_native = (native_lib.has_cabac_engine()
+                                  if self.cabac_device_binarize
+                                  else native_lib.has_cabac())
+            if not self._cabac_native:
+                log.error(
+                    "ENCODER_ENTROPY=cabac without the native engine (no "
+                    "g++, or native/cabac.cpp did not build): every frame "
+                    "is coded by the Python engine, about 100x slower; "
+                    "counted in dngd_encoder_cabac_fallback_total"
+                    "{kind=\"python\"}")
         self._sps = syn.sps_rbsp(width, height,
                                  profile="main" if cabac else "baseline")
         self._pps = syn.pps_rbsp(init_qp=qp, cabac=cabac)
@@ -873,9 +906,7 @@ class H264Encoder(Encoder):
             # are per-shard; the global-reduce stats stay exact)
             self._content_submit(y, frame_type="intra")
             hdrw = cabac_binarize.header_words(self._sp_rows_local())
-            guess = getattr(self, "_cabac_bin_pull_guess",
-                            8 * self._CABAC_PULL_WORDS)
-            prefix = buf[:, :hdrw + guess]
+            prefix = buf[:, :hdrw + self._cabac_pull["intra"].guess]
             _prefetch_host(prefix)
             return ("sp_bin", "intra", qp, idr_pic_id, 0, buf, prefix,
                     lv)
@@ -906,9 +937,7 @@ class H264Encoder(Encoder):
             self._count_dispatch(t0)
             self._content_submit(y)
             hdrw = cabac_binarize.header_words(self._sp_rows_local())
-            guess = getattr(self, "_cabac_p_bin_pull_guess",
-                            4 * self._CABAC_PULL_WORDS)
-            prefix = buf[:, :hdrw + guess]
+            prefix = buf[:, :hdrw + self._cabac_pull["p"].guess]
             _prefetch_host(prefix)
             return ("sp_bin", "p", qp, 0, frame_num, buf, prefix,
                     (lv, mv))
@@ -1015,15 +1044,10 @@ class H264Encoder(Encoder):
         hdrw = cabac_binarize.header_words(rows_l)
         heads = np.asarray(prefix)                # (nx, hdrw + guess)
         t0 = time.perf_counter()
-        hist_attr = ("_cabac_bin_pull_hist" if kind == "intra"
-                     else "_cabac_p_bin_pull_hist")
-        hist = getattr(self, hist_attr, None)
-        if hist is None:
-            import collections as _c
-            hist = _c.deque(maxlen=8)
-            setattr(self, hist_attr, hist)
-        bucket = self._CABAC_PULL_WORDS
-        shard_bufs = []
+        pull = self._cabac_pull[kind]      # guess and history; the shards
+        shard_bufs = []                    # are pulled here, side by side
+        if not self._cabac_native:
+            _M_CABAC_PYTHON.inc()
         overflow = False
         need_max = 0
         for i in range(len(heads)):
@@ -1034,14 +1058,11 @@ class H264Encoder(Encoder):
             total = cabac_binarize.payload_words(head)
             need_max = max(need_max, total)
             if hdrw + total > head.shape[0]:
-                extra = -(-total // bucket) * bucket
-                head = np.asarray(buf[i, :hdrw + extra])
+                head = np.asarray(buf[i, :hdrw + pull.rung(total)])
             shard_bufs.append(head)
         au = None
         if not overflow:
-            hist.append(need_max)
-            setattr(self, hist_attr.replace("_hist", "_guess"),
-                    -(-max(hist) // bucket) * bucket)
+            pull.note(need_max)
             stitched = cabac_binarize.stitch_rows(shard_bufs, rows_l)
             if kind == "intra":
                 au = h264_cabac.encode_intra_from_binstream(
@@ -1060,6 +1081,7 @@ class H264Encoder(Encoder):
             return au
         # overflow (packed stream or engine cap): dense fallback from
         # the sharded stage's own level tensors, gathered lazily
+        _note_cabac_dense()
         if kind == "intra":
             lv = lv_mv
             dense = {k: np.asarray(lv[k])
@@ -1172,14 +1194,14 @@ class H264Encoder(Encoder):
 
     @property
     def _dyn_qp(self) -> bool:
-        """The per-frame device-CAVLC path at tune=off runs ONE compiled
-        program set for every qp (qp is a traced scalar there): nothing
-        is qp-specialized, so the rate ladder and the degrade bias move
-        freely on a cold cache and there is no ladder to prewarm.  The
-        hq tiers keep qp static (their lambda decisions are
-        compile-time floats)."""
+        """The per-frame device-CAVLC and CABAC paths at tune=off run ONE
+        compiled program set for every qp (qp is a traced scalar there):
+        nothing is qp-specialized, so the rate ladder and the degrade
+        bias move freely on a cold cache and there is no ladder to
+        prewarm.  The hq tiers keep qp static (their lambda decisions
+        are compile-time floats)."""
         return (self._ktune == "off" and self.mode == "cavlc"
-                and self.entropy == "device")
+                and self.entropy in ("device", "cabac"))
 
     def _deblock(self, y, cb, cr, qp: int, **bs_inputs):
         """In-loop filter of the per-frame device path (qp traced where
@@ -1274,6 +1296,38 @@ class H264Encoder(Encoder):
         log.info("qp-ladder prewarm: %d/%d qps in %.1f s", done, len(qps),
                  time.perf_counter() - t0)
         return done
+
+    def warm_pulls(self) -> int:
+        """Compile every prefix slice the per-frame CABAC path's two
+        pulls can meet, up to the transport buffer's whole length (past
+        it the stream's overflow flag is up and the levels go dense): one
+        IDR and one P frame through a scratch encoder, whose programs
+        and slices land in the process-wide jit cache this encoder
+        shares.  For codec set-up (web/session.py under ENCODER_PREWARM),
+        BEFORE frames are served: it compiles the path's programs too,
+        and compiling beside the serving thread is what the installed
+        libtpu does not survive (:meth:`prewarm`).  Returns the slices
+        compiled; 0 on every other path (the CAVLC pull ladder is
+        content's to walk: 64 KiB steps of a 46 KB frame)."""
+        if (self.entropy != "cabac" or self.mode != "cavlc"
+                or self._spatial_nx > 1):
+            return 0
+        t0 = time.perf_counter()
+        scratch = H264Encoder(
+            self.width, self.height, qp=self.qp, mode=self.mode,
+            entropy=self.entropy, host_color=self.host_color, gop=2,
+            deblock=self.deblock, intra_modes=self.i16_modes,
+            superstep_chunk=0, spatial_shards=1, tune=self.tune,
+            damage_mask=False)
+        rgb = np.zeros((self.height, self.width, 3), np.uint8)
+        buf = scratch._submit_cabac_intra(rgb, 0)[1]
+        n = scratch._cabac_pull["intra"].warm(buf)
+        buf = scratch._submit_cabac_p(
+            *scratch._planes_device(rgb), self.qp)[3]
+        n += scratch._cabac_pull["p"].warm(buf)
+        log.info("CABAC pull ladder: %d slices of %d-word buffers in "
+                 "%.1f s", n, buf.shape[0], time.perf_counter() - t0)
+        return n
 
     def prewarm_async(self, qps=None):
         """Run :meth:`prewarm` in a daemon thread; returns (thread,
@@ -1411,19 +1465,19 @@ class H264Encoder(Encoder):
     # the device stage under the host entropy stage.
     # ------------------------------------------------------------------
 
-    _CABAC_PULL_WORDS = 1 << 14          # pull-guess bucket, in words
-
     @property
     def cabac_device_binarize(self) -> bool:
         """Device-side binarization + ctxIdx derivation (round 6): the
         device emits the packed (bin, ctxIdx, bypass) record stream
         (ops/cabac_binarize) and the host runs only the arithmetic
-        engine.  Opt-in via ENCODER_CABAC_BINARIZE=device (the record
-        stream's wide slot graph is a long XLA compile on the CPU
-        fallback backend, so the round-5 split — level_pack transport +
-        full host coder — stays the default until first use is warmed).
+        engine.  Opt-in via ENCODER_CABAC_BINARIZE=device, which is what
+        the benchmark's ``desk1080-cabac`` serves; the round-5 split —
+        level_pack transport + full host coder — stays the default: on a
+        v5e at 1080p the binarize program is 23 ms of the chip a frame
+        and ``device`` the slower of the two on a desktop (PERF.md PR 28).
         Either path emits byte-identical streams (tested); an overflow
-        in the packed stream falls back dense per-frame."""
+        in the packed stream falls back dense per-frame, and is
+        counted."""
         v = getattr(self, "_cabac_dev_bin", None)
         if v is None:
             import os
@@ -1475,110 +1529,59 @@ class H264Encoder(Encoder):
 
         if self._spatial_nx > 1:
             return self._sp_submit_intra(rgb, idr_pic_id)
-        t0 = time.perf_counter()
         qp = self._eff_qp()
-        planes = self._host_yuv420(rgb) if self.host_color else None
-        if planes is not None:
-            levels = h264_device.encode_intra_frame_yuv(
-                jnp.asarray(planes[0]), jnp.asarray(planes[1]),
-                jnp.asarray(planes[2]), qp, i16_modes=self.i16_modes,
-                tune=self._ktune)
-        else:
-            levels = h264_device.encode_intra_frame(
-                jnp.asarray(rgb), self.pad_h, self.pad_w, qp,
-                i16_modes=self.i16_modes, tune=self._ktune)
-        if self.gop > 1:
-            # advance the reference at submit time (device futures), same
-            # contract as the device-CAVLC path
-            recon3 = (levels["recon_y"], levels["recon_cb"],
-                      levels["recon_cr"])
-            if self.deblock:
-                from ..ops import h264_deblock
-                recon3 = h264_deblock.deblock_frame(*recon3, qp)
-            self._ref = recon3
-        self._count_dispatch(t0)
-        self._content_submit(
-            jnp.asarray(planes[0]) if planes is not None
-            else _yuv_stage(jnp.asarray(rgb), self.pad_h, self.pad_w)[0],
-            recon_y=levels.get("recon_y"), frame_type="intra")
-        if self.keep_recon and self.gop > 1:
-            # pull NOW: with deblock off these recon planes become the
-            # next P submit's DONATED refs — dead by collect time
-            levels = dict(levels)
-            for k in ("recon_y", "recon_cb", "recon_cr"):
-                levels[k] = np.asarray(levels[k])
-        if self.cabac_device_binarize:
-            buf = cabac_binarize.binarize_intra(
-                levels["luma_dc"], levels["luma_ac"], levels["cb_dc"],
-                levels["cb_ac"], levels["cr_dc"], levels["cr_ac"],
-                levels["pred_mode"], levels["mb_i4"],
-                levels["i4_modes"], levels["luma_i4"])
-            guess = getattr(self, "_cabac_bin_pull_guess",
-                            8 * self._CABAC_PULL_WORDS)
-            prefix = buf[:cabac_binarize.header_words(self.mb_h) + guess]
-            _prefetch_host(prefix)
-            return ("bin", levels, buf, prefix, None, qp, idr_pic_id)
-        buf = level_pack.pack_levels(levels, level_pack.INTRA_KEYS)
-        small = {k: levels[k].astype(jnp.int8)
-                 for k in ("pred_mode", "mb_i4", "i4_modes")}
-        if "qp_map" in levels:           # tune=hq: per-MB qp (<= 51)
-            small["qp_map"] = levels["qp_map"].astype(jnp.int8)
-        guess = getattr(self, "_cabac_pull_guess",
-                        8 * self._CABAC_PULL_WORDS)
-        prefix = buf[:level_pack.header_words(self.mb_h) + guess]
-        _prefetch_host(prefix)
-        for v in small.values():
-            _prefetch_host(v)
-        return ("lv", levels, buf, prefix, small, qp, idr_pic_id)
-
-    def _pull_packed(self, buf, prefix, keys, hist_attr: str):
-        """Pull the packed transport prefix, re-pulling on a short read;
-        returns dense level arrays or None on value overflow."""
-        from ..ops import level_pack
-
-        hdrw = level_pack.header_words(self.mb_h)
-        head = np.asarray(prefix)
-        if head[1]:
-            return None
-        total = level_pack.payload_words(head)
-        hist = getattr(self, hist_attr, None)
-        if hist is None:
-            import collections as _c
-            hist = _c.deque(maxlen=8)
-            setattr(self, hist_attr, hist)
-        bucket = self._CABAC_PULL_WORDS
-        hist.append(total)
-        guess = -(-max(hist) // bucket) * bucket
-        setattr(self, hist_attr.replace("_hist", "_guess"), guess)
-        if hdrw + total > len(head):
-            extra = -(-total // bucket) * bucket
-            head = np.asarray(buf[:hdrw + extra])
-        return level_pack.unpack_levels(head, self.mb_h, self.mb_w, keys)
-
-    def _pull_binstream(self, buf, prefix, hist_attr: str):
-        """Pull a cabac_binarize transport prefix (decaying-max guess,
-        re-pull on short read); returns the host buffer or None on the
-        overflow flag."""
-        from ..ops import cabac_binarize
-
-        hdrw = cabac_binarize.header_words(self.mb_h)
-        head = np.asarray(prefix)
-        if head[1]:
-            return None
-        total = cabac_binarize.payload_words(head)
-        hist = getattr(self, hist_attr, None)
-        if hist is None:
-            import collections as _c
-            hist = _c.deque(maxlen=8)
-            setattr(self, hist_attr, hist)
-        bucket = self._CABAC_PULL_WORDS
-        hist.append(total)
-        guess = -(-max(hist) // bucket) * bucket
-        setattr(self, hist_attr.replace("_hist", "_guess"), guess)
-        if hdrw + total > len(head):
-            extra = -(-total // bucket) * bucket
-            head = np.asarray(buf[:hdrw + extra])
-        return head
+        with obst.stage("colour"):
+            planes = self._host_yuv420(rgb) if self.host_color else None
+        with obst.stage("dispatch") as span:
+            if planes is not None and self._dyn_qp:
+                levels = h264_device.encode_intra_frame_yuv_dynqp(
+                    *planes, np.int32(qp), i16_modes=self.i16_modes,
+                    tune="off")
+            elif planes is not None:
+                levels = h264_device.encode_intra_frame_yuv(
+                    *planes, qp, i16_modes=self.i16_modes,
+                    tune=self._ktune)
+            else:
+                levels = h264_device.encode_intra_frame(
+                    jnp.asarray(rgb), self.pad_h, self.pad_w, qp,
+                    i16_modes=self.i16_modes, tune=self._ktune)
+            if self.gop > 1:
+                # advance the reference at submit time (device futures),
+                # same contract as the device-CAVLC path
+                recon3 = (levels["recon_y"], levels["recon_cb"],
+                          levels["recon_cr"])
+                self._ref = (self._deblock(*recon3, qp) if self.deblock
+                             else recon3)
+            self._content_submit(
+                planes[0] if planes is not None
+                else _yuv_stage(jnp.asarray(rgb), self.pad_h, self.pad_w)[0],
+                recon_y=levels.get("recon_y"), frame_type="intra")
+            if self.keep_recon and self.gop > 1:
+                # pull NOW: with deblock off these recon planes become the
+                # next P submit's DONATED refs — dead by collect time
+                levels = dict(levels)
+                for k in ("recon_y", "recon_cb", "recon_cr"):
+                    levels[k] = np.asarray(levels[k])
+            # the int8 mode planes ride beside the packed levels; the
+            # record stream carries them itself
+            small = None
+            if self.cabac_device_binarize:
+                buf = cabac_binarize.binarize_intra(
+                    levels["luma_dc"], levels["luma_ac"], levels["cb_dc"],
+                    levels["cb_ac"], levels["cr_dc"], levels["cr_ac"],
+                    levels["pred_mode"], levels["mb_i4"],
+                    levels["i4_modes"], levels["luma_i4"])
+            else:
+                buf = level_pack.pack_levels(levels, level_pack.INTRA_KEYS)
+                small = {k: levels[k].astype(jnp.int8)
+                         for k in ("pred_mode", "mb_i4", "i4_modes")}
+                if "qp_map" in levels:       # tune=hq: per-MB qp (<= 51)
+                    small["qp_map"] = levels["qp_map"].astype(jnp.int8)
+                for v in small.values():
+                    _prefetch_host(v)
+            prefix = self._cabac_pull["intra"].prefix(buf)
+        self._count_dispatch(ms=span.ms)
+        return (levels, buf, prefix, small, qp, idr_pic_id)
 
     def _collect_cabac_intra(self, submitted) -> bytes:
         from ..bitstream import h264_cabac
@@ -1586,45 +1589,42 @@ class H264Encoder(Encoder):
 
         if submitted[0] in ("sp", "sp_bin"):
             return self._sp_collect(submitted)
-        kind, levels, buf, prefix, small, qp, idr_pic_id = submitted
+        levels, buf, prefix, small, qp, idr_pic_id = submitted
         if self.keep_recon:
             self.last_recon = tuple(
                 np.asarray(levels[k])
                 for k in ("recon_y", "recon_cb", "recon_cr"))
-        if kind == "bin":
-            head = self._pull_binstream(buf, prefix,
-                                        "_cabac_bin_pull_hist")
-            if head is not None:
+        if not self._cabac_native:
+            _M_CABAC_PYTHON.inc()
+        head = self._cabac_pull["intra"].pull(buf, prefix)
+        with obst.stage("assemble", more=True):
+            hdr = dict(qp=qp, frame_num=0, idr_pic_id=idr_pic_id,
+                       sps=self._sps, pps=self._pps, with_headers=True,
+                       qp_delta=qp - self.qp,
+                       deblocking_idc=self._deblock_idc)
+            dense = None
+            if head is not None and small is None:
                 au = h264_cabac.encode_intra_from_binstream(
-                    head, nr=self.mb_h, nc_mb=self.mb_w, qp=qp,
-                    frame_num=0, idr_pic_id=idr_pic_id, sps=self._sps,
-                    pps=self._pps, with_headers=True,
-                    qp_delta=qp - self.qp,
-                    deblocking_idc=self._deblock_idc)
+                    head, nr=self.mb_h, nc_mb=self.mb_w, **hdr)
                 if au is not None:
                     return au
-            # overflow (packed stream or engine cap): dense fallback
-            dense = {k: np.asarray(levels[k])
-                     for k, _, _ in level_pack.INTRA_KEYS}
-            dense.update({k: np.asarray(levels[k])
-                          for k in ("pred_mode", "mb_i4", "i4_modes")})
-        else:
-            dense = self._pull_packed(buf, prefix, level_pack.INTRA_KEYS,
-                                      "_cabac_pull_hist")
-            if dense is None:        # value overflow: dense fallback
+            elif head is not None:
+                dense = level_pack.unpack_levels(
+                    head, self.mb_h, self.mb_w, level_pack.INTRA_KEYS)
+            if dense is None:    # the stream's flag or the engine's cap
+                _note_cabac_dense()
                 dense = {k: np.asarray(levels[k])
                          for k, _, _ in level_pack.INTRA_KEYS}
-            dense.update({k: np.asarray(v) for k, v in small.items()})
-        qp_map = dense.pop("qp_map", None)
-        if qp_map is not None:
-            qp_map = qp_map.astype(np.int32)
-            self._note_qp_map(qp_map, levels=dense, slice_qp=qp,
-                              intra=True)
-        return h264_cabac.encode_intra_picture(
-            dense, qp=qp, frame_num=0, idr_pic_id=idr_pic_id,
-            sps=self._sps, pps=self._pps, with_headers=True,
-            qp_delta=qp - self.qp, deblocking_idc=self._deblock_idc,
-            qp_map=qp_map)
+            modes = small if small is not None else {
+                k: levels[k] for k in ("pred_mode", "mb_i4", "i4_modes")}
+            dense.update({k: np.asarray(v) for k, v in modes.items()})
+            qp_map = dense.pop("qp_map", None)
+            if qp_map is not None:
+                qp_map = qp_map.astype(np.int32)
+                self._note_qp_map(qp_map, levels=dense, slice_qp=qp,
+                                  intra=True)
+            return h264_cabac.encode_intra_picture(dense, **hdr,
+                                                   qp_map=qp_map)
 
     def _submit_cabac_p(self, y, cb, cr, qp: int, frame_num: int = None,
                         next_y=None):
@@ -1632,54 +1632,47 @@ class H264Encoder(Encoder):
 
         if self._spatial_nx > 1:
             return self._sp_submit_p(y, cb, cr, qp, frame_num)
-        t0 = time.perf_counter()
-        frame_num = self._frame_num if frame_num is None else frame_num
-        # self._ref is DONATED to the inter stage (recon aliases its
-        # buffers — ops/h264_inter ring contract): dead past this call
-        out = h264_inter.encode_p_frame(
-            jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr), *self._ref,
-            qp=qp, tune=self._ktune, next_y=next_y)
-        recon = (out["recon_y"], out["recon_cb"], out["recon_cr"])
-        if self.deblock:
-            from ..ops import h264_deblock
-            from ..ops.h264_device import nnz_blocks_raster
-            # nnz per 4x4 block, raster order, computed ON DEVICE (the
-            # host variant in _encode_p_host forces a sync at submit)
-            self._ref = h264_deblock.deblock_frame(
-                *recon, qp, nnz_blk=nnz_blocks_raster(out["luma"]),
-                mv=out["mv"].astype(jnp.int32))
-        else:
-            self._ref = recon
-        self._count_dispatch(t0)
-        self._content_submit(
-            jnp.asarray(y), recon_y=out["recon_y"], mv=out["mv"],
-            resid=(out["luma"], out["cb_dc"], out["cb_ac"],
-                   out["cr_dc"], out["cr_ac"]),
-            mb_intra=out.get("mb_intra"))
-        if self.keep_recon:
-            # pull NOW: with deblock off these arrays are the next
-            # submit's donated refs — dead by collect time in a pipeline
-            recon = tuple(np.asarray(p) for p in recon)
-        mv = out["mv"]                       # already int8
-        if self.cabac_device_binarize:
-            buf = cabac_binarize.binarize_p(
-                out["mv"], out["luma"], out["cb_dc"], out["cb_ac"],
-                out["cr_dc"], out["cr_ac"])
-            guess = getattr(self, "_cabac_p_bin_pull_guess",
-                            4 * self._CABAC_PULL_WORDS)
-            prefix = buf[:cabac_binarize.header_words(self.mb_h)
-                         + guess]
-            _prefetch_host(prefix)
+        with obst.stage("dispatch") as span:
+            frame_num = self._frame_num if frame_num is None else frame_num
+            # self._ref is DONATED to the inter stage (recon aliases its
+            # buffers — ops/h264_inter ring contract): dead past this call
+            if self._dyn_qp:   # tune=off: no lookahead luma, no I16-in-P
+                out = h264_inter.encode_p_frame_dynqp(
+                    jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr),
+                    *self._ref, np.int32(qp), tune="off")
+            else:
+                out = h264_inter.encode_p_frame(
+                    jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr),
+                    *self._ref, qp=qp, tune=self._ktune, next_y=next_y)
+            recon = (out["recon_y"], out["recon_cb"], out["recon_cr"])
+            mv = out["mv"]                       # already int8
+            if self.deblock:
+                nnz, mv32 = _cabac_bs_inputs(out["luma"], mv)
+                self._ref = self._deblock(*recon, qp, nnz_blk=nnz, mv=mv32)
+            else:
+                self._ref = recon
+            self._content_submit(
+                jnp.asarray(y), recon_y=out["recon_y"], mv=mv,
+                resid=(out["luma"], out["cb_dc"], out["cb_ac"],
+                       out["cr_dc"], out["cr_ac"]),
+                mb_intra=out.get("mb_intra"))
             if self.keep_recon:
+                # pull NOW: with deblock off these arrays are the next
+                # submit's donated refs — dead by collect time in a
+                # pipeline
+                recon = tuple(np.asarray(p) for p in recon)
+            binarized = self.cabac_device_binarize
+            if binarized:
+                buf = cabac_binarize.binarize_p(
+                    mv, out["luma"], out["cb_dc"], out["cb_ac"],
+                    out["cr_dc"], out["cr_ac"])
+            else:
+                buf = level_pack.pack_levels(out, level_pack.P_KEYS)
+            prefix = self._cabac_pull["p"].prefix(buf)
+            if self.keep_recon or not binarized:
                 _prefetch_host(mv)
-            return ("bin", out, recon, buf, prefix, mv, qp, frame_num)
-        buf = level_pack.pack_levels(out, level_pack.P_KEYS)
-        guess = getattr(self, "_cabac_p_pull_guess",
-                        4 * self._CABAC_PULL_WORDS)
-        prefix = buf[:level_pack.header_words(self.mb_h) + guess]
-        _prefetch_host(prefix)
-        _prefetch_host(mv)
-        return ("lv", out, recon, buf, prefix, mv, qp, frame_num)
+        self._count_dispatch(ms=span.ms)
+        return (binarized, out, recon, buf, prefix, mv, qp, frame_num)
 
     def _collect_cabac_p(self, submitted) -> bytes:
         from ..bitstream import h264_cabac
@@ -1687,35 +1680,34 @@ class H264Encoder(Encoder):
 
         if submitted[0] in ("sp", "sp_bin"):
             return self._sp_collect(submitted)
-        kind, out, recon, buf, prefix, mv, qp, frame_num = submitted
+        binarized, out, recon, buf, prefix, mv, qp, frame_num = submitted
         if self.keep_recon:
             self.last_recon = tuple(np.asarray(p) for p in recon)
             self.last_mv = np.asarray(mv, np.int32)
-        if kind == "bin":
-            head = self._pull_binstream(buf, prefix,
-                                        "_cabac_p_bin_pull_hist")
-            if head is not None:
+        if not self._cabac_native:
+            _M_CABAC_PYTHON.inc()
+        head = self._cabac_pull["p"].pull(buf, prefix)
+        with obst.stage("assemble", more=True):
+            hdr = dict(qp=qp, frame_num=frame_num, qp_delta=qp - self.qp,
+                       deblocking_idc=self._deblock_idc)
+            dense = None
+            if head is not None and binarized:
                 au = h264_cabac.encode_p_from_binstream(
-                    head, nr=self.mb_h, nc_mb=self.mb_w, qp=qp,
-                    frame_num=frame_num, qp_delta=qp - self.qp,
-                    deblocking_idc=self._deblock_idc)
+                    head, nr=self.mb_h, nc_mb=self.mb_w, **hdr)
                 if au is not None:
                     return au
-            dense = {k: np.asarray(out[k])
-                     for k, _, _ in level_pack.P_KEYS}
-        else:
-            dense = self._pull_packed(buf, prefix, level_pack.P_KEYS,
-                                      "_cabac_p_pull_hist")
-            if dense is None:
+            elif head is not None:
+                dense = level_pack.unpack_levels(
+                    head, self.mb_h, self.mb_w, level_pack.P_KEYS)
+            if dense is None:    # the stream's flag or the engine's cap
+                _note_cabac_dense()
                 dense = {k: np.asarray(out[k])
                          for k, _, _ in level_pack.P_KEYS}
-        dense["mv"] = np.asarray(mv, np.int32)
-        qp_map = (np.asarray(out["qp_map"]) if "qp_map" in out
-                  else None)
-        self._note_qp_map(qp_map, levels=dense, slice_qp=qp)
-        return h264_cabac.encode_p_picture(
-            dense, qp=qp, frame_num=frame_num, qp_delta=qp - self.qp,
-            deblocking_idc=self._deblock_idc, qp_map=qp_map)
+            dense["mv"] = np.asarray(mv, np.int32)
+            qp_map = (np.asarray(out["qp_map"]) if "qp_map" in out
+                      else None)
+            self._note_qp_map(qp_map, levels=dense, slice_qp=qp)
+            return h264_cabac.encode_p_picture(dense, **hdr, qp_map=qp_map)
 
     def _encode_host_entropy(self, rgb, idr_pic_id: int,
                              prefer_native: bool = None,
@@ -2293,9 +2285,7 @@ class H264Encoder(Encoder):
             rows = (self._sp_rows_local() if self._spatial_nx > 1
                     else self.mb_h)
             hdrw = cabac_binarize.header_words(rows)
-            guess = getattr(self, "_cabac_p_bin_pull_guess",
-                            4 * self._CABAC_PULL_WORDS)
-            plen = hdrw + guess
+            plen = hdrw + self._cabac_pull["p"].guess
             hdrs = ()
         # damage-masked chunk: shared row bucket = the worst frame's
         # rung (a shared static bucket keeps ONE compile per rung; the
@@ -2534,9 +2524,10 @@ class H264Encoder(Encoder):
                                         flats[slot], head,
                                         (lv, mvs[slot]))
         # same pull-guess/short-read/overflow protocol as the per-frame
-        # path — ONE implementation, shared hist/guess attributes
-        head = self._pull_binstream(flats[slot], head,
-                                    "_cabac_p_bin_pull_hist")
+        # path — ONE implementation, the P frames' pull helper
+        if not self._cabac_native:
+            _M_CABAC_PYTHON.inc()
+        head = self._cabac_pull["p"].pull(flats[slot], head)
         if head is not None:
             au = h264_cabac.encode_p_from_binstream(
                 head, nr=self.mb_h, nc_mb=self.mb_w, qp=qp,
@@ -2546,6 +2537,7 @@ class H264Encoder(Encoder):
                 return au
         # packed-stream or engine overflow: dense fallback from the
         # chunk's level tensors (same contract as _collect_cabac_p)
+        _note_cabac_dense()
         dense = {k: np.asarray(v[slot]) for k, v in lvs.items()}
         dense["mv"] = np.asarray(mvs[slot], np.int32)
         return h264_cabac.encode_p_picture(

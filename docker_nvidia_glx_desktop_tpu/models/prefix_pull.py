@@ -1,0 +1,103 @@
+"""The host's pull of a device buffer whose length only the device knows:
+a guessed prefix, copied while the next frame is dispatched, and a second
+pull where the guess was short (models/h264.py)."""
+
+from __future__ import annotations
+
+import collections
+
+import numpy as np
+
+from ..obs import metrics as obsm
+from ..obs import trace as obst
+
+M_PULL_EXTRA = obsm.counter(
+    "dngd_encoder_pull_extra_total",
+    "Frames whose bitstream outgrew the guessed pull prefix and paid a "
+    "second device->host round trip (the first time a length is seen "
+    "it is also a compile of the slice)")
+M_CABAC_RECORD_BYTES = obsm.counter(
+    "dngd_encoder_cabac_record_bytes_total",
+    "Bytes of CABAC transport the host pulled for its engine: header and "
+    "payload of the binarize record stream (of the packed levels under "
+    "ENCODER_CABAC_BINARIZE=host), without the slack of the guessed prefix")
+
+
+def prefetch_host(arr) -> None:
+    """Start the device->host copy of a pull-prefix at SUBMIT time.
+
+    The pipelined serving loop collects frames with a synchronous
+    ``np.asarray`` — one host<->device round-trip per frame.
+    ``copy_to_host_async`` lets the pulls of in-flight frames overlap
+    each other and the next frame's dispatch."""
+    arr.copy_to_host_async()
+
+
+class PrefixPull:
+    """The host's pull of one kind of CABAC transport buffer
+    (ops/cabac_binarize and ops/level_pack share the layout: ``hdrw``
+    header words, [1] the overflow flag and [2] the payload's words,
+    then the payload).
+
+    What is pulled is the header and a GUESS of the payload: the
+    decaying max of the last 64 frames' needs (a second or two: content
+    whose size hovers round a rung would otherwise mispredict every few
+    frames, and a mispredict is a second device round trip, behind the
+    next frame's programs where the device sets the pace), rounded up to
+    a rung.
+    Rungs are multiples of 64 KiB with two significant bits (1, 2, 3,
+    4, 6, 8, 12, 16, 24 ...): every rung is one compiled slice, a
+    quarter of a second in the serving thread when first met (PERF.md
+    PR 24 finding 3), and a 1080p record buffer is 33 MiB, 19 rungs
+    where a linear ladder has 521.  :meth:`warm` compiles them all."""
+
+    BUCKET = 1 << 14                       # words: 64 KiB
+
+    def __init__(self, hdrw: int, buckets: int):
+        self.hdrw = hdrw
+        self.guess = buckets * self.BUCKET
+        self.hist = collections.deque(maxlen=64)
+
+    @classmethod
+    def rung(cls, words: int) -> int:
+        b = max(-(-words // cls.BUCKET), 1)
+        step = 1 << max(b.bit_length() - 2, 0)
+        return -(-b // step) * step * cls.BUCKET
+
+    def note(self, words: int) -> None:
+        """One frame's payload: the next guess covers it."""
+        self.hist.append(words)
+        self.guess = self.rung(max(self.hist))
+
+    def prefix(self, buf):
+        """Slice the guessed prefix off ``buf`` and start its copy to
+        the host (at submit time)."""
+        head = buf[:self.hdrw + self.guess]
+        prefetch_host(head)
+        return head
+
+    def warm(self, buf) -> int:
+        """Compile the slice of every rung ``buf`` can meet (up to its
+        whole length); returns how many."""
+        words = n = 0
+        while self.hdrw + words < buf.shape[0]:
+            words = self.rung(words + 1)
+            buf[:self.hdrw + words].block_until_ready()
+            n += 1
+        return n
+
+    def pull(self, buf, prefix):
+        """The host copy of header and payload, pulled again where the
+        guess was short; None on the overflow flag."""
+        with obst.stage("pull"):
+            head = np.asarray(prefix)
+        if head[1]:
+            return None
+        words = int(head[2])
+        self.note(words)
+        if self.hdrw + words > len(head):
+            M_PULL_EXTRA.inc()
+            with obst.stage("pull_extra"):
+                head = np.asarray(buf[:self.hdrw + self.rung(words)])
+        M_CABAC_RECORD_BYTES.inc(4 * (self.hdrw + words))
+        return head

@@ -96,6 +96,9 @@ STAGE_BUCKETS_MS: Tuple[float, ...] = (
 # registered at import, so each family renders from the first scrape.
 STAGES = ("capture", "encode_submit", "encode_collect", "colour",
           "dispatch", "pull", "pull_extra", "assemble")
+# The CABAC path's own, inside ``assemble``: the host arithmetic engine
+# (bitstream/h264_cabac.py).
+CABAC_STAGES = ("engine",)
 
 _stage_defs: Dict[str, tuple] = {}     # name -> (histogram, span name)
 _annotation = None                     # jax.profiler.TraceAnnotation, lazily
@@ -170,7 +173,7 @@ def stage(name: str, more: bool = False) -> _StageSpan:
     return _StageSpan(*_stage_def(name), more)
 
 
-for _name in STAGES:
+for _name in STAGES + CABAC_STAGES:
     _stage_def(_name)
 
 # The one stage that crosses threads, so it is no profiler span: stamped

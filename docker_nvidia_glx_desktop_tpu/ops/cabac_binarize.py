@@ -200,9 +200,16 @@ def _residual_slots(coeffs, cat: int, cbf_inc, emit):
     lvl = a - 1
 
     def after(x):            # count over scan positions > i
-        x = x.astype(jnp.int32)
+        # Between barriers, a program step of its own: fused with what
+        # feeds and reads it, XLA:TPU made of this reversed cumsum wrong
+        # counts for the 15 coefficients of I16 AC blocks (v5e: 4,875
+        # words of a 1080p picture's stream, which no decoder takes;
+        # XLA:CPU, and the chip on the cumsum alone, count right).  A
+        # running sum or a triangular matmul count right too, at twice
+        # the program's device time (PERF.md PR 28).
+        x = jax.lax.optimization_barrier(x.astype(jnp.int32))
         rev = jnp.cumsum(x[..., ::-1], axis=-1)[..., ::-1]
-        return rev - x
+        return jax.lax.optimization_barrier(rev) - x
 
     num_gt1 = after(nz & (a > 1))
     num_eq1 = after(a == 1)
@@ -412,6 +419,7 @@ def _pack_stream(recs: _Recs, value_ovf):
 
 
 @jax.jit
+@jax.named_scope("dngd.binarize")
 def binarize_p(mv, luma, cb_dc, cb_ac, cr_dc, cr_ac):
     """Record stream for a P picture (P_L0_16x16 + P_Skip subset).
 
@@ -487,6 +495,7 @@ def binarize_p(mv, luma, cb_dc, cb_ac, cr_dc, cr_ac):
 
 
 @jax.jit
+@jax.named_scope("dngd.binarize")
 def binarize_intra(luma_dc, luma_ac, cb_dc, cb_ac, cr_dc, cr_ac,
                    pred_mode, mb_i4, i4_modes, luma_i4):
     """Record stream for an I picture (I_16x16 + I_NxN subset)."""

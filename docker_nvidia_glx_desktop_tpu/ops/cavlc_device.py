@@ -731,9 +731,8 @@ def encode_intra_cavlc_frame(rgb, hdr_vals, hdr_lens, pad_h: int, pad_w: int,
     """
     from . import h264_device
 
-    with jax.named_scope("dngd.intra"):
-        levels = h264_device.encode_intra_frame.__wrapped__(
-            rgb, pad_h, pad_w, qp, i16_modes, tune, next_y)
+    levels = h264_device.encode_intra_frame.__wrapped__(
+        rgb, pad_h, pad_w, qp, i16_modes, tune, next_y)
     return _finish_cavlc(levels, hdr_vals, hdr_lens, with_recon, qp)
 
 
@@ -749,9 +748,8 @@ def encode_intra_cavlc_frame_yuv(y, cb, cr, hdr_vals, hdr_lens, qp: int,
     h264_device.encode_intra_frame_yuv)."""
     from . import h264_device
 
-    with jax.named_scope("dngd.intra"):
-        levels = h264_device.encode_intra_frame_yuv.__wrapped__(
-            y, cb, cr, qp, i16_modes, tune, next_y)
+    levels = h264_device.encode_intra_frame_yuv.__wrapped__(
+        y, cb, cr, qp, i16_modes, tune, next_y)
     return _finish_cavlc(levels, hdr_vals, hdr_lens, with_recon, qp)
 
 

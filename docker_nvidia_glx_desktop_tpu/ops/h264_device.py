@@ -554,6 +554,7 @@ def encode_intra_frame(rgb, pad_h: int, pad_w: int, qp: int,
 
 
 @functools.partial(jax.jit, static_argnames=("qp", "i16_modes", "tune"))
+@jax.named_scope("dngd.intra")
 def encode_intra_frame_yuv(y, cb, cr, qp: int, i16_modes: str = "auto",
                            tune: str = "off", next_y=None):
     """Same device stage from pre-converted YUV 4:2:0 planes (already padded
@@ -702,3 +703,9 @@ def encode_intra_frame_yuv(y, cb, cr, qp: int, i16_modes: str = "auto",
     if qp_map is not None:
         out["qp_map"] = qp_map        # (R, C) absolute per-MB qp (tune=hq)
     return out
+
+
+#: qp-traced twin (tune="off" only), for the per-frame CABAC path — see
+#: cavlc_device.encode_intra_cavlc_frame_yuv_dynqp.
+encode_intra_frame_yuv_dynqp = jax.jit(
+    encode_intra_frame_yuv.__wrapped__, static_argnames=("i16_modes", "tune"))

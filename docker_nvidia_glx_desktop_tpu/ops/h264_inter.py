@@ -297,6 +297,14 @@ def encode_p_frame(y, cb, cr, ref_y, ref_cb, ref_cr, qp: int,
         tune=tune, next_y=next_y, p_intra=p_intra)
 
 
+#: qp-traced twin (tune="off" only), for the per-frame CABAC path — see
+#: cavlc_device.encode_intra_cavlc_frame_yuv_dynqp.
+encode_p_frame_dynqp = jax.jit(
+    encode_p_frame.__wrapped__,
+    static_argnames=("refine", "tune", "p_intra"),
+    donate_argnames=RING_DONATE)
+
+
 def encode_p_frame_padded_ref(y, cb, cr, ref_y_pad, ref_cb_pad, ref_cr_pad,
                               qp: int, refine: str = "alt",
                               tune: str = "off", next_y=None,

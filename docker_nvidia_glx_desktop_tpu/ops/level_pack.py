@@ -40,6 +40,8 @@ Transport layout (uint32 words):
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -128,10 +130,16 @@ def _pack(slots3: jax.Array) -> jax.Array:
     return jnp.concatenate([hdr, payload])
 
 
+@functools.partial(jax.jit, static_argnames=("keys",))
+@jax.named_scope("dngd.level_pack")
+def _pack_keys(levels: dict, keys) -> jax.Array:
+    return _pack(_mb_slots(levels, keys))
+
+
 def pack_levels(levels: dict, keys) -> jax.Array:
     """Compact the level tensors named by ``keys`` (INTRA_KEYS/P_KEYS)
-    into one uint32 transport buffer (device computation, no sync)."""
-    return _pack(_mb_slots(levels, keys))
+    into one uint32 transport buffer (one device program, no sync)."""
+    return _pack_keys({k: levels[k] for k, _, _ in keys}, keys)
 
 
 def header_words(rows: int) -> int:

@@ -421,6 +421,8 @@ async def _signalling_handler(request: web.Request, session, audio,
                              and getattr(audio, "format", "") == "opus")
                 peer = WebRtcPeer(clock=getattr(session, "clock", None),
                                   video_codec=rtc_codec,
+                                  sps=getattr(getattr(session, "muxer",
+                                                      None), "sps", None),
                                   advertise_ip=advertise_ip,
                                   with_audio=rtc_audio,
                                   turn=conn_turn)

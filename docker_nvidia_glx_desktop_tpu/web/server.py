@@ -602,8 +602,12 @@ def make_app(cfg: Config, session=None,
         try:
             hello = (sess.hello() if hasattr(sess, "hello") else
                      {"type": "hello", "codec": sess.codec_name,
-                      "mime": getattr(sess, "mime",
-                                      'video/mp4; codecs="avc1.42E01E"'),
+                      # the codec string the muxer derives from the SPS
+                      # it was given (web/mp4.py): Main for CABAC; the
+                      # literal is for a session double without one
+                      "mime": getattr(sess, "mime", None) or getattr(
+                          getattr(sess, "muxer", None), "mime",
+                          'video/mp4; codecs="avc1.42E01E"'),
                       "width": sess.source.width,
                       "height": sess.source.height})
             hello["audio"] = audio is not None
@@ -980,6 +984,8 @@ async def _handle_offer(msg: dict, ws, session, conn: dict) -> None:
         _teardown_peer(conn, session)        # renegotiation replaces peer
         peer = WebRtcPeer(clock=getattr(session, "clock", None),
                           video_codec=rtc_codec,
+                          sps=getattr(getattr(session, "muxer", None),
+                                      "sps", None),
                           advertise_ip=conn["advertise_ip"],
                           with_audio=rtc_audio,
                           turn=conn.get("turn"))

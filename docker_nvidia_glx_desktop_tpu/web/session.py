@@ -377,6 +377,10 @@ class StreamSession:
         # super-step ring encoders stage chunk+1 frames in flight (the
         # chunk dispatches as ONE device program); classic codecs keep 2
         self.PIPELINE_DEPTH = getattr(self.encoder, "pipeline_depth", 2)
+        if self.cfg.encoder_prewarm and hasattr(self.encoder, "warm_pulls"):
+            # every length of the CABAC path's pulls, compiled before a
+            # frame is served and not in the serving thread when first met
+            self.encoder.warm_pulls()
         if self._qp_offset:
             # degradation survives a codec rebuild (resize mid-degrade)
             self.encoder.degrade_qp_offset = self._qp_offset

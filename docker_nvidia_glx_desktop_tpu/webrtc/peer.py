@@ -62,12 +62,15 @@ class WebRtcPeer:
                  video_codec: str = "H264",
                  advertise_ip: str = "127.0.0.1",
                  certificate: Optional[Certificate] = None,
+                 sps: Optional[bytes] = None,
                  with_audio: bool = True,
                  turn: Optional[dict] = None):
         from .ice import IceLiteEndpoint
 
         self.clock = clock if clock is not None else MediaClock()
         self.video_codec = video_codec
+        # what the H.264 stream is negotiated as: Main when its SPS is
+        self.h264_profile = sdp.h264_profile_level_id(sps)
         self.advertise_ip = advertise_ip
         self.with_audio = with_audio
         # {"host","port","username","credential"} -> allocate a relayed
@@ -168,7 +171,8 @@ class WebRtcPeer:
         answer SDP."""
         self._loop = asyncio.get_running_loop()
         self.ready = self._loop.create_future()
-        offer = sdp.parse_offer(offer_sdp, video_codec=self.video_codec)
+        offer = sdp.parse_offer(offer_sdp, video_codec=self.video_codec,
+                                h264_profile=self.h264_profile)
         self._offer = offer
         if not self.with_audio:
             # no RTC-feedable audio (e.g. AUDIO_CODEC=pcm): answer the
@@ -203,7 +207,7 @@ class WebRtcPeer:
             candidates,
             self.advertise_ip,
             ssrcs=ssrcs,
-            video_codec=self.video_codec)
+            video_codec=self.video_codec, h264_profile=self.h264_profile)
         return answer
 
     def _negotiate_feedback(self, m: "sdp.MediaSection") -> None:
@@ -269,7 +273,8 @@ class WebRtcPeer:
             ssrcs={"video": self.video.ssrc, "audio": self.audio.ssrc,
                    "video_rtx": self.video_fb.rtx.ssrc},
             video_codec=self.video_codec, with_audio=self.with_audio,
-            with_datachannel=with_datachannel)
+            with_datachannel=with_datachannel,
+            h264_profile=self.h264_profile)
 
     async def handle_answer(self, answer_sdp: str) -> None:
         """Complete the server-initiated negotiation with the browser's

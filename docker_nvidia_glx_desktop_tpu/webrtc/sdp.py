@@ -168,17 +168,31 @@ def _rtx_for(codec_table: Dict[int, dict], pt: int) -> Optional[int]:
     return None
 
 
-def _choose_video_pt(table: Dict[int, dict], prefer: str):
+H264_BASELINE, H264_MAIN = "42e01f", "4d001f"
+
+
+def h264_profile_level_id(sps: Optional[bytes]) -> str:
+    """What our H.264 stream is negotiated as, from its SPS NAL: Main
+    (``4d001f``) when ``profile_idc`` is 77 (ENCODER_ENTROPY=cabac),
+    else constrained baseline (``42e01f``).  Level 3.1 in both, as
+    browsers offer it: the decoder goes by the SPS in the stream."""
+    return H264_MAIN if sps and len(sps) > 1 and sps[1] == 77 \
+        else H264_BASELINE
+
+
+def _choose_video_pt(table: Dict[int, dict], prefer: str,
+                     h264_profile: str = H264_BASELINE):
     """Pick our codec's payload type from the browser's offer."""
     if prefer == "H264":
-        # packetization-mode=1 + constrained-baseline 42xx is what the
-        # slice-per-row CAVLC encoder emits
+        # packetization-mode=1 + the profile of our stream: constrained
+        # baseline 42xx is what the slice-per-row CAVLC encoder emits,
+        # Main 4dxx the CABAC one
         for pt, info in table.items():
             if info.get("codec") != "H264":
                 continue
             fmtp = info.get("fmtp", "")
             if ("packetization-mode=1" in fmtp
-                    and "profile-level-id=42" in fmtp):
+                    and f"profile-level-id={h264_profile[:2]}" in fmtp):
                 return pt, info
         for pt, info in table.items():      # any packetization-mode=1 H264
             if (info.get("codec") == "H264"
@@ -190,7 +204,8 @@ def _choose_video_pt(table: Dict[int, dict], prefer: str):
     return None, {}
 
 
-def parse_offer(sdp: str, video_codec: str = "H264") -> RemoteOffer:
+def parse_offer(sdp: str, video_codec: str = "H264",
+                h264_profile: str = H264_BASELINE) -> RemoteOffer:
     if not isinstance(sdp, str):
         raise SdpError("sdp_not_text")
     if len(sdp) > MAX_SDP_BYTES:
@@ -275,7 +290,8 @@ def parse_offer(sdp: str, video_codec: str = "H264") -> RemoteOffer:
                                       max_message_size=max_msg,
                                       proto=proto))
         elif kind == "video":
-            pt, info = _choose_video_pt(table, video_codec)
+            pt, info = _choose_video_pt(table, video_codec,
+                                        h264_profile)
             fb_table = _feedback_table(sec)
             media.append(MediaSection(
                 kind, mid, pt, info.get("codec", ""),
@@ -337,7 +353,8 @@ def _append_application_section(out: List[str], proto: str, mid: str,
 def build_answer(offer: RemoteOffer, ice_ufrag: str, ice_pwd: str,
                  fingerprint: str, candidate, advertise_ip: str,
                  ssrcs: Dict[str, int],
-                 video_codec: str = "H264") -> str:
+                 video_codec: str = "H264",
+                 h264_profile: str = H264_BASELINE) -> str:
     """Answer SDP: ICE-lite, sendonly media, BUNDLE, rtcp-mux.
 
     ``candidate``: one ``candidate:...`` line or a list of them (host
@@ -395,7 +412,7 @@ def build_answer(offer: RemoteOffer, ice_ufrag: str, ice_pwd: str,
                 out.append(f"a=rtpmap:{pt} H264/90000")
                 fmtp = m.fmtp or ("level-asymmetry-allowed=1;"
                                   "packetization-mode=1;"
-                                  "profile-level-id=42e01f")
+                                  f"profile-level-id={h264_profile}")
                 out.append(f"a=fmtp:{pt} {fmtp}")
             else:
                 out.append(f"a=rtpmap:{pt} VP8/90000")
@@ -426,7 +443,8 @@ def build_offer(ice_ufrag: str, ice_pwd: str, fingerprint: str,
                 candidate, advertise_ip: str, ssrcs: Dict[str, int],
                 video_codec: str = "H264",
                 with_audio: bool = True,
-                with_datachannel: bool = True) -> str:
+                with_datachannel: bool = True,
+                h264_profile: str = H264_BASELINE) -> str:
     """Server-initiated offer (the stock-selkies role inversion: the
     app offers sendonly media, the browser answers).  ICE-lite with
     setup:actpass — the full-ICE browser takes the controlling role and
@@ -476,7 +494,8 @@ def build_offer(ice_ufrag: str, ice_pwd: str, fingerprint: str,
             if video_codec == "H264":
                 out.append(f"a=rtpmap:{pt} H264/90000")
                 out.append(f"a=fmtp:{pt} level-asymmetry-allowed=1;"
-                           "packetization-mode=1;profile-level-id=42e01f")
+                           "packetization-mode=1;"
+                           f"profile-level-id={h264_profile}")
             else:
                 out.append(f"a=rtpmap:{pt} VP8/90000")
             for f in SUPPORTED_VIDEO_FB:

@@ -152,20 +152,39 @@ class TestRecordStream:
         assert got is not None
         assert got == want
 
-    def test_serving_paths_agree(self, monkeypatch):
+    @pytest.mark.parametrize("how", ["constructed", "served"])
+    def test_serving_paths_agree(self, monkeypatch, how):
         """H264Encoder entropy='cabac' with device binarization (the
         round-6 default) must emit the exact bytes the round-5 host
-        split does, GOP-deep through the pipelined API."""
+        split does, GOP-deep through the pipelined API: constructed
+        directly at a fixed qp, and as ``make_encoder`` serves it under
+        the environment of benchmark/configs/desk1080-cabac.json (qp
+        traced, the rate controller moving it)."""
+        import json
+        import pathlib
+
+        from docker_nvidia_glx_desktop_tpu.models import make_encoder
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
+        from docker_nvidia_glx_desktop_tpu.utils.config import from_env
 
         frames = [np.ascontiguousarray(np.roll(
             conftest.make_test_frame(96, 128, seed=9), 2 * i, axis=1))
             for i in range(4)]
+        env = json.loads((pathlib.Path(__file__).resolve().parents[1]
+                          / "benchmark" / "configs"
+                          / "desk1080-cabac.json").read_text())["env"]
 
         def run(mode):
             monkeypatch.setenv("ENCODER_CABAC_BINARIZE", mode)
-            enc = H264Encoder(128, 96, qp=26, mode="cavlc",
-                              entropy="cabac", gop=4, deblock=True)
+            if how == "served":
+                enc, _ = make_encoder(from_env(dict(
+                    env, SIZEW="128", SIZEH="96", PASSWD="pw",
+                    ENCODER_GOP="4")), 128, 96)
+                assert enc._dyn_qp and enc._rate is not None
+                assert enc.cabac_device_binarize == (mode == "device")
+            else:
+                enc = H264Encoder(128, 96, qp=26, mode="cavlc",
+                                  entropy="cabac", gop=4, deblock=True)
             out = []
             pend = []
             i = 0
