@@ -10,8 +10,14 @@ This module moves binarization and ctxIdx computation onto the device:
 a pure-JAX kernel walks the H.264 CABAC syntax (spec 9.3.2/9.3.3) for
 every macroblock IN PARALLEL and emits a packed record stream — the
 exact (bin, ctxIdx, bypass) sequence the arithmetic engine must
-consume — through the same scatter-free bitmerge hierarchy level_pack
-uses.  The host (native/cabac.cpp ``h264_cabac_engine_rows``) then runs
+consume.  The slots are packed (``_pack_stream``) through the same
+scatter-free bitmerge hierarchy level_pack uses wherever there is no TPU;
+on the TPU that hierarchy's barrel-shifter stages were each a pass of the
+worst-case-sized buffer through HBM (23 ms a 1080p frame whatever the
+content), so there ``ops/cabac_pack.py`` packs the same slots into the same
+buffer, word for word, with two Pallas kernels whose merge stages stay in
+VMEM (``jax.default_backend()`` chooses, as ``ops/h264_deblock.py`` does
+for the loop filter).  The host (native/cabac.cpp ``h264_cabac_engine_rows``) then runs
 ONLY the arithmetic engine: read record, update range/low, emit bits.
 No dense level tensors cross the link and the host never re-derives a
 context.
@@ -53,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import bitmerge
+from . import bitmerge, cabac_pack
 
 __all__ = ["META_WORDS", "binarize_p", "binarize_intra", "split_rows",
            "header_words", "payload_words", "decode_records_py",
@@ -199,25 +205,8 @@ def _residual_slots(coeffs, cat: int, cbf_inc, emit):
     a = jnp.abs(coeffs)
     lvl = a - 1
 
-    def after(x):            # count over scan positions > i
-        # Between barriers, a program step of its own: fused with what
-        # feeds and reads it, XLA:TPU made of this reversed cumsum wrong
-        # counts for the 15 coefficients of I16 AC blocks (v5e: 4,875
-        # words of a 1080p picture's stream, which no decoder takes;
-        # XLA:CPU, and the chip on the cumsum alone, count right).  A
-        # running sum or a triangular matmul count right too, at twice
-        # the program's device time (PERF.md PR 28).
-        x = jax.lax.optimization_barrier(x.astype(jnp.int32))
-        rev = jnp.cumsum(x[..., ::-1], axis=-1)[..., ::-1]
-        return jax.lax.optimization_barrier(rev) - x
-
-    num_gt1 = after(nz & (a > 1))
-    num_eq1 = after(a == 1)
     abs_base = 227 + _ABS_OFF[cat]
     capn = 3 if cat == 3 else 4
-    c0 = abs_base + jnp.where(num_gt1 > 0, 0,
-                              jnp.minimum(4, 1 + num_eq1))
-    cn = abs_base + 5 + jnp.minimum(capn, num_gt1)
     prefix = jnp.minimum(lvl, 14)
     # UEG0 suffix (lvl >= 14) + sign, as bypass runs.  DC categories
     # (0, 3) carry the Hadamard-amplified magnitudes, so they get a
@@ -245,12 +234,25 @@ def _residual_slots(coeffs, cat: int, cbf_inc, emit):
         lo_bits = bits & ((1 << lo_len) - 1)
     zero = jnp.zeros(coeffs.shape[:-1], bool)
 
+    # Levels above 1 and equal to 1 at the scan positions behind j, as
+    # running counts of this loop and NOT as a reversed ``jnp.cumsum``:
+    # fused with what feeds and reads it, XLA:TPU counts that wrong (I16
+    # AC blocks on the v5e: 4,875 words of a 1080p picture's stream, which
+    # no decoder takes), and held apart by ``optimization_barrier``s it is
+    # a ``reduce_window`` over a minor dimension of 16, 4.4 ms a frame
+    # (PERF.md PR 28, PR 29).
+    num_gt1 = num_eq1 = jnp.zeros(coeffs.shape[:-1], jnp.int32)
     for j in range(n - 1, -1, -1):            # reverse scan order
         nzj = emit & nz[..., j]
-        add(_dec(c0[..., j], lvl[..., j] >= 1, nzj), 11)
-        run = _run(cn[..., j], jnp.clip(prefix[..., j] - 1, 1, 14),
+        c0 = abs_base + jnp.where(num_gt1 > 0, 0,
+                                  jnp.minimum(4, 1 + num_eq1))
+        cn = abs_base + 5 + jnp.minimum(capn, num_gt1)
+        num_gt1 = num_gt1 + (a[..., j] > 1)
+        num_eq1 = num_eq1 + (a[..., j] == 1)
+        add(_dec(c0, lvl[..., j] >= 1, nzj), 11)
+        run = _run(cn, jnp.clip(prefix[..., j] - 1, 1, 14),
                    nzj & (prefix[..., j] >= 2))
-        term = _dec(cn[..., j], zero,
+        term = _dec(cn, zero,
                     nzj & (prefix[..., j] >= 1) & (prefix[..., j] < 14))
         add(_cat(run, term), 26)
         if wide:
@@ -374,8 +376,33 @@ def _mvd_slots(recs, mvd_comp, s_left, base: int, pres):
 
 def _pack_stream(recs: _Recs, value_ovf):
     """Slot arrays -> bitmerge hierarchy -> version-2 transport buffer
-    (per-row BIT counts in the meta table)."""
+    (per-row BIT counts in the meta table).  On the TPU the hierarchy is
+    ``cabac_pack``'s two kernels; the buffer is the same, word for word."""
     vals, lns = recs.stacked()
+    r, c, s = vals.shape
+    s += (-s) % 8                       # slots merge eight to a piece
+    p2 = 1 << int(np.ceil(np.log2(s // 8)))
+    mb_cap = min(p2 * 8, -(-recs.max_bits // 32))
+    c2 = 1 << int(np.ceil(np.log2(c)))
+    if jax.default_backend() == "tpu":
+        overflow, row_bits, payload = cabac_pack.pack_rows(
+            vals, lns, value_ovf, mb_cap, c2 * mb_cap)
+    else:
+        overflow, row_bits, payload = _pack_rows_xla(
+            vals, lns, value_ovf, mb_cap, p2, c2)
+    row_words = ((row_bits + 31) >> 5).astype(jnp.int32)
+    hdr = jnp.zeros(META_WORDS + r, jnp.uint32)
+    hdr = (hdr.at[0].set(2)
+           .at[1].set(overflow.astype(jnp.uint32))
+           .at[2].set(row_words.sum().astype(jnp.uint32))
+           .at[3].set(r).at[4].set(s)
+           .at[META_WORDS:].set(row_bits.astype(jnp.uint32)))
+    return jnp.concatenate([hdr, payload])
+
+
+def _pack_rows_xla(vals, lns, value_ovf, mb_cap: int, p2: int, c2: int):
+    """(overflow, per-row bits, payload) through the bitmerge hierarchy:
+    the packer wherever there is no TPU, and the kernels' oracle."""
     r, c, s = vals.shape
     pad = (-s) % 8
     if pad:
@@ -385,26 +412,16 @@ def _pack_stream(recs: _Recs, value_ovf):
     nb = s // 8
     w1, nb1, _ = bitmerge.slots_to_words(
         vals.reshape(r, c, nb, 8), lns.reshape(r, c, nb, 8), 8)
-    p2 = 1 << int(np.ceil(np.log2(nb)))
     w1 = jnp.pad(w1, ((0, 0), (0, 0), (0, p2 - nb), (0, 0)))
     nb1 = jnp.pad(nb1, ((0, 0), (0, 0), (0, p2 - nb)))
     w2, mb_bits = bitmerge.merge_pieces_tree(w1, nb1)
-    mb_cap = min(p2 * 8, -(-recs.max_bits // 32))
     overflow = value_ovf.any() | (mb_bits > 32 * mb_cap).any()
     w2 = w2[..., :mb_cap]
-    c2 = 1 << int(np.ceil(np.log2(c)))
     w2 = jnp.pad(w2, ((0, 0), (0, c2 - c), (0, 0)))
     mb_bits = jnp.pad(mb_bits, ((0, 0), (0, c2 - c)))
     w3, row_bits = bitmerge.merge_pieces_tree(w2, mb_bits)
     row_words = ((row_bits + 31) >> 5).astype(jnp.int32)
     row_cap = w3.shape[-1]
-
-    hdr = jnp.zeros(META_WORDS + r, jnp.uint32)
-    hdr = (hdr.at[0].set(2)
-           .at[1].set(overflow.astype(jnp.uint32))
-           .at[2].set(row_words.sum().astype(jnp.uint32))
-           .at[3].set(r).at[4].set(s)
-           .at[META_WORDS:].set(row_bits.astype(jnp.uint32)))
     offs = jnp.concatenate(
         [jnp.zeros(1, jnp.int32), jnp.cumsum(row_words)])[:r]
     payload = jnp.zeros(r * row_cap, jnp.uint32)
@@ -414,8 +431,7 @@ def _pack_stream(recs: _Recs, value_ovf):
             acc, jax.lax.dynamic_index_in_dim(w3, i, keepdims=False),
             (offs[i],))
 
-    payload = jax.lax.fori_loop(0, r, body, payload)
-    return jnp.concatenate([hdr, payload])
+    return overflow, row_bits, jax.lax.fori_loop(0, r, body, payload)
 
 
 @jax.jit

@@ -1,0 +1,126 @@
+"""The CABAC record packer's TPU kernels (``ops/cabac_pack``: slot ->
+macroblock by a compress network, macroblock -> row -> frame by a scalar
+walk) against the XLA packer they replace on the chip, which stays the CPU
+path and the oracle.
+
+Tier-1 on purpose (``test_cabac_device`` is a slow module): the kernels run
+here in ``pltpu.force_tpu_interpret_mode()``, reached through
+``_pack_stream``'s own ``jax.default_backend()`` test, as the loop filter's
+kernel is in ``test_cabac_device.TestDeblockKernel``.  What Mosaic makes of
+them at 1920x1080 is ``tests/test_chip_compile.py``'s part.
+"""
+
+import numpy as np
+import pytest
+
+import conftest
+from test_cabac_device import _p_levels, _yuv
+
+_PACK_CASES = ("desktop", "fulldamage", "all_skip", "empty_rows",
+               "extreme_levels", "overflow", "shard_1x5")
+
+
+@pytest.fixture(scope="module")
+def kernel_jits():
+    """kind -> the jit these tests trace on the TPU's branch, and reuse: a
+    case is 6 s of interpreter, a trace 8 to 25 s more."""
+    return {}
+
+
+def _pack_case(kind, case):
+    """Level tensors for one packer case (the arguments of ``binarize_p`` /
+    ``binarize_intra``): real stage output for the desktop, crafted (3, 5)
+    grids (a column count that is no power of two) for the rest, and one
+    MB row of 5 for a spatial shard."""
+    import jax.numpy as jnp
+
+    from docker_nvidia_glx_desktop_tpu.ops import h264_device
+
+    rng = np.random.default_rng(sum(map(ord, kind + case)))
+    if case == "desktop":
+        if kind == "p":
+            out = _p_levels(qp=26)
+            return tuple(np.asarray(out[k]) for k in (
+                "mv", "luma", "cb_dc", "cb_ac", "cr_dc", "cr_ac"))
+        f0 = _yuv(conftest.make_test_frame(96, 128, seed=5), 128, 96)
+        lv = h264_device.encode_intra_frame_yuv(
+            *[jnp.asarray(p) for p in f0], 26)
+        return tuple(np.asarray(lv[k]) for k in (
+            "luma_dc", "luma_ac", "cb_dc", "cb_ac", "cr_dc", "cr_ac",
+            "pred_mode", "mb_i4", "i4_modes", "luma_i4"))
+    nr, nc = (1, 5) if case == "shard_1x5" else (3, 5)
+    rows = {"all_skip": [], "empty_rows": [0, 2]}.get(case, range(nr))
+    z = lambda *shape: np.zeros((nr, nc) + shape, np.int32)
+
+    def fill(a, lo, hi, one_in=1):
+        for r in rows:
+            keep = rng.integers(0, one_in, a[r].shape) == 0
+            a[r] = rng.integers(lo, hi + 1, a[r].shape) * keep
+        return a
+
+    sparse = 1 if case == "fulldamage" else 3
+    cb_dc, cr_dc = fill(z(4), -9, 9), fill(z(4), -9, 9, sparse)
+    cb_ac, cr_ac = fill(z(4, 15), -2, 2, sparse), fill(z(4, 15), -2, 2, 4)
+    if kind == "p":
+        mv, luma = fill(z(2), -39, 39), fill(z(16, 16), -3, 3, sparse)
+        if case == "extreme_levels":
+            luma[0, 0, 0, 0], luma[0, 0, 0, 5] = 141, -141
+            luma[2, 1, 3, :] = rng.integers(-20, 21, 16)
+            cb_dc[2, 2] = (16398, -16398, 700, 0)    # two-slot DC suffixes
+        if case == "overflow":
+            luma[0, 0, 0, 0] = 500      # test_p_overflow_flag_on_giant_level
+        return mv, luma, cb_dc, cb_ac, cr_dc, cr_ac
+    mb_i4 = fill(z(), 0, 1)
+    i16 = (1 - mb_i4)[..., None]
+    luma_dc = fill(z(16), -30, 30, sparse) * i16
+    luma_ac = fill(z(16, 15), -3, 3, sparse) * i16[..., None]
+    luma_i4 = fill(z(16, 16), -4, 4, sparse) * mb_i4[..., None, None]
+    if case == "extreme_levels":
+        mb_i4[0, 0] = 0
+        luma_dc[0, 0, :4] = (16398, -16398, 15, -9000)
+        luma_ac[0, 0, 2, 0] = -141
+        cr_dc[1, 1] = (-16398, 1, 0, 2000)
+    if case == "overflow":
+        mb_i4[0, 0] = 0
+        luma_ac[0, 0, 0, 0] = 500
+    return (luma_dc, luma_ac, cb_dc, cb_ac, cr_dc, cr_ac, fill(z(), 0, 3),
+            mb_i4, fill(z(16), 0, 8), luma_i4)
+
+
+class TestPackKernels:
+    @pytest.mark.parametrize("case", _PACK_CASES)
+    @pytest.mark.parametrize("kind", ["p", "intra"])
+    def test_kernels_buffer_equals_xla_packer(self, kind, case,
+                                              monkeypatch, kernel_jits):
+        """The TPU packer (``ops/cabac_pack``: two Pallas kernels, here in
+        interpret mode and reached through ``_pack_stream``'s own backend
+        test) must give the XLA packer's transport buffer word for word:
+        header, row bit table, every payload word up to ``head[2]``, and
+        the zeros behind them; on the overflow input, the same flag."""
+        import jax
+        from jax.experimental.pallas import tpu as pltpu
+
+        from docker_nvidia_glx_desktop_tpu.ops import cabac_binarize as cb
+
+        args = _pack_case(kind, case)
+        fn = cb.binarize_p if kind == "p" else cb.binarize_intra
+        want = np.asarray(fn(*args))
+        # a jit of a function of its own: JAX keeps traces by the function,
+        # whichever jit asks, and ``fn`` holds the CPU's by now
+        body = kernel_jits.setdefault(
+            kind, jax.jit(lambda *a: fn.__wrapped__(*a)))
+        with monkeypatch.context() as mp, pltpu.force_tpu_interpret_mode():
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            got = np.asarray(body(*args))
+        assert got.shape == want.shape
+        assert int(got[1]) == int(want[1]) == (case == "overflow")
+        if case == "overflow":
+            return
+        rows = args[0].shape[0]
+        assert int(want[3]) == rows and int(want[2]) > 0
+        if case in ("all_skip", "empty_rows") and kind == "p":
+            # an empty row is 120 skip flags and the end of slice: short
+            assert int(want[cb.META_WORDS + rows - 2]) < 32 * args[0].shape[1]
+        n = cb.META_WORDS + rows + int(want[2])
+        np.testing.assert_array_equal(got[:n], want[:n])
+        np.testing.assert_array_equal(got[n:], want[n:])

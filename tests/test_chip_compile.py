@@ -11,7 +11,9 @@ under ``JAX_PLATFORMS=tpu``),
 the in-loop deblock of a P frame on the TPU's schedule, the Pallas
 kernel compiled by Mosaic (the ``jax.default_backend()`` branch would pick
 the CPU's scan here, so the test answers "tpu" while that one program is
-lowered), and the (4,1) session-mesh step of
+lowered), the two CABAC binarize programs with the record packer's two
+kernels (``ops/cabac_pack``, chosen the same way), and the (4,1)
+session-mesh step of
 ``TPU_SESSIONS``/``TPU_MESH`` on a ``Mesh`` of the four described
 devices.
 
@@ -65,7 +67,8 @@ def programs(topo, no_persistent_cache):
     from jax.sharding import (NamedSharding, PartitionSpec as P,
                               SingleDeviceSharding)
 
-    from docker_nvidia_glx_desktop_tpu.ops import (cavlc_device,
+    from docker_nvidia_glx_desktop_tpu.ops import (cabac_binarize,
+                                                   cavlc_device,
                                                    cavlc_p_device,
                                                    h264_deblock)
     from docker_nvidia_glx_desktop_tpu.parallel import batch
@@ -109,6 +112,19 @@ def programs(topo, no_persistent_cache):
         lowered["deblock_p"] = jax.jit(
             h264_deblock.deblock_frame.__wrapped__).lower(
                 ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv)
+        # H264Encoder._submit_cabac_p / _submit_cabac_intra: the record
+        # stream of a P picture and of an IDR, packed by the two kernels
+        lv = lambda *shape: jax.ShapeDtypeStruct(
+            (H // 16, W // 16) + shape, jnp.int32, sharding=one)
+        chroma = (lv(4), lv(4, 15), lv(4), lv(4, 15))
+        # (through functions of their own: JAX keeps a trace by the
+        # function, and another test may have left the CPU's there)
+        lowered["binarize_p"] = jax.jit(
+            lambda *a: cabac_binarize.binarize_p.__wrapped__(*a)).lower(
+                lv(2), lv(16, 16), *chroma)
+        lowered["binarize_intra"] = jax.jit(
+            lambda *a: cabac_binarize.binarize_intra.__wrapped__(*a)).lower(
+                lv(16), lv(16, 15), *chroma, lv(), lv(), lv(16), lv(16, 16))
     # web/multisession: four 1080p sessions, one per chip
     mesh = batch.make_mesh((4, 1), topo.devices)
     sess = NamedSharding(mesh, P("session", "spatial", None))
@@ -129,7 +145,8 @@ def programs(topo, no_persistent_cache):
     # side by side overflow the stack of the installed libtpu's
     # compiler (SIGSEGV in TpuBroadcastRewriter; PERF.md Findings,
     # PR 22), and a worker that dies takes the whole file with it.
-    order = ("mesh41", "p", "intra", "deblock_p")
+    order = ("mesh41", "p", "intra", "binarize_intra", "binarize_p",
+             "deblock_p")
     with ThreadPoolExecutor(2) as ex:
         return dict(ex.map(compile_one, ((k, lowered[k]) for k in order)))
 
@@ -167,6 +184,24 @@ def test_p_deblock_compiles_with_the_edge_kernel(programs):
     text = c.as_text()
     assert "dngd_deblock_edges" in text and "tpu_custom_call" in text
     assert " while(" not in text
+
+
+@pytest.mark.parametrize("name", ["binarize_p", "binarize_intra"])
+def test_binarize_compiles_with_the_pack_kernels(programs, name):
+    from docker_nvidia_glx_desktop_tpu.ops import cabac_pack
+
+    c = _compiled(programs, name)
+    assert 0 < _device_bytes(c) < HBM_BYTES
+    # both merge levels are Mosaic kernels; no barrel-shifter tree and no
+    # row-by-row ``dynamic_update_slice`` loop is left in the program
+    text = c.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "cabac_compact" in text and "cabac_rows" in text
+    assert " while(" not in text
+    # kernel A asks for more VMEM than the compiler's default scoped limit
+    # (16 MiB on a v5e) and says so; the chip has 128 MiB
+    assert f'"size":"{cabac_pack.VMEM_LIMIT_BYTES}"' in text
+    assert cabac_pack.VMEM_LIMIT_BYTES <= 64 * 1024 * 1024
 
 
 def test_session_mesh_step_fits_each_chip(programs):
