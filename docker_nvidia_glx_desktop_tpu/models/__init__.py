@@ -5,18 +5,6 @@ from .mjpeg import JpegEncoder  # noqa: F401
 from .h264 import H264Encoder  # noqa: F401
 
 
-def make_flagship_encoder(width: int, height: int):
-    """Best available codec path for benchmarking/serving.
-
-    H.264 CAVLC with device-side entropy (ops/cavlc_device): transform,
-    quant, AND bit packing all run on TPU, so only the packed bitstream
-    crosses the host link.  Returns (encoder, codec_name).
-    """
-    return (H264Encoder(width, height, mode="cavlc", entropy="device",
-                        host_color=True),
-            "h264_cavlc")
-
-
 def make_encoder(cfg, width: int, height: int):
     """Codec from the config surface (WEBRTC_ENCODER + ENCODER_* knobs,
     reference Dockerfile:210-211 / SURVEY.md §2.4).

@@ -1471,10 +1471,12 @@ class H264Encoder(Encoder):
         device emits the packed (bin, ctxIdx, bypass) record stream
         (ops/cabac_binarize) and the host runs only the arithmetic
         engine.  Opt-in via ENCODER_CABAC_BINARIZE=device, which is what
-        the benchmark's ``desk1080-cabac`` serves; the round-5 split —
-        level_pack transport + full host coder — stays the default: on a
-        v5e at 1080p the binarize program is 23 ms of the chip a frame
-        and ``device`` the slower of the two on a desktop (PERF.md PR 28).
+        the benchmark's ``desk1080-cabac`` serves: on a v5e at 1080p the
+        binarize program is ``cabac_binarize_ms`` 2.1 of the chip a frame
+        and both cells deliver 58.7–59.2 frames/s (ledger, PR 29), where
+        the round-5 split — level_pack transport + full host coder —
+        read 34.25 / 40.85 (builder's chip runs, PR 28).  ``host`` is
+        still the default: the flip is ROADMAP R4(b)'s.
         Either path emits byte-identical streams (tested); an overflow
         in the packed stream falls back dense per-frame, and is
         counted."""

@@ -108,15 +108,6 @@ class BudgetLedger:
         self._lock = threading.Lock()          # guards dict mutation only
         self._link_rtt_ms: Optional[float] = None
         self._link_probe: Optional[dict] = None
-        # device-internal stage profile ({"device-me": ms, ...}): the
-        # fused device step is ONE span to the host tracer, so ME /
-        # deblock / entropy attribution inside it must be FED by a
-        # caller of set_device_profile (bench.py does, from the devloop
-        # stage loops; a serving process that wants the rows on its
-        # /debug/budget calls the same API) — first-class spans here so
-        # an over-budget 4K frame attributes to a stage, not "the
-        # device"
-        self._device_profile: Dict[str, float] = {}
         # per-frame Python->device crossing counts (record_dispatch):
         # the super-step acceptance gauge — per-frame dispatch serves
         # ~1/frame, the GOP-chunk ring ~1/chunk
@@ -193,25 +184,17 @@ class BudgetLedger:
         self._stage("dispatch").append(float(gap_ms))
         self._dirty = True
 
-    def record_spatial(self, halo_ms: Optional[float] = None,
-                       stitch_ms: Optional[float] = None) -> None:
+    def record_spatial(self, stitch_ms: float) -> None:
         """Spatial-shard overhead attribution (single-session mesh
-        sharding, parallel/batch spatial steps): ``halo_ms`` is the
-        per-step cost of the ppermute reference-halo exchange (fed by
-        the bench's halo-on/halo-off differencing — it is fused inside
-        the device program and invisible to host tracing), ``stitch_ms``
-        the host-side per-AU shard assembly/stitch cost (measured live
-        by the encoder's spatial collect).  Both land as free-standing
-        ``halo-exchange`` / ``bitstream-stitch`` stages — /debug/budget
-        rows and the ``dngd_halo_ms`` / ``dngd_stitch_ms`` gauges — so
-        a 4K regression names the leaking sub-stage instead of a
-        blended device number.  NOT frame stages: the halo lives inside
-        device-collect and the stitch inside bitstream; adding them to
-        the compute floor would double-count."""
-        if halo_ms is not None:
-            self._stage("halo-exchange").append(float(halo_ms))
-        if stitch_ms is not None:
-            self._stage("bitstream-stitch").append(float(stitch_ms))
+        sharding, parallel/batch spatial steps): ``stitch_ms`` is the
+        host-side per-AU shard assembly/stitch cost, measured live by
+        the encoder's spatial collect.  It lands as the free-standing
+        ``bitstream-stitch`` stage — a /debug/budget row and the
+        ``dngd_stitch_ms`` gauge.  NOT a frame stage: the stitch lives
+        inside bitstream; adding it to the compute floor would
+        double-count.  (The ppermute halo exchange is fused inside the
+        device program: only a device trace can attribute it.)"""
+        self._stage("bitstream-stitch").append(float(stitch_ms))
         self._dirty = True
 
     def record_content(self, damage_fraction: float) -> None:
@@ -265,25 +248,6 @@ class BudgetLedger:
                      ) -> None:
         self._link_rtt_ms = float(rtt_ms)
         self._link_probe = probe
-
-    def set_device_profile(self, stages: Dict[str, float]) -> None:
-        """Record device-internal stage timings (ms) as first-class
-        spans — e.g. {"device-me": 12.1, "device-deblock": 2.3,
-        "device-entropy": 5.0} from the devloop stage loops.  They feed
-        the ``device-*`` rows of /debug/budget attribution and the
-        slo_stage_p50_ms gauges (one observation each; re-calling
-        replaces the window so the profile stays current)."""
-        for name, ms in stages.items():
-            key = name if name.startswith("device-") else f"device-{name}"
-            dq = self._stage(key)
-            dq.clear()
-            dq.append(float(ms))
-            self._device_profile[key] = float(ms)
-        self._dirty = True
-
-    @property
-    def device_profile(self) -> Dict[str, float]:
-        return dict(self._device_profile)
 
     def probe_link(self) -> Optional[dict]:
         """Run the devloop link probe and record its result.  Safe to
@@ -399,7 +363,6 @@ class BudgetLedger:
                "compute_p50_ms": compute,
                "stages": summary,
                "dispatch": self.dispatch_summary(),
-               "device_profile": dict(self._device_profile),
                "rungs": {}}
         for rung in SLO_LADDER + ((active,) if active is not None
                                   and active.name.startswith("custom_")
@@ -583,18 +546,11 @@ def register_slo_gauges(ledger: Optional[BudgetLedger] = None,
         "p50 submit-to-launch gap per frame (the Python dispatch cost "
         "inside device-submit)", registry=reg)
 
-    g_halo = obsm.gauge(
-        "dngd_halo_ms",
-        "p50 spatial-shard reference-halo exchange cost per step "
-        "(ppermute inside the sharded device program; fed by the bench "
-        "halo-on/off differencing via BudgetLedger.record_spatial)",
-        registry=reg)
     g_stitch = obsm.gauge(
         "dngd_stitch_ms",
         "p50 host-side bitstream stitch/assembly cost per spatially-"
         "sharded AU (per-shard NAL concat / CABAC record-stream row "
         "stitch)", registry=reg)
-    g_halo.set_function(lambda: led._stage_p50("halo-exchange"))
     g_stitch.set_function(lambda: led._stage_p50("bitstream-stitch"))
 
     def _disp_read(which: str):
@@ -711,13 +667,6 @@ def render_budget_text(ledger: Optional[BudgetLedger] = None) -> str:
             bar = "#" * min(60, int(a["budget_pct"] * 0.6))
             lines.append(f"  {a['stage']:<16} {a['p50_ms']:>9.3f} ms "
                          f"{a['budget_pct']:>6.1f}%  {bar}")
-    if ev.get("device_profile"):
-        lines.append("")
-        lines.append("device stage profile (devloop; inside the fused "
-                     "device step — attributes ME/deblock/entropy):")
-        for name, ms in sorted(ev["device_profile"].items(),
-                               key=lambda kv: -kv[1]):
-            lines.append(f"  {name:<16} {ms:>9.3f} ms")
     g2g = _journey_summary()
     if g2g:
         lines.append("")

@@ -63,8 +63,8 @@ _enabled = True
 
 
 def set_enabled(v: bool) -> None:
-    """Master switch (the bench's content_overhead_pct A/B arm): off
-    means the encoders dispatch NO stats work at all."""
+    """Master switch: off means the encoders dispatch NO stats work at
+    all."""
     global _enabled
     _enabled = bool(v)
 

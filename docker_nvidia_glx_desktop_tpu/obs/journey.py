@@ -68,8 +68,8 @@ _ENABLED = True
 
 
 def set_enabled(flag: bool) -> None:
-    """Master switch for the bench --quick trace-overhead A/B: off turns
-    mint/complete/close into early returns on the identical code path."""
+    """Master switch: off turns mint/complete/close into early returns
+    on the identical code path."""
     global _ENABLED
     _ENABLED = bool(flag)
 

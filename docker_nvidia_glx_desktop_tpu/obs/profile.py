@@ -22,11 +22,11 @@ the per-stage numbers a LIVE property of the process:
   fired since that stage's previous sample (or it is the stage's first)
   and ``phase="steady"`` otherwise — cold-jit and steady-state separate
   cleanly on the same histogram family.
-- **Cost-analysis capture**: callers with concrete arguments in hand
-  (``ops.devloop.capture_cost_analysis``) lower a jitted step and feed
-  XLA's own cost model (flops / bytes accessed) via
-  :meth:`KernelProfiler.note_cost_analysis` — the static half of the
-  cold/steady story, served next to the measured timings.
+- **Cost-analysis capture**: a caller with a compiled step in hand
+  feeds XLA's own cost model (``compiled.cost_analysis()``: flops /
+  bytes accessed) via :meth:`KernelProfiler.note_cost_analysis` — the
+  static half of the cold/steady story, served next to the measured
+  timings.
 - ``/debug/profile`` (obs/http) exports the bounded sample ring as
   Chrome trace-event JSON (open it in Perfetto / ``chrome://tracing``);
   ``?format=json`` returns the structured snapshot BENCH embeds.
@@ -211,8 +211,8 @@ class KernelProfiler:
 
     def note_cost_analysis(self, name: str, info: dict) -> None:
         """Record XLA's static cost model for one compiled step (flops /
-        bytes accessed / utilization) — fed by ops.devloop.
-        capture_cost_analysis with the caller's concrete arguments."""
+        bytes accessed / utilization), as ``compiled.cost_analysis()``
+        gives it."""
         keep = {}
         for k, v in (info or {}).items():
             if k in ("flops", "bytes accessed") or k.startswith(
@@ -232,7 +232,7 @@ class KernelProfiler:
     def stage_summary(self) -> Dict[str, Dict[str, float]]:
         """{stage: {p50, p90, p99, n, cold_n}} over the sample ring
         (exact percentiles from raw samples — the histograms serve
-        Prometheus, this serves BENCH and the tripwire)."""
+        Prometheus, this serves BENCH and ``/debug/profile``)."""
         samples = list(self._ring)
         by_stage: Dict[str, list] = {}
         cold: Dict[str, int] = {}

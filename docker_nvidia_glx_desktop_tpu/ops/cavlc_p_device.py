@@ -400,8 +400,7 @@ def encode_p_cavlc_frame(y, cb, cr, ref_y, ref_cb, ref_cr,
     from . import h264_inter
 
     out = h264_inter.encode_p_frame.__wrapped__(
-        y, cb, cr, ref_y, ref_cb, ref_cr, qp, "alt", tune, next_y,
-        p_intra)
+        y, cb, cr, ref_y, ref_cb, ref_cr, qp, tune, next_y, p_intra)
     return _finish_p(out, hdr_vals, hdr_lens, slice_qp=qp)
 
 

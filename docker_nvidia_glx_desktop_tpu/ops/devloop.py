@@ -1,10 +1,10 @@
-"""Device-resident encode-throughput loops (the compute-only benchmark).
+"""The GOP-chunk super-step (:func:`build_p_chunk_step`, the served ring),
+the link probe, and two device-resident encode loops.
 
-The serving benchmark measures the whole pipeline — host color conversion,
-host->device transfer, device encode, bitstream pull.  The host stages and
-the link can hide what the device itself can sustain (the
-reference's NVENC envelope is opaque silicon; ours is measurable).  These
-loops answer the device-only question honestly:
+The loops time what the device alone sustains, apart from host colour
+conversion, transfer and pull — on a chip; on the CPU backend they time
+XLA:CPU, which nobody serves (the benchmark's device trace is the
+yardstick: PERF.md section 5):
 
 - K encode steps run inside ONE ``lax.fori_loop`` with the trip count as a
   *traced* scalar (one compile, any K) and a data dependency per iteration
@@ -76,111 +76,6 @@ def p_loop(y, cb, cr, ref_y, ref_cb, ref_cr, hv, hl, steps, qp: int,
             ry2, rcb2, rcr2 = h264_deblock.deblock_frame(
                 ry2, rcb2, rcr2, qp, nnz_blk=nnz, mv=mv)
         acc = acc + flat[cavlc_device.META_WORDS * 4].astype(jnp.uint32)
-        return acc, ry2, rcb2, rcr2
-
-    out = lax.fori_loop(0, steps, body,
-                        (jnp.uint32(0), ref_y, ref_cb, ref_cr))
-    return out[0]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("qp", "i16_modes", "binarize"))
-def cabac_intra_loop(y, cb, cr, steps, qp: int, i16_modes: str = "auto",
-                     binarize: bool = False):
-    """``steps`` CABAC-path device stages (intra transform+quant +
-    compaction — everything that runs on device per frame when
-    ``ENCODER_ENTROPY=cabac``; the host stage overlaps in the serving
-    pipeline).  ``binarize=True`` measures the round-6 split (device
-    binarization + ctxIdx via ops/cabac_binarize — the host then runs
-    only the arithmetic engine); False keeps the round-5 level_pack
-    transport for the old/new comparison."""
-    from . import cabac_binarize, h264_device, level_pack
-
-    def body(i, acc):
-        lv = h264_device.encode_intra_frame_yuv(
-            _perturb(y, i), _perturb(cb, i), _perturb(cr, i), qp,
-            i16_modes=i16_modes)
-        if binarize:
-            buf = cabac_binarize.binarize_intra(
-                lv["luma_dc"], lv["luma_ac"], lv["cb_dc"], lv["cb_ac"],
-                lv["cr_dc"], lv["cr_ac"], lv["pred_mode"], lv["mb_i4"],
-                lv["i4_modes"], lv["luma_i4"])
-        else:
-            buf = level_pack.pack_levels(lv, level_pack.INTRA_KEYS)
-        return acc + buf[2].astype(jnp.uint32)
-
-    return lax.fori_loop(0, steps, body, jnp.uint32(0))
-
-
-@functools.partial(jax.jit, static_argnames=("qp", "refine"))
-# not donated on purpose — see p_loop.
-# dngd: ignore[jax-donate-missing]
-def inter_loop(y, cb, cr, ref_y, ref_cb, ref_cr, steps, qp: int,
-               refine: str = "alt"):
-    """``steps`` inter stages (ME/MC/residual, NO deblock or entropy),
-    recon-chained — isolates the ME-dominated stage so the round-6
-    alternate-line refinement ("alt") can be profiled against the
-    round-5 full-line re-rank ("full")."""
-    from . import h264_inter
-
-    def body(i, carry):
-        acc, ry, rcb, rcr = carry
-        out = h264_inter.encode_p_frame(
-            _perturb(y, i), _perturb(cb, i), _perturb(cr, i),
-            ry, rcb, rcr, qp=qp, refine=refine)
-        acc = acc + out["luma"][0, 0, 0, 0].astype(jnp.uint32)
-        return acc, out["recon_y"], out["recon_cb"], out["recon_cr"]
-
-    out = lax.fori_loop(0, steps, body,
-                        (jnp.uint32(0), ref_y, ref_cb, ref_cr))
-    return out[0]
-
-
-@functools.partial(jax.jit, static_argnames=("qp",))
-def deblock_loop(y, cb, cr, steps, qp: int):
-    """``steps`` loop-filter applications chained through their output
-    (intra bS pattern) — isolates the deblock stage."""
-    from . import h264_deblock
-
-    def body(i, carry):
-        acc, fy, fcb, fcr = carry
-        fy, fcb, fcr = h264_deblock.deblock_frame(
-            _perturb(fy, i), _perturb(fcb, i), _perturb(fcr, i), qp)
-        return acc + fy[0, 0].astype(jnp.uint32), fy, fcb, fcr
-
-    out = lax.fori_loop(0, steps, body, (jnp.uint32(0), y, cb, cr))
-    return out[0]
-
-
-@functools.partial(jax.jit, static_argnames=("qp", "deblock", "binarize"))
-# not donated on purpose — see p_loop.
-# dngd: ignore[jax-donate-missing]
-def cabac_p_loop(y, cb, cr, ref_y, ref_cb, ref_cr, steps, qp: int,
-                 deblock: bool = True, binarize: bool = False):
-    """``steps`` CABAC-path P device stages (inter predict + transform +
-    quant + deblock + compaction), recon-chained like :func:`p_loop`.
-    ``binarize=True`` measures the round-6 device-binarization split."""
-    from . import cabac_binarize, h264_deblock, h264_inter, level_pack
-    from .h264_device import nnz_blocks_raster
-
-    def body(i, carry):
-        acc, ry, rcb, rcr = carry
-        out = h264_inter.encode_p_frame(
-            _perturb(y, i), _perturb(cb, i), _perturb(cr, i),
-            ry, rcb, rcr, qp=qp)
-        ry2, rcb2, rcr2 = (out["recon_y"], out["recon_cb"],
-                           out["recon_cr"])
-        if deblock:
-            ry2, rcb2, rcr2 = h264_deblock.deblock_frame(
-                ry2, rcb2, rcr2, qp, nnz_blk=nnz_blocks_raster(out["luma"]),
-                mv=out["mv"].astype(jnp.int32))
-        if binarize:
-            buf = cabac_binarize.binarize_p(
-                out["mv"], out["luma"], out["cb_dc"], out["cb_ac"],
-                out["cr_dc"], out["cr_ac"])
-        else:
-            buf = level_pack.pack_levels(out, level_pack.P_KEYS)
-        acc = acc + buf[2].astype(jnp.uint32)
         return acc, ry2, rcb2, rcr2
 
     out = lax.fori_loop(0, steps, body,
@@ -321,7 +216,7 @@ def build_p_chunk_step(qp: int, deblock: bool = True,
                     next_y, p_intra)
         else:
             out = h264_inter.encode_p_frame.__wrapped__(
-                y, cb, cr, ry, rcb, rcr, qp, "alt", tune, next_y)
+                y, cb, cr, ry, rcb, rcr, qp, tune, next_y)
             ny, ncb, ncr = (out["recon_y"], out["recon_cb"],
                             out["recon_cr"])
             mv = out["mv"]
@@ -478,30 +373,3 @@ def measure_steady_state(loop_fn, *, budget_s: float = 60.0,
     return {"step_ms": round(step_s * 1e3, 3),
             "fps": round(1.0 / step_s, 1),
             "k_hi": k_hi}
-
-
-def capture_cost_analysis(name: str, jitted, *args, **static_kw) -> dict:
-    """Lower+compile ``jitted`` for ``args`` and publish XLA's cost
-    analysis (flops, bytes accessed, utilization) into the kernel
-    profiler (obs/profile) under ``name``.
-
-    This is the static half of the profiling plane: the histograms say
-    what a stage COSTS on the wall clock, the cost analysis says what
-    XLA thinks the computation IS — together they separate "the kernel
-    got slower" from "the kernel got bigger".  Compiling here is a
-    cache hit whenever the serving path already jitted the same shapes,
-    so calling it after a warmup round is effectively free.
-
-    Returns the captured dict ({} when the backend exposes none).
-    """
-    from ..obs.profile import PROFILER
-
-    try:
-        lowered = jitted.lower(*args, **static_kw)
-        info = lowered.compile().cost_analysis()
-    except Exception:
-        return {}
-    if not info:
-        return {}
-    PROFILER.note_cost_analysis(name, info)
-    return info

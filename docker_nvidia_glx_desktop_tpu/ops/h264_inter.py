@@ -267,11 +267,10 @@ def _mb_windows(tiles, off_y, off_x, dlim: int, size: int):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("qp", "refine", "tune", "p_intra"),
+                   static_argnames=("qp", "tune", "p_intra"),
                    donate_argnames=RING_DONATE)
 def encode_p_frame(y, cb, cr, ref_y, ref_cb, ref_cr, qp: int,
-                   refine: str = "alt", tune: str = "off", next_y=None,
-                   p_intra: bool = False):
+                   tune: str = "off", next_y=None, p_intra: bool = False):
     """Device stage for one P frame (planes already MB-padded).
 
     The reference planes are DONATED (:data:`RING_DONATE`; empty only
@@ -293,21 +292,20 @@ def encode_p_frame(y, cb, cr, ref_y, ref_cb, ref_cr, qp: int,
                 for r in (ref_y, ref_cb, ref_cr)]
         ref_pads = [jnp.pad(r, _PAD, mode="edge") for r in refs]
     return encode_p_frame_padded_ref(
-        y, cb, cr, *ref_pads, qp, refine=refine,
-        tune=tune, next_y=next_y, p_intra=p_intra)
+        y, cb, cr, *ref_pads, qp, tune=tune, next_y=next_y,
+        p_intra=p_intra)
 
 
 #: qp-traced twin (tune="off" only), for the per-frame CABAC path — see
 #: cavlc_device.encode_intra_cavlc_frame_yuv_dynqp.
 encode_p_frame_dynqp = jax.jit(
     encode_p_frame.__wrapped__,
-    static_argnames=("refine", "tune", "p_intra"),
+    static_argnames=("tune", "p_intra"),
     donate_argnames=RING_DONATE)
 
 
 def encode_p_frame_padded_ref(y, cb, cr, ref_y_pad, ref_cb_pad, ref_cr_pad,
-                              qp: int, refine: str = "alt",
-                              tune: str = "off", next_y=None,
+                              qp: int, tune: str = "off", next_y=None,
                               p_intra: bool = False):
     """Core P stage with the references ALREADY padded by ``_PAD`` on every
     side.  Single-device callers pad with edge replication; the
@@ -315,13 +313,10 @@ def encode_p_frame_padded_ref(y, cb, cr, ref_y_pad, ref_cb_pad, ref_cr_pad,
     halo exchange — SURVEY.md §5's context-parallel analog), which is the
     only difference between a sharded and a monolithic encode.
 
-    ``refine``: "alt" (default) evaluates the subpel-refinement SADs on
-    every other luma line — half the residual-window work of the int/
-    half/quarter re-rank stages, the round-5 "next lever".  "full" keeps
-    the full-line re-rank (the pre-round-6 behavior) for the bench's
-    old-vs-new stage profile and the pick-agreement tests.  Either way
-    the final prediction is the exact normative interpolation at the
-    winning MV, so the bitstream stays conformant — the choice only
+    The integer re-rank and both subpel-refinement stages score their
+    SADs on every other luma line (half the residual-window work).  The
+    final prediction is the exact normative interpolation at the winning
+    MV, so the bitstream stays conformant — the alternate-line scale only
     moves WHICH conformant MV wins near ties.
 
     ``tune`` (ENCODER_TUNE): "off" keeps every decision and output
@@ -381,11 +376,10 @@ def encode_p_frame_padded_ref(y, cb, cr, ref_y_pad, ref_cb_pad, ref_cr_pad,
         # --- integer motion estimation: coarse grid ------------------------
         # Alternate-line SAD (even rows only): half the abs-diff traffic and
         # half the pooled rows for the map stage that evaluates 81 candidates
-        # — the classic encoder trade.  Under refine="alt" (default) the
-        # +-1/half/quarter refinement stages below score on the SAME
-        # alternate-line scale (biases halved with it); refine="full"
-        # re-ranks with full-line SADs at full-strength biases.  The zero-MV
-        # bias here is halved to match the half-sample magnitudes.
+        # — the classic encoder trade.  The +-1/half/quarter refinement
+        # stages below score on the SAME alternate-line scale (biases
+        # halved with it).  The zero-MV bias here is halved to match the
+        # half-sample magnitudes.
         shifts = jnp.asarray(_candidate_shifts())              # (81, 2)
         y_alt = y[0::2]
 
@@ -431,18 +425,14 @@ def encode_p_frame_padded_ref(y, cb, cr, ref_y_pad, ref_cb_pad, ref_cr_pad,
     with jax.named_scope("dngd.me_int"):
         # --- +-1 integer refinement of the coarse grid ---------------------
         # An 18-wide window aligned one pel above-left of mv_coarse holds all
-        # nine candidates (center included) as static slices.  Under
-        # refine="alt" the re-rank (and both subpel stages below) evaluates
-        # the residual window on EVERY OTHER luma line — the same scale as
-        # the coarse stage, so best_sad carries cleanly into the half-pel
-        # comparison and all biases halve with it; refine="full" keeps the
-        # full-line re-rank and full-strength biases (pre-round-6 behavior).
-        # The (0,0) displacement keeps the zero-MV bias — it is reachable
-        # only as the center of a zero coarse MV — so static content stays
-        # skippable.
-        alt = refine != "full"
-        srow = 2 if alt else 1
-        scale = srow
+        # nine candidates (center included) as static slices.  The re-rank
+        # (and both subpel stages below) evaluates the residual window on
+        # EVERY OTHER luma line — the same scale as the coarse stage, so
+        # best_sad carries cleanly into the half-pel comparison and all
+        # biases halve with it.  The (0,0) displacement keeps the zero-MV
+        # bias — it is reachable only as the center of a zero coarse MV —
+        # so static content stays skippable.
+        srow = scale = 2
         cur_cmp = cur_y[:, :, 0::srow, :]
 
         w18 = _mb_windows(tiles4[0][:, :, 1:, 1:],

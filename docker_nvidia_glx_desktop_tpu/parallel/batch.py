@@ -606,20 +606,17 @@ def feasible_spatial_shards(pad_h: int, want: int,
     return min(up) if up else max(cands)
 
 
-def _spatial_halo_pad(nx: int, halo: bool = True):
+def _spatial_halo_pad(nx: int):
     """Per-shard reference padding for a SINGLE session's (h_l, w)
     planes: ``_PAD`` rows of neighbor halo over ``ppermute`` at interior
-    seams, edge replication at frame edges.  ``halo=False`` replaces the
-    exchange with edge replication everywhere — wrong bytes, identical
-    compute shape — the measurement-only twin the bench differences to
-    attribute the halo-exchange cost (obs/budget ``dngd_halo_ms``)."""
+    seams, edge replication at frame edges."""
     from ..ops.h264_inter import _PAD
 
     perm_down = [(i, i + 1) for i in range(nx - 1)]
     perm_up = [(i + 1, i) for i in range(nx - 1)]
 
     def pad(ref):
-        if nx == 1 or not halo:
+        if nx == 1:
             return jnp.pad(ref, ((_PAD, _PAD), (_PAD, _PAD)),
                            mode="edge")
         top_halo = jax.lax.ppermute(ref[-_PAD:], "spatial", perm_down)
@@ -828,9 +825,8 @@ def _spatial_encode_frame(entropy: str, deblock: bool, qp: int,
 
 def h264_spatial_step(mesh: Mesh, frame_h: int, frame_w: int,
                       qp: int = 26, deblock: bool = False,
-                      entropy: str = "cavlc", halo: bool = True,
-                      tune: str = "off", p_intra: bool = False,
-                      masked: bool = False):
+                      entropy: str = "cavlc", tune: str = "off",
+                      p_intra: bool = False, masked: bool = False):
     """Build the jitted single-session SPATIAL **P** step (the tentpole
     kernel): ME/MC with the reference halo exchanged over ``ppermute``,
     per-shard in-loop deblock, per-shard entropy.
@@ -843,10 +839,6 @@ def h264_spatial_step(mesh: Mesh, frame_h: int, frame_w: int,
     with references consumed/returned SHARDED under the identical
     ``P("spatial", None)`` spec (ring contract), ``mv``/``levels``
     lazy for the overflow fallback.
-
-    ``halo=False`` builds the measurement twin (edge replication at the
-    seams — wrong bytes, same compute/collective shape minus the
-    ppermute): differencing the two attributes the halo-exchange cost.
     """
     ns, nx = mesh.devices.shape
     assert ns == 1, "spatial steps serve ONE session"
@@ -862,8 +854,8 @@ def h264_spatial_step(mesh: Mesh, frame_h: int, frame_w: int,
         lv_keys = lv_keys + ("mb_intra", "i16_dc", "i16_ac")
     lv_spec = {k: P("spatial") for k in lv_keys}
     encode_one = _spatial_encode_frame(entropy, deblock, qp,
-                                       _spatial_halo_pad(nx, halo=halo),
-                                       tune=tune, p_intra=p_intra)
+                                       _spatial_halo_pad(nx), tune=tune,
+                                       p_intra=p_intra)
 
     if entropy == "cavlc" and masked:
         # damage-masked variant: one extra (rows,) bool input sharded

@@ -21,13 +21,12 @@ Tier-1 on purpose (not in conftest's ``_SLOW_MODULES``).  Only one
 process may hold the TPU library, so everything that touches the
 topology lives in module-scoped fixtures of THIS file — nothing at
 import, in a ``skipif``, a ``parametrize`` argument or ``conftest.py`` —
-and the compiles run in this process, two at a time on threads (an XLA
-compile releases the GIL), with the persistent cache off around them (a
-described-topology entry can be written but never read back).
+and the compiles run in this process, one after the other, with the
+persistent cache off around them (a described-topology entry can be
+written but never read back).
 """
 
 import os
-from concurrent.futures import ThreadPoolExecutor
 
 import pytest
 
@@ -141,14 +140,12 @@ def programs(topo, no_persistent_cache):
         except Exception as e:          # re-raised by the test that owns it
             return name, e
 
-    # Two at a time, longest first, and never more: six TPU compiles
-    # side by side overflow the stack of the installed libtpu's
-    # compiler (SIGSEGV in TpuBroadcastRewriter; PERF.md Findings,
-    # PR 22), and a worker that dies takes the whole file with it.
-    order = ("mesh41", "p", "intra", "binarize_intra", "binarize_p",
-             "deblock_p")
-    with ThreadPoolExecutor(2) as ex:
-        return dict(ex.map(compile_one, ((k, lowered[k]) for k in order)))
+    # One at a time, on this thread: six TPU compiles side by side
+    # overflow the stack of the installed libtpu's compiler (SIGSEGV in
+    # TpuBroadcastRewriter; PERF.md Findings, PR 22), and two side by
+    # side still died in 2 of 5 whole runs under six workers (PR 30).  A
+    # worker that dies takes the whole file with it, and the run hangs.
+    return dict(map(compile_one, lowered.items()))
 
 
 def _compiled(programs, name):

@@ -20,6 +20,38 @@ class TestCodecFactory:
         assert enc._rate is not None
         assert enc._rate.target_bits == pytest.approx(2000 * 1000 / 30)
 
+    def test_make_encoder_is_the_one_factory_and_builds_what_is_served(self):
+        """No second factory beside ``make_encoder`` (a bench once timed
+        one that built an all-intra encoder without loop filter or rate
+        control), and the defaults are the deployment PERF.md section 4
+        measures: device CAVLC, the loop filter on, GOP 60, CBR."""
+        import inspect
+
+        from docker_nvidia_glx_desktop_tpu import models
+
+        factories = [n for n, f in vars(models).items()
+                     if inspect.isfunction(f) and not n.startswith("_")]
+        assert factories == ["make_encoder"]
+        enc, name = make_encoder(from_env({}), 64, 48)
+        assert name == "h264_cavlc"
+        assert enc.mode == "cavlc" and enc.entropy == "device"
+        assert enc.deblock and enc.gop == 60 and enc._rate is not None
+
+    @pytest.mark.parametrize("function,gone", [
+        ("ops.h264_inter:encode_p_frame", "refine"),
+        ("parallel.batch:h264_spatial_step", "halo"),
+    ])
+    def test_no_parameter_selects_a_measurement_twin(self, function, gone):
+        """The alternate-line search and the exchanged halo are THE
+        paths: no argument builds another program beside the served one."""
+        import importlib
+        import inspect
+
+        module, name = function.split(":")
+        f = getattr(importlib.import_module(
+            f"docker_nvidia_glx_desktop_tpu.{module}"), name)
+        assert gone not in inspect.signature(f).parameters
+
     def test_legacy_aliases(self):
         for legacy in ("nvh264enc", "x264enc"):
             cfg = from_env({"WEBRTC_ENCODER": legacy})

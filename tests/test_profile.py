@@ -375,7 +375,7 @@ class TestSeriesOverflowCounter:
 
 
 # ---------------------------------------------------------------------------
-# Provenance + tripwire
+# Provenance
 # ---------------------------------------------------------------------------
 
 class TestProvenance:
@@ -405,64 +405,8 @@ class TestProvenance:
         assert knobs["ENCODER_TUNE"] == "hq"
         assert "UNRELATED_SECRET" not in knobs
 
-    def test_tripwire_pass_and_intersection(self):
-        res = obspv.stage_p50_tripwire(
-            {"a": 10.0, "b": 5.0, "new-stage": 99.0},
-            {"a": 10.0, "b": 4.0, "removed": 1.0})
-        assert res["ok"]
-        assert set(res["compared"]) == {"a", "b"}   # intersection only
-        assert res["regressions"] == {}
-
-    def test_tripwire_fail_names_the_stage(self):
-        res = obspv.stage_p50_tripwire({"a": 20.0}, {"a": 10.0})
-        assert not res["ok"]
-        reg = res["regressions"]["a"]
-        assert reg["limit_ms"] == pytest.approx(10.0 * 1.25 + 2.0)
-        assert reg["got_ms"] == 20.0
-
-    def test_tripwire_guard_absorbs_tiny_stages(self):
-        """A 0.1 ms stage tripling is noise, not a regression — the
-        absolute guard keeps percentage gates honest at micro scales."""
-        res = obspv.stage_p50_tripwire({"ring-collect": 0.3},
-                                       {"ring-collect": 0.1})
-        assert res["ok"]
-
-    def test_tripwire_cli_pass_fail_and_backend_gate(self, tmp_path):
-        base = {"backend": "cpu",
-                "profile_stage_p50_ms": {"a": 10.0}}
-        bp = tmp_path / "baseline.json"
-        bp.write_text(json.dumps(base))
-
-        def artifact(p50):
-            art = tmp_path / "bench_quick.json"
-            art.write_text("progress line, not json\n" + json.dumps(
-                {"profile": {"stage_p50_ms_steady": {"a": p50}},
-                 "provenance": {"topology": {"backend": "cpu"}}}) + "\n")
-            return str(art)
-
-        ok = obspv._tripwire_cli(
-            ["--tripwire", artifact(11.0), "--baseline", str(bp)])
-        assert ok == 0
-        bad = obspv._tripwire_cli(
-            ["--tripwire", artifact(50.0), "--baseline", str(bp)])
-        assert bad == 1
-        # baseline recorded on another backend -> refuse to compare
-        base["backend"] = "tpu"
-        bp.write_text(json.dumps(base))
-        assert obspv._tripwire_cli(
-            ["--tripwire", artifact(11.0), "--baseline", str(bp)]) == 1
-
-    def test_tripwire_cli_no_baseline_block_is_informational(self, tmp_path):
-        bp = tmp_path / "baseline.json"
-        bp.write_text(json.dumps({"stages": {}}))
-        art = tmp_path / "a.json"
-        art.write_text(json.dumps(
-            {"profile": {"stage_p50_ms_steady": {"a": 1.0}}}) + "\n")
-        assert obspv._tripwire_cli(
-            ["--tripwire", str(art), "--baseline", str(bp)]) == 0
-
     def test_bench_snapshot_embeds_all_planes(self):
-        snap = obspv.bench_snapshot(include_metrics=False)
+        snap = obspv.bench_snapshot()
         assert "provenance" in snap
         assert "profile" in snap
         assert "slo" in snap

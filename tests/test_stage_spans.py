@@ -101,6 +101,85 @@ def test_dispatch_accounting_rides_the_dispatch_span(encoder):
     assert enc.pop_dispatch_sample() == (1, 0.0)
 
 
+def test_no_compile_when_qp_walks_the_ladder(encoder):
+    """The steady window compiles nothing: after one IDR and one P frame
+    the traced-qp programs serve every rung of the rate ladder, the
+    degrade bias and a new IDR without one compile request."""
+    enc = encoder
+    for _ in range(2):                          # a P frame, warm
+        enc.encode_collect(enc.encode_submit(frame(6)))
+    compiles = obsm.REGISTRY.get("jax_compile_cache_requests_total")
+    requests = compiles.value
+    try:
+        for c, qp in enumerate((36, 18, 44, 16, None)):
+            enc._forced_qp = qp
+            if c == 3:
+                enc._force_idr = True
+            if qp is None:
+                enc.degrade_qp_offset = 4       # the rate ladder, biased
+            ef = enc.encode_collect(enc.encode_submit(frame(7 + c)))
+            assert ef.keyframe == (c == 3)
+    finally:
+        enc._forced_qp, enc.degrade_qp_offset = None, 0
+    assert compiles.value == requests
+
+
+def drive(enc, frames) -> list:
+    """The session loop's pipelined shape at the encoder's own depth."""
+    out, pend = [], []
+    for f in frames:
+        pend.append(enc.encode_submit(f))
+        while len(pend) >= enc.pipeline_depth:
+            out.append(enc.encode_collect(pend.pop(0)).data)
+    while pend:
+        out.append(enc.encode_collect(pend.pop(0)).data)
+    return out
+
+
+def content(kind: str, n: int = 9) -> list:
+    """``noise``: every macroblock changes every frame.  ``calm``: a
+    still picture with one 16x16 block of noise walking along the top."""
+    r = np.random.default_rng(20)
+    if kind == "noise":
+        return [r.integers(0, 256, (H, W, 3), np.uint8) for _ in range(n)]
+    out = []
+    for i in range(n):
+        f = frame(0)
+        x0 = (16 * i) % (W - 16)
+        f[0:16, x0:x0 + 16] = r.integers(0, 256, (16, 16, 3), np.uint8)
+        out.append(f)
+    return out
+
+
+def raw_encoder(**options):
+    from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
+
+    return H264Encoder(W, H, mode="cavlc", entropy="device",
+                       host_color=True, gop=9, **options)
+
+
+# Python->device crossings over one GOP of 9 frames (1 IDR + 8 P)
+@pytest.mark.parametrize("options,kind,crossings", [
+    ({}, "noise", 9),                           # the per-frame path
+    ({"superstep_chunk": 4}, "noise", 1 + 2),   # the ring: one a chunk
+    ({"damage_mask": True}, "calm", 9),         # the row worklist rides
+    ({"damage_mask": True}, "noise", 9),        # the submit crossing
+], ids=["per_frame", "ring", "masked_calm", "masked_full"])
+def test_crossings_a_gop(options, kind, crossings):
+    """One crossing a frame on the per-frame path, with or without the
+    damage mask and whatever the damage; one a chunk on the ring."""
+    enc = raw_encoder(**options)
+    assert len(drive(enc, content(kind))) == 9
+    assert enc._disp_count == crossings
+
+
+def test_full_damage_through_the_mask_is_the_unmasked_stream():
+    """Every row damaged: the masked encoder emits the mask-off bytes."""
+    frames = content("noise")
+    assert drive(raw_encoder(damage_mask=True), frames) == drive(
+        raw_encoder(), frames)
+
+
 @pytest.fixture(scope="module")
 def served():
     """Frames served by a StreamSession at 128x96 (GOP 4: IDRs and P
