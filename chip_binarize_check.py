@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
-"""The two CABAC binarize programs on the chip against XLA:CPU, by hand.
+"""The entropy fronts' packers on the chip against XLA:CPU, by hand.
 
     chiprun --timeout 1800 -- python3 chip_binarize_check.py
+    chiprun --timeout 1800 -- python3 chip_binarize_check.py --cavlc
 
 On the TPU ``ops/cabac_binarize`` packs its record slots with the two Pallas
 kernels of ``ops/cabac_pack``; everywhere else with the bitmerge hierarchy.
@@ -13,6 +14,13 @@ traffics at qp 20, 32 and 44, twelve transport buffers from the chip, each
 against XLA:CPU's from the same level tensors, whole buffer, word for word;
 then the programs' device time.  One JSON line a picture; the last line is
 ``ALL_IDENTICAL`` and the exit code 0 only if all twelve are.
+
+``--cavlc``: the same for the CAVLC programs' ``flat`` (``cavlc_device.
+pack_frame``: the same two kernels on the TPU since PR 31), at 1920x1088 and
+2560x1600, the I and the P picture of three pictures each, whole buffer,
+byte for byte; then device time by inner scope of ``dngd.pack`` from a trace
+of four calls of each program and of its bitmerge form compiled for the chip
+(what ran there before PR 31; ``chiprun_out/cavlc_pack_<H>.xplane.pb``).
 """
 
 import json
@@ -39,19 +47,20 @@ I_KEYS = ("luma_dc", "luma_ac", "cb_dc", "cb_ac", "cr_dc", "cr_ac",
           "pred_mode", "mb_i4", "i4_modes", "luma_i4")
 
 
-def pictures():
+def pictures(w=W, h=H, qps={"desktop": (20, 32, 44),
+                             "fulldamage": (20, 32, 44)}):
     """(name, binarize_p's arguments, binarize_intra's) from the served
     device stages: frame 200 as an I picture, frame 201 predicted from it."""
     for kind, seed in (("desktop", 3141592653), ("fulldamage", 2718281828)):
         traffic = json.loads(
             (ROOT / "benchmark" / "traffic" / f"{kind}.json").read_text())
-        scene = build_scene(traffic, W, H, 60, seed)
+        scene = build_scene(traffic, w, h, 60, seed)
         planes = []
         for c in (200, 201):
-            rgb = np.zeros((H, W, 3), np.uint8)
+            rgb = np.zeros((h, w, 3), np.uint8)
             scene.render(c, rgb)
-            planes.append(rgb_to_yuv420_host(rgb, H, W, float_fallback=True))
-        for qp in (20, 32, 44):
+            planes.append(rgb_to_yuv420_host(rgb, h, w, float_fallback=True))
+        for qp in qps[kind]:
             lv = h264_device.encode_intra_frame_yuv_dynqp(
                 *map(jnp.asarray, planes[0]), np.int32(qp),
                 i16_modes="auto", tune="off")
@@ -63,10 +72,124 @@ def pictures():
                    [np.asarray(lv[k]) for k in I_KEYS])
 
 
+def _flat(kind, hv, hl, *levels):
+    """``flat`` of an I or a P picture from its level tensors: the served
+    programs' ``dngd.slots`` and ``dngd.pack``, without the stages in front."""
+    from docker_nvidia_glx_desktop_tpu.ops import cavlc_device, cavlc_p_device
+
+    none = jnp.zeros((1, 1), jnp.uint8)          # the recon rides through
+    lv = dict(zip(P_KEYS if kind == "p" else I_KEYS, levels),
+              recon_y=none, recon_cb=none, recon_cr=none)
+    if kind == "p":
+        return cavlc_p_device._finish_p(lv, hv, hl, slice_qp=26)[0]
+    return cavlc_device._finish_cavlc(lv, hv, hl, False, 26)
+
+
+def _program(name, kind):
+    """A jit of ``_flat`` through a function of its own (JAX keeps a trace
+    by the function), under a name the trace can be read by."""
+    def fn(*a):
+        return _flat(kind, *a)
+    fn.__name__ = name
+    return jax.jit(fn)
+
+
+def _pack_scopes(path) -> dict:
+    """{program: {scope: device self time, ms a call}} of a trace of
+    ``_flat``: the ``dngd.`` stages, ``dngd.pack`` by the scope inside it."""
+    from benchmark import stage_reduce
+
+    planes = stage_reduce.load(str(path))
+    dev = planes[min(p for p in planes
+                     if p.startswith(stage_reduce.DEVICE_PREFIX))]
+    calls = {}
+    for name, *_ in dev.get(stage_reduce.MODULES_LINE, ()):
+        name = stage_reduce.short_module(name)
+        calls[name] = calls.get(name, 0) + 1
+    out = {}
+    for (_name, _s, _e, op_name), ps in stage_reduce.self_times(
+            dev.get(stage_reduce.OPS_LINE, ())):
+        parts = op_name.split("/")
+        prog = "jit_" + parts[0][4:-1] if parts[0][:4] == "jit(" else "-"
+        scope = stage_reduce.scope_of(op_name)
+        if scope == "dngd.pack":
+            inner = parts[parts.index(scope) + 1:-1]
+            scope += "/" + (inner[0] if inner and inner[0][:4] != "jit("
+                            else "(no inner scope)")
+        by = out.setdefault(prog, {})
+        by[scope] = by.get(scope, 0.0) + ps / 1e9 / calls.get(prog, 1)
+    return {prog: {k: round(v, 4) for k, v in
+                   sorted(by.items(), key=lambda kv: -kv[1])}
+            for prog, by in out.items()}
+
+
+def cavlc() -> int:
+    """The CAVLC ``flat`` of the chip (the kernels) against XLA:CPU's (the
+    bitmerge hierarchy) on the same levels, then where the time goes."""
+    from docker_nvidia_glx_desktop_tpu.ops import cavlc_device
+
+    cpu = jax.devices("cpu")[0]
+    out_dir = ROOT / "chiprun_out"
+    out_dir.mkdir(exist_ok=True)
+    same = True
+    for w, h in ((1920, 1088), (2560, 1600)):
+        kinds = ("p", "intra")
+        on_chip = {k: _program(f"flat_{k}", k) for k in kinds}
+        before = {k: _program(f"flat_{k}_bitmerge", k) for k in kinds}
+        on_cpu = {k: _program(f"flat_{k}_cpu", k) for k in kinds}
+        pics = list(pictures(w, h, qps={"desktop": (20,),
+                                        "fulldamage": (26, 38)}))
+        timed = {}
+        for name, p_args, i_args in pics:
+            for kind, args in (("p", p_args), ("intra", i_args)):
+                hv, hl = cavlc_device.slice_header_slots(
+                    h // 16, w // 16, frame_num=5, deblocking_idc=2,
+                    **({"slice_type": 5, "idr": False} if kind == "p"
+                       else {}))
+                got = np.asarray(on_chip[kind](hv, hl, *args))
+                with mock.patch.object(jax, "default_backend",
+                                       lambda: "cpu"):
+                    want = np.asarray(on_cpu[kind](
+                        *[jax.device_put(a, cpu) for a in (hv, hl, *args)]))
+                meta = cavlc_device.FlatMeta(want, h // 16)
+                if meta.overflow:       # the flag is all such a buffer says
+                    got, want = got[:4], want[:4]
+                differing = (int((got != want).sum())
+                             if got.shape == want.shape else -1)
+                same &= differing == 0
+                print(json.dumps({
+                    "picture": f"{w}x{h}.{name}", "kind": kind,
+                    "identical": differing == 0,
+                    "differing_bytes": differing,
+                    "stream_words": meta.total_words,
+                    "overflow": int(meta.overflow)}), flush=True)
+                timed[kind] = [jnp.asarray(a) for a in (hv, hl, *args)]
+        with mock.patch.object(jax, "default_backend", lambda: "cpu"):
+            for kind, dev in timed.items():       # traced here, run below
+                before[kind](*dev).block_until_ready()
+        trace_dir = out_dir / f"cavlc_pack_{h}"
+        jax.profiler.start_trace(str(trace_dir))
+        for kind, dev in timed.items():
+            for fn in (on_chip[kind], before[kind]):
+                for _ in range(4):
+                    fn(*dev).block_until_ready()
+        jax.profiler.stop_trace()
+        pb = next(trace_dir.rglob("*.xplane.pb"))
+        keep = out_dir / f"cavlc_pack_{h}.xplane.pb"
+        pb.replace(keep)
+        print(json.dumps({"geometry": f"{w}x{h}",
+                          "ms_per_call_by_scope": _pack_scopes(keep)}),
+              flush=True)
+    print("ALL_IDENTICAL" if same else "DIFFERENT", flush=True)
+    return 0 if same else 1
+
+
 def main() -> int:
     if jax.default_backend() != "tpu":
         print(f"no TPU: the default backend is {jax.default_backend()!r}")
         return 2
+    if "--cavlc" in sys.argv[1:]:
+        return cavlc()
     cpu = jax.devices("cpu")[0]
     # functions of their own: JAX keeps a trace by the function, and the
     # chip's programs are traces of ``cb.binarize_*``

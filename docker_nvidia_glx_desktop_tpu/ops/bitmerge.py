@@ -26,12 +26,14 @@ said: on the v5e a barrel-shifter stage (``pad`` + ``where`` over the whole
 worst-case-sized buffer) is one round trip through HBM, a tree level is 4
 to 17 of them, and whether the even/odd halves ``words[..., 0::2, :]`` are
 slices or gathers hangs on the layout XLA picks for a minor dimension of 8
-to 64.  The CAVLC programs pay 2.8 ms a 1080p frame for their row merge
-(``pack_ms``); the CABAC record packer, with 1,564 slots a macroblock, paid
-23 ms (16 GB of passes to pack under 1 MB) and now keeps the same merge in
-VMEM on the TPU (``ops/cabac_pack.py``; PERF.md, PR 29).  These functions
-stay as they are: the CAVLC row merge, ``level_pack``, the CABAC packer off
-the TPU, and the oracle the kernels are tested against.
+to 64.  The CAVLC programs paid 2.8 ms a 1080p frame (4.2 at 2560x1600) for
+this hierarchy and the gather behind it (``pack_ms``); the CABAC record
+packer, with 1,564 slots a macroblock, paid 23 ms (16 GB of passes to pack
+under 1 MB).  On the TPU both now keep the same merge in VMEM: ONE packer,
+``ops/cabac_pack.pack_rows`` (CABAC since PR 29, the CAVLC frame pack since
+PR 31; PERF.md).  Off the TPU these functions are the packer of both
+(``cavlc_device._pack_rows_bitmerge``, ``cabac_binarize._pack_rows_xla``),
+of ``level_pack``, and the oracle the kernels are tested against.
 Static caps (256 b/block, 2048 b/MB) bound the buffers;
 content that overflows them (possible only near qp<=8 on pathological
 blocks) raises a per-frame overflow flag and the caller falls back to host

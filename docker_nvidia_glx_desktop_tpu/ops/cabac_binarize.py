@@ -386,7 +386,7 @@ def _pack_stream(recs: _Recs, value_ovf):
     c2 = 1 << int(np.ceil(np.log2(c)))
     if jax.default_backend() == "tpu":
         overflow, row_bits, payload = cabac_pack.pack_rows(
-            vals, lns, value_ovf, mb_cap, c2 * mb_cap)
+            vals, lns, value_ovf, mb_cap, r * c2 * mb_cap)
     else:
         overflow, row_bits, payload = _pack_rows_xla(
             vals, lns, value_ovf, mb_cap, p2, c2)

@@ -1,13 +1,15 @@
-"""The CABAC record packer on the TPU: two Pallas kernels whose merge
-stages stay in VMEM.
+"""The bit packer of the TPU: two Pallas kernels whose merge stages stay
+in VMEM.
 
 ``cabac_binarize._pack_stream`` turns (R, C, S) record slots into the
-version-2 transport buffer.  Its XLA form (``bitmerge``'s dense L1 and two
-``merge_pieces_tree``s) is right everywhere and stays the CPU path and the
-oracle, but on the chip every barrel-shifter stage of those trees is a
-round trip of the whole worst-case-sized buffer through HBM: 16 GB of
-passes to pack under 1 MB (PERF.md, PR 29).  Here the same bits are put
-in the same places by
+version-2 transport buffer, ``cavlc_device.pack_frame`` (R, C, S) CAVLC
+slots into the rows of ``flat``.  Their XLA form (``bitmerge``'s dense L1
+and merge trees) is right everywhere and stays the CPU path and the oracle,
+but on the chip every barrel-shifter stage of those trees is a round trip
+of the whole worst-case-sized buffer through HBM: 16 GB of passes to pack
+under 1 MB of records (PERF.md, PR 29), and for CAVLC a row tree that
+doubles at 163 pieces plus a 131,072-word gather whatever the picture
+(PR 31).  Here the same bits are put in the same places by
 
   kernel A, slot -> macroblock   1,024 macroblocks on the (8, 128) lanes of
       a vreg, the word axis on the MAJOR axis.  Every 8 slots merge into
@@ -21,14 +23,19 @@ in the same places by
       along the major axis chosen per lane: no lane shuffle, no gather,
       11 stages over 1,568 words, in place.
   kernel B, macroblock -> row -> frame   walks the macroblocks in stream
-      order.  A macroblock's words are one (8, 128) chunk of 1,024 (two
-      for an I picture), its word offset a SCALAR: three rolls put the
-      chunk at its offset, two ORs put it into the row's buffer in VMEM,
-      and the chunks a row touched go to the payload by DMA.  Work follows
-      the content, not the cap.
+      order, their word offsets SCALARS.  A macroblock of up to 127 words
+      (CAVLC's 65) is one line of 128, eight macroblocks a chunk: one roll
+      a macroblock, two chunks put together in registers and OR-ed into
+      the row's buffer in VMEM.  A longer one (a CABAC P macroblock's
+      1,024 words, an I macroblock's two chunks) is whole (8, 128) chunks:
+      three rolls put a chunk at its offset, two ORs put it into the
+      buffer.  The chunks a row touched go to the payload by DMA at the
+      row's word offset.  Work follows the content, not the cap.
 
-XLA does what is left: packing (value, length) into one word, the 8,160
-bit counts and their prefix sums, two 2-D transposes, the header.
+XLA does what is left: packing (value, length) into one word, the bit
+counts and their prefix sums, two 2-D transposes, the header.  R, C, S and
+the piece size follow from the shapes and the caps: one algorithm for both
+entropy coders, the ring, the shards and the masked path's worklist.
 """
 
 from __future__ import annotations
@@ -54,23 +61,29 @@ _srl = jax.lax.shift_right_logical
 _shl = jax.lax.shift_left
 
 
-def _compact_kernel(sh_ref, slots_ref, out_ref, dd_ref, *, n_groups):
+def _compact_kernel(sh_ref, slots_ref, out_ref, w_ref, dd_ref, *, n_groups):
     """Kernel A: one tile's slots -> each macroblock's words, compacted.
 
     sh_ref (8, 128): bit phase of each macroblock's first bit in its word.
     slots_ref (8 * n_groups, 8, 128): value | length << 26, slot-major.
-    out_ref the same shape: word w of every macroblock; dd_ref (scratch):
-    how far each word still has to go."""
+    w_ref (scratch) the same shape: word w of every macroblock; dd_ref
+    (scratch): how far each word still has to go.  out_ref: the first words
+    of w_ref, as many as a macroblock's piece may hold."""
     n_words = 8 * n_groups
 
-    def group(g, bit):
+    def group(g, carry):
         """Slots 8g..8g+7 -> words 8g..8g+7, as ``bitmerge._hi_lo`` and
         ``slots_to_words`` place them; ``bit``: the stream's length so far
-        (with the phase)."""
+        (with the phase).  Eight slots of up to 32 bits behind a phase of
+        up to 31 reach into a ninth word: ``spill``, which is word 0 of
+        the next group (the same place and the same way to go: the group
+        then ends in the word its successor starts in)."""
+        bit, spill = carry
         base = g * 8
         slab = slots_ref[pl.ds(base, 8)]
         off = bit & 31
-        words = [jnp.zeros((8, 128), jnp.int32)] * 8
+        words = [spill] + [jnp.zeros((8, 128), jnp.int32)] * 7
+        spill = jnp.zeros((8, 128), jnp.int32)
         for j in range(8):
             x = slab[j]
             ln = _srl(x, _LEN_SHIFT)
@@ -86,32 +99,35 @@ def _compact_kernel(sh_ref, slots_ref, out_ref, dd_ref, *, n_groups):
                 words[k] = words[k] | jnp.where(at[k], hi, 0)
                 if k:
                     words[k] = words[k] | jnp.where(at[k - 1], lo, 0)
+            spill = spill | jnp.where(at[7], lo, 0)
             off = off + ln
         words = jnp.stack(words)
-        out_ref[pl.ds(base, 8)] = words
+        w_ref[pl.ds(base, 8)] = words
         dd_ref[pl.ds(base, 8)] = jnp.where(words != 0,
                                            base - (bit >> 5), 0)
-        return bit + off - (bit & 31)
+        return bit + off - (bit & 31), spill
 
-    jax.lax.fori_loop(0, n_groups, group, sh_ref[...])
+    # (``pack_rows`` flags a frame whose last group could spill)
+    jax.lax.fori_loop(0, n_groups, group,
+                      (sh_ref[...], jnp.zeros((8, 128), jnp.int32)))
 
     # The compress network, in place and upwards: position p takes what it
     # keeps and what comes down from p + step; a slab of positions reads
     # both before it writes, and the slabs after it have not been written.
     def move(p, size, step):
         here, there = pl.ds(p, size), pl.ds(p + step, size)
-        x0, e0 = out_ref[here], dd_ref[here]
-        x1, e1 = out_ref[there], dd_ref[there]
+        x0, e0 = w_ref[here], dd_ref[here]
+        x1, e1 = w_ref[there], dd_ref[there]
         keep = (e0 & step) == 0
         come = (e1 & step) != 0
-        out_ref[here] = jnp.where(keep, x0, 0) | jnp.where(come, x1, 0)
+        w_ref[here] = jnp.where(keep, x0, 0) | jnp.where(come, x1, 0)
         dd_ref[here] = jnp.where(keep, e0, 0) | jnp.where(come, e1, 0)
 
     def leave(p, size, step):
         here = pl.ds(p, size)
         e0 = dd_ref[here]
         keep = (e0 & step) == 0
-        out_ref[here] = jnp.where(keep, out_ref[here], 0)
+        w_ref[here] = jnp.where(keep, w_ref[here], 0)
         dd_ref[here] = jnp.where(keep, e0, 0)
 
     def sweep(fn, lo, hi, step):
@@ -132,16 +148,24 @@ def _compact_kernel(sh_ref, slots_ref, out_ref, dd_ref, *, n_groups):
         sweep(leave, n_words - step, n_words, step)
         step *= 2
 
+    def hand_out(p, size, _):
+        out_ref[pl.ds(p, size)] = w_ref[pl.ds(p, size)]
+
+    sweep(hand_out, 0, out_ref.shape[0], None)
+
 
 def _rows_kernel(gw_ref, fc_ref, pieces_ref, _zeros_ref, out_ref,
                  rowbuf, carry, sem, *, cols, piece_chunks):
     """Kernel B: one MB row's pieces -> its stretch of the payload.
 
-    gw_ref (N,) SMEM: the payload word each macroblock starts in.
-    fc_ref (R + 1,) SMEM: the payload chunk each row starts in (and the
-    chunk the last row ends in).  pieces_ref (cols, piece_chunks, 8, 128):
-    the row's macroblocks, each already at its bit phase.  out_ref: the
-    payload in HBM as chunks, zero where no row writes."""
+    gw_ref SMEM: the payload word each macroblock starts in.  fc_ref
+    (R + 1,) SMEM: the payload chunk each row starts in (and the chunk the
+    last row ends in).  pieces_ref: the row's macroblocks, each already at
+    its bit phase: (cols, piece_chunks, 8, 128), a macroblock whole chunks,
+    or with ``piece_chunks`` 0 (cols / 8, 8, 128), a macroblock one line of
+    128 words and eight macroblocks a chunk (gw_ref is then padded to the
+    same eights).  out_ref: the payload in HBM as chunks, zero where no row
+    writes."""
     r = pl.program_id(0)
     first = fc_ref[r]
     n_chunks = fc_ref[r + 1] - first + 1
@@ -151,7 +175,7 @@ def _rows_kernel(gw_ref, fc_ref, pieces_ref, _zeros_ref, out_ref,
         rowbuf[i] = jnp.zeros((8, 128), jnp.int32)
         return _
 
-    jax.lax.fori_loop(0, n_dma * _DMA_CHUNKS + piece_chunks, clear, 0)
+    jax.lax.fori_loop(0, n_dma * _DMA_CHUNKS + max(piece_chunks, 1), clear, 0)
 
     # the chunk this row starts in holds the end of the rows before it
     @pl.when(r > 0)
@@ -159,7 +183,8 @@ def _rows_kernel(gw_ref, fc_ref, pieces_ref, _zeros_ref, out_ref,
         rowbuf[0] = carry[...]
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
-    lin = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0) * 128 + lane
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    lin = sub * 128 + lane
 
     def place(c, _):
         k = gw_ref[r * cols + c] - first * CHUNK
@@ -177,7 +202,33 @@ def _rows_kernel(gw_ref, fc_ref, pieces_ref, _zeros_ref, out_ref,
             rowbuf[q + j + 1] = rowbuf[q + j + 1] | jnp.where(lin < o, t, 0)
         return _
 
-    jax.lax.fori_loop(0, cols, place, 0)
+    def place_lines(g, _):
+        """Eight macroblocks of a line each: under 1,024 words in all, so
+        they lie in the chunk the first starts in and the one behind it,
+        and both are put together in registers."""
+        at = (r * (cols // 8) + g) * 8
+        q = (gw_ref[at] - first * CHUNK) >> 10
+        lines = pieces_ref[g]
+        here = jnp.zeros((8, 128), jnp.int32)
+        behind = jnp.zeros((8, 128), jnp.int32)
+        for m in range(8):
+            k = gw_ref[at + m] - (first + q) * CHUNK      # 0 .. 2,047
+            a = k >> 7
+            b = k & 127
+            # the line turned by ``b``: what passes lane 127 belongs to
+            # the line below
+            t = pltpu.roll(jnp.broadcast_to(lines[m:m + 1], (8, 128)), b, 1)
+            row = jnp.where(lane >= b, a, a + 1)
+            here = here | jnp.where(sub == row, t, 0)
+            behind = behind | jnp.where(sub == row - 8, t, 0)
+        rowbuf[q] = rowbuf[q] | here
+        rowbuf[q + 1] = rowbuf[q + 1] | behind
+        return _
+
+    if piece_chunks:
+        jax.lax.fori_loop(0, cols, place, 0)
+    else:
+        jax.lax.fori_loop(0, cols // 8, place_lines, 0)
     carry[...] = rowbuf[n_chunks - 1]
 
     def copy(i):
@@ -201,26 +252,38 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def pack_rows(vals, lns, value_ovf, mb_cap: int, row_cap: int):
-    """The packing of ``cabac_binarize._pack_stream`` for the TPU: (overflow
-    flag, per-row bit counts, payload of R * row_cap words), the payload
-    word for word the bitmerge hierarchy's.  vals/lns (R, C, S) as
-    ``_Recs.stacked`` gives them, ``value_ovf`` (R, C); ``mb_cap`` words a
-    macroblock and ``row_cap`` words a row are the static caps."""
+def pack_rows(vals, lns, value_ovf, mb_cap: int, out_words: int):
+    """(R, C, S) slots -> (overflow flag, per-row bit counts, payload of
+    ``out_words`` words): every row's bits from a word of its own, the rows
+    one behind the other, zeros behind the last; word for word what the
+    bitmerge hierarchy gives (``cabac_binarize._pack_rows_xla``,
+    ``cavlc_device._pack_rows_bitmerge``).  A slot is a value of at most
+    26 bits and a length of at most 32 (the bits above the value are
+    zeros, as a level escape's prefix is); ``value_ovf`` (R, C) is what
+    the caller found too long by its own caps; ``mb_cap`` words a
+    macroblock and ``out_words`` in all are the static caps.  Everything
+    else follows from the shapes."""
     r, c, s = vals.shape
     s8 = _round_up(s, 8)
     n_groups = s8 // 8
     n = r * c
     n_pad = _round_up(n, TILE)
-    # words a macroblock's piece may hold, with its phase: whole chunks
-    piece_chunks = -(-(mb_cap + 1) // CHUNK)
-    piece_words = piece_chunks * CHUNK
+    # words a macroblock's piece may hold, with its phase: one line of a
+    # chunk (eight macroblocks a chunk: CAVLC's 65) or whole chunks
+    lines = mb_cap + 1 <= 128
+    piece_chunks = 0 if lines else -(-(mb_cap + 1) // CHUNK)
+    piece_words = 128 if lines else piece_chunks * CHUNK
+    c8 = _round_up(c, 8) if lines else c
 
     with jax.named_scope("cabac_offsets"):
         lns = lns.astype(jnp.int32)
         mb_bits = lns.sum(-1)                                   # (R, C)
-        overflow = value_ovf.any() | (mb_bits > 32 * mb_cap).any()
         row_bits = mb_bits.sum(-1)
+        total_words = ((row_bits + 31) >> 5).sum()
+        # (kernel A drops what the last eight slots spill behind word S)
+        overflow = (value_ovf.any() | (mb_bits > 32 * mb_cap).any()
+                    | (total_words > out_words)
+                    | (lns[..., s8 - 8:].sum(-1) > 32 * 8 - 31).any())
         # an overflowing frame is coded again by the dense path: its
         # buffer only has to carry the flag, and nothing may leave the
         # row buffers
@@ -243,44 +306,52 @@ def pack_rows(vals, lns, value_ovf, mb_cap: int, row_cap: int):
                          ((0, n_pad - n), (0, s8 - s)))
         slots = packed.T.reshape(s8, n_pad // 128, 128)
 
+    keep = min(s8, piece_words)
     with jax.named_scope("cabac_compact"):
         words = pl.pallas_call(
             functools.partial(_compact_kernel, n_groups=n_groups),
             name="cabac_compact",
-            out_shape=jax.ShapeDtypeStruct(slots.shape, jnp.int32),
+            out_shape=jax.ShapeDtypeStruct((keep,) + slots.shape[1:],
+                                           jnp.int32),
             grid=(n_pad // TILE,),
             in_specs=[pl.BlockSpec((8, 128), lambda t: (t, 0)),
                       pl.BlockSpec((s8, 8, 128), lambda t: (0, t, 0))],
-            out_specs=pl.BlockSpec((s8, 8, 128), lambda t: (0, t, 0)),
-            scratch_shapes=[pltpu.VMEM((s8, 8, 128), jnp.int32)],
+            out_specs=pl.BlockSpec((keep, 8, 128), lambda t: (0, t, 0)),
+            scratch_shapes=[pltpu.VMEM((s8, 8, 128), jnp.int32)] * 2,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",),
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
         )(phase.reshape(n_pad // 128, 128), slots)
 
     with jax.named_scope("cabac_mb_major"):
-        words = words.reshape(s8, n_pad)
-        if s8 >= piece_words:
-            words = words[:piece_words]
+        words = jnp.pad(words.reshape(keep, n_pad),
+                        ((0, piece_words - keep), (0, 0)))
+        if lines:
+            # a row's macroblocks from a chunk of their own, in eights
+            pieces = jnp.pad(words.T[:n].reshape(r, c, 128),
+                             ((0, 0), (0, c8 - c), (0, 0)))
+            pieces = pieces.reshape(r, c8 // 8, 8, 128)
+            gw = jnp.pad(gw.reshape(r, c), ((0, 0), (0, c8 - c)),
+                         mode="edge").reshape(r * c8)
+            block = (None, c8 // 8, 8, 128)
         else:
-            words = jnp.pad(words, ((0, piece_words - s8), (0, 0)))
-        pieces = words.T.reshape(n_pad, piece_chunks, 8, 128)
+            pieces = words.T.reshape(n_pad, piece_chunks, 8, 128)
+            block = (c, piece_chunks, 8, 128)
 
     # a row touches the chunks of its own words and, with the piece that
-    # ends it, ``piece_chunks`` more; the DMA writes whole groups of chunks
+    # ends it, one more; the DMA writes whole groups of chunks
     row_chunks = _round_up(-(-c * mb_cap // CHUNK) + 1, _DMA_CHUNKS)
-    n_out = -(-r * row_cap // CHUNK) + row_chunks
+    n_out = -(-out_words // CHUNK) + row_chunks
     with jax.named_scope("cabac_rows"):
         payload = pl.pallas_call(
-            functools.partial(_rows_kernel, cols=c,
+            functools.partial(_rows_kernel, cols=c8,
                               piece_chunks=piece_chunks),
             name="cabac_rows",
             out_shape=jax.ShapeDtypeStruct((n_out, 8, 128), jnp.int32),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2, grid=(r,),
                 in_specs=[
-                    pl.BlockSpec((c, piece_chunks, 8, 128),
-                                 lambda i, gw, fc: (i, 0, 0, 0)),
+                    pl.BlockSpec(block, lambda i, gw, fc: (i, 0, 0, 0)),
                     pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=pl.BlockSpec(memory_space=pl.ANY),
                 scratch_shapes=[
@@ -294,4 +365,4 @@ def pack_rows(vals, lns, value_ovf, mb_cap: int, row_cap: int):
         )(gw, fc, pieces, jnp.zeros((n_out, 8, 128), jnp.int32))
 
     payload = jax.lax.bitcast_convert_type(payload, jnp.uint32)
-    return overflow, row_bits, payload.reshape(-1)[:r * row_cap]
+    return overflow, row_bits, payload.reshape(-1)[:out_words]

@@ -18,16 +18,20 @@ whole entropy stage onto the TPU:
 2. nC contexts (§9.2.1) are pure neighbor shifts over the per-block
    total_coeff grids — no sequencing at all, because the slice-per-MB-row
    structure (ops/h264_device.py) removes cross-row dependencies.
-3. Bits are concatenated scatter-free by the :mod:`.bitmerge` hierarchy:
-   slots -> 256-bit block buffers -> 2048-bit MB buffers (dense mask
-   reductions) -> per-row slice RBSPs (barrel-shift reduction tree).
-   Pathological content that overflows the static block/MB caps sets a
-   per-frame flag and the caller falls back to host entropy (never at
-   sane qp; correctness is never silently lost).
-4. Rows are compacted into one flat buffer by an output-sized gather, with
-   a small metadata header prepended, so the host can fetch metadata +
-   bitstream in a single bucketed pull, then only does emulation-prevention
-   escaping + Annex-B NAL wrapping.
+3. Bits are concatenated scatter-free, each row's RBSP from a word of its
+   own, into one flat buffer with a small metadata header in front, so the
+   host fetches metadata + bitstream in a single bucketed pull and only
+   does emulation-prevention escaping + Annex-B NAL wrapping
+   (:func:`pack_frame`, for I and P pictures alike).  On the TPU by
+   :mod:`.cabac_pack`'s two kernels (merge stages in VMEM, rows to their
+   word offsets by DMA; PR 31); everywhere else by the :mod:`.bitmerge`
+   hierarchy (slots -> 256-bit block buffers -> 2048-bit MB buffers by
+   dense mask reductions -> rows by a barrel-shift reduction tree) and an
+   output-sized gather: the same bytes, and the tests' oracle.
+4. Pathological content that overflows the static block/MB caps or the
+   flat buffer sets a per-frame flag (the same flag in both forms) and the
+   caller falls back to host entropy (never at sane qp; correctness is
+   never silently lost).
 
 The pure-Python reference (bitstream/cavlc.py, bitstream/h264_entropy.py)
 defines the contract; tests enforce byte-identical output.
@@ -45,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..bitstream import cavlc as ref
-from . import bitmerge
+from . import bitmerge, cabac_pack
 
 # ---------------------------------------------------------------------------
 # Dense constant tables (padded to uniform shapes for device gathers)
@@ -627,77 +631,42 @@ META_QP_SUM_WORD = 2 + 2 * MAX_META_ROWS          # = 1022 < META_WORDS
 
 
 def pack_frame(values, lengths, syn_vals, syn_lens, hdr_vals, hdr_lens,
-               qp_sum=None):
-    """Scatter-free packing of a frame's CAVLC slots into row RBSPs.
+               trail_vals=None, trail_lens=None, qp_sum=None):
+    """Scatter-free packing of a picture's CAVLC slots into row RBSPs, for
+    I and P pictures alike.
 
-    Returns (flat, overflow) where ``flat`` is a (META_WORDS*4 +
-    FLAT_CAP_WORDS*4,) uint8 buffer: metadata words (flags, total words,
-    per-row byte counts and word offsets) followed by the rows' RBSPs, each
-    row starting at a 4-byte-aligned offset.  ``qp_sum`` (tune=hq) rides
-    in META_QP_SUM_WORD so the host's rate controller can normalize by
-    the mean coded qp without an extra device pull.
+    values/lengths (R, C, B, 34): the blocks' slots; syn_* (R, C, S): the
+    macroblock layer's; hdr_* (R, HDR_SLOTS): each row's slice header;
+    trail_* (R,): a P row's trailing skip run (length 0 where the row ends
+    on a coded macroblock; None for an I picture).  Returns (flat,
+    overflow) where ``flat`` is a (META_WORDS*4 + FLAT_CAP_WORDS*4,) uint8
+    buffer: metadata words (flags, total words, per-row byte counts and
+    word offsets) followed by the rows' RBSPs, each row starting at a
+    4-byte-aligned offset.  ``qp_sum`` (tune=hq) rides in
+    META_QP_SUM_WORD so the host's rate controller can normalize by the
+    mean coded qp without an extra device pull.
+
+    The rows are merged by ``ops/cabac_pack``'s two kernels on the TPU and
+    by the :mod:`.bitmerge` hierarchy everywhere else; the buffer is the
+    same, byte for byte, and so is the overflow flag.
     """
-    nr, nc_mb = syn_vals.shape[:2]
-
-    # L1: each block's 34 slots -> 8-word buffer.
-    blk_words, blk_bits, blk_ovf = bitmerge.slots_to_words(
-        values, lengths, bitmerge.BLOCK_WORDS)              # (R,C,27,8)
-
-    # MB syntax piece: 20 slots (<= ~80 bits) -> 8-word buffer.
-    syn_words, syn_bits, syn_ovf = bitmerge.slots_to_words(
-        syn_vals, syn_lens, bitmerge.BLOCK_WORDS)           # (R,C,8)
-
-    # L2: 28 pieces -> 64-word MB buffer.
-    pieces = jnp.concatenate([syn_words[:, :, None, :], blk_words], axis=2)
-    piece_bits = jnp.concatenate([syn_bits[:, :, None], blk_bits], axis=2)
-    mb_words, mb_bits, mb_ovf = bitmerge.merge_pieces_dense(
-        pieces, piece_bits, bitmerge.MB_WORDS)              # (R, C, 64)
-
-    # L3: 128 pieces (header + 120 MBs + trailing + padding) -> row RBSP.
-    hdr_words4, hdr_bits, _ = bitmerge.slots_to_words(
-        hdr_vals, hdr_lens, 4)                              # (R, 4)
-    hdr_words = jnp.pad(hdr_words4, ((0, 0), (0, bitmerge.MB_WORDS - 4)))
-
-    body_bits = hdr_bits + mb_bits.sum(axis=1)
-    pad = (8 - ((body_bits + 1) % 8)) % 8
-    # rbsp trailing: stop bit '1' + pad zeros; MSB-aligned that is always
-    # 0x80000000 in word 0, only the *length* varies.
-    trail_words = jnp.zeros((nr, bitmerge.MB_WORDS), jnp.uint32)
-    trail_words = trail_words.at[:, 0].set(jnp.uint32(1) << 31)
-    trail_bits = pad + 1
-
-    n_pieces = 1 + nc_mb + 1
-    p2 = 1 << int(np.ceil(np.log2(n_pieces)))
-    row_pieces = jnp.concatenate([
-        hdr_words[:, None, :], mb_words,
-        trail_words[:, None, :],
-        jnp.zeros((nr, p2 - n_pieces, bitmerge.MB_WORDS), jnp.uint32)], axis=1)
-    row_bits_in = jnp.concatenate([
-        hdr_bits[:, None], mb_bits, trail_bits[:, None],
-        jnp.zeros((nr, p2 - n_pieces), jnp.int32)], axis=1)
-    row_words_buf, row_bits = bitmerge.merge_pieces_tree(
-        row_pieces, row_bits_in)                            # (R, p2*64)
+    nr = syn_vals.shape[0]
+    assert nr <= MAX_META_ROWS, "metadata header row capacity exceeded"
+    if trail_lens is None:
+        trail_vals = jnp.zeros(nr, jnp.uint32)
+        trail_lens = jnp.zeros(nr, jnp.int32)
+    rows = (_pack_rows_kernels if jax.default_backend() == "tpu"
+            else _pack_rows_bitmerge)
+    overflow, row_bits, flat_words = rows(
+        values, lengths, syn_vals, syn_lens, hdr_vals, hdr_lens,
+        trail_vals, trail_lens)
 
     row_bytes = row_bits // 8                               # byte-aligned
     row_words = (row_bytes + 3) // 4
-    word_off = jnp.cumsum(row_words) - row_words
-    total_words = word_off[-1] + row_words[-1]
+    word_cum = jnp.cumsum(row_words)
+    word_off = word_cum - row_words
+    total_words = word_cum[-1]
 
-    # Output-sized gather compaction: flat word j belongs to row
-    # r(j) = #\{rows whose span ends at or before j\}.
-    word_cum = jnp.cumsum(row_words)                        # inclusive
-    j = jnp.arange(FLAT_CAP_WORDS, dtype=jnp.int32)
-    r = (j[:, None] >= word_cum[None, :]).sum(axis=1)
-    rc = jnp.clip(r, 0, nr - 1)
-    src = rc * row_words_buf.shape[1] + (j - word_off[rc])
-    src = jnp.clip(src, 0, nr * row_words_buf.shape[1] - 1)
-    flat_words = jnp.where(j < total_words,
-                           row_words_buf.reshape(-1)[src], 0)
-
-    overflow = (jnp.any(blk_ovf) | jnp.any(syn_ovf) | jnp.any(mb_ovf)
-                | (total_words > FLAT_CAP_WORDS))
-
-    assert nr <= MAX_META_ROWS, "metadata header row capacity exceeded"
     meta = jnp.zeros(META_WORDS, jnp.uint32)
     meta = meta.at[0].set(overflow.astype(jnp.uint32))
     meta = meta.at[1].set(total_words.astype(jnp.uint32))
@@ -707,11 +676,148 @@ def pack_frame(values, lengths, syn_vals, syn_lens, hdr_vals, hdr_lens,
     if qp_sum is not None:
         meta = meta.at[META_QP_SUM_WORD].set(qp_sum.astype(jnp.uint32))
 
-    allw = jnp.concatenate([meta, flat_words])
-    flat = jnp.stack([(allw >> 24) & 0xFF, (allw >> 16) & 0xFF,
-                      (allw >> 8) & 0xFF, allw & 0xFF],
-                     axis=-1).reshape(-1).astype(jnp.uint8)
+    with jax.named_scope("flat_bytes"):
+        allw = jnp.concatenate([meta, flat_words])
+        flat = jnp.stack([(allw >> 24) & 0xFF, (allw >> 16) & 0xFF,
+                          (allw >> 8) & 0xFF, allw & 0xFF],
+                         axis=-1).reshape(-1).astype(jnp.uint8)
     return flat, overflow
+
+
+def _rbsp_stop(body_bits):
+    """Length of a row's rbsp trailing bits: the stop bit and the zeros to
+    the next byte, for a slice of ``body_bits`` bits."""
+    return 1 + (8 - ((body_bits + 1) % 8)) % 8
+
+
+def _pack_rows_bitmerge(values, lengths, syn_vals, syn_lens, hdr_vals,
+                        hdr_lens, trail_vals, trail_lens):
+    """The rows through the bitmerge hierarchy and an output-sized gather:
+    (overflow, per-row bit counts, FLAT_CAP_WORDS words).  The packer
+    wherever there is no TPU, and the kernels' oracle."""
+    nr, nc_mb = syn_vals.shape[:2]
+
+    with jax.named_scope("bitmerge_blocks"):
+        # L1: each block's 34 slots -> 8-word buffer.
+        blk_words, blk_bits, blk_ovf = bitmerge.slots_to_words(
+            values, lengths, bitmerge.BLOCK_WORDS)          # (R,C,B,8)
+        # MB syntax piece (an I macroblock's 20 slots are <= ~80 bits, a
+        # P macroblock's skip_run..qp_delta <= ~40) -> 8-word buffer.
+        syn_words, syn_bits, syn_ovf = bitmerge.slots_to_words(
+            syn_vals, syn_lens, bitmerge.BLOCK_WORDS)       # (R,C,8)
+
+    with jax.named_scope("bitmerge_mbs"):
+        # L2: 1 + B pieces -> 64-word MB buffer.
+        pieces = jnp.concatenate([syn_words[:, :, None, :], blk_words],
+                                 axis=2)
+        piece_bits = jnp.concatenate([syn_bits[:, :, None], blk_bits],
+                                     axis=2)
+        mb_words, mb_bits, mb_ovf = bitmerge.merge_pieces_dense(
+            pieces, piece_bits, bitmerge.MB_WORDS)          # (R, C, 64)
+
+    with jax.named_scope("bitmerge_rows"):
+        # L3: header + C MBs + trailing run + rbsp trailing (+ padding to
+        # a power of two) -> row RBSP.
+        hdr_words4, hdr_bits, _ = bitmerge.slots_to_words(
+            hdr_vals, hdr_lens, 4)                          # (R, 4)
+        hdr_words = jnp.pad(hdr_words4,
+                            ((0, 0), (0, bitmerge.MB_WORDS - 4)))
+
+        # trailing skip run piece (<= 23 bits); the shift is guarded
+        # because a zero-length piece would shift by 32 (undefined across
+        # backends).
+        trailrun_words = jnp.zeros((nr, bitmerge.MB_WORDS), jnp.uint32)
+        trailrun_words = trailrun_words.at[:, 0].set(jnp.where(
+            trail_lens > 0,
+            trail_vals.astype(jnp.uint32)
+            << (32 - jnp.maximum(trail_lens, 1)).astype(jnp.uint32),
+            jnp.uint32(0)))
+
+        # rbsp trailing: stop bit '1' + pad zeros; MSB-aligned that is
+        # always 0x80000000 in word 0, only the *length* varies.
+        stop_words = jnp.zeros((nr, bitmerge.MB_WORDS), jnp.uint32)
+        stop_words = stop_words.at[:, 0].set(jnp.uint32(1) << 31)
+        stop_bits = _rbsp_stop(hdr_bits + mb_bits.sum(axis=1) + trail_lens)
+
+        n_pieces = 1 + nc_mb + 2                       # hdr, MBs, run, rbsp
+        p2 = 1 << int(np.ceil(np.log2(n_pieces)))
+        row_pieces = jnp.concatenate([
+            hdr_words[:, None, :], mb_words,
+            trailrun_words[:, None, :], stop_words[:, None, :],
+            jnp.zeros((nr, p2 - n_pieces, bitmerge.MB_WORDS), jnp.uint32)],
+            axis=1)
+        row_bits_in = jnp.concatenate([
+            hdr_bits[:, None], mb_bits, trail_lens[:, None],
+            stop_bits[:, None], jnp.zeros((nr, p2 - n_pieces), jnp.int32)],
+            axis=1)
+        row_words_buf, row_bits = bitmerge.merge_pieces_tree(
+            row_pieces, row_bits_in)                        # (R, p2*64)
+
+    with jax.named_scope("bitmerge_gather"):
+        row_words = (row_bits // 8 + 3) // 4
+        word_cum = jnp.cumsum(row_words)                    # inclusive
+        word_off = word_cum - row_words
+        total_words = word_cum[-1]
+
+        # Output-sized gather compaction: flat word j belongs to row
+        # r(j) = #\{rows whose span ends at or before j\}.
+        j = jnp.arange(FLAT_CAP_WORDS, dtype=jnp.int32)
+        r = (j[:, None] >= word_cum[None, :]).sum(axis=1)
+        rc = jnp.clip(r, 0, nr - 1)
+        src = rc * row_words_buf.shape[1] + (j - word_off[rc])
+        src = jnp.clip(src, 0, nr * row_words_buf.shape[1] - 1)
+        flat_words = jnp.where(j < total_words,
+                               row_words_buf.reshape(-1)[src], 0)
+
+    overflow = (jnp.any(blk_ovf) | jnp.any(syn_ovf) | jnp.any(mb_ovf)
+                | (total_words > FLAT_CAP_WORDS))
+    return overflow, row_bits, flat_words
+
+
+def _pack_rows_kernels(values, lengths, syn_vals, syn_lens, hdr_vals,
+                       hdr_lens, trail_vals, trail_lens):
+    """The same rows through ``cabac_pack.pack_rows`` (the TPU's form): a
+    macroblock is ONE run of slots, its syntax and then its blocks, the
+    row's slice header in front of its first macroblock's and the trailing
+    run and the rbsp trailing bits behind its last one's; the caps are the
+    hierarchy's, tested on sums of lengths."""
+    nr, nc_mb = syn_vals.shape[:2]
+    lengths = lengths.astype(jnp.int32)
+    syn_lens = syn_lens.astype(jnp.int32)
+    hdr_lens = hdr_lens.astype(jnp.int32)
+    blk_bits = lengths.sum(-1)                              # (R, C, B)
+    syn_bits = syn_lens.sum(-1)
+    mb_bits = syn_bits + blk_bits.sum(-1)
+    caps_ovf = ((blk_bits > bitmerge.BLOCK_CAP_BITS).any(-1)
+                | (syn_bits > bitmerge.BLOCK_CAP_BITS)
+                | (mb_bits > bitmerge.MB_CAP_BITS))          # (R, C)
+
+    # a header slot is 32 bits of the stream, a slot of the kernels holds
+    # a value of 26: two halves
+    hv = hdr_vals.astype(jnp.uint32)
+    row_head_v = jnp.stack([hv >> 16, hv & 0xFFFF], -1).reshape(nr, -1)
+    row_head_l = jnp.stack([jnp.maximum(hdr_lens - 16, 0),
+                            jnp.minimum(hdr_lens, 16)], -1).reshape(nr, -1)
+    stop_bits = _rbsp_stop(hdr_lens.sum(-1) + mb_bits.sum(-1) + trail_lens)
+    row_tail_v = jnp.stack([trail_vals.astype(jnp.uint32),
+                            jnp.uint32(1) << (stop_bits - 1)], -1)
+    row_tail_l = jnp.stack([trail_lens, stop_bits], -1)
+
+    col = jnp.arange(nc_mb)[None, :, None]
+    vals = jnp.concatenate([
+        jnp.where(col == 0, row_head_v[:, None, :], 0),
+        syn_vals.astype(jnp.uint32),
+        values.astype(jnp.uint32).reshape(nr, nc_mb, -1),
+        jnp.where(col == nc_mb - 1, row_tail_v[:, None, :], 0)], axis=-1)
+    lens = jnp.concatenate([
+        jnp.where(col == 0, row_head_l[:, None, :], 0),
+        syn_lens,
+        lengths.reshape(nr, nc_mb, -1),
+        jnp.where(col == nc_mb - 1, row_tail_l[:, None, :], 0)], axis=-1)
+    # a column's words: the macroblock's, a word a header slot, two behind
+    col_cap = bitmerge.MB_WORDS + hdr_vals.shape[-1] + 2
+    return cabac_pack.pack_rows(vals, lens, caps_ovf, col_cap,
+                                FLAT_CAP_WORDS)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Device-side CAVLC entropy for P-frames.
 
 Companion to :mod:`.cavlc_device` (the intra entropy stage): the same
-slot -> block -> MB -> row bitmerge hierarchy, with the P-slice MB layer
+frame pack (:func:`.cavlc_device.pack_frame`), with the P-slice MB layer
 built on device instead of a fixed syntax table:
 
 - **mb_skip_run**: with slice-per-row, a skipped MB is exactly
@@ -33,9 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..bitstream.h264_entropy import _CBP_INTER_BY_CODENUM
-from . import bitmerge
-from .cavlc_device import (FLAT_CAP_WORDS, MAX_META_ROWS, META_WORDS,
-                           code_blocks, nc_grid)
+from .cavlc_device import code_blocks, nc_grid, pack_frame
 from .h264_inter import RING_DONATE
 
 _I32 = np.int32
@@ -295,92 +293,6 @@ def p_frame_block_slots(out: dict):
     return values, lengths, cbp, mv
 
 
-def pack_p_frame(values, lengths, hdr6_vals, hdr6_lens, trail_vals,
-                 trail_lens, slice_vals, slice_lens, qp_sum=None):
-    """Pack a P frame's slots into the flat metadata+bitstream buffer
-    (same layout as cavlc_device.pack_frame; ``qp_sum`` rides in
-    META_QP_SUM_WORD under tune=hq)."""
-    nr, nc_mb = values.shape[:2]
-
-    blk_words, blk_bits, blk_ovf = bitmerge.slots_to_words(
-        values, lengths, bitmerge.BLOCK_WORDS)         # (R,C,26,8)
-
-    # MB header piece (skip_run..qp_delta; <= ~40 bits -> block buffer)
-    hw, hb, h_ovf = bitmerge.slots_to_words(
-        hdr6_vals, hdr6_lens, bitmerge.BLOCK_WORDS)    # (R, C, 8)
-
-    pieces = jnp.concatenate([hw[:, :, None, :], blk_words], axis=2)
-    piece_bits = jnp.concatenate([hb[:, :, None], blk_bits], axis=2)
-    mb_words, mb_bits, mb_ovf = bitmerge.merge_pieces_dense(
-        pieces, piece_bits, bitmerge.MB_WORDS)         # (R, C, 64)
-
-    hdr_words4, hdr_bits, _ = bitmerge.slots_to_words(
-        slice_vals, slice_lens, 4)                     # (R, 4)
-    hdr_words = jnp.pad(hdr_words4, ((0, 0), (0, bitmerge.MB_WORDS - 4)))
-
-    # trailing skip run piece (<= 23 bits); the shift is guarded because a
-    # zero-length piece would shift by 32 (undefined across backends).
-    trailrun_words = jnp.zeros((nr, bitmerge.MB_WORDS), jnp.uint32)
-    trailrun_words = trailrun_words.at[:, 0].set(jnp.where(
-        trail_lens > 0,
-        trail_vals.astype(jnp.uint32)
-        << (32 - jnp.maximum(trail_lens, 1)).astype(jnp.uint32),
-        jnp.uint32(0)))
-
-    body_bits = hdr_bits + mb_bits.sum(axis=1) + trail_lens
-    pad = (8 - ((body_bits + 1) % 8)) % 8
-    trail_words = jnp.zeros((nr, bitmerge.MB_WORDS), jnp.uint32)
-    trail_words = trail_words.at[:, 0].set(jnp.uint32(1) << 31)
-    trail_bits = pad + 1
-
-    n_pieces = 1 + nc_mb + 2                           # hdr, MBs, run, rbsp
-    p2 = 1 << int(np.ceil(np.log2(n_pieces)))
-    row_pieces = jnp.concatenate([
-        hdr_words[:, None, :], mb_words,
-        trailrun_words[:, None, :], trail_words[:, None, :],
-        jnp.zeros((nr, p2 - n_pieces, bitmerge.MB_WORDS), jnp.uint32)],
-        axis=1)
-    row_bits_in = jnp.concatenate([
-        hdr_bits[:, None], mb_bits, trail_lens[:, None],
-        trail_bits[:, None], jnp.zeros((nr, p2 - n_pieces), jnp.int32)],
-        axis=1)
-    row_words_buf, row_bits = bitmerge.merge_pieces_tree(
-        row_pieces, row_bits_in)
-
-    row_bytes = row_bits // 8
-    row_words = (row_bytes + 3) // 4
-    word_off = jnp.cumsum(row_words) - row_words
-    total_words = word_off[-1] + row_words[-1]
-
-    word_cum = jnp.cumsum(row_words)
-    j = jnp.arange(FLAT_CAP_WORDS, dtype=jnp.int32)
-    r = (j[:, None] >= word_cum[None, :]).sum(axis=1)
-    rc = jnp.clip(r, 0, nr - 1)
-    src = rc * row_words_buf.shape[1] + (j - word_off[rc])
-    src = jnp.clip(src, 0, nr * row_words_buf.shape[1] - 1)
-    flat_words = jnp.where(j < total_words,
-                           row_words_buf.reshape(-1)[src], 0)
-
-    overflow = (jnp.any(blk_ovf) | jnp.any(h_ovf) | jnp.any(mb_ovf)
-                | (total_words > FLAT_CAP_WORDS))
-
-    meta = jnp.zeros(META_WORDS, jnp.uint32)
-    meta = meta.at[0].set(overflow.astype(jnp.uint32))
-    meta = meta.at[1].set(total_words.astype(jnp.uint32))
-    meta = meta.at[2:2 + nr].set(row_bytes.astype(jnp.uint32))
-    meta = meta.at[2 + MAX_META_ROWS:2 + MAX_META_ROWS + nr].set(
-        word_off.astype(jnp.uint32))
-    if qp_sum is not None:
-        from .cavlc_device import META_QP_SUM_WORD
-        meta = meta.at[META_QP_SUM_WORD].set(qp_sum.astype(jnp.uint32))
-
-    allw = jnp.concatenate([meta, flat_words])
-    flat = jnp.stack([(allw >> 24) & 0xFF, (allw >> 16) & 0xFF,
-                      (allw >> 8) & 0xFF, allw & 0xFF],
-                     axis=-1).reshape(-1).astype(jnp.uint8)
-    return flat, overflow
-
-
 @functools.partial(jax.jit, static_argnames=("qp", "tune", "p_intra"),
                    donate_argnames=RING_DONATE)
 def encode_p_cavlc_frame(y, cb, cr, ref_y, ref_cb, ref_cr,
@@ -452,8 +364,8 @@ def _finish_p(out: dict, hdr_vals, hdr_lens, slice_qp: int = None):
         hv6, hl6, tv, tl, _skip = p_mb_header_slots(mv, cbp, qp_se=qp_se,
                                                     mb_intra=mb_intra)
     with jax.named_scope("dngd.pack"):
-        flat, _ = pack_p_frame(values, lengths, hv6, hl6, tv, tl,
-                               hdr_vals, hdr_lens, qp_sum=qp_sum)
+        flat, _ = pack_frame(values, lengths, hv6, hl6, hdr_vals,
+                             hdr_lens, tv, tl, qp_sum=qp_sum)
     with jax.named_scope("dngd.deblock_bs"):
         # per-4x4 coded-coefficient flags in raster [by][bx] order — the
         # deblocking bS=2 input (ops/h264_deblock.p_bs)

@@ -27,11 +27,11 @@ def kernel_jits():
     return {}
 
 
-def _pack_case(kind, case):
+def _pack_case(kind, case, grid=None):
     """Level tensors for one packer case (the arguments of ``binarize_p`` /
     ``binarize_intra``): real stage output for the desktop, crafted (3, 5)
     grids (a column count that is no power of two) for the rest, and one
-    MB row of 5 for a spatial shard."""
+    MB row of 5 for a spatial shard; ``grid``: another (rows, columns)."""
     import jax.numpy as jnp
 
     from docker_nvidia_glx_desktop_tpu.ops import h264_device
@@ -48,7 +48,7 @@ def _pack_case(kind, case):
         return tuple(np.asarray(lv[k]) for k in (
             "luma_dc", "luma_ac", "cb_dc", "cb_ac", "cr_dc", "cr_ac",
             "pred_mode", "mb_i4", "i4_modes", "luma_i4"))
-    nr, nc = (1, 5) if case == "shard_1x5" else (3, 5)
+    nr, nc = grid or ((1, 5) if case == "shard_1x5" else (3, 5))
     rows = {"all_skip": [], "empty_rows": [0, 2]}.get(case, range(nr))
     z = lambda *shape: np.zeros((nr, nc) + shape, np.int32)
 
@@ -124,3 +124,48 @@ class TestPackKernels:
         n = cb.META_WORDS + rows + int(want[2])
         np.testing.assert_array_equal(got[:n], want[:n])
         np.testing.assert_array_equal(got[n:], want[n:])
+
+    @pytest.mark.parametrize("widest", [26, 32])
+    def test_pack_rows_against_a_bit_string(self, widest):
+        """``pack_rows`` itself on slots up to its stated widths (a value of
+        at most 26 bits under a length of at most 32: CAVLC's level escapes
+        are 28, 30 and 32), against the bits written out one after the
+        other.  Eight 32-bit slots behind a phase reach into a ninth word,
+        which is the next group's first: the case must hold such groups."""
+        import jax
+        from jax.experimental.pallas import tpu as pltpu
+
+        from docker_nvidia_glx_desktop_tpu.ops import cabac_pack
+
+        rng = np.random.default_rng(widest)
+        r, c, s = 2, 3, 37
+        lns = rng.integers(widest - 5, widest + 1, (r, c, s))
+        lns *= rng.integers(0, 8, (r, c, s)) > 0            # some empty
+        lns[1, 1] = 0                                       # an empty MB
+        vals = rng.integers(0, 1 << 26, (r, c, s)) & ((1 << lns) - 1)
+        # a macroblock's piece: a line of a chunk, or whole chunks
+        cap, out_words = (64 if widest == 32 else 200), 512
+        want = np.zeros(out_words, np.uint32)
+        word, spills = 0, 0
+        for i in range(r):
+            bits = "".join(format(int(v), "b").zfill(int(n))
+                           for v, n in zip(vals[i].ravel(), lns[i].ravel())
+                           if n)
+            bits += "0" * (-len(bits) % 32)
+            row = [int(bits[k:k + 32], 2) for k in range(0, len(bits), 32)]
+            want[word:word + len(row)] = row
+            word += len(row)
+            ends = np.cumsum(np.pad(lns[i], ((0, 0), (0, 3))).reshape(c, -1, 8)
+                             .sum(-1).ravel())
+            spills += int(((ends - np.diff(ends, prepend=0)) % 32
+                           + np.diff(ends, prepend=0) > 256).sum())
+        assert (spills > 0) == (widest == 32)
+        with pltpu.force_tpu_interpret_mode():
+            overflow, row_bits, payload = jax.jit(
+                cabac_pack.pack_rows, static_argnums=(3, 4))(
+                    vals.astype(np.uint32), lns.astype(np.int32),
+                    np.zeros((r, c), bool), cap, out_words)
+        assert not bool(overflow)
+        np.testing.assert_array_equal(np.asarray(row_bits),
+                                      lns.sum((1, 2)))
+        np.testing.assert_array_equal(np.asarray(payload), want)

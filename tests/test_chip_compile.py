@@ -9,13 +9,14 @@ call shapes: the intra step as ``H264Encoder._submit_device`` issues it
 ``_submit_p_device`` issues it (reference ring donated, as it resolves
 under ``JAX_PLATFORMS=tpu``),
 the in-loop deblock of a P frame on the TPU's schedule, the Pallas
-kernel compiled by Mosaic (the ``jax.default_backend()`` branch would pick
-the CPU's scan here, so the test answers "tpu" while that one program is
+kernel compiled by Mosaic (a ``jax.default_backend()`` branch would pick
+the CPU's form here, so the test answers "tpu" while the programs are
 lowered), the two CABAC binarize programs with the record packer's two
-kernels (``ops/cabac_pack``, chosen the same way), and the (4,1)
-session-mesh step of
-``TPU_SESSIONS``/``TPU_MESH`` on a ``Mesh`` of the four described
-devices.
+kernels (``ops/cabac_pack``, chosen the same way; since PR 31 the CAVLC
+frame pack of the intra and the P step is the same two), the (4,1)
+session-mesh step of ``TPU_SESSIONS``/``TPU_MESH`` on a ``Mesh`` of the
+four described devices, and a P step of two sessions a chip (``jax.vmap``
+over the kernels).
 
 Tier-1 on purpose (not in conftest's ``_SLOW_MODULES``).  Only one
 process may hold the TPU library, so everything that touches the
@@ -89,25 +90,29 @@ def programs(topo, no_persistent_cache):
 
     qp = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)   # traced
     lowered = {}
-    # H264Encoder._submit_device: host-converted planes, recon kept
-    lowered["intra"] = cavlc_device.encode_intra_cavlc_frame_yuv_dynqp.lower(
-        y, c, c, hv, hl, qp, with_recon=True, i16_modes="auto", tune="off")
-    # H264Encoder._submit_p_device with the ring donated (RING_DONATE is
-    # resolved from JAX_PLATFORMS at import and is () in this CPU-held
-    # process, so the donated jit is rebuilt here from the same body)
     p_body = cavlc_p_device.encode_p_cavlc_frame.__wrapped__
-    p_args = (y, c, c, y, c, c, hv, hl, qp, "off", None, False)
-    lowered["p"] = jax.jit(
-        p_body, static_argnames=("tune", "p_intra"),
-        donate_argnames=("ref_y", "ref_cb", "ref_cr")).lower(*p_args)
-    _flat, ry, rcb, rcr, mv, nnz, _lv = on_chip(
-        jax.eval_shape(lambda *a: p_body(*a, "off", None, False),
-                       *p_args[:9]))
-    # H264Encoder._deblock as the served path calls it (traced qp), on
-    # the schedule a TPU backend picks; a jit of its own, so that no
-    # trace of the CPU's schedule is handed back
+    p_args = (y, c, c, y, c, c, hv, hl, qp)
+    # every program on the branch a TPU backend picks, through functions
+    # of their own: JAX keeps a trace by the function, and another test
+    # may have left the CPU's there
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
+        # H264Encoder._submit_device: host-converted planes, recon kept
+        intra_body = cavlc_device.encode_intra_cavlc_frame_yuv.__wrapped__
+        lowered["intra"] = jax.jit(lambda *a: intra_body(
+            *a, with_recon=True, i16_modes="auto", tune="off")).lower(
+                y, c, c, hv, hl, qp)
+        # H264Encoder._submit_p_device with the ring donated (RING_DONATE
+        # is resolved from JAX_PLATFORMS at import and is () in this
+        # CPU-held process, so the donated jit is rebuilt here from the
+        # same body: ref_y, ref_cb, ref_cr)
+        lowered["p"] = jax.jit(
+            lambda *a: p_body(*a, "off", None, False),
+            donate_argnums=(3, 4, 5)).lower(*p_args)
+        _flat, ry, rcb, rcr, mv, nnz, _lv = on_chip(
+            jax.eval_shape(lambda *a: p_body(*a, "off", None, False),
+                           *p_args))
+        # H264Encoder._deblock as the served path calls it (traced qp)
         lowered["deblock_p"] = jax.jit(
             h264_deblock.deblock_frame.__wrapped__).lower(
                 ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv)
@@ -116,22 +121,30 @@ def programs(topo, no_persistent_cache):
         lv = lambda *shape: jax.ShapeDtypeStruct(
             (H // 16, W // 16) + shape, jnp.int32, sharding=one)
         chroma = (lv(4), lv(4, 15), lv(4), lv(4, 15))
-        # (through functions of their own: JAX keeps a trace by the
-        # function, and another test may have left the CPU's there)
         lowered["binarize_p"] = jax.jit(
             lambda *a: cabac_binarize.binarize_p.__wrapped__(*a)).lower(
                 lv(2), lv(16, 16), *chroma)
         lowered["binarize_intra"] = jax.jit(
             lambda *a: cabac_binarize.binarize_intra.__wrapped__(*a)).lower(
                 lv(16), lv(16, 15), *chroma, lv(), lv(), lv(16), lv(16, 16))
-    # web/multisession: four 1080p sessions, one per chip
-    mesh = batch.make_mesh((4, 1), topo.devices)
-    sess = NamedSharding(mesh, P("session", "spatial", None))
-    step, _rows = batch.h264_batch_encode_step(mesh, H, W, qp=QP)
-    lowered["mesh41"] = jax.jit(step).lower(
-        jax.ShapeDtypeStruct((4, H, W), jnp.uint8, sharding=sess),
-        jax.ShapeDtypeStruct((4, H // 2, W // 2), jnp.uint8, sharding=sess),
-        jax.ShapeDtypeStruct((4, H // 2, W // 2), jnp.uint8, sharding=sess))
+        # web/multisession: four 1080p sessions, one per chip
+        mesh = batch.make_mesh((4, 1), topo.devices)
+        planes = lambda m, n: tuple(
+            jax.ShapeDtypeStruct((n, H // d, W // d), jnp.uint8,
+                                 sharding=NamedSharding(
+                                     m, P("session", "spatial", None)))
+            for d in (1, 2, 2))
+        step, _rows = batch.h264_batch_encode_step(mesh, H, W, qp=QP)
+        lowered["mesh41"] = jax.jit(step).lower(*planes(mesh, 4))
+        # two sessions a chip: the P step's jax.vmap is then a grid
+        # dimension of kernel A and a loop round kernel B
+        mesh2 = batch.make_mesh((1, 1), topo.devices[:1])
+        rows = NamedSharding(mesh2, P("spatial", None))
+        step, _rows = batch.h264_p_batch_step(mesh2, H, W, qp=QP)
+        lowered["p_two_sessions"] = jax.jit(step).lower(
+            *planes(mesh2, 2), *planes(mesh2, 2),
+            jax.ShapeDtypeStruct(hv_np.shape, hv_np.dtype, sharding=rows),
+            jax.ShapeDtypeStruct(hl_np.shape, hl_np.dtype, sharding=rows))
 
     def compile_one(item):
         name, low = item
@@ -162,9 +175,22 @@ def _device_bytes(compiled) -> int:
             - m.alias_size_in_bytes)
 
 
+def _has_the_pack_kernels(text, calls=2):
+    """Both merge levels of a packer are Mosaic kernels, and kernel A asks
+    for more VMEM than the compiler's default scoped limit (16 MiB on a
+    v5e) and says so; the chip has 128 MiB."""
+    from docker_nvidia_glx_desktop_tpu.ops import cabac_pack
+
+    assert text.count("tpu_custom_call") == calls
+    assert "cabac_compact" in text and "cabac_rows" in text
+    assert f'"size":"{cabac_pack.VMEM_LIMIT_BYTES}"' in text
+    assert cabac_pack.VMEM_LIMIT_BYTES <= 64 * 1024 * 1024
+
+
 def test_intra_step_compiles_for_v5e(programs):
     c = _compiled(programs, "intra")
     assert 0 < _device_bytes(c) < HBM_BYTES
+    _has_the_pack_kernels(c.as_text())
 
 
 def test_p_step_compiles_and_donates_the_ring(programs):
@@ -172,6 +198,7 @@ def test_p_step_compiles_and_donates_the_ring(programs):
     assert 0 < _device_bytes(c) < HBM_BYTES
     # the recon is written in place of the donated reference planes
     assert c.memory_analysis().alias_size_in_bytes >= H * W * 3 // 2
+    _has_the_pack_kernels(c.as_text())
 
 
 def test_p_deblock_compiles_with_the_edge_kernel(programs):
@@ -185,20 +212,13 @@ def test_p_deblock_compiles_with_the_edge_kernel(programs):
 
 @pytest.mark.parametrize("name", ["binarize_p", "binarize_intra"])
 def test_binarize_compiles_with_the_pack_kernels(programs, name):
-    from docker_nvidia_glx_desktop_tpu.ops import cabac_pack
-
     c = _compiled(programs, name)
     assert 0 < _device_bytes(c) < HBM_BYTES
-    # both merge levels are Mosaic kernels; no barrel-shifter tree and no
-    # row-by-row ``dynamic_update_slice`` loop is left in the program
+    # no barrel-shifter tree and no row-by-row ``dynamic_update_slice``
+    # loop is left in the program
     text = c.as_text()
-    assert text.count("tpu_custom_call") == 2
-    assert "cabac_compact" in text and "cabac_rows" in text
+    _has_the_pack_kernels(text)
     assert " while(" not in text
-    # kernel A asks for more VMEM than the compiler's default scoped limit
-    # (16 MiB on a v5e) and says so; the chip has 128 MiB
-    assert f'"size":"{cabac_pack.VMEM_LIMIT_BYTES}"' in text
-    assert cabac_pack.VMEM_LIMIT_BYTES <= 64 * 1024 * 1024
 
 
 def test_session_mesh_step_fits_each_chip(programs):
@@ -206,3 +226,13 @@ def test_session_mesh_step_fits_each_chip(programs):
     # memory_analysis() of a partitioned program is per device
     assert 0 < _device_bytes(c) < HBM_BYTES
     assert len(c.input_shardings[0][0].device_set) == 4
+    _has_the_pack_kernels(c.as_text())
+
+
+def test_two_sessions_a_chip_fit_with_the_kernels_vmapped(programs):
+    c = _compiled(programs, "p_two_sessions")
+    assert 0 < _device_bytes(c) < HBM_BYTES
+    # kernel A once over a grid with the sessions in it, kernel B in the
+    # loop JAX puts round a kernel whose prefetched scalars are batched
+    text = c.as_text()
+    assert "cabac_compact" in text and "cabac_rows" in text
