@@ -9,11 +9,14 @@ kernels of ``ops/cabac_pack``; everywhere else with the bitmerge hierarchy.
 Tier-1 holds the kernels to that hierarchy in interpret mode at small sizes.
 What only the chip can show is what XLA:TPU and Mosaic make of the programs
 at 1920x1080 (PR 28: a fused reversed cumsum counted wrong there and nowhere
-else).  So: one I picture and the P picture after it of the benchmark's two
-traffics at qp 20, 32 and 44, twelve transport buffers from the chip, each
-against XLA:CPU's from the same level tensors, whole buffer, word for word;
-then the programs' device time.  One JSON line a picture; the last line is
-``ALL_IDENTICAL`` and the exit code 0 only if all twelve are.
+else) and at 3840x2160 (a new shape is a new compile).  So: one I picture
+and the P picture after it of the benchmark's two traffics at qp 20, 32 and
+44, twelve transport buffers from the chip at 1920x1088, and at 3840x2176
+six (the desktop at qp 32, full damage at qp 20 and 44), each against
+XLA:CPU's from the same level tensors, whole buffer, word for word; then the
+programs' device time.  One JSON line a picture; the last line is
+``ALL_IDENTICAL`` and the exit code 0 only if all eighteen are.
+``--geometry WxH`` runs one of the two sizes alone.
 
 ``--cavlc``: the same for the CAVLC programs' ``flat`` (``cavlc_device.
 pack_frame``: the same two kernels on the TPU since PR 31), at 1920x1088 and
@@ -41,14 +44,14 @@ from docker_nvidia_glx_desktop_tpu.ops import cabac_binarize as cb
 from docker_nvidia_glx_desktop_tpu.ops import h264_device, h264_inter
 from docker_nvidia_glx_desktop_tpu.utils.hostcolor import rgb_to_yuv420_host
 
-W, H = 1920, 1088
+QPS = {(1920, 1088): {"desktop": (20, 32, 44), "fulldamage": (20, 32, 44)},
+       (3840, 2176): {"desktop": (32,), "fulldamage": (20, 44)}}
 P_KEYS = ("mv", "luma", "cb_dc", "cb_ac", "cr_dc", "cr_ac")
 I_KEYS = ("luma_dc", "luma_ac", "cb_dc", "cb_ac", "cr_dc", "cr_ac",
           "pred_mode", "mb_i4", "i4_modes", "luma_i4")
 
 
-def pictures(w=W, h=H, qps={"desktop": (20, 32, 44),
-                             "fulldamage": (20, 32, 44)}):
+def pictures(w, h, qps):
     """(name, binarize_p's arguments, binarize_intra's) from the served
     device stages: frame 200 as an I picture, frame 201 predicted from it."""
     for kind, seed in (("desktop", 3141592653), ("fulldamage", 2718281828)):
@@ -196,32 +199,41 @@ def main() -> int:
     on_cpu = {"p": jax.jit(lambda *a: cb.binarize_p.__wrapped__(*a)),
               "intra": jax.jit(lambda *a: cb.binarize_intra.__wrapped__(*a))}
     on_chip = {"p": cb.binarize_p, "intra": cb.binarize_intra}
-    pics = list(pictures())
+    sizes = list(QPS)
+    if "--geometry" in sys.argv[1:]:
+        w, h = sys.argv[sys.argv.index("--geometry") + 1].lower().split("x")
+        sizes = [(int(w), int(h))]
     same = True
-    for name, p_args, i_args in pics:
-        for kind, args in (("p", p_args), ("intra", i_args)):
-            got = np.asarray(on_chip[kind](*args))
-            with mock.patch.object(jax, "default_backend", lambda: "cpu"):
-                want = np.asarray(on_cpu[kind](
-                    *[jax.device_put(a, cpu) for a in args]))
-            differing = (int((got != want).sum())
-                         if got.shape == want.shape else -1)
-            same &= differing == 0
-            print(json.dumps({
-                "picture": name, "kind": kind, "identical": differing == 0,
-                "differing_words": differing, "payload_words": int(want[2]),
-                "overflow": int(want[1])}), flush=True)
-    for name, p_args, i_args in pics[1::3]:
-        for kind, args in (("p", p_args), ("intra", i_args)):
-            dev = [jnp.asarray(a) for a in args]
-            on_chip[kind](*dev).block_until_ready()
-            t0 = time.perf_counter()
-            for _ in range(20):
-                out = on_chip[kind](*dev)
-            out.block_until_ready()
-            print(json.dumps({
-                "picture": name, "kind": kind,
-                "ms_per_call": (time.perf_counter() - t0) * 50}), flush=True)
+    for w, h in sizes:
+        pics = list(pictures(w, h, QPS[w, h]))
+        for name, p_args, i_args in pics:
+            for kind, args in (("p", p_args), ("intra", i_args)):
+                got = np.asarray(on_chip[kind](*args))
+                with mock.patch.object(jax, "default_backend",
+                                       lambda: "cpu"):
+                    want = np.asarray(on_cpu[kind](
+                        *[jax.device_put(a, cpu) for a in args]))
+                differing = (int((got != want).sum())
+                             if got.shape == want.shape else -1)
+                same &= differing == 0
+                print(json.dumps({
+                    "picture": f"{w}x{h}.{name}", "kind": kind,
+                    "identical": differing == 0,
+                    "differing_words": differing,
+                    "payload_words": int(want[2]),
+                    "overflow": int(want[1])}), flush=True)
+        for name, p_args, i_args in pics[1::3]:
+            for kind, args in (("p", p_args), ("intra", i_args)):
+                dev = [jnp.asarray(a) for a in args]
+                on_chip[kind](*dev).block_until_ready()
+                t0 = time.perf_counter()
+                for _ in range(20):
+                    out = on_chip[kind](*dev)
+                out.block_until_ready()
+                print(json.dumps({
+                    "picture": f"{w}x{h}.{name}", "kind": kind,
+                    "ms_per_call": (time.perf_counter() - t0) * 50}),
+                    flush=True)
     print("ALL_IDENTICAL" if same else "DIFFERENT", flush=True)
     return 0 if same else 1
 

@@ -78,10 +78,33 @@ def nal_unit(nal_type: int, rbsp: bytes, ref_idc: int = 3) -> bytes:
 # Parameter sets (baseline profile)
 # ---------------------------------------------------------------------------
 
-def sps_rbsp(width: int, height: int, level_idc: int = 42,
+# Table A-1 from level 4.2 up: (level_idc, MaxMBPS, MaxFS).
+_LEVELS = ((42, 522240, 8704), (50, 589824, 22080), (51, 983040, 36864),
+           (52, 2073600, 36864), (60, 4177920, 139264),
+           (61, 8355840, 139264), (62, 16711680, 139264))
+
+
+def level_idc_for(width: int, height: int, fps: float) -> int:
+    """The lowest level of Table A-1 that holds ``fps`` pictures a second
+    of this size (MaxFS, MaxMBPS, and A.3.1's sides of at most
+    sqrt(8 * MaxFS) macroblocks), never under 4.2: what a hardware decoder
+    sizes itself by.  Past 6.2 the stream declares 6.2."""
+    mb_w = (width + 15) // 16
+    mb_h = (height + 15) // 16
+    fs = mb_w * mb_h
+    for idc, max_mbps, max_fs in _LEVELS:
+        if (fs <= max_fs and fs * fps <= max_mbps
+                and max(mb_w, mb_h) ** 2 <= 8 * max_fs):
+            return idc
+    return _LEVELS[-1][0]
+
+
+def sps_rbsp(width: int, height: int, fps: float = 60.0,
              profile: str = "baseline") -> bytes:
     """Sequence parameter set for progressive 4:2:0.
 
+    ``fps``: the refresh the stream is built for; with the size it sets
+    ``level_idc`` (:func:`level_idc_for`).
     ``profile``: "baseline" (CAVLC streams) or "main" (required for
     CABAC, spec A.2.2 — baseline excludes entropy_coding_mode_flag=1).
     Frame cropping carries non-multiple-of-16 dimensions; POC type 2 keeps
@@ -98,7 +121,7 @@ def sps_rbsp(width: int, height: int, level_idc: int = 42,
     else:
         bw.write(66, 8)              # profile_idc: baseline
         bw.write(0b11000000, 8)      # constraint_set0+1, reserved zeros
-    bw.write(level_idc, 8)
+    bw.write(level_idc_for(width, height, fps), 8)
     write_ue(bw, 0)                  # seq_parameter_set_id
     write_ue(bw, 0)                  # log2_max_frame_num_minus4 -> 4 bits
     write_ue(bw, 2)                  # pic_order_cnt_type

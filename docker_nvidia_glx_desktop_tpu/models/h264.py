@@ -29,6 +29,7 @@ from ..obs.profile import PROFILER
 from ..ops import color
 from ..utils.mathutil import round_up
 from .base import EncodedFrame, Encoder
+from .prefix_pull import M_D2H_BYTES as _M_D2H_BYTES
 from .prefix_pull import M_PULL_EXTRA as _M_PULL_EXTRA
 from .prefix_pull import PrefixPull, prefetch_host as _prefetch_host
 
@@ -46,6 +47,11 @@ _M_CABAC_FALLBACK = obsm.counter(
     "the level tensors were pulled whole; python = no native engine was "
     "built and the Python coder (about 100x slower) coded the frame",
     ("kind",))
+_M_H2D_BYTES = obsm.counter(
+    "dngd_encoder_h2d_bytes_total",
+    "Bytes of host arrays the per-frame CABAC path handed to its device "
+    "programs in dispatch: the picture's planes (the RGB frame where the "
+    "colour conversion is the device's)")
 _M_CABAC_DENSE = _M_CABAC_FALLBACK.labels("dense")
 _M_CABAC_PYTHON = _M_CABAC_FALLBACK.labels("python")
 
@@ -58,6 +64,13 @@ def _note_cabac_dense() -> None:
     if n == 1 or n % 100 == 0:
         log.warning("CABAC transport overflow: level tensors pulled "
                     "dense for this frame (%d so far)", n)
+
+
+def _note_h2d(*arrays) -> None:
+    """Host arrays on their way into a device program: counted (what is
+    on the device already crosses nothing)."""
+    _M_H2D_BYTES.inc(sum(a.nbytes for a in arrays
+                         if isinstance(a, np.ndarray)))
 
 
 def _note_entropy_overflow(what: str) -> None:
@@ -446,7 +459,7 @@ class H264Encoder(Encoder):
                     "is coded by the Python engine, about 100x slower; "
                     "counted in dngd_encoder_cabac_fallback_total"
                     "{kind=\"python\"}")
-        self._sps = syn.sps_rbsp(width, height,
+        self._sps = syn.sps_rbsp(width, height, fps,
                                  profile="main" if cabac else "baseline")
         self._pps = syn.pps_rbsp(init_qp=qp, cabac=cabac)
         self._hdr_slots_cache = {}
@@ -703,9 +716,10 @@ class H264Encoder(Encoder):
                 h = self._content_pending.pop(idx, None)
             if h is None:
                 return
-            stats = cs.vec_to_stats(np.asarray(h["vec"]),
-                                    np.asarray(h["grid"]),
-                                    self.pad_h * self.pad_w)
+            vec, grid = np.asarray(h["vec"]), np.asarray(h["grid"])
+            if kind in ("cabac_p", "cabac_intra"):
+                _M_D2H_BYTES.inc(vec.nbytes + grid.nbytes)
+            stats = cs.vec_to_stats(vec, grid, self.pad_h * self.pad_w)
             if h.get("first"):
                 stats["damage_fraction"] = None
                 stats["damage_grid"] = None
@@ -1535,6 +1549,7 @@ class H264Encoder(Encoder):
         with obst.stage("colour"):
             planes = self._host_yuv420(rgb) if self.host_color else None
         with obst.stage("dispatch") as span:
+            _note_h2d(*(planes if planes is not None else (rgb,)))
             if planes is not None and self._dyn_qp:
                 levels = h264_device.encode_intra_frame_yuv_dynqp(
                     *planes, np.int32(qp), i16_modes=self.i16_modes,
@@ -1617,6 +1632,7 @@ class H264Encoder(Encoder):
                 _note_cabac_dense()
                 dense = {k: np.asarray(levels[k])
                          for k, _, _ in level_pack.INTRA_KEYS}
+                _M_D2H_BYTES.inc(sum(v.nbytes for v in dense.values()))
             modes = small if small is not None else {
                 k: levels[k] for k in ("pred_mode", "mb_i4", "i4_modes")}
             dense.update({k: np.asarray(v) for k, v in modes.items()})
@@ -1636,6 +1652,7 @@ class H264Encoder(Encoder):
             return self._sp_submit_p(y, cb, cr, qp, frame_num)
         with obst.stage("dispatch") as span:
             frame_num = self._frame_num if frame_num is None else frame_num
+            _note_h2d(y, cb, cr)
             # self._ref is DONATED to the inter stage (recon aliases its
             # buffers — ops/h264_inter ring contract): dead past this call
             if self._dyn_qp:   # tune=off: no lookahead luma, no I16-in-P
@@ -1705,6 +1722,7 @@ class H264Encoder(Encoder):
                 _note_cabac_dense()
                 dense = {k: np.asarray(out[k])
                          for k, _, _ in level_pack.P_KEYS}
+                _M_D2H_BYTES.inc(sum(v.nbytes for v in dense.values()))
             dense["mv"] = np.asarray(mv, np.int32)
             qp_map = (np.asarray(out["qp_map"]) if "qp_map" in out
                       else None)

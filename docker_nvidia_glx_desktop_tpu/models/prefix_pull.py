@@ -21,6 +21,12 @@ M_CABAC_RECORD_BYTES = obsm.counter(
     "Bytes of CABAC transport the host pulled for its engine: header and "
     "payload of the binarize record stream (of the packed levels under "
     "ENCODER_CABAC_BINARIZE=host), without the slack of the guessed prefix")
+M_D2H_BYTES = obsm.counter(
+    "dngd_encoder_d2h_bytes_total",
+    "Bytes the per-frame CABAC path copied from the device: the guessed "
+    "prefix of the transport buffer, slack included, a second pull where "
+    "the guess was short, the content statistics' vector and grid, and "
+    "the level tensors of a dense fallback")
 
 
 def prefetch_host(arr) -> None:
@@ -91,6 +97,7 @@ class PrefixPull:
         guess was short; None on the overflow flag."""
         with obst.stage("pull"):
             head = np.asarray(prefix)
+        M_D2H_BYTES.inc(head.nbytes)
         if head[1]:
             return None
         words = int(head[2])
@@ -99,5 +106,6 @@ class PrefixPull:
             M_PULL_EXTRA.inc()
             with obst.stage("pull_extra"):
                 head = np.asarray(buf[:self.hdrw + self.rung(words)])
+            M_D2H_BYTES.inc(head.nbytes)
         M_CABAC_RECORD_BYTES.inc(4 * (self.hdrw + words))
         return head

@@ -153,7 +153,8 @@ class Config:
     # feasible_spatial_shards), "auto" = shard when the geometry's
     # modeled per-chip cost (fleet/capacity) exceeds the active SLO
     # rung's budget — one 4K session spreads across the chips the model
-    # says it needs instead of missing 4K30 on one.
+    # says it needs (what ONE chip measured at 4K30: PERF.md, cell
+    # desk2160-cabac.fulldamage).
     encoder_spatial_shards: str = "0"
     # Perceptual-efficiency tuning tier (ops/aq, ROADMAP item 4):
     # "off" = pre-tune encoder, byte-identical output; "hq" = per-MB

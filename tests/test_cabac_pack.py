@@ -16,8 +16,11 @@ import pytest
 import conftest
 from test_cabac_device import _p_levels, _yuv
 
+# (the 240-column programs are 40 s of trace and XLA:CPU compile each: the
+# slow tier's; tier-1 holds ``pack_rows`` itself to 240 columns below)
 _PACK_CASES = ("desktop", "fulldamage", "all_skip", "empty_rows",
-               "extreme_levels", "overflow", "shard_1x5")
+               "extreme_levels", "overflow", "shard_1x5",
+               pytest.param("rows_of_240", marks=pytest.mark.slow))
 
 
 @pytest.fixture(scope="module")
@@ -30,8 +33,10 @@ def kernel_jits():
 def _pack_case(kind, case, grid=None):
     """Level tensors for one packer case (the arguments of ``binarize_p`` /
     ``binarize_intra``): real stage output for the desktop, crafted (3, 5)
-    grids (a column count that is no power of two) for the rest, and one
-    MB row of 5 for a spatial shard; ``grid``: another (rows, columns)."""
+    grids (a column count that is no power of two) for the rest, one MB
+    row of 5 for a spatial shard, and two rows of 240, a 3840-wide picture's
+    (full damage: kernel B's row buffer at its longest); ``grid``: another
+    (rows, columns)."""
     import jax.numpy as jnp
 
     from docker_nvidia_glx_desktop_tpu.ops import h264_device
@@ -48,7 +53,8 @@ def _pack_case(kind, case, grid=None):
         return tuple(np.asarray(lv[k]) for k in (
             "luma_dc", "luma_ac", "cb_dc", "cb_ac", "cr_dc", "cr_ac",
             "pred_mode", "mb_i4", "i4_modes", "luma_i4"))
-    nr, nc = grid or ((1, 5) if case == "shard_1x5" else (3, 5))
+    nr, nc = grid or {"shard_1x5": (1, 5),
+                      "rows_of_240": (2, 240)}.get(case, (3, 5))
     rows = {"all_skip": [], "empty_rows": [0, 2]}.get(case, range(nr))
     z = lambda *shape: np.zeros((nr, nc) + shape, np.int32)
 
@@ -58,7 +64,7 @@ def _pack_case(kind, case, grid=None):
             a[r] = rng.integers(lo, hi + 1, a[r].shape) * keep
         return a
 
-    sparse = 1 if case == "fulldamage" else 3
+    sparse = 1 if case in ("fulldamage", "rows_of_240") else 3
     cb_dc, cr_dc = fill(z(4), -9, 9), fill(z(4), -9, 9, sparse)
     cb_ac, cr_ac = fill(z(4, 15), -2, 2, sparse), fill(z(4, 15), -2, 2, 4)
     if kind == "p":
@@ -125,26 +131,29 @@ class TestPackKernels:
         np.testing.assert_array_equal(got[:n], want[:n])
         np.testing.assert_array_equal(got[n:], want[n:])
 
+    @pytest.mark.parametrize("cols", [3, 240])
     @pytest.mark.parametrize("widest", [26, 32])
-    def test_pack_rows_against_a_bit_string(self, widest):
+    def test_pack_rows_against_a_bit_string(self, widest, cols):
         """``pack_rows`` itself on slots up to its stated widths (a value of
         at most 26 bits under a length of at most 32: CAVLC's level escapes
         are 28, 30 and 32), against the bits written out one after the
         other.  Eight 32-bit slots behind a phase reach into a ninth word,
-        which is the next group's first: the case must hold such groups."""
+        which is the next group's first: the case must hold such groups.
+        240 columns are a 3840-wide picture's rows: kernel B's walk and
+        row buffer at the length the 4K deployment gives them."""
         import jax
         from jax.experimental.pallas import tpu as pltpu
 
         from docker_nvidia_glx_desktop_tpu.ops import cabac_pack
 
         rng = np.random.default_rng(widest)
-        r, c, s = 2, 3, 37
+        r, c, s = 2, cols, 37
         lns = rng.integers(widest - 5, widest + 1, (r, c, s))
         lns *= rng.integers(0, 8, (r, c, s)) > 0            # some empty
         lns[1, 1] = 0                                       # an empty MB
         vals = rng.integers(0, 1 << 26, (r, c, s)) & ((1 << lns) - 1)
         # a macroblock's piece: a line of a chunk, or whole chunks
-        cap, out_words = (64 if widest == 32 else 200), 512
+        cap, out_words = (64 if widest == 32 else 200), 512 * -(-cols // 3)
         want = np.zeros(out_words, np.uint32)
         word, spills = 0, 0
         for i in range(r):

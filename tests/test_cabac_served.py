@@ -2,7 +2,10 @@
 ``make_encoder``, the deployment ``benchmark/configs/desk1080-cabac.json``) at
 128x96: against the plain references (the pure-Python CABAC coder on the same
 level tensors; cv2's ffmpeg as the independent decoder), with ``qp`` traced,
-the pull ladder warmed, its stages sampled and its fallbacks counted."""
+the pull ladder warmed, its stages sampled and its fallbacks counted.  And
+what ``desk2160-cabac`` adds: the level a stream declares, and the same
+comparison at 3840x32, the 4K deployment's 240 macroblocks a row (slow tier:
+40 s of XLA:CPU compiles)."""
 
 import json
 import pathlib
@@ -31,18 +34,18 @@ CONFIG_ENV = json.loads(
     (ROOT / "benchmark" / "configs" / "desk1080-cabac.json").read_text())["env"]
 
 
-def config(**over):
+def config(w=W, h=H, **over):
     """The deployment's environment, geometry overridden."""
-    return from_env(dict(CONFIG_ENV, SIZEW=str(W), SIZEH=str(H),
+    return from_env(dict(CONFIG_ENV, SIZEW=str(w), SIZEH=str(h),
                          PASSWD="pw", **over))
 
 
-def frame(c: int, noise: float = 10.0, seed: int = 28) -> np.ndarray:
+def frame(c: int, noise: float = 10.0, seed: int = 28, w=W, h=H) -> np.ndarray:
     """Seeded noise over ramps that pan by (2c, c): the search finds the
     ramps, the noise leaves levels in every macroblock."""
-    yy, xx = np.mgrid[c:c + H, 2 * c:2 * c + W]
+    yy, xx = np.mgrid[c:c + h, 2 * c:2 * c + w]
     v = (xx * 1.5 + yy * 0.7) % 256 + np.random.default_rng(
-        seed + c).normal(0.0, noise, (H, W))
+        seed + c).normal(0.0, noise, (h, w))
     return np.clip(np.stack([v, 0.8 * v + 20, 255 - v], axis=-1),
                    0, 255).astype(np.uint8)
 
@@ -56,7 +59,7 @@ def samples(stage: str) -> int:
     return obsm.REGISTRY.get(f"dngd_stage_{stage}_ms")._default.count
 
 
-def decode_luma(data: bytes, path):
+def decode_luma(data: bytes, path, w=W, h=H):
     """The decoder's own luma planes (no colour conversion of cv2's)."""
     path.write_bytes(data)
     cap = cv2.VideoCapture(str(path))
@@ -66,7 +69,7 @@ def decode_luma(data: bytes, path):
         ok, img = cap.read()
         if not ok:
             break
-        out.append(np.asarray(img).reshape(-1)[:W * H].reshape(H, W).copy())
+        out.append(np.asarray(img).reshape(-1)[:w * h].reshape(h, w).copy())
     cap.release()
     return out
 
@@ -86,10 +89,10 @@ def small_buckets():
     mp.undo()
 
 
-def served_encoder(**over):
+def served_encoder(w=W, h=H, **over):
     from docker_nvidia_glx_desktop_tpu.models import make_encoder
 
-    enc, name = make_encoder(config(**over), W, H)
+    enc, name = make_encoder(config(w, h, **over), w, h)
     assert name == "h264_cabac" and enc._dyn_qp
     return enc
 
@@ -104,30 +107,70 @@ def encoder():
     return enc
 
 
-def test_served_stream_is_the_reference_coders_and_the_decoders(encoder,
-                                                                tmp_path):
-    """1 IDR + 5 P frames with the rate controller moving ``qp``: every
-    access unit is byte for byte what the Python reference coder makes of
-    the same levels, and the independent decoder's luma is the encoder's
-    own reference picture after every frame."""
-    enc = encoder
+@pytest.mark.parametrize("w,h,n", [
+    (W, H, 6), pytest.param(3840, 32, 4, marks=pytest.mark.slow)])
+def test_served_stream_is_the_reference_coders_and_the_decoders(
+        w, h, n, request, tmp_path):
+    """1 IDR + 5 P frames with the rate controller moving ``qp`` (at
+    3840x32, a 4K picture's 240 macroblocks a row, 1 + 3): every access
+    unit is byte for byte what the Python reference coder makes of the
+    same levels, and the independent decoder's luma is the encoder's own
+    reference picture after every frame."""
+    enc = (request.getfixturevalue("encoder") if (w, h) == (W, H)
+           else served_encoder(w, h))
     enc.request_keyframe()
     data, refs, qps, keys = enc.headers(), [], [], []
-    for c in range(6):
-        token = enc.encode_submit(frame(c))
+    for c in range(n):
+        token = enc.encode_submit(frame(c, w=w, h=h))
         want = reference_unit(enc, token)
         ef = enc.encode_collect(token)
         assert ef.data == want, f"frame {c} differs from the reference coder"
         data += ef.data
         keys.append(ef.keyframe)
         qps.append(token[4][-2])
-        refs.append(np.array(enc.export_state()["ref"][0][:H, :W]))
-    assert keys == [True] + [False] * 5
-    assert len(set(qps)) >= 3, qps             # qp moved at least twice
-    lumas = decode_luma(data, tmp_path / "served.h264")
-    assert len(lumas) == 6
+        refs.append(np.array(enc.export_state()["ref"][0][:h, :w]))
+    assert keys == [True] + [False] * (n - 1)
+    assert len(set(qps)) >= n // 2, qps        # qp moved at least twice
+    lumas = decode_luma(data, tmp_path / "served.h264", w, h)
+    assert len(lumas) == n
     for c, (luma, ref) in enumerate(zip(lumas, refs)):
         assert np.array_equal(luma, ref), f"picture {c} is not the reference"
+
+
+@pytest.mark.parametrize("w,h,fps,level", [
+    (1280, 720, 30, 42), (1920, 1080, 60, 42), (2560, 1600, 60, 51),
+    (3840, 2160, 30, 51)])
+def test_stream_declares_the_level_its_size_and_refresh_need(
+        w, h, fps, level, tmp_path):
+    """H.264 Table A-1: the lowest level whose MaxFS and MaxMBPS hold the
+    stream, never under 4.2 (every stream up to 1080p60 keeps its bytes).
+    The muxer's codec string, which sizes a browser's hardware decoder,
+    follows the SPS, and the independent decoder still takes the stream:
+    one picture of I_PCM macroblocks at the real size (no device program),
+    whose luma comes back sample for sample."""
+    from docker_nvidia_glx_desktop_tpu.bitstream import h264 as syn
+    import jax.numpy as jnp
+
+    from docker_nvidia_glx_desktop_tpu.models import H264Encoder
+    from docker_nvidia_glx_desktop_tpu.models.h264 import _yuv_stage
+    from docker_nvidia_glx_desktop_tpu.web.mp4 import Mp4Muxer, split_annexb
+
+    assert syn.level_idc_for(w, h, fps) == level
+    for profile, idc in (("main", 77), ("baseline", 66)):
+        sps = syn.sps_rbsp(w, h, fps, profile=profile)
+        assert (sps[0], sps[2]) == (idc, level)
+    enc = H264Encoder(w, h, mode="pcm", fps=fps)
+    nals = split_annexb(enc.headers())
+    sps = next(n for n in nals if n[0] & 0x1F == 7)
+    pps = next(n for n in nals if n[0] & 0x1F == 8)
+    assert Mp4Muxer(w, h, sps, pps, fps=fps).mime == (
+        f'video/mp4; codecs="avc1.42C0{level:02X}"')
+    grey = np.full((h, w, 3), 97, np.uint8)
+    grey[:, ::2] = 180
+    lumas = decode_luma(enc.encode(grey).data, tmp_path / "pcm.h264", w, h)
+    want = _yuv_stage(jnp.asarray(grey), enc.pad_h, enc.pad_w)[0]
+    assert len(lumas) == 1
+    assert np.array_equal(lumas[0], np.asarray(want)[:h, :w])
 
 
 def test_codec_string_and_sdp_profile_follow_the_served_sps(encoder):
@@ -257,6 +300,8 @@ def served():
     before = {n: samples(n) for n in names}
     record = counter("dngd_encoder_cabac_record_bytes_total")
     fell = counter("dngd_encoder_cabac_fallback_total")
+    link = {d: counter(f"dngd_encoder_{d}_bytes_total")
+            for d in ("h2d", "d2h")}
     sess.start()
     try:
         assert done.wait(300), posted
@@ -266,6 +311,8 @@ def served():
             "posted": list(posted), "mime": sess.hello()["mime"],
             "record": counter("dngd_encoder_cabac_record_bytes_total")
             - record,
+            "link": {d: counter(f"dngd_encoder_{d}_bytes_total") - was
+                     for d, was in link.items()},
             "fell": counter("dngd_encoder_cabac_fallback_total") - fell}
 
 
@@ -288,6 +335,18 @@ def test_a_served_cabac_frame_is_counted_and_never_falls_back(served):
     assert served["record"] >= frames * 4 * (8 + NR + NR * NC // 4)
     assert served["fell"] == 0
     assert served["mime"].startswith('video/mp4; codecs="avc1.4D')
+
+
+def test_a_served_cabac_frame_counts_its_bytes_over_the_link(served):
+    """Host to device: the three planes of every dispatched frame, nothing
+    else.  Device to host: at least the record stream the engine read (the
+    guessed prefix has slack on top, and the content statistics ride
+    beside it), and under the whole buffer a frame."""
+    frames = len(served["posted"])
+    planes = W * H * 3 // 2
+    sent, rest = divmod(served["link"]["h2d"], planes)
+    assert rest == 0 and frames <= sent <= frames + 3, served["link"]
+    assert served["record"] < served["link"]["d2h"] < frames * 4 * 48_000
 
 
 def test_a_dense_fallback_is_counted_and_codes_the_same_bytes(

@@ -13,7 +13,9 @@ kernel compiled by Mosaic (a ``jax.default_backend()`` branch would pick
 the CPU's form here, so the test answers "tpu" while the programs are
 lowered), the two CABAC binarize programs with the record packer's two
 kernels (``ops/cabac_pack``, chosen the same way; since PR 31 the CAVLC
-frame pack of the intra and the P step is the same two), the (4,1)
+frame pack of the intra and the P step is the same two), the P picture's
+binarize program and the loop filter again at 3840x2176 (the 4K
+deployment's 240 macroblocks a row: a new shape is a new compile), the (4,1)
 session-mesh step of ``TPU_SESSIONS``/``TPU_MESH`` on a ``Mesh`` of the
 four described devices, and a P step of two sessions a chip (``jax.vmap``
 over the kernels).
@@ -127,6 +129,23 @@ def programs(topo, no_persistent_cache):
         lowered["binarize_intra"] = jax.jit(
             lambda *a: cabac_binarize.binarize_intra.__wrapped__(*a)).lower(
                 lv(16), lv(16, 15), *chroma, lv(), lv(), lv(16), lv(16, 16))
+        # desk2160-cabac (PR 32): the two places whose kernels read a row
+        # of 240 macroblocks from their shapes: the record packer (kernel
+        # A's tiles, kernel B's row buffer) and the loop filter
+        h4, w4 = 2176, 3840
+        at4 = lambda dt, *shape: jax.ShapeDtypeStruct(
+            (h4 // 16, w4 // 16) + shape, dt, sharding=one)
+        lowered["binarize_p_2160"] = jax.jit(
+            lambda *a: cabac_binarize.binarize_p.__wrapped__(*a)).lower(
+                at4(jnp.int8, 2), at4(jnp.int32, 16, 16),
+                *[at4(jnp.int32, *sh) for sh in ((4,), (4, 15)) * 2])
+        y4, c4 = (jax.ShapeDtypeStruct((h4 // d, w4 // d), jnp.uint8,
+                                       sharding=one) for d in (1, 2))
+        lowered["deblock_p_2160"] = jax.jit(
+            lambda *a, **kw: h264_deblock.deblock_frame.__wrapped__(
+                *a, **kw)).lower(y4, c4, c4, qp,
+                                 nnz_blk=at4(jnp.bool_, 4, 4),
+                                 mv=at4(jnp.int32, 2))
         # web/multisession: four 1080p sessions, one per chip
         mesh = batch.make_mesh((4, 1), topo.devices)
         planes = lambda m, n: tuple(
@@ -201,8 +220,9 @@ def test_p_step_compiles_and_donates_the_ring(programs):
     _has_the_pack_kernels(c.as_text())
 
 
-def test_p_deblock_compiles_with_the_edge_kernel(programs):
-    c = _compiled(programs, "deblock_p")
+@pytest.mark.parametrize("name", ["deblock_p", "deblock_p_2160"])
+def test_p_deblock_compiles_with_the_edge_kernel(programs, name):
+    c = _compiled(programs, name)
     assert 0 < _device_bytes(c) < HBM_BYTES
     # the whole edge chain is one Mosaic kernel, and no scan is left
     text = c.as_text()
@@ -210,7 +230,8 @@ def test_p_deblock_compiles_with_the_edge_kernel(programs):
     assert " while(" not in text
 
 
-@pytest.mark.parametrize("name", ["binarize_p", "binarize_intra"])
+@pytest.mark.parametrize("name", ["binarize_p", "binarize_intra",
+                                  "binarize_p_2160"])
 def test_binarize_compiles_with_the_pack_kernels(programs, name):
     c = _compiled(programs, name)
     assert 0 < _device_bytes(c) < HBM_BYTES
