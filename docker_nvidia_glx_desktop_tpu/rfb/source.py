@@ -27,7 +27,10 @@ __all__ = ["FrameSource", "SyntheticSource", "NumpySource", "make_source"]
 
 
 class FrameSource:
-    """Interface: latest-frame semantics (lossy, like a framebuffer)."""
+    """Interface: latest-frame semantics (lossy, like a framebuffer).  A
+    source whose ``frame()`` costs a picture also offers ``seq()``, the
+    number ``frame()`` would return now, to a consumer that waits for the
+    next frame (web/session.py:_await_frame)."""
 
     width: int
     height: int
@@ -59,8 +62,11 @@ class SyntheticSource(FrameSource):
         self._band = (rng.integers(0, 2, size=(max(height // 8, 1), width, 3))
                       * 200).astype(np.uint8)
 
+    def seq(self) -> int:
+        return int((time.monotonic() - self._t0) * self._fps)
+
     def frame(self) -> Tuple[np.ndarray, int]:
-        seq = int((time.monotonic() - self._t0) * self._fps)
+        seq = self.seq()
         f = self._base.copy()
         h, w = self.height, self.width
         # moving window
@@ -96,6 +102,9 @@ class NumpySource(FrameSource):
     def frame(self) -> Tuple[np.ndarray, int]:
         with self._lock:
             return self._frame, self._seq
+
+    def seq(self) -> int:
+        return self._seq
 
 
 class XShmSource(FrameSource):

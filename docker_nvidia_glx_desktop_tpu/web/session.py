@@ -89,6 +89,13 @@ _M_SOURCE_FAIL = obsm.counter(
 _M_KEYFRAMES = obsm.counter(
     "dngd_encoder_keyframes_total",
     "Keyframes delivered to fan-out (IDR resyncs land here)")
+_M_LOCKED_TAKES = obsm.counter(
+    "dngd_session_locked_takes_total",
+    "Turns whose next frame was found BY the end-of-turn wait: taken "
+    "within a look's step of the source swapping it in")
+_M_TAKE_LOOKS = obsm.counter(
+    "dngd_session_take_looks_total",
+    "Looks at the source's sequence number spent in the end-of-turn wait")
 M_IDR_REQUESTS = obsm.counter(
     "dngd_idr_requests_total",
     "Forced-IDR requests through the session's rate-limited "
@@ -301,6 +308,9 @@ class StreamSession:
         self._stop = threading.Event()
         self._prewarm = None
         self._last_seq = -1
+        # seconds the last take came after its frame's swap through turns
+        # that overran the refresh, as far as the loop knows (_await_frame)
+        self._behind = 0.0
         self._need_frame = False
         # set on a collect failure: suppress delivery of in-flight P
         # frames (they predict from a reference the client never got)
@@ -765,6 +775,59 @@ class StreamSession:
         return False
 
     PIPELINE_DEPTH = 2   # frames in flight: upload/compute/pull overlap
+    # The end of a turn that has time left (_await_frame): asleep until a
+    # guard short of the refresh, then a look at the source every step.
+    # On the chip's host a sleep of 3 ms overshoots by 0.25 ms (1.1 at
+    # worst) and the display swaps under 0.2 ms late: the guard covers
+    # both.  A step is slept as 1.2 ms there, and a frame found by the
+    # wait is 0.4 ms old at the median, for 3 looks a frame (PERF.md
+    # section 6, PR 33).
+    TAKE_GUARD_S = 0.0015
+    TAKE_STEP_S = 0.0005
+
+    def _source_seq(self) -> int:
+        """The source's cheapest look: its sequence number alone."""
+        peek = getattr(self.source, "seq", None)
+        return peek() if peek is not None else self.source.frame()[1]
+
+    def _await_frame(self, t0: float, frame_interval: float) -> None:
+        """End a turn that began at ``t0`` on the SOURCE's next frame.
+
+        A sleep of what is left of the refresh makes the turn one refresh
+        plus the sleep's overshoot: the loop slides against the display by
+        0.1-0.2 ms a turn, the age of the frame it finds sweeps 0..16.7 ms
+        and one frame in ~110 goes by unseen; a deadline on the loop's own
+        clock would freeze that age wherever the session started.  So the
+        turn sleeps to a guard short of the refresh and then looks every
+        step until the sequence number moves, or until the idle poll's
+        quarter refresh past the old wake-up time (a source gone quiet);
+        the top of the loop takes the frame.  ``_behind`` is how late the
+        last take was through turns that overran: the wait starts that
+        much sooner, so the lateness is made up by each turn's slack."""
+        wake = t0 + frame_interval - self.TAKE_GUARD_S - self._behind
+        limit = t0 + frame_interval + frame_interval / 4
+        now = time.perf_counter()
+        if wake > now:
+            time.sleep(wake - now)
+        looks = 0
+        while not self._stop.is_set():
+            looks += 1
+            try:
+                moved = self._source_seq() != self._last_seq
+            except Exception:
+                break               # the top of the loop meets it, and retries
+            now = time.perf_counter()
+            if moved and looks > 1:
+                _M_LOCKED_TAKES.inc()
+                self._behind = 0.0
+            elif moved:
+                # already there: the turn ends early by what it had left
+                self._behind = max(
+                    0.0, self._behind - (t0 + frame_interval - now))
+            if moved or now + self.TAKE_STEP_S > limit:
+                break
+            time.sleep(self.TAKE_STEP_S)
+        _M_TAKE_LOOKS.inc(looks)
 
     def _run(self) -> None:
         pending: list = []                   # submitted tokens, oldest first
@@ -1021,10 +1084,13 @@ class StreamSession:
 
             elapsed = time.perf_counter() - t0
             sleep = frame_interval - elapsed
-            if sleep > 0 and not self._subscribers:
+            if sleep <= 0:
+                # over the refresh: the newest frame is taken at once
+                self._behind = (self._behind - sleep) % frame_interval
+            elif not self._subscribers:
                 time.sleep(min(sleep * 4, 0.25))   # idle: throttle down
-            elif sleep > 0:
-                time.sleep(sleep)
+            else:
+                self._await_frame(t0, frame_interval)
 
     def _post(self, fragment: bytes, keyframe: bool,
               fid: int = 0) -> None:

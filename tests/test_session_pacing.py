@@ -1,0 +1,265 @@
+"""How a turn of the session loop ends (web/session.py:_await_frame), on a
+clock that no wall time moves: ``StreamSession._run`` itself, in the test's
+own thread, with the module's ``time`` replaced by a fake whose ``sleep``
+advances it, a 60 Hz counter as the source and an encoder whose only work
+is to advance the clock.  Six xdist workers cannot make any of it late."""
+
+import math
+import types
+
+import pytest
+
+from docker_nvidia_glx_desktop_tpu.rfb.source import (NumpySource,
+                                                       SyntheticSource)
+from docker_nvidia_glx_desktop_tpu.utils.config import from_env
+from docker_nvidia_glx_desktop_tpu.web import session as session_mod
+from docker_nvidia_glx_desktop_tpu.web.session import StreamSession
+
+REFRESH = 1 / 60
+STEP = StreamSession.TAKE_STEP_S
+EPS = 0.0002        # a sleep's overshoot and a few reads of the clock
+
+
+class FakeTime:
+    """``perf_counter``, ``monotonic`` and ``sleep`` of one made-up clock.
+    A sleep overshoots as the kernel's does; a read costs a microsecond, so
+    that a loop which only reads the clock still gets somewhere."""
+
+    def __init__(self, overshoot=0.00006):
+        self.t, self.overshoot, self.sleeps = 50.0, overshoot, []
+
+    def perf_counter(self):
+        self.t += 1e-6
+        return self.t
+
+    monotonic = perf_counter
+
+    def sleep(self, dt):
+        self.sleeps.append(dt)
+        self.t += max(dt, 0.0) + self.overshoot
+
+
+class Counter60:
+    """A display: frame ``k`` is swapped in at ``k / hz`` whatever the loop
+    does.  As ``benchmark/display.py`` it has no peek and notes the first
+    look that found each frame."""
+
+    width, height = 64, 48
+
+    def __init__(self, clock, hz=60.0, until=10.0, static=False):
+        self.clock, self.hz, self.static = clock, hz, static
+        self.t0 = clock.t
+        self.until = self.t0 + until
+        self.seen = {}                   # k -> clock at the first look
+        self.session = None
+
+    def frame(self):
+        now = self.clock.perf_counter()
+        if now >= self.until:
+            self.session._stop.set()
+        k = 0 if self.static else int((now - self.t0) * self.hz)
+        self.seen.setdefault(k, now)
+        return k, k                      # the "picture" is its own index
+
+    def age(self, k):
+        return self.seen[k] - (self.t0 + k / self.hz)
+
+
+class Work:
+    """An encoder front that costs time and nothing else: ``cost(n)``
+    seconds in the loop's ``n``-th turn, 0.7 of it in the submit."""
+
+    pipeline_depth = 2
+
+    def __init__(self, clock, cost):
+        self.clock, self.cost, self.taken = clock, cost, []
+
+    def encode_submit(self, k):
+        self.taken.append(k)
+        self.clock.t += 0.7 * self.cost(len(self.taken))
+        return k
+
+    def encode_collect(self, k):
+        self.clock.t += 0.3 * self.cost(len(self.taken))
+        return types.SimpleNamespace(data=b"au", keyframe=False,
+                                     encode_ms=1.0)
+
+    def request_keyframe(self):
+        pass
+
+    def export_state(self):
+        return {}
+
+
+def counters():
+    return (session_mod._M_LOCKED_TAKES.value,
+            session_mod._M_TAKE_LOOKS.value)
+
+
+def drive(monkeypatch, cost=lambda n: 0.010, seconds=10.0, overshoot=0.00006,
+          subscribers=True, end_of_turn=None, fps_cap=None, static=False):
+    """Run the loop for ``seconds`` of the fake clock; what it took and when."""
+    clock = FakeTime(overshoot)
+    monkeypatch.setattr(session_mod, "time", clock)
+    cfg = from_env({"PASSWD": "pw", "SIZEW": "64", "SIZEH": "48",
+                    "REFRESH": "60", "WEBRTC_ENCODER": "tpumjpegenc",
+                    "ENCODER_PREWARM": "false"})
+    source = Counter60(clock, until=seconds, static=static)
+    sess = StreamSession(cfg, source)
+    source.session = sess
+    sess.encoder = work = Work(clock, cost)
+    sess.PIPELINE_DEPTH = work.pipeline_depth
+    sess._post = lambda *a, **k: None
+    sess._fps_cap = fps_cap
+    if subscribers:
+        sess.subscribe()
+    if end_of_turn is not None:
+        monkeypatch.setattr(StreamSession, "_await_frame", end_of_turn)
+    before = counters()
+    try:
+        sess._run()
+    finally:
+        sess.close()
+    locked, looks = (b - a for a, b in zip(before, counters()))
+    return types.SimpleNamespace(
+        clock=clock, source=source, taken=work.taken,
+        ages=[source.age(k) for k in work.taken],
+        locked=locked, looks=looks)
+
+
+def relative_sleep(self, t0, frame_interval):
+    """The end of a turn as it was before PR 33."""
+    left = frame_interval - (session_mod.time.perf_counter() - t0)
+    if left > 0:
+        session_mod.time.sleep(left)
+
+
+def test_every_take_is_within_a_step_of_its_swap_and_none_is_skipped(
+        monkeypatch):
+    run = drive(monkeypatch)
+    assert len(run.taken) >= 598
+    assert run.taken[12:] == list(range(run.taken[12], run.taken[-1] + 1))
+    assert max(run.ages[12:]) <= STEP + EPS
+    assert run.locked >= len(run.taken) - 13
+    assert run.looks / len(run.taken) <= 4.0
+
+
+def test_the_relative_sleep_it_replaced_was_a_sawtooth(monkeypatch):
+    """The regression this file guards: a turn of one refresh plus the
+    sleep's overshoot slides against the display, the age sweeps the whole
+    refresh and a frame goes by unseen every hundred-odd turns."""
+    run = drive(monkeypatch, overshoot=0.00015, end_of_turn=relative_sleep)
+    ages = sorted(run.ages)
+    assert ages[len(ages) // 2] > 0.25 * REFRESH
+    assert ages[-1] > 0.9 * REFRESH
+    skipped = run.taken[-1] - run.taken[0] + 1 - len(run.taken)
+    assert 3 <= skipped <= 8             # one in ~110 of 600
+    assert run.looks == 0
+
+
+def test_work_over_the_refresh_takes_the_newest_frame_at_once(monkeypatch):
+    run = drive(monkeypatch, cost=lambda n: 0.0202, seconds=4.0)
+    # the first turn fills the pipeline (a submit alone) and may wait
+    first = math.ceil(
+        (StreamSession.TAKE_GUARD_S + REFRESH / 4) / STEP)
+    assert run.looks <= first and run.locked <= 1
+    assert len([dt for dt in run.clock.sleeps if dt > 0]) <= first
+    gaps = {b - a for a, b in zip(run.taken, run.taken[1:])}
+    assert gaps <= {1, 2}                # 49.5 of 60 a second, the newest each
+
+
+def test_one_long_turn_is_made_up_by_the_slack_and_skips_nothing_more(
+        monkeypatch):
+    at = 200
+    run = drive(monkeypatch,
+                cost=lambda n: 0.040 if n == at + 1 else 0.010, seconds=6.0)
+    # a 40 ms turn spans two refreshes: the frame after its own is the loss
+    assert run.taken[at + 1] == run.taken[at] + 2
+    rest = run.taken[at + 1:]
+    assert rest == list(range(rest[0], rest[-1] + 1))
+    slack = REFRESH - 0.010
+    back = math.ceil(REFRESH / slack)
+    assert max(run.ages[at + 1 + back:]) <= STEP + EPS
+    assert run.ages[at + 1] > 0.005      # it did carry the overrun's age
+
+
+def test_a_static_source_with_nothing_pending_idles_without_a_look(
+        monkeypatch):
+    run = drive(monkeypatch, seconds=2.0, static=True)
+    assert run.taken == [0]              # the joiner's keyframe
+    # the one frame's turn and the turn that drained it end in the wait,
+    # each at its limit; from there the idle poll, a quarter refresh a time
+    polls = [dt for dt in run.clock.sleeps
+             if dt == pytest.approx(REFRESH / 4)]
+    assert len(polls) > 400
+    assert run.looks <= 2 * math.ceil(
+        (StreamSession.TAKE_GUARD_S + REFRESH / 4) / STEP)
+    assert run.locked == 0
+
+
+def test_the_limit_ends_the_wait_of_a_source_that_never_changes(monkeypatch):
+    clock = FakeTime()
+    monkeypatch.setattr(session_mod, "time", clock)
+    sess = types.SimpleNamespace(
+        _last_seq=0, _behind=0.0,
+        _stop=types.SimpleNamespace(is_set=lambda: False),
+        TAKE_GUARD_S=StreamSession.TAKE_GUARD_S, TAKE_STEP_S=STEP,
+        _source_seq=lambda: 0)
+    t0 = clock.t
+    StreamSession._await_frame(sess, t0, REFRESH)
+    assert REFRESH < clock.t - t0 <= REFRESH * 1.25 + EPS
+    assert clock.sleeps[0] == pytest.approx(
+        REFRESH - StreamSession.TAKE_GUARD_S, abs=1e-5)
+
+
+def test_a_cap_under_the_sources_rate_sets_the_rate(monkeypatch):
+    run = drive(monkeypatch, seconds=10.0, fps_cap=30.0)
+    # the frame is there long before the guard: taken at the cap's rate
+    # (a guard short of its interval), never locked
+    assert 295 <= len(run.taken) <= 316
+    assert run.locked == 0
+    assert run.looks <= len(run.taken)
+
+
+def test_without_subscribers_the_loop_throttles_and_never_waits(monkeypatch):
+    run = drive(monkeypatch, seconds=4.0, subscribers=False)
+    assert run.looks == 0
+    # a 10 ms turn leaves 6.7 ms of the refresh, and sleeps four times that
+    assert len(run.taken) < 4.0 / (0.010 + 4 * 0.006)
+    assert any(dt > REFRESH for dt in run.clock.sleeps)
+
+
+def test_a_source_with_a_peek_is_looked_at_through_it(monkeypatch):
+    clock = FakeTime()
+    monkeypatch.setattr(session_mod, "time", clock)
+    frames = []
+
+    class Peeking(NumpySource):
+        def frame(self):
+            frames.append(1)
+            return super().frame()
+
+    src = Peeking(64, 48)
+    sess = types.SimpleNamespace(source=src)
+    assert StreamSession._source_seq(sess) == 0 and not frames
+    sess.source = Counter60(clock)
+    assert StreamSession._source_seq(sess) == 0 and sess.source.seen
+
+
+def test_the_synthetic_sources_peek_agrees_with_its_frame(monkeypatch):
+    """``SyntheticSource.frame()`` paints a whole picture a call; a look of
+    the wait costs a read of the clock."""
+    from docker_nvidia_glx_desktop_tpu.rfb import source as source_mod
+    now = [100.0]
+    monkeypatch.setattr(source_mod, "time",
+                        types.SimpleNamespace(monotonic=lambda: now[0]))
+    src = SyntheticSource(64, 48, fps=60.0)
+    base, src._base = src._base, None   # a peek that painted would raise
+    seqs = []
+    for _ in range(6):
+        now[0] += 0.0123
+        seqs.append(src.seq())
+    src._base = base
+    assert src.frame()[1] == seqs[-1] == int(6 * 0.0123 * 60.0)
+    assert seqs == sorted(seqs) and len(set(seqs)) > 3
+    assert NumpySource(64, 48).seq() == NumpySource(64, 48).frame()[1] == 0
