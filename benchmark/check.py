@@ -11,6 +11,7 @@ what decoders assume then, and what ``utils/hostcolor`` feeds the encoder).
 
 from __future__ import annotations
 
+import bisect
 import struct
 
 import cv2
@@ -83,14 +84,36 @@ def read_stream(path: str, width: int, height: int, render_luma, psnr_every: int
     return ks, psnr
 
 
-def order_faults(ks, stamps, handed_at: dict) -> int:
-    """Pictures whose frame index does not read, does not rise strictly, was
-    never handed out by the display, or arrived before it was handed out."""
-    faults, last = 0, -1
-    for k, stamp in zip(ks, stamps):
-        if k is None or k <= last or k not in handed_at \
-                or stamp < handed_at[k]:
-            faults += 1
+def order_faults(ks, stamps, handed) -> list:
+    """The pictures whose frame index does not read, does not rise strictly,
+    was never handed out by the display, or arrived before it was handed out;
+    ``handed`` is the display's ``(k, time)`` of every frame it gave out, in
+    order.  One dict a fault, ``len()`` of the list is the number compared:
+    ``picture`` (its place in the stream), ``k`` (read from it), ``after``
+    (the highest ``k`` read before it), ``handed_k`` (the last ``k`` the
+    display had handed out when the picture arrived: a ``k`` above it means
+    the picture was AHEAD of the display, so the display's buffer changed
+    under the session; one at or under ``after`` is a picture sent again or
+    out of order, which is the program's doing), ``stamp`` and ``why``."""
+    handed_at = dict(handed)
+    times = [t for _, t in handed]
+    faults, last = [], -1
+    for i, (k, stamp) in enumerate(zip(ks, stamps)):
+        if k is None:
+            why = "the barcode does not read"
+        elif k <= last:
+            why = "k does not rise"
+        elif k not in handed_at:
+            why = "the display never handed this k out"
+        elif stamp < handed_at[k]:
+            why = "arrived before the display handed it out"
+        else:
+            why = None
+        if why:
+            n = bisect.bisect_right(times, stamp)
+            faults.append({"picture": i, "k": k, "after": last,
+                           "handed_k": handed[n - 1][0] if n else None,
+                           "stamp": stamp, "why": why})
         if k is not None:
             last = max(last, k)
     return faults

@@ -10,6 +10,17 @@ the offered rate is the refresh, a slower system delivers fewer of the same
 frames, and a delivered frame's latency counts from when frame ``k`` was due.
 The child records how late it swapped every frame in.
 
+The ring is indexed by frames RENDERED, not by ``k``: the child's ``n``-th
+render goes into ``ring[n % RING]`` whatever ``k`` it shows, and ``k`` and the
+slot are published together in one control word, so the serving process never
+pairs a ``k`` with another frame's buffer.  The guarantee: a buffer handed out
+as frame ``k`` is not written again before ``RING - 1`` further frames have
+been rendered, however many refreshes the display skipped (indexed by ``k`` it
+was: a display that skipped exactly 11 or 23 refreshes rendered ``k + 12`` or
+``k + 24`` into the buffer it had just handed out, PERF.md section 6, PR 34).
+The child writes every buffer once before it says it is ready, so no frame
+pays for the first touch of its pages.
+
 ``mark_epoch()`` (called at the start of the measured window) restarts the
 content at its first frame from the refresh after next, so that every run of
 one seed shows the window the same pictures whatever its set-up took; the
@@ -33,7 +44,8 @@ import numpy as np
 
 RING = 12           # buffers: the encoder may still hold the frames in flight
 MAX_FRAMES = 1 << 17                      # 36 minutes of refreshes at 60 Hz
-# the control block, int64 words
+TOUCHED = 128       # what a buffer holds between its first touch and a frame
+# the control block, int64 words; LATEST is k * RING + slot, one store
 LATEST, EPOCH_NEXT, STOP, T0_NS, SKIPPED, READY, CPUS, LATE_US = \
     0, 1, 3, 4, 5, 6, 7, 16
 READY_TIMEOUT_S = 120.0
@@ -95,13 +107,14 @@ class Display:
         return k
 
     def frame(self):
-        k = int(self._ctl[LATEST])
-        if k < 0:
+        word = int(self._ctl[LATEST])
+        if word < 0:
             return self._ring[0], -1
+        k, slot = divmod(word, RING)
         if k != self._handed_k:
             self._handed_k = k
             self.handed.append((k, time.monotonic()))
-        return self._ring[k % RING], k
+        return self._ring[slot], k
 
     @property
     def skipped(self) -> int:
@@ -157,22 +170,26 @@ def child_main() -> int:
     scene = build_scene(job["traffic"], w, h, fps, job["seed"])
     shm_ring, ring = _attach(job["ring"], (RING, h, w, 3), np.uint8)
     shm_ctl, ctl = _attach(job["ctl"], (LATE_US + MAX_FRAMES,), np.int64)
+    # the first write to a buffer faults its pages in (0.2 s a 12.3 MB buffer
+    # in some checkouts, PR 32): paid here, not by the run's first frames
+    ring.fill(TOUCHED)
     t0 = time.monotonic()
     ctl[T0_NS] = int(t0 * 1e9)
     ctl[CPUS] = inherited
     ctl[READY] = 1
-    k, epoch = 0, 0
+    k, epoch, n = 0, 0, 0               # n: frames rendered
     while not ctl[STOP] and k < MAX_FRAMES:
         nxt = int(ctl[EPOCH_NEXT])
         if 0 <= nxt <= k:
             epoch, ctl[EPOCH_NEXT] = nxt, -1
-        buf = ring[k % RING]
+        slot = n % RING
+        buf = ring[slot]
         scene.render(k - epoch, buf)
         barcode.draw(buf, k)
         wait = t0 + k / fps - time.monotonic()
         if wait > 0:
             time.sleep(wait)
-        ctl[LATEST] = k
+        ctl[LATEST] = k * RING + slot
         now = time.monotonic()
         ctl[LATE_US + k] = max(1, int((now - (t0 + k / fps)) * 1e6))
         # a display that overran its refresh shows the frame that is due now,
@@ -180,7 +197,7 @@ def child_main() -> int:
         due_now = int((now - t0) * fps) + 1
         if due_now > k + 1:
             ctl[SKIPPED] += due_now - (k + 1)
-        k = max(k + 1, due_now)
+        k, n = max(k + 1, due_now), n + 1
     del ring, ctl
     shm_ring.close()
     shm_ctl.close()

@@ -39,6 +39,7 @@ os.environ.setdefault("OPENCV_LOG_LEVEL", "ERROR")
 
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
+import faulthandler  # noqa: E402
 import glob  # noqa: E402
 import importlib  # noqa: E402
 import importlib.util  # noqa: E402
@@ -48,6 +49,7 @@ import shutil  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import threading  # noqa: E402
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -61,6 +63,9 @@ TRACE_START_S = 0.1         # start_trace itself took 0.05-0.06 s on the chip
 CLOSED_LOOP_FRAMES = 8      # one IDR and seven P frames
 PULL_BUCKET = 1 << 16       # the step of the encoder's pull-size ladder
 PULL_BUCKETS = 12           # sizes warmed: up to 768 KiB a frame
+FAULT_LINES = 20            # frame order faults named one by one
+STALL_S = 1.0               # no frame taken for this long: dump the stacks
+STALL_LOOK_S = 0.25         # the watchdog's sleep between two looks
 
 # Every number compared is exact, so every limit is 0 (PERF.md section 2).
 LIMITS = {"undecoded_fragments": 0, "frame_order_faults": 0,
@@ -226,6 +231,27 @@ def warm_pull_ladder(encoder, frames, buckets: int) -> None:
     encoder.import_state(first)
 
 
+def watch_for_a_stall(display, stop, stall_s: float = STALL_S,
+                      look_s: float = STALL_LOOK_S) -> None:
+    """The window's watchdog thread: when the session has taken no new frame
+    from the display (``display.handed`` has not grown) for ``stall_s``, say
+    so and write every thread's stack to standard error, once, and end.  One
+    run in 62 of PR 33 took no frame for 14.6 s and nothing said where the
+    session thread stood.  It reads one length every ``look_s`` and changes
+    no number."""
+    seen, since = len(display.handed), time.monotonic()
+    while not stop.wait(look_s):
+        n, now = len(display.handed), time.monotonic()
+        if n != seen:
+            seen, since = n, now
+        elif now - since >= stall_s:
+            note(f"STALL: no frame taken from the display for "
+                 f"{now - since:.2f} s ({n} taken so far); every thread's "
+                 "stack follows on standard error")
+            faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
+            return
+
+
 # -- the run ------------------------------------------------------------------
 
 async def serve_window(spec: dict, args, client,
@@ -273,6 +299,7 @@ async def serve_window(spec: dict, args, client,
         session.start()
         runner = await serve(cfg, session, None)
         obs: dict = {"display": display, "scene": scene, "cfg": cfg}
+        window_over = threading.Event()
         try:
             client.stdin.write((json.dumps({
                 "port": bound_port(runner), "user": "u",
@@ -291,6 +318,10 @@ async def serve_window(spec: dict, args, client,
             t_start = time.monotonic()
             obs["t_start"], obs["t_end"] = t_start, t_start + args.seconds
             obs["setup_s"] = t_start - T_PROCESS_START
+            watchdog = threading.Thread(
+                target=watch_for_a_stall, args=(display, window_over),
+                daemon=True)
+            watchdog.start()
             if args.trace:
                 # the traced span is the window's end: stop_trace takes
                 # minutes (about 200 s for a second of trace: 850,000 device
@@ -310,6 +341,8 @@ async def serve_window(spec: dict, args, client,
                 note(f"start_trace took {time.monotonic() - t_tr:.2f} s")
                 obs["trace_dir"] = tdir
             await asyncio.sleep(max(0.0, obs["t_end"] - time.monotonic()))
+            window_over.set()
+            watchdog.join()
             obs["counters_end"] = program_counters()
             if args.trace:
                 t_stop = time.monotonic()
@@ -318,6 +351,7 @@ async def serve_window(spec: dict, args, client,
             obs["memory_peak_bytes"] = memory_peak_bytes()
             await asyncio.sleep(GRACE_S)
         finally:
+            window_over.set()
             try:
                 client.stdin.write(b"\n")
                 client.stdin.flush()
@@ -382,9 +416,10 @@ def reduce_run(args, obs: dict, workdir: pathlib.Path) -> dict:
         str(workdir / "stream.mp4"), width, height, render_luma,
         psnr_every=5, in_window=lambda i: t_start <= stamps[i] < t_end)
     handed_at = dict(display.handed)
+    faults = check.order_faults(ks, stamps, display.handed)
     compared = {
         "undecoded_fragments": abs(len(frags) - len(ks)),
-        "frame_order_faults": check.order_faults(ks, stamps, handed_at),
+        "frame_order_faults": len(faults),
         "p_run_over_gop": max(0, check.longest_p_run(frags)
                               - (cfg.encoder_gop - 1)),
         "compiles_in_window": int(
@@ -418,6 +453,13 @@ def reduce_run(args, obs: dict, workdir: pathlib.Path) -> dict:
     note(f"a frame's age when the session took it from the display: p50 "
          f"{stats.percentile(capture_age_ms, 50):.3f} ms; from there to the "
          f"client: p50 {stats.percentile(taken_to_glass_ms, 50):.3f} ms")
+    for f in faults[:FAULT_LINES]:
+        note(f"frame order fault: picture {f['picture']} reads k = {f['k']} "
+             f"after k = {f['after']}; when it arrived, "
+             f"{f['stamp'] - t_start:.4f} s into the window, the display had "
+             f"last handed out k = {f['handed_k']}: {f['why']}")
+    if len(faults) > FAULT_LINES:
+        note(f"and {len(faults) - FAULT_LINES} more frame order faults")
     for name, value in compared.items():
         note(f"compared: {name} = {value} (limit {LIMITS[name]})")
     for t, secs in BACKEND_COMPILES:

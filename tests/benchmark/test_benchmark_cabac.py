@@ -73,7 +73,12 @@ def test_the_cell_resolves_and_owes_the_unlisted_readers_and_its_six(cell):
     assert found["env"] == CONFIG["env"]
     unlisted = [m["name"] for m in MANIFEST["per_layer"]
                 if "workloads" not in m]
-    assert found["per_layer"] == unlisted + list(READERS)
+    # a set: a metric that a later PR appends for these cells (PR 33's
+    # locked_take_pct waited a PR for this) moves no position here
+    listed = {m["name"] for m in MANIFEST["per_layer"]
+              if cell in m.get("workloads", ())}
+    assert set(READERS) | {"locked_take_pct"} <= listed
+    assert set(found["per_layer"]) == set(unlisted) | listed
     # the CAVLC programs' stage readers are not this cell's to report
     assert not {"slots_ms", "pack_ms", "deblock_ms"} & set(found["per_layer"])
 
@@ -84,7 +89,13 @@ def test_manifest_lists_the_reader_for_the_two_cells(name):
     layer, moves, source, unit = READERS[name]
     assert (m["layer"], m["moves"], m["source"], m["unit"], m["better"]) == (
         layer, moves, source, unit, "lower")
-    assert m["workloads"] == CELLS
+    # the 4K cell (PR 32) owes the readers of counters and spans; the three
+    # stage readers give nothing there (88.2% of its trace under a scope,
+    # layer_metrics/_stages.py asks for 90%), and a metric that is owed and
+    # not given refuses the traced run
+    assert m["workloads"][:2] == CELLS
+    assert ("desk2160-cabac.fulldamage" in m["workloads"]) is (
+        source != "device_trace")
 
 
 def hand_run(**stages):

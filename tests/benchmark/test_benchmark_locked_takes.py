@@ -57,7 +57,8 @@ def test_both_counters_stand_in_metrics_before_the_first_take():
 
 
 OWED_BY = ["desk1080.desktop", "desk1600.fulldamage", "desk1080.fulldamage",
-           "desk2160-cabac.fulldamage"]
+           "desk2160-cabac.fulldamage", "desk1080-cabac.fulldamage",
+           "desk1080-cabac.desktop", "desk1600.desktop"]
 
 
 def entry():
@@ -73,11 +74,9 @@ def test_the_manifest_entry():
         "name": "locked_take_pct", "unit": "%", "better": "higher",
         "source": "program_counter",
         "layer": "session loop and encoder front", "moves": "g2g_p50_ms"}
-    # every cell has the counter.  The two desk1080-cabac cells are not
-    # listed because tests/benchmark/test_benchmark_cabac.py pins their
-    # readers to "the unlisted ones, then their six": they join with the
-    # benchmark PR that may edit that file (PERF.md section 7)
-    assert cells[:4] == OWED_BY
+    # every cell whose program has the counter: all seven (the two
+    # desk1080-cabac cells and desk1600.desktop joined in PR 34)
+    assert cells[:len(OWED_BY)] == OWED_BY
 
 
 @pytest.mark.parametrize("cell", OWED_BY)

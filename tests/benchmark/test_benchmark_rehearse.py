@@ -5,6 +5,7 @@ sound and once with the timed path broken underneath."""
 
 import json
 import pathlib
+import re
 import subprocess
 import sys
 import types
@@ -132,6 +133,16 @@ def test_a_broken_timed_path_comes_out_as_not_correct():
     line, out = _rehearse("--trace", "1", "--control", "stale_frame")
     assert line["rehearsal"]["correct_before_override"] is False
     assert line["rehearsal"]["compared"]["frame_order_faults"] > 0
+    # every fault is named: the picture was BEHIND what the display had
+    # handed out (a frame sent again), not ahead of it (a buffer overwritten)
+    named = re.findall(
+        r"frame order fault: picture \d+ reads k = (\d+) after k = (\d+); "
+        r"when it arrived, -?[\d.]+ s into the window, the display had last "
+        r"handed out k = (\d+): k does not rise", out)
+    assert named and len(named) == min(
+        line["rehearsal"]["compared"]["frame_order_faults"], 20)
+    assert all(int(k) <= int(after) <= int(handed)
+               for k, after, handed in named)
     assert not {"device_ms_per_frame", "device_idle_pct", "me_subpel_ms",
                 "deblock_ms", "unscoped_ms"} & set(line["metrics"])
     assert 0 <= line["metrics"]["capture_age_p50_ms"]["value"] < 100

@@ -84,13 +84,33 @@ def test_percentiles_due_times_and_the_window_on_a_hand_made_list():
     assert round(stats.psnr_db(np.zeros((4, 4)), np.full((4, 4), 255)), 6) == 0
 
 
-def test_order_faults_counts_what_a_broken_stream_shows():
-    handed = {5: 1.0, 6: 1.1, 7: 1.2}
-    assert check.order_faults([5, 6, 7], [1.05, 1.15, 1.25], handed) == 0
-    assert check.order_faults([5, 5, 7], [1.05, 1.15, 1.25], handed) == 1
-    assert check.order_faults([5, None, 7], [1.05, 1.15, 1.25], handed) == 1
-    assert check.order_faults([5, 6, 9], [1.05, 1.15, 1.25], handed) == 1
-    assert check.order_faults([5, 6, 7], [1.05, 1.05, 1.25], handed) == 1
+HANDED = [(5, 1.0), (6, 1.1), (7, 1.2)]
+
+
+@pytest.mark.parametrize("ks,stamps,faults", [
+    ([5, 6, 7], [1.05, 1.15, 1.25], 0),
+    ([5, 5, 7], [1.05, 1.15, 1.25], 1),         # a picture sent again
+    ([5, None, 7], [1.05, 1.15, 1.25], 1),      # a barcode that does not read
+    ([5, 6, 9], [1.05, 1.15, 1.25], 1),         # never handed out
+    ([5, 6, 7], [1.05, 1.05, 1.25], 1)])        # arrived before it was handed
+def test_order_faults_counts_what_a_broken_stream_shows(ks, stamps, faults):
+    assert len(check.order_faults(ks, stamps, HANDED)) == faults
+
+
+def test_a_fault_names_the_k_read_and_the_k_handed():
+    """A picture AHEAD of what the display had handed out (its buffer changed
+    under the session: the ring's fault of PR 32) and one BEHIND (the
+    program sent a picture again) read differently."""
+    ahead, = check.order_faults([5, 7, None], [1.05, 1.15, 1.16], HANDED)[:1]
+    assert (ahead["picture"], ahead["k"], ahead["after"], ahead["handed_k"],
+            ahead["stamp"]) == (1, 7, 5, 6, 1.15)
+    assert ahead["k"] > ahead["handed_k"] and "before" in ahead["why"]
+    behind, = check.order_faults([5, 6, 6], [1.05, 1.15, 1.25], HANDED)
+    assert (behind["picture"], behind["k"], behind["after"],
+            behind["handed_k"]) == (2, 6, 6, 7)
+    assert behind["k"] <= behind["after"] and "rise" in behind["why"]
+    unread, = check.order_faults([None], [0.5], HANDED)
+    assert unread["k"] is None and unread["handed_k"] is None
 
 
 def test_union_busy_and_window_on_made_up_planes():
