@@ -100,18 +100,21 @@ def level_idc_for(width: int, height: int, fps: float) -> int:
 
 
 def sps_rbsp(width: int, height: int, fps: float = 60.0,
-             profile: str = "baseline") -> bytes:
+             profile: str = "baseline", coded_height: int = None) -> bytes:
     """Sequence parameter set for progressive 4:2:0.
 
-    ``fps``: the refresh the stream is built for; with the size it sets
-    ``level_idc`` (:func:`level_idc_for`).
+    ``fps``: the refresh the stream is built for; with the CODED size it
+    sets ``level_idc`` (:func:`level_idc_for`).
     ``profile``: "baseline" (CAVLC streams) or "main" (required for
     CABAC, spec A.2.2 — baseline excludes entropy_coding_mode_flag=1).
+    ``coded_height``: lines the encoder codes where that is more than
+    ``height`` rounded up to 16 (a picture padded until a mesh's shards
+    divide its macroblock rows, models/h264.py); cropped back as well.
     Frame cropping carries non-multiple-of-16 dimensions; POC type 2 keeps
     the slice header free of POC syntax for an I/P-only stream.
     """
     mb_w = (width + 15) // 16
-    mb_h = (height + 15) // 16
+    mb_h = (max(height, coded_height or 0) + 15) // 16
     crop_r = mb_w * 16 - width      # luma samples to crop on the right
     crop_b = mb_h * 16 - height     # and bottom
     bw = BitWriter()
@@ -121,7 +124,7 @@ def sps_rbsp(width: int, height: int, fps: float = 60.0,
     else:
         bw.write(66, 8)              # profile_idc: baseline
         bw.write(0b11000000, 8)      # constraint_set0+1, reserved zeros
-    bw.write(level_idc_for(width, height, fps), 8)
+    bw.write(level_idc_for(width, mb_h * 16, fps), 8)
     write_ue(bw, 0)                  # seq_parameter_set_id
     write_ue(bw, 0)                  # log2_max_frame_num_minus4 -> 4 bits
     write_ue(bw, 2)                  # pic_order_cnt_type

@@ -136,11 +136,12 @@ class CapacityModel:
         budget consumes several chips (the frame's MB rows shard across
         them, parallel/batch spatial steps) instead of missing its SLO;
         admission and drain planning must charge it accordingly.
-        Returns ``ceil(cost / (headroom * budget))`` rounded UP to a
-        shard count the geometry can actually split into
-        (``parallel.batch.feasible_spatial_shards`` — charging 4 chips
-        for native 4K's 135 MB rows would leave one idle while the
-        session still misses budget on a (1,3) mesh), capped at
+        Returns ``ceil(cost / (headroom * budget))`` as a shard count
+        the geometry can split into
+        (``parallel.batch.feasible_spatial_shards``: the coded height
+        follows the mesh, so native 4K's 135 MB rows are coded as 136
+        over 2 or 4 chips and every chip charged works; only a shard
+        too short for the motion search's halo is refused), capped at
         ``max_chips``; 1 whenever the session fits one chip (including
         under ``per_chip_override`` — an operator pinning sessions per
         chip has declared the chip sufficient)."""
@@ -153,11 +154,11 @@ class CapacityModel:
         need = -int(-cost // max(allowed, 1e-6))
         if need > 1:
             from ..parallel.batch import feasible_spatial_shards
-            pad_h = (-(-int(height) // 16)) * 16
             # nx never exceeds the MB row count — cap the search there,
             # not at a 2^16 sentinel
             need = feasible_spatial_shards(
-                pad_h, need, min(int(max_chips), max(pad_h // 16, 1)))
+                height, need,
+                min(int(max_chips), max(-(-int(height) // 16), 1)))
         return max(1, min(int(max_chips), need))
 
     def sessions_per_chip(self, width: int, height: int, fps: float,

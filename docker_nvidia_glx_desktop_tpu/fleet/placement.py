@@ -153,7 +153,7 @@ def plan_placement(sessions: Sequence[SessionSpec], n_chips: int,
     measure on N, plan N-1) — the measured-cost normalization must use
     the former or a hypothetical smaller plan understates per-session
     cost by measured/planned."""
-    from ..parallel.batch import replan_mesh
+    from ..parallel.batch import coded_height, replan_mesh
 
     model = model if model is not None else CapacityModel()
     rng = random.Random(seed)
@@ -255,7 +255,10 @@ def plan_placement(sessions: Sequence[SessionSpec], n_chips: int,
     buckets: Dict[Tuple[int, int], BucketPlan] = {}
     for key in sorted(placed):
         n = chips[key]
-        mesh = replan_mesh(len(placed[key]), n, key[0],
+        # a sharded session's coded height follows its mesh (the rows
+        # are padded until the shards divide them)
+        mesh = replan_mesh(len(placed[key]), n,
+                           coded_height(key[0], chips_per[key]),
                            want_nx=chips_per[key])
         buckets[key] = BucketPlan(
             key=key, chips=n, mesh=mesh,

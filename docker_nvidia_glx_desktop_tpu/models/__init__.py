@@ -5,9 +5,12 @@ from .mjpeg import JpegEncoder  # noqa: F401
 from .h264 import H264Encoder  # noqa: F401
 
 
-def make_encoder(cfg, width: int, height: int):
+def make_encoder(cfg, width: int, height: int, row_align: int = None):
     """Codec from the config surface (WEBRTC_ENCODER + ENCODER_* knobs,
-    reference Dockerfile:210-211 / SURVEY.md §2.4).
+    reference Dockerfile:210-211 / SURVEY.md §2.4).  ``row_align``
+    (H.264 only, no knob): the coded picture's macroblock rows are a
+    multiple of it, as a spatial mesh of that many shards codes them
+    (``H264Encoder``); the one-chip reference of a sharded deployment.
 
     Raises a clear error for codec names nothing implements — the
     reference's fallback matrix (README.md:21,35) lists vp8enc/vp9enc,
@@ -29,7 +32,8 @@ def make_encoder(cfg, width: int, height: int):
                           superstep_chunk=cfg.encoder_chunk,
                           spatial_shards=getattr(
                               cfg, "encoder_spatial_shards", None),
-                          tune=getattr(cfg, "encoder_tune", None))
+                          tune=getattr(cfg, "encoder_tune", None),
+                          row_align=row_align)
         return enc, f"h264_{'cabac' if entropy == 'cabac' else 'cavlc'}"
     if codec == "tpumjpegenc":
         return JpegEncoder(width, height), "mjpeg"

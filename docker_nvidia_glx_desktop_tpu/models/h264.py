@@ -52,6 +52,20 @@ _M_H2D_BYTES = obsm.counter(
     "Bytes of host arrays the per-frame CABAC path handed to its device "
     "programs in dispatch: the picture's planes (the RGB frame where the "
     "colour conversion is the device's)")
+_M_MESH_HALO_BYTES = obsm.counter(
+    "dngd_mesh_halo_bytes_total",
+    "Bytes of reference halo one chip of a spatial mesh receives from "
+    "its neighbours (parallel/batch.spatial_halo_bytes: an interior "
+    "shard's two, from the operands' shapes at dispatch)")
+_M_MESH_GATHER_BYTES = obsm.counter(
+    "dngd_mesh_gather_bytes_total",
+    "Bytes of the other shards' entropy buffers an all_gather brings one "
+    "chip of a spatial mesh; 0 on the per-frame steps, whose shards are "
+    "pulled each from its own chip")
+_M_MESH_SHARDS = obsm.gauge(
+    "dngd_mesh_shards",
+    "Chips one session's macroblock rows are spread over "
+    "(ENCODER_SPATIAL_SHARDS as resolved; 1 = one chip)")
 _M_CABAC_DENSE = _M_CABAC_FALLBACK.labels("dense")
 _M_CABAC_PYTHON = _M_CABAC_FALLBACK.labels("python")
 
@@ -330,7 +344,8 @@ class H264Encoder(Encoder):
                  gop: int = 1, bitrate_kbps: int = 0, fps: float = 60.0,
                  deblock: bool = False, intra_modes: str = None,
                  superstep_chunk: int = None, spatial_shards=None,
-                 tune: str = None, damage_mask: bool = None):
+                 tune: str = None, damage_mask: bool = None,
+                 row_align: int = None):
         """``entropy``: where/how entropy coding runs —
         "device" (TPU CAVLC, via ops/cavlc_device: only the packed
         bitstream crosses the host link), "native" (host C++ CAVLC),
@@ -354,7 +369,15 @@ class H264Encoder(Encoder):
         slice headers signal disable_deblocking_filter_idc=2 and the
         reference planes P frames predict from are loop-filtered exactly
         as a conformant decoder filters them.  The native C entropy coder
-        has no idc plumbing, so ``entropy="native"`` keeps it off."""
+        has no idc plumbing, so ``entropy="native"`` keeps it off.
+        ``row_align``: the coded picture's macroblock rows are a multiple
+        of this, the added lines repeating the last one and cropped back
+        by the SPS (as a height that is no multiple of 16 is padded).
+        Left None it is the shard count ``spatial_shards`` resolves to
+        (the coded height follows the mesh: 2160 lines on four chips are
+        coded as 136 rows, 34 a shard) and 1 without shards; given, a
+        one-chip encoder codes the very picture a mesh of that many
+        shards does (the byte-identity reference of the sharded path)."""
         super().__init__(width, height)
         if mode not in ("pcm", "cavlc"):
             raise NotImplementedError(f"h264 mode {mode!r} not built yet")
@@ -428,8 +451,13 @@ class H264Encoder(Encoder):
         else:
             self.i16_modes = intra_modes or "auto"
         self.last_recon = None
+        self.fps = float(fps)
+        self._spatial_req = spatial_shards
+        self._spatial_nx_cached = None
+        self.row_align = max(int(
+            self._spatial_plan() if row_align is None else row_align), 1)
         self.pad_w = round_up(width, 16)
-        self.pad_h = round_up(height, 16)
+        self.pad_h = round_up(height, 16 * self.row_align)
         self.mb_w = self.pad_w // 16
         self.mb_h = self.pad_h // 16
         cabac = entropy == "cabac"
@@ -460,7 +488,8 @@ class H264Encoder(Encoder):
                     "counted in dngd_encoder_cabac_fallback_total"
                     "{kind=\"python\"}")
         self._sps = syn.sps_rbsp(width, height, fps,
-                                 profile="main" if cabac else "baseline")
+                                 profile="main" if cabac else "baseline",
+                                 coded_height=self.pad_h)
         self._pps = syn.pps_rbsp(init_qp=qp, cabac=cabac)
         self._hdr_slots_cache = {}
         # GOP / reference state (device-resident planes)
@@ -498,12 +527,11 @@ class H264Encoder(Encoder):
         # ONE session's frame split over several chips' MB rows
         # (parallel/batch spatial steps): the resolution-ladder lever
         # for geometry whose modeled per-chip cost exceeds its SLO
-        # rung.  Resolved lazily (_spatial_nx: needs the device count
-        # and, under "auto", the capacity model).
-        self.fps = float(fps)
-        self._spatial_req = spatial_shards
-        self._spatial_nx_cached = None
+        # rung.  The count is planned at construction (the coded height
+        # follows it: _spatial_plan) and resolved at the first frame
+        # (_spatial_nx).
         self._sp_steps = {}
+        self._sp_pull = None
         self._sp_mesh_cache = None
         self._sp_hdr_cache = {}
         # dispatch accounting (obs/budget 'dispatch' stage): Python ->
@@ -780,7 +808,12 @@ class H264Encoder(Encoder):
     #
     # The batch managers shard populations of sessions; this shards a
     # single session's MB rows over a (1, N) mesh when one chip cannot
-    # close the geometry's budget (the 4K30 lever, ROADMAP item 3).
+    # close the geometry's budget: 4K30 CABAC on ONE v5e chip delivers
+    # 21.5 of 30 frames/s at 43-44 ms of device a frame (the benchmark's
+    # cell desk2160-cabac.fulldamage); over the four chips of a host it
+    # is the cell desk2160-cabac-mesh4.fulldamage (PERF.md section 4).
+    # The coded height follows the mesh (row_align: 2160 lines are coded
+    # as 136 rows over four chips, the SPS crops the padding row).
     # The sharded steps live in parallel/batch (h264_spatial_*); the
     # assembled AU is byte-identical to the single-device path — CAVLC
     # shards concatenate NAL-by-NAL (slice-per-MB-row), CABAC binarize
@@ -790,46 +823,62 @@ class H264Encoder(Encoder):
     # P("spatial", None) spec.
     # ------------------------------------------------------------------
 
+    def _spatial_plan(self) -> int:
+        """Shards the request asks for and the host can give (1 = off).
+        Eligibility mirrors the super-step ring's: device-resident
+        entropy (device CAVLC, or CABAC with device binarization) and no
+        per-frame recon pulls (``keep_recon`` is the tests' PSNR hook;
+        the sharded recon stays distributed by design).  Asked at
+        construction, where the coded height follows the answer
+        (``row_align``), and again at the first frame."""
+        req = self._spatial_req
+        if req is None:
+            import os
+            req = os.environ.get("ENCODER_SPATIAL_SHARDS", "0")
+        req = str(req).strip() or "0"
+        eligible = (self.mode == "cavlc" and not self.keep_recon
+                    and (self.entropy == "device"
+                         or (self.entropy == "cabac"
+                             and self.cabac_device_binarize)))
+        if not eligible or req in ("0", "1", "off"):
+            return 1
+        import jax
+        ndev = len(jax.devices())
+        if req == "auto":
+            want = spatial_auto_shards(self.width, self.height, self.fps,
+                                       n_devices=ndev)
+        else:
+            try:
+                want = int(req)
+            except ValueError:
+                # a typo'd knob must not kill every frame of the
+                # session — warn, serve unsharded
+                log.warning("ENCODER_SPATIAL_SHARDS=%r not understood; "
+                            "spatial sharding off", req)
+                want = 1
+        if want <= 1 or ndev <= 1:
+            return 1
+        from ..parallel import batch
+        return batch.feasible_spatial_shards(self.height, want, ndev)
+
     @property
     def _spatial_nx(self) -> int:
-        """Resolved spatial shard count (1 = off).  Eligibility mirrors
-        the super-step ring's: device-resident entropy (device CAVLC,
-        or CABAC with device binarization) and no per-frame recon pulls
-        (``keep_recon`` is the tests' PSNR hook; the sharded recon
-        stays distributed by design)."""
+        """Resolved spatial shard count (1 = off): the plan, where the
+        coded picture's rows divide over it (they do when the plan set
+        ``row_align``; an encoder built with another alignment serves
+        on one chip)."""
         n = self._spatial_nx_cached
         if n is None:
-            n = 1
-            req = self._spatial_req
-            if req is None:
-                import os
-                req = os.environ.get("ENCODER_SPATIAL_SHARDS", "0")
-            req = str(req).strip() or "0"
-            eligible = (self.mode == "cavlc" and not self.keep_recon
-                        and (self.entropy == "device"
-                             or (self.entropy == "cabac"
-                                 and self.cabac_device_binarize)))
-            if eligible and req not in ("0", "1", "off"):
-                import jax
-                ndev = len(jax.devices())
-                if req == "auto":
-                    want = spatial_auto_shards(
-                        self.width, self.height, self.fps,
-                        n_devices=ndev)
-                else:
-                    try:
-                        want = int(req)
-                    except ValueError:
-                        # a typo'd knob must not kill every frame of
-                        # the session — warn once, serve unsharded
-                        log.warning(
-                            "ENCODER_SPATIAL_SHARDS=%r not understood;"
-                            " spatial sharding off", req)
-                        want = 1
-                if want > 1 and ndev > 1:
-                    from ..parallel import batch
-                    n = batch.feasible_spatial_shards(
-                        self.pad_h, want, ndev)
+            from ..parallel import batch
+            n = self._spatial_plan()
+            if n > 1 and (self.mb_h % n
+                          or not batch.p_halo_feasible(self.pad_h, n)):
+                log.warning("%d MB rows do not divide over %d shards "
+                            "(row_align=%d): spatial sharding off",
+                            self.mb_h, n, self.row_align)
+                n = 1
+            if n > 1:
+                _M_MESH_SHARDS.set(n)
             self._spatial_nx_cached = n
         return n
 
@@ -844,9 +893,12 @@ class H264Encoder(Encoder):
         return self._sp_mesh_cache
 
     def _sp_step(self, kind: str, qp: int):
-        """Cached sharded step builders (one XLA compile per (kind,
-        qp), mirroring the per-frame path's static-qp specialization)."""
-        key = (kind, qp)
+        """Cached sharded step builders.  Where qp is traced
+        (:attr:`_dyn_qp`, the served default) one program a kind serves
+        every qp and the step is handed it as its last operand
+        (:meth:`_sp_qp_operand`); the hq tiers compile one a (kind,
+        qp)."""
+        key = (kind, None if self._dyn_qp else qp)
         got = self._sp_steps.get(key)
         if got is None:
             from ..parallel import batch
@@ -854,17 +906,33 @@ class H264Encoder(Encoder):
             mesh = self._sp_mesh()
             if kind == "intra":
                 got, _ = batch.h264_spatial_intra_step(
-                    mesh, self.pad_h, self.pad_w, qp, entropy=ent,
+                    mesh, self.pad_h, self.pad_w, key[1], entropy=ent,
                     i16_modes=self.i16_modes, deblock=self.deblock,
                     with_recon=self.gop > 1, tune=self._ktune)
             else:
                 got, _ = batch.h264_spatial_step(
-                    mesh, self.pad_h, self.pad_w, qp,
+                    mesh, self.pad_h, self.pad_w, key[1],
                     deblock=self.deblock, entropy=ent,
                     tune=self._ktune, p_intra=self._p_intra,
                     masked=(kind == "p_masked"))
             self._sp_steps[key] = got
         return got
+
+    def _sp_qp_operand(self, qp: int) -> tuple:
+        """The traced qp as the step's last operand, or nothing where
+        the step closed over it."""
+        return (np.int32(qp),) if self._dyn_qp else ()
+
+    def _sp_cabac_pull(self, kind: str) -> PrefixPull:
+        """The pull helper of one kind of frame's per-shard record
+        buffers (header words of a SHARD's rows; the guess covers the
+        longest shard)."""
+        if self._sp_pull is None:
+            from ..ops import cabac_binarize
+            hdrw = cabac_binarize.header_words(self._sp_rows_local())
+            self._sp_pull = {"intra": PrefixPull(hdrw, 8),
+                             "p": PrefixPull(hdrw, 4)}
+        return self._sp_pull[kind]
 
     def _sp_hdr_slots(self, idr: bool, frame_num: int,
                       idr_pic_id: int, qp_delta: int):
@@ -889,89 +957,96 @@ class H264Encoder(Encoder):
             self._sp_hdr_cache[key] = got
         return got
 
-    def _sp_record_stitch(self, t0: float) -> None:
-        """Attribute the host-side shard assembly/stitch cost (obs
-        budget ``bitstream-stitch`` stage / dngd_stitch_ms gauge)."""
+    def _sp_record_stitch(self, ms: float) -> None:
+        """The host-side shard assembly/stitch cost, to the budget
+        ledger too (obs/budget ``bitstream-stitch`` row, dngd_stitch_ms
+        gauge); the stage span ``stitch`` is what measured it."""
         try:
             from ..obs.budget import LEDGER
-            LEDGER.record_spatial(
-                stitch_ms=(time.perf_counter() - t0) * 1e3)
+            LEDGER.record_spatial(stitch_ms=ms)
         except Exception:
             pass
 
     def _sp_submit_intra(self, rgb, idr_pic_id: int):
-        from ..ops import cabac_binarize, cavlc_device
+        from ..ops import cavlc_device
 
-        t0 = time.perf_counter()
         qp = self._eff_qp()
-        step = self._sp_step("intra", qp)
-        y, cb, cr = self._planes_device(rgb)
-        if self.entropy == "cabac":
-            out = step(y, cb, cr)
-            if self.gop > 1:
-                buf, ry, rcb, rcr, lv = out
-                # reference advances at submit time (sharded device
-                # futures; deblock fused in the sharded program)
-                self._ref = (ry, rcb, rcr)
+        y, cb, cr = self._planes_device(rgb)      # stage "colour"
+        with obst.stage("dispatch") as span:
+            step = self._sp_step("intra", qp)
+            _note_h2d(y, cb, cr)
+            if self.entropy == "cabac":
+                out = step(y, cb, cr, *self._sp_qp_operand(qp))
+                if self.gop > 1:
+                    buf, ry, rcb, rcr, lv = out
+                    # reference advances at submit time (sharded device
+                    # futures; deblock fused in the sharded program)
+                    self._ref = (ry, rcb, rcr)
+                else:
+                    buf, lv = out
+                marker = "sp_bin"
+                prefix = self._sp_cabac_pull("intra").prefix(buf)
             else:
-                buf, lv = out
-            self._count_dispatch(t0)
+                hv, hl = self._sp_hdr_slots(True, 0, idr_pic_id,
+                                            qp - self.qp)
+                out = step(y, cb, cr, hv, hl, *self._sp_qp_operand(qp))
+                if self.gop > 1:
+                    buf, ry, rcb, rcr = out
+                    self._ref = (ry, rcb, rcr)
+                else:
+                    buf = out
+                marker, lv = "sp", None
+                base = cavlc_device.META_WORDS * 4
+                guess = getattr(self, "_pull_guess", 4 * self._PULL_BUCKET)
+                prefix = buf[:, :base + guess]
+                _prefetch_host(prefix)
             # sharded stats: damage + activity only (recon/MV layouts
             # are per-shard; the global-reduce stats stay exact)
             self._content_submit(y, frame_type="intra")
-            hdrw = cabac_binarize.header_words(self._sp_rows_local())
-            prefix = buf[:, :hdrw + self._cabac_pull["intra"].guess]
-            _prefetch_host(prefix)
-            return ("sp_bin", "intra", qp, idr_pic_id, 0, buf, prefix,
-                    lv)
-        hv, hl = self._sp_hdr_slots(True, 0, idr_pic_id, qp - self.qp)
-        out = step(y, cb, cr, hv, hl)
-        if self.gop > 1:
-            flat, ry, rcb, rcr = out
-            self._ref = (ry, rcb, rcr)
-        else:
-            flat = out
-        self._count_dispatch(t0)
-        self._content_submit(y, frame_type="intra")
-        base = cavlc_device.META_WORDS * 4
-        guess = getattr(self, "_pull_guess", 4 * self._PULL_BUCKET)
-        prefix = flat[:, :base + guess]
-        _prefetch_host(prefix)
-        return ("sp", "intra", qp, idr_pic_id, 0, flat, prefix, None)
+        self._count_dispatch(ms=span.ms)
+        return (marker, "intra", qp, idr_pic_id, 0, buf, prefix, lv)
 
     def _sp_submit_p(self, y, cb, cr, qp: int, frame_num: int = None):
-        from ..ops import cabac_binarize, cavlc_device
+        from ..ops import cavlc_device
+        from ..parallel import batch
 
-        t0 = time.perf_counter()
-        frame_num = self._frame_num if frame_num is None else frame_num
-        step = self._sp_step("p", qp)
-        if self.entropy == "cabac":
-            buf, ry, rcb, rcr, mv, lv = step(y, cb, cr, *self._ref)
+        with obst.stage("dispatch") as span:
+            frame_num = self._frame_num if frame_num is None else frame_num
+            _note_h2d(y, cb, cr, *self._ref)
+            qp_t = self._sp_qp_operand(qp)
+            if self.entropy == "cabac":
+                step = self._sp_step("p", qp)
+                buf, ry, rcb, rcr, mv, lv = step(y, cb, cr, *self._ref,
+                                                 *qp_t)
+                marker = "sp_bin"
+                prefix = self._sp_cabac_pull("p").prefix(buf)
+            else:
+                hv, hl = self._sp_hdr_slots(False, frame_num, 0,
+                                            qp - self.qp)
+                keep = self._sp_damage_keep()
+                if keep is not None:
+                    step = self._sp_step("p_masked", qp)
+                    buf, ry, rcb, rcr, mv, lv = step(
+                        y, cb, cr, *self._ref, hv, hl, keep, *qp_t)
+                else:
+                    step = self._sp_step("p", qp)
+                    buf, ry, rcb, rcr, mv, lv = step(
+                        y, cb, cr, *self._ref, hv, hl, *qp_t)
+                marker = "sp"
+                base = cavlc_device.META_WORDS * 4
+                guess = getattr(self, "_p_pull_guess",
+                                2 * self._PULL_BUCKET)
+                prefix = buf[:, :base + guess]
+                _prefetch_host(prefix)
+            # what one chip receives in this frame's one collective, from
+            # the operands' shapes (the per-frame steps gather nothing:
+            # dngd_mesh_gather_bytes_total stays 0)
+            _M_MESH_HALO_BYTES.inc(batch.spatial_halo_bytes(
+                self.pad_w, self._spatial_nx, self._ref[0].dtype.itemsize))
             self._ref = (ry, rcb, rcr)
-            self._count_dispatch(t0)
             self._content_submit(y)
-            hdrw = cabac_binarize.header_words(self._sp_rows_local())
-            prefix = buf[:, :hdrw + self._cabac_pull["p"].guess]
-            _prefetch_host(prefix)
-            return ("sp_bin", "p", qp, 0, frame_num, buf, prefix,
-                    (lv, mv))
-        hv, hl = self._sp_hdr_slots(False, frame_num, 0, qp - self.qp)
-        keep = self._sp_damage_keep()
-        if keep is not None:
-            step = self._sp_step("p_masked", qp)
-            flat, ry, rcb, rcr, mv, lv = step(y, cb, cr, *self._ref,
-                                              hv, hl, keep)
-        else:
-            flat, ry, rcb, rcr, mv, lv = step(y, cb, cr, *self._ref,
-                                              hv, hl)
-        self._ref = (ry, rcb, rcr)
-        self._count_dispatch(t0)
-        self._content_submit(y)
-        base = cavlc_device.META_WORDS * 4
-        guess = getattr(self, "_p_pull_guess", 2 * self._PULL_BUCKET)
-        prefix = flat[:, :base + guess]
-        _prefetch_host(prefix)
-        return ("sp", "p", qp, 0, frame_num, flat, prefix, (lv, mv))
+        self._count_dispatch(ms=span.ms)
+        return (marker, "p", qp, 0, frame_num, buf, prefix, (lv, mv))
 
     def _sp_collect(self, submitted) -> bytes:
         marker, kind, qp, idr_pic_id, frame_num, buf, prefix, lv_mv = \
@@ -994,7 +1069,8 @@ class H264Encoder(Encoder):
 
         rows_l = self._sp_rows_local()
         base = cavlc_device.META_WORDS * 4
-        bufs = np.asarray(prefix)                 # (nx, base + guess)
+        with obst.stage("pull"):
+            bufs = np.asarray(prefix)             # (nx, base + guess)
         t0 = time.perf_counter()                  # post-pull: stitch only
         metas = [cavlc_device.FlatMeta(bufs[i], rows_l)
                  for i in range(len(bufs))]
@@ -1041,78 +1117,63 @@ class H264Encoder(Encoder):
                 nal_type=None if kind == "intra" else syn.NAL_SLICE,
                 ref_idc=3 if kind == "intra" else 2))
         au = b"".join(parts)
-        self._sp_record_stitch(t0)
+        self._sp_record_stitch((time.perf_counter() - t0) * 1e3)
         return au
 
     def _sp_collect_bin(self, kind: str, qp: int, idr_pic_id: int,
                         frame_num: int, buf, prefix, lv_mv) -> bytes:
-        """Assemble a spatially-sharded CABAC AU: per-shard pull of the
-        binarize record streams, row-wise stitch into one whole-frame
-        transport buffer (ops/cabac_binarize.stitch_rows), then the
-        UNCHANGED host arithmetic engine — byte-identical to the
-        single-device path."""
+        """Assemble a spatially-sharded CABAC AU: one pull of every
+        shard's binarize record stream (the one-chip path's ladder of
+        lengths, :class:`PrefixPull`), row-wise stitch into one
+        whole-frame transport buffer (ops/cabac_binarize.stitch_rows),
+        then the UNCHANGED host arithmetic engine — byte-identical to
+        the single-device path.  Stages as on one chip (``pull``,
+        ``pull_extra``, ``assemble`` with ``engine`` inside), and
+        ``stitch`` inside ``assemble``."""
         from ..bitstream import h264_cabac
         from ..ops import cabac_binarize, level_pack
 
         rows_l = self._sp_rows_local()
-        hdrw = cabac_binarize.header_words(rows_l)
-        heads = np.asarray(prefix)                # (nx, hdrw + guess)
-        t0 = time.perf_counter()
-        pull = self._cabac_pull[kind]      # guess and history; the shards
-        shard_bufs = []                    # are pulled here, side by side
         if not self._cabac_native:
             _M_CABAC_PYTHON.inc()
-        overflow = False
-        need_max = 0
-        for i in range(len(heads)):
-            head = heads[i]
-            if head[1]:
-                overflow = True
-                break
-            total = cabac_binarize.payload_words(head)
-            need_max = max(need_max, total)
-            if hdrw + total > head.shape[0]:
-                head = np.asarray(buf[i, :hdrw + pull.rung(total)])
-            shard_bufs.append(head)
-        au = None
-        if not overflow:
-            pull.note(need_max)
-            stitched = cabac_binarize.stitch_rows(shard_bufs, rows_l)
+        heads = self._sp_cabac_pull(kind).pull_shards(buf, prefix)
+        with obst.stage("assemble", more=True):
+            hdr = dict(qp=qp, qp_delta=qp - self.qp,
+                       deblocking_idc=self._deblock_idc)
             if kind == "intra":
-                au = h264_cabac.encode_intra_from_binstream(
-                    stitched, nr=self.mb_h, nc_mb=self.mb_w, qp=qp,
-                    frame_num=0, idr_pic_id=idr_pic_id, sps=self._sps,
-                    pps=self._pps, with_headers=True,
-                    qp_delta=qp - self.qp,
-                    deblocking_idc=self._deblock_idc)
+                hdr.update(frame_num=0, idr_pic_id=idr_pic_id,
+                           sps=self._sps, pps=self._pps,
+                           with_headers=True)
             else:
-                au = h264_cabac.encode_p_from_binstream(
-                    stitched, nr=self.mb_h, nc_mb=self.mb_w, qp=qp,
-                    frame_num=frame_num, qp_delta=qp - self.qp,
-                    deblocking_idc=self._deblock_idc)
-        if au is not None:
-            self._sp_record_stitch(t0)
-            return au
-        # overflow (packed stream or engine cap): dense fallback from
-        # the sharded stage's own level tensors, gathered lazily
-        _note_cabac_dense()
-        if kind == "intra":
-            lv = lv_mv
-            dense = {k: np.asarray(lv[k])
-                     for k, _, _ in level_pack.INTRA_KEYS}
-            dense.update({k: np.asarray(lv[k])
-                          for k in ("pred_mode", "mb_i4", "i4_modes")})
-            return h264_cabac.encode_intra_picture(
-                dense, qp=qp, frame_num=0, idr_pic_id=idr_pic_id,
-                sps=self._sps, pps=self._pps, with_headers=True,
-                qp_delta=qp - self.qp,
-                deblocking_idc=self._deblock_idc)
-        lv, mv = lv_mv
-        dense = {k: np.asarray(v) for k, v in lv.items()}
-        dense["mv"] = np.asarray(mv, np.int32)
-        return h264_cabac.encode_p_picture(
-            dense, qp=qp, frame_num=frame_num, qp_delta=qp - self.qp,
-            deblocking_idc=self._deblock_idc)
+                hdr.update(frame_num=frame_num)
+            au = None
+            if heads is not None:
+                with obst.stage("stitch") as span:
+                    stitched = cabac_binarize.stitch_rows(list(heads),
+                                                          rows_l)
+                self._sp_record_stitch(span.ms)
+                code = (h264_cabac.encode_intra_from_binstream
+                        if kind == "intra"
+                        else h264_cabac.encode_p_from_binstream)
+                au = code(stitched, nr=self.mb_h, nc_mb=self.mb_w, **hdr)
+            if au is not None:
+                return au
+            # overflow (packed stream or engine cap): dense fallback from
+            # the sharded stage's own level tensors, gathered lazily
+            _note_cabac_dense()
+            if kind == "intra":
+                lv = lv_mv
+                dense = {k: np.asarray(lv[k])
+                         for k, _, _ in level_pack.INTRA_KEYS}
+                _M_D2H_BYTES.inc(sum(v.nbytes for v in dense.values()))
+                dense.update({k: np.asarray(lv[k])
+                              for k in ("pred_mode", "mb_i4", "i4_modes")})
+                return h264_cabac.encode_intra_picture(dense, **hdr)
+            lv, mv = lv_mv
+            dense = {k: np.asarray(v) for k, v in lv.items()}
+            _M_D2H_BYTES.inc(sum(v.nbytes for v in dense.values()))
+            dense["mv"] = np.asarray(mv, np.int32)
+            return h264_cabac.encode_p_picture(dense, **hdr)
 
     # ------------------------------------------------------------------
     # I_PCM path: conformance bootstrap, trivially correct samples
@@ -1295,7 +1356,8 @@ class H264Encoder(Encoder):
             entropy=self.entropy, host_color=self.host_color,
             gop=max(self.gop, 2), deblock=self.deblock,
             intra_modes=self.i16_modes,
-            spatial_shards=self._spatial_nx, tune=self.tune)
+            spatial_shards=self._spatial_nx, tune=self.tune,
+            row_align=self.row_align)
         rgb = np.zeros((self.height, self.width, 3), np.uint8)
         t0 = time.perf_counter()
         done = 0
@@ -1320,27 +1382,43 @@ class H264Encoder(Encoder):
         shares.  For codec set-up (web/session.py under ENCODER_PREWARM),
         BEFORE frames are served: it compiles the path's programs too,
         and compiling beside the serving thread is what the installed
-        libtpu does not survive (:meth:`prewarm`).  Returns the slices
-        compiled; 0 on every other path (the CAVLC pull ladder is
-        content's to walk: 64 KiB steps of a 46 KB frame)."""
-        if (self.entropy != "cabac" or self.mode != "cavlc"
-                or self._spatial_nx > 1):
+        libtpu does not survive (:meth:`prewarm`).  On a spatial mesh
+        the scratch encoder runs this one's mesh and step programs, and
+        the slices are those of the stacked per-shard buffers.  Returns
+        the slices compiled; 0 on every other path (the CAVLC pull
+        ladder is content's to walk: 64 KiB steps of a 46 KB frame)."""
+        if self.entropy != "cabac" or self.mode != "cavlc":
+            return 0
+        nx = self._spatial_nx
+        if nx > 1 and (self._ring_chunk or not self.cabac_device_binarize):
             return 0
         t0 = time.perf_counter()
         scratch = H264Encoder(
             self.width, self.height, qp=self.qp, mode=self.mode,
             entropy=self.entropy, host_color=self.host_color, gop=2,
             deblock=self.deblock, intra_modes=self.i16_modes,
-            superstep_chunk=0, spatial_shards=1, tune=self.tune,
-            damage_mask=False)
+            superstep_chunk=0, spatial_shards=nx, tune=self.tune,
+            damage_mask=False, row_align=self.row_align)
         rgb = np.zeros((self.height, self.width, 3), np.uint8)
-        buf = scratch._submit_cabac_intra(rgb, 0)[1]
-        n = scratch._cabac_pull["intra"].warm(buf)
-        buf = scratch._submit_cabac_p(
-            *scratch._planes_device(rgb), self.qp)[3]
-        n += scratch._cabac_pull["p"].warm(buf)
+        if nx > 1:
+            scratch._cabac_dev_bin = True     # as this one's, pinned or not
+            scratch._sp_mesh_cache = self._sp_mesh()
+            scratch._sp_steps = self._sp_steps
+            pulls = (scratch._sp_cabac_pull("intra"),
+                     scratch._sp_cabac_pull("p"))
+            buf = scratch._sp_submit_intra(rgb, 0)[5]
+            n = pulls[0].warm(buf)
+            buf = scratch._sp_submit_p(
+                *scratch._planes_device(rgb), self.qp)[5]
+        else:
+            pulls = (scratch._cabac_pull["intra"], scratch._cabac_pull["p"])
+            buf = scratch._submit_cabac_intra(rgb, 0)[1]
+            n = pulls[0].warm(buf)
+            buf = scratch._submit_cabac_p(
+                *scratch._planes_device(rgb), self.qp)[3]
+        n += pulls[1].warm(buf)
         log.info("CABAC pull ladder: %d slices of %d-word buffers in "
-                 "%.1f s", n, buf.shape[0], time.perf_counter() - t0)
+                 "%.1f s", n, buf.shape[-1], time.perf_counter() - t0)
         return n
 
     def prewarm_async(self, qps=None):

@@ -55,7 +55,12 @@ class PrefixPull:
     4, 6, 8, 12, 16, 24 ...): every rung is one compiled slice, a
     quarter of a second in the serving thread when first met (PERF.md
     PR 24 finding 3), and a 1080p record buffer is 33 MiB, 19 rungs
-    where a linear ladder has 521.  :meth:`warm` compiles them all."""
+    where a linear ladder has 521.  :meth:`warm` compiles them all.
+
+    A spatial mesh's frame is one such buffer a shard, stacked
+    (``(shards, words)``): the same ladder along the last axis, every
+    shard pulled at the length the longest needs
+    (:meth:`pull_shards`)."""
 
     BUCKET = 1 << 14                       # words: 64 KiB
 
@@ -78,17 +83,21 @@ class PrefixPull:
     def prefix(self, buf):
         """Slice the guessed prefix off ``buf`` and start its copy to
         the host (at submit time)."""
-        head = buf[:self.hdrw + self.guess]
+        head = self._cut(buf, self.guess)
         prefetch_host(head)
         return head
+
+    def _cut(self, buf, words: int):
+        n = self.hdrw + words
+        return buf[:n] if buf.ndim == 1 else buf[:, :n]
 
     def warm(self, buf) -> int:
         """Compile the slice of every rung ``buf`` can meet (up to its
         whole length); returns how many."""
         words = n = 0
-        while self.hdrw + words < buf.shape[0]:
+        while self.hdrw + words < buf.shape[-1]:
             words = self.rung(words + 1)
-            buf[:self.hdrw + words].block_until_ready()
+            self._cut(buf, words).block_until_ready()
             n += 1
         return n
 
@@ -109,3 +118,24 @@ class PrefixPull:
             M_D2H_BYTES.inc(head.nbytes)
         M_CABAC_RECORD_BYTES.inc(4 * (self.hdrw + words))
         return head
+
+    def pull_shards(self, buf, prefix):
+        """:meth:`pull` for a mesh's ``(shards, words)`` buffer: the
+        host copy of every shard's header and payload, all pulled again
+        at the longest need's rung where the guess was short of any;
+        None where any shard's overflow flag is up."""
+        with obst.stage("pull"):
+            heads = np.asarray(prefix)
+        M_D2H_BYTES.inc(heads.nbytes)
+        if heads[:, 1].any():
+            return None
+        words = heads[:, 2].astype(np.int64)
+        need = int(words.max())
+        self.note(need)
+        if self.hdrw + need > heads.shape[1]:
+            M_PULL_EXTRA.inc()
+            with obst.stage("pull_extra"):
+                heads = np.asarray(self._cut(buf, self.rung(need)))
+            M_D2H_BYTES.inc(heads.nbytes)
+        M_CABAC_RECORD_BYTES.inc(4 * int((self.hdrw + words).sum()))
+        return heads

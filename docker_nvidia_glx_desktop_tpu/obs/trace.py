@@ -99,6 +99,10 @@ STAGES = ("capture", "encode_submit", "encode_collect", "colour",
 # The CABAC path's own, inside ``assemble``: the host arithmetic engine
 # (bitstream/h264_cabac.py).
 CABAC_STAGES = ("engine",)
+# A spatial mesh's own, inside ``assemble``: the per-shard record streams
+# stitched row-wise into one transport buffer (models/h264.py
+# ``_sp_collect_bin``, ops/cabac_binarize.stitch_rows).
+MESH_STAGES = ("stitch",)
 
 _stage_defs: Dict[str, tuple] = {}     # name -> (histogram, span name)
 _annotation = None                     # jax.profiler.TraceAnnotation, lazily
@@ -173,7 +177,7 @@ def stage(name: str, more: bool = False) -> _StageSpan:
     return _StageSpan(*_stage_def(name), more)
 
 
-for _name in STAGES + CABAC_STAGES:
+for _name in STAGES + CABAC_STAGES + MESH_STAGES:
     _stage_def(_name)
 
 # The one stage that crosses threads, so it is no profiler span: stamped

@@ -149,6 +149,7 @@ def _timed_step(fn, kind: str):
         _TRACER.record_span(stage, t0, dur, next_frame_id())
         return out
 
+    run.__wrapped__ = fn       # the jitted step itself (.lower, the name)
     return run
 
 
@@ -589,19 +590,37 @@ def make_spatial_mesh(nx: int, devices=None) -> Mesh:
     return make_mesh((1, nx), devices[:nx])
 
 
-def feasible_spatial_shards(pad_h: int, want: int,
+def coded_height(height: int, nx: int = 1) -> int:
+    """Lines of the CODED picture of a ``height``-line display whose MB
+    rows ``nx`` spatial shards divide: the next multiple of ``16 * nx``
+    (edge rows repeated, the SPS crops them back), at most ``nx - 1`` MB
+    rows more than a one-chip encoder codes."""
+    step = 16 * max(int(nx), 1)
+    return -(-int(height) // step) * step
+
+
+def feasible_spatial_shards(height: int, want: int,
                             n_devices: int) -> int:
     """Clamp a requested spatial shard count to what the geometry
-    supports: ``nx`` must divide the MB rows evenly (shard_map) and
-    leave each shard tall enough to donate the P halo.  Prefers the
-    smallest feasible count >= ``want`` (enough chips to close the
-    budget), else the largest feasible one below it.  Note 4K native
-    (135 MB rows) shards 3- or 5-way, not 2/4 — the caller gets the
-    honest nearest shape instead of an assertion."""
-    rows = max(pad_h // 16, 1)
+    supports.  The coded height FOLLOWS the mesh (:func:`coded_height`:
+    the picture is padded to a multiple of ``16 * nx`` lines and
+    cropped back by the SPS), so the MB rows always divide; what is
+    left of the rule is that each shard is tall enough to donate the P
+    halo and that no shard is padding alone.  Prefers the smallest
+    feasible count >= ``want`` (enough chips to close the budget), else
+    the largest feasible one below it.  Native 4K (135 MB rows) on a
+    four-chip host: 4 shards of 34 rows, one padding row of 136
+    (``desk2160-cabac-mesh4``)."""
     want = max(int(want), 1)
+    rows = -(-int(height) // 16)
+
+    def feasible(n: int) -> bool:
+        coded = coded_height(height, n)
+        return (p_halo_feasible(coded, n)
+                and coded // 16 - rows < max(coded // 16 // n, 1))
+
     cands = [n for n in range(1, max(int(n_devices), 1) + 1)
-             if rows % n == 0 and p_halo_feasible(pad_h, n)]
+             if feasible(n)]
     up = [n for n in cands if n >= want]
     return min(up) if up else max(cands)
 
@@ -609,7 +628,9 @@ def feasible_spatial_shards(pad_h: int, want: int,
 def _spatial_halo_pad(nx: int):
     """Per-shard reference padding for a SINGLE session's (h_l, w)
     planes: ``_PAD`` rows of neighbor halo over ``ppermute`` at interior
-    seams, edge replication at frame edges."""
+    seams, edge replication at frame edges.  The rows cross in the
+    plane's own dtype (uint8 references: a quarter of the int32 the
+    search reads) under the scope ``dngd.halo``."""
     from ..ops.h264_inter import _PAD
 
     perm_down = [(i, i + 1) for i in range(nx - 1)]
@@ -619,17 +640,44 @@ def _spatial_halo_pad(nx: int):
         if nx == 1:
             return jnp.pad(ref, ((_PAD, _PAD), (_PAD, _PAD)),
                            mode="edge")
-        top_halo = jax.lax.ppermute(ref[-_PAD:], "spatial", perm_down)
-        bot_halo = jax.lax.ppermute(ref[:_PAD], "spatial", perm_up)
-        ax = jax.lax.axis_index("spatial")
-        edge_top = jnp.repeat(ref[:1], _PAD, axis=0)
-        edge_bot = jnp.repeat(ref[-1:], _PAD, axis=0)
-        top = jnp.where(ax == 0, edge_top, top_halo)
-        bot = jnp.where(ax == nx - 1, edge_bot, bot_halo)
-        rows = jnp.concatenate([top, ref, bot], axis=0)
-        return jnp.pad(rows, ((0, 0), (_PAD, _PAD)), mode="edge")
+        with jax.named_scope("dngd.halo"):
+            top_halo = jax.lax.ppermute(ref[-_PAD:], "spatial", perm_down)
+            bot_halo = jax.lax.ppermute(ref[:_PAD], "spatial", perm_up)
+            ax = jax.lax.axis_index("spatial")
+            edge_top = jnp.repeat(ref[:1], _PAD, axis=0)
+            edge_bot = jnp.repeat(ref[-1:], _PAD, axis=0)
+            top = jnp.where(ax == 0, edge_top, top_halo)
+            bot = jnp.where(ax == nx - 1, edge_bot, bot_halo)
+            rows = jnp.concatenate([top, ref, bot], axis=0)
+            return jnp.pad(rows, ((0, 0), (_PAD, _PAD)), mode="edge")
 
     return pad
+
+
+#: The per-frame steps' entropy buffers, ``(nx, L)`` with row ``i`` on
+#: chip ``i``: the host pulls a guessed prefix of each shard's buffer from
+#: its own chip.  Not an ``all_gather`` to every chip: compiled for a v5e
+#: 2x2 that is an all-reduce over ``nx`` x 36 MB a 4K frame, to spare the
+#: host three pulls of under a megabyte (PERF.md section 6, PR 36).  The
+#: chunk step below still gathers (its ring hands the host ``K`` frames).
+_SHARD_BUF_SPEC = P("spatial", None)
+
+
+def _gather_shards(buf):
+    """Every shard's entropy buffer on every chip (scope
+    ``dngd.gather``): the host then pulls one array."""
+    with jax.named_scope("dngd.gather"):
+        return jax.lax.all_gather(buf, axis_name="spatial")
+
+
+def spatial_halo_bytes(frame_w: int, nx: int, itemsize: int = 1) -> int:
+    """Bytes the reference halo brings ONE chip a P frame: ``_PAD`` luma
+    rows and ``_PAD`` rows of each chroma plane from each neighbour (two
+    for an interior shard, the most any chip receives; one on a
+    two-chip mesh)."""
+    from ..ops.h264_inter import _PAD
+    return (min(max(nx - 1, 0), 2) * _PAD * (frame_w + 2 * (frame_w // 2))
+            * itemsize)
 
 
 # P-path levels dict keys (ops/cavlc_p_device._finish_p contract): the
@@ -658,11 +706,20 @@ def h264_spatial_intra_step(mesh: Mesh, frame_h: int, frame_w: int,
       - entropy="cavlc":  step(y, cb, cr, hv, hl) ->
         (flat_shards (nx, L)[, recon_y, recon_cb, recon_cr]) with the
         recon staying SHARDED on device (``P("spatial", None)``) as the
-        P chain's reference ring.
+        P chain's reference ring, and each shard's entropy buffer on
+        its own chip (row ``i`` of ``flat_shards`` lives on chip ``i``:
+        the host pulls a guessed prefix of each, :data:`_SHARD_BUF_SPEC`).
       - entropy="cabac":  step(y, cb, cr) ->
         (rec_shards (nx, Lb)[, recon...], levels) — per-shard
         cabac_binarize record streams (stitched host-side) plus the
         lazy level tensors the dense overflow fallback needs.
+
+    ``qp=None`` (tune="off" only) builds the qp-TRACED program, as the
+    one-chip ``_dynqp`` twins are built: the step takes the slice qp as
+    one more, replicated, int32 operand after the others, so one compile
+    serves the whole rate ladder.  The compiled program is named
+    ``jit_encode_intra_mesh``: a frame, to the benchmark's reductions,
+    is an execution of a program whose name starts with ``jit_encode_``.
 
     ``deblock`` loop-filters each shard's recon before it becomes the
     reference (byte-identical to whole-frame filtering under idc=2).
@@ -682,11 +739,19 @@ def h264_spatial_intra_step(mesh: Mesh, frame_h: int, frame_w: int,
     # hq+cabac through the dense host path instead)
     assert not (tune == "hq" and entropy == "cabac"), \
         "tune=hq has no device-binarize qp plumbing (use dense CABAC)"
+    assert entropy in ("cavlc", "cabac"), \
+        f"unknown spatial entropy {entropy!r}"
+    dyn = qp is None
+    assert not dyn or tune == "off", "a traced qp needs tune='off'"
+    static_qp = qp
     rows_local = (frame_h // 16) // nx
     plane_spec, row_spec = _spatial_specs(mesh)
+    buf_spec = _SHARD_BUF_SPEC
+    qp_spec = (P(),) if dyn else ()
 
     if entropy == "cavlc":
-        def shard_fn(y, cb, cr, hv_l, hl_l):
+        def encode_intra_mesh(y, cb, cr, hv_l, hl_l, *qp_t):
+            qp = qp_t[0] if dyn else static_qp
             out = cavlc_device.encode_intra_cavlc_frame_yuv.__wrapped__(
                 y, cb, cr, hv_l, hl_l, qp, with_recon=with_recon,
                 i16_modes=i16_modes, tune=tune)
@@ -697,53 +762,43 @@ def h264_spatial_intra_step(mesh: Mesh, frame_h: int, frame_w: int,
             if with_recon and deblock:
                 recon = h264_deblock.deblock_frame.__wrapped__(
                     *recon, qp)
-            flat_all = jax.lax.all_gather(flat, axis_name="spatial")
             if not with_recon:
-                return flat_all
-            return (flat_all,) + tuple(recon)
+                return flat[None]
+            return (flat[None],) + tuple(recon)
 
-        out_specs = ((P(None, None),) + (plane_spec,) * 3
-                     if with_recon else P(None, None))
-        step = jax.jit(shard_map(
-            shard_fn, mesh=mesh,
-            in_specs=(plane_spec,) * 3 + (row_spec,) * 2,
-            out_specs=out_specs,
-            # check_vma=False: all_gather outputs are replicated across
-            # "spatial" (same rationale as the batch steps above)
-            check_vma=False,
-        ))
-        return _timed_step(step, "h264_sp_intra"), rows_local
+        in_specs = (plane_spec,) * 3 + (row_spec,) * 2 + qp_spec
+        out_specs = ((buf_spec,) + (plane_spec,) * 3
+                     if with_recon else buf_spec)
+    else:
+        def encode_intra_mesh(y, cb, cr, *qp_t):
+            qp = qp_t[0] if dyn else static_qp
+            lv = h264_device.encode_intra_frame_yuv.__wrapped__(
+                y, cb, cr, qp, i16_modes, tune)
+            buf = cabac_binarize.binarize_intra.__wrapped__(
+                lv["luma_dc"], lv["luma_ac"], lv["cb_dc"], lv["cb_ac"],
+                lv["cr_dc"], lv["cr_ac"], lv["pred_mode"], lv["mb_i4"],
+                lv["i4_modes"], lv["luma_i4"])
+            recon = (lv["recon_y"], lv["recon_cb"], lv["recon_cr"])
+            if deblock:
+                recon = h264_deblock.deblock_frame.__wrapped__(*recon, qp)
+            small = {k: v for k, v in lv.items()
+                     if not k.startswith("recon")}
+            if with_recon:
+                return (buf[None],) + tuple(recon) + (small,)
+            return buf[None], small
 
-    assert entropy == "cabac", f"unknown spatial entropy {entropy!r}"
-
-    def shard_fn(y, cb, cr):
-        lv = h264_device.encode_intra_frame_yuv.__wrapped__(
-            y, cb, cr, qp, i16_modes, tune)
-        buf = cabac_binarize.binarize_intra.__wrapped__(
-            lv["luma_dc"], lv["luma_ac"], lv["cb_dc"], lv["cb_ac"],
-            lv["cr_dc"], lv["cr_ac"], lv["pred_mode"], lv["mb_i4"],
-            lv["i4_modes"], lv["luma_i4"])
-        recon = (lv["recon_y"], lv["recon_cb"], lv["recon_cr"])
-        if deblock:
-            recon = h264_deblock.deblock_frame.__wrapped__(*recon, qp)
-        small = {k: v for k, v in lv.items()
-                 if not k.startswith("recon")}
-        buf_all = jax.lax.all_gather(buf, axis_name="spatial")
-        if with_recon:
-            return (buf_all,) + tuple(recon) + (small,)
-        return buf_all, small
-
-    lv_spec = jax.tree_util.tree_map(
-        lambda _: P("spatial"),
-        {k: 0 for k in ("luma_dc", "luma_ac", "cb_dc", "cb_ac",
-                        "cr_dc", "cr_ac", "pred_mode", "mb_i4",
-                        "i4_modes", "luma_i4")})
-    out_specs = ((P(None, None),)
-                 + ((plane_spec,) * 3 if with_recon else ())
-                 + (lv_spec,))
+        lv_spec = jax.tree_util.tree_map(
+            lambda _: P("spatial"),
+            {k: 0 for k in ("luma_dc", "luma_ac", "cb_dc", "cb_ac",
+                            "cr_dc", "cr_ac", "pred_mode", "mb_i4",
+                            "i4_modes", "luma_i4")})
+        in_specs = (plane_spec,) * 3 + qp_spec
+        out_specs = ((buf_spec,)
+                     + ((plane_spec,) * 3 if with_recon else ())
+                     + (lv_spec,))
     step = jax.jit(shard_map(
-        shard_fn, mesh=mesh,
-        in_specs=(plane_spec,) * 3,
+        encode_intra_mesh, mesh=mesh,
+        in_specs=in_specs,
         out_specs=out_specs,
         check_vma=False,
     ))
@@ -760,6 +815,8 @@ def _spatial_encode_frame(entropy: str, deblock: bool, qp: int,
     fn(y, cb, cr, ry, rcb, rcr, hv_f, hl_f, next_y=None, keep=None) ->
     (flat, ny, ncb, ncr, mv, levels).  ``tune``/``next_y``: the
     ENCODER_TUNE=hq axis — per-MB, so shard-safe by construction.
+    A caller whose qp is a traced operand (tune="off") hands it to
+    ``fn(..., qp=...)`` in place of the closed-over constant.
 
     ``keep`` (cavlc only) is the damage mask's per-local-row gate
     (ops/damage_mask.force_skip_rows): rows where ``keep`` is False are
@@ -778,10 +835,11 @@ def _spatial_encode_frame(entropy: str, deblock: bool, qp: int,
         "p_intra requires cavlc entropy, deblock off"
 
     def encode_one(y, cb, cr, ry, rcb, rcr, hv_f, hl_f, next_y=None,
-                   keep=None):
-        ry_pad = halo_pad(ry.astype(jnp.int32))
-        rcb_pad = halo_pad(rcb.astype(jnp.int32))
-        rcr_pad = halo_pad(rcr.astype(jnp.int32))
+                   keep=None, qp=qp):
+        # the halo rows cross the mesh as the references' own uint8
+        ry_pad = halo_pad(ry).astype(jnp.int32)
+        rcb_pad = halo_pad(rcb).astype(jnp.int32)
+        rcr_pad = halo_pad(rcr).astype(jnp.int32)
         if entropy == "cavlc":
             if keep is not None:
                 # decomposed fused stage: inter core -> forced-skip row
@@ -838,7 +896,10 @@ def h264_spatial_step(mesh: Mesh, frame_h: int, frame_w: int,
         (rec_shards (nx, Lb), ry', rcb', rcr', mv, levels)
     with references consumed/returned SHARDED under the identical
     ``P("spatial", None)`` spec (ring contract), ``mv``/``levels``
-    lazy for the overflow fallback.
+    lazy for the overflow fallback.  ``qp=None`` (tune="off" only): the
+    slice qp is one more, replicated, int32 operand after the others
+    (:func:`h264_spatial_intra_step`); the compiled program is named
+    ``jit_encode_p_mesh``.
     """
     ns, nx = mesh.devices.shape
     assert ns == 1, "spatial steps serve ONE session"
@@ -847,6 +908,8 @@ def h264_spatial_step(mesh: Mesh, frame_h: int, frame_w: int,
     assert p_halo_feasible(frame_h, nx), "shards too short for the halo"
     assert entropy in ("cavlc", "cabac"), \
         f"unknown spatial entropy {entropy!r}"
+    dyn = qp is None
+    assert not dyn or tune == "off", "a traced qp needs tune='off'"
     rows_local = (frame_h // 16) // nx
     plane_spec, row_spec = _spatial_specs(mesh)
     lv_keys = _P_LEVEL_KEYS + (("qp_map",) if tune == "hq" else ())
@@ -856,41 +919,30 @@ def h264_spatial_step(mesh: Mesh, frame_h: int, frame_w: int,
     encode_one = _spatial_encode_frame(entropy, deblock, qp,
                                        _spatial_halo_pad(nx), tune=tune,
                                        p_intra=p_intra)
+    # operands after the six planes: the header slots (cavlc), the
+    # damage mask's row gate (a separate build, so that the unmasked
+    # program and its bytes are untouched: rows gated False emit as
+    # pure skip runs with their recon frozen, ops/damage_mask), the
+    # traced qp
+    masked = masked and entropy == "cavlc"
+    n_hdr = 2 if entropy == "cavlc" else 0
+    in_specs = ((plane_spec,) * 6 + (row_spec,) * n_hdr
+                + ((P("spatial"),) if masked else ())
+                + ((P(),) if dyn else ()))
 
-    if entropy == "cavlc" and masked:
-        # damage-masked variant: one extra (rows,) bool input sharded
-        # like the header slots — rows gated False emit as pure skip
-        # runs with their recon frozen (ops/damage_mask).  A separate
-        # build so the unmasked program (and its bytes) is untouched.
-        def shard_fn(y, cb, cr, ry, rcb, rcr, hv_l, hl_l, keep_l):
-            flat, ny, ncb, ncr, mv, lv = encode_one(
-                y, cb, cr, ry, rcb, rcr, hv_l, hl_l, keep=keep_l)
-            return (jax.lax.all_gather(flat, axis_name="spatial"),
-                    ny, ncb, ncr, mv, lv)
+    def encode_p_mesh(y, cb, cr, ry, rcb, rcr, *rest):
+        hv_l, hl_l = rest[:n_hdr] if n_hdr else (None, None)
+        kw = {"keep": rest[n_hdr]} if masked else {}
+        if dyn:
+            kw["qp"] = rest[-1]
+        flat, ny, ncb, ncr, mv, lv = encode_one(
+            y, cb, cr, ry, rcb, rcr, hv_l, hl_l, **kw)
+        return flat[None], ny, ncb, ncr, mv, lv
 
-        in_specs = (plane_spec,) * 6 + (row_spec,) * 2 + (P("spatial"),)
-    elif entropy == "cavlc":
-        def shard_fn(y, cb, cr, ry, rcb, rcr, hv_l, hl_l):
-            flat, ny, ncb, ncr, mv, lv = encode_one(
-                y, cb, cr, ry, rcb, rcr, hv_l, hl_l)
-            return (jax.lax.all_gather(flat, axis_name="spatial"),
-                    ny, ncb, ncr, mv, lv)
-
-        in_specs = (plane_spec,) * 6 + (row_spec,) * 2
-    else:
-        assert entropy == "cabac", f"unknown spatial entropy {entropy!r}"
-
-        def shard_fn(y, cb, cr, ry, rcb, rcr):
-            flat, ny, ncb, ncr, mv, lv = encode_one(
-                y, cb, cr, ry, rcb, rcr, None, None)
-            return (jax.lax.all_gather(flat, axis_name="spatial"),
-                    ny, ncb, ncr, mv, lv)
-
-        in_specs = (plane_spec,) * 6
     step = jax.jit(shard_map(
-        shard_fn, mesh=mesh,
+        encode_p_mesh, mesh=mesh,
         in_specs=in_specs,
-        out_specs=(P(None, None), plane_spec, plane_spec, plane_spec,
+        out_specs=(_SHARD_BUF_SPEC, plane_spec, plane_spec, plane_spec,
                    P("spatial"), lv_spec),
         check_vma=False,
     ))
@@ -955,7 +1007,7 @@ def h264_spatial_chunk_step(mesh: Mesh, qp: int = 26,
                     (y, cb, cr), hv_f, hl_f = xs, None, None
             flat, ny, ncb, ncr, mv, lv = encode_one(
                 y, cb, cr, ry, rcb, rcr, hv_f, hl_f, next_y=next_y)
-            flat_all = jax.lax.all_gather(flat, axis_name="spatial")
+            flat_all = _gather_shards(flat)
             return (ny, ncb, ncr), (flat_all, mv, lv)
 
         xs = ((ys, cbs, crs, hv, hl) if entropy == "cavlc"

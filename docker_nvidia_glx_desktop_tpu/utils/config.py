@@ -148,13 +148,15 @@ class Config:
     # Best with ENCODER_GOP = k*chunk + 1 so whole P-runs chunk evenly.
     encoder_chunk: int = 0
     # Spatial mesh sharding of ONE session's frame (resolution ladder):
-    # "0"/"1" = off, an integer = that many MB-row shards (clamped to
-    # what the geometry divides into, parallel/batch.
-    # feasible_spatial_shards), "auto" = shard when the geometry's
+    # "0"/"1" = off, an integer = that many MB-row shards (the coded
+    # height follows it: padded to a multiple of 16 x shards lines and
+    # cropped by the SPS; clamped where a shard would be too short for
+    # the search's halo, parallel/batch.feasible_spatial_shards),
+    # "auto" = shard when the geometry's
     # modeled per-chip cost (fleet/capacity) exceeds the active SLO
     # rung's budget — one 4K session spreads across the chips the model
     # says it needs (what ONE chip measured at 4K30: PERF.md, cell
-    # desk2160-cabac.fulldamage).
+    # desk2160-cabac.fulldamage; four: desk2160-cabac-mesh4.fulldamage).
     encoder_spatial_shards: str = "0"
     # Perceptual-efficiency tuning tier (ops/aq, ROADMAP item 4):
     # "off" = pre-tune encoder, byte-identical output; "hq" = per-MB

@@ -360,9 +360,10 @@ class TestMultiChipSessions:
 
     # prior 1.4 us/MB: 1080p60 fits one chip (11.4 ms vs 14.2
     # allowed); 4K30 (32400 MBs = 45.4 ms vs 28.3 allowed) needs
-    # ceil=2, rounded UP to 3 — native 4K's 135 MB rows shard 3-way,
-    # never 2 (feasible_spatial_shards); 4K60 (vs 14.2) needs
-    # ceil=4 -> 5.
+    # ceil=2 and gets 2: the coded height follows the mesh (native
+    # 4K's 135 MB rows are coded as 136, 68 a shard:
+    # feasible_spatial_shards); 4K60 (vs 14.2) needs ceil=4 and gets
+    # 4 (34 rows a shard).
     PRIOR = 1.4
 
     def _model(self):
@@ -371,19 +372,19 @@ class TestMultiChipSessions:
     def test_chips_for_session_model(self):
         m = self._model()
         assert m.chips_for_session(1920, 1080, 60.0) == 1
-        assert m.chips_for_session(3840, 2160, 30.0) == 3
-        assert m.chips_for_session(3840, 2160, 60.0) == 5
+        assert m.chips_for_session(3840, 2160, 30.0) == 2
+        assert m.chips_for_session(3840, 2160, 60.0) == 4
         # operator per-chip pin declares the chip sufficient
         assert _fresh_model(per_chip_override=2).chips_for_session(
             3840, 2160, 60.0) == 1
 
     def test_fleet_capacity_divides_by_chip_group(self):
         m = self._model()
-        # 8 chips of 3-chip 4K30 sessions = 2 sessions, not 8
-        assert m.fleet_capacity(8, 3840, 2160, 30.0) == 2
+        # 8 chips of 2-chip 4K30 sessions = 4 sessions, not 8
+        assert m.fleet_capacity(8, 3840, 2160, 30.0) == 4
         assert m.fleet_capacity(2, 3840, 2160, 30.0) == 1
         assert m.snapshot(8, 3840, 2160, 60.0)[
-            "chips_per_session"] == 5
+            "chips_per_session"] == 4
 
     def test_modeled_capacity_never_exceeded_with_multichip(self):
         rnd = random.Random(31)
@@ -413,16 +414,16 @@ class TestMultiChipSessions:
         m = self._model()
         fourk = [SessionSpec(sid="uhd", width=3840, height=2160,
                              fps=30.0, tier=1, joined_at=1.0)]
-        # 4 chips: N-1 = 3 still fits the 3-chip 4K30 session
-        plan = drain_chip(fourk, 4, model=m, seed=0)
+        # 3 chips: N-1 = 2 still fits the 2-chip 4K30 session
+        plan = drain_chip(fourk, 3, model=m, seed=0)
         assert plan.placed() == ("uhd",) and not plan.shed
         b = next(iter(plan.buckets.values()))
-        assert b.chips == 3 and b.chips_per_session == 3
+        assert b.chips == 2 and b.chips_per_session == 2
         # mesh realizes the spatial extent the session is charged for
-        # (135 MB rows -> a (1, 3) mesh)
-        assert b.mesh == (1, 3)
-        # 3 chips: N-1 = 2 cannot host a 3-chip session — shed whole
-        plan = drain_chip(fourk, 3, model=m, seed=0)
+        # (135 MB rows coded as 136 -> a (1, 2) mesh)
+        assert b.mesh == (1, 2)
+        # 2 chips: N-1 = 1 cannot host a 2-chip session — shed whole
+        plan = drain_chip(fourk, 2, model=m, seed=0)
         assert plan.shed == ("uhd",) and not plan.placed()
 
     def test_mixed_mesh_1080p_and_4k(self):
@@ -433,11 +434,11 @@ class TestMultiChipSessions:
                  for i in range(4)]
         specs.append(SessionSpec(sid="uhd", width=3840, height=2160,
                                  fps=30.0, tier=2, joined_at=0.5))
-        plan = plan_placement(specs, 7, model=m, seed=3)
+        plan = plan_placement(specs, 6, model=m, seed=3)
         assert sorted(plan.placed()) == sorted(s.sid for s in specs)
         uhd = plan.buckets[(2160, 3840)]
-        assert uhd.chips == 3 and uhd.chips_per_session == 3
-        assert uhd.mesh == (1, 3)
+        assert uhd.chips == 2 and uhd.chips_per_session == 2
+        assert uhd.mesh == (1, 2)
         hd = plan.buckets[(1088, 1920)]
         assert hd.chips == 4 and len(hd.sessions) == 4
 

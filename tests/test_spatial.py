@@ -252,16 +252,22 @@ class TestStitchOracle:
 class TestShardPlanning:
     def test_feasible_spatial_shards(self):
         f = batch.feasible_spatial_shards
-        # 4K native: 135 MB rows — 2/4 infeasible, 3 is the honest
-        # nearest shape above a want of 2
-        assert f(2160, 2, 8) == 3
-        assert f(2160, 4, 8) == 5
+        # the coded height follows the mesh (PR 36): 4K native is 135
+        # MB rows, coded as 136 over 2 or 4 chips (68 / 34 a shard)
+        assert f(2160, 2, 8) == 2
+        assert f(2160, 4, 8) == 4
         assert f(2160, 1, 8) == 1
-        # 2176 (136 rows) splits 2/4/8
+        assert batch.coded_height(2160, 4) == 2176
+        assert batch.coded_height(2160, 2) == 2176
+        assert batch.coded_height(2160, 1) == 2160
+        # 2176 (136 rows) splits 2/4/8 as it is, and 3-way with two
+        # rows of padding (138 rows, 46 a shard)
         assert f(2176, 4, 8) == 4
-        assert f(2176, 3, 8) == 4
+        assert f(2176, 3, 8) == 3
+        assert batch.coded_height(2176, 3) == 2208
         # halo infeasibility: 4 rows cannot split 4 ways (1 row/shard
-        # donates too little chroma halo)
+        # donates too little chroma halo), nor 3 ways (6 coded rows
+        # would leave the third shard padding alone)
         assert f(64, 4, 8) == 2
         # device ceiling
         assert f(2176, 4, 2) == 2
