@@ -11,7 +11,21 @@ tools are produced initially (CAVLC, I-slices), matching the reference's
 
 from __future__ import annotations
 
+import numpy as np
+
+from ..obs import metrics as obsm
 from .bitwriter import BitWriter
+
+_M_ASSEMBLE = obsm.counter(
+    "dngd_encoder_assemble_total",
+    "Calls that framed a frame's row slices as Annex-B NAL units "
+    "(annexb_rows: one a frame; one a shard on a CAVLC mesh): native = one "
+    "call into native/entropy.cpp over all rows; python = the library was "
+    "not built and every byte of every row went through the Python escape "
+    "loop, about 50 times slower",
+    ("road",))
+_M_ASSEMBLE_NATIVE = _M_ASSEMBLE.labels("native")
+_M_ASSEMBLE_PYTHON = _M_ASSEMBLE.labels("python")
 
 
 # ---------------------------------------------------------------------------
@@ -64,7 +78,17 @@ def emulation_prevention(rbsp: bytes) -> bytes:
 
 
 def nal_unit(nal_type: int, rbsp: bytes, ref_idc: int = 3) -> bytes:
-    """Annex-B NAL unit: start code + header byte + EPB-escaped RBSP."""
+    """Annex-B NAL unit: start code + header byte + EPB-escaped RBSP.
+
+    For the single NALs: SPS, PPS, the host-entropy fallbacks' slices.
+    The threshold keeps a short RBSP (an SPS is a dozen bytes) off a
+    ``ctypes`` call whose fixed cost, two array wraps and a copy out, is
+    that of a few hundred bytes of the Python loop; it was set generously,
+    which priced every row slice (0.2-2 KB) at the loop's 54 ns a byte
+    while the frame roads came through here a row at a time.  They do not
+    any more: :func:`annexb_rows` frames all rows in one native call, and
+    only its fallback without the library calls this a row.
+    """
     from ..native import lib as native_lib
     header = bytes([(ref_idc << 5) | nal_type])
     if len(rbsp) > 4096 and native_lib.available():
@@ -72,6 +96,63 @@ def nal_unit(nal_type: int, rbsp: bytes, ref_idc: int = 3) -> bytes:
     else:
         escaped = emulation_prevention(rbsp)
     return START_CODE + header + escaped
+
+
+def annexb_rows(src: np.ndarray, row_off, row_len, nal_type: int,
+                ref_idc: int = 3, *, prefix: bytes = b"", mb_step: int = 0,
+                slice_hdr: dict = None) -> bytes:
+    """A frame's row slices as Annex-B NAL units behind ``prefix`` (the
+    SPS/PPS of an IDR): row ``r``'s RBSP is ``src[row_off[r]:][:row_len[r]]``
+    of the uint8 buffer ``src``, so neither frame road copies a row.
+
+    ``slice_hdr`` (the CABAC road, whose slice headers are the host's):
+    :func:`slice_header`'s keywords but ``first_mb``, which is
+    ``r * mb_step``; the header is padded with cabac_alignment_one_bits
+    and escaped with the row as one RBSP.  ``None`` where the rows carry
+    their headers (device CAVLC).
+
+    With the compiled library ONE call frames every row
+    (native/entropy.cpp ``h264_annexb_rows``); without it
+    :func:`nal_unit` a row, the same bytes (tests/test_annexb_rows.py).
+    Counted by road in ``dngd_encoder_assemble_total``."""
+    from ..native import lib as native_lib
+    rows = len(row_off)
+    if native_lib.available():
+        _M_ASSEMBLE_NATIVE.inc()
+        tail = tail_nbits = 0
+        if slice_hdr is not None:
+            # the headers differ in first_mb alone, their first field:
+            # with first_mb 0 that field is the one bit 1; the rest is
+            # every row's tail
+            bw = BitWriter()
+            slice_header(bw, first_mb=0, **slice_hdr)
+            bits, nbits = bw.peek_bits()
+            tail_nbits = nbits - 1
+            tail = bits - (1 << tail_nbits)
+        # a row: start code, NAL byte, at most 16 bytes of slice header,
+        # its RBSP.  Escapes are rare in entropy-coded bytes; the worst
+        # case (all zeros: three bytes for two) where that was short
+        total = int(np.sum(row_len)) + 21 * rows
+        for cap in (total + total // 16, total + total // 2):
+            au = native_lib.annexb_rows(
+                src, row_off, row_len, (ref_idc << 5) | nal_type, cap,
+                prefix=prefix, mb_step=mb_step, hdr_tail=tail,
+                hdr_tail_nbits=tail_nbits)
+            if not isinstance(au, int):
+                return au
+        raise RuntimeError("annexb_rows: worst-case output cap too short")
+    _M_ASSEMBLE_PYTHON.inc()
+    out = bytearray(prefix)
+    for r in range(rows):
+        start = int(row_off[r])
+        rbsp = src[start:start + int(row_len[r])].tobytes()
+        if slice_hdr is not None:
+            bw = BitWriter()
+            slice_header(bw, first_mb=r * mb_step, **slice_hdr)
+            bw.pad_to_byte(1)             # cabac_alignment_one_bit
+            rbsp = bw.getvalue() + rbsp
+        out += nal_unit(nal_type, rbsp, ref_idc=ref_idc)
+    return bytes(out)
 
 
 # ---------------------------------------------------------------------------

@@ -102,8 +102,10 @@ def _engine_rows(buf: np.ndarray, nr: int, nc_mb: int, table_idx: int,
                  qp: int):
     """Replay a device-binarized record stream (ops/cabac_binarize wire
     format) through the arithmetic engine: native C rows when built,
-    else the pure-Python engine.  Returns per-row slice payloads, or
-    None on the transport's overflow flag (caller goes dense)."""
+    else the pure-Python engine.  Returns ``(src, row_off, row_len)``,
+    the rows' slice payloads where they lie in one uint8 buffer (the
+    native engine's own output, uncut), or None on the transport's
+    overflow flag (caller goes dense)."""
     from ..native import lib as native_lib
     from ..ops import cabac_binarize
 
@@ -116,12 +118,13 @@ def _engine_rows(buf: np.ndarray, nr: int, nc_mb: int, table_idx: int,
         ctx, rng, tmps, tlps = _native_tables(table_idx)
         for scale in (1, 4):
             cap = (2048 + nc_mb * 1536) * scale
-            rows = native_lib.cabac_engine_rows(
+            got = native_lib.cabac_engine_rows(
                 payload, row_off, row_bits, nr, qp, ctx, rng, tmps,
                 tlps, cap)
-            if isinstance(rows, list):
-                return rows
-            if rows == -2:
+            if isinstance(got, tuple):
+                out, lens = got
+                return out, np.arange(nr, dtype=np.int64) * cap, lens
+            if got == -2:
                 # malformed record stream: a bigger output cap cannot
                 # help — name the real failure instead of retrying
                 logging.getLogger(__name__).warning(
@@ -150,7 +153,9 @@ def _engine_rows(buf: np.ndarray, nr: int, nc_mb: int, table_idx: int,
             else:
                 enc.terminate(rec[1])
         out.append(enc.get_bytes())
-    return out
+    lens = np.array([len(pl) for pl in out], np.int64)
+    return (np.frombuffer(b"".join(out), np.uint8),
+            np.cumsum(lens) - lens, lens)
 
 
 def encode_intra_from_binstream(buf: np.ndarray, *, nr: int, nc_mb: int,
@@ -163,22 +168,18 @@ def encode_intra_from_binstream(buf: np.ndarray, *, nr: int, nc_mb: int,
     """IDR access unit from a device-binarized record stream, or None
     when the transport flagged overflow (caller re-encodes dense)."""
     with obst.stage("engine"):
-        payloads = _engine_rows(buf, nr, nc_mb, 0, qp)
-    if payloads is None:
+        rows = _engine_rows(buf, nr, nc_mb, 0, qp)
+    if rows is None:
         return None
-    out = bytearray()
+    headers = b""
     if with_headers:
-        out += syn.nal_unit(syn.NAL_SPS, sps)
-        out += syn.nal_unit(syn.NAL_PPS, pps)
-    for my, pl in enumerate(payloads):
-        bw = BitWriter()
-        syn.slice_header(bw, first_mb=my * nc_mb, slice_type=7,
-                         frame_num=frame_num, idr=True,
-                         idr_pic_id=idr_pic_id, qp_delta=qp_delta,
-                         deblocking_idc=deblocking_idc, cabac=True)
-        bw.pad_to_byte(1)
-        out += syn.nal_unit(syn.NAL_IDR, bw.getvalue() + pl)
-    return bytes(out)
+        headers = (syn.nal_unit(syn.NAL_SPS, sps)
+                   + syn.nal_unit(syn.NAL_PPS, pps))
+    return syn.annexb_rows(
+        *rows, syn.NAL_IDR, prefix=headers, mb_step=nc_mb,
+        slice_hdr=dict(slice_type=7, frame_num=frame_num, idr=True,
+                       idr_pic_id=idr_pic_id, qp_delta=qp_delta,
+                       deblocking_idc=deblocking_idc, cabac=True))
 
 
 def encode_p_from_binstream(buf: np.ndarray, *, nr: int, nc_mb: int,
@@ -188,21 +189,14 @@ def encode_p_from_binstream(buf: np.ndarray, *, nr: int, nc_mb: int,
     """P access unit from a device-binarized record stream, or None on
     the transport overflow flag."""
     with obst.stage("engine"):
-        payloads = _engine_rows(buf, nr, nc_mb, 1 + cabac_init_idc, qp)
-    if payloads is None:
+        rows = _engine_rows(buf, nr, nc_mb, 1 + cabac_init_idc, qp)
+    if rows is None:
         return None
-    out = bytearray()
-    for my, pl in enumerate(payloads):
-        bw = BitWriter()
-        syn.slice_header(bw, first_mb=my * nc_mb, slice_type=5,
-                         frame_num=frame_num, idr=False,
-                         qp_delta=qp_delta,
-                         deblocking_idc=deblocking_idc, cabac=True,
-                         cabac_init_idc=cabac_init_idc)
-        bw.pad_to_byte(1)
-        out += syn.nal_unit(syn.NAL_SLICE, bw.getvalue() + pl,
-                            ref_idc=2)
-    return bytes(out)
+    return syn.annexb_rows(
+        *rows, syn.NAL_SLICE, 2, mb_step=nc_mb,
+        slice_hdr=dict(slice_type=5, frame_num=frame_num, idr=False,
+                       qp_delta=qp_delta, deblocking_idc=deblocking_idc,
+                       cabac=True, cabac_init_idc=cabac_init_idc))
 
 
 def _prep_common(cb_dc, cb_ac, cr_dc, cr_ac):

@@ -11,7 +11,11 @@
 // Exported C ABI (see native/lib.py for the ctypes bindings):
 //   jpeg_component_histogram  : per-component DC/AC symbol histograms
 //   jpeg_encode_scan          : interleaved 4:2:0 MCU scan emission
-//   h264_emulation_prevention : Annex-B EPB escaping
+//   h264_emulation_prevention : Annex-B EPB escaping of one RBSP
+//   h264_annexb_rows          : a frame's row slices as Annex-B NALs, one
+//                               call (start code, NAL header, the row's
+//                               CABAC slice header where the host builds
+//                               it, EPB escaping)
 
 #include <cstdint>
 #include <cstring>
@@ -103,6 +107,38 @@ inline int32_t encode_block(BitWriter& bw, const int32_t* zz, int32_t prev_dc,
   return zz[0];
 }
 
+// Emulation prevention (spec §7.4.1.1) of n bytes into out from pos on;
+// `zeros` is the run of 0x00 that ends the bytes escaped so far, so that
+// one NAL's RBSP may come in pieces.  Returns the new pos, -1 past out_cap.
+// Entropy-coded bytes hold a zero in 256: outside a run of zeros the bytes
+// up to the next one are copied whole.
+inline int64_t escape_into(const uint8_t* in, int64_t n, uint8_t* out,
+                           int64_t pos, int64_t out_cap, int& zeros) {
+  int64_t i = 0;
+  while (i < n) {
+    if (zeros == 0) {
+      const void* z = memchr(in + i, 0, n - i);
+      int64_t run = z ? (const uint8_t*)z - (in + i) + 1 : n - i;
+      if (run > out_cap - pos) return -1;
+      memcpy(out + pos, in + i, run);
+      pos += run;
+      i += run;
+      zeros = z ? 1 : 0;
+      continue;
+    }
+    uint8_t b = in[i++];
+    if (zeros >= 2 && b <= 3) {
+      if (pos >= out_cap) return -1;
+      out[pos++] = 3;
+      zeros = 0;
+    }
+    if (pos >= out_cap) return -1;
+    out[pos++] = b;
+    zeros = (b == 0) ? zeros + 1 : 0;
+  }
+  return pos;
+}
+
 }  // namespace
 
 extern "C" {
@@ -162,18 +198,54 @@ int64_t jpeg_encode_scan(const int32_t* y, const int32_t* cb, const int32_t* cr,
 // Returns bytes written, or -1 if out_cap too small.
 int64_t h264_emulation_prevention(const uint8_t* in, int64_t n,
                                   uint8_t* out, int64_t out_cap) {
-  int64_t pos = 0;
   int zeros = 0;
-  for (int64_t i = 0; i < n; ++i) {
-    uint8_t b = in[i];
-    if (zeros >= 2 && b <= 3) {
-      if (pos >= out_cap) return -1;
-      out[pos++] = 3;
-      zeros = 0;
+  return escape_into(in, n, out, 0, out_cap, zeros);
+}
+
+// All row slices of a frame as Annex-B NAL units, in one call: per row the
+// start code, the NAL header byte, the slice header where the host builds
+// it, and the EPB-escaped RBSP (the escape state starts anew at every NAL
+// and runs on from the header's bytes into the payload's).  Byte for byte
+// bitstream/h264.py:nal_unit over every row.
+//   src, src_len  : the buffer the rows' RBSP bytes lie in
+//   row_off/len   : int64[rows], each row's bytes within src
+//   nal_header    : (nal_ref_idc << 5) | nal_unit_type
+//   hdr_tail, hdr_tail_nbits (0: the rows carry their slice headers
+//                   already, the CAVLC road): the slice header after
+//                   first_mb_in_slice, right-aligned; row r's header is
+//                   ue(r * mb_step) + the tail + cabac_alignment_one_bits
+// Returns bytes written, -1 if out_cap is too small, -2 if a row lies
+// outside src.
+int64_t h264_annexb_rows(const uint8_t* src, int64_t src_len,
+                         const int64_t* row_off, const int64_t* row_len,
+                         int64_t rows, int32_t nal_header, int64_t mb_step,
+                         uint64_t hdr_tail, int32_t hdr_tail_nbits,
+                         uint8_t* out, int64_t out_cap) {
+  int64_t pos = 0;
+  for (int64_t r = 0; r < rows; ++r) {
+    if (row_off[r] < 0 || row_len[r] < 0 || row_off[r] > src_len - row_len[r])
+      return -2;
+    if (pos + 5 > out_cap) return -1;
+    out[pos++] = 0; out[pos++] = 0; out[pos++] = 0; out[pos++] = 1;
+    out[pos++] = (uint8_t)nal_header;
+    int zeros = 0;
+    if (hdr_tail_nbits > 0) {
+      uint8_t hdr[24];               // ue() of 32 bits + 64 of tail: 16 bytes
+      BitWriter bw(hdr, sizeof hdr, /*stuff=*/false);
+      uint32_t code = (uint32_t)(r * mb_step) + 1;      // ue(first_mb)
+      int nb = 32 - __builtin_clz(code);
+      bw.write(0, nb - 1);
+      bw.write(code, nb);
+      if (hdr_tail_nbits > 32)
+        bw.write((uint32_t)(hdr_tail >> 32), hdr_tail_nbits - 32);
+      bw.write((uint32_t)hdr_tail,
+               hdr_tail_nbits > 32 ? 32 : hdr_tail_nbits);
+      bw.pad_to_byte(1);
+      pos = escape_into(hdr, bw.pos, out, pos, out_cap, zeros);
+      if (pos < 0) return -1;
     }
-    if (pos >= out_cap) return -1;
-    out[pos++] = b;
-    zeros = (b == 0) ? zeros + 1 : 0;
+    pos = escape_into(src + row_off[r], row_len[r], out, pos, out_cap, zeros);
+    if (pos < 0) return -1;
   }
   return pos;
 }

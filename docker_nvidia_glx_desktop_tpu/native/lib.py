@@ -111,6 +111,11 @@ def get_lib() -> Optional[ctypes.CDLL]:
         lib.h264_emulation_prevention.argtypes = [
             u8p, ctypes.c_int64, u8p, ctypes.c_int64]
         lib.h264_emulation_prevention.restype = ctypes.c_int64
+        lib.h264_annexb_rows.argtypes = [
+            u8p, ctypes.c_int64, i64p, i64p, ctypes.c_int64,
+            ctypes.c_int32, ctypes.c_int64, ctypes.c_uint64, ctypes.c_int32,
+            u8p, ctypes.c_int64]
+        lib.h264_annexb_rows.restype = ctypes.c_int64
         if hasattr(lib, "h264_encode_intra_picture"):
             lib.h264_encode_intra_picture.argtypes = [
                 i32p, i32p, i32p, i32p, i32p, i32p,
@@ -202,7 +207,10 @@ def cabac_engine_rows(payload: np.ndarray, row_off: np.ndarray,
                       ctx_init, rng, tmps, tlps, cap: int):
     """Run the arithmetic engine over per-row record streams.
 
-    Returns the per-row slice payload bytes, or the int failure code:
+    Returns ``(out, lens)``: the engine's own output buffer (``rows`` x
+    ``cap`` bytes, row ``r``'s slice payload the first ``lens[r]`` bytes
+    at ``r * cap``; :func:`annexb_rows` frames it where it lies), or the
+    int failure code:
     -1 = output cap overflow (caller may retry with a larger cap),
     -2 = malformed record stream (retrying cannot help — the caller
     should fall back dense and name the real failure)."""
@@ -217,8 +225,7 @@ def cabac_engine_rows(payload: np.ndarray, row_off: np.ndarray,
         rows, int(qp), ctx_init, rng, tmps, tlps, out, lens, cap)
     if rc != 0:
         return int(rc)
-    return [out[r * cap:r * cap + lens[r]].tobytes()
-            for r in range(rows)]
+    return out, lens
 
 
 def has_level_unpack() -> bool:
@@ -294,6 +301,37 @@ def emulation_prevention(rbsp: bytes) -> bytes:
     n = lib.h264_emulation_prevention(src, len(src), out, len(out))
     assert n >= 0
     return out[:n].tobytes()
+
+
+def annexb_rows(src: np.ndarray, row_off: np.ndarray, row_len: np.ndarray,
+                nal_header: int, cap: int, *, prefix: bytes = b"",
+                mb_step: int = 0, hdr_tail: int = 0,
+                hdr_tail_nbits: int = 0):
+    """A frame's row slices as Annex-B NAL units behind ``prefix``, in ONE
+    C call (native/entropy.cpp ``h264_annexb_rows``; the caller and the
+    Python road it must equal: bitstream/h264.py ``annexb_rows``).
+
+    ``cap`` bounds the NALs' bytes.  Returns the bytes, or -1 where
+    ``cap`` was short (the caller retries with the worst case)."""
+    lib = get_lib()
+    assert lib is not None
+    src = np.ascontiguousarray(src, np.uint8).reshape(-1)
+    row_off = np.ascontiguousarray(row_off, np.int64)
+    row_len = np.ascontiguousarray(row_len, np.int64)
+    rows = len(row_off)
+    if len(row_len) != rows or not 0 <= rows * mb_step < 1 << 31 \
+            or not 0 <= hdr_tail_nbits <= 64 or hdr_tail >> hdr_tail_nbits:
+        raise ValueError("annexb_rows: malformed rows or slice header")
+    out = np.empty(len(prefix) + cap, np.uint8)
+    out[:len(prefix)] = np.frombuffer(prefix, np.uint8)
+    n = lib.h264_annexb_rows(src, src.size, row_off, row_len, rows,
+                             nal_header, mb_step, hdr_tail, hdr_tail_nbits,
+                             out[len(prefix):], cap)
+    if n == -2:
+        raise ValueError("annexb_rows: a row lies outside its buffer")
+    if n < 0:
+        return int(n)
+    return out[:len(prefix) + n].tobytes()
 
 
 # ---------------------------------------------------------------------------

@@ -937,16 +937,13 @@ def slice_header_slots(nr: int, nc_mb: int, *, frame_num: int,
 def assemble_annexb(flat_host: np.ndarray, meta: FlatMeta,
                     *, headers: bytes = b"", nal_type: int = None,
                     ref_idc: int = 3) -> bytes:
-    """Host side: split the flat buffer into rows, EPB-escape each RBSP and
-    wrap it in Annex-B NALs (IDR by default; (NAL_SLICE, 2) for P)."""
+    """Host side: the flat buffer's rows as Annex-B NALs behind ``headers``
+    (IDR by default; (NAL_SLICE, 2) for P), each RBSP EPB-escaped: one
+    native call over the pulled buffer (bitstream/h264.py
+    ``annexb_rows``)."""
     from ..bitstream import h264 as syn
 
-    if nal_type is None:
-        nal_type = syn.NAL_IDR
-    base = META_WORDS * 4
-    out = bytearray(headers)
-    for r in range(len(meta.row_bytes)):
-        start = base + 4 * int(meta.word_off[r])
-        rbsp = flat_host[start:start + int(meta.row_bytes[r])].tobytes()
-        out += syn.nal_unit(nal_type, rbsp, ref_idc=ref_idc)
-    return bytes(out)
+    return syn.annexb_rows(
+        flat_host, META_WORDS * 4 + 4 * meta.word_off, meta.row_bytes,
+        syn.NAL_IDR if nal_type is None else nal_type, ref_idc,
+        prefix=headers)
