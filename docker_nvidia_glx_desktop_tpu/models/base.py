@@ -69,6 +69,12 @@ class Encoder:
         """Finish the frame started by :meth:`encode_submit`."""
         return token[4]
 
+    def token_ready(self, token) -> Optional[bool]:
+        """Whether the device has finished the frame ``token`` stands
+        for, asked without blocking; None where a codec cannot say (the
+        session's ``dngd_session_ready_wait_ms`` then takes no sample)."""
+        return None
+
     # Dispatch accounting (obs/budget 'dispatch' stage): codecs with a
     # device stage report Python -> device crossings + submit-to-launch
     # gap accrued since the last pop; the session feeds the ledger so

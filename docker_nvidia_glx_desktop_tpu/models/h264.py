@@ -16,6 +16,7 @@ from __future__ import annotations
 import functools
 import logging
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -2824,6 +2825,34 @@ class H264Encoder(Encoder):
         self._content_stash(idx)
         return tok
 
+    # where each per-frame path's token keeps the device array its collect
+    # pulls FIRST (the guessed prefix; models/prefix_pull.py)
+    _PREFIX_AT = {"intra": 5, "p": 5, "cabac_intra": 2, "cabac_p": 4}
+
+    def token_ready(self, token) -> Optional[bool]:
+        """Whether the device has FINISHED what ``encode_collect(token)``
+        pulls first: ``is_ready()`` of the guessed prefix (of every
+        shard's on a mesh, which is one array).  It asks and nothing
+        else: no transfer starts, nothing blocks, nothing compiles, and
+        it never raises.  ``None`` where there is nothing to ask: the
+        ring and masked paths, a synchronous token, an array without
+        ``is_ready``, one deleted or donated since."""
+        try:
+            at = self._PREFIX_AT.get(token[0])
+            if at is None:
+                return None
+            payload = token[4]
+            if isinstance(payload[0], str):      # a marked token:
+                if payload[0] not in ("sp", "sp_bin"):
+                    return None                  # masked
+                at = 6                           # a mesh's
+            prefix = payload[at]
+            # (asked of a deleted array, jaxlib 0.9's is_ready() takes the
+            # process down instead of raising)
+            return None if prefix.is_deleted() else bool(prefix.is_ready())
+        except Exception:
+            return None
+
     def encode_collect(self, token) -> EncodedFrame:
         kind, idx, t0, key, payload = token
         if kind == "sync":
@@ -2853,7 +2882,8 @@ class H264Encoder(Encoder):
         if self._rate is not None:
             self._rate.update(len(data) * 8,
                               mean_qp=self._take_mean_qp())
-        self._content_finish(token, data)
+        with obst.stage("stats"):
+            self._content_finish(token, data)
         # journey attribution: a ring frame that rode a dispatched chunk
         # carries its chunk identity; a flushed partial ring went
         # per-frame and is unchunked (it paid its own dispatch)

@@ -27,7 +27,10 @@ span through the listener hook, so a non-zero count means the budget
 ledger's view is incomplete).
 
 Stage spans (:func:`stage`) name the work INSIDE a frame's marks —
-capture, colour conversion, dispatch, pull, assembly — where it happens.
+capture, colour conversion, dispatch, pull, assembly — where it happens,
+and the rest of the session thread's turn round them (``TURN_STAGES``:
+the content statistics' pull, the loop's tail after the muxer, the wait
+at the turn's end), so that a turn is spans from end to end.
 A stage span is a ``jax.profiler.TraceAnnotation("dngd.<name>")`` for its
 duration, so whenever a profiler session is open it lies on the device
 trace's clock, and on exit it observes its milliseconds into an
@@ -50,7 +53,7 @@ from . import metrics as obsm
 __all__ = ["TraceRecorder", "tracer", "tracers", "next_frame_id",
            "export_chrome_trace", "set_enabled", "enabled",
            "dropped_total", "DEFAULT_CAPACITY", "stage", "STAGES",
-           "STAGE_BUCKETS_MS", "M_WS_SEND_MS"]
+           "TURN_STAGES", "STAGE_BUCKETS_MS", "M_WS_SEND_MS"]
 
 DEFAULT_CAPACITY = 4096      # spans per recorder (ring; oldest evicted)
 
@@ -103,6 +106,15 @@ CABAC_STAGES = ("engine",)
 # stitched row-wise into one transport buffer (models/h264.py
 # ``_sp_collect_bin``, ops/cabac_binarize.stitch_rows).
 MESH_STAGES = ("stitch",)
+# The rest of a turn of the session thread (PR 38), so that the device's
+# idle gaps fall under a span wherever the host is: ``stats``, the content
+# statistics' pull that ends ``H264Encoder.encode_collect`` (one sample a
+# collected frame, the span of an early return where the content plane is
+# off); ``publish``, the loop's tail from the muxer's ``assemble`` to the
+# end of the collect branch (one sample a delivered frame); ``await``,
+# ``StreamSession._await_frame`` whole (one sample a turn that has time
+# left, so not every frame's: that is why these are not in ``STAGES``).
+TURN_STAGES = ("stats", "publish", "await")
 
 _stage_defs: Dict[str, tuple] = {}     # name -> (histogram, span name)
 _annotation = None                     # jax.profiler.TraceAnnotation, lazily
@@ -173,11 +185,15 @@ def stage(name: str, more: bool = False) -> _StageSpan:
     whose work lies in two places on one thread (``assemble``: the
     encoder's Annex-B assembly, then the session's muxer): the first part
     is its own profiler span and hands its milliseconds to the part that
-    closes the stage, which takes the frame's one sample."""
+    closes the stage, which takes the frame's one sample.
+
+    Host stages: ``STAGES`` (the frame's own work), ``CABAC_STAGES`` and
+    ``MESH_STAGES`` (inside ``assemble``), ``TURN_STAGES`` (``stats``,
+    ``publish``, ``await``: the rest of the session thread's turn)."""
     return _StageSpan(*_stage_def(name), more)
 
 
-for _name in STAGES + CABAC_STAGES + MESH_STAGES:
+for _name in STAGES + CABAC_STAGES + MESH_STAGES + TURN_STAGES:
     _stage_def(_name)
 
 # The one stage that crosses threads, so it is no profiler span: stamped

@@ -15,6 +15,7 @@ the event loop keeps signaling responsive) and publishes into asyncio via
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import threading
 import time
@@ -96,6 +97,29 @@ _M_LOCKED_TAKES = obsm.counter(
 _M_TAKE_LOOKS = obsm.counter(
     "dngd_session_take_looks_total",
     "Looks at the source's sequence number spent in the end-of-turn wait")
+_M_TURN_MS = obsm.histogram(
+    "dngd_session_turn_ms",
+    "A turn of the session thread that took a frame, from its top to the "
+    "end of its own work: capture, submit, the collect of the frame "
+    "before (its wait for the device included), the muxer and the loop's "
+    "tail; the wait for the source's next frame at its end is NOT in it "
+    "(dngd_stage_await_ms).  Over the refresh interval: the thread "
+    "cannot keep the source's rate",
+    buckets=obst.STAGE_BUCKETS_MS)
+_M_READY_WAIT_MS = obsm.histogram(
+    "dngd_session_ready_wait_ms",
+    "How long a frame the device had FINISHED waited for the session "
+    "thread to begin its collect, one sample a collected frame: the "
+    "collect's start less the first look that found the frame's prefix "
+    "ready (H264Encoder.token_ready; looks at the turn's end, at every "
+    "look of the end-of-turn wait, at the next turn's top and at the "
+    "collect's start).  A FLOOR: the true wait is longer by up to the "
+    "distance to the look before (the wait's sleep; 0.5 ms once the looks "
+    "run; the whole submit between a turn's top and its collect's "
+    "start).  0 where the thread waited for the device instead "
+    "(dngd_stage_pull_ms has that side).  High: latency that collecting "
+    "sooner, or one frame in flight instead of two, would take out",
+    buckets=obst.STAGE_BUCKETS_MS)
 M_IDR_REQUESTS = obsm.counter(
     "dngd_idr_requests_total",
     "Forced-IDR requests through the session's rate-limited "
@@ -311,6 +335,11 @@ class StreamSession:
         # seconds the last take came after its frame's swap through turns
         # that overran the refresh, as far as the loop knows (_await_frame)
         self._behind = 0.0
+        # the oldest frame in flight, asked whether the device has finished
+        # it (_watch, _probe_ready): the question (None: nothing to ask, or
+        # no longer), and when the first yes came
+        self._probe = None
+        self._ready_at: Optional[float] = None
         self._need_frame = False
         # set on a collect failure: suppress delivery of in-flight P
         # frames (they predict from a reference the client never got)
@@ -774,7 +803,11 @@ class StreamSession:
             return True
         return False
 
-    PIPELINE_DEPTH = 2   # frames in flight: upload/compute/pull overlap
+    # Frames in flight: upload/compute/pull overlap.  Frame k is submitted
+    # in turn k and collected in turn k+1 AFTER frame k+1's submit; what
+    # that costs a frame the device finished sooner is priced by
+    # dngd_session_ready_wait_ms (the benchmark's ready_wait_mean_ms).
+    PIPELINE_DEPTH = 2
     # The end of a turn that has time left (_await_frame): asleep until a
     # guard short of the refresh, then a look at the source every step.
     # On the chip's host a sleep of 3 ms overshoots by 0.25 ms (1.1 at
@@ -803,31 +836,72 @@ class StreamSession:
         quarter refresh past the old wake-up time (a source gone quiet);
         the top of the loop takes the frame.  ``_behind`` is how late the
         last take was through turns that overran: the wait starts that
-        much sooner, so the lateness is made up by each turn's slack."""
-        wake = t0 + frame_interval - self.TAKE_GUARD_S - self._behind
-        limit = t0 + frame_interval + frame_interval / 4
-        now = time.perf_counter()
-        if wake > now:
-            time.sleep(wake - now)
-        looks = 0
-        while not self._stop.is_set():
-            looks += 1
-            try:
-                moved = self._source_seq() != self._last_seq
-            except Exception:
-                break               # the top of the loop meets it, and retries
+        much sooner, so the lateness is made up by each turn's slack.  The
+        whole of it is the stage ``await``, and every look also asks
+        whether the device has finished the frame in flight
+        (``_probe_ready``: the loop is awake anyway)."""
+        with obst.stage("await"):
+            wake = t0 + frame_interval - self.TAKE_GUARD_S - self._behind
+            limit = t0 + frame_interval + frame_interval / 4
             now = time.perf_counter()
-            if moved and looks > 1:
-                _M_LOCKED_TAKES.inc()
-                self._behind = 0.0
-            elif moved:
-                # already there: the turn ends early by what it had left
-                self._behind = max(
-                    0.0, self._behind - (t0 + frame_interval - now))
-            if moved or now + self.TAKE_STEP_S > limit:
-                break
-            time.sleep(self.TAKE_STEP_S)
-        _M_TAKE_LOOKS.inc(looks)
+            if wake > now:
+                time.sleep(wake - now)
+            looks = 0
+            while not self._stop.is_set():
+                looks += 1
+                self._probe_ready()
+                try:
+                    moved = self._source_seq() != self._last_seq
+                except Exception:
+                    break           # the top of the loop meets it, and retries
+                now = time.perf_counter()
+                if moved and looks > 1:
+                    _M_LOCKED_TAKES.inc()
+                    self._behind = 0.0
+                elif moved:
+                    # already there: the turn ends early by what it had left
+                    self._behind = max(
+                        0.0, self._behind - (t0 + frame_interval - now))
+                if moved or now + self.TAKE_STEP_S > limit:
+                    break
+                time.sleep(self.TAKE_STEP_S)
+            _M_TAKE_LOOKS.inc(looks)
+
+    # -- how long a finished frame waits for its collect ----------------
+
+    def _watch(self, token) -> None:
+        """From now on ``token`` is the oldest frame in flight (None:
+        nothing is), and the one the probes ask about."""
+        self._ready_at = None
+        ask = getattr(self.encoder, "token_ready", None)
+        self._probe = (functools.partial(ask, token)
+                       if token is not None and ask is not None else None)
+
+    def _probe_ready(self) -> Optional[bool]:
+        """One look at the oldest frame in flight: has the device finished
+        it?  The first yes is stamped and ends the asking, as does an
+        encoder that cannot say (None).  Nothing is asked with tracing
+        off, or once the answer is known."""
+        probe = self._probe
+        if probe is None or not obst.enabled():
+            return None
+        ready = probe()
+        if ready is None or ready:
+            self._probe = None
+            if ready:
+                self._ready_at = time.perf_counter()
+        return ready
+
+    def _ready_wait_ms(self, tc: float) -> Optional[float]:
+        """At the start ``tc`` of the oldest frame's collect: how long it
+        had been finished by the first probe that said so.  0.0 when only
+        the probe made now says so, or none does (the thread is then the
+        one that waits); None where nobody could say."""
+        if not obst.enabled():
+            return None
+        if self._ready_at is not None:
+            return (tc - self._ready_at) * 1e3
+        return None if self._probe_ready() is None else 0.0
 
     def _run(self) -> None:
         pending: list = []                   # submitted tokens, oldest first
@@ -845,9 +919,11 @@ class StreamSession:
                         self.encoder.encode_collect(pending.pop(0)[0])
                     except Exception:
                         pass
+                self._watch(None)
                 self._apply_resize()
             self._idr_tick()       # grant a deferred rate-limited IDR
             t0 = time.perf_counter()
+            self._probe_ready()
             try:
                 if rfaults.fire("xserver_gone") is not None:
                     raise ConnectionError("fault injection: xserver_gone")
@@ -935,6 +1011,7 @@ class StreamSession:
                         # in-flight frames died with the device; the
                         # recovery IDR is the client's next sync point
                         pending.clear()
+                        self._watch(None)
                         self._drop_until_key = True
                         if not self._recover_device():
                             log.error("device recovery exhausted; "
@@ -952,6 +1029,8 @@ class StreamSession:
                 pending.append((token, capture_pts, fid,
                                 [("capture", t0), ("captured", t_cap),
                                  ("device-submit", t_sub)]))
+                if len(pending) == 1:
+                    self._watch(token)
                 submit_ms = (t_sub - t0) * 1e3
                 self._submit_ms.append(submit_ms)
                 _M_SUBMIT_MS.observe(submit_ms)
@@ -970,6 +1049,8 @@ class StreamSession:
                             or not changed):
                 tc = time.perf_counter()
                 token, frame_pts, fid, marks = pending.pop(0)
+                ready_ms = self._ready_wait_ms(tc)
+                self._watch(pending[0][0] if pending else None)
                 try:
                     spec = rfaults.fire("collect_timeout")
                     if spec is not None:
@@ -1004,6 +1085,8 @@ class StreamSession:
                 collect_ms = (t_col - tc) * 1e3
                 self._collect_ms.append(collect_ms)
                 _M_COLLECT_MS.observe(collect_ms)
+                if ready_ms is not None:
+                    _M_READY_WAIT_MS.observe(ready_ms)
                 marks.append(("device-collect", t_col))
                 if self._drop_until_key:
                     if not ef.keyframe:
@@ -1020,61 +1103,65 @@ class StreamSession:
                                                 keyframe=ef.keyframe,
                                                 pts_ms=frame_pts // 90)
                             if self.muxer is not None else ef.data)
-                marks.append(("bitstream", time.perf_counter()))
-                self.stats.record_frame(ef.encode_ms, len(frag))
-                _M_FRAMES.inc()
-                if ef.keyframe:
-                    _M_KEYFRAMES.inc()
-                _M_BYTES.inc(len(frag))
-                self._post(frag, ef.keyframe, fid)
-                t_pub = time.perf_counter()
-                marks.append(("publish", t_pub))
-                # journey: publish + the encoder's chunk/shard identity
-                # (device span amortizes over the chunk at export);
-                # device_ms = this frame's own submit span + collect
-                jmeta = (self.encoder.pop_journey_meta()
-                         if hasattr(self.encoder, "pop_journey_meta")
-                         else None)
-                self.journeys.complete(
-                    fid, t_pub,
-                    device_ms=collect_ms + (marks[2][1] - marks[1][1])
-                    * 1e3,
-                    meta=jmeta)
-                # pts is the cross-track key: the webrtc 'rtp-sent' span
-                # for this frame carries the identical pts value;
-                # session/chunk/shard meta labels the Chrome-trace lane
-                tmeta = [("session", self.journeys.session)]
-                if jmeta and jmeta.get("chunk_len", 1) > 1:
-                    tmeta += [("chunk", jmeta["chunk_id"]),
-                              ("slot", jmeta["slot"])]
-                if jmeta and jmeta.get("shards", 1) > 1:
-                    tmeta.append(("shards", jmeta["shards"]))
-                self._tracer.record_marks(fid, marks, pts=frame_pts,
-                                          meta=tuple(tmeta))
-                # content & quality plane (obs/content): the encoder's
-                # in-graph stats for this frame, if one was sampled
-                cstats = (self.encoder.pop_content_stats()
-                          if hasattr(self.encoder, "pop_content_stats")
-                          else None)
-                if cstats is not None:
-                    try:
-                        from ..obs.content import PLANE as _content
-                        _content.record(self.journeys.session, cstats)
-                    except Exception:
-                        log.exception("content stats record failed")
-                self._last_tick = time.monotonic()   # delivered = progress
-                # energy-proxy gauges on a ~2 s cadence at 60 fps: the
-                # read is two getrusage fields, publish is two gauge sets
-                self._energy_frames += 1
-                if self._energy_frames >= 120:
-                    try:
-                        self._energy.publish(
-                            self._energy_frames,
-                            tune=getattr(self.encoder, "tune", "off"))
-                    except Exception:
-                        pass
-                    self._energy.reset()
-                    self._energy_frames = 0
+                # the loop's tail for a delivered frame, one span: counters,
+                # the hand-over to the event loop, journey, marks, content
+                # record, energy gauges
+                with obst.stage("publish"):
+                    marks.append(("bitstream", time.perf_counter()))
+                    self.stats.record_frame(ef.encode_ms, len(frag))
+                    _M_FRAMES.inc()
+                    if ef.keyframe:
+                        _M_KEYFRAMES.inc()
+                    _M_BYTES.inc(len(frag))
+                    self._post(frag, ef.keyframe, fid)
+                    t_pub = time.perf_counter()
+                    marks.append(("publish", t_pub))
+                    # journey: publish + the encoder's chunk/shard identity
+                    # (device span amortizes over the chunk at export);
+                    # device_ms = this frame's own submit span + collect
+                    jmeta = (self.encoder.pop_journey_meta()
+                             if hasattr(self.encoder, "pop_journey_meta")
+                             else None)
+                    self.journeys.complete(
+                        fid, t_pub,
+                        device_ms=collect_ms
+                        + (marks[2][1] - marks[1][1]) * 1e3,
+                        meta=jmeta)
+                    # pts is the cross-track key: the webrtc 'rtp-sent' span
+                    # for this frame carries the identical pts value;
+                    # session/chunk/shard meta labels the Chrome-trace lane
+                    tmeta = [("session", self.journeys.session)]
+                    if jmeta and jmeta.get("chunk_len", 1) > 1:
+                        tmeta += [("chunk", jmeta["chunk_id"]),
+                                  ("slot", jmeta["slot"])]
+                    if jmeta and jmeta.get("shards", 1) > 1:
+                        tmeta.append(("shards", jmeta["shards"]))
+                    self._tracer.record_marks(fid, marks, pts=frame_pts,
+                                              meta=tuple(tmeta))
+                    # content & quality plane (obs/content): the encoder's
+                    # in-graph stats for this frame, if one was sampled
+                    cstats = (self.encoder.pop_content_stats()
+                              if hasattr(self.encoder, "pop_content_stats")
+                              else None)
+                    if cstats is not None:
+                        try:
+                            from ..obs.content import PLANE as _content
+                            _content.record(self.journeys.session, cstats)
+                        except Exception:
+                            log.exception("content stats record failed")
+                    self._last_tick = time.monotonic()   # delivered = progress
+                    # energy-proxy gauges on a ~2 s cadence at 60 fps: the
+                    # read is two getrusage fields, publish is two gauge sets
+                    self._energy_frames += 1
+                    if self._energy_frames >= 120:
+                        try:
+                            self._energy.publish(
+                                self._energy_frames,
+                                tune=getattr(self.encoder, "tune", "off"))
+                        except Exception:
+                            pass
+                        self._energy.reset()
+                        self._energy_frames = 0
 
             # continuity checkpoint on its cadence (the due-check is one
             # clock read).  Mid-pipeline state is fine: counters may run
@@ -1083,6 +1170,9 @@ class StreamSession:
             self._ckpt.maybe_snapshot(self.encoder)
 
             elapsed = time.perf_counter() - t0
+            if changed and obst.enabled():
+                _M_TURN_MS.observe(elapsed * 1e3)
+            self._probe_ready()
             sleep = frame_interval - elapsed
             if sleep <= 0:
                 # over the refresh: the newest frame is taken at once

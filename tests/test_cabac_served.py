@@ -137,6 +137,30 @@ def test_served_stream_is_the_reference_coders_and_the_decoders(
         assert np.array_equal(luma, ref), f"picture {c} is not the reference"
 
 
+@pytest.mark.parametrize("kind", ["cabac_intra", "cabac_p"])
+def test_token_ready_answers_for_a_cabac_token_and_changes_no_byte(
+        encoder, kind):
+    """``H264Encoder.token_ready``: ``is_ready()`` of the record stream's
+    guessed prefix, which the collect pulls first.  A bool before the
+    collect, True after it, no compile, one ``stats`` span a collected
+    frame, and the access unit is still the reference coder's."""
+    enc = encoder
+    if kind == "cabac_intra":
+        enc.request_keyframe()
+    else:
+        enc.encode_collect(enc.encode_submit(frame(40)))
+    compiles, stats = (counter("jax_compile_cache_requests_total"),
+                       samples("stats"))
+    token = enc.encode_submit(frame(41))
+    assert token[0] == kind
+    assert enc.token_ready(token) in (True, False)
+    want = reference_unit(enc, token)
+    assert enc.encode_collect(token).data == want
+    assert enc.token_ready(token) is True
+    assert counter("jax_compile_cache_requests_total") == compiles
+    assert samples("stats") == stats + 1
+
+
 @pytest.mark.parametrize("w,h,fps,level", [
     (1280, 720, 30, 42), (1920, 1080, 60, 42), (2560, 1600, 60, 51),
     (3840, 2160, 30, 51)])

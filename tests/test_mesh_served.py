@@ -68,11 +68,14 @@ def walked(tmp_path_factory):
         for h in (128, 112):
             one, mesh = _pair(h, mp)
             before = _counters()
-            units, refs, steps = [], [], []
+            units, refs, steps, ready = [], [], [], []
             for rgb, qp in zip(_frames(len(QPS), h, seed=h), QPS):
                 one._forced_qp = mesh._forced_qp = qp
                 a = one.encode(rgb)
-                b = mesh.encode_collect(mesh.encode_submit(rgb))
+                token = mesh.encode_submit(rgb)
+                ready.append(mesh.token_ready(token))
+                b = mesh.encode_collect(token)
+                ready.append(mesh.token_ready(token))
                 units.append((a, b))
                 refs.append(np.array(
                     mesh.export_state()["ref"][0][:h, :W]))
@@ -94,7 +97,8 @@ def walked(tmp_path_factory):
             shape = (int(cap.get(cv2.CAP_PROP_FRAME_WIDTH)),
                      int(cap.get(cv2.CAP_PROP_FRAME_HEIGHT)))
             out[h] = dict(one=one, mesh=mesh, units=units, refs=refs,
-                          steps=steps, decoded=decoded, before=before,
+                          steps=steps, decoded=decoded, ready=ready,
+                          before=before,
                           after=after, path=path, shape=shape)
     return out
 
@@ -192,6 +196,22 @@ def test_spans_and_counters_of_the_mesh_path(walked, h):
     assert d["dngd_encoder_h2d_bytes_total"] >= 2 * n * (128 * W * 3 // 2)
     assert d["dngd_encoder_d2h_bytes_total"] > 0
     assert d.get('dngd_encoder_cabac_fallback_total{kind="dense"}', 0) == 0
+
+
+@pytest.mark.parametrize("h", [128, 112])
+def test_a_mesh_token_says_whether_every_shard_is_finished(walked, h):
+    """``token_ready``: ``is_ready()`` of the stacked prefix
+    ``pull_shards`` pulls first, one array over every shard; a bool before
+    the collect, True after it, and the access units (above) are the
+    one-chip encoder's all the same.  One ``stats`` span a collected
+    frame."""
+    ready = walked[h]["ready"]
+    assert len(ready) == 2 * len(QPS)
+    assert all(r in (True, False) for r in ready[0::2])
+    assert all(r is True for r in ready[1::2])
+    d = walked[h]
+    assert d["after"]["dngd_stage_stats_ms_count"] \
+        - d["before"]["dngd_stage_stats_ms_count"] == len(QPS)
 
 
 def test_the_pull_ladder_is_warmed_for_the_stacked_buffers(monkeypatch):

@@ -97,7 +97,8 @@ def counters():
 
 
 def drive(monkeypatch, cost=lambda n: 0.010, seconds=10.0, overshoot=0.00006,
-          subscribers=True, end_of_turn=None, fps_cap=None, static=False):
+          subscribers=True, end_of_turn=None, fps_cap=None, static=False,
+          work=Work):
     """Run the loop for ``seconds`` of the fake clock; what it took and when."""
     clock = FakeTime(overshoot)
     monkeypatch.setattr(session_mod, "time", clock)
@@ -107,7 +108,7 @@ def drive(monkeypatch, cost=lambda n: 0.010, seconds=10.0, overshoot=0.00006,
     source = Counter60(clock, until=seconds, static=static)
     sess = StreamSession(cfg, source)
     source.session = sess
-    sess.encoder = work = Work(clock, cost)
+    sess.encoder = work = work(clock, cost)
     sess.PIPELINE_DEPTH = work.pipeline_depth
     sess._post = lambda *a, **k: None
     sess._fps_cap = fps_cap
@@ -122,7 +123,7 @@ def drive(monkeypatch, cost=lambda n: 0.010, seconds=10.0, overshoot=0.00006,
         sess.close()
     locked, looks = (b - a for a, b in zip(before, counters()))
     return types.SimpleNamespace(
-        clock=clock, source=source, taken=work.taken,
+        clock=clock, source=source, taken=work.taken, work=work,
         ages=[source.age(k) for k in work.taken],
         locked=locked, looks=looks)
 
@@ -204,7 +205,7 @@ def test_the_limit_ends_the_wait_of_a_source_that_never_changes(monkeypatch):
         _last_seq=0, _behind=0.0,
         _stop=types.SimpleNamespace(is_set=lambda: False),
         TAKE_GUARD_S=StreamSession.TAKE_GUARD_S, TAKE_STEP_S=STEP,
-        _source_seq=lambda: 0)
+        _source_seq=lambda: 0, _probe_ready=lambda: None)
     t0 = clock.t
     StreamSession._await_frame(sess, t0, REFRESH)
     assert REFRESH < clock.t - t0 <= REFRESH * 1.25 + EPS
@@ -263,3 +264,130 @@ def test_the_synthetic_sources_peek_agrees_with_its_frame(monkeypatch):
     assert src.frame()[1] == seqs[-1] == int(6 * 0.0123 * 60.0)
     assert seqs == sorted(seqs) and len(set(seqs)) > 3
     assert NumpySource(64, 48).seq() == NumpySource(64, 48).frame()[1] == 0
+
+
+# -- how long a finished frame waits for its collect (PR 38) ------------------
+
+def probed(device_s):
+    """An encoder front whose device finishes a frame ``device_s`` after its
+    submit's end and answers ``token_ready`` (None where ``device_s`` is):
+    it notes the first yes a frame and when each collect began."""
+
+    class Probed(Work):
+        def __init__(self, clock, cost):
+            super().__init__(clock, cost)
+            self.done_at, self.first_yes, self.collected = {}, {}, {}
+            self.asked = 0
+
+        def encode_submit(self, k):
+            super().encode_submit(k)
+            self.done_at[k] = self.clock.t + (device_s or 0.0)
+            return k
+
+        def token_ready(self, k):
+            self.asked += 1
+            if device_s is None:
+                return None
+            assert k not in self.first_yes      # asked no more after a yes
+            if self.clock.t < self.done_at[k]:
+                return False
+            self.first_yes[k] = self.clock.t
+            return True
+
+        def encode_collect(self, k):
+            self.collected[k] = self.clock.t
+            return super().encode_collect(k)
+
+    return Probed
+
+
+def ready_wait():
+    h = session_mod._M_READY_WAIT_MS._default
+    return h.count, h.sum
+
+
+def test_a_frame_finished_at_a_look_waited_from_that_look_to_its_collect(
+        monkeypatch):
+    """Submit ends 7 ms into a 10 ms turn and the device needs 8.5 ms more:
+    finished 15.5 ms in, between the wait's first look (15.2) and its
+    second.  The sample is the collect's start less that second look."""
+    n0, s0 = ready_wait()
+    run = drive(monkeypatch, seconds=4.0, work=probed(0.0085))
+    n, total = (b - a for a, b in zip((n0, s0), ready_wait()))
+    w = run.work
+    assert n == len(w.collected) >= 236
+    waits = [(w.collected[k] - w.first_yes[k]) * 1e3 for k in w.collected]
+    # (a collect begins two reads of the clock after ``tc`` was taken)
+    assert total == pytest.approx(sum(waits), abs=0.01 * n)
+    steady = sorted(waits[12:])
+    assert REFRESH * 1e3 + 7.0 - 15.5 - (STEP + EPS) * 1e3 <= steady[0]
+    assert steady[-1] <= REFRESH * 1e3 + 7.0 - 15.5 + 0.01
+    # the end of the turn, the look after the sleep, the look that said
+    # yes: asked three times a frame and never again
+    assert w.asked <= 3 * len(w.taken) + 12
+
+
+@pytest.mark.parametrize("device_s", [0.0127, 0.030],
+                         ids=["first_yes_at_the_collect", "never_by_then"])
+def test_a_frame_the_thread_waits_for_reads_zero(monkeypatch, device_s):
+    """Finished between the next turn's top and its collect's start (7 ms
+    + 12.7 = 19.7 ms after its turn began, 3 ms into the next), or after
+    it: the thread is the one that waits, and the sample is 0.0."""
+    n0, s0 = ready_wait()
+    run = drive(monkeypatch, seconds=2.0, work=probed(device_s))
+    n, total = (b - a for a, b in zip((n0, s0), ready_wait()))
+    assert n == len(run.work.collected) >= 116
+    assert total == 0.0
+    if device_s < 0.02:
+        at = [run.work.first_yes[k] - run.work.collected[k]
+              for k in run.work.first_yes]
+        assert len(at) >= n - 2 and all(-1e-5 < d <= 0.0 for d in at[2:])
+    else:
+        assert not run.work.first_yes
+
+
+def test_an_encoder_that_cannot_say_gives_no_sample(monkeypatch):
+    before = ready_wait()
+    run = drive(monkeypatch, seconds=2.0, work=probed(None))
+    assert ready_wait() == before
+    # asked once a frame: None ends the asking
+    assert len(run.taken) - 2 <= run.work.asked <= len(run.taken)
+    before = ready_wait()
+    drive(monkeypatch, seconds=1.0)            # no ``token_ready`` at all
+    assert ready_wait() == before
+
+
+def test_the_probes_change_no_take(monkeypatch):
+    plain = drive(monkeypatch, seconds=4.0)
+    asked = drive(monkeypatch, seconds=4.0, work=probed(0.0085))
+    assert asked.taken == plain.taken and len(plain.taken) >= 238
+    # the stamp of the first yes is one more read of the clock a frame, a
+    # microsecond here: which look finds a frame may change, its bound not
+    for run in (plain, asked):
+        assert max(run.ages[12:]) <= STEP + EPS
+        assert run.locked >= len(run.taken) - 13
+    assert abs(asked.looks - plain.looks) <= 0.02 * plain.looks
+
+
+def test_with_tracing_off_nothing_is_asked_and_nothing_observed(monkeypatch):
+    from docker_nvidia_glx_desktop_tpu.obs import trace as obst
+    turn = session_mod._M_TURN_MS._default
+    before = (ready_wait(), turn.count)
+    obst.set_enabled(False)
+    try:
+        run = drive(monkeypatch, seconds=1.0, work=probed(0.0085))
+    finally:
+        obst.set_enabled(True)
+    assert len(run.taken) > 50 and run.work.asked == 0
+    assert (ready_wait(), turn.count) == before
+
+
+def test_the_turn_histogram_has_one_sample_a_turn_that_took_a_frame(
+        monkeypatch):
+    turn = session_mod._M_TURN_MS._default
+    n0, s0 = turn.count, turn.sum
+    run = drive(monkeypatch, seconds=2.0)
+    n = turn.count - n0
+    assert n == len(run.taken)
+    # 10 ms of work a turn (7 in the first, a submit alone), not the wait
+    assert (turn.sum - s0) / n == pytest.approx(10.0, abs=0.1)

@@ -12,17 +12,23 @@ from docker_nvidia_glx_desktop_tpu.obs import metrics as obsm
 from docker_nvidia_glx_desktop_tpu.obs import procstats
 from docker_nvidia_glx_desktop_tpu.obs import trace as obst
 from docker_nvidia_glx_desktop_tpu.utils.config import from_env
+# (importing the session registers its families)
+from docker_nvidia_glx_desktop_tpu.web import session as _  # noqa: F401
 
 W, H = 128, 96
 ENCODER_STAGES = ("colour", "dispatch", "pull", "pull_extra")
 MARKS = ("capture", "captured", "device-submit", "device-collect",
          "bitstream", "publish")
+# the session's own histograms of a turn (web/session.py, PR 38)
+TURN_FAMILIES = ("dngd_session_turn_ms", "dngd_session_ready_wait_ms")
 
 
 def counts() -> dict:
     """Samples so far in every stage family and the extra-pull counter."""
     out = {name: obsm.REGISTRY.get(f"dngd_stage_{name}_ms")._default.count
-           for name in obst.STAGES}
+           for name in obst.STAGES + obst.TURN_STAGES}
+    for name in TURN_FAMILIES:
+        out[name] = obsm.REGISTRY.get(name)._default.count
     out["ws_send"] = obst.M_WS_SEND_MS._default.count
     out["pull_extra_total"] = obsm.REGISTRY.get(
         "dngd_encoder_pull_extra_total").value
@@ -184,13 +190,7 @@ def test_full_damage_through_the_mask_is_the_unmasked_stream():
 def served():
     """Frames served by a StreamSession at 128x96 (GOP 4: IDRs and P
     frames), with what the stage families and the pipeline recorder saw."""
-    from docker_nvidia_glx_desktop_tpu.rfb.source import SyntheticSource
-    from docker_nvidia_glx_desktop_tpu.web.session import StreamSession
-
-    cfg = from_env({"PASSWD": "pw", "SIZEW": str(W), "SIZEH": str(H),
-                    "REFRESH": "30", "ENCODER_GOP": "4",
-                    "ENCODER_PREWARM": "false"})
-    sess = StreamSession(cfg, SyntheticSource(W, H, fps=30))
+    sess = small_session()
     marks, posted, done = [], [], threading.Event()
     listener = lambda kind, entry: marks.append((kind, entry))  # noqa: E731
     obst.tracer("pipeline").add_listener(listener)
@@ -201,6 +201,7 @@ def served():
             done.set()
 
     sess._post = post
+    waits, answers = spy_on_the_turn(sess)
     before, dropped = counts(), obst.dropped_total()
     sess.start()
     try:
@@ -209,7 +210,30 @@ def served():
         sess.stop()
         obst.tracer("pipeline").remove_listener(listener)
     return {"stages": delta(before), "posted": list(posted),
-            "marks": marks, "dropped": obst.dropped_total() - dropped}
+            "marks": marks, "dropped": obst.dropped_total() - dropped,
+            "waits": len(waits), "answers": answers}
+
+
+def small_session():
+    """A StreamSession at 128x96, GOP 4 (IDRs and P frames), not started."""
+    from docker_nvidia_glx_desktop_tpu.rfb.source import SyntheticSource
+    from docker_nvidia_glx_desktop_tpu.web.session import StreamSession
+
+    cfg = from_env({"PASSWD": "pw", "SIZEW": str(W), "SIZEH": str(H),
+                    "REFRESH": "30", "ENCODER_GOP": "4",
+                    "ENCODER_PREWARM": "false"})
+    return StreamSession(cfg, SyntheticSource(W, H, fps=30))
+
+
+def spy_on_the_turn(sess):
+    """Every turn that enters the end-of-turn wait, and every answer of
+    the encoder's ``token_ready``, noted."""
+    waits, answers = [], []
+    wait, ready = sess._await_frame, sess.encoder.token_ready
+    sess._await_frame = lambda *a: (waits.append(1), wait(*a))[1]
+    sess.encoder.token_ready = (
+        lambda token: (answers.append(ready(token)), answers[-1])[1])
+    return waits, answers
 
 
 @pytest.mark.parametrize("name", [n for n in obst.STAGES
@@ -223,6 +247,111 @@ def test_a_served_frame_is_one_sample_of_every_stage(served, name):
     assert frames <= got <= frames + 3, (name, got, frames)
     assert served["stages"]["pull_extra"] == 0
     assert served["stages"]["pull_extra_total"] == 0
+
+
+def test_a_served_frame_is_one_sample_of_stats_and_of_publish(served):
+    """``stats`` a collected frame (the content statistics' pull that ends
+    ``encode_collect``), ``publish`` a delivered one (the loop's tail)."""
+    frames = len(served["posted"])
+    assert served["stages"]["publish"] == frames
+    assert frames <= served["stages"]["stats"] <= frames + 3
+    assert served["stages"]["stats"] == served["stages"]["encode_collect"]
+
+
+def test_a_turn_that_waits_is_one_sample_of_await(served):
+    """One a turn that entered ``_await_frame`` and none of any other (a
+    turn over the refresh has none: tests/test_session_pacing.py)."""
+    assert served["stages"]["await"] == served["waits"]
+
+
+def test_a_turn_that_took_a_frame_is_one_sample_of_the_turn(served):
+    assert served["stages"]["dngd_session_turn_ms"] == \
+        served["stages"]["encode_submit"]
+
+
+def test_a_collected_frame_is_one_sample_of_the_ready_wait(served):
+    """The per-frame CAVLC tokens answer a bool, so every collected frame
+    has its sample; asked until the first yes and at the collect's start,
+    six times a turn at most."""
+    got = served["stages"]
+    assert got["dngd_session_ready_wait_ms"] == got["encode_collect"]
+    assert served["answers"] and set(served["answers"]) <= {True, False}
+    assert len(served["answers"]) <= 6 * got["encode_submit"]
+
+
+def test_with_tracing_off_no_new_family_moves_and_nothing_is_asked():
+    sess = small_session()
+    posted, done = [], threading.Event()
+    sess._post = lambda *a, **k: (posted.append(1),
+                                  len(posted) >= 5 and done.set())
+    waits, answers = spy_on_the_turn(sess)
+    obst.set_enabled(False)
+    try:
+        before = counts()
+        sess.start()
+        try:
+            assert done.wait(300), posted
+        finally:
+            sess.stop()
+        got = delta(before)
+    finally:
+        obst.set_enabled(True)
+    assert not answers and len(posted) >= 5
+    new = obst.TURN_STAGES + TURN_FAMILIES
+    assert {k: got[k] for k in new} == dict.fromkeys(new, 0)
+
+
+@pytest.mark.parametrize("kind", ["idr", "p"])
+def test_token_ready_answers_for_a_per_frame_token_and_changes_no_byte(
+        encoder, kind):
+    """``is_ready()`` of the prefix the collect pulls first: a bool before
+    the collect and True after it, and the access unit is the one an
+    encoder that was never asked gives."""
+    from docker_nvidia_glx_desktop_tpu.models import make_encoder
+
+    cfg = from_env({"PASSWD": "pw", "SIZEW": str(W), "SIZEH": str(H),
+                    "REFRESH": "30", "ENCODER_PREWARM": "false"})
+    units = []
+    for ask in (True, False):
+        enc, _ = make_encoder(cfg, W, H)
+        enc._forced_qp = 30
+        enc.encode_collect(enc.encode_submit(frame(0)))
+        if kind == "idr":
+            enc._force_idr = True
+        compiles = obsm.REGISTRY.get("jax_compile_cache_requests_total")
+        token, requests = enc.encode_submit(frame(3)), compiles.value
+        assert token[0] == ("intra" if kind == "idr" else "p")
+        if ask:
+            assert enc.token_ready(token) in (True, False)
+            assert compiles.value == requests
+        units.append(enc.encode_collect(token).data)
+        if ask:
+            assert enc.token_ready(token) is True
+    assert units[0] == units[1]
+
+
+def test_token_ready_is_none_where_there_is_nothing_to_ask():
+    """The ring and masked paths, a synchronous token, a prefix that has
+    been deleted: None, and never an exception."""
+    import jax.numpy as jnp
+
+    from docker_nvidia_glx_desktop_tpu.models.base import Encoder
+
+    enc = raw_encoder()
+    assert Encoder.token_ready(enc, ("p", 0, 0.0, False, ())) is None
+    assert enc.token_ready(("sync", None, None, True, object())) is None
+    assert enc.token_ready(("ring", 1, 0.0, False, ({}, 0))) is None
+    assert enc.token_ready(("p", 1, 0.0, False, ("dmg",) + (None,) * 7)) \
+        is None
+    assert enc.token_ready(("p", 1, 0.0, False, (None,) * 7)) is None
+    assert enc.token_ready(None) is None
+    gone = jnp.zeros(8)
+    payload = (30, 1, {}, None, None, gone, None)
+    assert enc.token_ready(("p", 1, 0.0, False, payload)) is True
+    gone.delete()
+    assert enc.token_ready(("p", 1, 0.0, False, payload)) is None
+    stacked = ("sp_bin", "p", 30, 0, 1, None, jnp.zeros((2, 8)), None)
+    assert enc.token_ready(("cabac_p", 1, 0.0, False, stacked)) is True
 
 
 def test_a_served_frames_marks_are_the_six_they_were(served):
@@ -348,6 +477,13 @@ SCOPES = {
                 "dngd.deblock_edges", "dngd.deblock_v", "dngd.deblock_h"),
     "colour": ("dngd.colour",),
     "frame_stats": ("dngd.frame_stats",),
+    # the CABAC path's own five (PR 38; ENCODER_ENTROPY=cabac)
+    "cabac_p": ("dngd.ingest", "dngd.me_int", "dngd.me_subpel", "dngd.mc",
+                "dngd.tq", "dngd.recon"),
+    "cabac_intra": ("dngd.intra",),
+    "binarize_p": ("dngd.binarize",),
+    "binarize_intra": ("dngd.binarize",),
+    "cabac_bs": ("dngd.deblock_bs",),
 }
 
 
@@ -362,9 +498,11 @@ def lowered():
     without them.  For these compiles the key holds the metadata too."""
     import jax
 
-    from docker_nvidia_glx_desktop_tpu.models.h264 import _yuv_stage
+    from docker_nvidia_glx_desktop_tpu.models.h264 import (_cabac_bs_inputs,
+                                                            _yuv_stage)
     from docker_nvidia_glx_desktop_tpu.ops import (
-        cavlc_device, cavlc_p_device, content_stats, h264_deblock)
+        cabac_binarize, cavlc_device, cavlc_p_device, content_stats,
+        h264_deblock, h264_device, h264_inter)
 
     w, h = 176, 112
     nr, nc = h // 16, w // 16
@@ -387,6 +525,29 @@ def lowered():
         "frame_stats": content_stats.frame_stats.lower(
             y, y, y, mv, (), None, 512),
     }
+    # the CABAC path as H264Encoder._submit_cabac_p / _submit_cabac_intra
+    # hand it on: the level tensors of the P and the intra program into
+    # the two binarize programs and the loop filter's inputs
+    lv_p = jax.eval_shape(
+        lambda *a: h264_inter.encode_p_frame_dynqp(*a, tune="off"),
+        y, c, c, y, c, c, qp)
+    lv_i = jax.eval_shape(
+        lambda *a: h264_device.encode_intra_frame_yuv_dynqp(
+            *a, i16_modes="auto", tune="off"), y, c, c, qp)
+    zeros = lambda lv, *keys: [np.zeros(lv[k].shape, lv[k].dtype)  # noqa: E731
+                               for k in keys]
+    progs.update({
+        "cabac_p": h264_inter.encode_p_frame_dynqp.lower(
+            y, c, c, y, c, c, qp, tune="off"),
+        "cabac_intra": h264_device.encode_intra_frame_yuv_dynqp.lower(
+            y, c, c, qp, i16_modes="auto", tune="off"),
+        "binarize_p": cabac_binarize.binarize_p.lower(*zeros(
+            lv_p, "mv", "luma", "cb_dc", "cb_ac", "cr_dc", "cr_ac")),
+        "binarize_intra": cabac_binarize.binarize_intra.lower(*zeros(
+            lv_i, "luma_dc", "luma_ac", "cb_dc", "cb_ac", "cr_dc", "cr_ac",
+            "pred_mode", "mb_i4", "i4_modes", "luma_i4")),
+        "cabac_bs": _cabac_bs_inputs.lower(*zeros(lv_p, "luma", "mv")),
+    })
     flag = "jax_compilation_cache_include_metadata_in_key"
     was = getattr(jax.config, flag)
     jax.config.update(flag, True)
