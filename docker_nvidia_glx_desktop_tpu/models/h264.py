@@ -968,11 +968,10 @@ class H264Encoder(Encoder):
         except Exception:
             pass
 
-    def _sp_submit_intra(self, rgb, idr_pic_id: int):
+    def _sp_submit_intra(self, idr_pic_id: int, begun):
         from ..ops import cavlc_device
 
-        qp = self._eff_qp()
-        y, cb, cr = self._planes_device(rgb)      # stage "colour"
+        qp, (y, cb, cr) = begun                   # of _intra_begin
         with obst.stage("dispatch") as span:
             step = self._sp_step("intra", qp)
             _note_h2d(y, cb, cr)
@@ -1407,7 +1406,7 @@ class H264Encoder(Encoder):
             scratch._sp_steps = self._sp_steps
             pulls = (scratch._sp_cabac_pull("intra"),
                      scratch._sp_cabac_pull("p"))
-            buf = scratch._sp_submit_intra(rgb, 0)[5]
+            buf = scratch._submit_cabac_intra(rgb, 0)[5]
             n = pulls[0].warm(buf)
             buf = scratch._sp_submit_p(
                 *scratch._planes_device(rgb), self.qp)[5]
@@ -1446,8 +1445,19 @@ class H264Encoder(Encoder):
             self._hdr_slots_cache[key] = slots
         return slots
 
-    def _submit_device(self, rgb, idr_pic_id: int):
-        """Dispatch the device stage asynchronously (no host sync).
+    def _intra_begin(self, rgb):
+        """An intra frame's qp and planes, ``(qp, planes)``: the half of
+        its submit that hands the device nothing while the host converts
+        (planes None: the device converts, in the second half)."""
+        qp = self._eff_qp()
+        if self._spatial_nx > 1:
+            return qp, self._planes_device(rgb)
+        with obst.stage("colour"):
+            return qp, (self._host_yuv420(rgb) if self.host_color else None)
+
+    def _submit_device(self, rgb, idr_pic_id: int, begun=None):
+        """Dispatch the device stage asynchronously (no host sync);
+        ``begun``: what :meth:`_intra_begin` made of ``rgb`` already.
 
         When cv2 is available the RGB->YUV420 conversion runs on the host
         (SIMD, ~2-5 ms at 1080p) so only 1.5 B/px cross the host->device
@@ -1456,12 +1466,12 @@ class H264Encoder(Encoder):
         "video" (tested in tests/test_h264_cavlc.py)."""
         from ..ops import cavlc_device
 
+        if begun is None:
+            begun = self._intra_begin(rgb)
         if self._spatial_nx > 1:
-            return self._sp_submit_intra(rgb, idr_pic_id)
-        qp = self._eff_qp()
+            return self._sp_submit_intra(idr_pic_id, begun)
+        qp, planes = begun
         with_recon = self.keep_recon or self.gop > 1
-        with obst.stage("colour"):
-            planes = self._host_yuv420(rgb) if self.host_color else None
         with obst.stage("dispatch") as span:
             hv, hl = self._hdr_slots(idr_pic_id, qp_delta=qp - self.qp)
             if planes is not None and self._dyn_qp:
@@ -1619,14 +1629,14 @@ class H264Encoder(Encoder):
         self._mean_qp_pending = None
         return m
 
-    def _submit_cabac_intra(self, rgb, idr_pic_id: int):
+    def _submit_cabac_intra(self, rgb, idr_pic_id: int, begun=None):
         from ..ops import cabac_binarize, h264_device, level_pack
 
+        if begun is None:
+            begun = self._intra_begin(rgb)
         if self._spatial_nx > 1:
-            return self._sp_submit_intra(rgb, idr_pic_id)
-        qp = self._eff_qp()
-        with obst.stage("colour"):
-            planes = self._host_yuv420(rgb) if self.host_color else None
+            return self._sp_submit_intra(idr_pic_id, begun)
+        qp, planes = begun
         with obst.stage("dispatch") as span:
             _note_h2d(*(planes if planes is not None else (rgb,)))
             if planes is not None and self._dyn_qp:
@@ -2755,12 +2765,31 @@ class H264Encoder(Encoder):
     # and the current frame's compute overlap; collect blocks on the pull.
     # ------------------------------------------------------------------
 
+    # What the session loop puts between the halves of a submit, for the
+    # length of its own call: the collect of the frame before, where the
+    # device has finished it (web/session.py:_collect_between).
+    between_halves = None
+
     def encode_submit(self, rgb):
         """Start encoding a frame; returns an opaque token.  Device-entropy
         CAVLC and packed-transport CABAC pipeline fully — including GOP
         mode, where the reference dependency between consecutive P frames
         lives on device, so frame N+1 can be submitted while frame N's
-        bitstream is still in flight."""
+        bitstream is still in flight.
+
+        On the per-frame paths the submit is two halves.  The FIRST is
+        the frame's index, the GOP's decision, the rate controller's qp
+        reservation and the colour conversion: everything up to the
+        planes, and nothing for the device while the host converts.  The
+        SECOND is the dispatch: header slots, H2D, the frame's programs,
+        the prefix slice and its prefetch.  ``between_halves``, where the
+        caller has set one, is called with nothing between the two;
+        whatever it does, the stream is the same bytes (an
+        ``encode_collect`` there folds its frame into the rate controller
+        AFTER this frame's qp was reserved, as it does behind the whole
+        submit).  The super-step ring and the damage mask (whose plan
+        feeds the controller from inside the dispatch) submit in one
+        piece and call nothing."""
         if self.mode != "cavlc" or self.entropy not in ("device", "cabac"):
             ef = self.encode(rgb)
             self._content_last = None    # sync path: no stats contract
@@ -2771,18 +2800,13 @@ class H264Encoder(Encoder):
         t0 = time.perf_counter()
         n0 = self._rate.mark() if self._rate is not None else 0
         try:
-            if self.gop == 1:
-                kind = "cabac_intra" if cabac else "intra"
-                sub = (self._submit_cabac_intra(rgb, idx % 2) if cabac
-                       else self._submit_device(rgb, idx % 2))
-                PROFILER.record_encoder(
-                    self, f"{kind}-submit",
-                    (time.perf_counter() - t0) * 1e3)
-                self._content_stash(idx)
-                return (kind, idx, t0, True, sub)
-            idr = (self._gop_pos == 0 or self._force_idr
-                   or self._ref is None)
-            if idr:
+            intra = (self.gop == 1 or self._gop_pos == 0 or self._force_idr
+                     or self._ref is None)
+            if not intra:
+                self._frame_num = (self._frame_num + 1) % 16
+            elif self.gop == 1:
+                pic_id = idx % 2
+            else:
                 if self._ring is not None:
                     # partial chunk ahead of an IDR: per-frame flush
                     # (byte-identical path) so the ring never straddles
@@ -2792,22 +2816,39 @@ class H264Encoder(Encoder):
                 self._gop_pos = 0
                 self._frame_num = 0
                 self._idr_count += 1
+                pic_id = self._idr_count % 2
+            ring = not intra and self._ring_chunk
+            if intra:
+                begun = self._intra_begin(rgb)
+            elif not ring:
+                qp = self._eff_qp(keyframe=False)
+                y, cb, cr = self._planes_device(rgb)
+            between = self.between_halves
+            if between is not None and not (self._ring_chunk
+                                            or self.damage_mask):
+                reserved = self._rate.mark() - n0 \
+                    if self._rate is not None else 0
+                t_b = time.perf_counter()
+                try:
+                    between()
+                finally:
+                    # (a collect in there took ITS reservation off the
+                    # queue's other end: this frame's count from the top)
+                    t0 += time.perf_counter() - t_b
+                    if self._rate is not None:
+                        n0 = self._rate.mark() - reserved
+            if intra:
                 kind = "cabac_intra" if cabac else "intra"
-                sub = (self._submit_cabac_intra(rgb, self._idr_count % 2)
-                       if cabac
-                       else self._submit_device(rgb, self._idr_count % 2))
+                sub = (self._submit_cabac_intra(rgb, pic_id, begun) if cabac
+                       else self._submit_device(rgb, pic_id, begun))
                 tok = (kind, idx, t0, True, sub)
+            elif ring:
+                tok = self._ring_stage(rgb, idx, t0)
             else:
-                self._frame_num = (self._frame_num + 1) % 16
-                if self._ring_chunk:
-                    tok = self._ring_stage(rgb, idx, t0)
-                else:
-                    qp = self._eff_qp(keyframe=False)
-                    y, cb, cr = self._planes_device(rgb)
-                    kind = "cabac_p" if cabac else "p"
-                    sub = (self._submit_cabac_p(y, cb, cr, qp) if cabac
-                           else self._submit_p_device(y, cb, cr, qp))
-                    tok = (kind, idx, t0, False, sub)
+                kind = "cabac_p" if cabac else "p"
+                sub = (self._submit_cabac_p(y, cb, cr, qp) if cabac
+                       else self._submit_p_device(y, cb, cr, qp))
+                tok = (kind, idx, t0, False, sub)
         except Exception:
             # this submit's qp reservation (if it got that far) will never
             # see an update(); drop it so EMA attribution stays aligned

@@ -165,6 +165,19 @@ class _StageSpan:
             self._t0 = time.perf_counter()
         return self
 
+    @contextlib.contextmanager
+    def suspended(self):
+        """The open stage's clock stops for the block: what runs inside is
+        none of its milliseconds (the session's early collect between the
+        halves of ``encode_submit``).  The profiler span stays open round
+        it, so the block's own spans nest in it."""
+        t = time.perf_counter()
+        try:
+            yield
+        finally:
+            if self._ann is not None:
+                self._t0 += time.perf_counter() - t
+
     def __exit__(self, *exc) -> bool:
         ann = self._ann
         if ann is not None:

@@ -97,6 +97,13 @@ _M_LOCKED_TAKES = obsm.counter(
 _M_TAKE_LOOKS = obsm.counter(
     "dngd_session_take_looks_total",
     "Looks at the source's sequence number spent in the end-of-turn wait")
+_M_EARLY_COLLECTS = obsm.counter(
+    "dngd_session_early_collects_total",
+    "Turns that collected the frame before BETWEEN the halves of their own "
+    "frame's submit (behind the colour conversion, in front of the "
+    "dispatch), because the device had finished it by then; over "
+    "dngd_encoder_frames_total: the share of frames whose way to the "
+    "client lost the next frame's dispatch")
 _M_TURN_MS = obsm.histogram(
     "dngd_session_turn_ms",
     "A turn of the session thread that took a frame, from its top to the "
@@ -112,13 +119,14 @@ _M_READY_WAIT_MS = obsm.histogram(
     "thread to begin its collect, one sample a collected frame: the "
     "collect's start less the first look that found the frame's prefix "
     "ready (H264Encoder.token_ready; looks at the turn's end, at every "
-    "look of the end-of-turn wait, at the next turn's top and at the "
-    "collect's start).  A FLOOR: the true wait is longer by up to the "
-    "distance to the look before (the wait's sleep; 0.5 ms once the looks "
-    "run; the whole submit between a turn's top and its collect's "
-    "start).  0 where the thread waited for the device instead "
-    "(dngd_stage_pull_ms has that side).  High: latency that collecting "
-    "sooner, or one frame in flight instead of two, would take out",
+    "look of the end-of-turn wait, at the next turn's top, between the "
+    "halves of a two-part submit and at the collect's start).  A FLOOR: "
+    "the true wait is longer by up to the distance to the look before "
+    "(the wait's sleep; 0.5 ms once the looks run; the colour conversion "
+    "between a turn's top and the look behind it).  0 where the thread "
+    "waited for the device instead (dngd_stage_pull_ms has that side).  "
+    "High: latency that collecting sooner, or one frame in flight instead "
+    "of two, would take out",
     buckets=obst.STAGE_BUCKETS_MS)
 M_IDR_REQUESTS = obsm.counter(
     "dngd_idr_requests_total",
@@ -340,6 +348,10 @@ class StreamSession:
         # no longer), and when the first yes came
         self._probe = None
         self._ready_at: Optional[float] = None
+        # a turn's open ``encode_submit`` stage, and what a collect made
+        # between the halves of that submit left: (seconds, went on)
+        self._submit_span = None
+        self._early: Optional[tuple] = None
         self._need_frame = False
         # set on a collect failure: suppress delivery of in-flight P
         # frames (they predict from a reference the client never got)
@@ -804,9 +816,10 @@ class StreamSession:
         return False
 
     # Frames in flight: upload/compute/pull overlap.  Frame k is submitted
-    # in turn k and collected in turn k+1 AFTER frame k+1's submit; what
-    # that costs a frame the device finished sooner is priced by
-    # dngd_session_ready_wait_ms (the benchmark's ready_wait_mean_ms).
+    # in turn k and collected in turn k+1: behind frame k+1's colour
+    # conversion and in front of its dispatch where the device has finished
+    # it by then (_collect_between), else after the whole submit.  What the
+    # wait costs a finished frame is priced by dngd_session_ready_wait_ms.
     PIPELINE_DEPTH = 2
     # The end of a turn that has time left (_await_frame): asleep until a
     # guard short of the refresh, then a look at the source every step.
@@ -877,13 +890,14 @@ class StreamSession:
         self._probe = (functools.partial(ask, token)
                        if token is not None and ask is not None else None)
 
-    def _probe_ready(self) -> Optional[bool]:
+    def _probe_ready(self, always: bool = False) -> Optional[bool]:
         """One look at the oldest frame in flight: has the device finished
         it?  The first yes is stamped and ends the asking, as does an
-        encoder that cannot say (None).  Nothing is asked with tracing
-        off, or once the answer is known."""
+        encoder that cannot say (None).  Nothing is asked once the answer
+        is known, nor with tracing off unless ``always`` (the look a
+        turn's order rests on)."""
         probe = self._probe
-        if probe is None or not obst.enabled():
+        if probe is None or not (always or obst.enabled()):
             return None
         ready = probe()
         if ready is None or ready:
@@ -903,8 +917,147 @@ class StreamSession:
             return (tc - self._ready_at) * 1e3
         return None if self._probe_ready() is None else 0.0
 
+    def _collect_oldest(self, pending: list) -> bool:
+        """Collect the oldest frame in flight, mux it and hand it on.
+        False where the turn ends here and now (the collect failed, or the
+        frame is a stale P in front of the resync IDR)."""
+        tc = time.perf_counter()
+        token, frame_pts, fid, marks, sub_ms = pending.pop(0)
+        ready_ms = self._ready_wait_ms(tc)
+        self._watch(pending[0][0] if pending else None)
+        try:
+            spec = rfaults.fire("collect_timeout")
+            if spec is not None:
+                if spec.get("mode") == "slow":
+                    # sustained-budget-breach injection: inflate
+                    # the collect stage without dropping frames
+                    time.sleep(
+                        float(spec.get("delay_ms", 50.0)) / 1e3)
+                else:
+                    raise TimeoutError(
+                        "fault injection: collect_timeout")
+            with obst.stage("encode_collect"):
+                ef = self.encoder.encode_collect(token)
+        except Exception:
+            # Transient device/transfer failure: drop this frame,
+            # keep the session alive (supervisord-style resilience).
+            # P tokens already in flight predict from a reference
+            # the client will now never decode — deliver nothing
+            # until the encoder's forced-IDR resync arrives.
+            log.exception("encode_collect failed; dropping frame")
+            _M_COLLECT_FAIL.inc()
+            self._drop_until_key = True
+            # the encoder forces its own IDR when ITS collect
+            # failed; a failure raised before reaching it (device
+            # RPC timeout, injected collect_timeout) needs the
+            # session to request the resync — idempotent either
+            # way, and rate-limited/deduped against PLI and the
+            # ladder rung (a deferred grant lands via _idr_tick)
+            self.request_idr("resync")
+            return False
+        t_col = time.perf_counter()
+        collect_ms = (t_col - tc) * 1e3
+        self._collect_ms.append(collect_ms)
+        _M_COLLECT_MS.observe(collect_ms)
+        if ready_ms is not None:
+            _M_READY_WAIT_MS.observe(ready_ms)
+        marks.append(("device-collect", t_col))
+        if self._drop_until_key:
+            if not ef.keyframe:
+                return False    # stale pre-failure P frame
+            self._drop_until_key = False
+        for fn in list(self._au_listeners):
+            try:
+                fn(ef.data, ef.keyframe, frame_pts)
+            except Exception:
+                log.exception("AU listener failed")
+        # closes the stage the encoder's Annex-B assembly opened
+        with obst.stage("assemble"):
+            frag = (self.muxer.fragment(ef.data,
+                                        keyframe=ef.keyframe,
+                                        pts_ms=frame_pts // 90)
+                    if self.muxer is not None else ef.data)
+        # the loop's tail for a delivered frame, one span: counters,
+        # the hand-over to the event loop, journey, marks, content
+        # record, energy gauges
+        with obst.stage("publish"):
+            marks.append(("bitstream", time.perf_counter()))
+            self.stats.record_frame(ef.encode_ms, len(frag))
+            _M_FRAMES.inc()
+            if ef.keyframe:
+                _M_KEYFRAMES.inc()
+            _M_BYTES.inc(len(frag))
+            self._post(frag, ef.keyframe, fid)
+            t_pub = time.perf_counter()
+            marks.append(("publish", t_pub))
+            # journey: publish + the encoder's chunk/shard identity
+            # (device span amortizes over the chunk at export);
+            # device_ms = this frame's own submit span + collect
+            jmeta = (self.encoder.pop_journey_meta()
+                     if hasattr(self.encoder, "pop_journey_meta")
+                     else None)
+            self.journeys.complete(
+                fid, t_pub,
+                device_ms=collect_ms + sub_ms,
+                meta=jmeta)
+            # pts is the cross-track key: the webrtc 'rtp-sent' span
+            # for this frame carries the identical pts value;
+            # session/chunk/shard meta labels the Chrome-trace lane
+            tmeta = [("session", self.journeys.session)]
+            if jmeta and jmeta.get("chunk_len", 1) > 1:
+                tmeta += [("chunk", jmeta["chunk_id"]),
+                          ("slot", jmeta["slot"])]
+            if jmeta and jmeta.get("shards", 1) > 1:
+                tmeta.append(("shards", jmeta["shards"]))
+            self._tracer.record_marks(fid, marks, pts=frame_pts,
+                                      meta=tuple(tmeta))
+            # content & quality plane (obs/content): the encoder's
+            # in-graph stats for this frame, if one was sampled
+            cstats = (self.encoder.pop_content_stats()
+                      if hasattr(self.encoder, "pop_content_stats")
+                      else None)
+            if cstats is not None:
+                try:
+                    from ..obs.content import PLANE as _content
+                    _content.record(self.journeys.session, cstats)
+                except Exception:
+                    log.exception("content stats record failed")
+            self._last_tick = time.monotonic()   # delivered = progress
+            # energy-proxy gauges on a ~2 s cadence at 60 fps: the
+            # read is two getrusage fields, publish is two gauge sets
+            self._energy_frames += 1
+            if self._energy_frames >= 120:
+                try:
+                    self._energy.publish(
+                        self._energy_frames,
+                        tune=getattr(self.encoder, "tune", "off"))
+                except Exception:
+                    pass
+                self._energy.reset()
+                self._energy_frames = 0
+        return True
+
+    def _collect_between(self, pending: list) -> None:
+        """Between the halves of the turn's own submit (the encoder calls
+        it, H264Encoder.encode_submit: the planes are made and the device
+        has been handed nothing of the frame yet).  A frame that is owed
+        its collect and that the device has FINISHED goes out now, in front
+        of the dispatch (5 ms at 1080p) instead of behind it; one not
+        finished waits behind it as before, so that no pull ever keeps the
+        device waiting for its next frame.  The look is taken with tracing
+        on or off."""
+        if (pending and len(pending) >= self.PIPELINE_DEPTH - 1
+                and (self._ready_at is not None
+                     or self._probe_ready(always=True))):
+            _M_EARLY_COLLECTS.inc()
+            with self._submit_span.suspended():
+                t_early = time.perf_counter()
+                went_on = self._collect_oldest(pending)
+                self._early = (time.perf_counter() - t_early, went_on)
+
     def _run(self) -> None:
         pending: list = []                   # submitted tokens, oldest first
+        between = functools.partial(self._collect_between, pending)
         while not self._stop.is_set():
             # re-read each iteration: the degrade ladder caps the rate live
             rate = max(self.cfg.refresh, 1)
@@ -976,6 +1129,10 @@ class StreamSession:
                 # client's ack (or via the peer's RTCP seq mapping)
                 self.journeys.mint(fid, pts=capture_pts, t_capture=t0)
                 t_cap = time.perf_counter()
+                # an encoder whose submit comes in two halves calls
+                # ``between`` behind the first (H264Encoder.encode_submit)
+                enc, self._early = self.encoder, None
+                two_part = hasattr(enc, "between_halves")
                 try:
                     if rfaults.fire("device_submit_error") is not None:
                         raise RuntimeError(
@@ -987,8 +1144,10 @@ class StreamSession:
                         raise RuntimeError(
                             "fault injection: device_preempt "
                             "(device revoked)")
-                    with obst.stage("encode_submit"):
-                        token = self.encoder.encode_submit(rgb)
+                    if two_part:
+                        enc.between_halves = between
+                    with obst.stage("encode_submit") as self._submit_span:
+                        token = enc.encode_submit(rgb)
                 except Exception:
                     # One failed submit drops one frame (nothing is in
                     # flight for it); a consecutive run — a device that
@@ -1022,16 +1181,24 @@ class StreamSession:
                     self._need_frame = True     # retry the capture
                     time.sleep(frame_interval)
                     continue
+                finally:
+                    if two_part:
+                        enc.between_halves = None
+                early_s, went_on = self._early or (0.0, True)
                 self._submit_breaker.record_success()
                 t_sub = time.perf_counter()
                 # marks flow to the trace ring at publish; span names
                 # are derived at export time (no per-frame formatting)
+                # (behind an early collect the span that ends on
+                # "device-submit" holds that collect too; the frame's own
+                # submit goes beside the marks, for its journey)
                 pending.append((token, capture_pts, fid,
                                 [("capture", t0), ("captured", t_cap),
-                                 ("device-submit", t_sub)]))
+                                 ("device-submit", t_sub)],
+                                (t_sub - t_cap - early_s) * 1e3))
                 if len(pending) == 1:
                     self._watch(token)
-                submit_ms = (t_sub - t0) * 1e3
+                submit_ms = (t_sub - t0 - early_s) * 1e3
                 self._submit_ms.append(submit_ms)
                 _M_SUBMIT_MS.observe(submit_ms)
                 # dispatch stage (obs/budget): Python->device crossings
@@ -1043,125 +1210,14 @@ class StreamSession:
                     else None
                 if disp is not None:
                     obsb.LEDGER.record_dispatch(disp[0], disp[1])
+                if not went_on:
+                    continue
             # Collect the oldest frame once the pipeline is full (or the
             # source went quiet — drain so its frames aren't stranded).
             if pending and (len(pending) >= self.PIPELINE_DEPTH
                             or not changed):
-                tc = time.perf_counter()
-                token, frame_pts, fid, marks = pending.pop(0)
-                ready_ms = self._ready_wait_ms(tc)
-                self._watch(pending[0][0] if pending else None)
-                try:
-                    spec = rfaults.fire("collect_timeout")
-                    if spec is not None:
-                        if spec.get("mode") == "slow":
-                            # sustained-budget-breach injection: inflate
-                            # the collect stage without dropping frames
-                            time.sleep(
-                                float(spec.get("delay_ms", 50.0)) / 1e3)
-                        else:
-                            raise TimeoutError(
-                                "fault injection: collect_timeout")
-                    with obst.stage("encode_collect"):
-                        ef = self.encoder.encode_collect(token)
-                except Exception:
-                    # Transient device/transfer failure: drop this frame,
-                    # keep the session alive (supervisord-style resilience).
-                    # P tokens already in flight predict from a reference
-                    # the client will now never decode — deliver nothing
-                    # until the encoder's forced-IDR resync arrives.
-                    log.exception("encode_collect failed; dropping frame")
-                    _M_COLLECT_FAIL.inc()
-                    self._drop_until_key = True
-                    # the encoder forces its own IDR when ITS collect
-                    # failed; a failure raised before reaching it (device
-                    # RPC timeout, injected collect_timeout) needs the
-                    # session to request the resync — idempotent either
-                    # way, and rate-limited/deduped against PLI and the
-                    # ladder rung (a deferred grant lands via _idr_tick)
-                    self.request_idr("resync")
+                if not self._collect_oldest(pending):
                     continue
-                t_col = time.perf_counter()
-                collect_ms = (t_col - tc) * 1e3
-                self._collect_ms.append(collect_ms)
-                _M_COLLECT_MS.observe(collect_ms)
-                if ready_ms is not None:
-                    _M_READY_WAIT_MS.observe(ready_ms)
-                marks.append(("device-collect", t_col))
-                if self._drop_until_key:
-                    if not ef.keyframe:
-                        continue        # stale pre-failure P frame
-                    self._drop_until_key = False
-                for fn in list(self._au_listeners):
-                    try:
-                        fn(ef.data, ef.keyframe, frame_pts)
-                    except Exception:
-                        log.exception("AU listener failed")
-                # closes the stage the encoder's Annex-B assembly opened
-                with obst.stage("assemble"):
-                    frag = (self.muxer.fragment(ef.data,
-                                                keyframe=ef.keyframe,
-                                                pts_ms=frame_pts // 90)
-                            if self.muxer is not None else ef.data)
-                # the loop's tail for a delivered frame, one span: counters,
-                # the hand-over to the event loop, journey, marks, content
-                # record, energy gauges
-                with obst.stage("publish"):
-                    marks.append(("bitstream", time.perf_counter()))
-                    self.stats.record_frame(ef.encode_ms, len(frag))
-                    _M_FRAMES.inc()
-                    if ef.keyframe:
-                        _M_KEYFRAMES.inc()
-                    _M_BYTES.inc(len(frag))
-                    self._post(frag, ef.keyframe, fid)
-                    t_pub = time.perf_counter()
-                    marks.append(("publish", t_pub))
-                    # journey: publish + the encoder's chunk/shard identity
-                    # (device span amortizes over the chunk at export);
-                    # device_ms = this frame's own submit span + collect
-                    jmeta = (self.encoder.pop_journey_meta()
-                             if hasattr(self.encoder, "pop_journey_meta")
-                             else None)
-                    self.journeys.complete(
-                        fid, t_pub,
-                        device_ms=collect_ms
-                        + (marks[2][1] - marks[1][1]) * 1e3,
-                        meta=jmeta)
-                    # pts is the cross-track key: the webrtc 'rtp-sent' span
-                    # for this frame carries the identical pts value;
-                    # session/chunk/shard meta labels the Chrome-trace lane
-                    tmeta = [("session", self.journeys.session)]
-                    if jmeta and jmeta.get("chunk_len", 1) > 1:
-                        tmeta += [("chunk", jmeta["chunk_id"]),
-                                  ("slot", jmeta["slot"])]
-                    if jmeta and jmeta.get("shards", 1) > 1:
-                        tmeta.append(("shards", jmeta["shards"]))
-                    self._tracer.record_marks(fid, marks, pts=frame_pts,
-                                              meta=tuple(tmeta))
-                    # content & quality plane (obs/content): the encoder's
-                    # in-graph stats for this frame, if one was sampled
-                    cstats = (self.encoder.pop_content_stats()
-                              if hasattr(self.encoder, "pop_content_stats")
-                              else None)
-                    if cstats is not None:
-                        try:
-                            from ..obs.content import PLANE as _content
-                            _content.record(self.journeys.session, cstats)
-                        except Exception:
-                            log.exception("content stats record failed")
-                    self._last_tick = time.monotonic()   # delivered = progress
-                    # energy-proxy gauges on a ~2 s cadence at 60 fps: the
-                    # read is two getrusage fields, publish is two gauge sets
-                    self._energy_frames += 1
-                    if self._energy_frames >= 120:
-                        try:
-                            self._energy.publish(
-                                self._energy_frames,
-                                tune=getattr(self.encoder, "tune", "off"))
-                        except Exception:
-                            pass
-                        self._energy.reset()
-                        self._energy_frames = 0
 
             # continuity checkpoint on its cadence (the due-check is one
             # clock read).  Mid-pipeline state is fine: counters may run

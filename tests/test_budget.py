@@ -414,7 +414,8 @@ def test_frame_feed_matches_session_mark_names():
 
     from docker_nvidia_glx_desktop_tpu.web import session
 
-    src = inspect.getsource(session.StreamSession._run)
+    src = inspect.getsource(session.StreamSession._run) + inspect.getsource(
+        session.StreamSession._collect_oldest)
     for mark in ("capture", "captured", "device-submit",
                  "device-collect", "bitstream", "publish"):
         assert f'("{mark}"' in src, f"mark {mark!r} gone from session"

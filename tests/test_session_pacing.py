@@ -98,8 +98,9 @@ def counters():
 
 def drive(monkeypatch, cost=lambda n: 0.010, seconds=10.0, overshoot=0.00006,
           subscribers=True, end_of_turn=None, fps_cap=None, static=False,
-          work=Work):
-    """Run the loop for ``seconds`` of the fake clock; what it took and when."""
+          work=Work, prepare=None):
+    """Run the loop for ``seconds`` of the fake clock; what it took and
+    when.  ``prepare(sess)``: a test's own hooks, before the run."""
     clock = FakeTime(overshoot)
     monkeypatch.setattr(session_mod, "time", clock)
     cfg = from_env({"PASSWD": "pw", "SIZEW": "64", "SIZEH": "48",
@@ -116,11 +117,16 @@ def drive(monkeypatch, cost=lambda n: 0.010, seconds=10.0, overshoot=0.00006,
         sess.subscribe()
     if end_of_turn is not None:
         monkeypatch.setattr(StreamSession, "_await_frame", end_of_turn)
+    if prepare is not None:
+        prepare(sess)
     before = counters()
     try:
         sess._run()
     finally:
         sess.close()
+        # the process-wide ring keeps no frame of a made-up clock: full, it
+        # would count every later test's frames as overwritten
+        sess._tracer.clear()
     locked, looks = (b - a for a, b in zip(before, counters()))
     return types.SimpleNamespace(
         clock=clock, source=source, taken=work.taken, work=work,
@@ -391,3 +397,162 @@ def test_the_turn_histogram_has_one_sample_a_turn_that_took_a_frame(
     assert n == len(run.taken)
     # 10 ms of work a turn (7 in the first, a submit alone), not the wait
     assert (turn.sum - s0) / n == pytest.approx(10.0, abs=0.1)
+
+
+# -- the order of a turn: a finished frame goes out between the halves of the
+# -- next frame's submit (PR 39) ---------------------------------------------
+
+def two_part(device_s, one_piece=False, depth=2):
+    """An encoder front whose submit comes in two halves (0.3 and 0.4 of the
+    turn's cost; the collect is the other 0.3), with the caller's
+    ``between_halves`` called between them, and whose device finishes a
+    frame ``device_s`` after its dispatch (None: it cannot say).  Every
+    call is noted in order.  ``one_piece``: the hook is there and this
+    submit calls nothing (a ring's)."""
+
+    class TwoPart(Work):
+        pipeline_depth = depth
+        between_halves = None
+
+        def __init__(self, clock, cost):
+            super().__init__(clock, cost)
+            self.calls, self.done_at, self.asked = [], {}, 0
+
+        def spend(self, share):
+            self.clock.t += share * self.cost(len(self.taken))
+
+        def encode_submit(self, k):
+            self.taken.append(k)
+            self.calls.append(("begin", k))
+            self.spend(0.3)
+            if not one_piece:
+                self.between_halves()
+            self.calls.append(("dispatch", k))
+            self.spend(0.4)
+            self.done_at[k] = self.clock.t + (device_s or 0.0)
+            return k
+
+        def token_ready(self, k):
+            self.asked += 1
+            if device_s is None:
+                return None
+            return self.clock.t >= self.done_at[k]
+
+        def encode_collect(self, k):
+            self.calls.append(("collect", k))
+            assert self.between_halves is None or \
+                self.calls[-2][0] == "begin"       # set for the call alone
+            return super().encode_collect(k)
+
+    return TwoPart
+
+
+def early_collects():
+    return session_mod._M_EARLY_COLLECTS.value
+
+
+def kinds_by_turn(calls):
+    """The calls of each turn, as a string: a turn begins with its frame's
+    ``begin``."""
+    turns = []
+    for what, _ in calls:
+        if what == "begin" or not turns:
+            turns.append([])
+        turns[-1].append(what)
+    return [" ".join(t) for t in turns]
+
+
+def test_a_finished_frame_is_collected_between_the_halves(monkeypatch):
+    """10 ms of work a turn and a device that needs 2 ms: frame ``k`` is
+    finished long before turn ``k+1`` has its planes, so every turn but the
+    first runs begin(k+1), collect(k), dispatch(k+1)."""
+    n0 = early_collects()
+    run = drive(monkeypatch, seconds=2.0, work=two_part(0.002))
+    turns = kinds_by_turn(run.work.calls)
+    assert turns[0] == "begin dispatch" and len(turns) >= 118
+    assert set(turns[1:]) == {"begin collect dispatch"}
+    assert early_collects() - n0 == len(turns) - 1
+    # each collect is of the frame before the one begun
+    calls = run.work.calls
+    for (a, k1), (b, k0), (c, k2) in zip(calls[2::3], calls[3::3],
+                                         calls[4::3]):
+        assert (a, b, c) == ("begin", "collect", "dispatch")
+        assert k1 == k2 and k0 == run.taken[run.taken.index(k1) - 1]
+    # and the order moved no take
+    plain = drive(monkeypatch, seconds=2.0)
+    assert run.taken == plain.taken
+
+
+@pytest.mark.parametrize("work, turn", [
+    (two_part(0.030), "begin dispatch collect"),
+    (two_part(None), "begin dispatch collect"),
+    (two_part(0.002, one_piece=True), "begin dispatch collect"),
+    (probed(0.002), None),
+], ids=["not_finished", "cannot_say", "one_piece_submit", "no_hook"])
+def test_any_other_turn_keeps_todays_order(monkeypatch, work, turn):
+    n0 = early_collects()
+    run = drive(monkeypatch, seconds=2.0, work=work)
+    assert early_collects() == n0 and len(run.taken) >= 100
+    if turn is None:         # an encoder without the hook: submit, collect
+        w = run.work         # (a collect starts where the next submit ended)
+        assert all(w.collected[k] >= w.done_at[nxt] - 0.002 - 1e-9
+                   for k, nxt in zip(run.taken, run.taken[1:])
+                   if k in w.collected)
+    else:
+        turns = kinds_by_turn(run.work.calls)
+        assert set(turns[1:]) == {turn}, set(turns)
+
+
+def test_a_collect_not_yet_owed_is_not_made_early(monkeypatch):
+    """Three frames in flight: the turn that begins the second owes no
+    collect, whatever the device says; the third turn owes the first
+    frame's, and makes it between its halves."""
+    run = drive(monkeypatch, seconds=1.0, work=two_part(0.002, depth=3))
+    turns = kinds_by_turn(run.work.calls)
+    assert turns[:3] == ["begin dispatch", "begin dispatch",
+                         "begin collect dispatch"]
+    assert set(turns[2:]) == {"begin collect dispatch"}
+    assert [k for what, k in run.work.calls if what == "collect"] == \
+        run.taken[:len(run.taken) - 2]
+
+
+def test_the_drain_of_a_quiet_source_is_no_early_collect(monkeypatch):
+    n0 = early_collects()
+    run = drive(monkeypatch, seconds=1.0, static=True, work=two_part(0.002))
+    assert run.work.calls == [("begin", 0), ("dispatch", 0), ("collect", 0)]
+    assert early_collects() == n0
+
+
+def test_the_order_does_not_depend_on_tracing(monkeypatch):
+    from docker_nvidia_glx_desktop_tpu.obs import trace as obst
+    on = drive(monkeypatch, seconds=1.0, work=two_part(0.002))
+    n0 = early_collects()
+    obst.set_enabled(False)
+    try:
+        off = drive(monkeypatch, seconds=1.0, work=two_part(0.002))
+        slow = drive(monkeypatch, seconds=1.0, work=two_part(0.030))
+    finally:
+        obst.set_enabled(True)
+    assert off.work.calls == on.work.calls
+    assert early_collects() - n0 == len(off.taken) - 1
+    # one look a turn, the order's own, and none of the others
+    assert off.work.asked == len(off.taken) - 1
+    assert set(kinds_by_turn(slow.work.calls)[1:]) == {
+        "begin dispatch collect"}
+
+
+@pytest.mark.parametrize("device_s", [0.002, 0.030],
+                         ids=["early", "late"])
+def test_the_submit_sample_holds_no_part_of_the_collect(monkeypatch,
+                                                        device_s):
+    """7 ms of the turn's 10 are the submit's halves, 3 the collect: the
+    histogram reads 7 whichever order the turn took, and the turn 10."""
+    sub = session_mod._M_SUBMIT_MS._default
+    turn = session_mod._M_TURN_MS._default
+    before = (sub.count, sub.sum, turn.count, turn.sum)
+    run = drive(monkeypatch, seconds=2.0, work=two_part(device_s))
+    n, ms, tn, tms = (b - a for a, b in zip(
+        before, (sub.count, sub.sum, turn.count, turn.sum)))
+    assert n == tn == len(run.taken)
+    assert ms / n == pytest.approx(7.0, abs=0.05)
+    assert tms / tn == pytest.approx(10.0, abs=0.1)

@@ -279,7 +279,9 @@ def test_a_collected_frame_is_one_sample_of_the_ready_wait(served):
     assert len(served["answers"]) <= 6 * got["encode_submit"]
 
 
-def test_with_tracing_off_no_new_family_moves_and_nothing_is_asked():
+def test_with_tracing_off_no_new_family_moves_and_only_the_order_asks():
+    """The one look left is the one a turn's order rests on (web/session.py:
+    between the halves of the submit), once a frame at most."""
     sess = small_session()
     posted, done = [], threading.Event()
     sess._post = lambda *a, **k: (posted.append(1),
@@ -296,7 +298,7 @@ def test_with_tracing_off_no_new_family_moves_and_nothing_is_asked():
         got = delta(before)
     finally:
         obst.set_enabled(True)
-    assert not answers and len(posted) >= 5
+    assert len(posted) >= 5 and len(answers) <= len(posted) + 3
     new = obst.TURN_STAGES + TURN_FAMILIES
     assert {k: got[k] for k in new} == dict.fromkeys(new, 0)
 
