@@ -67,6 +67,34 @@ _M_MESH_SHARDS = obsm.gauge(
     "dngd_mesh_shards",
     "Chips one session's macroblock rows are spread over "
     "(ENCODER_SPATIAL_SHARDS as resolved; 1 = one chip)")
+_M_MASK_ROWS = obsm.counter(
+    "dngd_mask_rows_total",
+    "Macroblock rows of the P frames a damage-mask session planned "
+    "(DNGD_DAMAGE_MASK; the frame's rows, every planned P frame; an IDR "
+    "is planned by nothing and counted by none of the dngd_mask_ families)")
+_M_MASK_ROWS_DAMAGED = obsm.counter(
+    "dngd_mask_rows_damaged_total",
+    "Rows of those in which the damage grid found a changed macroblock "
+    "(the plan's worklist before padding; a wholly calm frame still "
+    "names row 0, a frame past the ladder's top names every row)")
+_M_MASK_ROWS_CODED = obsm.counter(
+    "dngd_mask_rows_coded_total",
+    "Rows of those the device was handed: the worklist padded to its "
+    "power-of-two bucket on a frame of the row program, every row on a "
+    "frame of the full-frame program")
+_M_MASK_ROWS_GATHERED = obsm.counter(
+    "dngd_mask_rows_gathered_total",
+    "Rows the ROW program gathered and scattered back (its bucket, a "
+    "frame; nothing on a frame of the full-frame program): "
+    "dngd_mask_rows_coded_total less the dense frames' rows")
+_M_MASK_FRAMES = obsm.counter(
+    "dngd_mask_frames_total",
+    "Planned P frames by the program that coded them: rows = "
+    "jit_encode_p_rows_b<bucket> over the worklist, dense = the "
+    "full-frame P program (the plan reached the ladder's top)",
+    ("program",))
+_M_MASK_FRAMES_ROWS = _M_MASK_FRAMES.labels("rows")
+_M_MASK_FRAMES_DENSE = _M_MASK_FRAMES.labels("dense")
 _M_CABAC_DENSE = _M_CABAC_FALLBACK.labels("dense")
 _M_CABAC_PYTHON = _M_CABAC_FALLBACK.labels("python")
 
@@ -86,6 +114,19 @@ def _note_h2d(*arrays) -> None:
     on the device already crosses nothing)."""
     _M_H2D_BYTES.inc(sum(a.nbytes for a in arrays
                          if isinstance(a, np.ndarray)))
+
+
+def _note_mask_plan(plan) -> None:
+    """A P frame's damage plan on its way to the device: its rows, the
+    damaged and the coded ones, and the program that codes it."""
+    _M_MASK_ROWS.inc(plan.total)
+    _M_MASK_ROWS_DAMAGED.inc(len(plan.rows))
+    _M_MASK_ROWS_CODED.inc(plan.bucket)
+    if plan.full:
+        _M_MASK_FRAMES_DENSE.inc()
+    else:
+        _M_MASK_FRAMES_ROWS.inc()
+        _M_MASK_ROWS_GATHERED.inc(plan.bucket)
 
 
 def _note_entropy_overflow(what: str) -> None:
@@ -1250,9 +1291,11 @@ class H264Encoder(Encoder):
             # damage-gating twin: the ingest luma chain advances on
             # EVERY host-converted frame (IDR, ring-staged, per-frame
             # alike) so the gating grid always diffs strictly
-            # frame-to-frame — exactly the content plane's semantics
+            # frame-to-frame — exactly the content plane's semantics.
+            # (The chain keeps the planes themselves: each conversion
+            # makes new ones and nothing writes to them afterwards.)
             self._damage_prev_y = self._damage_cur_y
-            self._damage_cur_y = np.array(planes[0], copy=True)
+            self._damage_cur_y = planes[0]
         return planes
 
     def _encode_cavlc_device(self, rgb, idr_pic_id: int) -> bytes:
@@ -1385,8 +1428,12 @@ class H264Encoder(Encoder):
         libtpu does not survive (:meth:`prewarm`).  On a spatial mesh
         the scratch encoder runs this one's mesh and step programs, and
         the slices are those of the stacked per-shard buffers.  Returns
-        the slices compiled; 0 on every other path (the CAVLC pull
-        ladder is content's to walk: 64 KiB steps of a 46 KB frame)."""
+        the slices compiled.  A damage-mask session compiles its row
+        programs here instead (:meth:`_warm_row_buckets`); 0 on every
+        other path (the CAVLC pull ladder is content's to walk: 64 KiB
+        steps of a 46 KB frame)."""
+        if self.mode == "cavlc" and self.entropy == "device":
+            return self._warm_row_buckets()
         if self.entropy != "cabac" or self.mode != "cavlc":
             return 0
         nx = self._spatial_nx
@@ -1420,6 +1467,58 @@ class H264Encoder(Encoder):
         log.info("CABAC pull ladder: %d slices of %d-word buffers in "
                  "%.1f s", n, buf.shape[-1], time.perf_counter() - t0)
         return n
+
+    def _warm_row_buckets(self) -> int:
+        """Compile everything a damage-mask session's frames can ask for,
+        before frames are served: the IDR program, the full-frame P
+        program with its loop filter (the ladder's top), the row program
+        of every bucket under it (``ops/damage_mask.bucket_ladder``: 1, 2,
+        4 ... 64 at 100 rows; qp is traced, so one a bucket serves the
+        whole rate ladder) and every prefix slice the pull ladder can cut
+        off the flat buffer, which has one length for every bucket and
+        for the dense frame.  One scratch encoder, a frame a program, one
+        after the other (never side by side: :meth:`prewarm`).  Returns
+        programs and slices compiled; 0 where the mask is off, and where
+        qp is static (the hq tiers: a program a bucket AND a rung, which
+        :meth:`prewarm` walks at the calm frame's bucket alone)."""
+        if not (self.damage_mask and self._dyn_qp and self.host_color
+                and self.gop > 1) or self.keep_recon \
+                or self._spatial_nx > 1 or self._ring_chunk:
+            return 0
+        from ..ops import cavlc_device
+        from ..ops import damage_mask as dmg
+
+        t0 = time.perf_counter()
+        scratch = H264Encoder(
+            self.width, self.height, qp=self.qp, mode=self.mode,
+            entropy=self.entropy, host_color=True, gop=self.gop,
+            deblock=self.deblock, intra_modes=self.i16_modes,
+            superstep_chunk=0, spatial_shards=1, tune=self.tune,
+            damage_mask=True, row_align=self.row_align)
+        rgb = np.zeros((self.height, self.width, 3), np.uint8)
+        scratch.encode(rgb)                       # the IDR
+        planes = scratch._planes_device(rgb)
+        if not isinstance(planes[0], np.ndarray):
+            return 0                              # no host colour: no plan
+        total = self.mb_h
+        ladder = dmg.bucket_ladder(total)
+        for bucket in [total] + ladder:           # the dense program first
+            rows = np.arange(bucket, dtype=np.int32)
+            sub = scratch._submit_p_device(
+                *planes, self.qp,
+                damage_plan=dmg.RowPlan(rows, rows, bucket, total, 1.0))
+            scratch._collect_p_device(sub)
+        flat, step, slices = sub[4], self._PULL_BUCKET, 0
+        base = cavlc_device.META_WORDS * 4
+        while base + slices * step < flat.shape[0]:
+            slices += 1
+            flat[:base + slices * step].block_until_ready()
+        log.info("damage mask: %d row programs (buckets %s of %d rows) "
+                 "beside the IDR and the full-frame P program, %d prefix "
+                 "slices of the %d-byte flat buffer, in %.1f s",
+                 len(ladder), ladder, total, slices, flat.shape[0],
+                 time.perf_counter() - t0)
+        return len(ladder) + 2 + slices
 
     def prewarm_async(self, qps=None):
         """Run :meth:`prewarm` in a daemon thread; returns (thread,
@@ -2018,13 +2117,18 @@ class H264Encoder(Encoder):
 
         if self._spatial_nx > 1:
             return self._sp_submit_p(y, cb, cr, qp, frame_num)
-        # an explicit plan (ring flush) carries the STAGE-time damage
-        # baseline — the twin chain has moved past these frames
+        # an explicit plan carries the damage baseline of when it was
+        # made: the first half of encode_submit's (made there, so that the
+        # session's collect between the halves has only the dispatch
+        # behind it), or a ring flush's STAGE-time one — the twin chain
+        # has moved past those frames
         plan = (damage_plan if damage_plan is not None
                 else self._damage_plan(y))
-        if plan is not None and not plan.full:
-            return self._submit_p_masked(y, cb, cr, qp, frame_num,
-                                         next_y, plan)
+        if plan is not None:
+            _note_mask_plan(plan)
+            if not plan.full:
+                return self._submit_p_masked(y, cb, cr, qp, frame_num,
+                                             next_y, plan)
         with obst.stage("dispatch") as span:
             frame_num = self._frame_num if frame_num is None else frame_num
             hv, hl = self._p_hdr_slots(frame_num, qp - self.qp)
@@ -2117,15 +2221,19 @@ class H264Encoder(Encoder):
     # compacts each P frame to its damaged MB rows; untouched rows ship
     # as host-cached all-skip slices whose decoder reconstruction is
     # the reference rows bit-exactly.  One submit event per frame
-    # either way — dispatch-crossings-per-frame is unchanged.
+    # either way — dispatch-crossings-per-frame is unchanged — and the
+    # same stages and the same token as the dense P frame: ``damage_grid``
+    # (the plan, in the first half of encode_submit) in front of
+    # ``dispatch``, then ``pull``, ``pull_extra``, ``assemble``.
 
     def _damage_plan(self, y):
         """RowPlan for the CURRENT host-ingested frame, or None when
         the masked path cannot serve it (mask off, device-side ingest,
-        keep_recon debug pulls, non-device entropy).  Feeds the rate
-        controller's damage consumer as a side effect."""
+        keep_recon debug pulls, non-device entropy, a spatial mesh).
+        Feeds the rate controller's damage consumer as a side effect."""
         if (not self.damage_mask or self.mode != "cavlc"
                 or self.entropy != "device" or self.keep_recon
+                or self._spatial_nx > 1      # a mesh gates rows instead
                 or not isinstance(y, np.ndarray)
                 or self._damage_cur_y is None):
             return None
@@ -2133,7 +2241,8 @@ class H264Encoder(Encoder):
         prev = self._damage_prev_y
         if prev is not None and prev.shape != y.shape:
             prev = None                   # post-resize: everything dirty
-        plan = dmg.plan_rows(dmg.damage_grid_np(np.asarray(y), prev))
+        with obst.stage("damage_grid"):
+            plan = dmg.plan_rows(dmg.damage_grid_np(y, prev))
         self._damage_frac = plan.frac
         if self._rate is not None:
             try:
@@ -2196,23 +2305,30 @@ class H264Encoder(Encoder):
         from ..ops import cavlc_device
         from ..ops import damage_mask as dmg
 
-        t0 = time.perf_counter()
-        frame_num = self._frame_num if frame_num is None else frame_num
-        hv, hl = self._p_hdr_slots_np(frame_num, qp - self.qp)
-        flat, ry, rcb, rcr, mv, nnz, levels = dmg.encode_p_rows(
-            jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr),
-            *self._ref, jnp.asarray(plan.padded),
-            jnp.asarray(hv[plan.padded]), jnp.asarray(hl[plan.padded]),
-            qp, tune=self._ktune,
-            next_y=None if next_y is None else jnp.asarray(next_y),
-            p_intra=self._p_intra, deblock=self.deblock)
-        self._count_dispatch(t0)
-        self._ref = (ry, rcb, rcr)
-        self._content_submit(jnp.asarray(y), recon_y=ry)
-        base = cavlc_device.META_WORDS * 4
-        guess = getattr(self, "_p_pull_guess", 2 * self._PULL_BUCKET)
-        prefix = flat[:base + guess]
-        _prefetch_host(prefix)
+        with obst.stage("dispatch") as span:
+            frame_num = self._frame_num if frame_num is None else frame_num
+            hv, hl = self._p_hdr_slots_np(frame_num, qp - self.qp)
+            planes = (jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr))
+            work = (jnp.asarray(plan.padded), jnp.asarray(hv[plan.padded]),
+                    jnp.asarray(hl[plan.padded]))
+            if self._dyn_qp:      # tune=off: one program a row bucket
+                flat, ry, rcb, rcr, mv, nnz, levels = \
+                    dmg.row_step(plan.bucket)(
+                        *planes, *self._ref, *work, np.int32(qp),
+                        tune="off", next_y=None, p_intra=False,
+                        deblock=self.deblock)
+            else:
+                flat, ry, rcb, rcr, mv, nnz, levels = dmg.encode_p_rows(
+                    *planes, *self._ref, *work, qp, tune=self._ktune,
+                    next_y=None if next_y is None else jnp.asarray(next_y),
+                    p_intra=self._p_intra, deblock=self.deblock)
+            self._ref = (ry, rcb, rcr)
+            self._content_submit(planes[0], recon_y=ry)
+            base = cavlc_device.META_WORDS * 4
+            guess = getattr(self, "_p_pull_guess", 2 * self._PULL_BUCKET)
+            prefix = flat[:base + guess]
+            _prefetch_host(prefix)
+        self._count_dispatch(ms=span.ms)
         return ("dmg", qp, frame_num, levels, flat, prefix, mv, plan)
 
     def _collect_p_masked(self, submitted) -> bytes:
@@ -2222,7 +2338,8 @@ class H264Encoder(Encoder):
 
         _, qp, frame_num, levels, flat, prefix, mv, plan = submitted
         base = cavlc_device.META_WORDS * 4
-        buf = np.asarray(prefix)
+        with obst.stage("pull"):
+            buf = np.asarray(prefix)
         meta = cavlc_device.FlatMeta(buf, plan.bucket)
         if meta.overflow:
             _note_entropy_overflow("masked p")
@@ -2231,24 +2348,26 @@ class H264Encoder(Encoder):
             # (untouched rows zero = skip) and host-entropy the WHOLE
             # frame — same bytes the device would have packed, ref
             # chain needs no rewind
-            pulled = {k: np.asarray(v) for k, v in levels.items()}
-            qp_map = pulled.pop("qp_map", None)
-            full_lv, full_mv = dmg.scatter_levels_np(
-                pulled, np.asarray(mv), plan.padded, self.mb_h)
-            full_lv["mv"] = full_mv
-            if qp_map is not None:
-                # untouched (skip) rows never code mb_qp_delta; slice
-                # qp keeps the host coder's chain arithmetic aligned
-                fq = np.full((self.mb_h,) + np.asarray(qp_map).shape[1:],
-                             qp, np.asarray(qp_map).dtype)
-                fq[plan.padded] = np.asarray(qp_map)
-                qp_map = fq
-            self.last_mv = full_mv
-            self._note_qp_map(qp_map, levels=full_lv, slice_qp=qp)
-            return h264_entropy.encode_p_picture(
-                full_lv, frame_num=frame_num, qp_delta=qp - self.qp,
-                deblocking_idc=self._deblock_idc,
-                qp_map=qp_map, slice_qp=qp)
+            with obst.stage("assemble", more=True):
+                pulled = {k: np.asarray(v) for k, v in levels.items()}
+                qp_map = pulled.pop("qp_map", None)
+                full_lv, full_mv = dmg.scatter_levels_np(
+                    pulled, np.asarray(mv), plan.padded, self.mb_h)
+                full_lv["mv"] = full_mv
+                if qp_map is not None:
+                    # untouched (skip) rows never code mb_qp_delta; slice
+                    # qp keeps the host coder's chain arithmetic aligned
+                    fq = np.full(
+                        (self.mb_h,) + np.asarray(qp_map).shape[1:],
+                        qp, np.asarray(qp_map).dtype)
+                    fq[plan.padded] = np.asarray(qp_map)
+                    qp_map = fq
+                self.last_mv = full_mv
+                self._note_qp_map(qp_map, levels=full_lv, slice_qp=qp)
+                return h264_entropy.encode_p_picture(
+                    full_lv, frame_num=frame_num, qp_delta=qp - self.qp,
+                    deblocking_idc=self._deblock_idc,
+                    qp_map=qp_map, slice_qp=qp)
         if meta.qp_sum:
             # meta sums the WORKLIST's effective qps; untouched rows
             # decode at slice qp.  (Padded duplicate rows bias the sum
@@ -2262,11 +2381,14 @@ class H264Encoder(Encoder):
         self._p_pull_guess = -(-max(self._p_pull_hist) // bucket) * bucket
         if need > len(buf) - base:
             extra = -(-need // bucket) * bucket
-            buf = np.asarray(flat[:base + extra])
-        return dmg.assemble_masked_au(
-            buf, meta, plan.rows, self.mb_h, self.mb_w,
-            frame_num=frame_num, qp_delta=qp - self.qp,
-            deblocking_idc=self._deblock_idc)
+            _M_PULL_EXTRA.inc()
+            with obst.stage("pull_extra"):
+                buf = np.asarray(flat[:base + extra])
+        with obst.stage("assemble", more=True):
+            return dmg.assemble_masked_au(
+                buf, meta, plan.rows, self.mb_h, self.mb_w,
+                frame_num=frame_num, qp_delta=qp - self.qp,
+                deblocking_idc=self._deblock_idc)
 
     # ------------------------------------------------------------------
     # Super-step ring: P frames stage HOST-side (no device dispatch at
@@ -2779,17 +2901,18 @@ class H264Encoder(Encoder):
 
         On the per-frame paths the submit is two halves.  The FIRST is
         the frame's index, the GOP's decision, the rate controller's qp
-        reservation and the colour conversion: everything up to the
+        reservation, the colour conversion and, on a damage-mask
+        session, the P frame's row plan (it needs the luma alone, and
+        tells the controller its damage there): everything up to the
         planes, and nothing for the device while the host converts.  The
         SECOND is the dispatch: header slots, H2D, the frame's programs,
         the prefix slice and its prefetch.  ``between_halves``, where the
         caller has set one, is called with nothing between the two;
         whatever it does, the stream is the same bytes (an
         ``encode_collect`` there folds its frame into the rate controller
-        AFTER this frame's qp was reserved, as it does behind the whole
-        submit).  The super-step ring and the damage mask (whose plan
-        feeds the controller from inside the dispatch) submit in one
-        piece and call nothing."""
+        AFTER this frame's qp was reserved and its damage noted, as it
+        does behind the whole submit).  The super-step ring submits in
+        one piece and calls nothing."""
         if self.mode != "cavlc" or self.entropy not in ("device", "cabac"):
             ef = self.encode(rgb)
             self._content_last = None    # sync path: no stats contract
@@ -2823,9 +2946,9 @@ class H264Encoder(Encoder):
             elif not ring:
                 qp = self._eff_qp(keyframe=False)
                 y, cb, cr = self._planes_device(rgb)
+                plan = self._damage_plan(y)
             between = self.between_halves
-            if between is not None and not (self._ring_chunk
-                                            or self.damage_mask):
+            if between is not None and not self._ring_chunk:
                 reserved = self._rate.mark() - n0 \
                     if self._rate is not None else 0
                 t_b = time.perf_counter()
@@ -2847,7 +2970,8 @@ class H264Encoder(Encoder):
             else:
                 kind = "cabac_p" if cabac else "p"
                 sub = (self._submit_cabac_p(y, cb, cr, qp) if cabac
-                       else self._submit_p_device(y, cb, cr, qp))
+                       else self._submit_p_device(y, cb, cr, qp,
+                                                  damage_plan=plan))
                 tok = (kind, idx, t0, False, sub)
         except Exception:
             # this submit's qp reservation (if it got that far) will never
@@ -2869,6 +2993,9 @@ class H264Encoder(Encoder):
     # where each per-frame path's token keeps the device array its collect
     # pulls FIRST (the guessed prefix; models/prefix_pull.py)
     _PREFIX_AT = {"intra": 5, "p": 5, "cabac_intra": 2, "cabac_p": 4}
+    # ... and where a P frame's marked payload does: a mesh's, a damage
+    # mask's row program's
+    _MARKED_PREFIX_AT = {"sp": 6, "sp_bin": 6, "dmg": 5}
 
     def token_ready(self, token) -> Optional[bool]:
         """Whether the device has FINISHED what ``encode_collect(token)``
@@ -2876,7 +3003,7 @@ class H264Encoder(Encoder):
         shard's on a mesh, which is one array).  It asks and nothing
         else: no transfer starts, nothing blocks, nothing compiles, and
         it never raises.  ``None`` where there is nothing to ask: the
-        ring and masked paths, a synchronous token, an array without
+        ring path, a synchronous token, an array without
         ``is_ready``, one deleted or donated since."""
         try:
             at = self._PREFIX_AT.get(token[0])
@@ -2884,9 +3011,9 @@ class H264Encoder(Encoder):
                 return None
             payload = token[4]
             if isinstance(payload[0], str):      # a marked token:
-                if payload[0] not in ("sp", "sp_bin"):
-                    return None                  # masked
-                at = 6                           # a mesh's
+                at = self._MARKED_PREFIX_AT.get(payload[0])
+                if at is None:
+                    return None
             prefix = payload[at]
             # (asked of a deleted array, jaxlib 0.9's is_ready() takes the
             # process down instead of raising)

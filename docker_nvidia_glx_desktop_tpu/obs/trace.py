@@ -53,7 +53,8 @@ from . import metrics as obsm
 __all__ = ["TraceRecorder", "tracer", "tracers", "next_frame_id",
            "export_chrome_trace", "set_enabled", "enabled",
            "dropped_total", "DEFAULT_CAPACITY", "stage", "STAGES",
-           "TURN_STAGES", "STAGE_BUCKETS_MS", "M_WS_SEND_MS"]
+           "CABAC_STAGES", "MESH_STAGES", "MASK_STAGES", "TURN_STAGES",
+           "STAGE_BUCKETS_MS", "M_WS_SEND_MS"]
 
 DEFAULT_CAPACITY = 4096      # spans per recorder (ring; oldest evicted)
 
@@ -106,6 +107,12 @@ CABAC_STAGES = ("engine",)
 # stitched row-wise into one transport buffer (models/h264.py
 # ``_sp_collect_bin``, ops/cabac_binarize.stitch_rows).
 MESH_STAGES = ("stitch",)
+# A damage-mask session's own (DNGD_DAMAGE_MASK), in front of ``dispatch``
+# in the first half of a P frame's submit: the host's damage grid of the
+# frame's luma against the frame before and the row plan made of it
+# (models/h264.py ``_damage_plan``, ops/damage_mask.damage_grid_np); one
+# sample a planned P frame, none on an IDR.
+MASK_STAGES = ("damage_grid",)
 # The rest of a turn of the session thread (PR 38), so that the device's
 # idle gaps fall under a span wherever the host is: ``stats``, the content
 # statistics' pull that ends ``H264Encoder.encode_collect`` (one sample a
@@ -201,12 +208,14 @@ def stage(name: str, more: bool = False) -> _StageSpan:
     closes the stage, which takes the frame's one sample.
 
     Host stages: ``STAGES`` (the frame's own work), ``CABAC_STAGES`` and
-    ``MESH_STAGES`` (inside ``assemble``), ``TURN_STAGES`` (``stats``,
+    ``MESH_STAGES`` (inside ``assemble``), ``MASK_STAGES`` (in front of
+    ``dispatch``), ``TURN_STAGES`` (``stats``,
     ``publish``, ``await``: the rest of the session thread's turn)."""
     return _StageSpan(*_stage_def(name), more)
 
 
-for _name in STAGES + CABAC_STAGES + MESH_STAGES + TURN_STAGES:
+for _name in (STAGES + CABAC_STAGES + MESH_STAGES + MASK_STAGES
+              + TURN_STAGES):
     _stage_def(_name)
 
 # The one stage that crosses threads, so it is no profiler span: stamped

@@ -31,6 +31,17 @@ fully-damaged frame falls back to the ordinary full-frame program,
 which the 100%-damage byte-identity test pins as bit-exact with the
 compacted program.
 
+At ``tune="off"`` the row step's qp is a traced scalar
+(:func:`row_step`, built as the ``_dynqp`` twins of ops/cavlc_p_device
+are): ONE compiled program a row bucket serves the whole rate ladder, so
+a mask session under CBR compiles nothing while it serves once the
+buckets are warm (``H264Encoder.warm_pulls``).  Each bucket's program
+carries its bucket in its name (``jit_encode_p_rows_b8``), so a device
+trace says how many rows every traced frame was handed.  The hq tiers
+keep the static form (:func:`encode_p_rows`).  The flat buffer has one
+length whatever the bucket, so the pull ladder's slices are the dense
+frame's.
+
 Knobs (all warn-and-default, utils/env):
 
 - ``DNGD_DAMAGE_MASK``        master gate for damage-driven encode
@@ -56,9 +67,15 @@ from ..bitstream.bitwriter import BitWriter
 from ..utils.env import env_flag, env_float
 from .h264_inter import _PAD, RING_DONATE
 
+try:
+    import cv2 as _cv2
+except Exception:                          # the numpy form serves
+    _cv2 = None
+
 __all__ = [
     "enabled", "cost_floor", "damage_factor", "damage_grid_np",
-    "plan_rows", "RowPlan", "encode_p_rows", "row_core",
+    "plan_rows", "RowPlan", "bucket_ladder", "encode_p_rows",
+    "row_step", "ROW_STEP_DYNQP_STATIC", "row_core",
     "skip_slice_nal", "assemble_masked_au", "force_skip_rows",
     "scatter_levels_np",
 ]
@@ -95,19 +112,32 @@ def damage_factor(damage, floor: float = None) -> float:
 # ---------------------------------------------------------------------------
 
 def damage_grid_np(y: np.ndarray, prev_y, thr_sad: int = None) -> np.ndarray:
-    """(R, C) uint8 damaged-MB grid — the exact numpy twin of
-    ``ops.content_stats._damage_grid`` (same per-MB abs-SAD sum, same
+    """(R, C) uint8 damaged-MB grid of two uint8 lumas — the exact numpy
+    twin of ``ops.content_stats._damage_grid`` (same per-MB abs-SAD sum, same
     threshold), evaluated host-side from the ingest luma so gating needs
     no device round-trip.  ``prev_y=None`` (stream start / resize)
-    marks everything damaged."""
+    marks everything damaged.
+
+    It runs on the session thread every P frame of a mask session, so it
+    stays in the samples' own width: the absolute difference in uint8
+    (cv2's where cv2 is there), sixteen rows summed in uint16 (at most
+    16 x 255), the sixteen columns of those in uint32.  The same sums as
+    the int64 form it replaced (tests/test_damage.py keeps that as the
+    oracle), in a twentieth of the time at 2560x1600."""
     if thr_sad is None:
         from ..obs import content as obsc
         thr_sad = obsc.damage_thr_sad()
     r, c = y.shape[0] // 16, y.shape[1] // 16
     if prev_y is None:
         return np.ones((r, c), np.uint8)
-    d = np.abs(y.astype(np.int64) - prev_y.astype(np.int64))
-    sad = d.reshape(r, 16, c, 16).sum(axis=(1, 3))
+    y, prev_y = np.asarray(y, np.uint8), np.asarray(prev_y, np.uint8)
+    if _cv2 is not None:
+        d = _cv2.absdiff(np.ascontiguousarray(y),
+                         np.ascontiguousarray(prev_y))
+    else:
+        d = np.maximum(y, prev_y) - np.minimum(y, prev_y)
+    cols = d.reshape(r, 16, c * 16).sum(axis=1, dtype=np.uint16)
+    sad = cols.reshape(r, c, 16).sum(axis=2, dtype=np.uint32)
     return (sad > thr_sad).astype(np.uint8)
 
 
@@ -143,12 +173,21 @@ def _bucket_for(n: int, total: int) -> int:
     return min(b, total)
 
 
+def bucket_ladder(total: int) -> list:
+    """Every bucket of a ``total``-row frame that the ROW program codes
+    (1, 2, 4 ... below ``total``; ``total`` itself is the full-frame
+    program's): what set-up compiles."""
+    return sorted({_bucket_for(n, total) for n in range(1, total)}
+                  - {total})
+
+
 def plan_rows(grid: np.ndarray) -> RowPlan:
     """Damaged-row worklist from a damage grid.  A fully-calm frame
-    still encodes ONE row (row 0) on device: the submit cadence — and
-    with it the dispatch-crossings-per-frame contract — is identical to
-    the unmasked encoder, and an undamaged row encodes to the same
-    all-skip slice bytes the host cache would emit."""
+    still encodes ONE row (row 0) on device: every P frame is one
+    dispatch and one pull, as on the unmasked encoder (a frame whose
+    programs the device never ran would have no token to collect), and
+    an undamaged row encodes to the same all-skip slice bytes the host
+    cache would emit."""
     total = int(grid.shape[0])
     rows = np.flatnonzero(grid.any(axis=1)).astype(np.int32)
     frac = float(grid.mean()) if grid.size else 0.0
@@ -187,35 +226,47 @@ def row_core(y, cb, cr, ref_y, ref_cb, ref_cr, rows, hv_r, hl_r,
     h, w = ref_y.shape
     wc = w // 2
     rb = rows.shape[0]
-    pry = jnp.pad(jnp.asarray(ref_y).astype(jnp.int32), _PAD, mode="edge")
-    prcb = jnp.pad(jnp.asarray(ref_cb).astype(jnp.int32), _PAD, mode="edge")
-    prcr = jnp.pad(jnp.asarray(ref_cr).astype(jnp.int32), _PAD, mode="edge")
+    # dngd.mask_gather: the references' pad (the WHOLE planes, as int32:
+    # the part of a frame's cost that does not shrink with the worklist)
+    # and the bands cut out of them and out of the frame
+    with jax.named_scope("dngd.mask_gather"):
+        pry = jnp.pad(jnp.asarray(ref_y).astype(jnp.int32), _PAD,
+                      mode="edge")
+        prcb = jnp.pad(jnp.asarray(ref_cb).astype(jnp.int32), _PAD,
+                       mode="edge")
+        prcr = jnp.pad(jnp.asarray(ref_cr).astype(jnp.int32), _PAD,
+                       mode="edge")
 
     def one(r):
-        yb = jax.lax.dynamic_slice(y, (r * 16, 0), (16, w))
-        cbb = jax.lax.dynamic_slice(cb, (r * 8, 0), (8, wc))
-        crb = jax.lax.dynamic_slice(cr, (r * 8, 0), (8, wc))
-        ryb = jax.lax.dynamic_slice(
-            pry, (r * 16, 0), (16 + 2 * _PAD, w + 2 * _PAD))
-        rcbb = jax.lax.dynamic_slice(
-            prcb, (r * 8, 0), (8 + 2 * _PAD, wc + 2 * _PAD))
-        rcrb = jax.lax.dynamic_slice(
-            prcr, (r * 8, 0), (8 + 2 * _PAD, wc + 2 * _PAD))
-        nyb = (None if next_y is None else
-               jax.lax.dynamic_slice(next_y, (r * 16, 0), (16, w)))
+        with jax.named_scope("dngd.mask_gather"):
+            yb = jax.lax.dynamic_slice(y, (r * 16, 0), (16, w))
+            cbb = jax.lax.dynamic_slice(cb, (r * 8, 0), (8, wc))
+            crb = jax.lax.dynamic_slice(cr, (r * 8, 0), (8, wc))
+            ryb = jax.lax.dynamic_slice(
+                pry, (r * 16, 0), (16 + 2 * _PAD, w + 2 * _PAD))
+            rcbb = jax.lax.dynamic_slice(
+                prcb, (r * 8, 0), (8 + 2 * _PAD, wc + 2 * _PAD))
+            rcrb = jax.lax.dynamic_slice(
+                prcr, (r * 8, 0), (8 + 2 * _PAD, wc + 2 * _PAD))
+            nyb = (None if next_y is None else
+                   jax.lax.dynamic_slice(next_y, (r * 16, 0), (16, w)))
         return h264_inter.encode_p_frame_padded_ref(
             yb, cbb, crb, ryb, rcbb, rcrb, qp, tune=tune, next_y=nyb,
             p_intra=p_intra)
 
-    outs = jax.vmap(one)(rows)
+    # (vmap writes itself round the first scope inside it, "vmap(row)":
+    # the stages' own names stay whole behind it, "vmap(row)/dngd.me_int",
+    # which is how a trace's reduction finds them)
+    outs = jax.vmap(jax.named_scope("row")(one))(rows)
     # per-row outputs carry a singleton row axis: (R_b, 1, C, ...) MB
     # tensors and (R_b, 16|8, W) planes — merge into one R_b-row frame
     # so _finish_p packs ONE flat buffer across the worklist
     out = {}
-    for k, v in outs.items():
-        out[k] = v.reshape((rb * v.shape[1],) + v.shape[2:]) \
-            if k.startswith("recon") else \
-            v.reshape((rb,) + v.shape[2:])
+    with jax.named_scope("dngd.mask_gather"):
+        for k, v in outs.items():
+            out[k] = v.reshape((rb * v.shape[1],) + v.shape[2:]) \
+                if k.startswith("recon") else \
+                v.reshape((rb,) + v.shape[2:])
     flat, ry, rcb, rcr, mv, nnz, levels = cavlc_p_device._finish_p(
         out, hv_r, hl_r, slice_qp=qp)
     if deblock:
@@ -227,12 +278,13 @@ def row_core(y, cb, cr, ref_y, ref_cb, ref_cr, rows, hv_r, hl_r,
     # scatter the (possibly filtered) recon rows back into the ring;
     # duplicate padded indices write identical values, so scatter order
     # cannot matter
-    new_ry = jnp.asarray(ref_y).reshape(h // 16, 16, w).at[rows].set(
-        ry.reshape(rb, 16, w)).reshape(h, w)
-    new_rcb = jnp.asarray(ref_cb).reshape(h // 16, 8, wc).at[rows].set(
-        rcb.reshape(rb, 8, wc)).reshape(h // 2, wc)
-    new_rcr = jnp.asarray(ref_cr).reshape(h // 16, 8, wc).at[rows].set(
-        rcr.reshape(rb, 8, wc)).reshape(h // 2, wc)
+    with jax.named_scope("dngd.mask_scatter"):
+        new_ry = jnp.asarray(ref_y).reshape(h // 16, 16, w).at[rows].set(
+            ry.reshape(rb, 16, w)).reshape(h, w)
+        new_rcb = jnp.asarray(ref_cb).reshape(h // 16, 8, wc).at[rows].set(
+            rcb.reshape(rb, 8, wc)).reshape(h // 2, wc)
+        new_rcr = jnp.asarray(ref_cr).reshape(h // 16, 8, wc).at[rows].set(
+            rcr.reshape(rb, 8, wc)).reshape(h // 2, wc)
     return flat, new_ry, new_rcb, new_rcr, mv, nnz, levels
 
 
@@ -249,6 +301,34 @@ def encode_p_rows(y, cb, cr, ref_y, ref_cb, ref_cr, rows, hv_r, hl_r,
     return row_core(y, cb, cr, ref_y, ref_cb, ref_cr, rows, hv_r, hl_r,
                     qp, tune=tune, next_y=next_y, p_intra=p_intra,
                     deblock=deblock)
+
+
+#: What the served row step is specialized on: NOT on ``qp``.  (The
+#: benchmark's mask cell reads this before it touches the chip, and runs
+#: no program whose row step compiles a rung of the rate ladder at a time:
+#: benchmark/layer_metrics/_mask.py.)
+ROW_STEP_DYNQP_STATIC = ("tune", "p_intra", "deblock")
+
+
+@functools.lru_cache(maxsize=None)
+def row_step(bucket: int):
+    """The served row step of one bucket: the qp-traced twin of
+    :func:`encode_p_rows` (tune="off" only — see
+    cavlc_device.encode_intra_cavlc_frame_yuv_dynqp), one compiled
+    program whatever the rate controller asks for, jitted under the
+    bucket's own name: ``jit_encode_p_rows_b<bucket>`` on the device
+    trace (a frame's program there starts with ``jit_encode_``), so the
+    rows a traced frame gathered are read off its program's name."""
+    def step(y, cb, cr, ref_y, ref_cb, ref_cr, rows, hv_r, hl_r, qp,
+             tune="off", next_y=None, p_intra=False, deblock=False):
+        assert rows.shape[0] == bucket, (rows.shape, bucket)
+        return row_core(y, cb, cr, ref_y, ref_cb, ref_cr, rows, hv_r,
+                        hl_r, qp, tune=tune, next_y=next_y,
+                        p_intra=p_intra, deblock=deblock)
+
+    step.__name__ = step.__qualname__ = f"encode_p_rows_b{bucket}"
+    return jax.jit(step, static_argnames=ROW_STEP_DYNQP_STATIC,
+                   donate_argnames=RING_DONATE)
 
 
 # ---------------------------------------------------------------------------

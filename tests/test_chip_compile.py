@@ -15,7 +15,11 @@ lowered), the two CABAC binarize programs with the record packer's two
 kernels (``ops/cabac_pack``, chosen the same way; since PR 31 the CAVLC
 frame pack of the intra and the P step is the same two), the P picture's
 binarize program and the loop filter again at 3840x2176 (the 4K
-deployment's 240 macroblocks a row: a new shape is a new compile), the (4,1)
+deployment's 240 macroblocks a row: a new shape is a new compile), a damage
+mask's row program at a bucket of 8 of the 68 rows (``ops/damage_mask``:
+the P step's stages under ``jax.vmap`` over row bands, the packer and the
+loop filter over the worklist's rows, the recon scattered into the donated
+ring; qp traced), the (4,1)
 session-mesh step of ``TPU_SESSIONS``/``TPU_MESH`` on a ``Mesh`` of the
 four described devices, and a P step of two sessions a chip (``jax.vmap``
 over the kernels).
@@ -72,6 +76,7 @@ def programs(topo, no_persistent_cache):
     from docker_nvidia_glx_desktop_tpu.ops import (cabac_binarize,
                                                    cavlc_device,
                                                    cavlc_p_device,
+                                                   damage_mask,
                                                    h264_deblock)
     from docker_nvidia_glx_desktop_tpu.parallel import batch
 
@@ -114,6 +119,18 @@ def programs(topo, no_persistent_cache):
         _flat, ry, rcb, rcr, mv, nnz, _lv = on_chip(
             jax.eval_shape(lambda *a: p_body(*a, "off", None, False),
                            *p_args))
+        # H264Encoder._submit_p_masked: a worklist of 8 rows, the loop
+        # filter inside the program, the ring donated as above
+        work = on_chip((jax.ShapeDtypeStruct((8,), jnp.int32),
+                        jax.ShapeDtypeStruct((8,) + hv_np.shape[1:],
+                                             hv_np.dtype),
+                        jax.ShapeDtypeStruct((8,) + hl_np.shape[1:],
+                                             hl_np.dtype)))
+        rows_body = damage_mask.row_step(8).__wrapped__
+        lowered["rows_b8"] = jax.jit(
+            lambda *a: rows_body(*a, tune="off", next_y=None,
+                                 p_intra=False, deblock=True),
+            donate_argnums=(3, 4, 5)).lower(y, c, c, y, c, c, *work, qp)
         # H264Encoder._deblock as the served path calls it (traced qp)
         lowered["deblock_p"] = jax.jit(
             h264_deblock.deblock_frame.__wrapped__).lower(
@@ -218,6 +235,20 @@ def test_p_step_compiles_and_donates_the_ring(programs):
     # the recon is written in place of the donated reference planes
     assert c.memory_analysis().alias_size_in_bytes >= H * W * 3 // 2
     _has_the_pack_kernels(c.as_text())
+
+
+def test_row_program_compiles_scatters_in_place_and_keeps_the_kernels(
+        programs):
+    c = _compiled(programs, "rows_b8")
+    assert 0 < _device_bytes(c) < HBM_BYTES
+    # the worklist's recon rows are written into the donated planes
+    assert c.memory_analysis().alias_size_in_bytes >= H * W * 3 // 2
+    # the packer's two kernels and the loop filter's one, over 8 rows
+    text = c.as_text()
+    _has_the_pack_kernels(text, calls=3)
+    assert "dngd_deblock_edges" in text
+    # what is held beside the planes is bands, not frames
+    assert c.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
 @pytest.mark.parametrize("name", ["deblock_p", "deblock_p_2160"])

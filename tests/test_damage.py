@@ -137,6 +137,46 @@ class TestOneSubstrate:
                 jnp.asarray(y), jnp.asarray(prev), thr))
             np.testing.assert_array_equal(host, dev)
 
+    @pytest.mark.parametrize("planes", ["random", "saturating", "ticks"])
+    def test_cheap_grid_equals_the_int64_form(self, planes, monkeypatch):
+        """The uint8 / uint16 / uint32 grid (cv2's absolute difference,
+        and numpy's without cv2) against the form it replaced: random
+        planes, planes of 0 against 255 (every sum at its largest, 65,280
+        a macroblock), and differences that land on both sides of the
+        threshold by one."""
+        from docker_nvidia_glx_desktop_tpu.ops import damage_mask as dmg
+
+        def old(y, prev, thr):
+            d = np.abs(y.astype(np.int64) - prev.astype(np.int64))
+            sad = d.reshape(ROWS, 16, COLS, 16).sum(axis=(1, 3))
+            return (sad > thr).astype(np.uint8)
+
+        r = np.random.default_rng(8)
+        if planes == "random":
+            y, prev = (r.integers(0, 256, (H, W)).astype(np.uint8)
+                       for _ in range(2))
+            y[:32] = prev[:32]                     # calm rows too
+        elif planes == "saturating":
+            y = np.where(r.integers(0, 2, (H, W)) > 0, 255, 0).astype(
+                np.uint8)
+            prev = (255 - y).astype(np.uint8)
+            prev[16:48, 32:96] = y[16:48, 32:96]
+        else:
+            prev = r.integers(0, 250, (H, W)).astype(np.uint8)
+            y = prev.copy()
+            y[0, :16] += 2                         # 32: under thr 33 ...
+            y[16, :16] += 2
+            y[17, 0] += 2                          # ... 34: over it
+        for thr in (33, 512, 65279, 65280):
+            want = old(y, prev, thr)
+            np.testing.assert_array_equal(
+                dmg.damage_grid_np(y, prev, thr), want)
+            with monkeypatch.context() as mp:
+                mp.setattr(dmg, "_cv2", None)
+                np.testing.assert_array_equal(
+                    dmg.damage_grid_np(y, prev, thr), want)
+        assert old(y, prev, 33).any() and not old(y, prev, 65280).any()
+
     def test_stream_start_marks_everything_damaged(self):
         from docker_nvidia_glx_desktop_tpu.ops import damage_mask as dmg
         y = np.zeros((H, W), np.uint8)

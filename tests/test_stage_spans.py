@@ -333,21 +333,23 @@ def test_token_ready_answers_for_a_per_frame_token_and_changes_no_byte(
 
 
 def test_token_ready_is_none_where_there_is_nothing_to_ask():
-    """The ring and masked paths, a synchronous token, a prefix that has
-    been deleted: None, and never an exception."""
+    """The ring path, a synchronous token, a marked token of no path or
+    without its prefix, a prefix that has been deleted: None, and never an
+    exception."""
     import jax.numpy as jnp
 
     from docker_nvidia_glx_desktop_tpu.models.base import Encoder
 
-    enc = raw_encoder()
+    enc, gone = raw_encoder(), jnp.zeros(8)
     assert Encoder.token_ready(enc, ("p", 0, 0.0, False, ())) is None
     assert enc.token_ready(("sync", None, None, True, object())) is None
     assert enc.token_ready(("ring", 1, 0.0, False, ({}, 0))) is None
     assert enc.token_ready(("p", 1, 0.0, False, ("dmg",) + (None,) * 7)) \
         is None
+    assert enc.token_ready(("p", 1, 0.0, False, ("other",) + (gone,) * 7)) \
+        is None
     assert enc.token_ready(("p", 1, 0.0, False, (None,) * 7)) is None
     assert enc.token_ready(None) is None
-    gone = jnp.zeros(8)
     payload = (30, 1, {}, None, None, gone, None)
     assert enc.token_ready(("p", 1, 0.0, False, payload)) is True
     gone.delete()
@@ -486,6 +488,13 @@ SCOPES = {
     "binarize_p": ("dngd.binarize",),
     "binarize_intra": ("dngd.binarize",),
     "cabac_bs": ("dngd.deblock_bs",),
+    # a damage-mask session's row program (ISSUE 40): the shared stages
+    # under their own names between the gather and the scatter
+    "rows": ("dngd.mask_gather", "dngd.ingest", "dngd.me_int",
+             "dngd.me_subpel", "dngd.mc", "dngd.tq", "dngd.recon",
+             "dngd.slots", "dngd.pack", "dngd.deblock_bs",
+             "dngd.deblock_tile", "dngd.deblock_edges", "dngd.deblock_v",
+             "dngd.deblock_h", "dngd.mask_scatter"),
 }
 
 
@@ -504,7 +513,7 @@ def lowered():
                                                             _yuv_stage)
     from docker_nvidia_glx_desktop_tpu.ops import (
         cabac_binarize, cavlc_device, cavlc_p_device, content_stats,
-        h264_deblock, h264_device, h264_inter)
+        damage_mask, h264_deblock, h264_device, h264_inter)
 
     w, h = 176, 112
     nr, nc = h // 16, w // 16
@@ -526,6 +535,11 @@ def lowered():
         "colour": _yuv_stage.lower(np.zeros((h, w, 3), np.uint8), h, w),
         "frame_stats": content_stats.frame_stats.lower(
             y, y, y, mv, (), None, 512),
+        # H264Encoder._submit_p_masked: a worklist of two of the seven rows
+        "rows": damage_mask.row_step(2).lower(
+            y, c, c, y, c, c, np.array([2, 5], np.int32), pv[[2, 5]],
+            pl[[2, 5]], qp, tune="off", next_y=None, p_intra=False,
+            deblock=True),
     }
     # the CABAC path as H264Encoder._submit_cabac_p / _submit_cabac_intra
     # hand it on: the level tensors of the P and the intra program into

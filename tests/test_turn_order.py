@@ -5,7 +5,11 @@ frame's submit (``H264Encoder.encode_submit`` calls the session's
 same access units, because the next frame's qp is reserved in the first half
 either way.  The real encoder
 (128x96, CAVLC and CABAC, rate control on and walking) under the real
-``StreamSession._run``, on the pacing tests' fake clock."""
+``StreamSession._run``, on the pacing tests' fake clock.  ``mask``: the
+CAVLC encoder under ``DNGD_DAMAGE_MASK`` on pictures of which only the top
+rows change, so that its P frames are the row program's (ISSUE 40: the row
+plan is made in the first half, and a mask session's turn has the two
+halves of any other)."""
 
 import numpy as np
 import pytest
@@ -30,18 +34,38 @@ def frame(c: int) -> np.ndarray:
                     axis=-1).clip(0, 255).astype(np.uint8)
 
 
-def new_encoder(entropy: str):
+def calm_frame(c: int) -> np.ndarray:
+    """``frame(c)`` in the top one to four macroblock rows, ``frame(0)``
+    under them: a damage plan of a bucket below the picture's six rows."""
+    out = frame(0)
+    rows = 16 * (1 + c % 4)
+    out[:rows] = frame(c)[:rows]
+    return out
+
+
+def pictures(kind: str):
+    return calm_frame if kind == "mask" else frame
+
+
+def new_encoder(kind: str):
+    """``device`` or ``cabac``: the entropy coder; ``mask``: ``device``
+    under the damage mask."""
+    mask = kind == "mask"
     cfg = from_env({"PASSWD": "pw", "SIZEW": str(W), "SIZEH": str(H),
-                    "REFRESH": "60", "ENCODER_ENTROPY": entropy,
+                    "REFRESH": "60",
+                    "ENCODER_ENTROPY": "device" if mask else kind,
                     "ENCODER_CABAC_BINARIZE": "device",
-                    "ENCODER_BITRATE_KBPS": "300", "ENCODER_GOP": "60",
-                    "ENCODER_PREWARM": "false"})
-    enc, _ = make_encoder(cfg, W, H)
+                    "ENCODER_BITRATE_KBPS": "100" if mask else "300",
+                    "ENCODER_GOP": "60", "ENCODER_PREWARM": "false"})
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DNGD_DAMAGE_MASK", "true" if mask else "false")
+        enc, _ = make_encoder(cfg, W, H)
     assert enc._dyn_qp and enc._rate is not None
+    assert enc.damage_mask == mask
     return enc
 
 
-def front(enc, early: bool, fail_at=None):
+def front(enc, early: bool, fail_at=None, frame=frame):
     """The session's view of ``enc``: the source's frame ``k`` is
     ``frame(k)``, and ``token_ready`` says ``early`` whatever the device
     does, so every owed collect goes in front of the dispatch, or none.
@@ -92,7 +116,7 @@ def serve(monkeypatch, entropy, early, fail_at=None):
     """FRAMES frames through ``StreamSession._run``: the access units the
     AU listeners were handed, the calls, and the encoder afterwards."""
     enc = new_encoder(entropy)
-    aus = []
+    aus, programs = [], mask_frames()
 
     def prepare(sess):
         sess._au_listeners.append(
@@ -102,14 +126,23 @@ def serve(monkeypatch, entropy, early, fail_at=None):
     f0 = session_mod._M_COLLECT_FAIL.value
     try:
         run = drive(monkeypatch, seconds=(FRAMES - 0.5) / 60.0,
-                    work=front(enc, early, fail_at), prepare=prepare)
+                    work=front(enc, early, fail_at, pictures(entropy)),
+                    prepare=prepare)
     finally:
         faults.disarm_all()
     run.collect_failures = session_mod._M_COLLECT_FAIL.value - f0
+    run.row_frames = mask_frames()["rows"] - programs["rows"]
     return aus, run, enc, session_mod._M_EARLY_COLLECTS.value - n0
 
 
-@pytest.fixture(scope="module", params=["device", "cabac"])
+def mask_frames() -> dict:
+    """P frames a damage plan has sent to each program so far."""
+    from docker_nvidia_glx_desktop_tpu.models import h264
+    return {"rows": h264._M_MASK_FRAMES_ROWS.value,
+            "dense": h264._M_MASK_FRAMES_DENSE.value}
+
+
+@pytest.fixture(scope="module", params=["device", "cabac", "mask"])
 def both_orders(request):
     """One run forced to the early order and one forced to today's."""
     mp = pytest.MonkeyPatch()
@@ -129,6 +162,9 @@ def test_both_orders_give_the_same_access_units(both_orders):
     # the controller ended where it ended, too
     assert enc_e._rate.level == enc_l._rate.level
     assert enc_e._rate.pending_count == enc_l._rate.pending_count
+    # ... and under the mask every P frame was the row program's
+    rows = len(run_e.taken) - 1 if both_orders[0] == "mask" else 0
+    assert run_e.row_frames == run_l.row_frames == rows
 
 
 def test_the_turns_took_the_orders_they_were_forced_to(both_orders):
@@ -150,12 +186,13 @@ def test_the_qp_walked_so_the_order_could_have_shown(both_orders):
     assert enc._rate._step_idx != enc._rate.STEPS.index(0)
 
 
-@pytest.mark.parametrize("entropy", ["device", "cabac"])
+@pytest.mark.parametrize("entropy", ["device", "cabac", "mask"])
 def test_a_hook_that_does_nothing_changes_no_token_and_no_byte(entropy):
     """``encode_submit`` is the two halves back to back, with or without a
     caller between them."""
     plain, hooked, calls = new_encoder(entropy), new_encoder(entropy), []
     hooked.between_halves = lambda: calls.append(hooked._rate.pending_count)
+    frame = pictures(entropy)
     for k in range(5):
         a, b = plain.encode_submit(frame(k)), hooked.encode_submit(frame(k))
         assert a[:2] == b[:2] and a[3] == b[3]
@@ -166,11 +203,12 @@ def test_a_hook_that_does_nothing_changes_no_token_and_no_byte(entropy):
     assert plain._rate.level == hooked._rate.level
 
 
-@pytest.mark.parametrize("one_piece", ["ring", "damage_mask", "sync"])
+@pytest.mark.parametrize("one_piece", ["ring", "masked_ring", "sync"])
 def test_a_one_piece_submit_calls_nothing_between(one_piece):
     from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
     kw = {"ring": dict(entropy="device", gop=30, superstep_chunk=4),
-          "damage_mask": dict(entropy="device", gop=30, damage_mask=True),
+          "masked_ring": dict(entropy="device", gop=30, superstep_chunk=4,
+                              damage_mask=True, host_color=True),
           "sync": dict(entropy="native", gop=30)}[one_piece]
     enc = H264Encoder(W, H, mode="cavlc", bitrate_kbps=300, fps=60, **kw)
     calls = []
