@@ -1272,10 +1272,10 @@ class H264Encoder(Encoder):
 
     def _host_yuv420(self, rgb):
         """(y, cb, cr) uint8 planes padded to MB multiples, host-converted
-        by the shared :mod:`..utils.hostcolor` path (cv2-accelerated for
-        single-core capture hosts).  Returns None when cv2 is unavailable
-        (the device conversion takes over) or the geometry resists
-        4:2:0."""
+        by the shared :mod:`..utils.hostcolor` path (cv2's bytes; in row
+        bands where the host has cores to spare).  Returns None when cv2
+        is unavailable (the device conversion takes over) or the geometry
+        resists 4:2:0."""
         cls = type(self)
         if cls._host_yuv_ok is False:
             return None
@@ -1559,10 +1559,14 @@ class H264Encoder(Encoder):
         ``begun``: what :meth:`_intra_begin` made of ``rgb`` already.
 
         When cv2 is available the RGB->YUV420 conversion runs on the host
-        (SIMD, ~2-5 ms at 1080p) so only 1.5 B/px cross the host->device
-        link instead of 3 — that link is the measured hot-path bottleneck
-        (SURVEY.md §3.2); cv2's BT.601 studio-range matches ops/color
-        "video" (tested in tests/test_h264_cavlc.py)."""
+        (the stage ``colour``: :mod:`..utils.hostcolor`, one native pass
+        over row bands on up to 8 cores, 0.8 ms at 1080p on a chip's host;
+        the three cv2 calls it equals byte for byte took 4.1 there, 2.2 of
+        them in ``cv2.transform``, which runs on ONE thread) so only
+        1.5 B/px cross the host->device link instead of 3 — that link is
+        the measured hot-path bottleneck (SURVEY.md §3.2); cv2's BT.601
+        studio-range matches ops/color "video" (tested in
+        tests/test_h264_cavlc.py)."""
         from ..ops import cavlc_device
 
         if begun is None:

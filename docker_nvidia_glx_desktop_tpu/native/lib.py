@@ -61,8 +61,11 @@ def _build() -> Optional[pathlib.Path]:
     # concurrent build must never leave a truncated .so at the cache path
     # (ctypes would then fail on every later run).
     tmp_path = so_path.with_suffix(f".tmp{os.getpid()}")
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           "-pthread"] + extra + ["-o", str(tmp_path)] + \
+    # -ffp-contract=off: colour.cpp's float32 chroma must round a product
+    # and a sum apart, as cv2.transform does (no fused multiply-add)
+    cmd = ["g++", "-O3", "-march=native", "-ffp-contract=off", "-shared",
+           "-fPIC", "-std=c++17", "-pthread"] + extra + \
+          ["-o", str(tmp_path)] + \
           [str(s) for s in sources]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
@@ -160,6 +163,18 @@ def get_lib() -> Optional[ctypes.CDLL]:
                     u8p, i64ap, ctypes.c_int64,
                 ]
                 lib.h264_cabac_engine_rows.restype = ctypes.c_int64
+        global _COLOUR_OK
+        if hasattr(lib, "rgb_to_yuv420_bands"):
+            lib.tpudesktop_colour_abi_version.restype = ctypes.c_int32
+            if lib.tpudesktop_colour_abi_version() == 1:
+                _COLOUR_OK = True
+                lib.rgb_to_yuv420_bands.argtypes = [
+                    u8p, ctypes.c_int64, ctypes.c_int64,
+                    u8p, ctypes.c_int64, u8p, u8p, ctypes.c_int64,
+                    np.ctypeslib.ndpointer(np.float32,
+                                           flags="C_CONTIGUOUS"),
+                    ctypes.c_int32]
+                lib.rgb_to_yuv420_bands.restype = None
         global _LEVELPACK_OK
         if hasattr(lib, "level_unpack_rows"):
             lib.tpudesktop_levelpack_abi_version.restype = ctypes.c_int32
@@ -190,6 +205,7 @@ def has_cavlc() -> bool:
 _CABAC_OK = False
 _ENGINE_OK = False
 _LEVELPACK_OK = False
+_COLOUR_OK = False
 
 
 def has_cabac() -> bool:
@@ -332,6 +348,36 @@ def annexb_rows(src: np.ndarray, row_off: np.ndarray, row_len: np.ndarray,
     if n < 0:
         return int(n)
     return out[:len(prefix) + n].tobytes()
+
+
+def has_colour() -> bool:
+    """The fused colour pass (native/colour.cpp) is built and its ABI
+    version checked."""
+    return get_lib() is not None and _COLOUR_OK
+
+
+def rgb_to_yuv420_bands(rgb: np.ndarray, y: np.ndarray, u: np.ndarray,
+                        v: np.ndarray, m: np.ndarray, bands: int) -> None:
+    """(h, w, 3) uint8 RGB into the top-left (h, w) of ``y`` and
+    (h/2, w/2) of ``u`` and ``v`` (planes at least that large: the pad is
+    the caller's), as ``bands`` row bands on the library's own pool, in
+    ONE C call (native/colour.cpp; the caller and the cv2 road it must
+    equal byte for byte: utils/hostcolor.py).  ``m``: the 2x4 chroma
+    matrix as float32."""
+    lib = get_lib()
+    assert lib is not None and _COLOUR_OK
+    h, w = rgb.shape[:2]
+    if any(a.dtype != np.uint8 or not a.flags.c_contiguous
+           for a in (rgb, y, u, v)) \
+            or rgb.shape != (h, w, 3) or h % 2 or w % 2 \
+            or y.ndim != 2 or y.shape[0] < h or y.shape[1] < w \
+            or u.shape != v.shape or u.ndim != 2 \
+            or u.shape[0] < h // 2 or u.shape[1] < w // 2 \
+            or m.dtype != np.float32 or m.shape != (2, 4) \
+            or not m.flags.c_contiguous or bands < 1:
+        raise ValueError("rgb_to_yuv420_bands: malformed picture or planes")
+    lib.rgb_to_yuv420_bands(rgb, h, w, y, y.shape[1], u, v, u.shape[1], m,
+                            bands)
 
 
 # ---------------------------------------------------------------------------
