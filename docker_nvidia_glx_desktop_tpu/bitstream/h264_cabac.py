@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..obs import metrics as obsm
 from ..obs import trace as obst
 from . import h264 as syn
 from .bitwriter import BitWriter
@@ -194,6 +195,141 @@ def encode_p_from_binstream(buf: np.ndarray, *, nr: int, nc_mb: int,
         return None
     return syn.annexb_rows(
         *rows, syn.NAL_SLICE, 2, mb_step=nc_mb,
+        slice_hdr=dict(slice_type=5, frame_num=frame_num, idr=False,
+                       qp_delta=qp_delta, deblocking_idc=deblocking_idc,
+                       cabac=True, cabac_init_idc=cabac_init_idc))
+
+
+# -- the damage-masked frame (ops/damage_mask, models/h264.py) ---------------
+
+_M_SKIP_SLICES = obsm.counter(
+    "dngd_encoder_cabac_skip_slices_total",
+    "All-skip CABAC slices a damage-masked frame's unplanned rows left "
+    "as, by where their slice data came from: cache = the payload this "
+    "process had coded for that qp before, coded = coded for this frame "
+    "(the first frame at a qp that set-up did not warm)",
+    ("road",))
+_M_SKIP_CACHE = _M_SKIP_SLICES.labels("cache")
+_M_SKIP_CODED = _M_SKIP_SLICES.labels("coded")
+_SKIP_PAYLOADS: dict = {}        # (nc_mb, qp, cabac_init_idc) -> bytes
+
+
+def skip_row_payload(nc_mb: int, qp: int, cabac_init_idc: int = 0):
+    """``(slice data, cached)`` of a P slice whose ``nc_mb`` macroblocks
+    are all skipped: ``mb_skip_flag`` 1 (ctxIdx 11: no neighbour of an
+    all-skip row's macroblock is coded or in its slice) and
+    ``end_of_slice_flag`` a macroblock, through the pure-Python engine.
+
+    Unlike CAVLC's ``mb_skip_run`` these bytes depend on the slice's qp
+    (9.3.1.1: it initialises the contexts) and on ``cabac_init_idc`` —
+    and on nothing else: not on the row and not on ``frame_num``, which
+    live in the slice HEADER, and the header is written a row by
+    ``syn.annexb_rows`` for coded and skipped rows alike.  So the cache
+    has at most 52 entries a geometry, and set-up fills it
+    (``H264Encoder._warm_row_buckets``)."""
+    key = (nc_mb, int(qp), cabac_init_idc)
+    got = _SKIP_PAYLOADS.get(key)
+    if got is not None:
+        return got, True
+    enc = CabacEncoder(1 + cabac_init_idc, int(qp))
+    sc = SliceCoder(enc, intra_slice=False)
+    for mx in range(nc_mb):
+        ctx = _MbCtx()
+        sc.mb_skip(True)
+        sc.qp_delta_absent()
+        ctx.skip = True
+        sc.left = ctx
+        sc.end_of_slice(mx == nc_mb - 1)
+    got = _SKIP_PAYLOADS[key] = enc.get_bytes()
+    return got, False
+
+
+def _engine_band(buf: np.ndarray, coded: int, nc_mb: int, table_idx: int,
+                 qp: int, tail: bytes):
+    """:func:`_engine_rows` for a row band (``buf[3]`` rows) of which the
+    first ``coded`` are coded (the rest repeat the last one: the
+    bucket's padding), with ``tail`` behind the rows' payloads in the
+    returned buffer.  ``(src, row_off, row_len, tail_off)`` or None (the
+    transport's overflow flag, the engine's cap: the caller goes
+    dense)."""
+    from ..native import lib as native_lib
+    from ..ops import cabac_binarize
+
+    split = cabac_binarize.split_rows(buf, int(buf[3]))
+    if split is None:
+        return None
+    payload, row_off, row_bits = split
+    if native_lib.has_cabac_engine():
+        ctx, rng, tmps, tlps = _native_tables(table_idx)
+        for scale in (1, 4):
+            cap = (2048 + nc_mb * 1536) * scale
+            got = native_lib.cabac_engine_rows_tail(
+                payload, row_off[:coded + 1], row_bits[:coded], coded, qp,
+                ctx, rng, tmps, tlps, cap, tail)
+            if isinstance(got, tuple):
+                out, lens = got
+                return (out, np.arange(coded, dtype=np.int64) * cap, lens,
+                        coded * cap)
+            if got == -2:
+                break                    # malformed: no cap can help
+        import logging
+        logging.getLogger(__name__).warning(
+            "native CABAC engine gave way on a masked frame's row band; "
+            "dense fallback")
+        return None
+    out = []
+    for r in range(coded):
+        enc = CabacEncoder(table_idx, qp)
+        for rec in cabac_binarize.decode_records_py(
+                payload[row_off[r]:row_off[r + 1]], int(row_bits[r])):
+            kind = rec[0]
+            if kind == "dec":
+                enc.decision(rec[1], rec[2])
+            elif kind == "run":
+                for _ in range(rec[2]):
+                    enc.decision(rec[1], 1)
+            elif kind == "byp":
+                for b in rec[1]:
+                    enc.bypass(b)
+            else:
+                enc.terminate(rec[1])
+        out.append(enc.get_bytes())
+    lens = np.array([len(pl) for pl in out], np.int64)
+    return (np.frombuffer(b"".join(out) + tail, np.uint8),
+            np.cumsum(lens) - lens, lens, int(lens.sum()))
+
+
+def encode_p_rows_from_binstream(buf: np.ndarray, rows, *, nr: int,
+                                 nc_mb: int, qp: int, frame_num: int,
+                                 qp_delta: int = 0,
+                                 deblocking_idc: int = 1,
+                                 cabac_init_idc: int = 0):
+    """P access unit of a damage-masked frame: ``buf`` is the record
+    stream of a row BAND (``ops/cabac_binarize.binarize_p`` over the
+    worklist's bucket: ``buf[3]`` rows, the first ``len(rows)`` of them
+    the frame's rows ``rows``, ascending; the padding behind them is
+    coded by the device and dropped here).  The engine codes those rows'
+    slices, every other row of the ``nr`` leaves as an all-skip slice
+    (:func:`skip_row_payload`, the stage ``skip_slices``), and the
+    access unit is the rows in order, each under its own
+    ``first_mb_in_slice``, framed in one call.  None on the transport's
+    overflow flag or the engine's cap."""
+    rows = np.asarray(rows, np.int64)
+    with obst.stage("skip_slices"):
+        tail, cached = skip_row_payload(nc_mb, qp, cabac_init_idc)
+        (_M_SKIP_CACHE if cached else _M_SKIP_CODED).inc(nr - len(rows))
+    with obst.stage("engine"):
+        got = _engine_band(buf, len(rows), nc_mb, 1 + cabac_init_idc, qp,
+                           tail)
+    if got is None:
+        return None
+    src, off, lens, tail_off = got
+    row_off = np.full(nr, tail_off, np.int64)
+    row_len = np.full(nr, len(tail), np.int64)
+    row_off[rows] = off
+    row_len[rows] = lens
+    return syn.annexb_rows(
+        src, row_off, row_len, syn.NAL_SLICE, 2, mb_step=nc_mb,
         slice_hdr=dict(slice_type=5, frame_num=frame_num, idr=False,
                        qp_delta=qp_delta, deblocking_idc=deblocking_idc,
                        cabac=True, cabac_init_idc=cabac_init_idc))

@@ -517,6 +517,9 @@ class H264Encoder(Encoder):
             hdrw = level_pack.header_words(self.mb_h)
             self._cabac_pull = {"intra": PrefixPull(hdrw, 8),
                                 "p": PrefixPull(hdrw, 4)}
+            # ... and one a row bucket of a damage-masked P frame, made
+            # when the bucket is first met (_cabac_mask_pull)
+            self._cabac_mask_pulls = {}
             # never silently: without the compiled engine every frame is
             # coded by the Python one, and counted as such
             self._cabac_native = (native_lib.has_cabac_engine()
@@ -787,7 +790,7 @@ class H264Encoder(Encoder):
             if h is None:
                 return
             vec, grid = np.asarray(h["vec"]), np.asarray(h["grid"])
-            if kind in ("cabac_p", "cabac_intra"):
+            if kind in ("cabac_p", "cabac_intra", "cabac_p_mask"):
                 _M_D2H_BYTES.inc(vec.nbytes + grid.nbytes)
             stats = cs.vec_to_stats(vec, grid, self.pad_h * self.pad_w)
             if h.get("first"):
@@ -1429,9 +1432,11 @@ class H264Encoder(Encoder):
         the scratch encoder runs this one's mesh and step programs, and
         the slices are those of the stacked per-shard buffers.  Returns
         the slices compiled.  A damage-mask session compiles its row
-        programs here instead (:meth:`_warm_row_buckets`); 0 on every
-        other path (the CAVLC pull ladder is content's to walk: 64 KiB
-        steps of a 46 KB frame)."""
+        programs here: instead, on the device CAVLC path, and behind the
+        dense ladder under the CABAC stream (:meth:`_warm_row_buckets`,
+        which does nothing where the mask is off); 0 on every other path
+        (the CAVLC pull ladder is content's to walk: 64 KiB steps of a
+        46 KB frame)."""
         if self.mode == "cavlc" and self.entropy == "device":
             return self._warm_row_buckets()
         if self.entropy != "cabac" or self.mode != "cavlc":
@@ -1466,7 +1471,7 @@ class H264Encoder(Encoder):
         n += pulls[1].warm(buf)
         log.info("CABAC pull ladder: %d slices of %d-word buffers in "
                  "%.1f s", n, buf.shape[-1], time.perf_counter() - t0)
-        return n
+        return n + self._warm_row_buckets()
 
     def _warm_row_buckets(self) -> int:
         """Compile everything a damage-mask session's frames can ask for,
@@ -1480,11 +1485,15 @@ class H264Encoder(Encoder):
         after the other (never side by side: :meth:`prewarm`).  Returns
         programs and slices compiled; 0 where the mask is off, and where
         qp is static (the hq tiers: a program a bucket AND a rung, which
-        :meth:`prewarm` walks at the calm frame's bucket alone)."""
+        :meth:`prewarm` walks at the calm frame's bucket alone).  Under
+        the CABAC stream the dense programs are :meth:`warm_pulls`' and
+        the rest is :meth:`_warm_row_buckets_cabac`."""
         if not (self.damage_mask and self._dyn_qp and self.host_color
                 and self.gop > 1) or self.keep_recon \
                 or self._spatial_nx > 1 or self._ring_chunk:
             return 0
+        if self.entropy == "cabac":
+            return self._warm_row_buckets_cabac()
         from ..ops import cavlc_device
         from ..ops import damage_mask as dmg
 
@@ -1519,6 +1528,52 @@ class H264Encoder(Encoder):
                  len(ladder), ladder, total, slices, flat.shape[0],
                  time.perf_counter() - t0)
         return len(ladder) + 2 + slices
+
+    def _warm_row_buckets_cabac(self) -> int:
+        """:meth:`_warm_row_buckets` for the CABAC stream, behind
+        :meth:`warm_pulls`' dense ladder (which has compiled the IDR's
+        and the dense P frame's programs and their pull slices): a frame
+        through the row program of every bucket and the binarizer over
+        its band (qp traced in the first, absent from the second: one
+        compile a bucket each), every prefix slice of every bucket's
+        record buffer, and the all-skip slice's data at every qp the
+        stream can take (``h264_cabac.skip_row_payload``: 52 entries of
+        a few bytes, through the Python engine).  Returns programs and
+        slices compiled; 0 where the binarizer is the host's."""
+        if not self.cabac_device_binarize:
+            return 0
+        from ..bitstream import h264_cabac
+        from ..ops import damage_mask as dmg
+
+        t0 = time.perf_counter()
+        scratch = H264Encoder(
+            self.width, self.height, qp=self.qp, mode=self.mode,
+            entropy=self.entropy, host_color=True, gop=self.gop,
+            deblock=self.deblock, intra_modes=self.i16_modes,
+            superstep_chunk=0, spatial_shards=1, tune=self.tune,
+            damage_mask=True, row_align=self.row_align)
+        scratch._cabac_dev_bin = True
+        rgb = np.zeros((self.height, self.width, 3), np.uint8)
+        scratch.encode(rgb)                       # the IDR: a reference
+        planes = scratch._planes_device(rgb)
+        if not isinstance(planes[0], np.ndarray):
+            return 0                              # no host colour: no plan
+        total, slices = self.mb_h, 0
+        ladder = dmg.bucket_ladder(total)
+        for bucket in ladder:
+            rows = np.arange(bucket, dtype=np.int32)
+            sub = scratch._submit_cabac_p_masked(
+                *planes, self.qp,
+                dmg.RowPlan(rows, rows, bucket, total, 1.0))
+            slices += scratch._cabac_mask_pull(bucket).warm(sub[5])
+            scratch._collect_cabac_p_masked(sub)
+        for qp in range(52):
+            h264_cabac.skip_row_payload(self.mb_w, qp)
+        log.info("damage mask (CABAC): %d row programs and their "
+                 "binarize (buckets %s of %d rows), %d prefix slices of "
+                 "their record buffers, in %.1f s", len(ladder), ladder,
+                 total, slices, time.perf_counter() - t0)
+        return 2 * len(ladder) + slices
 
     def prewarm_async(self, qps=None):
         """Run :meth:`prewarm` in a daemon thread; returns (thread,
@@ -1921,6 +1976,92 @@ class H264Encoder(Encoder):
             self._note_qp_map(qp_map, levels=dense, slice_qp=qp)
             return h264_cabac.encode_p_picture(dense, **hdr, qp_map=qp_map)
 
+    # -- the damage mask under the CABAC stream (ops/damage_mask) --------
+    # NEW functions beside the dense pair above, which they leave as it
+    # is: a planned P frame of at most the ladder's top goes through the
+    # row program of its bucket and the binarizer over that band, under a
+    # token kind of its own (``cabac_p_mask``); a plan past the ladder's
+    # top is the dense pair's frame.  Stages as on the CAVLC mask:
+    # ``damage_grid`` | ``dispatch``, ``pull``, ``pull_extra``,
+    # ``assemble`` (in it ``skip_slices`` and ``engine``).
+
+    def _cabac_mask_pull(self, bucket: int) -> PrefixPull:
+        """The pull helper of one row bucket's record buffer: its header
+        and its whole length follow the bucket, and its history is its
+        own, so that a run of four-row frames does not shrink the guess
+        the next dense frame's pull starts from."""
+        pull = self._cabac_mask_pulls.get(bucket)
+        if pull is None:
+            from ..ops import cabac_binarize
+            pull = self._cabac_mask_pulls[bucket] = PrefixPull(
+                cabac_binarize.header_words(bucket), 1)
+        return pull
+
+    def _submit_cabac_p_planned(self, y, cb, cr, qp: int, plan):
+        """``(token kind, payload)`` of a CABAC P frame that has a row
+        plan: the row program's where the plan is under the ladder's top,
+        else the dense program's."""
+        _note_mask_plan(plan)
+        if plan.full:
+            return "cabac_p", self._submit_cabac_p(y, cb, cr, qp)
+        return "cabac_p_mask", self._submit_cabac_p_masked(
+            y, cb, cr, qp, plan)
+
+    def _submit_cabac_p_masked(self, y, cb, cr, qp: int, plan):
+        """Masked counterpart of :meth:`_submit_cabac_p` (device binarize,
+        tune=off): the bucket's row program over the worklist (the refs
+        donated, the loop filter inside, the scattered planes the next
+        reference), then the binarizer over the band's vectors and
+        levels, then the guessed prefix of ITS record buffer on its way
+        to the host."""
+        from ..ops import cabac_binarize
+        from ..ops import damage_mask as dmg
+
+        with obst.stage("dispatch") as span:
+            _note_h2d(y, cb, cr, plan.padded)
+            planes = (jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr))
+            ry, rcb, rcr, mv, levels = dmg.row_step_cabac(plan.bucket)(
+                *planes, *self._ref, jnp.asarray(plan.padded),
+                np.int32(qp), deblock=self.deblock)
+            self._ref = (ry, rcb, rcr)
+            self._content_submit(planes[0], recon_y=ry)
+            buf = cabac_binarize.binarize_p(
+                mv, levels["luma"], levels["cb_dc"], levels["cb_ac"],
+                levels["cr_dc"], levels["cr_ac"])
+            prefix = self._cabac_mask_pull(plan.bucket).prefix(buf)
+        self._count_dispatch(ms=span.ms)
+        return (qp, self._frame_num, plan, levels, mv, buf, prefix)
+
+    def _collect_cabac_p_masked(self, submitted) -> bytes:
+        from ..bitstream import h264_cabac
+        from ..ops import damage_mask as dmg
+
+        qp, frame_num, plan, levels, mv, buf, prefix = submitted
+        if not self._cabac_native:
+            _M_CABAC_PYTHON.inc()
+        head = self._cabac_mask_pull(plan.bucket).pull(buf, prefix)
+        with obst.stage("assemble", more=True):
+            hdr = dict(qp=qp, frame_num=frame_num, qp_delta=qp - self.qp,
+                       deblocking_idc=self._deblock_idc)
+            if head is not None:
+                au = h264_cabac.encode_p_rows_from_binstream(
+                    head, plan.rows, nr=self.mb_h, nc_mb=self.mb_w, **hdr)
+                if au is not None:
+                    return au
+            # the stream's flag or the engine's cap: the worklist's
+            # levels scattered to full-frame shapes (untouched rows zero
+            # = skip) and the WHOLE frame through the host coder; the
+            # reference chain needs no rewind
+            _note_cabac_dense()
+            pulled = {k: np.asarray(v) for k, v in levels.items()}
+            mv_np = np.asarray(mv)
+            _M_D2H_BYTES.inc(mv_np.nbytes
+                             + sum(v.nbytes for v in pulled.values()))
+            dense, full_mv = dmg.scatter_levels_np(
+                pulled, mv_np, plan.padded, self.mb_h)
+            dense["mv"] = full_mv.astype(np.int32)
+            return h264_cabac.encode_p_picture(dense, **hdr)
+
     def _encode_host_entropy(self, rgb, idr_pic_id: int,
                              prefer_native: bool = None,
                              planes=None, qp: int = None,
@@ -2232,16 +2373,24 @@ class H264Encoder(Encoder):
 
     def _damage_plan(self, y):
         """RowPlan for the CURRENT host-ingested frame, or None when
-        the masked path cannot serve it (mask off, device-side ingest,
-        keep_recon debug pulls, non-device entropy, a spatial mesh).
-        Feeds the rate controller's damage consumer as a side effect."""
+        the masked path cannot serve it: mask off, device-side ingest,
+        keep_recon debug pulls, a spatial mesh, or an entropy placement
+        outside ``ops/damage_mask.MASKED_ENTROPY`` — the device CAVLC
+        path, and the CABAC path where the binarizer is the device's and
+        qp is traced (``ENCODER_CABAC_BINARIZE=device``, tune=off: the
+        host-binarize and hq CABAC frames have no row program, and stay
+        dense).  Feeds the rate controller's damage consumer as a side
+        effect."""
+        from ..ops import damage_mask as dmg
         if (not self.damage_mask or self.mode != "cavlc"
-                or self.entropy != "device" or self.keep_recon
+                or self.entropy not in dmg.MASKED_ENTROPY
+                or (self.entropy == "cabac"
+                    and not (self._dyn_qp and self.cabac_device_binarize))
+                or self.keep_recon
                 or self._spatial_nx > 1      # a mesh gates rows instead
                 or not isinstance(y, np.ndarray)
                 or self._damage_cur_y is None):
             return None
-        from ..ops import damage_mask as dmg
         prev = self._damage_prev_y
         if prev is not None and prev.shape != y.shape:
             prev = None                   # post-resize: everything dirty
@@ -2973,9 +3122,13 @@ class H264Encoder(Encoder):
                 tok = self._ring_stage(rgb, idx, t0)
             else:
                 kind = "cabac_p" if cabac else "p"
-                sub = (self._submit_cabac_p(y, cb, cr, qp) if cabac
-                       else self._submit_p_device(y, cb, cr, qp,
-                                                  damage_plan=plan))
+                if cabac and plan is not None:
+                    kind, sub = self._submit_cabac_p_planned(
+                        y, cb, cr, qp, plan)
+                else:
+                    sub = (self._submit_cabac_p(y, cb, cr, qp) if cabac
+                           else self._submit_p_device(y, cb, cr, qp,
+                                                      damage_plan=plan))
                 tok = (kind, idx, t0, False, sub)
         except Exception:
             # this submit's qp reservation (if it got that far) will never
@@ -2996,7 +3149,8 @@ class H264Encoder(Encoder):
 
     # where each per-frame path's token keeps the device array its collect
     # pulls FIRST (the guessed prefix; models/prefix_pull.py)
-    _PREFIX_AT = {"intra": 5, "p": 5, "cabac_intra": 2, "cabac_p": 4}
+    _PREFIX_AT = {"intra": 5, "p": 5, "cabac_intra": 2, "cabac_p": 4,
+                  "cabac_p_mask": 6}
     # ... and where a P frame's marked payload does: a mesh's, a damage
     # mask's row program's
     _MARKED_PREFIX_AT = {"sp": 6, "sp_bin": 6, "dmg": 5}
@@ -3037,6 +3191,8 @@ class H264Encoder(Encoder):
                 data = self._collect_p_device(payload)
             elif kind == "cabac_p":
                 data = self._collect_cabac_p(payload)
+            elif kind == "cabac_p_mask":
+                data = self._collect_cabac_p_masked(payload)
             elif kind == "cabac_intra":
                 data = self._collect_cabac_intra(payload)
             else:

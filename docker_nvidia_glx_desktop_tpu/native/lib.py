@@ -244,6 +244,32 @@ def cabac_engine_rows(payload: np.ndarray, row_off: np.ndarray,
     return out, lens
 
 
+def cabac_engine_rows_tail(payload: np.ndarray, row_off: np.ndarray,
+                           row_bits: np.ndarray, rows: int, qp: int,
+                           ctx_init, rng, tmps, tlps, cap: int,
+                           tail: bytes):
+    """:func:`cabac_engine_rows` over the first ``rows`` record streams of
+    a row BAND (a damage-masked frame's worklist), with ``tail`` laid
+    behind the engine's ``rows * cap`` output bytes in the one buffer it
+    returns: the slice data every unplanned row of the frame shares, so
+    that ``annexb_rows`` frames planned and unplanned rows from one
+    source, each where it lies.  The native entry is
+    :func:`cabac_engine_rows`'s, as it is; the same failure codes."""
+    lib = get_lib()
+    assert lib is not None and _ENGINE_OK
+    out = np.empty(rows * cap + len(tail), np.uint8)
+    out[rows * cap:] = np.frombuffer(tail, np.uint8)
+    lens = np.zeros(rows, np.int64)
+    rc = lib.h264_cabac_engine_rows(
+        np.ascontiguousarray(payload, np.uint32),
+        np.ascontiguousarray(row_off, np.int64),
+        np.ascontiguousarray(row_bits, np.int64),
+        rows, int(qp), ctx_init, rng, tmps, tlps, out, lens, cap)
+    if rc != 0:
+        return int(rc)
+    return out, lens
+
+
 def has_level_unpack() -> bool:
     return get_lib() is not None and _LEVELPACK_OK
 

@@ -53,7 +53,8 @@ from . import metrics as obsm
 __all__ = ["TraceRecorder", "tracer", "tracers", "next_frame_id",
            "export_chrome_trace", "set_enabled", "enabled",
            "dropped_total", "DEFAULT_CAPACITY", "stage", "STAGES",
-           "CABAC_STAGES", "MESH_STAGES", "MASK_STAGES", "TURN_STAGES",
+           "CABAC_STAGES", "MESH_STAGES", "MASK_STAGES", "MASK_CABAC_STAGES",
+           "TURN_STAGES",
            "STAGE_BUCKETS_MS", "M_WS_SEND_MS"]
 
 DEFAULT_CAPACITY = 4096      # spans per recorder (ring; oldest evicted)
@@ -113,6 +114,11 @@ MESH_STAGES = ("stitch",)
 # (models/h264.py ``_damage_plan``, ops/damage_mask.damage_grid_np); one
 # sample a planned P frame, none on an IDR.
 MASK_STAGES = ("damage_grid",)
+# ... and, under the CABAC stream, inside ``assemble`` beside ``engine``:
+# making or fetching the slice data the frame's unplanned rows leave as
+# (bitstream/h264_cabac.py ``encode_p_rows_from_binstream``); one sample a
+# frame of the CABAC row program.
+MASK_CABAC_STAGES = ("skip_slices",)
 # The rest of a turn of the session thread (PR 38), so that the device's
 # idle gaps fall under a span wherever the host is: ``stats``, the content
 # statistics' pull that ends ``H264Encoder.encode_collect`` (one sample a
@@ -209,13 +215,14 @@ def stage(name: str, more: bool = False) -> _StageSpan:
 
     Host stages: ``STAGES`` (the frame's own work), ``CABAC_STAGES`` and
     ``MESH_STAGES`` (inside ``assemble``), ``MASK_STAGES`` (in front of
-    ``dispatch``), ``TURN_STAGES`` (``stats``,
+    ``dispatch``), ``MASK_CABAC_STAGES`` (inside ``assemble``),
+    ``TURN_STAGES`` (``stats``,
     ``publish``, ``await``: the rest of the session thread's turn)."""
     return _StageSpan(*_stage_def(name), more)
 
 
 for _name in (STAGES + CABAC_STAGES + MESH_STAGES + MASK_STAGES
-              + TURN_STAGES):
+              + MASK_CABAC_STAGES + TURN_STAGES):
     _stage_def(_name)
 
 # The one stage that crosses threads, so it is no profiler span: stamped

@@ -42,6 +42,17 @@ keep the static form (:func:`encode_p_rows`).  The flat buffer has one
 length whatever the bucket, so the pull ladder's slices are the dense
 frame's.
 
+Under the CABAC stream (``ENCODER_ENTROPY=cabac`` with the binarizer on the
+device; PR 43) the worklist goes through :func:`row_step_cabac`
+(``jit_encode_p_rows_cabac_b<bucket>``): the same gather, the same stages
+under ``jax.vmap``, the loop filter and the scatter, and NO entropy stage —
+the band's vectors and levels go to ``ops/cabac_binarize.binarize_p`` as
+they are (a row is a slice there too, so nothing crosses a row), the host
+engine codes the planned rows' record streams, and the untouched rows leave
+as all-skip CABAC slices (``bitstream/h264_cabac.py``:
+``skip_row_payload``, ``encode_p_rows_from_binstream``).  Its record buffer
+grows with the bucket, so every bucket has a pull ladder of its own.
+
 Knobs (all warn-and-default, utils/env):
 
 - ``DNGD_DAMAGE_MASK``        master gate for damage-driven encode
@@ -75,7 +86,8 @@ except Exception:                          # the numpy form serves
 __all__ = [
     "enabled", "cost_floor", "damage_factor", "damage_grid_np",
     "plan_rows", "RowPlan", "bucket_ladder", "encode_p_rows",
-    "row_step", "ROW_STEP_DYNQP_STATIC", "row_core",
+    "row_step", "ROW_STEP_DYNQP_STATIC", "row_core", "MASKED_ENTROPY",
+    "row_core_cabac", "row_step_cabac",
     "skip_slice_nal", "assemble_masked_au", "force_skip_rows",
     "scatter_levels_np",
 ]
@@ -221,9 +233,33 @@ def row_core(y, cb, cr, ref_y, ref_cb, ref_cr, rows, hv_r, hl_r,
     full reference planes — downstream (pull-prefix, ring chain,
     overflow fallback) is shape-compatible by construction.
     """
-    from . import cavlc_p_device, h264_deblock, h264_inter
+    from . import cavlc_p_device, h264_deblock
 
-    h, w = ref_y.shape
+    out = _code_rows(y, cb, cr, ref_y, ref_cb, ref_cr, rows, qp, tune,
+                     next_y, p_intra)
+    flat, ry, rcb, rcr, mv, nnz, levels = cavlc_p_device._finish_p(
+        out, hv_r, hl_r, slice_qp=qp)
+    if deblock:
+        # idc=2 keeps every MB row independent, so filtering the
+        # compacted row stack equals filtering the full frame and
+        # gathering — the same argument the spatial shards rest on
+        ry, rcb, rcr = h264_deblock.deblock_frame.__wrapped__(
+            ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv.astype(jnp.int32))
+    new_ry, new_rcb, new_rcr = _scatter_rows(
+        ref_y, ref_cb, ref_cr, rows, ry, rcb, rcr)
+    return flat, new_ry, new_rcb, new_rcr, mv, nnz, levels
+
+
+def _code_rows(y, cb, cr, ref_y, ref_cb, ref_cr, rows, qp, tune: str,
+               next_y, p_intra: bool) -> dict:
+    """The way IN and the shared stages of both row programs (CAVLC's
+    :func:`row_core`, CABAC's :func:`row_core_cabac`): the worklist's
+    bands gathered out of the frame and the padded references, the
+    row-generic inter core under ``jax.vmap``, its outputs merged into
+    ONE frame of ``rows.shape[0]`` rows."""
+    from . import h264_inter
+
+    w = ref_y.shape[1]
     wc = w // 2
     rb = rows.shape[0]
     # dngd.mask_gather: the references' pad (the WHOLE planes, as int32:
@@ -260,24 +296,23 @@ def row_core(y, cb, cr, ref_y, ref_cb, ref_cr, rows, hv_r, hl_r,
     outs = jax.vmap(jax.named_scope("row")(one))(rows)
     # per-row outputs carry a singleton row axis: (R_b, 1, C, ...) MB
     # tensors and (R_b, 16|8, W) planes — merge into one R_b-row frame
-    # so _finish_p packs ONE flat buffer across the worklist
+    # so the entropy stage packs ONE buffer across the worklist
     out = {}
     with jax.named_scope("dngd.mask_gather"):
         for k, v in outs.items():
             out[k] = v.reshape((rb * v.shape[1],) + v.shape[2:]) \
                 if k.startswith("recon") else \
                 v.reshape((rb,) + v.shape[2:])
-    flat, ry, rcb, rcr, mv, nnz, levels = cavlc_p_device._finish_p(
-        out, hv_r, hl_r, slice_qp=qp)
-    if deblock:
-        # idc=2 keeps every MB row independent, so filtering the
-        # compacted row stack equals filtering the full frame and
-        # gathering — the same argument the spatial shards rest on
-        ry, rcb, rcr = h264_deblock.deblock_frame.__wrapped__(
-            ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv.astype(jnp.int32))
-    # scatter the (possibly filtered) recon rows back into the ring;
-    # duplicate padded indices write identical values, so scatter order
-    # cannot matter
+    return out
+
+
+def _scatter_rows(ref_y, ref_cb, ref_cr, rows, ry, rcb, rcr):
+    """The way OUT of both row programs: the (possibly filtered) recon
+    rows back into the ring; duplicate padded indices write identical
+    values, so scatter order cannot matter."""
+    h, w = ref_y.shape
+    wc = w // 2
+    rb = rows.shape[0]
     with jax.named_scope("dngd.mask_scatter"):
         new_ry = jnp.asarray(ref_y).reshape(h // 16, 16, w).at[rows].set(
             ry.reshape(rb, 16, w)).reshape(h, w)
@@ -285,7 +320,7 @@ def row_core(y, cb, cr, ref_y, ref_cb, ref_cr, rows, hv_r, hl_r,
             rcb.reshape(rb, 8, wc)).reshape(h // 2, wc)
         new_rcr = jnp.asarray(ref_cr).reshape(h // 16, 8, wc).at[rows].set(
             rcr.reshape(rb, 8, wc)).reshape(h // 2, wc)
-    return flat, new_ry, new_rcb, new_rcr, mv, nnz, levels
+    return new_ry, new_rcb, new_rcr
 
 
 @functools.partial(jax.jit,
@@ -328,6 +363,61 @@ def row_step(bucket: int):
 
     step.__name__ = step.__qualname__ = f"encode_p_rows_b{bucket}"
     return jax.jit(step, static_argnames=ROW_STEP_DYNQP_STATIC,
+                   donate_argnames=RING_DONATE)
+
+
+#: The entropy placements whose P frames the mask compacts
+#: (``H264Encoder._damage_plan``): the device CAVLC path (:func:`row_step`)
+#: and, since PR 43, the CABAC path with the binarizer on the device
+#: (:func:`row_step_cabac`).  (The benchmark's masked CABAC cell reads
+#: this before it touches the chip: a program that does not say, or does
+#: not name ``cabac``, drops the mask there and serves dense.)
+MASKED_ENTROPY = ("device", "cabac")
+
+
+def row_core_cabac(y, cb, cr, ref_y, ref_cb, ref_cr, rows, qp,
+                   deblock: bool = False):
+    """The CABAC stream's row-compacted P encode (tune="off"): the
+    stages :func:`row_core` shares (:func:`_code_rows`), the loop filter
+    over the worklist from the bS inputs the dense CABAC path takes
+    (``models/h264._cabac_bs_inputs``: the luma levels' coded-block flags
+    and the vectors), and the scatter into the reference planes.  No
+    ``slots`` and no ``pack``: the worklist's vectors and levels go to
+    ``ops/cabac_binarize.binarize_p`` as a band of ``rows.shape[0]`` rows
+    (a row is a slice, so the band's records ARE the dense frame's for
+    those rows).  Returns ``(ref_y', ref_cb', ref_cr', mv, levels)``."""
+    from . import h264_deblock
+    from .h264_device import nnz_blocks_raster
+
+    out = _code_rows(y, cb, cr, ref_y, ref_cb, ref_cr, rows, qp, "off",
+                     None, False)
+    ry, rcb, rcr, mv = (out["recon_y"], out["recon_cb"], out["recon_cr"],
+                        out["mv"])
+    if deblock:
+        with jax.named_scope("dngd.deblock_bs"):
+            nnz, mv32 = nnz_blocks_raster(out["luma"]), mv.astype(jnp.int32)
+        ry, rcb, rcr = h264_deblock.deblock_frame.__wrapped__(
+            ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv32)
+    refs = _scatter_rows(ref_y, ref_cb, ref_cr, rows, ry, rcb, rcr)
+    levels = {k: out[k] for k in ("luma", "cb_dc", "cb_ac", "cr_dc",
+                                  "cr_ac")}
+    return (*refs, mv, levels)
+
+
+@functools.lru_cache(maxsize=None)
+def row_step_cabac(bucket: int):
+    """The served CABAC row step of one bucket, ``qp`` traced: one
+    compiled program a bucket whatever the rate controller asks for,
+    ``jit_encode_p_rows_cabac_b<bucket>`` on the device trace (a frame
+    is counted by it, and the rows a traced frame gathered are read off
+    its name, as off :func:`row_step`'s)."""
+    def step(y, cb, cr, ref_y, ref_cb, ref_cr, rows, qp, deblock=False):
+        assert rows.shape[0] == bucket, (rows.shape, bucket)
+        return row_core_cabac(y, cb, cr, ref_y, ref_cb, ref_cr, rows, qp,
+                              deblock=deblock)
+
+    step.__name__ = step.__qualname__ = f"encode_p_rows_cabac_b{bucket}"
+    return jax.jit(step, static_argnames=("deblock",),
                    donate_argnames=RING_DONATE)
 
 

@@ -19,7 +19,8 @@ deployment's 240 macroblocks a row: a new shape is a new compile), a damage
 mask's row program at a bucket of 8 of the 68 rows (``ops/damage_mask``:
 the P step's stages under ``jax.vmap`` over row bands, the packer and the
 loop filter over the worklist's rows, the recon scattered into the donated
-ring; qp traced), the (4,1)
+ring; qp traced), the same worklist through the CABAC stream's row
+program and the binarizer over that band of 8 rows (PR 43), the (4,1)
 session-mesh step of ``TPU_SESSIONS``/``TPU_MESH`` on a ``Mesh`` of the
 four described devices, and a P step of two sessions a chip (``jax.vmap``
 over the kernels).
@@ -131,6 +132,21 @@ def programs(topo, no_persistent_cache):
             lambda *a: rows_body(*a, tune="off", next_y=None,
                                  p_intra=False, deblock=True),
             donate_argnums=(3, 4, 5)).lower(y, c, c, y, c, c, *work, qp)
+        # H264Encoder._submit_cabac_p_masked (PR 43): the same worklist
+        # through the CABAC stream's row step (no slots, no pack; the loop
+        # filter inside, the ring donated), and the binarizer over the
+        # band of 8 rows it hands on
+        rows_cabac_body = damage_mask.row_step_cabac(8).__wrapped__
+        cabac_work = (y, c, c, y, c, c, work[0], qp)
+        lowered["rows_cabac_b8"] = jax.jit(
+            lambda *a: rows_cabac_body(*a, deblock=True),
+            donate_argnums=(3, 4, 5)).lower(*cabac_work)
+        band = on_chip(jax.eval_shape(
+            lambda *a: rows_cabac_body(*a, deblock=True), *cabac_work))
+        lowered["binarize_band8"] = jax.jit(
+            lambda *a: cabac_binarize.binarize_p.__wrapped__(*a)).lower(
+                band[3], *(band[4][k] for k in (
+                    "luma", "cb_dc", "cb_ac", "cr_dc", "cr_ac")))
         # H264Encoder._deblock as the served path calls it (traced qp)
         lowered["deblock_p"] = jax.jit(
             h264_deblock.deblock_frame.__wrapped__).lower(
@@ -251,6 +267,18 @@ def test_row_program_compiles_scatters_in_place_and_keeps_the_kernels(
     assert c.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
+def test_cabac_row_program_compiles_with_the_loop_filter_and_no_packer(
+        programs):
+    c = _compiled(programs, "rows_cabac_b8")
+    assert 0 < _device_bytes(c) < HBM_BYTES
+    assert c.memory_analysis().alias_size_in_bytes >= H * W * 3 // 2
+    # the loop filter's kernel and nothing of an entropy stage
+    text = c.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "dngd_deblock_edges" in text and "cabac_compact" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 @pytest.mark.parametrize("name", ["deblock_p", "deblock_p_2160"])
 def test_p_deblock_compiles_with_the_edge_kernel(programs, name):
     c = _compiled(programs, name)
@@ -262,7 +290,7 @@ def test_p_deblock_compiles_with_the_edge_kernel(programs, name):
 
 
 @pytest.mark.parametrize("name", ["binarize_p", "binarize_intra",
-                                  "binarize_p_2160"])
+                                  "binarize_p_2160", "binarize_band8"])
 def test_binarize_compiles_with_the_pack_kernels(programs, name):
     c = _compiled(programs, name)
     assert 0 < _device_bytes(c) < HBM_BYTES

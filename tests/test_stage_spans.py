@@ -495,6 +495,14 @@ SCOPES = {
              "dngd.slots", "dngd.pack", "dngd.deblock_bs",
              "dngd.deblock_tile", "dngd.deblock_edges", "dngd.deblock_v",
              "dngd.deblock_h", "dngd.mask_scatter"),
+    # ... and the CABAC stream's (ISSUE 43): no slots and no pack, and the
+    # binarizer over the worklist's band as a program of its own
+    "rows_cabac": ("dngd.mask_gather", "dngd.ingest", "dngd.me_int",
+                   "dngd.me_subpel", "dngd.mc", "dngd.tq", "dngd.recon",
+                   "dngd.deblock_bs", "dngd.deblock_tile",
+                   "dngd.deblock_edges", "dngd.deblock_v", "dngd.deblock_h",
+                   "dngd.mask_scatter"),
+    "binarize_band": ("dngd.binarize",),
 }
 
 
@@ -563,6 +571,17 @@ def lowered():
             lv_i, "luma_dc", "luma_ac", "cb_dc", "cb_ac", "cr_dc", "cr_ac",
             "pred_mode", "mb_i4", "i4_modes", "luma_i4")),
         "cabac_bs": _cabac_bs_inputs.lower(*zeros(lv_p, "luma", "mv")),
+    })
+    # H264Encoder._submit_cabac_p_masked: the same worklist through the
+    # CABAC row step, its vectors and levels into the binarizer as a band
+    rows_cabac = damage_mask.row_step_cabac(2)
+    work = (y, c, c, y, c, c, np.array([2, 5], np.int32), qp)
+    band = jax.eval_shape(lambda *a: rows_cabac(*a, deblock=True), *work)
+    progs.update({
+        "rows_cabac": rows_cabac.lower(*work, deblock=True),
+        "binarize_band": cabac_binarize.binarize_p.lower(
+            np.zeros(band[3].shape, band[3].dtype), *zeros(
+                band[4], "luma", "cb_dc", "cb_ac", "cr_dc", "cr_ac")),
     })
     flag = "jax_compilation_cache_include_metadata_in_key"
     was = getattr(jax.config, flag)

@@ -9,7 +9,10 @@ either way.  The real encoder
 CAVLC encoder under ``DNGD_DAMAGE_MASK`` on pictures of which only the top
 rows change, so that its P frames are the row program's (ISSUE 40: the row
 plan is made in the first half, and a mask session's turn has the two
-halves of any other)."""
+halves of any other); ``cabac_mask``: the same pictures through the CABAC
+encoder under the mask (ISSUE 43), whose P frames are ``cabac_p_mask``
+tokens: ``token_ready`` answers by their prefix, so the early collect takes
+them as it takes any other."""
 
 import numpy as np
 import pytest
@@ -43,22 +46,28 @@ def calm_frame(c: int) -> np.ndarray:
     return out
 
 
+MASKS = {"mask": "device", "cabac_mask": "cabac"}
+
+
 def pictures(kind: str):
-    return calm_frame if kind == "mask" else frame
+    return calm_frame if kind in MASKS else frame
 
 
 def new_encoder(kind: str):
     """``device`` or ``cabac``: the entropy coder; ``mask``: ``device``
-    under the damage mask."""
-    mask = kind == "mask"
+    under the damage mask; ``cabac_mask``: ``cabac`` under it (ISSUE 43:
+    the row program of the CABAC stream, a token kind of its own)."""
+    mask = kind in MASKS
     cfg = from_env({"PASSWD": "pw", "SIZEW": str(W), "SIZEH": str(H),
                     "REFRESH": "60",
-                    "ENCODER_ENTROPY": "device" if mask else kind,
+                    "ENCODER_ENTROPY": MASKS.get(kind, kind),
                     "ENCODER_CABAC_BINARIZE": "device",
                     "ENCODER_BITRATE_KBPS": "100" if mask else "300",
                     "ENCODER_GOP": "60", "ENCODER_PREWARM": "false"})
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("DNGD_DAMAGE_MASK", "true" if mask else "false")
+        if kind == "cabac_mask":   # (the encoder asks the environment, once)
+            mp.setenv("ENCODER_CABAC_BINARIZE", "device")
         enc, _ = make_encoder(cfg, W, H)
     assert enc._dyn_qp and enc._rate is not None
     assert enc.damage_mask == mask
@@ -142,7 +151,8 @@ def mask_frames() -> dict:
             "dense": h264._M_MASK_FRAMES_DENSE.value}
 
 
-@pytest.fixture(scope="module", params=["device", "cabac", "mask"])
+@pytest.fixture(scope="module",
+                params=["device", "cabac", "mask", "cabac_mask"])
 def both_orders(request):
     """One run forced to the early order and one forced to today's."""
     mp = pytest.MonkeyPatch()
@@ -163,7 +173,7 @@ def test_both_orders_give_the_same_access_units(both_orders):
     assert enc_e._rate.level == enc_l._rate.level
     assert enc_e._rate.pending_count == enc_l._rate.pending_count
     # ... and under the mask every P frame was the row program's
-    rows = len(run_e.taken) - 1 if both_orders[0] == "mask" else 0
+    rows = len(run_e.taken) - 1 if both_orders[0] in MASKS else 0
     assert run_e.row_frames == run_l.row_frames == rows
 
 
@@ -186,7 +196,8 @@ def test_the_qp_walked_so_the_order_could_have_shown(both_orders):
     assert enc._rate._step_idx != enc._rate.STEPS.index(0)
 
 
-@pytest.mark.parametrize("entropy", ["device", "cabac", "mask"])
+@pytest.mark.parametrize("entropy", ["device", "cabac", "mask",
+                                     "cabac_mask"])
 def test_a_hook_that_does_nothing_changes_no_token_and_no_byte(entropy):
     """``encode_submit`` is the two halves back to back, with or without a
     caller between them."""
