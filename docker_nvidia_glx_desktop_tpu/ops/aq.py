@@ -4,8 +4,8 @@ Three device-side pieces that trade device cycles for bits — the NVENC
 tuning-ladder analog (PAPERS.md: "Evolution of NVENC Efficiency"):
 
 1. **Adaptive per-MB quantization** (:func:`aq_offsets`): a per-MB QP
-   delta plane from luma activity (variance), computed as one reduction
-   over the already-tiled 16x16 blocks.  Low-activity (flat) macroblocks
+   delta plane from luma activity (variance), two per-macroblock sums
+   over the plane (:func:`_mb_sum`).  Low-activity (flat) macroblocks
    quantize finer — they are cheap in bits and visually/numerically
    dominant; high-activity blocks absorb coarser quantization.  The map
    is a PURE PER-MB function (log-activity against a fixed reference
@@ -67,19 +67,27 @@ LOOKAHEAD_BIAS = int(_envf("DNGD_LOOKAHEAD_BIAS", 2))
 _AQ_REF_LOG = 12.0
 
 
-def _mb_reduce(plane, op):
-    """(H, W) -> (R, C) per-16x16-MB reduction."""
+def _mb_sum(plane):
+    """(H, W) -> (R, C) per-16x16-MB sum, rows first and then columns.
+
+    The rows are summed with the picture's width on the lanes, and only
+    the sixteenth-sized (R, W) result is split by columns.  Summed in one
+    step over ``reshape(R, 16, C, 16)`` a frame-sized array has a minor
+    dimension of 16, which the TPU tiles (8, 128): eight times its size,
+    and from 2560x1600 up too large for the fast memory (1.35 ms a frame
+    of ``frame_stats`` at that size, 2.6 at 4K: PERF.md Findings, PR 44).
+    Integer sums in either order: the same bits."""
     h, w = plane.shape
-    t = plane.reshape(h // 16, 16, w // 16, 16)
-    return op(t, (1, 3))
+    rows = plane.reshape(h // 16, 16, w).sum(1)
+    return rows.reshape(h // 16, w // 16, 16).sum(2)
 
 
 def mb_activity(y):
     """Per-MB luma activity: sum of squared deviation from the MB mean
-    (256 * variance), int32-exact.  One reduction over the tiled plane."""
+    (256 * variance), int32-exact."""
     yi = jnp.asarray(y, jnp.int32)
-    s = _mb_reduce(yi, jnp.sum)                       # (R, C)
-    s2 = _mb_reduce(yi * yi, jnp.sum)
+    s = _mb_sum(yi)                                   # (R, C)
+    s2 = _mb_sum(yi * yi)
     # 256 * var = sum(x^2) - sum(x)^2 / 256; keep integer via * 256
     return jnp.maximum(256 * s2 - s * s, 0)           # (R, C) ~2^24 max
 
@@ -107,7 +115,7 @@ def lookahead_bias(y, next_y, bias: int = None):
     Thresholds are per-pixel mean-abs-diff 1.0 / 6.0."""
     b = LOOKAHEAD_BIAS if bias is None else int(bias)
     d = jnp.abs(jnp.asarray(y, jnp.int32) - jnp.asarray(next_y, jnp.int32))
-    sad = _mb_reduce(d, jnp.sum)                      # (R, C), /256 = mean
+    sad = _mb_sum(d)                                  # (R, C), /256 = mean
     return jnp.where(sad <= 256, -b,
                      jnp.where(sad >= 6 * 256, 1, 0)).astype(jnp.int32)
 

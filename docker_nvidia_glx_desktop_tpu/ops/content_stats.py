@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .aq import _mb_reduce, mb_activity
+from .aq import _mb_sum, mb_activity
 
 __all__ = ["VEC_LEN", "frame_stats", "chunk_stats", "frame_stats_np",
            "mb_activity_np", "psnr_from_sse", "vec_to_stats",
@@ -72,7 +72,7 @@ def _damage_grid(y, prev_y, thr_sad: int):
     diff > ``thr_sad`` (the knob is a mean-per-pixel threshold scaled by
     256 host-side, so the device compare stays integer-exact)."""
     d = jnp.abs(jnp.asarray(y, jnp.int32) - jnp.asarray(prev_y, jnp.int32))
-    sad = _mb_reduce(d, jnp.sum)                       # (R, C) int32
+    sad = _mb_sum(d)                                   # (R, C) int32
     return (sad > thr_sad).astype(jnp.uint8)
 
 
@@ -81,7 +81,7 @@ def _luma_sse(y, recon_y):
     float32 — relative error ~1e-7, versus the 0.23% MSE slack a 0.01 dB
     PSNR tolerance allows."""
     d = jnp.asarray(y, jnp.int32) - jnp.asarray(recon_y, jnp.int32)
-    mb_sse = _mb_reduce(d * d, jnp.sum)                # (R, C) int32
+    mb_sse = _mb_sum(d * d)                            # (R, C) int32
     return jnp.sum(mb_sse.astype(jnp.float32))
 
 

@@ -23,7 +23,8 @@ ring; qp traced), the same worklist through the CABAC stream's row
 program and the binarizer over that band of 8 rows (PR 43), the (4,1)
 session-mesh step of ``TPU_SESSIONS``/``TPU_MESH`` on a ``Mesh`` of the
 four described devices, and a P step of two sessions a chip (``jax.vmap``
-over the kernels).
+over the kernels), and the content statistics' program at 2560x1600 and
+3840x2176, whose temporaries are read (PR 44).
 
 Tier-1 on purpose (not in conftest's ``_SLOW_MODULES``).  Only one
 process may hold the TPU library, so everything that touches the
@@ -77,6 +78,7 @@ def programs(topo, no_persistent_cache):
     from docker_nvidia_glx_desktop_tpu.ops import (cabac_binarize,
                                                    cavlc_device,
                                                    cavlc_p_device,
+                                                   content_stats,
                                                    damage_mask,
                                                    h264_deblock)
     from docker_nvidia_glx_desktop_tpu.parallel import batch
@@ -179,6 +181,19 @@ def programs(topo, no_persistent_cache):
                 *a, **kw)).lower(y4, c4, c4, qp,
                                  nnz_blk=at4(jnp.bool_, 4, 4),
                                  mv=at4(jnp.int32, 2))
+        # H264Encoder._content_submit beside a dense P frame: the luma,
+        # the one before, the recon, the vectors and the five level
+        # tensors as the P program hands them on, no intra map
+        for hs, ws in ((1600, 2560), (h4, w4)):
+            mb = lambda dt, *shape: jax.ShapeDtypeStruct(
+                (hs // 16, ws // 16) + shape, dt, sharding=one)
+            ys = jax.ShapeDtypeStruct((hs, ws), jnp.uint8, sharding=one)
+            lowered[f"frame_stats_{ws}x{hs}"] = jax.jit(
+                lambda *a: content_stats.frame_stats.__wrapped__(
+                    *a, None, 512)).lower(
+                        ys, ys, ys, mb(jnp.int8, 2),
+                        tuple(mb(jnp.int16, *sh) for sh in (
+                            (16, 16), (4,), (4, 15), (4,), (4, 15))))
         # web/multisession: four 1080p sessions, one per chip
         mesh = batch.make_mesh((4, 1), topo.devices)
         planes = lambda m, n: tuple(
@@ -299,6 +314,19 @@ def test_binarize_compiles_with_the_pack_kernels(programs, name):
     text = c.as_text()
     _has_the_pack_kernels(text)
     assert " while(" not in text
+
+
+@pytest.mark.parametrize("size", ["2560x1600", "3840x2176"])
+def test_frame_stats_holds_no_padded_picture(programs, size):
+    """The statistics read three planes and a few small tensors, and hold
+    little beside them.  Reduced to macroblocks over (R, 16, C, 16) in one
+    step, each of the four ``int32`` pictures was laid out with 16 of 128
+    lanes in use, eight times its size, and from this size up those copies
+    no longer fit the fast memory: 126.5 and 288.7 MiB of temporaries,
+    1.5 and 3.0 ms a frame on the chip.  This test FAILS on the tree of
+    before PR 44, which is its point."""
+    c = _compiled(programs, f"frame_stats_{size}")
+    assert 0 < c.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
 
 
 def test_session_mesh_step_fits_each_chip(programs):

@@ -120,6 +120,72 @@ class TestKernelsVsOracle:
         np.testing.assert_array_equal(
             np.asarray(mb_activity(y), np.int64), cs.mb_activity_np(y))
 
+    @pytest.mark.parametrize("frames", ["random", "extreme"])
+    @pytest.mark.parametrize("w,h", [(1920, 1088), (2560, 1600),
+                                     (3840, 2176)])
+    def test_served_sizes_match_oracle(self, w, h, frames):
+        """The served call (three ``uint8`` planes, the ``int8`` vectors,
+        the five ``int16`` level tensors, no intra map) at the served
+        sizes: the per-macroblock sums go rows first, then columns (PR 44),
+        and stay the oracle's one-step sums to the bit, also where every
+        sum is at its maximum (SSE 256 x 255^2 a macroblock)."""
+        rng = np.random.default_rng(w)
+        r, c = h // 16, w // 16
+        if frames == "random":
+            y, prev, recon = (rng.integers(0, 256, (h, w), dtype=np.uint8)
+                              for _ in range(3))
+            prev[: h // 2] = y[: h // 2]        # half the grid undamaged
+        else:
+            y = np.full((h, w), 255, np.uint8)
+            prev = recon = np.zeros((h, w), np.uint8)
+        mv = rng.integers(-8, 9, (r, c, 2)).astype(np.int8)
+        mv[rng.random((r, c)) < 0.5] = 0
+        resid = tuple(
+            (rng.integers(-2, 3, (r, c) + sh)
+             * (rng.random((r, c) + (1,) * len(sh)) < 0.3)).astype(np.int16)
+            for sh in ((16, 16), (4,), (4, 15), (4,), (4, 15)))
+        vec_d, grid_d = cs.frame_stats(y, prev, recon, mv, resid, None, 512)
+        vec_o, grid_o = cs.frame_stats_np(y, prev, recon, mv, resid,
+                                          None, 512)
+        vec_d = np.asarray(vec_d, np.float64)
+        np.testing.assert_array_equal(np.asarray(grid_d), grid_o)
+        for idx in (cs.IDX_DAMAGE, cs.IDX_SKIP, cs.IDX_INTER,
+                    cs.IDX_INTRA, cs.IDX_MBS):
+            assert vec_d[idx] == vec_o[idx], idx
+        assert 0 < vec_o[cs.IDX_SKIP] < r * c
+        assert vec_o[cs.IDX_DAMAGE] == (r * c if frames == "extreme"
+                                        else r * c // 2)
+        if frames == "extreme":
+            assert vec_o[cs.IDX_SSE] == 255.0 ** 2 * h * w
+        p_d = cs.psnr_from_sse(float(vec_d[cs.IDX_SSE]), h * w)
+        p_o = cs.psnr_from_sse(float(vec_o[cs.IDX_SSE]), h * w)
+        assert abs(p_d - p_o) < 0.01
+        for idx in (cs.IDX_MV_MEAN, cs.IDX_MV_P95,
+                    cs.IDX_ACT_P50, cs.IDX_ACT_P95):
+            np.testing.assert_allclose(vec_d[idx], vec_o[idx],
+                                       rtol=1e-5, atol=1e-3)
+
+    def test_activity_and_aq_match_the_one_step_sums(self, monkeypatch):
+        """``mb_activity`` and ``aq_offsets`` against themselves over the
+        per-macroblock sums taken in one step over (R, 16, C, 16)."""
+        from docker_nvidia_glx_desktop_tpu.ops import aq
+
+        def one_step(plane):
+            h, w = plane.shape
+            return plane.reshape(h // 16, 16, w // 16, 16).sum((1, 3))
+
+        # noise of another strength in every macroblock: flat to busy
+        rng = np.random.default_rng(44)
+        amp = rng.choice([0, 1, 4, 16, 64], (100, 160)).repeat(
+            16, 0).repeat(16, 1)
+        y = np.clip(128 + amp * rng.standard_normal((1600, 2560)),
+                    0, 255).astype(np.uint8)
+        act, offs = np.asarray(aq.mb_activity(y)), np.asarray(aq.aq_offsets(y))
+        monkeypatch.setattr(aq, "_mb_sum", one_step)
+        np.testing.assert_array_equal(act, np.asarray(aq.mb_activity(y)))
+        np.testing.assert_array_equal(offs, np.asarray(aq.aq_offsets(y)))
+        assert len(np.unique(offs)) >= 4
+
 
 class TestVecDecode:
     def test_psnr_from_sse(self):
