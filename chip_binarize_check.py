@@ -16,7 +16,7 @@ six (the desktop at qp 32, full damage at qp 20 and 44), each against
 XLA:CPU's from the same level tensors, whole buffer, word for word; then the
 programs' device time.  One JSON line a picture; the last line is
 ``ALL_IDENTICAL`` and the exit code 0 only if all eighteen are.
-``--geometry WxH`` runs one of the two sizes alone.
+``--geometry WxH`` runs one size alone (with ``--cavlc`` too).
 
 ``--cavlc``: the same for the CAVLC programs' ``flat`` (``cavlc_device.
 pack_frame``: the same two kernels on the TPU since PR 31), at 1920x1088 and
@@ -126,7 +126,7 @@ def _pack_scopes(path) -> dict:
             for prog, by in out.items()}
 
 
-def cavlc() -> int:
+def cavlc(sizes) -> int:
     """The CAVLC ``flat`` of the chip (the kernels) against XLA:CPU's (the
     bitmerge hierarchy) on the same levels, then where the time goes."""
     from docker_nvidia_glx_desktop_tpu.ops import cavlc_device
@@ -135,7 +135,7 @@ def cavlc() -> int:
     out_dir = ROOT / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
     same = True
-    for w, h in ((1920, 1088), (2560, 1600)):
+    for w, h in sizes:
         kinds = ("p", "intra")
         on_chip = {k: _program(f"flat_{k}", k) for k in kinds}
         before = {k: _program(f"flat_{k}_bitmerge", k) for k in kinds}
@@ -191,18 +191,19 @@ def main() -> int:
     if jax.default_backend() != "tpu":
         print(f"no TPU: the default backend is {jax.default_backend()!r}")
         return 2
+    sizes = ([(1920, 1088), (2560, 1600)] if "--cavlc" in sys.argv[1:]
+             else list(QPS))
+    if "--geometry" in sys.argv[1:]:
+        w, h = sys.argv[sys.argv.index("--geometry") + 1].lower().split("x")
+        sizes = [(int(w), int(h))]
     if "--cavlc" in sys.argv[1:]:
-        return cavlc()
+        return cavlc(sizes)
     cpu = jax.devices("cpu")[0]
     # functions of their own: JAX keeps a trace by the function, and the
     # chip's programs are traces of ``cb.binarize_*``
     on_cpu = {"p": jax.jit(lambda *a: cb.binarize_p.__wrapped__(*a)),
               "intra": jax.jit(lambda *a: cb.binarize_intra.__wrapped__(*a))}
     on_chip = {"p": cb.binarize_p, "intra": cb.binarize_intra}
-    sizes = list(QPS)
-    if "--geometry" in sys.argv[1:]:
-        w, h = sys.argv[sys.argv.index("--geometry") + 1].lower().split("x")
-        sizes = [(int(w), int(h))]
     same = True
     for w, h in sizes:
         pics = list(pictures(w, h, QPS[w, h]))

@@ -2,8 +2,10 @@
 in VMEM.
 
 ``cabac_binarize._pack_stream`` turns (R, C, S) record slots into the
-version-2 transport buffer, ``cavlc_device.pack_frame`` (R, C, S) CAVLC
-slots into the rows of ``flat``.  Their XLA form (``bitmerge``'s dense L1
+version-2 transport buffer (``pack_rows``), ``cavlc_device.pack_frame`` CAVLC
+slots into the rows of ``flat`` (``pack_rows_slot_major``: its builder codes
+its blocks block-major and hands over (S, R * C) slot words, kernel A's own
+order, PR 45).  Their XLA form (``bitmerge``'s dense L1
 and merge trees) is right everywhere and stays the CPU path and the oracle,
 but on the chip every barrel-shifter stage of those trees is a round trip
 of the whole worst-case-sized buffer through HBM: 16 GB of passes to pack
@@ -33,7 +35,8 @@ doubles at 163 pieces plus a 131,072-word gather whatever the picture
       row's word offset.  Work follows the content, not the cap.
 
 XLA does what is left: packing (value, length) into one word, the bit
-counts and their prefix sums, two 2-D transposes, the header.  R, C, S and
+counts and their prefix sums, two 2-D transposes (one where the slots come
+slot-major), the header.  R, C, S and
 the piece size follow from the shapes and the caps: one algorithm for both
 entropy coders, the ring, the shards and the masked path's worklist.
 """
@@ -252,6 +255,16 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def slot_words(vals, lns):
+    """(value, length) -> one ``int32`` a slot, value | length << 26: what
+    kernel A reads.  The value of an empty slot is dropped."""
+    packed = (jnp.where(lns > 0,
+                        vals.astype(jnp.uint32)
+                        & ((1 << _LEN_SHIFT) - 1), 0)
+              | (lns.astype(jnp.uint32) << _LEN_SHIFT))
+    return jax.lax.bitcast_convert_type(packed, jnp.int32)
+
+
 def pack_rows(vals, lns, value_ovf, mb_cap: int, out_words: int):
     """(R, C, S) slots -> (overflow flag, per-row bit counts, payload of
     ``out_words`` words): every row's bits from a word of its own, the rows
@@ -263,7 +276,25 @@ def pack_rows(vals, lns, value_ovf, mb_cap: int, out_words: int):
     the caller found too long by its own caps; ``mb_cap`` words a
     macroblock and ``out_words`` in all are the static caps.  Everything
     else follows from the shapes."""
-    r, c, s = vals.shape
+    return _pack_rows(vals, lns, value_ovf, mb_cap, out_words, 2)
+
+
+def pack_rows_slot_major(slots, value_ovf, mb_cap: int, out_words: int):
+    """``pack_rows`` for a caller that builds its slots as kernel A reads
+    them: ``slot_words`` of shape (S, R * C), a slot of every macroblock
+    one lane-dense row (the CAVLC slot builder's block-major order,
+    ``cavlc_device.pack_frame``).  R and C are ``value_ovf``'s; the same
+    words."""
+    return _pack_rows(slots, None, value_ovf, mb_cap, out_words, 0)
+
+
+def _pack_rows(vals, lns, value_ovf, mb_cap: int, out_words: int,
+               slot_axis: int):
+    """The packer behind both ways in.  ``slot_axis`` 2: ``vals`` and
+    ``lns`` (R, C, S), packed and turned here; 0: ``vals`` the slot words
+    (S, R * C), their lengths read off them where they are summed."""
+    r, c = value_ovf.shape
+    s = vals.shape[slot_axis]
     s8 = _round_up(s, 8)
     n_groups = s8 // 8
     n = r * c
@@ -276,14 +307,21 @@ def pack_rows(vals, lns, value_ovf, mb_cap: int, out_words: int):
     c8 = _round_up(c, 8) if lines else c
 
     with jax.named_scope("cabac_offsets"):
-        lns = lns.astype(jnp.int32)
-        mb_bits = lns.sum(-1)                                   # (R, C)
+        if slot_axis:
+            lns = lns.astype(jnp.int32)
+            bits = lambda part: part
+        else:       # the lengths lie in the slot words: read where summed
+            lns = vals
+            bits = lambda words: _srl(words, _LEN_SHIFT)
+        mb_bits = bits(lns).sum(slot_axis).reshape(r, c)
         row_bits = mb_bits.sum(-1)
         total_words = ((row_bits + 31) >> 5).sum()
         # (kernel A drops what the last eight slots spill behind word S)
+        last_group = [(s8 - 8) * (ax == slot_axis) for ax in range(lns.ndim)]
         overflow = (value_ovf.any() | (mb_bits > 32 * mb_cap).any()
                     | (total_words > out_words)
-                    | (lns[..., s8 - 8:].sum(-1) > 32 * 8 - 31).any())
+                    | (bits(jax.lax.slice(lns, last_group, lns.shape))
+                       .sum(slot_axis) > 32 * 8 - 31).any())
         # an overflowing frame is coded again by the dense path: its
         # buffer only has to carry the flag, and nothing may leave the
         # row buffers
@@ -297,14 +335,12 @@ def pack_rows(vals, lns, value_ovf, mb_cap: int, out_words: int):
         phase = jnp.pad((in_row & 31).reshape(n), (0, n_pad - n))
 
     with jax.named_scope("cabac_slots_major"):
-        packed = (jnp.where(lns > 0,
-                            vals.astype(jnp.uint32)
-                            & ((1 << _LEN_SHIFT) - 1), 0)
-                  | (lns.astype(jnp.uint32) << _LEN_SHIFT))
-        packed = jax.lax.bitcast_convert_type(packed, jnp.int32)
-        packed = jnp.pad(packed.reshape(n, s),
-                         ((0, n_pad - n), (0, s8 - s)))
-        slots = packed.T.reshape(s8, n_pad // 128, 128)
+        if slot_axis:
+            packed = jnp.pad(slot_words(vals, lns).reshape(n, s),
+                             ((0, n_pad - n), (0, s8 - s))).T
+        else:
+            packed = jnp.pad(vals, ((0, s8 - s), (0, n_pad - n)))
+        slots = packed.reshape(s8, n_pad // 128, 128)
 
     keep = min(s8, piece_words)
     with jax.named_scope("cabac_compact"):

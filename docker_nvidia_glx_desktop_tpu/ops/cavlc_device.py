@@ -417,16 +417,23 @@ _BLK_X = np.array([0, 1, 0, 1, 2, 3, 2, 3, 0, 1, 0, 1, 2, 3, 2, 3], _I32)
 _BLK_Y = np.array([0, 0, 1, 1, 0, 0, 1, 1, 2, 2, 3, 3, 2, 2, 3, 3], _I32)
 
 
+def blocks_first(a):
+    """(R, C, B, ...) per-macroblock blocks -> (B, R * C, ...): the slot
+    builders' block-major order."""
+    return jnp.moveaxis(a, 2, 0).reshape(
+        (a.shape[2], a.shape[0] * a.shape[1]) + a.shape[3:])
+
+
 def frame_block_slots(levels: dict, slice_qp: int = None):
     """Level tensors (ops/h264_device.encode_intra_frame) -> per-block slots.
 
     Handles mixed I_16x16 / I_NxN macroblocks (``mb_i4``): I_NxN luma
     blocks carry 16-coefficient levels (``luma_i4``) with per-8x8 cbp
     gating and no Hadamard DC block.  Returns (values, lengths, syn_vals,
-    syn_lens, qp_sum): (R, C, 27, 34) codeword slots plus the (R, C, 20)
-    MB-syntax slots (see MB_SYN_SLOTS layout); ``qp_sum`` is the summed
-    per-MB effective qp (tune=hq; None otherwise) the host normalizes
-    the rate model with.  ``slice_qp`` anchors the mb_qp_delta chain and
+    syn_lens, qp_sum): (27, R * C, 34) codeword slots, block-major as
+    :func:`pack_frame` takes them, plus the (R, C, 20) MB-syntax slots (see
+    MB_SYN_SLOTS layout); ``qp_sum`` is the summed per-MB effective qp
+    (tune=hq; None otherwise) the host normalizes the rate model with.  ``slice_qp`` anchors the mb_qp_delta chain and
     is required when ``levels`` carries a ``qp_map``.
     """
     luma_dc = levels["luma_dc"]        # (R, C, 16) zigzag
@@ -488,45 +495,54 @@ def frame_block_slots(levels: dict, slice_qp: int = None):
 
     nmb = nr * nc_mb
 
+    # The blocks are numbered BLOCK-major, index = block * macroblocks +
+    # macroblock: code_blocks' (N, 34) results are then, column by column,
+    # (27, R * C) planes with the long macroblock axis on the chip's lanes,
+    # and slot b * 34 + k of every macroblock is one lane-dense row of what
+    # the pack kernels read (:func:`pack_frame`).  Macroblock-major, the 27
+    # had to leave the lanes through copies padded to 128 (PR 45).
     blk_levels = jnp.concatenate([
-        pad16(luma_dc)[:, :, None, :],                      # lumaDC (I16)
-        luma_lv,                                            # 16 luma blocks
-        pad16(cb_dc)[:, :, None, :],                        # cbDC
-        pad16(cr_dc)[:, :, None, :],                        # crDC
-        pad16(cb_ac),                                       # 4 cbAC
-        pad16(cr_ac),                                       # 4 crAC
-    ], axis=2)                                              # (R, C, 27, 16)
+        pad16(luma_dc).reshape(1, nmb, 16),                 # lumaDC (I16)
+        blocks_first(luma_lv),                              # 16 luma blocks
+        pad16(cb_dc).reshape(1, nmb, 16),                   # cbDC
+        pad16(cr_dc).reshape(1, nmb, 16),                   # crDC
+        blocks_first(pad16(cb_ac)),                         # 4 cbAC
+        blocks_first(pad16(cr_ac)),                         # 4 crAC
+    ], axis=0)                                              # (27, R*C, 16)
 
     nc_luma_blk = ncl[:, :, jnp.asarray(_BLK_Y), jnp.asarray(_BLK_X)]
-    nc_c = lambda g: g.reshape(nr, nc_mb, 4)
+    nc_c = lambda g: blocks_first(g.reshape(nr, nc_mb, 4))
     blk_nc = jnp.concatenate([
-        nc_dc[:, :, None], nc_luma_blk,
-        jnp.zeros((nr, nc_mb, 2), jnp.int32),               # chroma DC: nC=-1
-        nc_c(nccb), nc_c(nccr)], axis=2)                    # (R, C, 27)
+        nc_dc.reshape(1, nmb), blocks_first(nc_luma_blk),
+        jnp.zeros((2, nmb), jnp.int32),                     # chroma DC: nC=-1
+        nc_c(nccb), nc_c(nccr)], axis=0)                    # (27, R*C)
 
     is_cdc = np.zeros(MB_BLOCKS, bool)
     is_cdc[17] = is_cdc[18] = True
-    max_coeff = jnp.full((nr, nc_mb, MB_BLOCKS), 15, jnp.int32)
-    max_coeff = max_coeff.at[:, :, 0].set(16)
-    max_coeff = max_coeff.at[:, :, 17:19].set(4)
-    max_coeff = max_coeff.at[:, :, 1:17].set(
-        jnp.where(mb_i4[:, :, None], 16, 15))
+    max_coeff = np.full(MB_BLOCKS, 15, _I32)
+    max_coeff[0] = 16
+    max_coeff[17:19] = 4
+    luma_blk = np.zeros(MB_BLOCKS, bool)
+    luma_blk[1:17] = True
+    max_coeff = jnp.where(
+        mb_i4.astype(bool).reshape(1, nmb) & luma_blk[:, None],
+        16, max_coeff[:, None])
 
     values, lengths = code_blocks(
-        blk_levels.reshape(nmb * MB_BLOCKS, 16),
+        blk_levels.reshape(MB_BLOCKS * nmb, 16),
         blk_nc.reshape(-1),
-        jnp.asarray(np.tile(is_cdc, nmb)),
+        jnp.asarray(np.repeat(is_cdc, nmb)),
         max_coeff.reshape(-1))
-    values = values.reshape(nr, nc_mb, MB_BLOCKS, BLOCK_SLOTS)
-    lengths = lengths.reshape(nr, nc_mb, MB_BLOCKS, BLOCK_SLOTS)
+    values = values.reshape(MB_BLOCKS, nmb, BLOCK_SLOTS)
+    lengths = lengths.reshape(MB_BLOCKS, nmb, BLOCK_SLOTS)
 
     # --- cbp gating: un-coded blocks emit nothing at all ---
-    gate = jnp.ones((nr, nc_mb, MB_BLOCKS), bool)
-    gate = gate.at[:, :, 0].set(~mb_i4)                     # no DC for I_NxN
-    gate = gate.at[:, :, 1:17].set(luma_gate)
-    gate = gate.at[:, :, 17:19].set((cbp_chroma > 0)[:, :, None])
-    gate = gate.at[:, :, 19:27].set((cbp_chroma == 2)[:, :, None])
-    lengths = lengths * gate[:, :, :, None]
+    gate = jnp.concatenate([
+        (~mb_i4).astype(bool).reshape(1, nmb),              # no DC for I_NxN
+        blocks_first(luma_gate),
+        jnp.stack([cbp_chroma > 0] * 2
+                  + [cbp_chroma == 2] * 8).reshape(10, nmb)], axis=0)
+    lengths = lengths * gate[:, :, None]
 
     # tune=hq: per-MB mb_qp_delta chained from the slice qp per row
     # (ops/aq.qp_chain).  The syntax exists for every I16 MB and for
@@ -635,8 +651,9 @@ def pack_frame(values, lengths, syn_vals, syn_lens, hdr_vals, hdr_lens,
     """Scatter-free packing of a picture's CAVLC slots into row RBSPs, for
     I and P pictures alike.
 
-    values/lengths (R, C, B, 34): the blocks' slots; syn_* (R, C, S): the
-    macroblock layer's; hdr_* (R, HDR_SLOTS): each row's slice header;
+    values/lengths (B, R * C, 34): the blocks' slots in the builders'
+    block-major order; syn_* (R, C, S): the macroblock layer's; hdr_*
+    (R, HDR_SLOTS): each row's slice header;
     trail_* (R,): a P row's trailing skip run (length 0 where the row ends
     on a coded macroblock; None for an I picture).  Returns (flat,
     overflow) where ``flat`` is a (META_WORDS*4 + FLAT_CAP_WORDS*4,) uint8
@@ -696,6 +713,11 @@ def _pack_rows_bitmerge(values, lengths, syn_vals, syn_lens, hdr_vals,
     (overflow, per-row bit counts, FLAT_CAP_WORDS words).  The packer
     wherever there is no TPU, and the kernels' oracle."""
     nr, nc_mb = syn_vals.shape[:2]
+    # the hierarchy merges a macroblock's blocks: its (R, C, B, 34) view
+    # of the block-major slots, a transpose no chip ever runs
+    values, lengths = (
+        jnp.moveaxis(a.reshape(-1, nr, nc_mb, BLOCK_SLOTS), 0, 2)
+        for a in (values, lengths))
 
     with jax.named_scope("bitmerge_blocks"):
         # L1: each block's 34 slots -> 8-word buffer.
@@ -776,19 +798,25 @@ def _pack_rows_bitmerge(values, lengths, syn_vals, syn_lens, hdr_vals,
 
 def _pack_rows_kernels(values, lengths, syn_vals, syn_lens, hdr_vals,
                        hdr_lens, trail_vals, trail_lens):
-    """The same rows through ``cabac_pack.pack_rows`` (the TPU's form): a
-    macroblock is ONE run of slots, its syntax and then its blocks, the
-    row's slice header in front of its first macroblock's and the trailing
-    run and the rbsp trailing bits behind its last one's; the caps are the
-    hierarchy's, tested on sums of lengths."""
+    """The same rows through ``cabac_pack.pack_rows_slot_major`` (the TPU's
+    form): a macroblock is ONE run of slots, its syntax and then its
+    blocks, the row's slice header in front of its first macroblock's and
+    the trailing run and the rbsp trailing bits behind its last one's; the
+    caps are the hierarchy's, tested on sums of lengths.  Value and length
+    become kernel A's slot words where they lie, and the run is put together
+    along the SLOT axis with the macroblocks on the lanes: slot b * 34 + k
+    of every macroblock is a row of the builder's (B, R * C, 34) as it
+    stands, so what kernel A reads is a permutation of major axes of what
+    ``code_blocks`` made."""
     nr, nc_mb = syn_vals.shape[:2]
+    nmb = nr * nc_mb
     lengths = lengths.astype(jnp.int32)
     syn_lens = syn_lens.astype(jnp.int32)
     hdr_lens = hdr_lens.astype(jnp.int32)
-    blk_bits = lengths.sum(-1)                              # (R, C, B)
+    blk_bits = lengths.sum(-1)                              # (B, R*C)
     syn_bits = syn_lens.sum(-1)
-    mb_bits = syn_bits + blk_bits.sum(-1)
-    caps_ovf = ((blk_bits > bitmerge.BLOCK_CAP_BITS).any(-1)
+    mb_bits = syn_bits + blk_bits.sum(0).reshape(nr, nc_mb)
+    caps_ovf = ((blk_bits > bitmerge.BLOCK_CAP_BITS).any(0).reshape(nr, nc_mb)
                 | (syn_bits > bitmerge.BLOCK_CAP_BITS)
                 | (mb_bits > bitmerge.MB_CAP_BITS))          # (R, C)
 
@@ -803,21 +831,26 @@ def _pack_rows_kernels(values, lengths, syn_vals, syn_lens, hdr_vals,
                             jnp.uint32(1) << (stop_bits - 1)], -1)
     row_tail_l = jnp.stack([trail_lens, stop_bits], -1)
 
-    col = jnp.arange(nc_mb)[None, :, None]
-    vals = jnp.concatenate([
-        jnp.where(col == 0, row_head_v[:, None, :], 0),
-        syn_vals.astype(jnp.uint32),
-        values.astype(jnp.uint32).reshape(nr, nc_mb, -1),
-        jnp.where(col == nc_mb - 1, row_tail_v[:, None, :], 0)], axis=-1)
-    lens = jnp.concatenate([
-        jnp.where(col == 0, row_head_l[:, None, :], 0),
-        syn_lens,
-        lengths.reshape(nr, nc_mb, -1),
-        jnp.where(col == nc_mb - 1, row_tail_l[:, None, :], 0)], axis=-1)
+    col = jnp.arange(nc_mb)[None, None, :]
+
+    def at_column(where, row_slots):
+        """(R, K) slots of a row -> (K, R * C), in that column alone (a
+        slot of length 0 elsewhere: the word 0)."""
+        return jnp.where(col == where, row_slots.T[:, :, None],
+                         0).reshape(-1, nmb)
+
+    slots = jnp.concatenate([
+        at_column(0, cabac_pack.slot_words(row_head_v, row_head_l)),
+        jnp.moveaxis(cabac_pack.slot_words(syn_vals, syn_lens),
+                     2, 0).reshape(-1, nmb),
+        jnp.moveaxis(cabac_pack.slot_words(values, lengths),
+                     2, 1).reshape(-1, nmb),
+        at_column(nc_mb - 1, cabac_pack.slot_words(row_tail_v, row_tail_l)),
+    ], axis=0)
     # a column's words: the macroblock's, a word a header slot, two behind
     col_cap = bitmerge.MB_WORDS + hdr_vals.shape[-1] + 2
-    return cabac_pack.pack_rows(vals, lens, caps_ovf, col_cap,
-                                FLAT_CAP_WORDS)
+    return cabac_pack.pack_rows_slot_major(slots, caps_ovf, col_cap,
+                                           FLAT_CAP_WORDS)
 
 
 # ---------------------------------------------------------------------------

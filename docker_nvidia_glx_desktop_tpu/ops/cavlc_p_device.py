@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..bitstream.h264_entropy import _CBP_INTER_BY_CODENUM
-from .cavlc_device import code_blocks, nc_grid, pack_frame
+from .cavlc_device import blocks_first, code_blocks, nc_grid, pack_frame
 from .h264_inter import RING_DONATE
 
 _I32 = np.int32
@@ -156,7 +156,8 @@ def p_mb_header_slots(mv, cbp, qp_se=None, mb_intra=None):
 def p_frame_block_slots(out: dict):
     """Inter residual tensors (ops/h264_inter.encode_p_frame) -> block
     slots + gates.  Returns (values, lengths, cbp, mv) with values/lengths
-    (R, C, 26, 34) — or (R, C, 27, 34) when the tune=hq I16-in-P path is
+    (26, R * C, 34), block-major as ``cavlc_device.pack_frame`` takes them
+    — or (27, R * C, 34) when the tune=hq I16-in-P path is
     active (``mb_intra`` in ``out``): block 0 is then Intra16x16DCLevel
     (gated to intra MBs; always coded there) and the 16 luma slots carry
     15-coefficient AC blocks for intra MBs (max_coeff 15 — total_zeros is
@@ -225,30 +226,33 @@ def p_frame_block_slots(out: dict):
         k = a.shape[-1]
         return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, 16 - k)])
 
+    # The blocks are numbered block-major, index = block * macroblocks +
+    # macroblock (cavlc_device.frame_block_slots): the long macroblock
+    # axis is what the chip's lanes run along, from here to the packer.
     luma_eff = luma
     if mb_intra is not None:
         luma_eff = jnp.where(intra[:, :, None, None],
                              pad16(i16_ac), luma)
     parts = [
-        luma_eff,                                      # 16 luma blocks
-        pad16(cb_dc)[:, :, None, :],
-        pad16(cr_dc)[:, :, None, :],
-        pad16(cb_ac),
-        pad16(cr_ac)]
+        blocks_first(luma_eff),                        # 16 luma blocks
+        pad16(cb_dc).reshape(1, nmb, 16),
+        pad16(cr_dc).reshape(1, nmb, 16),
+        blocks_first(pad16(cb_ac)),
+        blocks_first(pad16(cr_ac))]
     if mb_intra is not None:
-        parts.insert(0, i16_dc[:, :, None, :])         # Intra16x16DCLevel
-    blk_levels = jnp.concatenate(parts, axis=2)        # (R, C, nblk, 16)
+        parts.insert(0, i16_dc.reshape(1, nmb, 16))    # Intra16x16DCLevel
+    blk_levels = jnp.concatenate(parts, axis=0)        # (nblk, R*C, 16)
 
     nc_luma_blk = ncl[:, :, jnp.asarray(_BLK_Y), jnp.asarray(_BLK_X)]
-    nc_c = lambda g: g.reshape(nr, nc_mb, 4)
+    nc_c = lambda g: blocks_first(g.reshape(nr, nc_mb, 4))
     nc_parts = [
-        nc_luma_blk,
-        jnp.zeros((nr, nc_mb, 2), jnp.int32),          # chroma DC: nC=-1
+        blocks_first(nc_luma_blk),
+        jnp.zeros((2, nmb), jnp.int32),                # chroma DC: nC=-1
         nc_c(nccb), nc_c(nccr)]
     if mb_intra is not None:
         # Intra16x16DCLevel derives nC exactly as luma4x4BlkIdx 0
-        nc_parts.insert(0, ncl[:, :, 0, 0][:, :, None])
-    blk_nc = jnp.concatenate(nc_parts, axis=2)         # (R, C, nblk)
+        nc_parts.insert(0, ncl[:, :, 0, 0].reshape(1, nmb))
+    blk_nc = jnp.concatenate(nc_parts, axis=0)         # (nblk, R*C)
 
     off = 0 if mb_intra is None else 1
     is_cdc = np.zeros(nblk, bool)
@@ -257,39 +261,34 @@ def p_frame_block_slots(out: dict):
     max_coeff[off:off + 16] = 16
     max_coeff[off + 16] = max_coeff[off + 17] = 4
     if mb_intra is None:
-        mc = jnp.asarray(np.tile(max_coeff, nmb))
+        mc = jnp.asarray(np.repeat(max_coeff, nmb))
     else:
         max_coeff[0] = 16                              # Intra16x16DCLevel
-        mc = jnp.broadcast_to(jnp.asarray(max_coeff),
-                              (nr, nc_mb, nblk))
         # intra luma AC blocks are 15-coefficient (total_zeros absent
         # when total_coeff == 15, unlike the 16-coef inter blocks)
-        mc = jnp.where(intra[:, :, None]
-                       & (jnp.arange(nblk) >= off)[None, None, :]
-                       & (jnp.arange(nblk) < off + 16)[None, None, :],
-                       15, mc)
-        mc = mc.reshape(-1)
+        luma_blk = np.zeros(nblk, bool)
+        luma_blk[off:off + 16] = True
+        mc = jnp.where(intra.reshape(1, nmb) & luma_blk[:, None],
+                       15, max_coeff[:, None]).reshape(-1)
 
     values, lengths = code_blocks(
-        blk_levels.reshape(nmb * nblk, 16),
+        blk_levels.reshape(nblk * nmb, 16),
         blk_nc.reshape(-1),
-        jnp.asarray(np.tile(is_cdc, nmb)),
+        jnp.asarray(np.repeat(is_cdc, nmb)),
         mc)
-    values = values.reshape(nr, nc_mb, nblk, -1)
-    lengths = lengths.reshape(nr, nc_mb, nblk, -1)
+    values = values.reshape(nblk, nmb, -1)
+    lengths = lengths.reshape(nblk, nmb, -1)
 
-    gate = jnp.ones((nr, nc_mb, nblk), bool)
+    chroma_gate = jnp.stack([cbp_chroma > 0] * 2 + [cbp_chroma == 2] * 8)
     if mb_intra is None:
-        gate = gate.at[:, :, 0:16].set(grp_gate)
+        luma_gate = [blocks_first(grp_gate)]
     else:
-        gate = gate.at[:, :, 0].set(intra)             # DC: intra only
-        gate = gate.at[:, :, 1:17].set(
-            jnp.where(intra[:, :, None], cl15[:, :, None], grp_gate))
-    gate = gate.at[:, :, off + 16:off + 18].set(
-        (cbp_chroma > 0)[:, :, None])
-    gate = gate.at[:, :, off + 18:off + 26].set(
-        (cbp_chroma == 2)[:, :, None])
-    lengths = lengths * gate[:, :, :, None]
+        luma_gate = [intra.reshape(1, nmb),            # DC: intra only
+                     blocks_first(jnp.where(intra[:, :, None],
+                                            cl15[:, :, None], grp_gate))]
+    gate = jnp.concatenate(
+        luma_gate + [chroma_gate.reshape(10, nmb)], axis=0)    # (nblk, R*C)
+    lengths = lengths * gate[:, :, None]
     return values, lengths, cbp, mv
 
 

@@ -93,6 +93,21 @@ def _pack_case(kind, case, grid=None):
             mb_i4, fill(z(16), 0, 8), luma_i4)
 
 
+def _bit_string_slots(widest, cols):
+    """(values, lengths, words a macroblock, words in all) of ``pack_rows``'
+    own cases: two rows of ``cols`` macroblocks of 37 slots of up to
+    ``widest`` bits, some of them empty and one macroblock empty."""
+    rng = np.random.default_rng(widest)
+    r, c, s = 2, cols, 37
+    lns = rng.integers(widest - 5, widest + 1, (r, c, s))
+    lns *= rng.integers(0, 8, (r, c, s)) > 0            # some empty
+    lns[1, 1] = 0                                       # an empty MB
+    vals = rng.integers(0, 1 << 26, (r, c, s)) & ((1 << lns) - 1)
+    # a macroblock's piece: a line of a chunk, or whole chunks
+    cap, out_words = (64 if widest == 32 else 200), 512 * -(-cols // 3)
+    return vals, lns, cap, out_words
+
+
 class TestPackKernels:
     @pytest.mark.parametrize("case", _PACK_CASES)
     @pytest.mark.parametrize("kind", ["p", "intra"])
@@ -146,14 +161,8 @@ class TestPackKernels:
 
         from docker_nvidia_glx_desktop_tpu.ops import cabac_pack
 
-        rng = np.random.default_rng(widest)
-        r, c, s = 2, cols, 37
-        lns = rng.integers(widest - 5, widest + 1, (r, c, s))
-        lns *= rng.integers(0, 8, (r, c, s)) > 0            # some empty
-        lns[1, 1] = 0                                       # an empty MB
-        vals = rng.integers(0, 1 << 26, (r, c, s)) & ((1 << lns) - 1)
-        # a macroblock's piece: a line of a chunk, or whole chunks
-        cap, out_words = (64 if widest == 32 else 200), 512 * -(-cols // 3)
+        vals, lns, cap, out_words = _bit_string_slots(widest, cols)
+        r, c, _ = lns.shape
         want = np.zeros(out_words, np.uint32)
         word, spills = 0, 0
         for i in range(r):
@@ -178,3 +187,35 @@ class TestPackKernels:
         np.testing.assert_array_equal(np.asarray(row_bits),
                                       lns.sum((1, 2)))
         np.testing.assert_array_equal(np.asarray(payload), want)
+
+    @pytest.mark.parametrize("cols", [3, 240])
+    @pytest.mark.parametrize("widest", [26, 32])
+    def test_slot_major_entry_equals_the_front(self, widest, cols):
+        """``pack_rows_slot_major`` (PR 45: the CAVLC slot builder hands
+        its slots over as kernel A reads them, ``slot_words`` of shape
+        (S, R * C)) against the (R, C, S) front on the same slots: the
+        flag, every row's bit count and the payload word for word, on the
+        cases above and on one that passes a cap (the flag must be the
+        same flag)."""
+        import jax
+        from jax.experimental.pallas import tpu as pltpu
+
+        from docker_nvidia_glx_desktop_tpu.ops import cabac_pack
+
+        vals, lns, cap, out_words = _bit_string_slots(widest, cols)
+        r, c, s = lns.shape
+        vals, lns = vals.astype(np.uint32), lns.astype(np.int32)
+        slots = np.moveaxis(np.asarray(cabac_pack.slot_words(vals, lns)),
+                            2, 0).reshape(s, r * c)
+        front = jax.jit(cabac_pack.pack_rows, static_argnums=(3, 4))
+        entry = jax.jit(cabac_pack.pack_rows_slot_major,
+                        static_argnums=(2, 3))
+        ovf = np.zeros((r, c), bool)
+        with pltpu.force_tpu_interpret_mode():
+            for words in (out_words, 64):
+                want = front(vals, lns, ovf, cap, words)
+                got = entry(slots, ovf, cap, words)
+                assert bool(want[0]) == (words == 64)
+                for g, w in zip(got, want):
+                    np.testing.assert_array_equal(np.asarray(g),
+                                                  np.asarray(w))

@@ -24,6 +24,20 @@ _FLAT_CASES = ("desktop", "fulldamage", "all_skip", "empty_rows",
                "two_sessions")
 _SMALL_CAP = 512            # words: under a (3, 5) grid's full damage
 
+# sha256 of ``flat`` (its first 16 hex digits) from these level tensors, as
+# ``flat_digests()`` made them on the tree of commit 8d589af (PR 44), with
+# that tree's package on the path: the slot builders numbered their blocks
+# macroblock-major there (PR 45 turned the order round, in the builders and
+# in both forms of the pack at once)
+_DIGEST_CASES = ("desktop", "fulldamage", "level_escapes",
+                 "row_of_163_pieces")
+_DIGEST_KEYS = [(kind, case) for kind in ("p", "intra")
+                for case in _DIGEST_CASES]
+_PARENT_FLAT = dict(zip(_DIGEST_KEYS, """
+    1b50bab6d87b1884 5134c2f9e639e30d 7423715ef5a7d5fd f64984f9c60f0355
+    bd2c2750fb53948e e4ffbee66158f610 1768757311963b60 b55f8e39d65ad88d
+    """.split()))
+
 
 def _levels(kind, case):
     """The level tensors of one case, as ``_pack_case`` crafts them, and on
@@ -66,6 +80,32 @@ def _flat(kind, hv, hl, *levels):
     return cavlc_device._finish_cavlc(lv, hv, hl, False, 26)
 
 
+def _header_slots(kind, nr, nc):
+    from docker_nvidia_glx_desktop_tpu.ops import cavlc_device
+
+    return cavlc_device.slice_header_slots(
+        nr, nc, frame_num=3, deblocking_idc=2,
+        **({"slice_type": 5, "idr": False} if kind == "p" else {}))
+
+
+def flat_digests(jits=None, keys=_DIGEST_KEYS):
+    """(kind, case) -> digest of the bitmerge form's ``flat``."""
+    import hashlib
+
+    import jax
+
+    jits = {} if jits is None else jits
+    out = {}
+    for kind, case in keys:
+        args = _levels(kind, case)
+        fn = jits.setdefault(("bitmerge", kind, ""), jax.jit(
+            lambda *a, kind=kind: _flat(kind, *a)))
+        flat = np.asarray(fn(*_header_slots(kind, *args[0].shape[:2]),
+                             *args))
+        out[kind, case] = hashlib.sha256(flat.tobytes()).hexdigest()[:16]
+    return out
+
+
 def _vmapped_grids_in_the_interpreter(mp):
     """``jax.vmap`` puts a grid dimension of its own in front of a kernel's;
     the Mosaic lowering gives it "parallel" semantics beside the kernel's
@@ -85,6 +125,16 @@ def flat_jits():
 
 
 class TestFlatPackKernels:
+    @pytest.mark.parametrize("kind,case", _DIGEST_KEYS)
+    def test_flat_is_what_the_macroblock_major_builders_gave(
+            self, kind, case, flat_jits):
+        """The builders and both forms of the pack changed their order of
+        blocks in one PR, and the test below holds the two forms to each
+        other only: they could drift TOGETHER.  So ``flat`` is held to the
+        bytes the tree before gave for the same level tensors."""
+        assert flat_digests(flat_jits, [(kind, case)]) == {
+            (kind, case): _PARENT_FLAT[kind, case]}
+
     @pytest.mark.parametrize("case", _FLAT_CASES)
     @pytest.mark.parametrize("kind", ["p", "intra"])
     def test_kernels_flat_equals_bitmerge_flat(self, kind, case,
@@ -104,9 +154,7 @@ class TestFlatPackKernels:
                                                                 "two_"))
                 else _levels(kind, case))
         nr, nc = args[0].shape[:2]
-        hv, hl = cavlc_device.slice_header_slots(
-            nr, nc, frame_num=3, deblocking_idc=2,
-            **({"slice_type": 5, "idr": False} if kind == "p" else {}))
+        hv, hl = _header_slots(kind, nr, nc)
         body = lambda *a: _flat(kind, *a)
         if case == "two_sessions":
             # parallel/batch's steps: jax.vmap over the sessions of a chip
@@ -134,3 +182,34 @@ class TestFlatPackKernels:
                 continue
             assert meta.total_words > 0
             np.testing.assert_array_equal(got1, want1)
+
+
+@pytest.mark.parametrize("order", ["reversed", "shuffled", "block_major"])
+def test_code_blocks_knows_no_order_of_its_blocks(order):
+    """``code_blocks`` codes each of its N blocks by itself: a permutation
+    of the blocks (with their nC, kind and maxNumCoeff) permutes the rows
+    of its (N, 34) values and lengths and changes nothing else, which is
+    why the builders may number their blocks as the packer wants them
+    (``block_major``: 26 blocks of 15 macroblocks turned from
+    macroblock-major, the permutation PR 45 made)."""
+    from docker_nvidia_glx_desktop_tpu.ops import cavlc_device
+
+    nblk, nmb = 26, 15
+    n = nblk * nmb
+    rng = np.random.default_rng(45)
+    max_coeff = rng.choice([4, 15, 16], n)
+    levels = rng.integers(-3, 4, (n, 16)) * (rng.integers(0, 3, (n, 16)) == 0)
+    levels[::7, :3] = (2100, -40, 9)                    # escapes among them
+    levels[::11] = 0                                    # and empty blocks
+    levels *= np.arange(16) < max_coeff[:, None]
+    nc = rng.integers(0, 17, n)
+    perm = {"reversed": np.arange(n)[::-1],
+            "shuffled": rng.permutation(n),
+            "block_major": np.arange(n).reshape(nmb, nblk).T.ravel()}[order]
+    code = lambda idx: [np.asarray(a) for a in cavlc_device.code_blocks(
+        levels[idx], nc[idx], max_coeff[idx] == 4, max_coeff[idx])]
+    values, lengths = code(np.arange(n))
+    assert lengths.shape == (n, cavlc_device.BLOCK_SLOTS) and lengths.any(1).all()
+    got_values, got_lengths = code(perm)
+    np.testing.assert_array_equal(got_values, values[perm])
+    np.testing.assert_array_equal(got_lengths, lengths[perm])

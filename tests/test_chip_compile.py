@@ -23,8 +23,10 @@ ring; qp traced), the same worklist through the CABAC stream's row
 program and the binarizer over that band of 8 rows (PR 43), the (4,1)
 session-mesh step of ``TPU_SESSIONS``/``TPU_MESH`` on a ``Mesh`` of the
 four described devices, and a P step of two sessions a chip (``jax.vmap``
-over the kernels), and the content statistics' program at 2560x1600 and
-3840x2176, whose temporaries are read (PR 44).
+over the kernels), the content statistics' program at 2560x1600 and
+3840x2176, whose temporaries are read (PR 44), and the dense P step again
+at 2560x1600, whose buffers under the slot builder and the pack are read
+one by one (PR 45).
 
 Tier-1 on purpose (not in conftest's ``_SLOW_MODULES``).  Only one
 process may hold the TPU library, so everything that touches the
@@ -35,7 +37,9 @@ persistent cache off around them (a described-topology entry can be
 written but never read back).
 """
 
+import math
 import os
+import re
 
 import pytest
 
@@ -194,6 +198,17 @@ def programs(topo, no_persistent_cache):
                         ys, ys, ys, mb(jnp.int8, 2),
                         tuple(mb(jnp.int16, *sh) for sh in (
                             (16, 16), (4,), (4, 15), (4,), (4, 15))))
+        # the dense P step of the two device-paced 1600p cells (PR 45):
+        # the slot builder's hand-over to the pack kernels is read there
+        hv16, hl16 = (jax.ShapeDtypeStruct((100,) + a.shape[1:], a.dtype,
+                                           sharding=one)
+                      for a in (hv_np, hl_np))
+        y16, c16 = (jax.ShapeDtypeStruct((1600 // d, 2560 // d), jnp.uint8,
+                                         sharding=one) for d in (1, 2))
+        lowered["p_2560x1600"] = jax.jit(
+            lambda *a: p_body(*a, "off", None, False),
+            donate_argnums=(3, 4, 5)).lower(
+                y16, c16, c16, y16, c16, c16, hv16, hl16, qp)
         # web/multisession: four 1080p sessions, one per chip
         mesh = batch.make_mesh((4, 1), topo.devices)
         planes = lambda m, n: tuple(
@@ -242,6 +257,51 @@ def _device_bytes(compiled) -> int:
             - m.alias_size_in_bytes)
 
 
+_ELEMENT_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
+                  "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8}
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]*)\]\{([\d,]*)(?::([^}]*))?\} ")
+
+
+def _padded_buffers(text, least_bytes, widest_minor):
+    """The buffers of a compiled program that the TPU's tiling pads: every
+    instruction outside a fusion's body (those inside are no buffers)
+    whose result, with its ``minor_to_major`` and its tile applied, takes
+    ``least_bytes`` or more while the dimension on the lanes is
+    ``widest_minor`` or less, as (bytes as tiled, bytes of data, name,
+    shape, op_name), largest first.  ``s32[416000,34]{1,0:T(8,128)}`` is
+    34 of 128 lanes: 203.1 MiB for 54.0 (PERF.md section 7; the parse of
+    PR 44's satellite)."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    out, inside = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and "=" not in line.split("(")[0]:
+            inside = line.split()[0] in fused    # a computation's first line
+            continue
+        m = _INSTRUCTION.match(line)
+        if inside or not m or not m.group(3):
+            continue
+        name, dtype, dims, order, tiling = m.groups()
+        dims = [int(d) for d in dims.split(",")]
+        order = [int(d) for d in order.split(",")]
+        tiles = re.findall(r"T\(([\d,]+)\)", tiling or "")
+        padded = list(dims)
+        if tiles:
+            tile = [int(t) for t in tiles[0].split(",")]
+            if len(tiles) > 1 and len(tile) > 1:  # (k, 1): k rows a word
+                tile[-2] *= int(tiles[1].split(",")[0])
+            for t, ax in zip(reversed(tile), order):
+                padded[ax] = -(-dims[ax] // t) * t
+        size = _ELEMENT_BYTES.get(dtype, 4)
+        as_tiled = size * math.prod(padded)
+        if as_tiled >= least_bytes and dims[order[0]] <= widest_minor:
+            op = re.search(r'op_name="([^"]*)"', line)
+            out.append((as_tiled, size * math.prod(dims), name,
+                        line.split(" = ")[1].split(" ")[0],
+                        op.group(1) if op else ""))
+    return sorted(out, reverse=True)
+
+
 def _has_the_pack_kernels(text, calls=2):
     """Both merge levels of a packer are Mosaic kernels, and kernel A asks
     for more VMEM than the compiler's default scoped limit (16 MiB on a
@@ -266,6 +326,23 @@ def test_p_step_compiles_and_donates_the_ring(programs):
     # the recon is written in place of the donated reference planes
     assert c.memory_analysis().alias_size_in_bytes >= H * W * 3 // 2
     _has_the_pack_kernels(c.as_text())
+
+
+def test_slot_hand_over_at_1600p_pads_no_buffer(programs):
+    """The slot builder codes its blocks block-major and hands the pack
+    kernels slot words with the macroblocks on the lanes (PR 45).
+    Macroblock-major, the 26 blocks of a macroblock had to leave the lane
+    axis on the way, through ``s32[416000,34]{1,0}``,
+    ``s32[100,160,26,16]{2,1,0,3}`` and ``s32[100,160,26,15]{2,1,0,3}``:
+    445 MiB as tiled for 103 of data, and 367.1 MiB of temporaries in all
+    (PERF.md section 7).  This test FAILS on the tree of before PR 45."""
+    c = _compiled(programs, "p_2560x1600")
+    text = c.as_text()
+    _has_the_pack_kernels(text)
+    padded = [b for b in _padded_buffers(text, 32 * 2 ** 20, 36)
+              if "dngd.slots" in b[4] or "dngd.pack" in b[4]]
+    assert not padded, padded
+    assert 0 < c.memory_analysis().temp_size_in_bytes < 300 * 2 ** 20
 
 
 def test_row_program_compiles_scatters_in_place_and_keeps_the_kernels(
