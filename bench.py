@@ -393,7 +393,7 @@ def bdrate_main(quick: bool = False) -> None:
                "scrolling")
 
     def run_tier(frames, tier: str, qp: int):
-        enc = H264Encoder(w, h, qp=qp, mode="cavlc", entropy="device",
+        enc = H264Encoder(w, h, qp=qp, entropy="device",
                           gop=len(frames), keep_recon=True, tune=tier)
         bits = 0
         psnrs = []

@@ -2,7 +2,7 @@
 
 This is the entropy half of the ``nvh264enc`` replacement (reference
 Dockerfile:210): NVENC's silicon CAVLC stage re-implemented first-party.
-The native C++ fast path (``native/cavlc.cpp``) must produce byte-identical
+The device coder (``ops/cavlc_device``) must produce byte-identical
 output; tests enforce that.  Tables below are transcribed from the spec
 (Tables 9-5, 9-7, 9-8, 9-9(a), 9-10); `_check_prefix_free` validates each
 is a well-formed prefix code at import time so a transcription slip fails

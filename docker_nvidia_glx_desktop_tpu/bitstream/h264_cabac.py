@@ -10,6 +10,8 @@ arithmetic engine, so the C++ twin can code rows on a thread pool.
 
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 
 from ..obs import metrics as obsm
@@ -28,12 +30,36 @@ def _native_tables(table_idx: int):
             np.ascontiguousarray(tlps, np.uint8))
 
 
-# Per-frame output buffers, reused across calls (60 fps hot path; keyed
-# by geometry so a resize reallocates once).  THREAD-LOCAL: concurrent
-# sessions each run their own encode thread, and the ctypes call writes
-# into the buffer with the GIL released — a shared buffer would let two
-# frames scribble over each other.
-_TLS = __import__("threading").local()
+# The C coders' output buffer: ONE a thread, kept between frames (the
+# 60 fps hot path: a fresh rows x cap buffer is 12.6 MB at 1080p and
+# 50 MB at 4K, and the engine's pool then faults its pages in anew every
+# frame), grown to the largest size asked so far (a resize, a masked
+# frame's band after a smaller one) and handed out as a view.  Every
+# consumer frames or copies the rows where they lie and returns new
+# ``bytes`` inside the same call, so nothing holds the view when the next
+# picture is coded on that thread.  THREAD-LOCAL: concurrent sessions
+# each run their own encode thread, and the ctypes call writes into the
+# buffer with the GIL released: a shared buffer would let two frames
+# scribble over each other.
+_TLS = threading.local()
+
+
+def _row_cap(nc_mb: int) -> int:
+    """Output bytes the C coders are given a macroblock row."""
+    return 2048 + nc_mb * 1536
+
+
+def _out_buffer(size: int, scale: int = 1) -> np.ndarray:
+    """``size`` bytes for the C coders to write a picture's rows into:
+    a view of this thread's kept buffer, or at ``scale`` > 1 (the 4x
+    retry of a pathological low-qp row) a fresh one, so that one bad
+    frame does not pin 200 MB at 4K."""
+    if scale != 1:
+        return np.empty(size, np.uint8)
+    buf = getattr(_TLS, "buf", None)
+    if buf is None or len(buf) < size:
+        buf = _TLS.buf = np.empty(size, np.uint8)
+    return buf[:size]
 
 
 def _native_slices(symbol: str, table_idx: int, arrays, nr, nc_mb, qp):
@@ -49,18 +75,10 @@ def _native_slices(symbol: str, table_idx: int, arrays, nr, nc_mb, qp):
         return None
     fn = getattr(native_lib.get_lib(), symbol)
     ctx, rng, tmps, tlps = _native_tables(table_idx)
-    cache = getattr(_TLS, "bufs", None)
-    if cache is None:
-        cache = _TLS.bufs = {}
     with obst.stage("engine"):      # binarization and engine, in C
         for scale in (1, 4):
-            cap = (2048 + nc_mb * 1536) * scale
-            key = (symbol, nr, cap)
-            out = cache.get(key)
-            if out is None:
-                if len(cache) > 8:
-                    cache.clear()
-                out = cache[key] = np.empty(nr * cap, np.uint8)
+            cap = _row_cap(nc_mb) * scale
+            out = _out_buffer(nr * cap, scale)
             lens = np.zeros(nr, np.int64)
             rc = fn(*arrays, nr, nc_mb, int(qp), ctx, rng, tmps, tlps,
                     out, lens, cap)
@@ -118,13 +136,13 @@ def _engine_rows(buf: np.ndarray, nr: int, nc_mb: int, table_idx: int,
         import logging
         ctx, rng, tmps, tlps = _native_tables(table_idx)
         for scale in (1, 4):
-            cap = (2048 + nc_mb * 1536) * scale
+            cap = _row_cap(nc_mb) * scale
+            out = _out_buffer(nr * cap, scale)
             got = native_lib.cabac_engine_rows(
                 payload, row_off, row_bits, nr, qp, ctx, rng, tmps,
-                tlps, cap)
-            if isinstance(got, tuple):
-                out, lens = got
-                return out, np.arange(nr, dtype=np.int64) * cap, lens
+                tlps, cap, out)
+            if isinstance(got, np.ndarray):
+                return out, np.arange(nr, dtype=np.int64) * cap, got
             if got == -2:
                 # malformed record stream: a bigger output cap cannot
                 # help — name the real failure instead of retrying
@@ -262,13 +280,14 @@ def _engine_band(buf: np.ndarray, coded: int, nc_mb: int, table_idx: int,
     if native_lib.has_cabac_engine():
         ctx, rng, tmps, tlps = _native_tables(table_idx)
         for scale in (1, 4):
-            cap = (2048 + nc_mb * 1536) * scale
-            got = native_lib.cabac_engine_rows_tail(
+            cap = _row_cap(nc_mb) * scale
+            out = _out_buffer(coded * cap + len(tail), scale)
+            out[coded * cap:] = np.frombuffer(tail, np.uint8)
+            got = native_lib.cabac_engine_rows(
                 payload, row_off[:coded + 1], row_bits[:coded], coded, qp,
-                ctx, rng, tmps, tlps, cap, tail)
-            if isinstance(got, tuple):
-                out, lens = got
-                return (out, np.arange(coded, dtype=np.int64) * cap, lens,
+                ctx, rng, tmps, tlps, cap, out)
+            if isinstance(got, np.ndarray):
+                return (out, np.arange(coded, dtype=np.int64) * cap, got,
                         coded * cap)
             if got == -2:
                 break                    # malformed: no cap can help

@@ -3,7 +3,7 @@
 Consumes the quantized level tensors produced by the device stage
 (:mod:`..ops.h264_device`) and emits one CAVLC slice per macroblock row —
 the slice-per-row structure that legalizes the device stage's row
-parallelism.  The native C++ path (``native/cavlc.cpp``) mirrors this
+parallelism.  The device coder (``ops/cavlc_device``) mirrors this
 byte-for-byte; tests enforce equality.
 
 nC context derivation (spec §9.2.1) is vectorized in numpy up front so the

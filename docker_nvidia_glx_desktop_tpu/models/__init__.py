@@ -21,9 +21,9 @@ def make_encoder(cfg, width: int, height: int, row_align: int = None):
     codec = cfg.codec
     if codec == "tpuh264enc":
         entropy = cfg.encoder_entropy
-        if entropy not in ("device", "cabac", "native", "python"):
+        if entropy not in ("device", "cabac", "python"):
             raise ValueError(f"unknown ENCODER_ENTROPY {entropy!r}")
-        enc = H264Encoder(width, height, qp=cfg.encoder_qp, mode="cavlc",
+        enc = H264Encoder(width, height, qp=cfg.encoder_qp,
                           entropy=entropy, host_color=True,
                           gop=cfg.encoder_gop,
                           bitrate_kbps=cfg.encoder_bitrate_kbps,

@@ -1,14 +1,8 @@
-"""H.264 baseline encoder family — the flagship codec (the ``nvh264enc``
-replacement; reference Dockerfile:210, SURVEY.md §3.2 hot loop).
-
-Built modes:
-
-- ``"pcm"`` — every macroblock is I_PCM (raw samples).  Zero compression
-  (+2 bytes/MB over raw YUV), but a fully conformant stream that exercises
-  NAL/SPS/PPS/slice plumbing end-to-end.  The correctness bootstrap for the
-  CAVLC mode being built on top of it (I_16x16, DC prediction, integer 4x4
-  transform + Hadamard DC, CAVLC entropy).  In intra-only modes every frame
-  is an IDR, so ``request_keyframe`` is trivially satisfied.
+"""H.264 encoder family — the flagship codec (the ``nvh264enc``
+replacement; reference Dockerfile:210, SURVEY.md §3.2 hot loop):
+I_16x16 / I_4x4 intra and P frames over the integer 4x4 transform, behind
+a Baseline CAVLC or a Main-profile CABAC stream.  With ``gop == 1`` every
+frame is an IDR, so ``request_keyframe`` is trivially satisfied.
 """
 
 from __future__ import annotations
@@ -23,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..bitstream import h264 as syn
-from ..bitstream.bitwriter import BitWriter
 from ..obs import metrics as obsm
 from ..obs import trace as obst
 from ..obs.profile import PROFILER
@@ -31,8 +24,7 @@ from ..ops import color
 from ..utils.mathutil import round_up
 from .base import EncodedFrame, Encoder
 from .prefix_pull import M_D2H_BYTES as _M_D2H_BYTES
-from .prefix_pull import M_PULL_EXTRA as _M_PULL_EXTRA
-from .prefix_pull import PrefixPull, prefetch_host as _prefetch_host
+from .prefix_pull import FlatPull, PrefixPull, prefetch_host as _prefetch_host
 
 log = logging.getLogger(__name__)
 
@@ -345,13 +337,6 @@ def _cabac_bs_inputs(luma, mv):
     return nnz_blocks_raster(luma), mv.astype(jnp.int32)
 
 
-def _mb_tiles(plane: np.ndarray, size: int) -> np.ndarray:
-    """(H, W) -> (nmb_y*nmb_x, size*size) raster-order tiles."""
-    h, w = plane.shape
-    t = plane.reshape(h // size, size, w // size, size).swapaxes(1, 2)
-    return t.reshape(-1, size * size)
-
-
 def spatial_auto_shards(width: int, height: int, fps: float = 60.0,
                         n_devices: int = None, model=None) -> int:
     """Chips ONE session of this geometry should spread across
@@ -381,20 +366,24 @@ class H264Encoder(Encoder):
     codec = "h264"
 
     def __init__(self, width: int, height: int, qp: int = 26,
-                 mode: str = "pcm", entropy: str = "device",
+                 mode: str = "cavlc", entropy: str = "device",
                  keep_recon: bool = False, host_color: bool = False,
                  gop: int = 1, bitrate_kbps: int = 0, fps: float = 60.0,
                  deblock: bool = False, intra_modes: str = None,
                  superstep_chunk: int = None, spatial_shards=None,
                  tune: str = None, damage_mask: bool = None,
                  row_align: int = None):
-        """``entropy``: where/how entropy coding runs —
+        """``mode``: "cavlc", the one mode there is; any other value is
+        refused (the argument is kept for a caller that still names it).
+        ``entropy``: where/how entropy coding runs —
         "device" (TPU CAVLC, via ops/cavlc_device: only the packed
-        bitstream crosses the host link), "native" (host C++ CAVLC),
-        "python" (CAVLC reference), or "cabac" (host CABAC,
-        bitstream/h264_cabac: Main-profile entropy_coding_mode_flag=1
-        streams, ~10-15% smaller at equal PSNR — the reference's
-        nvh264enc default, ref Dockerfile:210).
+        bitstream crosses the host link), "python" (the host CAVLC
+        reference coder: the tests' reference and the device path's
+        overflow fallback, synchronous), or "cabac" (Main-profile
+        entropy_coding_mode_flag=1 streams, ~10-15% smaller at equal PSNR
+        — the reference's nvh264enc default, ref Dockerfile:210: the
+        device binarizes and the host runs the arithmetic engine,
+        :attr:`cabac_device_binarize`).
         ``keep_recon``: pull reconstruction planes to the host each frame
         (tests/PSNR only — it costs a multi-MB transfer per frame).
         ``host_color``: convert RGB->YUV420 on the host with cv2 before
@@ -410,8 +399,7 @@ class H264Encoder(Encoder):
         ``deblock``: normative in-loop deblocking (ops/h264_deblock):
         slice headers signal disable_deblocking_filter_idc=2 and the
         reference planes P frames predict from are loop-filtered exactly
-        as a conformant decoder filters them.  The native C entropy coder
-        has no idc plumbing, so ``entropy="native"`` keeps it off.
+        as a conformant decoder filters them.
         ``row_align``: the coded picture's macroblock rows are a multiple
         of this, the added lines repeating the last one and cropped back
         by the SPS (as a height that is no multiple of 16 is padded).
@@ -421,26 +409,21 @@ class H264Encoder(Encoder):
         one-chip encoder codes the very picture a mesh of that many
         shards does (the byte-identity reference of the sharded path)."""
         super().__init__(width, height)
-        if mode not in ("pcm", "cavlc"):
-            raise NotImplementedError(f"h264 mode {mode!r} not built yet")
-        if entropy not in ("device", "native", "python", "cabac"):
+        if mode != "cavlc":
+            raise ValueError(f"unknown h264 mode {mode!r}")
+        if entropy not in ("device", "python", "cabac"):
             raise ValueError(f"unknown entropy {entropy!r}")
-        if mode == "pcm" and entropy == "cabac":
-            # the PCM debug path writes plain bits; pairing it with a
-            # cabac=1 PPS would produce an undecodable stream
-            raise ValueError("mode='pcm' does not support entropy='cabac'")
         self.qp = qp
-        self.mode = mode
         self.entropy = entropy
         self.keep_recon = keep_recon
         self.host_color = host_color
         self.gop = max(int(gop), 1)
-        self.deblock = bool(deblock) and entropy != "native"
+        self.deblock = bool(deblock)
         self._deblock_idc = 2 if self.deblock else 1
         # -- perceptual-efficiency tuning tier (ENCODER_TUNE) ----------
         # "off" = byte-identical to the pre-tune encoder; "hq" = per-MB
         # adaptive quantization + Lagrangian mode decisions + optional
-        # 1-frame lookahead (ops/aq; ROADMAP item 4).  The kernel tune
+        # 1-frame lookahead (ops/aq).  The kernel tune
         # downgrades to "hq_noaq" when the loop filter is on: the
         # deblock kernel's thresholds are compiled per slice qp, so the
         # per-MB qp plane is a v1 deblock-off feature (the lambda
@@ -468,30 +451,23 @@ class H264Encoder(Encoder):
             self._ktune = "hq_noaq"
         else:
             self._ktune = tune
+        # where a CABAC stream is binarized (:attr:`cabac_device_binarize`):
+        # on the device, except that the record stream carries no per-MB
+        # qp, so the tier that codes one keeps the level transport
+        self._cabac_dev_bin = self._ktune != "hq"
         # I_16x16-in-P lambda mode decision (the intra escape for
         # content ME cannot track).  v1 plumbing: the device + python
         # CAVLC coders; gated off under deblock (intra bS rules are not
-        # modeled by the filter kernel), CABAC (no I16-in-P binarize
-        # records), and the native C coder (no mode plumbing).
+        # modeled by the filter kernel) and CABAC (no I16-in-P binarize
+        # records).
         self._p_intra = (self._ktune != "off" and not self.deblock
-                         and mode == "cavlc"
                          and entropy in ("device", "python"))
         self._mean_qp_pending = None     # per-frame mean coded qp (hq)
         # Intra mode-set selection ("auto" fast sets / "full" nine-mode
-        # I4x4, ENCODER_INTRA_MODES).  The native C CAVLC coder has no
-        # per-MB mode plumbing, so pin DC only when that coder will
-        # actually run — without the compiled lib the Python fallback
-        # handles modes fine.
+        # I4x4, ENCODER_INTRA_MODES).
         if intra_modes not in (None, "auto", "full", "i16", "dc"):
             raise ValueError(f"unknown intra_modes {intra_modes!r}")
-        if entropy == "native" and intra_modes in (None, "auto"):
-            # "auto" (the config default) must not defeat the DC pin, or
-            # ENCODER_ENTROPY=native would silently never run the native
-            # coder (it has no mode plumbing)
-            from ..native import lib as native_lib
-            self.i16_modes = "dc" if native_lib.has_cavlc() else "auto"
-        else:
-            self.i16_modes = intra_modes or "auto"
+        self.i16_modes = intra_modes or "auto"
         self.last_recon = None
         self.fps = float(fps)
         self._spatial_req = spatial_shards
@@ -547,14 +523,13 @@ class H264Encoder(Encoder):
                       if bitrate_kbps > 0 else None)
         self._forced_qp = None          # prewarm(): pin the ladder step
         self.degrade_qp_offset = 0      # resilience/degrade ladder bias
-        # Recent pull sizes (bits of history -> decaying max): the pull
-        # prefix must cover the LARGEST recent frame, not the previous
-        # one — content whose size alternates across frames would
-        # otherwise mispredict half the time, and every mispredict costs
-        # a serial second device pull (a full host<->device round trip).
-        import collections as _c
-        self._pull_hist = _c.deque(maxlen=8)
-        self._p_pull_hist = _c.deque(maxlen=8)
+        # The flat buffer's pull, one helper a kind of frame as the CABAC
+        # transport's above: the prefix must cover the LARGEST recent
+        # frame, not the previous one — content whose size alternates
+        # across frames would otherwise mispredict half the time, and
+        # every mispredict costs a serial second device pull (a full
+        # host<->device round trip).
+        self._flat_pull = {"intra": FlatPull(4), "p": FlatPull(2)}
         # -- super-step ring (ops/devloop.build_p_chunk_step) ----------
         # P frames are staged host-side into a GOP-chunk ring and the
         # whole chunk is dispatched as ONE donated-buffer XLA program
@@ -600,7 +575,7 @@ class H264Encoder(Encoder):
         self._content_pending = {}
         self._content_meta = None
         self._content_n = 0
-        # -- damage-driven encode (ops/damage_mask, ROADMAP item 3) ----
+        # -- damage-driven encode (ops/damage_mask) --------------------
         # Per-frame device cost proportional to CHANGED rows: the host
         # twin of the content plane's damage grid (same kernel, same
         # threshold — one substrate) compacts each P frame to a padded
@@ -823,17 +798,14 @@ class H264Encoder(Encoder):
     def _ring_chunk(self) -> int:
         """Frames per super-step chunk (0 = ring off).  The ring needs
         a GOP (P frames to chain), a device-resident entropy path
-        (device CAVLC, or CABAC with device binarization), and no
-        per-frame recon pulls (``keep_recon`` is the tests' PSNR hook —
-        the chunk step keeps recon on device by design)."""
+        (:attr:`_device_entropy`), and no per-frame recon pulls
+        (``keep_recon`` is the tests' PSNR hook — the chunk step keeps
+        recon on device by design)."""
         c = self._ring_chunk_cached
         if c is None:
             c = 0
-            if (self.superstep_chunk >= 2 and self.mode == "cavlc"
-                    and self.gop > 1 and not self.keep_recon
-                    and (self.entropy == "device"
-                         or (self.entropy == "cabac"
-                             and self.cabac_device_binarize))):
+            if (self.superstep_chunk >= 2 and self.gop > 1
+                    and not self.keep_recon and self._device_entropy):
                 # <= 6 so ring depth + pipeline never outruns the rate
                 # controller's MAX_INFLIGHT reservation window
                 c = max(2, min(self.superstep_chunk, 6))
@@ -871,8 +843,8 @@ class H264Encoder(Encoder):
     def _spatial_plan(self) -> int:
         """Shards the request asks for and the host can give (1 = off).
         Eligibility mirrors the super-step ring's: device-resident
-        entropy (device CAVLC, or CABAC with device binarization) and no
-        per-frame recon pulls (``keep_recon`` is the tests' PSNR hook;
+        entropy (:attr:`_device_entropy`) and no per-frame recon pulls
+        (``keep_recon`` is the tests' PSNR hook;
         the sharded recon stays distributed by design).  Asked at
         construction, where the coded height follows the answer
         (``row_align``), and again at the first frame."""
@@ -881,10 +853,7 @@ class H264Encoder(Encoder):
             import os
             req = os.environ.get("ENCODER_SPATIAL_SHARDS", "0")
         req = str(req).strip() or "0"
-        eligible = (self.mode == "cavlc" and not self.keep_recon
-                    and (self.entropy == "device"
-                         or (self.entropy == "cabac"
-                             and self.cabac_device_binarize)))
+        eligible = not self.keep_recon and self._device_entropy
         if not eligible or req in ("0", "1", "off"):
             return 1
         import jax
@@ -1013,8 +982,6 @@ class H264Encoder(Encoder):
             pass
 
     def _sp_submit_intra(self, idr_pic_id: int, begun):
-        from ..ops import cavlc_device
-
         qp, (y, cb, cr) = begun                   # of _intra_begin
         with obst.stage("dispatch") as span:
             step = self._sp_step("intra", qp)
@@ -1040,10 +1007,7 @@ class H264Encoder(Encoder):
                 else:
                     buf = out
                 marker, lv = "sp", None
-                base = cavlc_device.META_WORDS * 4
-                guess = getattr(self, "_pull_guess", 4 * self._PULL_BUCKET)
-                prefix = buf[:, :base + guess]
-                _prefetch_host(prefix)
+                prefix = self._flat_pull["intra"].prefix(buf)
             # sharded stats: damage + activity only (recon/MV layouts
             # are per-shard; the global-reduce stats stay exact)
             self._content_submit(y, frame_type="intra")
@@ -1051,7 +1015,6 @@ class H264Encoder(Encoder):
         return (marker, "intra", qp, idr_pic_id, 0, buf, prefix, lv)
 
     def _sp_submit_p(self, y, cb, cr, qp: int, frame_num: int = None):
-        from ..ops import cavlc_device
         from ..parallel import batch
 
         with obst.stage("dispatch") as span:
@@ -1077,11 +1040,7 @@ class H264Encoder(Encoder):
                     buf, ry, rcb, rcr, mv, lv = step(
                         y, cb, cr, *self._ref, hv, hl, *qp_t)
                 marker = "sp"
-                base = cavlc_device.META_WORDS * 4
-                guess = getattr(self, "_p_pull_guess",
-                                2 * self._PULL_BUCKET)
-                prefix = buf[:, :base + guess]
-                _prefetch_host(prefix)
+                prefix = self._flat_pull["p"].prefix(buf)
             # what one chip receives in this frame's one collective, from
             # the operands' shapes (the per-frame steps gather nothing:
             # dngd_mesh_gather_bytes_total stays 0)
@@ -1105,20 +1064,15 @@ class H264Encoder(Encoder):
                          frame_num: int, flat, prefix, lv_mv) -> bytes:
         """Assemble a spatially-sharded CAVLC AU: per-shard FlatMeta +
         NAL concatenation (slice-per-MB-row makes shards self-contained
-        — the 'stitch' is pure byte concatenation).  Same pull-guess /
-        short-read / overflow protocol as the single-device path, per
-        shard."""
+        — the 'stitch' is pure byte concatenation).  The one-chip path's
+        pull (:class:`FlatPull`), every shard at the longest's length."""
         from ..bitstream import h264 as syn, h264_entropy
         from ..ops import cavlc_device
 
-        rows_l = self._sp_rows_local()
-        base = cavlc_device.META_WORDS * 4
-        with obst.stage("pull"):
-            bufs = np.asarray(prefix)             # (nx, base + guess)
+        got = self._flat_pull[kind].pull(flat, prefix,
+                                         self._sp_rows_local())
         t0 = time.perf_counter()                  # post-pull: stitch only
-        metas = [cavlc_device.FlatMeta(bufs[i], rows_l)
-                 for i in range(len(bufs))]
-        if any(m.overflow for m in metas):
+        if got is None:
             _note_entropy_overflow("spatial " + kind)
             if kind == "p" and lv_mv is not None:
                 # host-entropy the sharded stage's OWN level tensors
@@ -1137,29 +1091,13 @@ class H264Encoder(Encoder):
             # intra overflow is pathological-qp only; the session's
             # resilience path turns this into an IDR resync
             raise RuntimeError("spatial intra shard overflow")
+        bufs, metas = got
         self._note_qp_sum(sum(m.qp_sum for m in metas))
-        need = max(4 * m.total_words for m in metas)
-        bucket = self._PULL_BUCKET
-        hist = self._pull_hist if kind == "intra" else self._p_pull_hist
-        hist.append(need)
-        guess = -(-max(hist) // bucket) * bucket
-        if kind == "intra":
-            self._pull_guess = guess
-        else:
-            self._p_pull_guess = guess
-        full = None
         parts = [self.headers()] if kind == "intra" else []
-        for i, m in enumerate(metas):
-            buf_i = bufs[i]
-            if 4 * m.total_words > len(buf_i) - base:
-                if full is None:
-                    extra = -(-need // bucket) * bucket
-                    full = np.asarray(flat[:, :base + extra])
-                buf_i = full[i]
-            parts.append(cavlc_device.assemble_annexb(
-                buf_i, m,
-                nal_type=None if kind == "intra" else syn.NAL_SLICE,
-                ref_idc=3 if kind == "intra" else 2))
+        parts += [cavlc_device.assemble_annexb(
+            buf_i, m, nal_type=None if kind == "intra" else syn.NAL_SLICE,
+            ref_idc=3 if kind == "intra" else 2)
+            for buf_i, m in zip(bufs, metas)]
         au = b"".join(parts)
         self._sp_record_stitch((time.perf_counter() - t0) * 1e3)
         return au
@@ -1180,7 +1118,7 @@ class H264Encoder(Encoder):
         rows_l = self._sp_rows_local()
         if not self._cabac_native:
             _M_CABAC_PYTHON.inc()
-        heads = self._sp_cabac_pull(kind).pull_shards(buf, prefix)
+        heads = self._sp_cabac_pull(kind).pull(buf, prefix)
         with obst.stage("assemble", more=True):
             hdr = dict(qp=qp, qp_delta=qp - self.qp,
                        deblocking_idc=self._deblock_idc)
@@ -1220,37 +1158,7 @@ class H264Encoder(Encoder):
             return h264_cabac.encode_p_picture(dense, **hdr)
 
     # ------------------------------------------------------------------
-    # I_PCM path: conformance bootstrap, trivially correct samples
-    # ------------------------------------------------------------------
-
-    def _encode_pcm(self, rgb) -> bytes:
-        y, cb, cr = _yuv_stage(jnp.asarray(rgb), self.pad_h, self.pad_w)
-        y, cb, cr = np.asarray(y), np.asarray(cb), np.asarray(cr)
-
-        bw = BitWriter()
-        syn.slice_header(bw, first_mb=0, slice_type=7,
-                         frame_num=0, idr=True,
-                         idr_pic_id=self.frame_index % 2)
-        # First macroblock: mb_type I_PCM = ue(25), then byte alignment.
-        syn.write_ue(bw, 25)
-        bw.pad_to_byte(0)                      # pcm_alignment_zero_bit(s)
-        head = bytes(bw.buf)                   # byte-aligned prefix
-
-        y_mb = _mb_tiles(y, 16)                # (nmb, 256)
-        cb_mb = _mb_tiles(cb, 8)               # (nmb, 64)
-        cr_mb = _mb_tiles(cr, 8)
-        nmb = y_mb.shape[0]
-
-        # Every subsequent MB starts byte-aligned: ue(25) is 9 bits
-        # ("0000 11010") + 7 alignment zeros = bytes 0x0D 0x00.
-        prefix = np.tile(np.array([0x0D, 0x00], np.uint8), (nmb, 1))
-        mbs = np.concatenate([prefix, y_mb, cb_mb, cr_mb], axis=1)
-        body = mbs.reshape(-1)[2:]             # first MB's prefix came via bw
-        rbsp = head + body.tobytes() + b"\x80"  # rbsp_trailing (aligned)
-        return self.headers() + syn.nal_unit(syn.NAL_IDR, rbsp)
-
-    # ------------------------------------------------------------------
-    # CAVLC I_16x16 path: the real flagship intra codec
+    # Intra path: one IDR through the stream's entropy coder
     # ------------------------------------------------------------------
 
     def _encode_cavlc(self, rgb) -> bytes:
@@ -1265,11 +1173,6 @@ class H264Encoder(Encoder):
                 self._submit_cabac_intra(rgb, idr_pic_id))
 
         return self._encode_host_entropy(rgb, idr_pic_id)
-
-    # Pull granularity for the flat buffer: a fixed set of prefix sizes so
-    # the slicing computation is compile-cached (a fresh size per frame
-    # would compile a new device slice every frame).
-    _PULL_BUCKET = 1 << 16                         # 64 KiB
 
     _host_yuv_ok = None                            # class-level cv2 probe
 
@@ -1321,8 +1224,7 @@ class H264Encoder(Encoder):
         bias move freely on a cold cache and there is no ladder to
         prewarm.  The hq tiers keep qp static (their lambda decisions
         are compile-time floats)."""
-        return (self._ktune == "off" and self.mode == "cavlc"
-                and self.entropy in ("device", "cabac"))
+        return self._ktune == "off" and self.entropy in ("device", "cabac")
 
     def _deblock(self, y, cb, cr, qp: int, **bs_inputs):
         """In-loop filter of the per-frame device path (qp traced where
@@ -1398,7 +1300,7 @@ class H264Encoder(Encoder):
             return 0
         qps = self.ladder_qps() if qps is None else list(qps)
         scratch = H264Encoder(
-            self.width, self.height, qp=self.qp, mode=self.mode,
+            self.width, self.height, qp=self.qp,
             entropy=self.entropy, host_color=self.host_color,
             gop=max(self.gop, 2), deblock=self.deblock,
             intra_modes=self.i16_modes,
@@ -1437,23 +1339,23 @@ class H264Encoder(Encoder):
         which does nothing where the mask is off); 0 on every other path
         (the CAVLC pull ladder is content's to walk: 64 KiB steps of a
         46 KB frame)."""
-        if self.mode == "cavlc" and self.entropy == "device":
+        if self.entropy == "device":
             return self._warm_row_buckets()
-        if self.entropy != "cabac" or self.mode != "cavlc":
+        if self.entropy != "cabac":
             return 0
         nx = self._spatial_nx
-        if nx > 1 and (self._ring_chunk or not self.cabac_device_binarize):
+        if nx > 1 and self._ring_chunk:
             return 0
         t0 = time.perf_counter()
         scratch = H264Encoder(
-            self.width, self.height, qp=self.qp, mode=self.mode,
+            self.width, self.height, qp=self.qp,
             entropy=self.entropy, host_color=self.host_color, gop=2,
             deblock=self.deblock, intra_modes=self.i16_modes,
             superstep_chunk=0, spatial_shards=nx, tune=self.tune,
             damage_mask=False, row_align=self.row_align)
+        scratch._cabac_dev_bin = self.cabac_device_binarize
         rgb = np.zeros((self.height, self.width, 3), np.uint8)
         if nx > 1:
-            scratch._cabac_dev_bin = True     # as this one's, pinned or not
             scratch._sp_mesh_cache = self._sp_mesh()
             scratch._sp_steps = self._sp_steps
             pulls = (scratch._sp_cabac_pull("intra"),
@@ -1494,12 +1396,11 @@ class H264Encoder(Encoder):
             return 0
         if self.entropy == "cabac":
             return self._warm_row_buckets_cabac()
-        from ..ops import cavlc_device
         from ..ops import damage_mask as dmg
 
         t0 = time.perf_counter()
         scratch = H264Encoder(
-            self.width, self.height, qp=self.qp, mode=self.mode,
+            self.width, self.height, qp=self.qp,
             entropy=self.entropy, host_color=True, gop=self.gop,
             deblock=self.deblock, intra_modes=self.i16_modes,
             superstep_chunk=0, spatial_shards=1, tune=self.tune,
@@ -1517,11 +1418,8 @@ class H264Encoder(Encoder):
                 *planes, self.qp,
                 damage_plan=dmg.RowPlan(rows, rows, bucket, total, 1.0))
             scratch._collect_p_device(sub)
-        flat, step, slices = sub[4], self._PULL_BUCKET, 0
-        base = cavlc_device.META_WORDS * 4
-        while base + slices * step < flat.shape[0]:
-            slices += 1
-            flat[:base + slices * step].block_until_ready()
+        flat = sub[4]
+        slices = scratch._flat_pull["p"].warm(flat)
         log.info("damage mask: %d row programs (buckets %s of %d rows) "
                  "beside the IDR and the full-frame P program, %d prefix "
                  "slices of the %d-byte flat buffer, in %.1f s",
@@ -1539,20 +1437,17 @@ class H264Encoder(Encoder):
         record buffer, and the all-skip slice's data at every qp the
         stream can take (``h264_cabac.skip_row_payload``: 52 entries of
         a few bytes, through the Python engine).  Returns programs and
-        slices compiled; 0 where the binarizer is the host's."""
-        if not self.cabac_device_binarize:
-            return 0
+        slices compiled."""
         from ..bitstream import h264_cabac
         from ..ops import damage_mask as dmg
 
         t0 = time.perf_counter()
         scratch = H264Encoder(
-            self.width, self.height, qp=self.qp, mode=self.mode,
+            self.width, self.height, qp=self.qp,
             entropy=self.entropy, host_color=True, gop=self.gop,
             deblock=self.deblock, intra_modes=self.i16_modes,
             superstep_chunk=0, spatial_shards=1, tune=self.tune,
             damage_mask=True, row_align=self.row_align)
-        scratch._cabac_dev_bin = True
         rgb = np.zeros((self.height, self.width, 3), np.uint8)
         scratch.encode(rgb)                       # the IDR: a reference
         planes = scratch._planes_device(rgb)
@@ -1670,9 +1565,7 @@ class H264Encoder(Encoder):
                 # pull NOW: with deblock off these arrays become the next P
                 # submit's DONATED refs — dead by collect time in a pipeline
                 recon = tuple(np.asarray(p) for p in recon)
-            guess = getattr(self, "_pull_guess", 4 * self._PULL_BUCKET)
-            prefix = flat[:cavlc_device.META_WORDS * 4 + guess]
-            _prefetch_host(prefix)
+            prefix = self._flat_pull["intra"].prefix(flat)
         self._count_dispatch(ms=span.ms)
         return (rgb, idr_pic_id, qp, planes, flat, prefix, recon)
 
@@ -1686,11 +1579,8 @@ class H264Encoder(Encoder):
         rgb, idr_pic_id, qp, planes, flat, prefix, recon = submitted
         if recon is not None and self.keep_recon:
             self.last_recon = tuple(np.asarray(p) for p in recon)
-        base = cavlc_device.META_WORDS * 4
-        with obst.stage("pull"):
-            buf = np.asarray(prefix)
-        meta = cavlc_device.FlatMeta(buf, self.mb_h)
-        if meta.overflow:
+        got = self._flat_pull["intra"].pull(flat, prefix, self.mb_h)
+        if got is None:
             _note_entropy_overflow("intra")
             # Reuse the exact device inputs (planes + rate-controlled qp)
             # so the fallback's recon matches what later pipelined frames
@@ -1699,19 +1589,8 @@ class H264Encoder(Encoder):
                 return self._encode_host_entropy(
                     rgb, idr_pic_id, planes=planes, qp=qp,
                     update_ref=not in_pipeline)
+        buf, meta = got
         self._note_qp_sum(meta.qp_sum)
-        need = 4 * meta.total_words
-        # Next frame's pull guess = decaying max of recent needs, ceiled
-        # to the bucket (a bounded set of slice lengths -> a bounded set
-        # of compiled slice executables).
-        bucket = self._PULL_BUCKET
-        self._pull_hist.append(need)
-        self._pull_guess = -(-max(self._pull_hist) // bucket) * bucket
-        if need > len(buf) - base:
-            extra = -(-need // bucket) * bucket
-            _M_PULL_EXTRA.inc()
-            with obst.stage("pull_extra"):
-                buf = np.asarray(flat[:base + extra])
         with obst.stage("assemble", more=True):
             return cavlc_device.assemble_annexb(buf, meta,
                                                 headers=self.headers())
@@ -1728,34 +1607,30 @@ class H264Encoder(Encoder):
 
     @property
     def cabac_device_binarize(self) -> bool:
-        """Device-side binarization + ctxIdx derivation (round 6): the
-        device emits the packed (bin, ctxIdx, bypass) record stream
+        """Whether the DEVICE binarizes a CABAC stream and derives its
+        ctxIdx: it emits the packed (bin, ctxIdx, bypass) record stream
         (ops/cabac_binarize) and the host runs only the arithmetic
-        engine.  Opt-in via ENCODER_CABAC_BINARIZE=device, which is what
-        the benchmark's ``desk1080-cabac`` serves: on a v5e at 1080p the
-        binarize program is ``cabac_binarize_ms`` 2.1 of the chip a frame
-        and both cells deliver 58.7–59.2 frames/s (ledger, PR 29), where
-        the round-5 split — level_pack transport + full host coder —
-        read 34.25 / 40.85 (builder's chip runs, PR 28).  ``host`` is
-        still the default: the flip is ROADMAP R4(b)'s.
-        Either path emits byte-identical streams (tested); an overflow
-        in the packed stream falls back dense per-frame, and is
+        engine.  Chosen from the tune: the record stream has no
+        ``mb_qp_delta``, so ``hq`` (a qp a macroblock) keeps the level
+        transport (ops/level_pack: packed levels to the host's whole
+        coder) and every other tier takes the records (``hq`` under the
+        loop filter is ``hq_noaq``, one qp a slice).  On a v5e at 1080p
+        the binarize program is ``cabac_binarize_ms`` 2.1 of the chip a
+        frame and both cells deliver 58.7-59.2 frames/s (ledger, PR 29),
+        where the level transport read 34.25 / 40.85 (builder's chip
+        runs, PR 28).  Either transport emits byte-identical streams
+        (tested: a test chooses one through ``_cabac_dev_bin``); an
+        overflow in the packed stream falls back dense per-frame, and is
         counted."""
-        v = getattr(self, "_cabac_dev_bin", None)
-        if v is None:
-            import os
-            v = os.environ.get("ENCODER_CABAC_BINARIZE",
-                               "host") == "device"
-            if v and self._ktune == "hq":
-                # the record stream has no mb_qp_delta plumbing yet;
-                # hq CABAC serves through the dense host path
-                log.warning(
-                    "ENCODER_CABAC_BINARIZE=device has no per-MB qp "
-                    "plumbing; ENCODER_TUNE=hq uses the dense host "
-                    "CABAC path")
-                v = False
-            self._cabac_dev_bin = v
-        return v
+        return self._cabac_dev_bin
+
+    @property
+    def _device_entropy(self) -> bool:
+        """Whether the bitstream (or its record stream) is made on the
+        device, which the super-step ring and the spatial mesh need:
+        device CAVLC, or CABAC binarized there."""
+        return self.entropy == "device" or (
+            self.entropy == "cabac" and self.cabac_device_binarize)
 
     # -- mean coded qp (tune=hq): RateController normalization ---------
 
@@ -2063,12 +1938,12 @@ class H264Encoder(Encoder):
             return h264_cabac.encode_p_picture(dense, **hdr)
 
     def _encode_host_entropy(self, rgb, idr_pic_id: int,
-                             prefer_native: bool = None,
                              planes=None, qp: int = None,
                              update_ref: bool = True) -> bytes:
-        """Host-entropy access unit: device transform+quant, CPU CAVLC.
+        """Host-entropy access unit: device transform+quant, the Python
+        CAVLC coder (bitstream/h264_entropy).
 
-        Shared by the "native"/"python" entropy modes and the device path's
+        Shared by ``entropy="python"`` and the device path's
         static-cap overflow fallback (pathological low-qp content), so the
         two can never diverge.  ``planes``/``qp`` let the fallback reuse
         the exact device inputs of the overflowed submit (host-color
@@ -2077,13 +1952,10 @@ class H264Encoder(Encoder):
         planes cross the host link only when ``keep_recon`` asked for them.
         """
         from ..bitstream import h264_entropy
-        from ..native import lib as native_lib
         from ..ops import h264_device
 
-        if prefer_native is None:
-            prefer_native = self.entropy != "python"
         if qp is None:
-            # direct host-entropy call (python/native modes): consult the
+            # direct host-entropy call (entropy="python"): consult the
             # rate controller like the device path's submit does — IDR
             # bursts must hit the VBV keyframe guard on every path
             qp = self._eff_qp()
@@ -2112,27 +1984,14 @@ class H264Encoder(Encoder):
         qp_map = levels.pop("qp_map", None)
         self._note_qp_map(qp_map, levels=levels, slice_qp=qp,
                           intra=True)
-        qp_delta = qp - self.qp
         # entropy == "cabac" never reaches here: _encode_cavlc routes it
         # to the packed-transport path (_submit/_collect_cabac_intra),
         # and the device-overflow fallback only runs with entropy=="device"
-        uses_modes = bool((levels["pred_mode"] != 2).any()
-                          or levels.get("mb_i4", np.False_).any())
-        if (qp_delta == 0 and not uses_modes and prefer_native
-                and qp_map is None
-                and not self.deblock and native_lib.has_cavlc()):
-            return (self.headers()
-                    + native_lib.h264_encode_intra_picture(
-                        levels, frame_num=0, idr_pic_id=idr_pic_id))
-        # the C coder has no qp_delta/qp_map plumbing; rate-controlled
-        # and tune=hq frames take the Python path
         return h264_entropy.encode_intra_picture(
             levels, frame_num=0, idr_pic_id=idr_pic_id,
             sps=self._sps, pps=self._pps, with_headers=True,
-            qp_delta=qp_delta, deblocking_idc=self._deblock_idc,
+            qp_delta=qp - self.qp, deblocking_idc=self._deblock_idc,
             qp_map=qp_map, slice_qp=qp)
-
-    # ------------------------------------------------------------------
 
     # ------------------------------------------------------------------
     # Inter (P-frame) path: GOP state machine + device inter stage
@@ -2162,8 +2021,8 @@ class H264Encoder(Encoder):
             "frame_num": self._frame_num,
             "idr_count": self._idr_count,
             "qp_offset": self.degrade_qp_offset,
-            "pull_guess": getattr(self, "_pull_guess", None),
-            "p_pull_guess": getattr(self, "_p_pull_guess", None),
+            "pull_guess": self._flat_pull["intra"].checkpoint(),
+            "p_pull_guess": self._flat_pull["p"].checkpoint(),
         })
         if self._rate is not None:
             st["rate"] = {
@@ -2188,10 +2047,8 @@ class H264Encoder(Encoder):
         self._frame_num = int(state.get("frame_num", 0))
         self._idr_count = int(state.get("idr_count", 0))
         self.degrade_qp_offset = int(state.get("qp_offset", 0))
-        if state.get("pull_guess"):
-            self._pull_guess = int(state["pull_guess"])
-        if state.get("p_pull_guess"):
-            self._p_pull_guess = int(state["p_pull_guess"])
+        self._flat_pull["intra"].restore(state.get("pull_guess"))
+        self._flat_pull["p"].restore(state.get("p_pull_guess"))
         rate = state.get("rate")
         if rate is not None and self._rate is not None:
             self._rate.level = float(rate["level"])
@@ -2258,7 +2115,7 @@ class H264Encoder(Encoder):
         dead past this call; the overflow fallback entropy-codes the
         stage's own level tensors instead of re-encoding against them.
         ``next_y`` (tune=hq ring flush): the 1-frame-lookahead luma."""
-        from ..ops import cavlc_device, cavlc_p_device
+        from ..ops import cavlc_p_device
 
         if self._spatial_nx > 1:
             return self._sp_submit_p(y, cb, cr, qp, frame_num)
@@ -2303,10 +2160,7 @@ class H264Encoder(Encoder):
                 # submit's (donated) refs — by collect time they may be dead
                 recon = tuple(np.asarray(p) for p in recon)
                 mv = np.asarray(mv)
-            base = cavlc_device.META_WORDS * 4
-            guess = getattr(self, "_p_pull_guess", 2 * self._PULL_BUCKET)
-            prefix = flat[:base + guess]
-            _prefetch_host(prefix)
+            prefix = self._flat_pull["p"].prefix(flat)
         self._count_dispatch(ms=span.ms)
         return (qp, frame_num, levels, recon, flat, prefix, mv)
 
@@ -2320,16 +2174,13 @@ class H264Encoder(Encoder):
         if isinstance(submitted[0], str) and submitted[0] == "dmg":
             return self._collect_p_masked(submitted)
         qp, frame_num, levels, recon, flat, prefix, mv = submitted
-        base = cavlc_device.META_WORDS * 4
-        with obst.stage("pull"):
-            buf = np.asarray(prefix)
-        meta = cavlc_device.FlatMeta(buf, self.mb_h)
+        got = self._flat_pull["p"].pull(flat, prefix, self.mb_h)
         if self.keep_recon:
             # THIS frame's recon (pulled at submit) — self._ref may
             # already belong to a newer pipelined submit.
             self.last_recon = tuple(np.asarray(p) for p in recon)
             self.last_mv = np.asarray(mv)
-        if meta.overflow:
+        if got is None:
             _note_entropy_overflow("p")
             # pathological content: host-entropy the SAME levels the
             # device stage produced (byte-identical to re-running the
@@ -2346,24 +2197,16 @@ class H264Encoder(Encoder):
                     pulled, frame_num=frame_num, qp_delta=qp - self.qp,
                     deblocking_idc=self._deblock_idc,
                     qp_map=qp_map, slice_qp=qp)
+        buf, meta = got
         self._note_qp_sum(meta.qp_sum)
-        need = 4 * meta.total_words
-        bucket = self._PULL_BUCKET
-        self._p_pull_hist.append(need)
-        self._p_pull_guess = -(-max(self._p_pull_hist) // bucket) * bucket
-        if need > len(buf) - base:
-            extra = -(-need // bucket) * bucket
-            _M_PULL_EXTRA.inc()
-            with obst.stage("pull_extra"):
-                buf = np.asarray(flat[:base + extra])
         with obst.stage("assemble", more=True):
             return cavlc_device.assemble_annexb(
                 buf, meta, nal_type=syn.NAL_SLICE, ref_idc=2)
 
     # ------------------------------------------------------------------
-    # Damage-driven encode (ops/damage_mask, ROADMAP item 3): the
-    # masked P path.  The host twin of the content plane's damage grid
-    # compacts each P frame to its damaged MB rows; untouched rows ship
+    # Damage-driven encode (ops/damage_mask): the masked P path.  The
+    # host twin of the content plane's damage grid compacts each P
+    # frame to its damaged MB rows; untouched rows ship
     # as host-cached all-skip slices whose decoder reconstruction is
     # the reference rows bit-exactly.  One submit event per frame
     # either way — dispatch-crossings-per-frame is unchanged — and the
@@ -2376,13 +2219,12 @@ class H264Encoder(Encoder):
         the masked path cannot serve it: mask off, device-side ingest,
         keep_recon debug pulls, a spatial mesh, or an entropy placement
         outside ``ops/damage_mask.MASKED_ENTROPY`` — the device CAVLC
-        path, and the CABAC path where the binarizer is the device's and
-        qp is traced (``ENCODER_CABAC_BINARIZE=device``, tune=off: the
-        host-binarize and hq CABAC frames have no row program, and stay
-        dense).  Feeds the rate controller's damage consumer as a side
-        effect."""
+        path, and the CABAC path where qp is traced and the device
+        binarizes (tune=off: the hq tiers' CABAC frames have no row
+        program, and stay dense).  Feeds the rate controller's damage
+        consumer as a side effect."""
         from ..ops import damage_mask as dmg
-        if (not self.damage_mask or self.mode != "cavlc"
+        if (not self.damage_mask
                 or self.entropy not in dmg.MASKED_ENTROPY
                 or (self.entropy == "cabac"
                     and not (self._dyn_qp and self.cabac_device_binarize))
@@ -2455,7 +2297,6 @@ class H264Encoder(Encoder):
         activity land; mode-mix stats are excluded on this path (the
         untouched rows ARE skip by construction — same documented
         exclusion class as the spatial shards)."""
-        from ..ops import cavlc_device
         from ..ops import damage_mask as dmg
 
         with obst.stage("dispatch") as span:
@@ -2477,24 +2318,17 @@ class H264Encoder(Encoder):
                     p_intra=self._p_intra, deblock=self.deblock)
             self._ref = (ry, rcb, rcr)
             self._content_submit(planes[0], recon_y=ry)
-            base = cavlc_device.META_WORDS * 4
-            guess = getattr(self, "_p_pull_guess", 2 * self._PULL_BUCKET)
-            prefix = flat[:base + guess]
-            _prefetch_host(prefix)
+            prefix = self._flat_pull["p"].prefix(flat)
         self._count_dispatch(ms=span.ms)
         return ("dmg", qp, frame_num, levels, flat, prefix, mv, plan)
 
     def _collect_p_masked(self, submitted) -> bytes:
-        from ..bitstream import h264 as syn, h264_entropy
-        from ..ops import cavlc_device
+        from ..bitstream import h264_entropy
         from ..ops import damage_mask as dmg
 
         _, qp, frame_num, levels, flat, prefix, mv, plan = submitted
-        base = cavlc_device.META_WORDS * 4
-        with obst.stage("pull"):
-            buf = np.asarray(prefix)
-        meta = cavlc_device.FlatMeta(buf, plan.bucket)
-        if meta.overflow:
+        got = self._flat_pull["p"].pull(flat, prefix, plan.bucket)
+        if got is None:
             _note_entropy_overflow("masked p")
             # flat-cap overflow on a compacted frame: scatter the
             # worklist's level tensors back to full-frame shapes
@@ -2521,6 +2355,7 @@ class H264Encoder(Encoder):
                     full_lv, frame_num=frame_num, qp_delta=qp - self.qp,
                     deblocking_idc=self._deblock_idc,
                     qp_map=qp_map, slice_qp=qp)
+        buf, meta = got
         if meta.qp_sum:
             # meta sums the WORKLIST's effective qps; untouched rows
             # decode at slice qp.  (Padded duplicate rows bias the sum
@@ -2528,15 +2363,6 @@ class H264Encoder(Encoder):
             self._note_qp_sum(int(meta.qp_sum)
                               + qp * self.mb_w
                               * (self.mb_h - plan.bucket))
-        need = 4 * meta.total_words
-        bucket = self._PULL_BUCKET
-        self._p_pull_hist.append(need)
-        self._p_pull_guess = -(-max(self._p_pull_hist) // bucket) * bucket
-        if need > len(buf) - base:
-            extra = -(-need // bucket) * bucket
-            _M_PULL_EXTRA.inc()
-            with obst.stage("pull_extra"):
-                buf = np.asarray(flat[:base + extra])
         with obst.stage("assemble", more=True):
             return dmg.assemble_masked_au(
                 buf, meta, plan.rows, self.mb_h, self.mb_w,
@@ -2652,16 +2478,15 @@ class H264Encoder(Encoder):
         """Launch the chunk: ONE jitted call; the ref ring is donated
         and the bitstream prefix comes back as an output of the same
         program (no separate slice dispatch)."""
-        from ..ops import cavlc_device, devloop
+        from ..ops import devloop
 
         t0 = time.perf_counter()
         self._chunk_seq += 1
         ring["chunk_id"] = self._chunk_seq
         qp = ring["qp"]
         if ring["kind"] == "cavlc":
-            base = cavlc_device.META_WORDS * 4
-            guess = getattr(self, "_p_pull_guess", 2 * self._PULL_BUCKET)
-            plen = base + guess
+            pull = self._flat_pull["p"]
+            plen = pull.hdrw + pull.guess
             hdrs = self._chunk_hdr_slots(tuple(ring["fns"]),
                                          qp - self.qp)
         else:
@@ -2757,7 +2582,8 @@ class H264Encoder(Encoder):
                 # sharded hq flush codes without the lookahead bias —
                 # conformant, rate-model safe (the qp_sum meta still
                 # rides), but not byte-equal to the chunk the frames
-                # would have ridden (ROADMAP item 4 pending list).
+                # would have ridden (the spatial step has no lookahead
+                # operand).
                 next_y = planes[min(i + 1, len(planes) - 1)][0]
             if ring["kind"] == "cavlc":
                 plans = ring.get("plans")
@@ -2817,9 +2643,10 @@ class H264Encoder(Encoder):
         if ring.get("dmg") is not None:
             return self._ring_collect_masked(ring, head, slot,
                                              frame_num)
-        base = cavlc_device.META_WORDS * 4
-        meta = cavlc_device.FlatMeta(head, self.mb_h)
-        if meta.overflow:
+        # the chunk's prefix is on the host already: the pull's first
+        # step copies nothing, its second is the per-frame path's
+        got = self._flat_pull["p"].pull(flats[slot], head, self.mb_h)
+        if got is None:
             _note_entropy_overflow("ring p")
             # same fallback as the per-frame path: host-entropy the
             # chunk's own level tensors for this frame
@@ -2831,15 +2658,8 @@ class H264Encoder(Encoder):
                 pulled, frame_num=frame_num, qp_delta=qp - self.qp,
                 deblocking_idc=self._deblock_idc, qp_map=qp_map,
                 slice_qp=qp)
+        buf, meta = got
         self._note_qp_sum(meta.qp_sum)
-        need = 4 * meta.total_words
-        bucket = self._PULL_BUCKET
-        self._p_pull_hist.append(need)
-        self._p_pull_guess = -(-max(self._p_pull_hist) // bucket) * bucket
-        buf = head
-        if need > len(buf) - base:
-            extra = -(-need // bucket) * bucket
-            buf = np.asarray(flats[slot][:base + extra])
         return cavlc_device.assemble_annexb(
             buf, meta, nal_type=syn.NAL_SLICE, ref_idc=2)
 
@@ -2849,16 +2669,14 @@ class H264Encoder(Encoder):
         against the chunk's stacked outputs — FlatMeta over the shared
         row bucket, skip-slice interleave from the staged worklist."""
         from ..bitstream import h264_entropy
-        from ..ops import cavlc_device
         from ..ops import damage_mask as dmg
 
         qp = ring["qp"]
         flats, _, mvs, lvs = ring["res"]
         bucket, padded = ring["dmg"]
         rows_p = padded[slot]
-        base = cavlc_device.META_WORDS * 4
-        meta = cavlc_device.FlatMeta(head, bucket)
-        if meta.overflow:
+        got = self._flat_pull["p"].pull(flats[slot], head, bucket)
+        if got is None:
             _note_entropy_overflow("masked ring p")
             pulled = {k: np.asarray(v[slot]) for k, v in lvs.items()}
             qp_map = pulled.pop("qp_map", None)
@@ -2877,18 +2695,11 @@ class H264Encoder(Encoder):
                 full_lv, frame_num=frame_num, qp_delta=qp - self.qp,
                 deblocking_idc=self._deblock_idc,
                 qp_map=qp_map, slice_qp=qp)
+        buf, meta = got
         if meta.qp_sum:
             self._note_qp_sum(int(meta.qp_sum)
                               + qp * self.mb_w
                               * (self.mb_h - bucket))
-        need = 4 * meta.total_words
-        bk = self._PULL_BUCKET
-        self._p_pull_hist.append(need)
-        self._p_pull_guess = -(-max(self._p_pull_hist) // bk) * bk
-        buf = head
-        if need > len(buf) - base:
-            extra = -(-need // bk) * bk
-            buf = np.asarray(flats[slot][:base + extra])
         return dmg.assemble_masked_au(
             buf, meta, rows_p, self.mb_h, self.mb_w,
             frame_num=frame_num, qp_delta=qp - self.qp,
@@ -3006,12 +2817,9 @@ class H264Encoder(Encoder):
 
     def encode(self, rgb) -> EncodedFrame:
         t0 = time.perf_counter()
-        if self.mode == "pcm":
-            data = self._encode_pcm(rgb)
-            key = True
-        elif self.mode == "cavlc" and self.gop > 1:
+        if self.gop > 1:
             data, key = self._gop_step(rgb)
-        elif self.mode == "cavlc":
+        else:
             n0 = self._rate.mark() if self._rate is not None else 0
             try:
                 data = self._encode_cavlc(rgb)
@@ -3023,8 +2831,6 @@ class H264Encoder(Encoder):
             if self._rate is not None:
                 self._rate.update(len(data) * 8,
                                   mean_qp=self._take_mean_qp())
-        else:
-            raise ValueError(f"unknown mode {self.mode}")
         ms = (time.perf_counter() - t0) * 1e3
         PROFILER.record_encoder(
             self, ("intra" if key else "p") + "-encode", ms)
@@ -3066,7 +2872,7 @@ class H264Encoder(Encoder):
         AFTER this frame's qp was reserved and its damage noted, as it
         does behind the whole submit).  The super-step ring submits in
         one piece and calls nothing."""
-        if self.mode != "cavlc" or self.entropy not in ("device", "cabac"):
+        if self.entropy not in ("device", "cabac"):
             ef = self.encode(rgb)
             self._content_last = None    # sync path: no stats contract
             return ("sync", None, None, True, ef)

@@ -19,8 +19,8 @@ M_PULL_EXTRA = obsm.counter(
 M_CABAC_RECORD_BYTES = obsm.counter(
     "dngd_encoder_cabac_record_bytes_total",
     "Bytes of CABAC transport the host pulled for its engine: header and "
-    "payload of the binarize record stream (of the packed levels under "
-    "ENCODER_CABAC_BINARIZE=host), without the slack of the guessed prefix")
+    "payload of the binarize record stream (of the packed levels on the "
+    "level transport), without the slack of the guessed prefix")
 M_D2H_BYTES = obsm.counter(
     "dngd_encoder_d2h_bytes_total",
     "Bytes the per-frame CABAC path copied from the device: the guessed "
@@ -59,21 +59,23 @@ class PrefixPull:
 
     A spatial mesh's frame is one such buffer a shard, stacked
     (``(shards, words)``): the same ladder along the last axis, every
-    shard pulled at the length the longest needs
-    (:meth:`pull_shards`)."""
+    shard pulled at the length the longest needs.
+
+    :class:`FlatPull` is the same pull of the CAVLC flat buffer, under
+    its own ladder and header."""
 
     BUCKET = 1 << 14                       # words: 64 KiB
+    HISTORY = 64                           # frames the guess looks back on
 
     def __init__(self, hdrw: int, buckets: int):
         self.hdrw = hdrw
         self.guess = buckets * self.BUCKET
-        self.hist = collections.deque(maxlen=64)
+        self.hist = collections.deque(maxlen=self.HISTORY)
 
-    @classmethod
-    def rung(cls, words: int) -> int:
-        b = max(-(-words // cls.BUCKET), 1)
+    def rung(self, words: int) -> int:
+        b = max(-(-words // self.BUCKET), 1)
         step = 1 << max(b.bit_length() - 2, 0)
-        return -(-b // step) * step * cls.BUCKET
+        return -(-b // step) * step * self.BUCKET
 
     def note(self, words: int) -> None:
         """One frame's payload: the next guess covers it."""
@@ -101,41 +103,95 @@ class PrefixPull:
             n += 1
         return n
 
-    def pull(self, buf, prefix):
-        """The host copy of header and payload, pulled again where the
-        guess was short; None on the overflow flag."""
+    def _pull(self, buf, prefix, read):
+        """The one pull, ``(host buffer, bytes copied)``: the host copy
+        of the guessed prefix (stage ``pull``), ``read(header)`` for what
+        the payload needs in the ladder's unit (None where an overflow
+        flag is up, which ends the pull: no buffer), the need noted for
+        the next guess, and the whole of it pulled again at its rung
+        where the guess was short (stage ``pull_extra``, counted)."""
         with obst.stage("pull"):
             head = np.asarray(prefix)
-        M_D2H_BYTES.inc(head.nbytes)
-        if head[1]:
-            return None
-        words = int(head[2])
-        self.note(words)
-        if self.hdrw + words > len(head):
+        copied = head.nbytes
+        need = read(head)
+        if need is None:
+            return None, copied
+        self.note(need)
+        if self.hdrw + need > head.shape[-1]:
             M_PULL_EXTRA.inc()
             with obst.stage("pull_extra"):
-                head = np.asarray(buf[:self.hdrw + self.rung(words)])
-            M_D2H_BYTES.inc(head.nbytes)
-        M_CABAC_RECORD_BYTES.inc(4 * (self.hdrw + words))
+                head = np.asarray(self._cut(buf, self.rung(need)))
+            copied += head.nbytes
+        return head, copied
+
+    def pull(self, buf, prefix):
+        """The host copy of header and payload (of every shard's, on a
+        mesh's ``(shards, words)`` buffer), pulled again where the guess
+        was short (all shards at the longest need's rung); None where an
+        overflow flag is up."""
+        head, copied = self._pull(
+            buf, prefix,
+            lambda h: None if h[..., 1].any() else int(h[..., 2].max()))
+        M_D2H_BYTES.inc(copied)
+        if head is not None:
+            words = head[..., 2].astype(np.int64)
+            M_CABAC_RECORD_BYTES.inc(4 * int((self.hdrw + words).sum()))
         return head
 
-    def pull_shards(self, buf, prefix):
-        """:meth:`pull` for a mesh's ``(shards, words)`` buffer: the
-        host copy of every shard's header and payload, all pulled again
-        at the longest need's rung where the guess was short of any;
-        None where any shard's overflow flag is up."""
-        with obst.stage("pull"):
-            heads = np.asarray(prefix)
-        M_D2H_BYTES.inc(heads.nbytes)
-        if heads[:, 1].any():
+
+class FlatPull(PrefixPull):
+    """The host's pull of the CAVLC flat buffer (ops/cavlc_device: a
+    header of ``META_WORDS`` words, then the frame's bytes), one a kind
+    of frame as the CABAC helpers are.  Its ladder is the linear one the
+    path has always walked: multiples of 64 KiB, the max of the last 8
+    frames' needs, counted in BYTES (the buffer is uint8).  Content
+    walks it (64 KiB steps of a 46 KB frame), the damage mask's set-up
+    warms it whole (:meth:`warm`)."""
+
+    BUCKET = 1 << 16                       # bytes: 64 KiB
+    HISTORY = 8
+
+    def __init__(self, buckets: int):
+        from ..ops import cavlc_device
+        super().__init__(cavlc_device.META_WORDS * 4, buckets)
+        self._learnt = False
+
+    def rung(self, nbytes: int) -> int:
+        return -(-nbytes // self.BUCKET) * self.BUCKET
+
+    def note(self, nbytes: int) -> None:
+        super().note(nbytes)
+        self._learnt = True
+
+    def checkpoint(self):
+        """The guess as ``export_state`` carries it: None until a frame
+        or a checkpoint has set it, so that putting a fresh encoder's
+        state back leaves a warmed guess alone."""
+        return self.guess if self._learnt else None
+
+    def restore(self, guess) -> None:
+        """A checkpoint's guess (``import_state``); None or 0 = none."""
+        if guess:
+            self.guess = int(guess)
+            self._learnt = True
+
+    def pull(self, flat, prefix, rows: int):
+        """``(host buffer, FlatMeta)`` of a frame of ``rows`` slices,
+        pulled again where the guess was short; on a mesh's ``(shards,
+        bytes)`` buffer, every shard's rows and a list of metas, all
+        pulled again at the longest need.  None where an overflow flag
+        is up (the caller's host coder takes the frame)."""
+        from ..ops import cavlc_device
+        metas = []
+
+        def read(head):
+            metas[:] = [cavlc_device.FlatMeta(h, rows)
+                        for h in (head if head.ndim == 2 else head[None])]
+            if any(m.overflow for m in metas):
+                return None
+            return max(4 * m.total_words for m in metas)
+
+        buf, _ = self._pull(flat, prefix, read)
+        if buf is None:
             return None
-        words = heads[:, 2].astype(np.int64)
-        need = int(words.max())
-        self.note(need)
-        if self.hdrw + need > heads.shape[1]:
-            M_PULL_EXTRA.inc()
-            with obst.stage("pull_extra"):
-                heads = np.asarray(self._cut(buf, self.rung(need)))
-            M_D2H_BYTES.inc(heads.nbytes)
-        M_CABAC_RECORD_BYTES.inc(4 * int((self.hdrw + words).sum()))
-        return heads
+        return buf, (metas if buf.ndim == 2 else metas[0])

@@ -119,14 +119,6 @@ def get_lib() -> Optional[ctypes.CDLL]:
             ctypes.c_int32, ctypes.c_int64, ctypes.c_uint64, ctypes.c_int32,
             u8p, ctypes.c_int64]
         lib.h264_annexb_rows.restype = ctypes.c_int64
-        if hasattr(lib, "h264_encode_intra_picture"):
-            lib.h264_encode_intra_picture.argtypes = [
-                i32p, i32p, i32p, i32p, i32p, i32p,
-                ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_int32, ctypes.c_int32,
-                u8p, ctypes.c_int64,
-            ]
-            lib.h264_encode_intra_picture.restype = ctypes.c_int64
         global _CABAC_OK
         if hasattr(lib, "h264_cabac_intra_slices"):
             lib.tpudesktop_cabac_abi_version.restype = ctypes.c_int32
@@ -197,11 +189,6 @@ def available() -> bool:
     return get_lib() is not None
 
 
-def has_cavlc() -> bool:
-    lib = get_lib()
-    return lib is not None and hasattr(lib, "h264_encode_intra_picture")
-
-
 _CABAC_OK = False
 _ENGINE_OK = False
 _LEVELPACK_OK = False
@@ -220,19 +207,23 @@ def has_cabac_engine() -> bool:
 
 def cabac_engine_rows(payload: np.ndarray, row_off: np.ndarray,
                       row_bits: np.ndarray, rows: int, qp: int,
-                      ctx_init, rng, tmps, tlps, cap: int):
-    """Run the arithmetic engine over per-row record streams.
+                      ctx_init, rng, tmps, tlps, cap: int,
+                      out: np.ndarray):
+    """Run the arithmetic engine over per-row record streams, into the
+    CALLER's ``out`` (C-contiguous uint8, at least ``rows * cap`` long;
+    ``bitstream/h264_cabac._out_buffer`` owns it and keeps it between
+    frames: nothing but ``lens`` is allocated here).
 
-    Returns ``(out, lens)``: the engine's own output buffer (``rows`` x
-    ``cap`` bytes, row ``r``'s slice payload the first ``lens[r]`` bytes
-    at ``r * cap``; :func:`annexb_rows` frames it where it lies), or the
-    int failure code:
+    Returns ``lens``: row ``r``'s slice payload is the first ``lens[r]``
+    bytes of ``out`` at ``r * cap`` (:func:`annexb_rows` frames it where
+    it lies; bytes of ``out`` behind ``rows * cap`` are not touched), or
+    the int failure code:
     -1 = output cap overflow (caller may retry with a larger cap),
     -2 = malformed record stream (retrying cannot help — the caller
     should fall back dense and name the real failure)."""
     lib = get_lib()
     assert lib is not None and _ENGINE_OK
-    out = np.empty(rows * cap, np.uint8)
+    assert out.dtype == np.uint8 and out.size >= rows * cap
     lens = np.zeros(rows, np.int64)
     rc = lib.h264_cabac_engine_rows(
         np.ascontiguousarray(payload, np.uint32),
@@ -241,33 +232,7 @@ def cabac_engine_rows(payload: np.ndarray, row_off: np.ndarray,
         rows, int(qp), ctx_init, rng, tmps, tlps, out, lens, cap)
     if rc != 0:
         return int(rc)
-    return out, lens
-
-
-def cabac_engine_rows_tail(payload: np.ndarray, row_off: np.ndarray,
-                           row_bits: np.ndarray, rows: int, qp: int,
-                           ctx_init, rng, tmps, tlps, cap: int,
-                           tail: bytes):
-    """:func:`cabac_engine_rows` over the first ``rows`` record streams of
-    a row BAND (a damage-masked frame's worklist), with ``tail`` laid
-    behind the engine's ``rows * cap`` output bytes in the one buffer it
-    returns: the slice data every unplanned row of the frame shares, so
-    that ``annexb_rows`` frames planned and unplanned rows from one
-    source, each where it lies.  The native entry is
-    :func:`cabac_engine_rows`'s, as it is; the same failure codes."""
-    lib = get_lib()
-    assert lib is not None and _ENGINE_OK
-    out = np.empty(rows * cap + len(tail), np.uint8)
-    out[rows * cap:] = np.frombuffer(tail, np.uint8)
-    lens = np.zeros(rows, np.int64)
-    rc = lib.h264_cabac_engine_rows(
-        np.ascontiguousarray(payload, np.uint32),
-        np.ascontiguousarray(row_off, np.int64),
-        np.ascontiguousarray(row_bits, np.int64),
-        rows, int(qp), ctx_init, rng, tmps, tlps, out, lens, cap)
-    if rc != 0:
-        return int(rc)
-    return out, lens
+    return lens
 
 
 def has_level_unpack() -> bool:
@@ -285,25 +250,6 @@ def level_unpack(payload: np.ndarray, row_off: np.ndarray, rows: int,
         np.ascontiguousarray(row_off, np.int64),
         rows, slots_per_row, out)
     return out
-
-
-def h264_encode_intra_picture(levels: dict, *, frame_num: int,
-                              idr_pic_id: int) -> bytes:
-    """All row-slices of an I_16x16 picture as Annex-B NALs, via C."""
-    lib = get_lib()
-    assert lib is not None
-    c = lambda k: np.ascontiguousarray(levels[k], np.int32)
-    luma_dc = c("luma_dc")
-    nr, nc = luma_dc.shape[:2]
-    cap = max(1 << 16, int(nr * nc) * 800)
-    while True:
-        out = np.empty(cap, np.uint8)
-        n = lib.h264_encode_intra_picture(
-            luma_dc, c("luma_ac"), c("cb_dc"), c("cb_ac"), c("cr_dc"),
-            c("cr_ac"), nr, nc, frame_num, idr_pic_id, out, cap)
-        if n >= 0:
-            return out[:n].tobytes()
-        cap *= 2
 
 
 # ---------------------------------------------------------------------------

@@ -22,11 +22,6 @@ the per-stage numbers a LIVE property of the process:
   fired since that stage's previous sample (or it is the stage's first)
   and ``phase="steady"`` otherwise — cold-jit and steady-state separate
   cleanly on the same histogram family.
-- **Cost-analysis capture**: a caller with a compiled step in hand
-  feeds XLA's own cost model (``compiled.cost_analysis()``: flops /
-  bytes accessed) via :meth:`KernelProfiler.note_cost_analysis` — the
-  static half of the cold/steady story, served next to the measured
-  timings.
 - ``/debug/profile`` (obs/http) exports the bounded sample ring as
   Chrome trace-event JSON (open it in Perfetto / ``chrome://tracing``);
   ``?format=json`` returns the structured snapshot BENCH embeds.
@@ -94,7 +89,7 @@ def _backend_name() -> str:
 
 
 class KernelProfiler:
-    """Per-stage timing histograms + compile/cost capture + sample ring."""
+    """Per-stage timing histograms + compile capture + sample ring."""
 
     def __init__(self, capacity: int = RING_CAPACITY):
         self._ring: deque = deque(maxlen=capacity)
@@ -115,7 +110,6 @@ class KernelProfiler:
         self._last_seq: Dict[tuple, int] = {}
         self._compiles: deque = deque(maxlen=COMPILE_RING)
         self._compile_listener = False
-        self._cost: Dict[str, dict] = {}
         self._dropped = 0
 
     # -- backend (resolved once; cheap thereafter) ---------------------
@@ -207,26 +201,6 @@ class KernelProfiler:
         self._compile_listener = True
         return True
 
-    # -- cost analysis --------------------------------------------------
-
-    def note_cost_analysis(self, name: str, info: dict) -> None:
-        """Record XLA's static cost model for one compiled step (flops /
-        bytes accessed / utilization), as ``compiled.cost_analysis()``
-        gives it."""
-        keep = {}
-        for k, v in (info or {}).items():
-            if k in ("flops", "bytes accessed") or k.startswith(
-                    "utilization"):
-                try:
-                    keep[k] = float(v)
-                except (TypeError, ValueError):
-                    pass
-        if keep:
-            self._cost[str(name)] = keep
-
-    def cost_analysis(self) -> Dict[str, dict]:
-        return dict(self._cost)
-
     # -- scrape-time views ---------------------------------------------
 
     def stage_summary(self) -> Dict[str, Dict[str, float]]:
@@ -283,13 +257,12 @@ class KernelProfiler:
             "stage_p50_ms": self.stage_p50s(),
             "stage_p50_ms_steady": self.stage_p50s(steady_only=True),
             "compiles": self.compile_summary(),
-            "cost_analysis": self.cost_analysis(),
         }
 
     def export_chrome_trace(self) -> dict:
         """Perfetto-openable trace-event JSON: one track per stage
         (complete "X" events, chunk-amortized durations), plus an
-        ``xla-compile`` track, cost analysis in ``otherData``."""
+        ``xla-compile`` track."""
         samples = list(self._ring)
         compiles = list(self._compiles)
         ts0 = min([t for t, *_ in samples]
@@ -322,7 +295,6 @@ class KernelProfiler:
             "displayTimeUnit": "ms",
             "otherData": {
                 "backend": self.backend(),
-                "cost_analysis": self.cost_analysis(),
                 "compiles": self.compile_summary(),
             },
         }
@@ -333,7 +305,6 @@ class KernelProfiler:
         self._ring.clear()
         self._compiles.clear()
         self._last_seq.clear()
-        self._cost.clear()
 
 
 PROFILER = KernelProfiler()

@@ -38,26 +38,22 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.env import env_float as _envf
-
 __all__ = ["aq_offsets", "lookahead_bias", "lam_mode", "lam_mv",
            "qp_plane", "qp_chain", "qp_chain_np", "mse_planes",
            "AQ_STRENGTH", "AQ_MAX_DELTA", "AQ_MAX_UP", "LOOKAHEAD_BIAS"]
 
-# Operator knobs (read once at import, like DNGD_RING_DONATE): strength
-# in ~x264 aq-strength units, the delta clamps, and the lookahead reward.
-# The up/down clamps are ASYMMETRIC by default: lifting flat blocks
+# Strength in ~x264 aq-strength units, the delta clamps, and the
+# lookahead reward.
+# The up/down clamps are ASYMMETRIC: lifting flat blocks
 # (negative delta) buys PSNR cheaply — they cost few bits — while
 # coarsening busy blocks trades a lot of measured distortion for modest
 # savings, so the up side caps at +1 (the perceptual-masking headroom
 # is real but the BD-rate harness scores PSNR, and a +1 cap keeps hq
 # strictly non-losing there while still shaving busy-block bits).
-# env_float degrades a typo'd knob to its default with a warning — a
-# malformed value must not fail every hq encode at first import.
-AQ_STRENGTH = _envf("DNGD_AQ_STRENGTH", 1.0)
-AQ_MAX_DELTA = int(_envf("DNGD_AQ_MAX_DELTA", 4))
-AQ_MAX_UP = int(_envf("DNGD_AQ_MAX_UP", 1))
-LOOKAHEAD_BIAS = int(_envf("DNGD_LOOKAHEAD_BIAS", 2))
+AQ_STRENGTH = 1.0
+AQ_MAX_DELTA = 4
+AQ_MAX_UP = 1
+LOOKAHEAD_BIAS = 2
 
 # Reference log2 activity: a 16x16 block whose summed squared deviation
 # (256 * per-pixel variance) is ~2^_AQ_REF_LOG sits at delta 0.  12.0

@@ -1,21 +1,5 @@
-"""The GOP-chunk super-step (:func:`build_p_chunk_step`, the served ring),
-the link probe, and two device-resident encode loops.
-
-The loops time what the device alone sustains, apart from host colour
-conversion, transfer and pull — on a chip; on the CPU backend they time
-XLA:CPU, which nobody serves (the benchmark's device trace is the
-yardstick: PERF.md section 5):
-
-- K encode steps run inside ONE ``lax.fori_loop`` with the trip count as a
-  *traced* scalar (one compile, any K) and a data dependency per iteration
-  (input planes perturbed by the loop index; P frames chain their recon as
-  the next reference) so XLA cannot hoist or elide iterations.
-- Only a 4-byte checksum leaves the device.  Wall-clock of a K-step call is
-  ``RTT + K * step_ms``; differencing two trip counts cancels the RTT and
-  every other fixed cost, leaving pure device throughput.
-
-SURVEY.md §6: the 1080p60 real-time bar is 16.7 ms/frame — `step_ms` is the
-number that says whether the codec kernels themselves clear it.
+"""The GOP-chunk super-step (:func:`build_p_chunk_step`, the served ring)
+and the link probe (:func:`measure_link_rtt`, obs/budget's).
 """
 
 from __future__ import annotations
@@ -28,59 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax import lax
-
-
-def _perturb(plane, i):
-    """Mix the loop index into every pixel (cheap elementwise add) so the
-    whole frame's encode chain depends on ``i`` — defeats loop-invariant
-    code motion without changing the workload's character."""
-    return (plane.astype(jnp.int32) + (i & 1)).clip(0, 255).astype(jnp.uint8)
-
-
-@functools.partial(jax.jit, static_argnames=("qp", "i16_modes"))
-def intra_loop(y, cb, cr, hv, hl, steps, qp: int, i16_modes: str = "auto"):
-    """``steps`` intra CAVLC frame encodes, device-resident; returns a
-    uint32 checksum (forces execution, 4-byte pull)."""
-    from . import cavlc_device
-
-    def body(i, acc):
-        flat = cavlc_device.encode_intra_cavlc_frame_yuv(
-            _perturb(y, i), _perturb(cb, i), _perturb(cr, i),
-            hv, hl, qp, with_recon=False, i16_modes=i16_modes)
-        return acc + flat[cavlc_device.META_WORDS * 4].astype(jnp.uint32)
-
-    return lax.fori_loop(0, steps, body, jnp.uint32(0))
-
-
-@functools.partial(jax.jit, static_argnames=("qp", "deblock"))
-# NOT donated on purpose: measure_steady_state calls the loop at two
-# trip counts with the SAME ref buffers (the differencing trick), so
-# donating them would invalidate the caller's arrays between timed calls.
-# dngd: ignore[jax-donate-missing]
-def p_loop(y, cb, cr, ref_y, ref_cb, ref_cr, hv, hl, steps, qp: int,
-           deblock: bool = True):
-    """``steps`` P-frame encodes chained through their reconstruction (the
-    real GOP dependency: frame N+1 references frame N's recon).  With
-    ``deblock`` (the serving default, models/h264.py `_submit_p_device`)
-    each recon passes through the in-loop filter before becoming the next
-    reference, so step_ms matches what serving actually sustains."""
-    from . import cavlc_device, cavlc_p_device, h264_deblock
-
-    def body(i, carry):
-        acc, ry, rcb, rcr = carry
-        flat, ry2, rcb2, rcr2, mv, nnz, _lv = \
-            cavlc_p_device.encode_p_cavlc_frame(
-                _perturb(y, i), _perturb(cb, i), _perturb(cr, i),
-                ry, rcb, rcr, hv, hl, qp)
-        if deblock:
-            ry2, rcb2, rcr2 = h264_deblock.deblock_frame(
-                ry2, rcb2, rcr2, qp, nnz_blk=nnz, mv=mv)
-        acc = acc + flat[cavlc_device.META_WORDS * 4].astype(jnp.uint32)
-        return acc, ry2, rcb2, rcr2
-
-    out = lax.fori_loop(0, steps, body,
-                        (jnp.uint32(0), ref_y, ref_cb, ref_cr))
-    return out[0]
 
 
 # ---------------------------------------------------------------------------
@@ -308,7 +239,7 @@ def _probe_loop(x, steps):
 def measure_link_rtt(reps: int = 7, k_hi: int = 257) -> dict:
     """Estimate the host<->device round-trip cost of one dispatch+pull.
 
-    Same differencing trick as :func:`measure_steady_state`, inverted:
+    A differencing trick: the wall clock of a k-step call is
     ``t(k) = rtt + k * step`` — two trip counts give ``step``, and
     ``rtt = t_lo - k_lo * step`` is the fixed per-call cost (dispatch,
     transfer-out of the 4-byte checksum).
@@ -335,41 +266,3 @@ def measure_link_rtt(reps: int = 7, k_hi: int = 257) -> dict:
     return {"rtt_ms": round(rtt_s * 1e3, 3),
             "step_us": round(step_s * 1e6, 3),
             "samples": [round(v * 1e3, 3) for v in lo_sorted]}
-
-
-def measure_steady_state(loop_fn, *, budget_s: float = 60.0,
-                         k_lo: int = 4) -> dict:
-    """Run ``loop_fn(steps)->checksum`` at two trip counts and difference.
-
-    ``loop_fn`` must accept a Python int and block until the checksum is on
-    the host (a 4-byte pull).  Returns {"step_ms", "fps", "k_hi"}.
-    Trip counts are chosen adaptively so the measured signal dominates
-    round-trip noise while staying inside ``budget_s``.
-    """
-    loop_fn(1)                                   # compile + warm
-    t0 = time.perf_counter()
-    loop_fn(k_lo)
-    t_lo_probe = time.perf_counter() - t0
-    # Pick k_hi for a good signal inside the budget.  The two timed()
-    # calls below realize ~2 * (2 reps) * k_hi steps total, so size one
-    # k_hi call at ~budget/5 and NEVER floor above what the budget buys —
-    # on a slow backend (CPU fallback: seconds/step) an unconditional
-    # 8*k_lo floor would blow straight through the caller's watchdog.
-    per_step_guess = max(t_lo_probe / k_lo, 1e-5)
-    k_budget = int(0.2 * budget_s / per_step_guess)
-    k_hi = max(k_lo + 1, min(k_budget, 4096))
-
-    def timed(k, reps=2):
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            loop_fn(k)
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    t_lo = timed(k_lo)
-    t_hi = timed(k_hi)
-    step_s = max((t_hi - t_lo) / (k_hi - k_lo), 1e-9)
-    return {"step_ms": round(step_s * 1e3, 3),
-            "fps": round(1.0 / step_s, 1),
-            "k_hi": k_hi}

@@ -567,7 +567,7 @@ def encode_intra_frame_yuv(y, cb, cr, qp: int, i16_modes: str = "auto",
     path (fast mode sets); "full" = same but I4x4 block rows 1-3 search
     all NINE prediction modes (bx-sequential; ~2x the intra sequential
     depth for measurably fewer bits); "i16" = I16 DC/H only; "dc" = I16
-    DC only (the native host entropy coder has no mode plumbing).
+    DC only (no deployment sets it: a test's flattest mode set).
 
     I16x16 Vertical and Plane are NOT mode-set gaps: under slice-per-MB-
     row the macroblock above is always in a different slice, and samples

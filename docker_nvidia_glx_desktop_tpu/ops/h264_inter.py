@@ -50,7 +50,7 @@ def ring_donate_argnames():
     """The reference-ring donation set for jitted P stages.
 
     Donation (aliasing the new recon into the old reference's buffer)
-    is the ring contract ROADMAP item 2 calls for and what serving on
+    is the ring's contract and what serving on
     TPU runs with.  On the CPU backend donated scan carries have shown
     latent heap corruption in jaxlib's CPU client (order-dependent
     malloc aborts bisected in round 8), so ``auto`` donates only when
@@ -58,17 +58,11 @@ def ring_donate_argnames():
     of ``cpu`` (with JAX_PLATFORMS unset jax falls back to the CPU on a
     TPU-less box, which must not re-enable the crash).  The deploy
     manifest and image set JAX_PLATFORMS=tpu, so a deployed pod donates
-    and cannot start on the CPU by accident.
-    DNGD_RING_DONATE=1/0 force-overrides either way.  Resolved at
+    and cannot start on the CPU by accident.  Resolved at
     import time from the environment so no jax backend is initialized
     early."""
     import os
 
-    v = os.environ.get("DNGD_RING_DONATE", "auto")
-    if v == "1":
-        return ("ref_y", "ref_cb", "ref_cr")
-    if v == "0":
-        return ()
     plats = os.environ.get("JAX_PLATFORMS", "")
     return (("ref_y", "ref_cb", "ref_cr")
             if plats and "cpu" not in plats else ())

@@ -1037,10 +1037,10 @@ def h264_spatial_chunk_step(mesh: Mesh, qp: int = 26,
 
         in_specs = (frame_spec,) * 3 + (plane_spec,) * 3
     # ring donation honors the ONE switch the single-device chunk step
-    # uses (ops/h264_inter.RING_DONATE: DNGD_RING_DONATE force/auto —
-    # auto donates only on positive device-platform evidence, because
-    # jaxlib's CPU client corrupted the heap donating scan-carry rings,
-    # round 8 bisect).  Undonated, the contract is merely slower — the
+    # uses (ops/h264_inter.RING_DONATE: donated only on positive
+    # device-platform evidence, because jaxlib's CPU client corrupted
+    # the heap donating scan-carry rings, round 8 bisect).  Undonated,
+    # the contract is merely slower — the
     # returned ring still re-enters under the same fixed spec.
     from ..ops.h264_inter import RING_DONATE
     step = jax.jit(shard_map(
@@ -1083,7 +1083,7 @@ def dryrun_full_geometry(n_devices: int, h: int = 1088,
 
     devices = jax.devices()[:n_devices]
     mesh = make_mesh((n_devices, 1), devices)
-    enc = H264Encoder(w, h, qp=26, mode="cavlc")       # headers only
+    enc = H264Encoder(w, h, qp=26)                     # headers only
     rng = np.random.default_rng(7)
     # desktop-ish blocky YUV content (kron of an 8x coarse grid), one
     # shifted variant per session so every session codes distinct bytes.

@@ -88,7 +88,6 @@ def check_native() -> None:
     from ..native import lib
 
     assert lib.available(), "native entropy library failed to build"
-    assert lib.has_cavlc(), "native CAVLC entry points missing"
     assert lib.has_cabac(), "native CABAC entry points missing"
     _log("native entropy/CABAC shims built and loaded")
 
@@ -96,7 +95,7 @@ def check_native() -> None:
 def check_h264_gop_deblock() -> None:
     from ..models.h264 import H264Encoder
 
-    enc = H264Encoder(W, H, qp=28, mode="cavlc", entropy="device",
+    enc = H264Encoder(W, H, qp=28, entropy="device",
                       gop=2, deblock=True)
     f0, f1 = _test_frame(0), _test_frame(1)
     data = enc.headers() + enc.encode(f0).data + enc.encode(f1).data
@@ -109,7 +108,7 @@ def check_h264_gop_deblock() -> None:
 def check_h264_cabac() -> None:
     from ..models.h264 import H264Encoder
 
-    enc = H264Encoder(W, H, qp=28, mode="cavlc", entropy="cabac")
+    enc = H264Encoder(W, H, qp=28, entropy="cabac")
     f0 = _test_frame(2)
     data = enc.headers() + enc.encode(f0).data
     dec = _decode_h264(data, 1)

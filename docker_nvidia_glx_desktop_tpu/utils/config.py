@@ -132,10 +132,10 @@ class Config:
     # first scene cut never stalls on a fresh XLA compile
     encoder_prewarm: bool = True
     # entropy coder: "device" (TPU CAVLC — only packed bytes cross the
-    # host link; the serving default), "cabac" (host C++ CABAC, Main
-    # profile, ~0.85x the bytes — costs a level-tensor pull per frame,
-    # best on PCIe-attached chips or bitrate-constrained links),
-    # "native"/"python" (host CAVLC debug paths)
+    # host link; the serving default), "cabac" (Main profile, ~0.85x the
+    # bytes: the device binarizes, the host's C++ arithmetic engine codes
+    # the pulled record stream), "python" (the host CAVLC reference
+    # coder, synchronous: a debug path)
     encoder_entropy: str = "device"
     # intra mode search: "auto" (fast sets: I16 DC/H + I4x4 left/vertical
     # families) or "full" (nine-mode I4x4 — ~2x intra sequential depth

@@ -212,11 +212,10 @@ class BatchStreamManager:
         # in raw size within the same MB-padded bucket (each hub's own SPS
         # carries its crop window).  Mixed padded geometries are composed
         # by BucketedStreamManager.
-        probe = H264Encoder(w, h, qp=cfg.encoder_qp, mode="cavlc")
+        probe = H264Encoder(w, h, qp=cfg.encoder_qp)
         self._probe = probe
         probes = [probe if (s.width, s.height) == (w, h)
-                  else H264Encoder(s.width, s.height, qp=cfg.encoder_qp,
-                                   mode="cavlc")
+                  else H264Encoder(s.width, s.height, qp=cfg.encoder_qp)
                   for s in sources]
         assert all((p.pad_h, p.pad_w) == (probe.pad_h, probe.pad_w)
                    for p in probes), \
@@ -925,7 +924,7 @@ class BatchStreamManager:
                     w, h, level)
         for src in self.sources:
             src.resize(w, h)
-        probe = H264Encoder(w, h, qp=self.cfg.encoder_qp, mode="cavlc")
+        probe = H264Encoder(w, h, qp=self.cfg.encoder_qp)
         self._probe = probe
         self._hub_probes = [probe] * len(self.sources)
         # measured us/MB must be attributed to the NEW bucket geometry

@@ -52,17 +52,13 @@ def encoder(request):
     """A served encoder of either entropy coder, one IDR behind it."""
     from docker_nvidia_glx_desktop_tpu.models import make_encoder
 
-    mp = pytest.MonkeyPatch()
-    env = ROADS[request.param]
-    if "ENCODER_CABAC_BINARIZE" in env:     # read from the process's own
-        mp.setenv("ENCODER_CABAC_BINARIZE", env["ENCODER_CABAC_BINARIZE"])
-    cfg = from_env(dict(env, PASSWD="pw", SIZEW=str(W), SIZEH=str(H),
-                        REFRESH="30", ENCODER_PREWARM="false"))
+    cfg = from_env(dict(ROADS[request.param], PASSWD="pw", SIZEW=str(W),
+                        SIZEH=str(H), REFRESH="30",
+                        ENCODER_PREWARM="false"))
     enc, name = make_encoder(cfg, W, H)
     assert name == "h264_" + request.param
     enc.encode_collect(enc.encode_submit(frame(0)))
-    yield enc
-    mp.undo()
+    return enc
 
 
 @pytest.mark.parametrize("library", [True, False])

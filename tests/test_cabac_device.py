@@ -153,10 +153,10 @@ class TestRecordStream:
         assert got == want
 
     @pytest.mark.parametrize("how", ["constructed", "served"])
-    def test_serving_paths_agree(self, monkeypatch, how):
-        """H264Encoder entropy='cabac' with device binarization (the
-        round-6 default) must emit the exact bytes the round-5 host
-        split does, GOP-deep through the pipelined API: constructed
+    def test_serving_paths_agree(self, how):
+        """H264Encoder entropy='cabac' with device binarization (what
+        it chooses) must emit the exact bytes the level transport
+        does, GOP-deep through the pipelined API: constructed
         directly at a fixed qp, and as ``make_encoder`` serves it under
         the environment of benchmark/configs/desk1080-cabac.json (qp
         traced, the rate controller moving it)."""
@@ -175,16 +175,16 @@ class TestRecordStream:
                           / "desk1080-cabac.json").read_text())["env"]
 
         def run(mode):
-            monkeypatch.setenv("ENCODER_CABAC_BINARIZE", mode)
             if how == "served":
                 enc, _ = make_encoder(from_env(dict(
                     env, SIZEW="128", SIZEH="96", PASSWD="pw",
                     ENCODER_GOP="4")), 128, 96)
                 assert enc._dyn_qp and enc._rate is not None
-                assert enc.cabac_device_binarize == (mode == "device")
             else:
-                enc = H264Encoder(128, 96, qp=26, mode="cavlc",
+                enc = H264Encoder(128, 96, qp=26,
                                   entropy="cabac", gop=4, deblock=True)
+            assert enc.cabac_device_binarize       # chosen from the tune
+            enc._cabac_dev_bin = mode == "device"  # the test's hook
             out = []
             pend = []
             i = 0

@@ -76,14 +76,11 @@ def decode_luma(data: bytes, path, w=W, h=H):
 
 @pytest.fixture(scope="module", autouse=True)
 def small_buckets():
-    """The deployment's binarization placement (the encoder reads it from
-    the process's environment), and rungs of 4 KiB, so that a 128x96
-    record buffer (184 KiB) has the ladder a 1080p one has with 64 KiB
-    rungs."""
+    """Rungs of 4 KiB, so that a 128x96 record buffer (184 KiB) has the
+    ladder a 1080p one has with 64 KiB rungs."""
     from docker_nvidia_glx_desktop_tpu.models.prefix_pull import PrefixPull
 
     mp = pytest.MonkeyPatch()
-    mp.setenv("ENCODER_CABAC_BINARIZE", CONFIG_ENV["ENCODER_CABAC_BINARIZE"])
     mp.setattr(PrefixPull, "BUCKET", 1 << 10)
     yield
     mp.undo()
@@ -170,31 +167,35 @@ def test_stream_declares_the_level_its_size_and_refresh_need(
     stream, never under 4.2 (every stream up to 1080p60 keeps its bytes).
     The muxer's codec string, which sizes a browser's hardware decoder,
     follows the SPS, and the independent decoder still takes the stream:
-    one picture of I_PCM macroblocks at the real size (no device program),
-    whose luma comes back sample for sample."""
+    one picture at the real size through the CAVLC reference coder (its
+    levels all zero, so no device program runs), which decodes, at that
+    size, under that level, to the flat grey such a picture is."""
     from docker_nvidia_glx_desktop_tpu.bitstream import h264 as syn
-    import jax.numpy as jnp
-
+    from docker_nvidia_glx_desktop_tpu.bitstream import h264_entropy
     from docker_nvidia_glx_desktop_tpu.models import H264Encoder
-    from docker_nvidia_glx_desktop_tpu.models.h264 import _yuv_stage
     from docker_nvidia_glx_desktop_tpu.web.mp4 import Mp4Muxer, split_annexb
 
     assert syn.level_idc_for(w, h, fps) == level
     for profile, idc in (("main", 77), ("baseline", 66)):
         sps = syn.sps_rbsp(w, h, fps, profile=profile)
         assert (sps[0], sps[2]) == (idc, level)
-    enc = H264Encoder(w, h, mode="pcm", fps=fps)
+    enc = H264Encoder(w, h, fps=fps)
     nals = split_annexb(enc.headers())
     sps = next(n for n in nals if n[0] & 0x1F == 7)
     pps = next(n for n in nals if n[0] & 0x1F == 8)
     assert Mp4Muxer(w, h, sps, pps, fps=fps).mime == (
         f'video/mp4; codecs="avc1.42C0{level:02X}"')
-    grey = np.full((h, w, 3), 97, np.uint8)
-    grey[:, ::2] = 180
-    lumas = decode_luma(enc.encode(grey).data, tmp_path / "pcm.h264", w, h)
-    want = _yuv_stage(jnp.asarray(grey), enc.pad_h, enc.pad_w)[0]
-    assert len(lumas) == 1
-    assert np.array_equal(lumas[0], np.asarray(want)[:h, :w])
+
+    def zeros(*shape):
+        return np.zeros((enc.mb_h, enc.mb_w) + shape, np.int32)
+
+    unit = h264_entropy.encode_intra_picture(
+        dict(luma_dc=zeros(16), luma_ac=zeros(16, 15), cb_dc=zeros(4),
+             cb_ac=zeros(4, 15), cr_dc=zeros(4), cr_ac=zeros(4, 15)),
+        sps=enc._sps, pps=enc._pps, with_headers=True)
+    lumas = decode_luma(unit, tmp_path / "grey.h264", w, h)
+    assert len(lumas) == 1 and lumas[0].shape == (h, w)
+    assert (lumas[0] == 128).all()
 
 
 def test_codec_string_and_sdp_profile_follow_the_served_sps(encoder):

@@ -34,7 +34,7 @@ class TestCodecFactory:
         assert factories == ["make_encoder"]
         enc, name = make_encoder(from_env({}), 64, 48)
         assert name == "h264_cavlc"
-        assert enc.mode == "cavlc" and enc.entropy == "device"
+        assert enc.entropy == "device"
         assert enc.deblock and enc.gop == 60 and enc._rate is not None
 
     @pytest.mark.parametrize("function,gone", [

@@ -79,7 +79,7 @@ class TestOnOffByteIdentity:
     def test_perframe_cavlc_gop_deep(self):
         frames = _frames(11)
         stats = _assert_on_off_identical(
-            lambda: H264Encoder(W, H, mode="cavlc", entropy="device",
+            lambda: H264Encoder(W, H, entropy="device",
                                 host_color=True, gop=5, deblock=True),
             frames)
         # the ON arm really measured: PSNR on every frame, damage from
@@ -96,9 +96,8 @@ class TestOnOffByteIdentity:
         frames = _frames(9, seed=11)
 
         def make():
-            e = H264Encoder(W, H, mode="cavlc", entropy="cabac",
+            e = H264Encoder(W, H, entropy="cabac",
                             host_color=True, gop=4, deblock=True)
-            e._cabac_dev_bin = True      # pin: no env dependence
             return e
 
         stats = _assert_on_off_identical(make, frames)
@@ -107,7 +106,7 @@ class TestOnOffByteIdentity:
     def test_chunk_ring_gop_deep(self):
         frames = _frames(19, seed=7)
         stats = _assert_on_off_identical(
-            lambda: H264Encoder(W, H, mode="cavlc", entropy="device",
+            lambda: H264Encoder(W, H, entropy="device",
                                 host_color=True, gop=9, deblock=True,
                                 superstep_chunk=4),
             frames)
@@ -121,7 +120,7 @@ class TestOnOffByteIdentity:
         w, h = 64, 64
         frames = _frames(8, w=w, h=h, seed=5)
         stats = _assert_on_off_identical(
-            lambda: H264Encoder(w, h, mode="cavlc", entropy="device",
+            lambda: H264Encoder(w, h, entropy="device",
                                 host_color=True, gop=8, deblock=True,
                                 spatial_shards=2),
             frames)
@@ -158,10 +157,10 @@ class TestInPathConsistency:
         agree frame-for-frame, PSNR (chunk finals) within 0.01 dB."""
         frames = _frames(19, seed=7)
         sa, sb = [], []
-        _drive(H264Encoder(W, H, mode="cavlc", entropy="device",
+        _drive(H264Encoder(W, H, entropy="device",
                            host_color=True, gop=9, deblock=True),
                frames, stats_out=sa)
-        _drive(H264Encoder(W, H, mode="cavlc", entropy="device",
+        _drive(H264Encoder(W, H, entropy="device",
                            host_color=True, gop=9, deblock=True,
                            superstep_chunk=4),
                frames, stats_out=sb)
@@ -191,7 +190,7 @@ class TestInPathConsistency:
         from docker_nvidia_glx_desktop_tpu.models.h264 import _yuv_stage
 
         frames = _frames(5, seed=23)
-        enc = H264Encoder(W, H, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, entropy="device",
                           host_color=True, gop=5, deblock=True)
         stats = []
         _drive(enc, frames, stats_out=stats)
@@ -228,7 +227,7 @@ class TestInPathConsistency:
                  for _ in range(6)]
 
         def mean_damage(frames):
-            enc = H264Encoder(W, H, mode="cavlc", entropy="device",
+            enc = H264Encoder(W, H, entropy="device",
                               host_color=True, gop=6, deblock=True)
             stats = []
             _drive(enc, frames, stats_out=stats)

@@ -62,7 +62,7 @@ class TestH264Checkpoint:
         # no encode needed: the controller state is plain host floats
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
-        enc = H264Encoder(128, 96, mode="cavlc", gop=10,
+        enc = H264Encoder(128, 96, gop=10,
                           bitrate_kbps=4000, fps=30)
         enc._rate.level = 12345.0
         enc._rate._ema[True] = 5000.0
@@ -72,7 +72,7 @@ class TestH264Checkpoint:
         enc._rate._pending.append((True, 4))         # in-flight: dropped
         st = enc.export_state()
 
-        enc2 = H264Encoder(128, 96, mode="cavlc", gop=10,
+        enc2 = H264Encoder(128, 96, gop=10,
                            bitrate_kbps=4000, fps=30)
         enc2.import_state(st)
         assert enc2._rate.level == 12345.0
@@ -84,9 +84,9 @@ class TestH264Checkpoint:
     def test_degrade_bias_survives(self):
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
-        enc = H264Encoder(128, 96, mode="cavlc")
+        enc = H264Encoder(128, 96)
         enc.degrade_qp_offset = 4
-        enc2 = H264Encoder(128, 96, mode="cavlc")
+        enc2 = H264Encoder(128, 96)
         enc2.import_state(enc.export_state())
         assert enc2.degrade_qp_offset == 4
 

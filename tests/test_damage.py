@@ -107,7 +107,7 @@ def _drive(enc, frames):
     return out
 
 
-_KW = dict(mode="cavlc", entropy="device", host_color=True)
+_KW = dict(entropy="device", host_color=True)
 
 
 # -- one substrate ---------------------------------------------------------

@@ -1,68 +1,7 @@
-"""Signature-drift guard for the device-only benchmark loops.
+"""The link probe of ops/devloop (the chunk step's tests are
+tests/test_superstep.py's)."""
 
-Round-3 postmortem: `ops/devloop.p_loop` unpacked 5 values from
-`encode_p_cavlc_frame` after the deblock change made it return 6, and the
-resulting trace-time ValueError wiped BOTH device-only numbers from the
-driver's bench artifact.  These tests call both loops at tiny geometry on
-the CPU backend so any future signature drift breaks CI, not the artifact.
-"""
-
-import jax.numpy as jnp
-import numpy as np
-import pytest
-
-from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 from docker_nvidia_glx_desktop_tpu.ops import devloop
-
-
-W, H = 64, 48  # 4x3 macroblocks — smallest interesting geometry
-
-
-@pytest.fixture(scope="module")
-def planes():
-    r = np.random.default_rng(7)
-    y = r.integers(0, 256, size=(H, W), dtype=np.uint8)
-    cb = r.integers(0, 256, size=(H // 2, W // 2), dtype=np.uint8)
-    cr = r.integers(0, 256, size=(H // 2, W // 2), dtype=np.uint8)
-    return jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr)
-
-
-@pytest.fixture(scope="module")
-def enc():
-    return H264Encoder(W, H, mode="cavlc", entropy="device")
-
-
-def test_intra_loop_traces_and_runs(planes, enc):
-    hv, hl = enc._hdr_slots(0, 0)
-    c2 = np.asarray(devloop.intra_loop(*planes, hv, hl, jnp.int32(2),
-                                       enc.qp))
-    c3 = np.asarray(devloop.intra_loop(*planes, hv, hl, jnp.int32(3),
-                                       enc.qp))
-    assert c2.dtype == np.uint32
-    # trip count is traced, so both counts hit one compiled executable and
-    # the loop body genuinely executed (checksums accumulate per step)
-    assert int(c3) != 0 and int(c3) != int(c2)
-
-
-@pytest.mark.parametrize("deblock", [True, False])
-def test_p_loop_traces_and_runs(planes, enc, deblock):
-    """The exact bench call shape (bench.py device_only P measurement)."""
-    hvp, hlp = enc._p_hdr_slots(1, 0)
-    c = np.asarray(devloop.p_loop(*planes, *planes, hvp, hlp,
-                                  jnp.int32(2), enc.qp, deblock=deblock))
-    assert c.dtype == np.uint32
-
-
-def test_measure_steady_state_shape(planes, enc):
-    hv, hl = enc._hdr_slots(0, 0)
-
-    def run(k):
-        return np.asarray(devloop.intra_loop(*planes, hv, hl,
-                                             jnp.int32(k), enc.qp))
-
-    out = devloop.measure_steady_state(run, budget_s=5.0)
-    assert set(out) == {"step_ms", "fps", "k_hi"}
-    assert out["fps"] > 0
 
 
 def test_measure_link_rtt_shape():

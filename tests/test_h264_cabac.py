@@ -75,8 +75,8 @@ class TestConformance:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frame = conftest.make_test_frame(96, 128, seed=3)
-        cab = H264Encoder(128, 96, qp=qp, mode="cavlc", entropy="cabac")
-        cav = H264Encoder(128, 96, qp=qp, mode="cavlc", entropy="python")
+        cab = H264Encoder(128, 96, qp=qp, entropy="cabac")
+        cav = H264Encoder(128, 96, qp=qp, entropy="python")
         d_cab = _decode_all(cab.encode(frame).data, tmp_path)
         d_cav = _decode_all(cav.encode(frame).data, tmp_path)
         assert len(d_cab) == len(d_cav) == 1
@@ -98,8 +98,8 @@ class TestConformance:
         levels = h264_device.encode_intra_frame(
             jnp.asarray(frame), h, w, 26)
         assert np.asarray(levels["mb_i4"]).any()
-        cab = H264Encoder(w, h, qp=26, mode="cavlc", entropy="cabac")
-        cav = H264Encoder(w, h, qp=26, mode="cavlc", entropy="python")
+        cab = H264Encoder(w, h, qp=26, entropy="cabac")
+        cav = H264Encoder(w, h, qp=26, entropy="python")
         d1 = _decode_all(cab.encode(frame).data, tmp_path)
         d2 = _decode_all(cav.encode(frame).data, tmp_path)
         assert np.array_equal(d1[0], d2[0])
@@ -118,9 +118,9 @@ class TestConformance:
         frames = [np.ascontiguousarray(np.roll(
             conftest.make_test_frame(96, 128, seed=21), 3 * k, axis=1))
             for k in range(4)]
-        cab = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="cabac",
+        cab = H264Encoder(128, 96, qp=26, entropy="cabac",
                           gop=8)
-        cav = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="python",
+        cav = H264Encoder(128, 96, qp=26, entropy="python",
                           gop=8)
         d1 = _decode_all(b"".join(cab.encode(f).data for f in frames),
                          tmp_path)
@@ -137,9 +137,9 @@ class TestConformance:
         frames = [np.ascontiguousarray(np.roll(
             conftest.make_test_frame(96, 128, seed=9), 2 * k, axis=1))
             for k in range(4)]
-        cab = H264Encoder(128, 96, qp=28, mode="cavlc", entropy="cabac",
+        cab = H264Encoder(128, 96, qp=28, entropy="cabac",
                           gop=8, deblock=True)
-        cav = H264Encoder(128, 96, qp=28, mode="cavlc", entropy="python",
+        cav = H264Encoder(128, 96, qp=28, entropy="python",
                           gop=8, deblock=True)
         d1 = _decode_all(b"".join(cab.encode(f).data for f in frames),
                          tmp_path)
@@ -180,9 +180,9 @@ class TestBitrate:
         base = _desktop_frame()
         frames = [np.ascontiguousarray(np.roll(base, 4 * k, axis=1))
                   for k in range(6)]
-        cab = H264Encoder(640, 480, qp=26, mode="cavlc", entropy="cabac",
+        cab = H264Encoder(640, 480, qp=26, entropy="cabac",
                           gop=6)
-        cav = H264Encoder(640, 480, qp=26, mode="cavlc", entropy="python",
+        cav = H264Encoder(640, 480, qp=26, entropy="python",
                           gop=6)
         n_cab = sum(len(cab.encode(f).data) for f in frames)
         n_cav = sum(len(cav.encode(f).data) for f in frames)
@@ -320,6 +320,16 @@ class TestPackedTransport:
     instead of pulling the dense multi-MB tensors, and the packed path
     must be byte-identical to coding the dense arrays."""
 
+    @staticmethod
+    def _level_encoder(**kw):
+        """A CABAC encoder on the level transport (`hq` serves it; placement
+        by tune would hand these calls the record stream)."""
+        from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
+
+        enc = H264Encoder(128, 96, qp=26, entropy="cabac", **kw)
+        enc._cabac_dev_bin = False
+        return enc
+
     @pytest.mark.parametrize("density", [0.02, 0.3, 1.0])
     def test_level_pack_roundtrip(self, density):
         import jax.numpy as jnp
@@ -385,11 +395,10 @@ class TestPackedTransport:
         import jax.numpy as jnp
 
         from docker_nvidia_glx_desktop_tpu.bitstream import h264_cabac
-        from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
         from docker_nvidia_glx_desktop_tpu.ops import h264_device
 
         f0 = conftest.make_test_frame(96, 128, seed=5)
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="cabac")
+        enc = self._level_encoder()
         got = enc.encode(f0).data
         lv = h264_device.encode_intra_frame(jnp.asarray(f0), 96, 128, 26)
         lvn = {k: np.asarray(v) for k, v in lv.items()
@@ -401,15 +410,11 @@ class TestPackedTransport:
         assert len(_decode_all(got, tmp_path)) == 1
 
     def test_packed_gop_pipelined_matches_sync(self):
-        from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
-
         f0 = conftest.make_test_frame(96, 128, seed=6)
         f1 = np.ascontiguousarray(np.roll(f0, 3, axis=1))
-        sync = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="cabac",
-                           gop=4, deblock=True)
+        sync = self._level_encoder(gop=4, deblock=True)
         s0, s1 = sync.encode(f0).data, sync.encode(f1).data
-        pipe = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="cabac",
-                           gop=4, deblock=True)
+        pipe = self._level_encoder(gop=4, deblock=True)
         t0, t1 = pipe.encode_submit(f0), pipe.encode_submit(f1)
         assert pipe.encode_collect(t0).data == s0
         e1 = pipe.encode_collect(t1)
@@ -419,24 +424,23 @@ class TestPackedTransport:
         """Force the value-overflow flag on every frame: the stream must
         be identical anyway (correctness never depends on the packed
         transport)."""
-        from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
         from docker_nvidia_glx_desktop_tpu.ops import level_pack
 
         f0 = conftest.make_test_frame(96, 128, seed=7)
-        want = H264Encoder(128, 96, qp=26, mode="cavlc",
-                           entropy="cabac").encode(f0).data
+        want = self._level_encoder().encode(f0).data
 
         orig = level_pack.pack_levels
+        calls = []
 
         def sabotaged(levels, keys):
             import jax.numpy as jnp
+            calls.append(keys)
             buf = orig(levels, keys)
             return buf.at[1].set(jnp.uint32(1))      # claim overflow
 
         monkeypatch.setattr(level_pack, "pack_levels", sabotaged)
-        got = H264Encoder(128, 96, qp=26, mode="cavlc",
-                          entropy="cabac").encode(f0).data
-        assert got == want
+        got = self._level_encoder().encode(f0).data
+        assert calls and got == want
 
 
 def test_cabac_table_recovery_fails_at_construction(monkeypatch):
@@ -451,4 +455,4 @@ def test_cabac_table_recovery_fails_at_construction(monkeypatch):
 
     monkeypatch.setattr(cabac_tables, "engine_tables", boom)
     with pytest.raises(RuntimeError, match="CABAC recovery"):
-        H264Encoder(64, 48, qp=26, mode="cavlc", entropy="cabac")
+        H264Encoder(64, 48, qp=26, entropy="cabac")

@@ -45,7 +45,7 @@ def test_cavlc_decodes_and_matches_recon(tmp_path, qp):
     from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
     frame = conftest.make_test_frame(144, 176)
-    enc = H264Encoder(176, 144, qp=qp, mode="cavlc", keep_recon=True)
+    enc = H264Encoder(176, 144, qp=qp, keep_recon=True)
     ef = enc.encode(frame)
     assert ef.keyframe
     dec = _decode(ef.data, tmp_path)[0]
@@ -63,7 +63,7 @@ def test_cavlc_quality_improves_with_lower_qp(tmp_path):
     frame = conftest.make_test_frame(96, 128, seed=3)
     scores = []
     for qp in (16, 30, 42):
-        enc = H264Encoder(128, 96, qp=qp, mode="cavlc")
+        enc = H264Encoder(128, 96, qp=qp)
         dec = _decode(enc.encode(frame).data, tmp_path)[0]
         scores.append(_psnr(_luma(dec), _luma(frame)))
     assert scores[0] > scores[1] > scores[2]
@@ -75,7 +75,7 @@ def test_cavlc_cropping_non_multiple_of_16(tmp_path):
     from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
     frame = conftest.make_test_frame(100, 150, seed=5)
-    enc = H264Encoder(150, 100, qp=24, mode="cavlc")
+    enc = H264Encoder(150, 100, qp=24)
     dec = _decode(enc.encode(frame).data, tmp_path)[0]
     assert dec.shape == (100, 150, 3)
     assert _psnr(_luma(dec), _luma(frame)) > 30
@@ -85,7 +85,7 @@ def test_cavlc_multi_frame_stream(tmp_path):
     """Every frame is an IDR; a 3-frame stream decodes frame-accurately."""
     from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
-    enc = H264Encoder(128, 96, qp=24, mode="cavlc")
+    enc = H264Encoder(128, 96, qp=24)
     frames = [conftest.make_test_frame(96, 128, seed=s) for s in range(3)]
     data = b"".join(enc.encode(f).data for f in frames)
     decs = _decode(data, tmp_path, n=3)
@@ -98,36 +98,10 @@ def test_flat_frame_compresses_tightly():
     from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
     frame = np.full((144, 176, 3), 128, np.uint8)
-    enc = H264Encoder(176, 144, qp=26, mode="cavlc")
+    enc = H264Encoder(176, 144, qp=26)
     ef = enc.encode(frame)
     # 99 MBs; flat content should need only a few bits per MB + headers
     assert len(ef.data) < 600, len(ef.data)
-
-
-def test_native_matches_python_entropy(tmp_path):
-    """The C++ CAVLC coder must be byte-identical to the Python reference
-    (the twin-implementation contract claimed by both docstrings)."""
-    from docker_nvidia_glx_desktop_tpu.native import lib as native_lib
-
-    if not (native_lib.available() and native_lib.has_cavlc()):
-        pytest.skip("no C++ toolchain")
-    import jax.numpy as jnp
-    from docker_nvidia_glx_desktop_tpu.bitstream import h264_entropy
-    from docker_nvidia_glx_desktop_tpu.ops import h264_device
-
-    for seed, (h, w), qp in [(0, (144, 176), 26), (2, (96, 128), 18),
-                             (4, (64, 80), 40)]:
-        frame = conftest.make_test_frame(h, w, seed=seed)
-        # the C coder has no per-MB pred-mode plumbing: pin DC
-        levels = h264_device.encode_intra_frame(jnp.asarray(frame), h, w, qp,
-                                                i16_modes="dc")
-        levels = {k: np.asarray(v) for k, v in levels.items()
-                  if not k.startswith("recon")}
-        py = h264_entropy.encode_intra_picture(
-            levels, frame_num=0, idr_pic_id=1, with_headers=False)
-        na = native_lib.h264_encode_intra_picture(
-            levels, frame_num=0, idr_pic_id=1)
-        assert py == na, f"native/python divergence (seed={seed}, qp={qp})"
 
 
 def test_extreme_levels_low_qp(tmp_path):
@@ -140,7 +114,7 @@ def test_extreme_levels_low_qp(tmp_path):
     yy, xx = np.mgrid[0:64, 0:80]
     checker = (((yy // 4) + (xx // 4)) % 2 * 255).astype(np.uint8)
     frame = np.stack([checker] * 3, axis=-1)
-    enc = H264Encoder(80, 64, qp=1, mode="cavlc")
+    enc = H264Encoder(80, 64, qp=1)
     dec = _decode(enc.encode(frame).data, tmp_path)[0]
     assert _psnr(_luma(dec), _luma(frame)) > 38
 
@@ -152,8 +126,8 @@ def test_host_color_path_decodes(tmp_path):
     from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
     frame = conftest.make_test_frame(96, 128, seed=11)
-    host = H264Encoder(128, 96, qp=24, mode="cavlc", host_color=True)
-    dev = H264Encoder(128, 96, qp=24, mode="cavlc", host_color=False)
+    host = H264Encoder(128, 96, qp=24, host_color=True)
+    dev = H264Encoder(128, 96, qp=24, host_color=False)
     d_host = _decode(host.encode(frame).data, tmp_path)[0]
     d_dev = _decode(dev.encode(frame).data, tmp_path)[0]
     p_host = _psnr(_luma(d_host), _luma(frame))
@@ -175,7 +149,7 @@ def test_host_color_non_mb_geometry(tmp_path):
     from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
     frame = conftest.make_test_frame(100, 150, seed=6)
-    enc = H264Encoder(150, 100, qp=24, mode="cavlc", host_color=True)
+    enc = H264Encoder(150, 100, qp=24, host_color=True)
     dec = _decode(enc.encode(frame).data, tmp_path)[0]
     assert dec.shape == (100, 150, 3)
     assert _psnr(_luma(dec), _luma(frame)) > 30
@@ -199,15 +173,14 @@ def test_h_prediction_mode(tmp_path):
     modes = np.asarray(levels["pred_mode"])
     assert (modes[:, 1:] == 1).mean() > 0.5, "H mode rarely selected"
 
-    auto = H264Encoder(128, 96, qp=26, mode="cavlc", keep_recon=True)
+    auto = H264Encoder(128, 96, qp=26, keep_recon=True)
     ef = auto.encode(frame)
     dec = _decode(ef.data, tmp_path)[0]
     assert _psnr(_luma(dec), auto.last_recon[0][:96, :128]) > 40
 
-    dc = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="native")
-    assert dc.i16_modes == "dc"
-    dc_py = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="python")
-    dc_py.i16_modes = "dc"
+    dc_py = H264Encoder(128, 96, qp=26, entropy="python",
+                        intra_modes="dc")
+    assert dc_py.i16_modes == "dc"
     assert len(ef.data) < len(dc_py.encode(frame).data), \
         "H mode should beat DC-only on row-constant content"
 
@@ -226,8 +199,8 @@ def test_device_entropy_matches_python(tmp_path):
         (np.stack([checker] * 3, axis=-1), 80, 64, 1),
     ]
     for frame, w, h, qp in cases:
-        dev = H264Encoder(w, h, qp=qp, mode="cavlc", entropy="device")
-        py = H264Encoder(w, h, qp=qp, mode="cavlc", entropy="python")
+        dev = H264Encoder(w, h, qp=qp, entropy="device")
+        py = H264Encoder(w, h, qp=qp, entropy="python")
         assert dev.encode(frame).data == py.encode(frame).data, (w, h, qp)
 
 
@@ -265,7 +238,7 @@ class TestI4x4:
         modes = np.asarray(levels["i4_modes"])[np.asarray(levels["mb_i4"])]
         assert set(np.unique(modes)) <= {0, 1, 2, 3, 7, 8}
 
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", keep_recon=True)
+        enc = H264Encoder(128, 96, qp=26, keep_recon=True)
         dec = _decode(enc.encode(frame).data, tmp_path)[0]
         assert _psnr(_luma(dec), _luma(frame)) > 38
         # decoder output must track OUR closed-loop recon (any I4
@@ -279,8 +252,8 @@ class TestI4x4:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frame = self._chrome_frame()
-        dev = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="device")
-        py = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="python")
+        dev = H264Encoder(128, 96, qp=26, entropy="device")
+        py = H264Encoder(128, 96, qp=26, entropy="python")
         assert dev.encode(frame).data == py.encode(frame).data
 
     def test_i4_bitrate_win_on_chrome(self, tmp_path):
@@ -289,8 +262,8 @@ class TestI4x4:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frame = self._chrome_frame()
-        auto = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="python")
-        i16 = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="python")
+        auto = H264Encoder(128, 96, qp=26, entropy="python")
+        i16 = H264Encoder(128, 96, qp=26, entropy="python")
         i16.i16_modes = "i16"
         a = auto.encode(frame)
         b = i16.encode(frame)
@@ -305,7 +278,7 @@ class TestI4x4:
 
         frame = self._chrome_frame()
         moved = np.ascontiguousarray(np.roll(frame, 3, axis=1))
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", gop=4)
+        enc = H264Encoder(128, 96, qp=26, gop=4)
         efs = [enc.encode(f) for f in (frame, moved)]
         assert efs[0].keyframe and not efs[1].keyframe
         decs = _decode(b"".join(e.data for e in efs), tmp_path, n=2)
@@ -323,7 +296,7 @@ def test_tall_geometry_beyond_256_mb_rows(tmp_path):
     rng = np.random.default_rng(4)
     frame = np.repeat(rng.integers(0, 256, (h // 16, w, 3)), 16,
                       axis=0).astype(np.uint8)
-    enc = H264Encoder(w, h, qp=30, mode="cavlc", entropy="device")
+    enc = H264Encoder(w, h, qp=30, entropy="device")
     ef = enc.encode(frame)
     dec = _decode(ef.data, tmp_path)[0]
     assert dec.shape[:2] == (h, w)
@@ -358,7 +331,7 @@ class TestDeblocking:
         yy, xx = np.mgrid[0:h, 0:w]
         img = (100 + 60 * np.sin(xx / 19) + 50 * np.cos(yy / 23))
         frame = np.stack([img.astype(np.uint8)] * 3, -1)
-        enc = H264Encoder(w, h, qp=34, mode="cavlc", keep_recon=True,
+        enc = H264Encoder(w, h, qp=34, keep_recon=True,
                           deblock=True)
         dec = _decode(enc.encode(frame).data, tmp_path)[0]
         dy = _luma(dec)
@@ -381,7 +354,7 @@ class TestDeblocking:
         base = conftest.make_test_frame(h, w, seed=21)
         frames = [np.ascontiguousarray(np.roll(base, 2 * k, axis=1))
                   for k in range(8)]
-        enc = H264Encoder(w, h, qp=28, mode="cavlc", gop=8, deblock=True)
+        enc = H264Encoder(w, h, qp=28, gop=8, deblock=True)
         data = b"".join(enc.encode(f).data for f in frames)
         decs = _decode(data, tmp_path, n=8)
         early = _psnr(_luma(decs[1]), _luma(frames[1]))
@@ -431,9 +404,9 @@ class TestDeblocking:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frame = conftest.make_test_frame(96, 128, seed=3)
-        dev = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="device",
+        dev = H264Encoder(128, 96, qp=26, entropy="device",
                           deblock=True)
-        py = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="python",
+        py = H264Encoder(128, 96, qp=26, entropy="python",
                          deblock=True)
         assert dev.encode(frame).data == py.encode(frame).data
 
@@ -462,7 +435,7 @@ class TestI4FullModes:
             np.asarray(levels["i4_modes"])[np.asarray(levels["mb_i4"])]))
         assert used == set(range(9)), used   # every mode exercised
 
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", keep_recon=True,
+        enc = H264Encoder(128, 96, qp=26, keep_recon=True,
                           intra_modes="full")
         dec = _decode(enc.encode(frame).data, tmp_path)[0]
         # decoder output tracks OUR closed-loop recon: any predictor
@@ -478,9 +451,9 @@ class TestI4FullModes:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frame = self._chrome()
-        full = H264Encoder(128, 96, qp=qp, mode="cavlc",
+        full = H264Encoder(128, 96, qp=qp,
                            intra_modes="full")
-        auto = H264Encoder(128, 96, qp=qp, mode="cavlc",
+        auto = H264Encoder(128, 96, qp=qp,
                            intra_modes="auto")
         b_full = full.encode(frame).data
         b_auto = auto.encode(frame).data
@@ -491,9 +464,9 @@ class TestI4FullModes:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frame = self._chrome()
-        dev = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="device",
+        dev = H264Encoder(128, 96, qp=26, entropy="device",
                           intra_modes="full")
-        py = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="python",
+        py = H264Encoder(128, 96, qp=26, entropy="python",
                          intra_modes="full")
         assert dev.encode(frame).data == py.encode(frame).data
 
@@ -502,9 +475,9 @@ class TestI4FullModes:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frame = self._chrome()
-        cab = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="cabac",
+        cab = H264Encoder(128, 96, qp=26, entropy="cabac",
                           intra_modes="full")
-        cav = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="python",
+        cav = H264Encoder(128, 96, qp=26, entropy="python",
                           intra_modes="full")
         d1 = _decode(cab.encode(frame).data, tmp_path)[0]
         d2 = _decode(cav.encode(frame).data, tmp_path)[0]

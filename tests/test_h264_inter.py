@@ -49,7 +49,7 @@ class TestGopStream:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frames = _moving_frames(4)
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", gop=8)
+        enc = H264Encoder(128, 96, qp=26, gop=8)
         efs = [enc.encode(f) for f in frames]
         assert [e.keyframe for e in efs] == [True, False, False, False]
         decs = _decode_all(b"".join(e.data for e in efs), tmp_path)
@@ -64,7 +64,7 @@ class TestGopStream:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frames = _moving_frames(4)
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", gop=8,
+        enc = H264Encoder(128, 96, qp=26, gop=8,
                           keep_recon=True)
         data = b""
         recons = []
@@ -81,14 +81,14 @@ class TestGopStream:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frame = conftest.make_test_frame(96, 128, seed=10)
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", gop=8)
+        enc = H264Encoder(128, 96, qp=26, gop=8)
         sizes = [len(enc.encode(frame).data) for _ in range(4)]
         assert sizes[1] < sizes[0] / 10, sizes     # near-pure skip
 
-        enc_moving = H264Encoder(128, 96, qp=26, mode="cavlc", gop=8)
+        enc_moving = H264Encoder(128, 96, qp=26, gop=8)
         moving = _moving_frames(8, step=2)
         m_sizes = [len(enc_moving.encode(f).data) for f in moving]
-        intra = H264Encoder(128, 96, qp=26, mode="cavlc")
+        intra = H264Encoder(128, 96, qp=26)
         i_sizes = [len(intra.encode(f).data) for f in moving]
         assert sum(m_sizes) < sum(i_sizes) / 3, (m_sizes, i_sizes)
 
@@ -96,7 +96,7 @@ class TestGopStream:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frames = _moving_frames(4)
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", gop=100)
+        enc = H264Encoder(128, 96, qp=26, gop=100)
         assert enc.encode(frames[0]).keyframe
         assert not enc.encode(frames[1]).keyframe
         enc.request_keyframe()
@@ -107,7 +107,7 @@ class TestGopStream:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frames = _moving_frames(5, step=2)
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", gop=2)
+        enc = H264Encoder(128, 96, qp=26, gop=2)
         keys = [enc.encode(f).keyframe for f in frames]
         assert keys == [True, False, True, False, True]
 
@@ -124,7 +124,7 @@ class TestMotionEstimation:
 
         def planes(rgb):
             from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
-            e = H264Encoder(96, 64, host_color=True, mode="cavlc")
+            e = H264Encoder(96, 64, host_color=True)
             return e._host_yuv420(rgb)
 
         y0, cb0, cr0 = planes(base)
@@ -161,7 +161,7 @@ class TestMotionEstimation:
             frames.append(cv2_mod.resize(shifted, (w, h),
                                          interpolation=cv2_mod.INTER_AREA))
 
-        enc = H264Encoder(w, h, qp=24, mode="cavlc", gop=8, keep_recon=True)
+        enc = H264Encoder(w, h, qp=24, gop=8, keep_recon=True)
         data = b""
         recons = []
         odd_mvs = 0
@@ -183,7 +183,7 @@ class TestMotionEstimation:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frames = _moving_frames(18, h=48, w=64, step=2)
-        enc = H264Encoder(64, 48, qp=28, mode="cavlc", gop=20,
+        enc = H264Encoder(64, 48, qp=28, gop=20,
                           keep_recon=True)
         data = b""
         recons = []
@@ -204,10 +204,10 @@ class TestMotionEstimation:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
         frames = _moving_frames(6, step=2)
-        sync = H264Encoder(128, 96, qp=26, mode="cavlc", gop=4)
+        sync = H264Encoder(128, 96, qp=26, gop=4)
         want = [sync.encode(f).data for f in frames]
 
-        pipe = H264Encoder(128, 96, qp=26, mode="cavlc", gop=4)
+        pipe = H264Encoder(128, 96, qp=26, gop=4)
         got = []
         pending = []
         i = 0
@@ -232,9 +232,9 @@ class TestMotionEstimation:
             (_moving_frames(3, step=2), 40),
         ]
         for frames, qp in cases:
-            dev = H264Encoder(128, 96, qp=qp, mode="cavlc", gop=8,
+            dev = H264Encoder(128, 96, qp=qp, gop=8,
                               entropy="device")
-            host = H264Encoder(128, 96, qp=qp, mode="cavlc", gop=8,
+            host = H264Encoder(128, 96, qp=qp, gop=8,
                                entropy="python")
             for i, f in enumerate(frames):
                 d = dev.encode(f)
@@ -331,7 +331,7 @@ class TestVbvRateControl:
         rng = np.random.default_rng(0)
         calm = conftest.make_test_frame(96, 128, seed=1)
         busy = (rng.integers(0, 2, (96, 128, 3)) * 255).astype(np.uint8)
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="python",
+        enc = H264Encoder(128, 96, qp=26, entropy="python",
                           gop=10, bitrate_kbps=400, fps=10)
         sizes = []
         for i in range(30):
@@ -352,7 +352,7 @@ class TestEncodeFailureRecovery:
 
     def _enc(self):
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
-        return H264Encoder(128, 96, qp=26, mode="cavlc", entropy="device",
+        return H264Encoder(128, 96, qp=26, entropy="device",
                            gop=8, bitrate_kbps=800)
 
     def test_submit_failure_rolls_back_rate_and_forces_idr(self):
@@ -398,23 +398,24 @@ class TestServingLatencyFixes:
         second device pull (a full host<->device round trip)."""
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="device",
+        enc = H264Encoder(128, 96, qp=26, entropy="device",
                           gop=100)
-        enc._PULL_BUCKET = 4096       # bucket << frame-size delta here
+        pull = enc._flat_pull["p"]
+        pull.BUCKET = 4096            # bucket << frame-size delta here
         r = np.random.default_rng(0)
         noisy = r.integers(0, 256, (96, 128, 3), dtype=np.uint8)
         flat = np.full((96, 128, 3), 128, np.uint8)
         enc.encode(noisy)                      # IDR
         enc.encode(flat)                       # tiny P
         enc.encode(noisy)                      # big P
-        big_guess = enc._p_pull_guess
+        big_guess = pull.guess
         for _ in range(3):
             enc.encode(flat)                   # small Ps follow
-        assert enc._p_pull_guess == big_guess  # held by the 8-frame max
+        assert pull.guess == big_guess         # held by the 8-frame max
         # and after the window drains, the guess adapts back down
         for _ in range(8):
             enc.encode(flat)
-        assert enc._p_pull_guess < big_guess
+        assert pull.guess < big_guess
 
     def test_prewarm_compiles_ladder_qps(self):
         """prewarm() must hit the REAL serving jit-cache keys.  On the
@@ -424,7 +425,7 @@ class TestServingLatencyFixes:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
         from docker_nvidia_glx_desktop_tpu.ops import cavlc_p_device
 
-        enc = H264Encoder(64, 48, qp=26, mode="cavlc", entropy="device",
+        enc = H264Encoder(64, 48, qp=26, entropy="device",
                           gop=60, bitrate_kbps=500)
         qps = enc.ladder_qps()
         base = {min(51, max(0, 26 + s)) for s in type(enc._rate).STEPS}
@@ -450,7 +451,7 @@ class TestServingLatencyFixes:
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
         from docker_nvidia_glx_desktop_tpu.ops import cavlc_p_device
 
-        enc = H264Encoder(64, 48, qp=26, mode="cavlc", entropy="device",
+        enc = H264Encoder(64, 48, qp=26, entropy="device",
                           gop=60, bitrate_kbps=500, tune="hq")
         assert not enc._dyn_qp
         before = cavlc_p_device.encode_p_cavlc_frame._cache_size()
@@ -476,7 +477,7 @@ class TestServingLatencyFixes:
             for i in range(3)]
         outs = []
         for cls in (H264Encoder, StaticQp):
-            enc = cls(96, 64, qp=qp, mode="cavlc", entropy="device",
+            enc = cls(96, 64, qp=qp, entropy="device",
                       host_color=True, gop=60, deblock=True)
             assert enc._dyn_qp is (cls is H264Encoder)
             outs.append([enc.encode(f).data for f in frames])
@@ -489,7 +490,7 @@ class TestServingLatencyFixes:
         from docker_nvidia_glx_desktop_tpu.models import h264 as m
 
         frame = conftest.make_test_frame(64, 96, seed=3)
-        enc = m.H264Encoder(96, 64, qp=4, mode="cavlc", entropy="device",
+        enc = m.H264Encoder(96, 64, qp=4, entropy="device",
                             host_color=True, gop=60, deblock=True)
         before = m._M_ENTROPY_OVERFLOW.value
         au = enc.encode(frame).data       # noise band at qp 4: MB cap
@@ -505,7 +506,7 @@ class TestServingLatencyFixes:
 
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
-        enc = H264Encoder(64, 48, qp=26, mode="cavlc", entropy="device",
+        enc = H264Encoder(64, 48, qp=26, entropy="device",
                           gop=60, bitrate_kbps=500, intra_modes="full")
         seen = {}
         orig = H264Encoder.__init__
@@ -523,7 +524,7 @@ class TestServingLatencyFixes:
 
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
-        enc = H264Encoder(64, 48, qp=26, mode="cavlc", entropy="device",
+        enc = H264Encoder(64, 48, qp=26, entropy="device",
                           gop=60, bitrate_kbps=500)
         stop = threading.Event()
         stop.set()

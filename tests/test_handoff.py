@@ -100,7 +100,7 @@ class TestCheckpointSchema:
 
     def _enc(self):
         from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
-        return H264Encoder(128, 96, mode="cavlc", gop=10)
+        return H264Encoder(128, 96, gop=10)
 
     def test_export_carries_schema_and_codec_id(self):
         st = self._enc().export_state()

@@ -343,7 +343,6 @@ def test_the_served_stream_is_the_same_bytes_at_one_band_and_at_four(
     cfg = from_env({"PASSWD": "pw", "SIZEW": str(SERVED_W),
                     "SIZEH": str(SERVED_H), "REFRESH": "60",
                     "ENCODER_ENTROPY": entropy,
-                    "ENCODER_CABAC_BINARIZE": "device",
                     "ENCODER_BITRATE_KBPS": "2000", "ENCODER_GOP": "60",
                     "ENCODER_PREWARM": "false"})
     frames = [served_frame(k) for k in range(4)]

@@ -53,7 +53,7 @@ def dirty(base: np.ndarray, n_rows: int, seed: int) -> np.ndarray:
 def encoder():
     """The served mask encoder (device CAVLC, tune=off, CBR), its row
     programs compiled by its own set-up and one IDR behind it."""
-    enc = h264.H264Encoder(W, H, mode="cavlc", entropy="device",
+    enc = h264.H264Encoder(W, H, entropy="device",
                            host_color=True, gop=600, damage_mask=True,
                            deblock=True, bitrate_kbps=300, fps=60)
     assert enc._dyn_qp
@@ -85,7 +85,8 @@ def test_nothing_compiles_over_the_ladders_of_qp_and_of_buckets(encoder):
         for c, qp in enumerate(enc.ladder_qps()):
             n_rows = c % (ROWS + 1)
             enc._forced_qp = qp
-            enc._p_pull_guess = (1 + c % 8) * enc._PULL_BUCKET
+            pull = enc._flat_pull["p"]
+            pull.guess = (1 + c % 8) * pull.BUCKET
             token = enc.encode_submit(dirty(enc.base, n_rows, c))
             seen.add(token[4][0] if isinstance(token[4][0], str) else "p")
             assert len(enc.encode_collect(token).data) > 16
@@ -140,7 +141,7 @@ def test_a_masked_frame_is_one_sample_of_every_stage_and_counted(
     guessed prefix was short; rows damaged <= rows coded <= rows."""
     enc = encoder
     if short_guess:
-        enc._p_pull_guess = 16
+        enc._flat_pull["p"].guess = 16
     before = counts()
     token = enc.encode_submit(dirty(enc.base, n_rows, 40 + n_rows))
     assert (token[4][0] == "dmg") == (program == "rows")
@@ -162,7 +163,8 @@ def test_a_masked_frame_is_one_sample_of_every_stage_and_counted(
     assert got["dngd_mask_rows_gathered_total"] == (bucket if rows else 0)
     assert (got["rows_frames"], got["dense_frames"]) == (
         (1, 0) if rows else (0, 1))
-    assert enc._p_pull_guess >= enc._PULL_BUCKET       # the guess recovered
+    pull = enc._flat_pull["p"]
+    assert pull.guess >= pull.BUCKET                   # the guess recovered
 
 
 def test_an_idr_of_a_mask_session_is_planned_by_nothing(encoder):
@@ -183,7 +185,7 @@ def test_token_ready_answers_for_a_masked_token_and_changes_no_byte():
     units = []
     base = conftest.make_test_frame(H, W, seed=3)
     for ask in (True, False):
-        enc = h264.H264Encoder(W, H, mode="cavlc", entropy="device",
+        enc = h264.H264Encoder(W, H, entropy="device",
                                host_color=True, gop=600, damage_mask=True)
         enc.encode_collect(enc.encode_submit(base))
         token, n0 = enc.encode_submit(dirty(base, 2, 9)), requests()
@@ -198,7 +200,7 @@ def test_token_ready_answers_for_a_masked_token_and_changes_no_byte():
 
 
 def test_with_the_mask_off_no_mask_family_moves():
-    enc = h264.H264Encoder(W, H, mode="cavlc", entropy="device",
+    enc = h264.H264Encoder(W, H, entropy="device",
                            host_color=True, gop=600, damage_mask=False)
     base = conftest.make_test_frame(H, W, seed=3)
     enc.encode_collect(enc.encode_submit(base))

@@ -1,6 +1,6 @@
 """The damage mask under the CABAC stream, as a per-frame path like the others
-(ISSUE 43): ``ENCODER_ENTROPY=cabac`` + ``ENCODER_CABAC_BINARIZE=device`` +
-``DNGD_DAMAGE_MASK=true`` through ``encode_submit`` / ``encode_collect``.
+(ISSUE 43): ``ENCODER_ENTROPY=cabac`` + ``DNGD_DAMAGE_MASK=true`` through
+``encode_submit`` / ``encode_collect``.
 
 A planned P frame of at most the ladder's top goes through the row program of
 its bucket (``ops/damage_mask.row_step_cabac``) and the binarizer over that
@@ -63,17 +63,8 @@ def dirty(base: np.ndarray, rows, seed: int) -> np.ndarray:
     return out
 
 
-@pytest.fixture(scope="module", autouse=True)
-def binarize_on_the_device():
-    """The deployment's environment, for every encoder of this module and
-    for the scratch encoders their set-up builds."""
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("ENCODER_CABAC_BINARIZE", "device")
-        yield
-
-
 def new_encoder(w=W, h=H, mask=True, **kw):
-    kw = dict(dict(mode="cavlc", entropy="cabac", host_color=True, gop=600,
+    kw = dict(dict(entropy="cabac", host_color=True, gop=600,
                    damage_mask=mask, deblock=True, bitrate_kbps=300, fps=60),
               **kw)
     return h264.H264Encoder(w, h, **kw)
@@ -360,7 +351,7 @@ def test_the_dense_fallback_codes_the_same_frame_and_is_counted(
 @pytest.mark.parametrize("why", ["host_binarize", "keep_recon", "mesh",
                                  "mask_off", "hq"])
 def test_the_plan_is_none_where_the_row_program_cannot_serve(why):
-    """Host binarize (a level-pack transport), keep_recon's debug pulls, a
+    """Host binarize (the level-pack transport), keep_recon's debug pulls, a
     spatial mesh (it gates rows instead), the mask off, a static qp: no
     plan, and the frame is the dense programs'."""
     kw = {"keep_recon": dict(keep_recon=True),
@@ -368,7 +359,7 @@ def test_the_plan_is_none_where_the_row_program_cannot_serve(why):
           "hq": dict(tune="hq")}.get(why, {})
     enc = new_encoder(mask=kw.pop("damage_mask", True), **kw)
     if why == "host_binarize":
-        enc._cabac_dev_bin = False      # (ENCODER_CABAC_BINARIZE=host)
+        enc._cabac_dev_bin = False      # the level transport, pinned
     assert enc.cabac_device_binarize == (why != "host_binarize")
     assert enc._dyn_qp == (why != "hq")
     if why == "mesh":

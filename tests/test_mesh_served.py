@@ -33,10 +33,9 @@ def _frames(n, h, seed):
             for i in range(n)]
 
 
-def _pair(h, monkeypatch):
+def _pair(h):
     """(one chip, mesh) encoders of the same coded picture."""
-    monkeypatch.setenv("ENCODER_CABAC_BINARIZE", "device")
-    kw = dict(mode="cavlc", entropy="cabac", host_color=True, gop=GOP,
+    kw = dict(entropy="cabac", host_color=True, gop=GOP,
               deblock=True)
     mesh = H264Encoder(W, h, spatial_shards=NX, **kw)
     one = H264Encoder(W, h, row_align=mesh.row_align, **kw)
@@ -64,42 +63,41 @@ def walked(tmp_path_factory):
     import cv2
 
     out = {}
-    with pytest.MonkeyPatch.context() as mp:
-        for h in (128, 112):
-            one, mesh = _pair(h, mp)
-            before = _counters()
-            units, refs, steps, ready = [], [], [], []
-            for rgb, qp in zip(_frames(len(QPS), h, seed=h), QPS):
-                one._forced_qp = mesh._forced_qp = qp
-                a = one.encode(rgb)
-                token = mesh.encode_submit(rgb)
-                ready.append(mesh.token_ready(token))
-                b = mesh.encode_collect(token)
-                ready.append(mesh.token_ready(token))
-                units.append((a, b))
-                refs.append(np.array(
-                    mesh.export_state()["ref"][0][:h, :W]))
-                steps.append(len(mesh._sp_steps))
-            after = _counters()
-            path = str(tmp_path_factory.mktemp("mesh") / f"{h}.h264")
-            with open(path, "wb") as f:
-                f.write(mesh.headers() + b"".join(b.data for _, b in units))
-            cap = cv2.VideoCapture(path)
-            cap.set(cv2.CAP_PROP_CONVERT_RGB, 0)
-            decoded = []
-            while True:
-                ok, img = cap.read()
-                if not ok:
-                    break
-                decoded.append(np.asarray(img).reshape(-1)[:W * h]
-                               .reshape(h, W))
-            cap.release()
-            shape = (int(cap.get(cv2.CAP_PROP_FRAME_WIDTH)),
-                     int(cap.get(cv2.CAP_PROP_FRAME_HEIGHT)))
-            out[h] = dict(one=one, mesh=mesh, units=units, refs=refs,
-                          steps=steps, decoded=decoded, ready=ready,
-                          before=before,
-                          after=after, path=path, shape=shape)
+    for h in (128, 112):
+        one, mesh = _pair(h)
+        before = _counters()
+        units, refs, steps, ready = [], [], [], []
+        for rgb, qp in zip(_frames(len(QPS), h, seed=h), QPS):
+            one._forced_qp = mesh._forced_qp = qp
+            a = one.encode(rgb)
+            token = mesh.encode_submit(rgb)
+            ready.append(mesh.token_ready(token))
+            b = mesh.encode_collect(token)
+            ready.append(mesh.token_ready(token))
+            units.append((a, b))
+            refs.append(np.array(
+                mesh.export_state()["ref"][0][:h, :W]))
+            steps.append(len(mesh._sp_steps))
+        after = _counters()
+        path = str(tmp_path_factory.mktemp("mesh") / f"{h}.h264")
+        with open(path, "wb") as f:
+            f.write(mesh.headers() + b"".join(b.data for _, b in units))
+        cap = cv2.VideoCapture(path)
+        cap.set(cv2.CAP_PROP_CONVERT_RGB, 0)
+        decoded = []
+        while True:
+            ok, img = cap.read()
+            if not ok:
+                break
+            decoded.append(np.asarray(img).reshape(-1)[:W * h]
+                           .reshape(h, W))
+        cap.release()
+        shape = (int(cap.get(cv2.CAP_PROP_FRAME_WIDTH)),
+                 int(cap.get(cv2.CAP_PROP_FRAME_HEIGHT)))
+        out[h] = dict(one=one, mesh=mesh, units=units, refs=refs,
+                      steps=steps, decoded=decoded, ready=ready,
+                      before=before,
+                      after=after, path=path, shape=shape)
     return out
 
 
@@ -111,7 +109,7 @@ def test_the_coded_picture_follows_the_mesh(walked, h):
     assert (mesh.width, mesh.height) == (W, h)       # what hello says
     assert mesh.headers() == one.headers()           # the SPS crops both
     # without shards nothing changes: the parent's rows
-    plain = H264Encoder(W, h, mode="cavlc", entropy="cabac", gop=GOP)
+    plain = H264Encoder(W, h, entropy="cabac", gop=GOP)
     assert plain.row_align == 1 and plain.pad_h == -(-h // 16) * 16
 
 
@@ -201,7 +199,7 @@ def test_spans_and_counters_of_the_mesh_path(walked, h):
 @pytest.mark.parametrize("h", [128, 112])
 def test_a_mesh_token_says_whether_every_shard_is_finished(walked, h):
     """``token_ready``: ``is_ready()`` of the stacked prefix
-    ``pull_shards`` pulls first, one array over every shard; a bool before
+    ``PrefixPull.pull`` pulls first, one array over every shard; a bool before
     the collect, True after it, and the access units (above) are the
     one-chip encoder's all the same.  One ``stats`` span a collected
     frame."""
@@ -214,7 +212,7 @@ def test_a_mesh_token_says_whether_every_shard_is_finished(walked, h):
         - d["before"]["dngd_stage_stats_ms_count"] == len(QPS)
 
 
-def test_the_pull_ladder_is_warmed_for_the_stacked_buffers(monkeypatch):
+def test_the_pull_ladder_is_warmed_for_the_stacked_buffers():
     """``warm_pulls`` compiles every slice the mesh path's two pulls can
     meet, on this encoder's own mesh and programs; a walk of the guess over
     the whole ladder then compiles nothing."""
@@ -223,7 +221,7 @@ def test_the_pull_ladder_is_warmed_for_the_stacked_buffers(monkeypatch):
 
     if not compile_events_supported():
         pytest.skip("jax.monitoring compile events unavailable")
-    _, mesh = _pair(128, monkeypatch)
+    _, mesh = _pair(128)
     assert mesh.warm_pulls() > 0
     rgb = _frames(2, 128, seed=5)
     mesh.encode(rgb[0])

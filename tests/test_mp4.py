@@ -95,7 +95,7 @@ class TestGoldenDecode:
         from docker_nvidia_glx_desktop_tpu.web.mp4 import split_annexb
 
         w, h = 128, 96
-        enc = H264Encoder(w, h, mode="cavlc", entropy="python")
+        enc = H264Encoder(w, h, entropy="python")
         nals = split_annexb(enc.headers())
         sps = next(n for n in nals if (n[0] & 0x1F) == 7)
         pps = next(n for n in nals if (n[0] & 0x1F) == 8)

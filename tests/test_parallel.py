@@ -46,7 +46,7 @@ class TestH264Batch:
         h, w = 16 * nx * 2, 128                    # 128x128
         frames = [make_test_frame(h, w, seed=s) for s in range(ns)]
 
-        enc = H264Encoder(w, h, qp=26, mode="cavlc", host_color=True)
+        enc = H264Encoder(w, h, qp=26, host_color=True)
         planes = [enc._host_yuv420(f) for f in frames]
         ys = np.stack([p[0] for p in planes])
         cbs = np.stack([p[1] for p in planes])
@@ -59,7 +59,7 @@ class TestH264Batch:
             au = batch.assemble_session_h264(flat[s], rows_local,
                                              headers=enc.headers())
             # single-chip reference: same planes through the same codec
-            single = H264Encoder(w, h, qp=26, mode="cavlc",
+            single = H264Encoder(w, h, qp=26,
                                  host_color=True)
             ref_au = single.encode(frames[s]).data
             assert au == ref_au, f"session {s}: shard/single divergence"
@@ -72,7 +72,7 @@ class TestH264Batch:
         mesh = batch.make_mesh((ns, nx))
         h, w = 16 * nx * 2, 96                     # 64x96
         frames = [make_test_frame(h, w, seed=10 + s) for s in range(ns)]
-        enc = H264Encoder(w, h, qp=28, mode="cavlc", host_color=True)
+        enc = H264Encoder(w, h, qp=28, host_color=True)
         planes = [enc._host_yuv420(f) for f in frames]
         ys = np.stack([p[0] for p in planes])
         cbs = np.stack([p[1] for p in planes])
@@ -115,7 +115,7 @@ class TestH264PBatch:
         # single-device GOP references + expected P bytes per session
         single = []
         for s in range(ns):
-            enc = H264Encoder(w, h, qp=26, mode="cavlc", gop=8,
+            enc = H264Encoder(w, h, qp=26, gop=8,
                               host_color=True)
             enc.encode(base[s])                    # IDR establishes ref
             single.append(enc)
@@ -126,7 +126,7 @@ class TestH264PBatch:
             want.append(enc.encode(f).data)        # sequential P AU
 
         # batched: same planes + same refs through the sharded step
-        probe = H264Encoder(w, h, qp=26, mode="cavlc", host_color=True)
+        probe = H264Encoder(w, h, qp=26, host_color=True)
         planes = [probe._host_yuv420(f) for f in moved]
         ys = np.stack([p[0] for p in planes])
         cbs = np.stack([p[1] for p in planes])

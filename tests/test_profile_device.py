@@ -42,7 +42,7 @@ class TestDeviceProfile:
         randomly, so the compile count is >= 0 but the phase labels
         must still be internally consistent)."""
         obsp.PROFILER.clear()
-        enc = H264Encoder(W, H, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, entropy="device",
                           host_color=True, gop=5)
         out = _drive(enc, _frames(11))
         assert len(out) == 11
@@ -76,7 +76,7 @@ class TestDeviceProfile:
         obsp.PROFILER.clear()
         chunk = 4
         frames = _frames(17)
-        kw = dict(mode="cavlc", entropy="device", host_color=True, gop=9)
+        kw = dict(entropy="device", host_color=True, gop=9)
         _drive(H264Encoder(W, H, **kw), frames)
         _drive(H264Encoder(W, H, superstep_chunk=chunk, **kw), frames)
         by_stage = {}
@@ -96,7 +96,7 @@ class TestDeviceProfile:
         backend compile must have been observed by the listener (a
         fresh geometry forces one here if the cache was warm)."""
         before = obsp.PROFILER._compile_seq
-        enc = H264Encoder(W + 16, H + 16, mode="cavlc", entropy="device",
+        enc = H264Encoder(W + 16, H + 16, entropy="device",
                           host_color=True, gop=3)
         _drive(enc, [np.zeros((H + 16, W + 16, 3), np.uint8),
                      np.full((H + 16, W + 16, 3), 128, np.uint8)])

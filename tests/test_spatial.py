@@ -70,7 +70,7 @@ class TestSpatialByteIdentity:
     ])
     def test_cavlc_gop_deep(self, nx, w, h, deblock):
         frames = _frames(8, w=w, h=h, seed=5 + nx)
-        kw = dict(mode="cavlc", entropy="device", host_color=True,
+        kw = dict(entropy="device", host_color=True,
                   gop=8, deblock=deblock)
         a = H264Encoder(w, h, **kw)
         b = H264Encoder(w, h, spatial_shards=nx, **kw)
@@ -84,12 +84,10 @@ class TestSpatialByteIdentity:
     ])
     def test_cabac_binarize_gop_deep(self, nx, w, h, deblock):
         frames = _frames(7, w=w, h=h, seed=11 + nx)
-        kw = dict(mode="cavlc", entropy="cabac", host_color=True,
+        kw = dict(entropy="cabac", host_color=True,
                   gop=7, deblock=deblock)
         a = H264Encoder(w, h, **kw)
         b = H264Encoder(w, h, spatial_shards=nx, **kw)
-        a._cabac_dev_bin = True          # pin: no env dependence
-        b._cabac_dev_bin = True
         assert b._spatial_nx == nx
         _assert_streams_equal(a, b, frames)
 
@@ -97,7 +95,7 @@ class TestSpatialByteIdentity:
         """gop=1 (all-intra) shards too — every frame an IDR, no
         reference ring."""
         frames = _frames(4, seed=17)
-        kw = dict(mode="cavlc", entropy="device", host_color=True)
+        kw = dict(entropy="device", host_color=True)
         a = H264Encoder(W, H, **kw)
         b = H264Encoder(W, H, spatial_shards=2, **kw)
         _assert_streams_equal(a, b, frames)
@@ -108,9 +106,9 @@ class TestSpatialByteIdentity:
         dispatch per chunk, byte-identical to the plain single-device
         per-frame path — and ~1 crossing per chunk."""
         frames = _frames(13, seed=13, step=3)
-        a = H264Encoder(W, H, mode="cavlc", entropy="device",
+        a = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=13, deblock=True)
-        b = H264Encoder(W, H, mode="cavlc", entropy="device",
+        b = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=13, deblock=True,
                         spatial_shards=2, superstep_chunk=4)
         assert b._ring_chunk == 4 and b._spatial_nx == 2
@@ -120,13 +118,11 @@ class TestSpatialByteIdentity:
 
     def test_spatial_cabac_chunk_ring(self):
         frames = _frames(10, seed=19, step=3)
-        kw = dict(mode="cavlc", entropy="cabac", host_color=True,
+        kw = dict(entropy="cabac", host_color=True,
                   gop=10, deblock=True)
         a = H264Encoder(W, H, **kw)
         b = H264Encoder(W, H, spatial_shards=2, superstep_chunk=3,
                         **kw)
-        a._cabac_dev_bin = True
-        b._cabac_dev_bin = True
         assert b._ring_chunk == 3
         _assert_streams_equal(a, b, frames)
 
@@ -135,14 +131,14 @@ class TestSpatialByteIdentity:
         fresh spatial encoder resumes with a recovery IDR (continuity
         contract unchanged under sharding)."""
         frames = _frames(6, seed=23)
-        src = H264Encoder(W, H, mode="cavlc", entropy="device",
+        src = H264Encoder(W, H, entropy="device",
                           host_color=True, gop=12, deblock=True,
                           spatial_shards=2)
         for f in frames[:4]:
             src.encode(f)
         st = src.export_state()
         assert st["ref"] is not None
-        dst = H264Encoder(W, H, mode="cavlc", entropy="device",
+        dst = H264Encoder(W, H, entropy="device",
                           host_color=True, gop=12, deblock=True,
                           spatial_shards=2)
         dst.import_state(st)
@@ -287,16 +283,16 @@ class TestShardPlanning:
 
     def test_encoder_resolution_clamps(self):
         # 64x64 = 4 rows: a request for 4 shards clamps to 2 (halo)
-        enc = H264Encoder(W, H, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, entropy="device",
                           host_color=True, gop=4, spatial_shards=4)
         assert enc._spatial_nx == 2
         # keep_recon (the PSNR hook) disables sharding
-        enc2 = H264Encoder(W, H, mode="cavlc", entropy="device",
+        enc2 = H264Encoder(W, H, entropy="device",
                            host_color=True, gop=4, keep_recon=True,
                            spatial_shards=2)
         assert enc2._spatial_nx == 1
         # host-entropy modes never shard
-        enc3 = H264Encoder(W, H, mode="cavlc", entropy="python",
+        enc3 = H264Encoder(W, H, entropy="python",
                            gop=4, spatial_shards=2)
         assert enc3._spatial_nx == 1
 

@@ -71,10 +71,10 @@ def test_each_frame_is_one_sample_of_every_encoder_stage(encoder, kind,
     enc = encoder
     if kind == "idr":
         enc._force_idr = True
-    guess = "_pull_guess" if kind == "idr" else "_p_pull_guess"
+    pull = enc._flat_pull["intra" if kind == "idr" else "p"]
     if short_guess:
         # the next frame's prefix holds the header and 16 bytes
-        setattr(enc, guess, 16)
+        pull.guess = 16
     before = counts()
     ef = enc.encode_collect(enc.encode_submit(frame(3)))
     got = delta(before)
@@ -86,7 +86,7 @@ def test_each_frame_is_one_sample_of_every_encoder_stage(encoder, kind,
     # the encoder's assembly is the first part of a split stage: the
     # session's muxer closes it (below), so no sample yet
     assert got["assemble"] == 0
-    assert getattr(enc, guess) >= enc._PULL_BUCKET  # the guess recovered
+    assert pull.guess >= pull.BUCKET  # the guess recovered
 
 
 def test_dispatch_accounting_rides_the_dispatch_span(encoder):
@@ -160,7 +160,7 @@ def content(kind: str, n: int = 9) -> list:
 def raw_encoder(**options):
     from docker_nvidia_glx_desktop_tpu.models.h264 import H264Encoder
 
-    return H264Encoder(W, H, mode="cavlc", entropy="device",
+    return H264Encoder(W, H, entropy="device",
                        host_color=True, gop=9, **options)
 
 
@@ -583,16 +583,22 @@ def lowered():
             np.zeros(band[3].shape, band[3].dtype), *zeros(
                 band[4], "luma", "cb_dc", "cb_ac", "cr_dc", "cr_ac")),
     })
-    flag = "jax_compilation_cache_include_metadata_in_key"
-    was = getattr(jax.config, flag)
-    jax.config.update(flag, True)
+    # ... and none of them is written to the cache: nothing reads such an
+    # entry but this fixture, and serializing one took a worker down with
+    # a segmentation fault inside jaxlib (the driver's run of PR 46's tree)
+    flags = {"jax_compilation_cache_include_metadata_in_key": True,
+             "jax_persistent_cache_min_compile_time_secs": 1e9}
+    was = {f: getattr(jax.config, f) for f in flags}
+    for f, v in flags.items():
+        jax.config.update(f, v)
     try:
         return {k: (low.as_text(debug_info=True),
                     re.findall(r'op_name="([^"]*)"',
                                low.compile().as_text()))
                 for k, low in progs.items()}
     finally:
-        jax.config.update(flag, was)
+        for f, v in was.items():
+            jax.config.update(f, v)
 
 
 @pytest.mark.parametrize("program,scope", [

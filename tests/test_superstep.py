@@ -54,9 +54,9 @@ class TestRingByteIdentity:
         through the ring vs per-frame — plus the crossings claim: the
         ring must dispatch ~once per chunk, not per frame."""
         frames = _frames(19)
-        a = H264Encoder(W, H, mode="cavlc", entropy="device",
+        a = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=9, deblock=True)
-        b = H264Encoder(W, H, mode="cavlc", entropy="device",
+        b = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=9, deblock=True,
                         superstep_chunk=4)
         assert b._ring_chunk == 4 and b.pipeline_depth == 5
@@ -70,9 +70,9 @@ class TestRingByteIdentity:
         """gop=8 with chunk=3: every P-run is 2 chunks + 1 flushed
         frame — the IDR-due flush must be byte-invisible."""
         frames = _frames(17, seed=5)
-        a = H264Encoder(W, H, mode="cavlc", entropy="device",
+        a = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=8, deblock=True)
-        b = H264Encoder(W, H, mode="cavlc", entropy="device",
+        b = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=8, deblock=True,
                         superstep_chunk=3)
         _assert_streams_equal(a, b, frames)
@@ -81,7 +81,7 @@ class TestRingByteIdentity:
         """deblock off + nine-mode I_NxN IDRs: the ring's recon chain
         (refs aliased in place, no loop filter) must still match."""
         frames = _frames(10, seed=7)
-        kw = dict(mode="cavlc", entropy="device", host_color=True,
+        kw = dict(entropy="device", host_color=True,
                   gop=10, deblock=False, intra_modes="full")
         a = H264Encoder(W, H, **kw)
         b = H264Encoder(W, H, superstep_chunk=3, **kw)
@@ -91,12 +91,10 @@ class TestRingByteIdentity:
         """CABAC path: the chunk step fuses binarize_p into the scan;
         the host engine replays per frame — byte-identical streams."""
         frames = _frames(8, w=48, h=32, seed=9)
-        kw = dict(mode="cavlc", entropy="cabac", host_color=True,
+        kw = dict(entropy="cabac", host_color=True,
                   gop=8, deblock=True)
         a = H264Encoder(48, 32, **kw)
         b = H264Encoder(48, 32, superstep_chunk=3, **kw)
-        a._cabac_dev_bin = True          # pin: no env dependence
-        b._cabac_dev_bin = True
         assert b._ring_chunk == 3
         _assert_streams_equal(a, b, frames)
 
@@ -105,9 +103,9 @@ class TestRingByteIdentity:
         source / pipeline drain) must flush per-frame, byte-identically
         — frames are never stranded in the ring."""
         frames = _frames(6, seed=11)            # gop=16: IDR + 5 staged P
-        a = H264Encoder(W, H, mode="cavlc", entropy="device",
+        a = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=16, deblock=True)
-        b = H264Encoder(W, H, mode="cavlc", entropy="device",
+        b = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=16, deblock=True,
                         superstep_chunk=4)
         ra = [a.encode_collect(a.encode_submit(f)) for f in frames]
@@ -144,7 +142,7 @@ class TestRingByteIdentity:
 
         # integration: a rate-controlled ring run drains its ledger
         frames = _frames(13, seed=13)
-        b = H264Encoder(W, H, mode="cavlc", entropy="device",
+        b = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=13, deblock=True,
                         bitrate_kbps=800, fps=30.0, superstep_chunk=4)
         assert b._ring_chunk == 4
@@ -161,7 +159,7 @@ class TestRingOverflowFallback:
         tensors (no access to the consumed refs) — byte-identical to
         the per-frame stream."""
         frames = _frames(6, seed=17)
-        b = H264Encoder(W, H, mode="cavlc", entropy="device",
+        b = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=16, deblock=True,
                         superstep_chunk=4)
         pend = [b.encode_submit(f) for f in frames[:5]]
@@ -175,7 +173,7 @@ class TestRingOverflowFallback:
         prefix[1][3] = 1
         ring["prefix_np"] = prefix
         # per-frame twin for the expected bytes
-        a = H264Encoder(W, H, mode="cavlc", entropy="device",
+        a = H264Encoder(W, H, entropy="device",
                         host_color=True, gop=16, deblock=True)
         want = [a.encode_collect(a.encode_submit(f))
                 for f in frames[:5]]
@@ -287,7 +285,7 @@ class TestRetraceTripwire:
         if not compile_events_supported():
             pytest.skip("jax.monitoring compile events unavailable")
         frames = _frames(25, seed=19)
-        enc = H264Encoder(W, H, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, entropy="device",
                           host_color=True, gop=25, deblock=True,
                           superstep_chunk=4)
         pend = []

@@ -95,7 +95,7 @@ class TestHqConformance:
                                                    mkframes):
         n = 5
         frames = mkframes(n)
-        enc = H264Encoder(W, H, qp=qp, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, qp=qp, entropy="device",
                           gop=n, keep_recon=True, tune="hq")
         aus, recons = _encode_gop(enc, frames)
         dec = _decode_all(b"".join(aus), tmp_path, n)
@@ -110,7 +110,7 @@ class TestHqConformance:
 
         from docker_nvidia_glx_desktop_tpu.ops import h264_inter
         frames = _drift_frames(2)
-        enc = H264Encoder(W, H, qp=30, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, qp=30, entropy="device",
                           gop=2, tune="hq")
         planes = [_yuv_stage(jnp.asarray(f), enc.pad_h, enc.pad_w)
                   for f in frames]
@@ -131,9 +131,9 @@ class TestHqConformance:
     def test_hq_device_entropy_matches_python(self, qp):
         n = 4
         frames = _drift_frames(n)
-        e_dev = H264Encoder(W, H, qp=qp, mode="cavlc", entropy="device",
+        e_dev = H264Encoder(W, H, qp=qp, entropy="device",
                             gop=n, tune="hq")
-        e_py = H264Encoder(W, H, qp=qp, mode="cavlc", entropy="python",
+        e_py = H264Encoder(W, H, qp=qp, entropy="python",
                            gop=n, tune="hq")
         for i, f in enumerate(frames):
             a, b = e_dev.encode(f).data, e_py.encode(f).data
@@ -144,7 +144,7 @@ class TestHqConformance:
         I16-in-P there — the v1 gate models/h264 documents)."""
         n = 4
         frames = _mixed_frames(n)
-        enc = H264Encoder(W, H, qp=30, mode="cavlc", entropy="cabac",
+        enc = H264Encoder(W, H, qp=30, entropy="cabac",
                           gop=n, keep_recon=True, tune="hq")
         assert not enc._p_intra
         aus, recons = _encode_gop(enc, frames)
@@ -156,7 +156,7 @@ class TestHqConformance:
         """The attribution tier (lambda decisions, flat qp plane)."""
         n = 4
         frames = _drift_frames(n)
-        enc = H264Encoder(W, H, qp=30, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, qp=30, entropy="device",
                           gop=n, keep_recon=True, tune="hq_noaq")
         aus, recons = _encode_gop(enc, frames)
         dec = _decode_all(b"".join(aus), tmp_path, n)
@@ -187,10 +187,10 @@ class TestHqExecutionShapes:
         so hq chunk output is covered by the conformance test below."""
         n = 9                        # IDR + 2 chunks of 4
         frames = _drift_frames(n)
-        ref = H264Encoder(W, H, qp=30, mode="cavlc", entropy="device",
+        ref = H264Encoder(W, H, qp=30, entropy="device",
                           gop=n, tune="hq_noaq")
         want = [ref.encode(f).data for f in frames]
-        enc = H264Encoder(W, H, qp=30, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, qp=30, entropy="device",
                           gop=n, tune="hq_noaq", superstep_chunk=4)
         got = self._drive(enc, frames)
         for i, (a, b) in enumerate(zip(got, want)):
@@ -201,7 +201,7 @@ class TestHqExecutionShapes:
         through the scan) must decode and track the ring recon."""
         n = 9
         frames = _drift_frames(n)
-        enc = H264Encoder(W, H, qp=30, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, qp=30, entropy="device",
                           gop=n, tune="hq", superstep_chunk=4)
         assert enc.superstep_chunk >= 2   # ring actually eligible
         got = self._drive(enc, frames)
@@ -215,10 +215,10 @@ class TestHqExecutionShapes:
             pytest.skip("needs >= 2 devices")
         n = 5
         frames = _drift_frames(n)
-        ref = H264Encoder(W, H, qp=30, mode="cavlc", entropy="device",
+        ref = H264Encoder(W, H, qp=30, entropy="device",
                           gop=n, tune="hq")
         want = [ref.encode(f).data for f in frames]
-        enc = H264Encoder(W, H, qp=30, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, qp=30, entropy="device",
                           gop=n, tune="hq", spatial_shards=2)
         got = self._drive(enc, frames)
         for i, (a, b) in enumerate(zip(got, want)):
@@ -229,7 +229,7 @@ class TestOffTierOptOut:
     """tune=off must be strictly opt-in: no hq machinery engages."""
 
     def test_off_never_enables_p_intra_or_qp_map(self):
-        enc = H264Encoder(W, H, qp=30, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, qp=30, entropy="device",
                           gop=4, tune="off")
         assert enc.tune == "off" and enc._ktune == "off"
         assert not enc._p_intra
@@ -239,7 +239,7 @@ class TestOffTierOptOut:
         assert enc._take_mean_qp() is None   # no qp plane was produced
 
     def test_hq_with_deblock_degrades_to_noaq_no_pintra(self):
-        enc = H264Encoder(W, H, qp=30, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, qp=30, entropy="device",
                           gop=4, deblock=True, tune="hq")
         assert enc._ktune == "hq_noaq"
         assert not enc._p_intra      # intra bS is not modeled in v1
@@ -297,7 +297,7 @@ class TestHqRetrace:
         if not compile_events_supported():
             pytest.skip("jax.monitoring compile events unavailable")
         frames = _drift_frames(12)
-        enc = H264Encoder(W, H, qp=30, mode="cavlc", entropy="device",
+        enc = H264Encoder(W, H, qp=30, entropy="device",
                           gop=6, tune="hq")
         for f in frames[:7]:         # full GOP + next IDR warm-up
             enc.encode(f)

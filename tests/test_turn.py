@@ -228,7 +228,7 @@ class TestRelayedMediaE2e:
         from docker_nvidia_glx_desktop_tpu.webrtc.srtp import SrtpContext
 
         # encode outside the event loop: one IDR AU for the media check
-        enc = H264Encoder(128, 96, qp=26, mode="cavlc", entropy="device")
+        enc = H264Encoder(128, 96, qp=26, entropy="device")
         frame = np.zeros((96, 128, 3), np.uint8)
         frame[20:60, 30:90] = (200, 60, 40)
         au = enc.headers() + enc.encode(frame).data

@@ -61,13 +61,10 @@ def new_encoder(kind: str):
     cfg = from_env({"PASSWD": "pw", "SIZEW": str(W), "SIZEH": str(H),
                     "REFRESH": "60",
                     "ENCODER_ENTROPY": MASKS.get(kind, kind),
-                    "ENCODER_CABAC_BINARIZE": "device",
                     "ENCODER_BITRATE_KBPS": "100" if mask else "300",
                     "ENCODER_GOP": "60", "ENCODER_PREWARM": "false"})
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("DNGD_DAMAGE_MASK", "true" if mask else "false")
-        if kind == "cabac_mask":   # (the encoder asks the environment, once)
-            mp.setenv("ENCODER_CABAC_BINARIZE", "device")
         enc, _ = make_encoder(cfg, W, H)
     assert enc._dyn_qp and enc._rate is not None
     assert enc.damage_mask == mask
@@ -220,8 +217,8 @@ def test_a_one_piece_submit_calls_nothing_between(one_piece):
     kw = {"ring": dict(entropy="device", gop=30, superstep_chunk=4),
           "masked_ring": dict(entropy="device", gop=30, superstep_chunk=4,
                               damage_mask=True, host_color=True),
-          "sync": dict(entropy="native", gop=30)}[one_piece]
-    enc = H264Encoder(W, H, mode="cavlc", bitrate_kbps=300, fps=60, **kw)
+          "sync": dict(entropy="python", gop=30)}[one_piece]
+    enc = H264Encoder(W, H, bitrate_kbps=300, fps=60, **kw)
     calls = []
     enc.between_halves = lambda: calls.append(1)
     tokens = [enc.encode_submit(frame(k)) for k in range(2)]
