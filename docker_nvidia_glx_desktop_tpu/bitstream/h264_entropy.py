@@ -96,10 +96,11 @@ def p_mean_coded_qp(levels: dict, qp_map, slice_qp: int) -> float:
     return float(eff.mean())
 
 
-def intra_mean_coded_qp(levels: dict, qp_map, slice_qp: int) -> float:
-    """Mean effective per-MB qp of an intra picture under ``qp_map``:
+def intra_qp_chain(levels: dict, qp_map, slice_qp: int) -> np.ndarray:
+    """(R, C) effective per-MB qp of an intra picture under ``qp_map``:
     I_16x16 always codes the syntax; an I_NxN MB with cbp == 0 carries
-    the previous MB's qp (mirrors encode_intra_picture)."""
+    the previous MB's qp (mirrors encode_intra_picture).  What a decoder
+    holds as QPY, and so what the loop filter's thresholds follow."""
     from ..ops.aq import qp_chain_np
 
     luma_ac = np.asarray(levels["luma_ac"], np.int32)
@@ -118,7 +119,13 @@ def intra_mean_coded_qp(levels: dict, qp_map, slice_qp: int) -> float:
     codes = np.where(mb_i4, i4_codes, True)
     eff, _ = qp_chain_np(np.asarray(qp_map, np.int32), codes,
                          int(slice_qp))
-    return float(eff.mean())
+    return eff
+
+
+def intra_mean_coded_qp(levels: dict, qp_map, slice_qp: int) -> float:
+    """Mean of :func:`intra_qp_chain`: the statistic the device CAVLC
+    meta word sums."""
+    return float(intra_qp_chain(levels, qp_map, slice_qp).mean())
 
 
 def encode_p_picture(levels: dict, *, frame_num: int,

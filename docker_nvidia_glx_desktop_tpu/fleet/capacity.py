@@ -48,6 +48,10 @@ class CapacityModel:
     # whose hq step exceeds 1.5x off), not the typically-lower measured
     # ratio: admission must hold under the worst step the gate admits.
     # DNGD_HQ_COST_FACTOR overrides after a calibrating TPU round.
+    # (Measured: 1.01-1.04x at 1920x1080 on one v5e chip, 9.11 ms of
+    # device a frame against 8.75-9.03 on the same pictures: cells
+    # desk1080-hq.* beside desk1080.*, my chip runs, PR 48.  The default
+    # stays the gate's ceiling.)
     TUNE_COST_FACTORS = {"off": 1.0, "hq_noaq": 1.15, "hq": 1.5}
 
     def __init__(self, ledger=None, headroom: float = 0.85,

@@ -87,6 +87,25 @@ _M_MASK_FRAMES = obsm.counter(
     ("program",))
 _M_MASK_FRAMES_ROWS = _M_MASK_FRAMES.labels("rows")
 _M_MASK_FRAMES_DENSE = _M_MASK_FRAMES.labels("dense")
+_M_P_MBS = obsm.counter(
+    "dngd_encoder_p_mbs_total",
+    "Macroblocks of the P frames coded under ENCODER_TUNE=hq on the served "
+    "per-frame path (a qp a macroblock, the intra escape open): what the "
+    "three families below are shares and means of")
+_M_P_INTRA_MBS = obsm.counter(
+    "dngd_encoder_p_intra_mbs_total",
+    "Macroblocks of those whose motion candidate lost to intra by "
+    "SSD + lambda * bits and were coded I_16x16 in the P slice (the "
+    "frame's meta word, no pull of its own)")
+_M_CODED_QP_SUM = obsm.counter(
+    "dngd_encoder_coded_qp_sum_total",
+    "Sum over those macroblocks of the EFFECTIVE qp (what a decoder holds "
+    "as QPY there: the mb_qp_delta chain; the frame's meta word)")
+_M_SLICE_QP_SUM = obsm.counter(
+    "dngd_encoder_slice_qp_sum_total",
+    "Sum over those macroblocks of their slice's qp (the rate ladder's "
+    "rung): coded less slice, over dngd_encoder_p_mbs_total, is what the "
+    "adaptive quantization moved the mean macroblock by")
 _M_CABAC_DENSE = _M_CABAC_FALLBACK.labels("dense")
 _M_CABAC_PYTHON = _M_CABAC_FALLBACK.labels("python")
 
@@ -423,11 +442,12 @@ class H264Encoder(Encoder):
         # -- perceptual-efficiency tuning tier (ENCODER_TUNE) ----------
         # "off" = byte-identical to the pre-tune encoder; "hq" = per-MB
         # adaptive quantization + Lagrangian mode decisions + optional
-        # 1-frame lookahead (ops/aq).  The kernel tune
-        # downgrades to "hq_noaq" when the loop filter is on: the
-        # deblock kernel's thresholds are compiled per slice qp, so the
-        # per-MB qp plane is a v1 deblock-off feature (the lambda
-        # decisions are qp-uniform and stay active).
+        # 1-frame lookahead (ops/aq).  Under the loop filter the tier is
+        # served whole on the per-frame, one-chip, device-CAVLC path
+        # (:attr:`_hq_loop`, decided at the end of this constructor: the
+        # filter takes the plane of effective qps and the intra flags);
+        # on every other path with the filter on the kernel tune is
+        # "hq_noaq" (the lambda decisions at one qp a slice).
         if tune is None:
             import os
             tune = os.environ.get("ENCODER_TUNE", "off") or "off"
@@ -442,24 +462,18 @@ class H264Encoder(Encoder):
                 "unknown ENCODER_TUNE %r: serving tune=off", tune)
             tune = "off"
         self.tune = tune
-        if tune == "hq" and self.deblock:
-            log.warning(
-                "ENCODER_TUNE=hq with deblock on: per-MB adaptive "
-                "quantization is disabled (lambda mode decisions stay "
-                "active) — the loop-filter thresholds are per-slice-qp "
-                "in v1")
-            self._ktune = "hq_noaq"
-        else:
-            self._ktune = tune
+        self._hq_loop = False
+        self._ktune = "hq_noaq" if tune == "hq" and self.deblock else tune
         # where a CABAC stream is binarized (:attr:`cabac_device_binarize`):
         # on the device, except that the record stream carries no per-MB
         # qp, so the tier that codes one keeps the level transport
         self._cabac_dev_bin = self._ktune != "hq"
         # I_16x16-in-P lambda mode decision (the intra escape for
         # content ME cannot track).  v1 plumbing: the device + python
-        # CAVLC coders; gated off under deblock (intra bS rules are not
-        # modeled by the filter kernel) and CABAC (no I16-in-P binarize
-        # records).
+        # CAVLC coders; gated off under deblock outside the served path
+        # (:attr:`_hq_loop`: the ring's, the mesh's and the row programs'
+        # filter calls hand over no intra flags) and CABAC (no I16-in-P
+        # binarize records).
         self._p_intra = (self._ktune != "off" and not self.deblock
                          and entropy in ("device", "python"))
         self._mean_qp_pending = None     # per-frame mean coded qp (hq)
@@ -593,6 +607,26 @@ class H264Encoder(Encoder):
         self._damage_prev_y = None       # previous frame's ingest luma
         self._damage_cur_y = None        # current frame's ingest luma
         self._damage_frac = None         # latest gated damage fraction
+        # -- ENCODER_TUNE=hq under the loop filter ---------------------
+        # Served whole where a frame is ONE device-CAVLC program on one
+        # chip and the filter is a program of its own behind it: qp is a
+        # traced scalar there as at tune=off (:attr:`_dyn_qp`), the plane
+        # of effective qps and the I_16x16 flags go to the filter
+        # (:meth:`_deblock`).  The ring, the mesh, the damage mask and
+        # CABAC keep the parent's tier.
+        if tune == "hq" and self.deblock:
+            if (entropy == "device" and not self.damage_mask
+                    and not self._ring_chunk and self._spatial_nx == 1):
+                self._hq_loop = True
+                self._ktune = "hq"
+                self._p_intra = True
+            else:
+                log.warning(
+                    "ENCODER_TUNE=hq with deblock on, outside the "
+                    "per-frame one-chip device-CAVLC path: per-MB "
+                    "adaptive quantization and I_16x16 in P are disabled "
+                    "(lambda mode decisions stay active): this path's "
+                    "loop filter is handed one qp a slice")
 
     def headers(self) -> bytes:
         return (syn.nal_unit(syn.NAL_SPS, self._sps)
@@ -1222,13 +1256,18 @@ class H264Encoder(Encoder):
         compiled program set for every qp (qp is a traced scalar there):
         nothing is qp-specialized, so the rate ladder and the degrade
         bias move freely on a cold cache and there is no ladder to
-        prewarm.  The hq tiers keep qp static (their lambda decisions
-        are compile-time floats)."""
-        return self._ktune == "off" and self.entropy in ("device", "cabac")
+        prewarm.  So does ``hq`` under the loop filter where it is served
+        whole (:attr:`_hq_loop`: everything downstream of the slice qp
+        is a plane there); the other hq paths keep qp static
+        (``hq_noaq``'s lambda decisions are compile-time floats)."""
+        return self._hq_loop or (self._ktune == "off"
+                                 and self.entropy in ("device", "cabac"))
 
     def _deblock(self, y, cb, cr, qp: int, **bs_inputs):
         """In-loop filter of the per-frame device path (qp traced where
-        the encode stage's is)."""
+        the encode stage's is); ``bs_inputs``: a P frame's ``nnz_blk``
+        and ``mv`` and, where ``hq`` is served whole, the frame's
+        ``qp_eff`` plane and ``mb_intra`` flags."""
         from ..ops import h264_deblock
         if self._dyn_qp:
             return h264_deblock.deblock_frame_dynqp(y, cb, cr, np.int32(qp),
@@ -1277,9 +1316,10 @@ class H264Encoder(Encoder):
         qps = set(base)
         for off in self.DEGRADE_QP_OFFSETS:
             qps |= {min(51, q + off) for q in base}
-        if self._ktune == "hq" and self.gop > 1:
+        if self._ktune == "hq" and self.gop > 1 and not self._dyn_qp:
             # IDRs code at qp - I_QP_BIAS (_eff_qp) — prewarm those
             # specializations too or the first hq scene cut compiles
+            # (the ring's; where qp is traced the bias is arithmetic)
             qps |= {max(q - self.I_QP_BIAS, 1) for q in set(qps)}
         return sorted(qps, key=lambda q: (abs(q - self.qp), q))
 
@@ -1527,10 +1567,20 @@ class H264Encoder(Encoder):
         with_recon = self.keep_recon or self.gop > 1
         with obst.stage("dispatch") as span:
             hv, hl = self._hdr_slots(idr_pic_id, qp_delta=qp - self.qp)
+            if planes is None and self._hq_loop:
+                # the traced-qp program takes planes: the device converts
+                planes = _yuv_stage(jnp.asarray(rgb), self.pad_h,
+                                    self.pad_w)
+            qp_eff = {}
             if planes is not None and self._dyn_qp:
                 out = cavlc_device.encode_intra_cavlc_frame_yuv_dynqp(
                     *planes, hv, hl, np.int32(qp), with_recon=with_recon,
-                    i16_modes=self.i16_modes, tune="off")
+                    i16_modes=self.i16_modes, tune=self._ktune,
+                    **({"with_qp_eff": True}
+                       if self._hq_loop and with_recon else {}))
+                if self._hq_loop and with_recon:
+                    qp_eff = {"qp_eff": out[1][3]}
+                    out = out[0], out[1][:3]
             elif planes is not None:
                 out = cavlc_device.encode_intra_cavlc_frame_yuv(
                     *planes, hv, hl, qp, with_recon=with_recon,
@@ -1551,7 +1601,7 @@ class H264Encoder(Encoder):
                 # loop-filtered picture — exactly what the decoder predicts
                 # from.
                 if self.deblock:
-                    self._ref = self._deblock(*recon, qp)
+                    self._ref = self._deblock(*recon, qp, **qp_eff)
                 else:
                     self._ref = tuple(recon)
             # content stats ride this submit's crossing (extra jit calls in
@@ -1973,7 +2023,13 @@ class H264Encoder(Encoder):
                       levels["recon_cr"])
             if self.deblock:
                 from ..ops import h264_deblock
-                recon3 = h264_deblock.deblock_frame(*recon3, qp)
+                kw = {}
+                if "qp_map" in levels:   # hq served whole: the chain's qps
+                    kw["qp_eff"] = h264_entropy.intra_qp_chain(
+                        {k: np.asarray(v) for k, v in levels.items()
+                         if not k.startswith("recon")},
+                        np.asarray(levels["qp_map"]), qp)
+                recon3 = h264_deblock.deblock_frame(*recon3, qp, **kw)
             self._ref = recon3
         if self.keep_recon:
             self.last_recon = tuple(
@@ -2134,11 +2190,12 @@ class H264Encoder(Encoder):
         with obst.stage("dispatch") as span:
             frame_num = self._frame_num if frame_num is None else frame_num
             hv, hl = self._p_hdr_slots(frame_num, qp - self.qp)
-            if self._dyn_qp:      # tune=off: no lookahead luma, no I16-in-P
+            if self._dyn_qp:      # no lookahead luma on the per-frame path
                 flat, ry, rcb, rcr, mv, nnz, levels = \
                     cavlc_p_device.encode_p_cavlc_frame_dynqp(
                         jnp.asarray(y), jnp.asarray(cb), jnp.asarray(cr),
-                        *self._ref, hv, hl, np.int32(qp), "off", None, False)
+                        *self._ref, hv, hl, np.int32(qp), self._ktune, None,
+                        self._p_intra, *((True,) if self._hq_loop else ()))
             else:
                 flat, ry, rcb, rcr, mv, nnz, levels = \
                     cavlc_p_device.encode_p_cavlc_frame(
@@ -2152,7 +2209,11 @@ class H264Encoder(Encoder):
                        levels["cr_dc"], levels["cr_ac"]),
                 mb_intra=levels.get("mb_intra"))
             if self.deblock:
-                self._ref = self._deblock(ry, rcb, rcr, qp, nnz_blk=nnz, mv=mv)
+                bs_inputs = {"nnz_blk": nnz, "mv": mv}
+                if self._hq_loop:    # the filter's thresholds and intra bS
+                    bs_inputs.update(qp_eff=levels["qp_eff"],
+                                     mb_intra=levels["mb_intra"])
+                self._ref = self._deblock(ry, rcb, rcr, qp, **bs_inputs)
             else:
                 self._ref = recon
             if self.keep_recon:
@@ -2188,7 +2249,8 @@ class H264Encoder(Encoder):
             # stream stays bit-consistent and the already-advanced
             # reference chain needs no rewind.
             with obst.stage("assemble", more=True):
-                pulled = {k: np.asarray(v) for k, v in levels.items()}
+                pulled = {k: np.asarray(v) for k, v in levels.items()
+                          if k != "qp_eff"}          # (the loop filter's)
                 pulled["mv"] = np.asarray(mv)
                 self.last_mv = pulled["mv"]
                 qp_map = pulled.pop("qp_map", None)
@@ -2199,6 +2261,12 @@ class H264Encoder(Encoder):
                     qp_map=qp_map, slice_qp=qp)
         buf, meta = got
         self._note_qp_sum(meta.qp_sum)
+        if self._hq_loop:
+            mbs = self.mb_w * self.mb_h
+            _M_P_MBS.inc(mbs)
+            _M_P_INTRA_MBS.inc(meta.p_intra_mbs)
+            _M_CODED_QP_SUM.inc(meta.qp_sum)
+            _M_SLICE_QP_SUM.inc(qp * mbs)
         with obst.stage("assemble", more=True):
             return cavlc_device.assemble_annexb(
                 buf, meta, nal_type=syn.NAL_SLICE, ref_idc=2)

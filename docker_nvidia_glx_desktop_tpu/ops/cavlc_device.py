@@ -432,9 +432,12 @@ def frame_block_slots(levels: dict, slice_qp: int = None):
     gating and no Hadamard DC block.  Returns (values, lengths, syn_vals,
     syn_lens, qp_sum): (27, R * C, 34) codeword slots, block-major as
     :func:`pack_frame` takes them, plus the (R, C, 20) MB-syntax slots (see
-    MB_SYN_SLOTS layout); ``qp_sum`` is the summed per-MB effective qp
-    (tune=hq; None otherwise) the host normalizes the rate model with.  ``slice_qp`` anchors the mb_qp_delta chain and
-    is required when ``levels`` carries a ``qp_map``.
+    MB_SYN_SLOTS layout); ``qp_eff`` is the (R, C) plane of each
+    macroblock's EFFECTIVE qp (``aq.qp_chain``: what a decoder holds as
+    QPY there, and what the loop filter's thresholds follow; tune=hq, None
+    otherwise), whose sum the host normalizes the rate model with.
+    ``slice_qp`` (a Python int or a traced scalar) anchors the mb_qp_delta
+    chain and is required when ``levels`` carries a ``qp_map``.
     """
     luma_dc = levels["luma_dc"]        # (R, C, 16) zigzag
     luma_ac = levels["luma_ac"]        # (R, C, 16, 15) blkIdx-ordered
@@ -548,21 +551,21 @@ def frame_block_slots(levels: dict, slice_qp: int = None):
     # (ops/aq.qp_chain).  The syntax exists for every I16 MB and for
     # I_NxN with cbp != 0 — exactly the MBs that dequantize anything.
     qp_se = None
-    qp_sum = None
+    qp_eff = None
     if "qp_map" in levels:
         from . import aq
-        cbp_any = jnp.where(mb_i4, cbp_luma4 > 0, cbp_luma) \
-            | (cbp_chroma > 0)
-        codes = ~mb_i4 | cbp_any
-        eff, delta = aq.qp_chain(levels["qp_map"], codes, int(slice_qp))
+        with jax.named_scope("dngd.aq"):
+            cbp_any = jnp.where(mb_i4, cbp_luma4 > 0, cbp_luma) \
+                | (cbp_chroma > 0)
+            codes = ~mb_i4 | cbp_any
+            qp_eff, delta = aq.qp_chain(levels["qp_map"], codes, slice_qp)
         sv, sl = se_slots(delta)
         qp_se = (sv, jnp.where(codes, sl, 0))
-        qp_sum = jnp.sum(eff).astype(jnp.uint32)
 
     syn_vals, syn_lens = intra_mb_syntax_slots(
         levels["pred_mode"], mb_i4, i4_modes, cbp_luma, cbp_luma4,
         cbp_chroma, qp_se=qp_se)
-    return values, lengths, syn_vals, syn_lens, qp_sum
+    return values, lengths, syn_vals, syn_lens, qp_eff
 
 
 def intra_mb_syntax_slots(pred_mode, mb_i4, i4_modes, cbp_luma, cbp_luma4,
@@ -644,10 +647,14 @@ HDR_SLOTS = 3          # slice header bits, pre-encoded on host (<= 96 bits)
 # (tune=hq; 0 = uniform slice qp).  Rows claim [2, 2+MAX_META_ROWS) and
 # [2+MAX_META_ROWS, 2+2*MAX_META_ROWS); this sits just past them.
 META_QP_SUM_WORD = 2 + 2 * MAX_META_ROWS          # = 1022 < META_WORDS
+# ... and the last word the P frame's macroblocks coded I_16x16 (tune=hq
+# with the intra escape; 0 elsewhere): dngd_encoder_p_intra_mbs_total
+META_P_INTRA_WORD = META_QP_SUM_WORD + 1          # = 1023
 
 
 def pack_frame(values, lengths, syn_vals, syn_lens, hdr_vals, hdr_lens,
-               trail_vals=None, trail_lens=None, qp_sum=None):
+               trail_vals=None, trail_lens=None, qp_sum=None,
+               p_intra_mbs=None):
     """Scatter-free packing of a picture's CAVLC slots into row RBSPs, for
     I and P pictures alike.
 
@@ -661,7 +668,8 @@ def pack_frame(values, lengths, syn_vals, syn_lens, hdr_vals, hdr_lens,
     word offsets) followed by the rows' RBSPs, each row starting at a
     4-byte-aligned offset.  ``qp_sum`` (tune=hq) rides in
     META_QP_SUM_WORD so the host's rate controller can normalize by the
-    mean coded qp without an extra device pull.
+    mean coded qp without an extra device pull; ``p_intra_mbs`` (a P
+    frame's I_16x16 macroblocks) rides in META_P_INTRA_WORD the same way.
 
     The rows are merged by ``ops/cabac_pack``'s two kernels on the TPU and
     by the :mod:`.bitmerge` hierarchy everywhere else; the buffer is the
@@ -692,6 +700,9 @@ def pack_frame(values, lengths, syn_vals, syn_lens, hdr_vals, hdr_lens,
         word_off.astype(jnp.uint32))
     if qp_sum is not None:
         meta = meta.at[META_QP_SUM_WORD].set(qp_sum.astype(jnp.uint32))
+    if p_intra_mbs is not None:
+        meta = meta.at[META_P_INTRA_WORD].set(
+            p_intra_mbs.astype(jnp.uint32))
 
     with jax.named_scope("flat_bytes"):
         allw = jnp.concatenate([meta, flat_words])
@@ -877,22 +888,26 @@ def encode_intra_cavlc_frame(rgb, hdr_vals, hdr_lens, pad_h: int, pad_w: int,
 
 @functools.partial(jax.jit,
                    static_argnames=("qp", "with_recon", "i16_modes",
-                                    "tune"))
+                                    "tune", "with_qp_eff"))
 def encode_intra_cavlc_frame_yuv(y, cb, cr, hdr_vals, hdr_lens, qp: int,
                                  with_recon: bool = False,
                                  i16_modes: str = "auto",
-                                 tune: str = "off", next_y=None):
+                                 tune: str = "off", next_y=None,
+                                 with_qp_eff: bool = False):
     """Device stage from pre-converted YUV 4:2:0 planes (host cv2 color
     conversion halves the host->device bytes; see
-    h264_device.encode_intra_frame_yuv)."""
+    h264_device.encode_intra_frame_yuv).  ``with_qp_eff`` (tune=hq with
+    the loop filter on): the recon tuple ends with the (R, C) plane of
+    effective qps the filter's thresholds follow."""
     from . import h264_device
 
     levels = h264_device.encode_intra_frame_yuv.__wrapped__(
         y, cb, cr, qp, i16_modes, tune, next_y)
-    return _finish_cavlc(levels, hdr_vals, hdr_lens, with_recon, qp)
+    return _finish_cavlc(levels, hdr_vals, hdr_lens, with_recon, qp,
+                         with_qp_eff)
 
 
-#: The same stage with ``qp`` TRACED (tune="off" only): one compiled
+#: The same stage with ``qp`` TRACED (tune "off" and "hq"): one compiled
 #: program serves every qp the rate ladder can ask for.  With qp static a
 #: 1080p program costs about a minute of host compile for the TPU and the
 #: served CBR ladder has 15 qps — tens of minutes cold, and the compiler
@@ -901,20 +916,22 @@ def encode_intra_cavlc_frame_yuv(y, cb, cr, hdr_vals, hdr_lens, qp: int,
 #: static-qp program at every qp (tests/test_h264_inter.py).
 encode_intra_cavlc_frame_yuv_dynqp = jax.jit(
     encode_intra_cavlc_frame_yuv.__wrapped__,
-    static_argnames=("with_recon", "i16_modes", "tune"))
+    static_argnames=("with_recon", "i16_modes", "tune", "with_qp_eff"))
 
 
 def _finish_cavlc(levels, hdr_vals, hdr_lens, with_recon: bool,
-                  slice_qp: int = None):
+                  slice_qp: int = None, with_qp_eff: bool = False):
     recon = (levels["recon_y"], levels["recon_cb"], levels["recon_cr"])
     with jax.named_scope("dngd.slots"):
-        values, lengths, syn_vals, syn_lens, qp_sum = frame_block_slots(
+        values, lengths, syn_vals, syn_lens, qp_eff = frame_block_slots(
             levels, slice_qp)
+        qp_sum = (None if qp_eff is None
+                  else jnp.sum(qp_eff).astype(jnp.uint32))
     with jax.named_scope("dngd.pack"):
         flat, _ = pack_frame(values, lengths, syn_vals, syn_lens,
                              hdr_vals, hdr_lens, qp_sum=qp_sum)
     if with_recon:
-        return flat, recon
+        return flat, (recon + (qp_eff,) if with_qp_eff else recon)
     return flat
 
 
@@ -931,6 +948,8 @@ class FlatMeta:
                               2 + MAX_META_ROWS + nr].astype(np.int64)
         # tune=hq: summed per-MB effective qp (0 = uniform slice qp)
         self.qp_sum = int(words[META_QP_SUM_WORD])
+        # ... and a P frame's macroblocks coded I_16x16 (0 elsewhere)
+        self.p_intra_mbs = int(words[META_P_INTRA_WORD])
 
 
 def slice_header_slots(nr: int, nc_mb: int, *, frame_num: int,

@@ -35,6 +35,10 @@ import numpy as np
 from ..bitstream.h264_entropy import _CBP_INTER_BY_CODENUM
 from .cavlc_device import blocks_first, code_blocks, nc_grid, pack_frame
 from .h264_inter import RING_DONATE
+# The tunes whose per-frame step takes the slice qp as a TRACED scalar: the
+# program's own word on it, which the benchmark's hq readers hold it to
+# (benchmark/layer_metrics/_hq.py).
+from .quant import TRACED_QP_TUNES as DYNQP_STEP_TUNES  # noqa: F401
 
 _I32 = np.int32
 
@@ -292,11 +296,13 @@ def p_frame_block_slots(out: dict):
     return values, lengths, cbp, mv
 
 
-@functools.partial(jax.jit, static_argnames=("qp", "tune", "p_intra"),
+@functools.partial(jax.jit, static_argnames=("qp", "tune", "p_intra",
+                                             "with_qp_eff"),
                    donate_argnames=RING_DONATE)
 def encode_p_cavlc_frame(y, cb, cr, ref_y, ref_cb, ref_cr,
                          hdr_vals, hdr_lens, qp: int, tune: str = "off",
-                         next_y=None, p_intra: bool = False):
+                         next_y=None, p_intra: bool = False,
+                         with_qp_eff: bool = False):
     """Fused P-frame device stage: ME/MC/residual (ops/h264_inter) +
     device CAVLC.  Returns (flat, recon_y, recon_cb, recon_cr, mv, nnz,
     levels) — only ``flat``'s prefix crosses the host link; the recon
@@ -307,19 +313,23 @@ def encode_p_cavlc_frame(y, cb, cr, ref_y, ref_cb, ref_cr,
     tensors the host entropy coder would need, so a flat-cap overflow
     falls back to host CAVLC of the SAME levels without ever re-reading
     the (now dead) reference planes — the levels are lazy device arrays
-    and cross the link only on that rare path."""
+    and cross the link only on that rare path.  ``with_qp_eff`` (tune=hq
+    with the loop filter on): ``levels["qp_eff"]`` is the (R, C) plane of
+    effective qps the filter's thresholds follow."""
     from . import h264_inter
 
     out = h264_inter.encode_p_frame.__wrapped__(
         y, cb, cr, ref_y, ref_cb, ref_cr, qp, tune, next_y, p_intra)
-    return _finish_p(out, hdr_vals, hdr_lens, slice_qp=qp)
+    return _finish_p(out, hdr_vals, hdr_lens, slice_qp=qp,
+                     with_qp_eff=with_qp_eff)
 
 
-#: qp-traced twin (tune="off" only) — see
+#: qp-traced twin (:data:`DYNQP_STEP_TUNES`) — see
 #: cavlc_device.encode_intra_cavlc_frame_yuv_dynqp.
 encode_p_cavlc_frame_dynqp = jax.jit(
     encode_p_cavlc_frame.__wrapped__,
-    static_argnames=("tune", "p_intra"), donate_argnames=RING_DONATE)
+    static_argnames=("tune", "p_intra", "with_qp_eff"),
+    donate_argnames=RING_DONATE)
 
 
 def encode_p_cavlc_frame_padded(y, cb, cr, ref_y_pad, ref_cb_pad,
@@ -339,7 +349,8 @@ def encode_p_cavlc_frame_padded(y, cb, cr, ref_y_pad, ref_cb_pad,
     return _finish_p(out, hdr_vals, hdr_lens, slice_qp=qp)
 
 
-def _finish_p(out: dict, hdr_vals, hdr_lens, slice_qp: int = None):
+def _finish_p(out: dict, hdr_vals, hdr_lens, slice_qp: int = None,
+              with_qp_eff: bool = False):
     import jax.numpy as jnp
     import numpy as np
 
@@ -349,13 +360,14 @@ def _finish_p(out: dict, hdr_vals, hdr_lens, slice_qp: int = None):
         values, lengths, cbp, mv = p_frame_block_slots(out)
         mb_intra = out.get("mb_intra")
         qp_se = None
-        qp_sum = None
+        qp_sum = eff = None
         if "qp_map" in out:
             from . import aq
-            codes = cbp > 0            # skip MBs have cbp == 0 too
-            if mb_intra is not None:   # I_16x16 always codes mb_qp_delta
-                codes = codes | jnp.asarray(mb_intra, bool)
-            eff, delta = aq.qp_chain(out["qp_map"], codes, int(slice_qp))
+            with jax.named_scope("dngd.aq"):
+                codes = cbp > 0        # skip MBs have cbp == 0 too
+                if mb_intra is not None:   # I_16x16 always codes mb_qp_delta
+                    codes = codes | jnp.asarray(mb_intra, bool)
+                eff, delta = aq.qp_chain(out["qp_map"], codes, slice_qp)
             from .cavlc_device import se_slots
             sv, sl = se_slots(delta)
             qp_se = (sv, jnp.where(codes, sl, 0))
@@ -363,8 +375,10 @@ def _finish_p(out: dict, hdr_vals, hdr_lens, slice_qp: int = None):
         hv6, hl6, tv, tl, _skip = p_mb_header_slots(mv, cbp, qp_se=qp_se,
                                                     mb_intra=mb_intra)
     with jax.named_scope("dngd.pack"):
-        flat, _ = pack_frame(values, lengths, hv6, hl6, hdr_vals,
-                             hdr_lens, tv, tl, qp_sum=qp_sum)
+        flat, _ = pack_frame(
+            values, lengths, hv6, hl6, hdr_vals, hdr_lens, tv, tl,
+            qp_sum=qp_sum,
+            p_intra_mbs=None if mb_intra is None else jnp.sum(mb_intra))
     with jax.named_scope("dngd.deblock_bs"):
         # per-4x4 coded-coefficient flags in raster [by][bx] order — the
         # deblocking bS=2 input (ops/h264_deblock.p_bs)
@@ -382,6 +396,8 @@ def _finish_p(out: dict, hdr_vals, hdr_lens, slice_qp: int = None):
                                   "cr_dc", "cr_ac")}
     if "qp_map" in out:
         levels["qp_map"] = out["qp_map"]
+        if with_qp_eff:
+            levels["qp_eff"] = eff
     if mb_intra is not None:       # I16-in-P tensors for the same fallback
         for k in ("mb_intra", "i16_dc", "i16_ac"):
             levels[k] = out[k]

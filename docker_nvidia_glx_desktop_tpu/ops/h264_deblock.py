@@ -205,17 +205,33 @@ _BS_V, _BS_V_C = 32, 80             # luma x=4,8,12; chroma x=4
 _BS_H, _BS_H_C = 96, 144            # luma y=4,8,12; chroma y=4
 _BS_ROWS = 160
 _COLS = 8                           # MB columns a grid step filters
+# With a qp a macroblock the thresholds belong to an edge: a second tile of
+# the bS tile's shape carries, line for line, the edge's thresholds as ONE
+# word, alpha | beta << 8 | tc0[k] << 13 + 5k (8, 5 and three times 5
+# bits), which the kernel reads as it reads bS.
+_THR_BETA, _THR_TC0 = 8, (13, 18, 23)
 
 
-def _edges_kernel(thr, y_in, c_in, bs_in, y_ahead, c_ahead, y_out, c_out,
-                  yw, cw, y_left, c_left):
+def _thr_of_word(w):
+    """(alpha, beta, tc0[3]) of threshold words, as `_filter_lines` takes
+    them."""
+    return (w & 0xFF, (w >> _THR_BETA) & 0x1F,
+            tuple((w >> at) & 0x1F for at in _THR_TC0))
+
+
+def _edges_kernel(thr, y_in, c_in, bs_in, *refs, per_edge: bool = False):
     """One block of MB columns: copy it and the first four pixel columns
     of the block to its right into the work tiles, run the chain, hand
     the (now half-filtered) columns of the right neighbour to the next
-    grid step."""
+    grid step.  ``per_edge``: the thresholds come line by line from a tile
+    behind the bS tile (``_thr_tiles``) and not from the ten scalars."""
     import jax
     from jax.experimental import pallas as pl
 
+    thr_in = None
+    if per_edge:
+        thr_in, *refs = refs
+    y_ahead, c_ahead, y_out, c_out, yw, cw, y_left, c_left = refs
     n = y_in.shape[0]
     lum = (thr[0], thr[1], (thr[2], thr[3], thr[4]), False)
     chrm = (thr[5], thr[6], (thr[7], thr[8], thr[9]), True)
@@ -236,14 +252,21 @@ def _edges_kernel(thr, y_in, c_in, bs_in, y_ahead, c_ahead, y_out, c_out,
 
     def column(g, _):
         bs = bs_in.at[g]
+
+        def edge(p, q, at, par):
+            """`_filter_lines` over the 16 lines whose bS is row ``at``."""
+            if per_edge:
+                par = (*_thr_of_word(thr_in.at[g][pl.ds(at, 16), :]),
+                       par[3])
+            return _filter_lines(p, q, bs[pl.ds(at, 16), :], *par)
+
         for w, ncols, keep, at_v, at_h, at_next, par in planes:
             mb, right = w.at[g], w.at[g + 1]
             # vertical edges inside the MB, on pixel-column tiles
             cols = [mb[pl.ds(x * 16, 16), :] for x in range(ncols)]
             for e, x in enumerate(range(4, ncols, 4)):
-                pn, qn = _filter_lines(
-                    [cols[x - 1 - k] for k in range(4)], cols[x:x + 4],
-                    bs[pl.ds(at_v + 16 * e, 16), :], *par)
+                pn, qn = edge([cols[x - 1 - k] for k in range(4)],
+                              cols[x:x + 4], at_v + 16 * e, par)
                 for k in range(keep):
                     cols[x - 1 - k], cols[x + k] = pn[k], qn[k]
             for x in range(4 - keep, ncols - 4 + keep):
@@ -253,18 +276,17 @@ def _edges_kernel(thr, y_in, c_in, bs_in, y_ahead, c_ahead, y_out, c_out,
             lines = [mb[pl.ds(l, 16, stride=ncols), :]
                      for l in range(ncols)]
             for e, yy in enumerate(range(4, ncols, 4)):
-                pn, qn = _filter_lines(
-                    [lines[yy - 1 - k] for k in range(4)], lines[yy:yy + 4],
-                    bs[pl.ds(at_h + 16 * e, 16), :], *par)
+                pn, qn = edge([lines[yy - 1 - k] for k in range(4)],
+                              lines[yy:yy + 4], at_h + 16 * e, par)
                 for k in range(keep):
                     lines[yy - 1 - k], lines[yy + k] = pn[k], qn[k]
             for l in range(4 - keep, ncols - 4 + keep):
                 mb[pl.ds(l, 16, stride=ncols), :] = lines[l]
             # the MB edge to the right neighbour
-            pn, qn = _filter_lines(
+            pn, qn = edge(
                 [mb[pl.ds((ncols - 1 - k) * 16, 16), :] for k in range(4)],
                 [right[pl.ds(k * 16, 16), :] for k in range(4)],
-                bs[pl.ds(at_next, 16), :], *par)
+                at_next, par)
             for k in range(keep):
                 mb[pl.ds((ncols - 1 - k) * 16, 16), :] = pn[k]
                 right[pl.ds(k * 16, 16), :] = qn[k]
@@ -276,14 +298,17 @@ def _edges_kernel(thr, y_in, c_in, bs_in, y_ahead, c_ahead, y_out, c_out,
     c_out[...] = cw[:n]
 
 
-def _bs_tiles(nnz_blk, mv, nr: int, nc: int):
+def _bs_tiles(nnz_blk, mv, nr: int, nc: int, mb_intra=None):
     """bS of every edge of a frame as the kernel reads it:
-    (nc, _BS_ROWS, nr) int32 (see the row map above)."""
+    (nc, _BS_ROWS, nr) int32 (see the row map above).  ``mb_intra``
+    (R, C) bool: the I_16x16 macroblocks of a P picture (3 inside one, 4
+    at a macroblock edge with one on either side: spec 8.7.2.1)."""
     import jax.numpy as jnp
 
-    if nnz_blk is None:                 # intra: 4 at MB edges, 3 inside
+    if nnz_blk is None or mb_intra is not None:
         col = jnp.arange(nc, dtype=jnp.int32)[:, None, None]
         row = jnp.arange(_BS_ROWS, dtype=jnp.int32)[None, :, None]
+    if nnz_blk is None:                 # intra: 4 at MB edges, 3 inside
         bs = jnp.where(row < _BS_V, jnp.where(col < nc - 1, 4, 0), 3)
         return jnp.broadcast_to(bs, (nc, _BS_ROWS, nr))
     n = nnz_blk.astype(jnp.int32).transpose(1, 2, 3, 0)   # (C, by, bx, R)
@@ -296,15 +321,78 @@ def _bs_tiles(nnz_blk, mv, nr: int, nc: int):
                     jnp.where(mvd[:, None], 1, 0))
     nxt = per4(jnp.pad(nxt, ((0, 1), (0, 0), (0, 0))))     # none at the end
     both = lambda a: jnp.concatenate([a[:, 0::2]] * 2, axis=1)  # (Cb, Cr)
-    return jnp.concatenate(
+    bs = jnp.concatenate(
         [nxt, both(nxt), *v_int, both(v_int[1]), *h_int,
          jnp.repeat(h_int[1][:, 0::2], 2, axis=1)], axis=1)
+    if mb_intra is None:
+        return bs
+    it = jnp.asarray(mb_intra, bool).T[:, None, :]             # (C, 1, R)
+    either = it | jnp.pad(it[1:], ((0, 1), (0, 0), (0, 0)))    # c or c + 1
+    return jnp.where(row < _BS_V,
+                     jnp.where(either & (col < nc - 1), 4, bs),
+                     jnp.where(it, 3, bs))
 
 
-def _deblock_frame_kernel(y, cb, cr, lum, chrm, nnz_blk, mv):
+def _lookup(table, q):
+    """``table[q]`` for a table of 52 words and a plane of indices, as a
+    select and a sum over the table's rows: elementwise work on 52 planes.
+    As gathers the twenty lookups of a 1080p picture's four planes cost the
+    chip 1.4 ms a frame, nine tenths of the filter's program (my chip run,
+    PR 48)."""
+    import jax.numpy as jnp
+
+    t = jnp.asarray(table, jnp.int32).reshape((-1,) + (1,) * q.ndim)
+    j = jnp.arange(t.shape[0], dtype=jnp.int32).reshape(t.shape)
+    return jnp.sum(jnp.where(q[None] == j, t, 0), axis=0)
+
+
+def _edge_thr_words(qp_eff):
+    """The thresholds an edge is filtered by (spec 8.7.2.2: looked up by
+    qPav), a word a macroblock, from the (R, C) plane of effective QPY:
+    inside a macroblock by its own QP, at the edge to its RIGHT neighbour
+    by the rounded mean of the two, for luma and, through each side's QPC,
+    for chroma.  Returns (luma inside, luma right edge, chroma inside,
+    chroma right edge), (R, C) each; the last column has no right edge
+    (its bS is 0) and repeats itself."""
+    import jax.numpy as jnp
+
+    from . import quant as _q
+
+    alpha_t, beta_t, tc0_t = load_tables()
+    words = alpha_t | (beta_t << _THR_BETA)
+    for k, at in enumerate(_THR_TC0):
+        words = words | (tc0_t[:, k] << at)
+    qy = jnp.clip(jnp.asarray(qp_eff, jnp.int32), 0, 51)
+    qc = _lookup(_q.QPC_TABLE, qy)
+    right = lambda a: jnp.concatenate([a[:, 1:], a[:, -1:]], axis=1)
+    return tuple(_lookup(words, q) for q in (
+        qy, (qy + right(qy) + 1) >> 1, qc, (qc + right(qc) + 1) >> 1))
+
+
+def _thr_tiles(qp_eff):
+    """The threshold word of every edge line, a tile of the bS tile's
+    shape, (nc, _BS_ROWS, nr).  The tables are read a macroblock
+    (`_edge_thr_words`), the lines only choose among what their macroblock
+    looked up."""
+    import jax.numpy as jnp
+
+    row = jnp.arange(_BS_ROWS, dtype=jnp.int32)[None, :, None]
+    at_next = row < _BS_V
+    chroma = (((row >= _BS_NEXT_C) & (row < _BS_V))
+              | ((row >= _BS_V_C) & (row < _BS_H)) | (row >= _BS_H_C))
+    inside, nxt, c_inside, c_nxt = (
+        w.T[:, None, :] for w in _edge_thr_words(qp_eff))      # (C, 1, R)
+    return jnp.where(at_next, jnp.where(chroma, c_nxt, nxt),
+                     jnp.where(chroma, c_inside, inside))
+
+
+def _deblock_frame_kernel(y, cb, cr, lum, chrm, nnz_blk, mv, qp_eff=None,
+                          mb_intra=None):
     """`deblock_frame` on the TPU: XLA lays planes and bS out (and back),
     one Pallas kernel filters every edge.  The thresholds reach it as ten
-    scalars, so one kernel serves a static and a traced qp."""
+    scalars, so one kernel serves a static and a traced qp; with a qp a
+    macroblock (``qp_eff``) they reach it as one more tile, a word a line
+    of an edge."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -320,7 +408,11 @@ def _deblock_frame_kernel(y, cb, cr, lum, chrm, nnz_blk, mv):
     with jax.named_scope("dngd.deblock_bs"):
         thr = jnp.stack([jnp.asarray(v, jnp.int32) for v in
                          (*lum[:2], *lum[2], *chrm[:2], *chrm[2])])
-        bs = pad(_bs_tiles(nnz_blk, mv, nr, nc))
+        bs = pad(_bs_tiles(nnz_blk, mv, nr, nc, mb_intra))
+        edge_thr = ()
+        if qp_eff is not None:
+            with jax.named_scope("dngd.deblock_thr"):
+                edge_thr = (pad(_thr_tiles(qp_eff)),)
     with jax.named_scope("dngd.deblock_tile"):
         # (MB row, line, pixel column) -> (pixel column, line, MB row)
         turn = lambda p, n: (p.astype(jnp.int32).reshape(nr, n, -1)
@@ -340,20 +432,22 @@ def _deblock_frame_kernel(y, cb, cr, lum, chrm, nnz_blk, mv):
             jnp.minimum((i + 1) * _COLS, nb * _COLS - 1), 0, r))
     with jax.named_scope("dngd.deblock_edges"):
         yt, ct = pl.pallas_call(
-            _edges_kernel,
+            (functools.partial(_edges_kernel, per_edge=True) if edge_thr
+             else _edges_kernel),
             name="dngd_deblock_edges",
             out_shape=(jax.ShapeDtypeStruct(yt.shape, jnp.int32),
                        jax.ShapeDtypeStruct(ct.shape, jnp.int32)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=(nl, nb),
-                in_specs=[blk(256), blk(128), blk(_BS_ROWS), ahead, ahead],
+                in_specs=[blk(256), blk(128), blk(_BS_ROWS),
+                          *[blk(_BS_ROWS) for _ in edge_thr], ahead, ahead],
                 out_specs=(blk(256), blk(128)),
                 scratch_shapes=[
                     pltpu.VMEM((_COLS + 1, 256, 128), jnp.int32),
                     pltpu.VMEM((_COLS + 1, 128, 128), jnp.int32),
                     pltpu.VMEM((64, 128), jnp.int32),
                     pltpu.VMEM((64, 128), jnp.int32)]),
-        )(thr, yt, ct, bs, yt, ct)
+        )(thr, yt, ct, bs, *edge_thr, yt, ct)
 
     with jax.named_scope("dngd.deblock_tile"):
         back = lambda t: (t.astype(jnp.uint8).transpose(2, 1, 0)
@@ -391,7 +485,8 @@ import jax as _jax
 
 
 @functools.partial(_jax.jit, static_argnames=("qp",))
-def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None):
+def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None, qp_eff=None,
+                  mb_intra=None):
     """Device loop filter for one frame (slice-per-row, idc=2 edges).
 
     y (H, W), cb/cr (H/2, W/2) uint8 recon planes.  Intra frames pass
@@ -399,7 +494,16 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None):
     nnz_blk (R, C, 4, 4) bool and mv (R, C, 2) quarter-pel.  ``qp`` is
     static here and traced in :data:`deblock_frame_dynqp`.  Returns
     filtered uint8 planes, byte-identical to :func:`deblock_frame_ref`
-    on either schedule (tested)."""
+    on either schedule (tested).
+
+    A picture with a qp a macroblock (ENCODER_TUNE=hq) passes ``qp_eff``,
+    the (R, C) plane of EFFECTIVE QPY (``aq.qp_chain``: a macroblock whose
+    syntax carries no mb_qp_delta, a skipped one included, holds the one
+    before it; the plane asked for is not what a decoder filters by), and
+    a P picture with I_16x16 macroblocks their (R, C) flags ``mb_intra``.
+    The thresholds then belong to an edge (`_edge_thr_words`) and the bS knows
+    the intra rules; ``qp`` is not read.  With neither the program is the
+    one it was (a branch at trace time)."""
     import jax
     import jax.numpy as jnp
 
@@ -423,14 +527,19 @@ def deblock_frame(y, cb, cr, qp: int, nnz_blk=None, mv=None):
             a_c, b_c, t_c = alpha_a[qp_c], beta_a[qp_c], tc0_a[qp_c]
     lum, chrm = (a_l, b_l, t_l), (a_c, b_c, t_c)
     if _jax.default_backend() == "tpu":
-        return _deblock_frame_kernel(y, cb, cr, lum, chrm, nnz_blk, mv)
-    return _deblock_frame_scan(y, cb, cr, lum, chrm, nnz_blk, mv)
+        return _deblock_frame_kernel(y, cb, cr, lum, chrm, nnz_blk, mv,
+                                     qp_eff, mb_intra)
+    return _deblock_frame_scan(y, cb, cr, lum, chrm, nnz_blk, mv, qp_eff,
+                               mb_intra)
 
 
-def _deblock_frame_scan(y, cb, cr, lum, chrm, nnz_blk, mv):
+def _deblock_frame_scan(y, cb, cr, lum, chrm, nnz_blk, mv, qp_eff=None,
+                        mb_intra=None):
     """`deblock_frame` as a `lax.scan` over MB columns, one column a step
     (the CPU backend measured wider steps slower); ``lum`` / ``chrm`` are
-    (alpha, beta, tc0[3])."""
+    (alpha, beta, tc0[3]), and with ``qp_eff`` every column brings its own
+    four of them: of the edge to its left neighbour and of its inside,
+    luma and chroma, a row of the picture each."""
     import jax
     import jax.numpy as jnp
 
@@ -453,11 +562,17 @@ def _deblock_frame_scan(y, cb, cr, lum, chrm, nnz_blk, mv):
                  (jnp.abs(mv[:, 1:] - mv[:, :-1]) >= 4).any(-1)], axis=1)
             bs_mb0 = jnp.where((left_nnz | nnz16y[:, :, :, 0]) > 0, 2,
                                jnp.where(mvd[:, :, None], 1, 0))
-            bs_mb0 = bs_mb0.at[:, 0].set(0)
             nnz16x = jnp.repeat(nnz_blk.astype(jnp.int32), 4, axis=3)
             bs_h_int = jnp.stack(
                 [(nnz16x[:, :, by - 1] | nnz16x[:, :, by]) * 2
                  for by in (1, 2, 3)], axis=2)                 # (R, C, 3, 16)
+            if mb_intra is not None:    # 3 inside I_16x16, 4 at its edges
+                it = jnp.asarray(mb_intra, bool)
+                either = it | jnp.pad(it[:, :-1], ((0, 0), (1, 0)))
+                bs_mb0 = jnp.where(either[:, :, None], 4, bs_mb0)
+                bs_v_int = jnp.where(it[:, :, None, None], 3, bs_v_int)
+                bs_h_int = jnp.where(it[:, :, None, None], 3, bs_h_int)
+            bs_mb0 = bs_mb0.at[:, 0].set(0)
             # scan-major layouts (C leading)
             bs_v_int = jnp.moveaxis(bs_v_int, 1, 0)            # (C, R, 3, 16)
             bs_mb0 = jnp.moveaxis(bs_mb0, 1, 0)                # (C, R, 16)
@@ -475,8 +590,22 @@ def _deblock_frame_scan(y, cb, cr, lum, chrm, nnz_blk, mv):
             cr.astype(jnp.int32).reshape(nr, 8, nc, 8).transpose(0, 2, 1, 3),
             1, 0)
 
+    thr_xs = ()
+    if qp_eff is not None:
+        with jax.named_scope("dngd.deblock_thr"):
+            # a column's LEFT edge is its left neighbour's right edge
+            left = lambda a: jnp.pad(a[:, :-1], ((0, 0), (1, 0)))
+            li, ln, ci, cn = _edge_thr_words(qp_eff)
+            thr_xs = tuple(w.T for w in (left(ln), li, left(cn), ci))  # (C, R)
+
     def col_step(carry, xs):
         yl, cbl, crl = carry            # left MB last-4 columns, post-H
+        lum_l = lum_i = lum
+        chrm_l = chrm_i = chrm
+        if thr_xs:
+            *xs, t_ll, t_li, t_cl, t_ci = xs
+            lum_l, lum_i, chrm_l, chrm_i = (
+                _thr_of_word(w[:, None]) for w in (t_ll, t_li, t_cl, t_ci))
         if intra:
             ymb, cbmb, crmb, idx = xs
             bs0 = jnp.full((nr, 16), 4, jnp.int32)
@@ -494,24 +623,24 @@ def _deblock_frame_scan(y, cb, cr, lum, chrm, nnz_blk, mv):
         # edges were filtered in the previous step) ---
         with jax.named_scope("dngd.deblock_v"):
             wide = jnp.concatenate([yl, ymb], axis=-1)     # (R, 16, 20)
-            wide = _edge_v_mb(wide, 4, bs0, *lum, False)
+            wide = _edge_v_mb(wide, 4, bs0, *lum_l, False)
             for e, x in enumerate((4, 8, 12)):
-                wide = _edge_v_mb(wide, 4 + x, bsv[e], *lum, False)
+                wide = _edge_v_mb(wide, 4 + x, bsv[e], *lum_i, False)
             left_fin = wide[..., :4]    # left MB cols 12..15, FINAL
             own = wide[..., 4:]
         with jax.named_scope("dngd.deblock_h"):
             for e, yy_ in enumerate((4, 8, 12)):
-                own = _edge_h_mb(own, yy_, bsh[e], *lum, False)
+                own = _edge_h_mb(own, yy_, bsh[e], *lum_i, False)
 
         # --- chroma: MB edge + internal x=4 (luma x=8), h y=4 (luma 8) --
         def chroma_mb(mbp, left):
             with jax.named_scope("dngd.deblock_v"):
                 w2 = jnp.concatenate([left, mbp], axis=-1)  # (R, 8, 12)
-                w2 = _edge_v_mb(w2, 4, bs0[:, 0::2], *chrm, True)
-                w2 = _edge_v_mb(w2, 8, bsv[1][:, 0::2], *chrm, True)
+                w2 = _edge_v_mb(w2, 4, bs0[:, 0::2], *chrm_l, True)
+                w2 = _edge_v_mb(w2, 8, bsv[1][:, 0::2], *chrm_i, True)
                 lf, ownp = w2[..., :4], w2[..., 4:]
             with jax.named_scope("dngd.deblock_h"):
-                ownp = _edge_h_mb(ownp, 4, bsh[1][:, 0::2], *chrm, True)
+                ownp = _edge_h_mb(ownp, 4, bsh[1][:, 0::2], *chrm_i, True)
             return lf, ownp
 
         cbl_fin, cb_own = chroma_mb(cbmb, cbl)
@@ -534,7 +663,7 @@ def _deblock_frame_scan(y, cb, cr, lum, chrm, nnz_blk, mv):
         else:
             xs = (ymbs, cbm, crm, bs_v_int, bs_mb0, bs_h_int,
                   jnp.arange(nc, dtype=jnp.int32))
-        carry, outs = jax.lax.scan(col_step, init, xs)
+        carry, outs = jax.lax.scan(col_step, init, xs + thr_xs)
         lf3, own13, cblf, cbo6, crlf, cro6 = outs
 
     def assemble(own_first, later_last, tailc, sub):

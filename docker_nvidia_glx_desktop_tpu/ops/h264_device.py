@@ -588,7 +588,7 @@ def encode_intra_frame_yuv(y, cb, cr, qp: int, i16_modes: str = "auto",
     cr = jnp.asarray(cr).astype(jnp.int32)
     if tune not in ("off", "hq", "hq_noaq"):
         raise ValueError(f"unknown tune {tune!r}")
-    quant.require_static_qp_unless_off(qp, tune)
+    quant.require_static_qp_for(qp, tune)
     pad_h, pad_w = y.shape
     nr, nc = pad_h // 16, pad_w // 16
     allow_i4 = i16_modes in ("auto", "full")
@@ -601,7 +601,8 @@ def encode_intra_frame_yuv(y, cb, cr, qp: int, i16_modes: str = "auto",
     qp_map = None
     if tune == "hq":
         from . import aq
-        qp_map = aq.qp_plane(y, qp, next_y)             # (R, C) absolute
+        with jax.named_scope("dngd.aq"):
+            qp_map = aq.qp_plane(y, qp, next_y)         # (R, C) absolute
         qpmbs = jnp.moveaxis(qp_map, 0, 1)              # (C, R) scan axis
         qcmbs = jnp.moveaxis(quant.chroma_qp_v(qp_map), 0, 1)
     else:

@@ -343,7 +343,7 @@ def encode_p_frame_padded_ref(y, cb, cr, ref_y_pad, ref_cb_pad, ref_cr_pad,
         ref_cr_pad = jnp.asarray(ref_cr_pad).astype(jnp.int32)
         if tune not in ("off", "hq", "hq_noaq"):
             raise ValueError(f"unknown tune {tune!r}")
-        quant.require_static_qp_unless_off(qp, tune)
+        quant.require_static_qp_for(qp, tune)
         pad_h, pad_w = y.shape
         nr, nc = pad_h // 16, pad_w // 16
 
@@ -356,7 +356,8 @@ def encode_p_frame_padded_ref(y, cb, cr, ref_y_pad, ref_cb_pad, ref_cr_pad,
         else:
             from . import aq
             if tune == "hq":
-                qp_map = aq.qp_plane(y, qp, next_y)         # (R, C)
+                with jax.named_scope("dngd.aq"):
+                    qp_map = aq.qp_plane(y, qp, next_y)     # (R, C)
                 qp_q = qp_map
                 qp_c = quant.chroma_qp_v(qp_map)
                 lam_d = aq.lam_mode(qp_map)                 # (R, C) float32
@@ -816,6 +817,7 @@ def encode_p_frame_padded_ref(y, cb, cr, ref_y_pad, ref_cb_pad, ref_cr_pad,
         out["qp_map"] = qp_map        # (R, C) absolute per-MB qp (tune=hq)
     if is_intra is not None:
         out["mb_intra"] = is_intra            # (R, C) bool
-        out["i16_dc"] = i16(i16_dc_zz)        # (R, C, 16) zigzag
-        out["i16_ac"] = i16(i16_ac_zz)        # (R, C, 16, 15) zigzag
+        with jax.named_scope("dngd.tq"):
+            out["i16_dc"] = i16(i16_dc_zz)    # (R, C, 16) zigzag
+            out["i16_ac"] = i16(i16_ac_zz)    # (R, C, 16, 15) zigzag
     return out

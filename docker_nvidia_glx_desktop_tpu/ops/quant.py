@@ -132,10 +132,18 @@ def chroma_qp_any(qp_y):
     return chroma_qp(qp_y) if _is_static_qp(qp_y) else chroma_qp_v(qp_y)
 
 
-def require_static_qp_unless_off(qp, tune: str) -> None:
-    """A traced slice qp serves tune="off" only: the hq tiers derive
-    compile-time floats (lambda) and the AQ plane from a Python qp."""
-    if tune != "off" and not _is_static_qp(qp):
+#: The tunes whose stages take the slice qp as a TRACED scalar (one compiled
+#: program for every rung of the rate ladder): "off", and "hq", where
+#: everything downstream of the slice qp is the (R, C) plane ``aq.qp_plane``
+#: makes of it (quantizers, chroma qp and lambdas are per macroblock
+#: already).  ``hq_noaq`` is not among them: its lambdas are compile-time
+#: floats.
+TRACED_QP_TUNES = ("off", "hq")
+
+
+def require_static_qp_for(qp, tune: str) -> None:
+    """A Python qp for every tune outside :data:`TRACED_QP_TUNES`."""
+    if tune not in TRACED_QP_TUNES and not _is_static_qp(qp):
         raise TypeError(f"tune={tune!r} needs a static (Python int) qp")
 
 

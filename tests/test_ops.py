@@ -1,5 +1,6 @@
 """Golden tests for the transform/quant/zigzag ops (SURVEY.md §4 unit tier)."""
 
+import pytest
 import numpy as np
 import scipy.fft
 
@@ -130,3 +131,197 @@ class TestJPEGQuant:
         lev = np.asarray(quant.jpeg_quantize(c, table))
         deq = np.asarray(quant.jpeg_dequantize(lev, table))
         assert np.abs(deq - c).max() <= table.max() / 2 + 1
+
+
+# -- the loop filter with a qp a macroblock (ENCODER_TUNE=hq, PR 48) ---------
+# A plain per-line filter by the text of spec 8.7, with Tables 8-16 and 8-17
+# written out: nothing of it comes from ops/.
+
+_ALPHA = [0] * 16 + [4, 4, 5, 6, 7, 8, 9, 10, 12, 13, 15, 17, 20, 22, 25, 28,
+                     32, 36, 40, 45, 50, 56, 63, 71, 80, 90, 101, 113, 127,
+                     144, 162, 182, 203, 226, 255, 255]
+_BETA = [0] * 16 + [2, 2, 2, 3, 3, 3, 3, 4, 4, 4, 6, 6, 7, 7, 8, 8, 9, 9, 10,
+                    10, 11, 11, 12, 12, 13, 13, 14, 14, 15, 15, 16, 16, 17,
+                    17, 18, 18]
+_TC0 = [(0, 0, 0)] * 17 + [
+    (0, 0, 1), (0, 0, 1), (0, 0, 1), (0, 0, 1), (0, 1, 1), (0, 1, 1),
+    (1, 1, 1), (1, 1, 1), (1, 1, 1), (1, 1, 1), (1, 1, 2), (1, 1, 2),
+    (1, 1, 2), (1, 1, 2), (1, 2, 3), (1, 2, 3), (2, 2, 3), (2, 2, 4),
+    (2, 3, 4), (2, 3, 4), (3, 3, 5), (3, 4, 6), (3, 4, 6), (4, 5, 7),
+    (4, 5, 8), (4, 6, 9), (5, 7, 10), (6, 8, 11), (6, 8, 13), (7, 10, 14),
+    (8, 11, 16), (9, 12, 18), (10, 13, 20), (11, 15, 23), (13, 17, 25)]
+_QPC = list(range(30)) + [29, 30, 31, 32, 32, 33, 34, 34, 35, 35, 36, 36, 37,
+                          37, 37, 38, 38, 38, 39, 39, 39, 39]
+
+
+def _plain_line(px, bs, qp, chroma):
+    """One line of one edge, 8.7.2.3 / 8.7.2.4: ``px`` holds p3 p2 p1 p0 q0 q1
+    q2 q3 (chroma: p1 p0 q0 q1) and is filtered in place."""
+    n = len(px) // 2
+    p = [int(px[n - 1 - k]) for k in range(n)]
+    q = [int(px[n + k]) for k in range(n)]
+    alpha, beta = _ALPHA[qp], _BETA[qp]
+    if bs == 0 or not (abs(p[0] - q[0]) < alpha and abs(p[1] - p[0]) < beta
+                       and abs(q[1] - q[0]) < beta):
+        return
+    clip = lambda lo, hi, v: max(lo, min(hi, v))
+    if bs < 4:
+        tc0 = _TC0[qp][bs - 1]
+        ap = not chroma and abs(p[2] - p[0]) < beta
+        aq = not chroma and abs(q[2] - q[0]) < beta
+        tc = tc0 + 1 if chroma else tc0 + ap + aq
+        d = clip(-tc, tc, (((q[0] - p[0]) << 2) + (p[1] - q[1]) + 4) >> 3)
+        px[n - 1], px[n] = clip(0, 255, p[0] + d), clip(0, 255, q[0] - d)
+        if ap:
+            px[n - 2] = p[1] + clip(-tc0, tc0, (
+                p[2] + ((p[0] + q[0] + 1) >> 1) - (p[1] << 1)) >> 1)
+        if aq:
+            px[n + 1] = q[1] + clip(-tc0, tc0, (
+                q[2] + ((p[0] + q[0] + 1) >> 1) - (q[1] << 1)) >> 1)
+        return
+    small = abs(p[0] - q[0]) < (alpha >> 2) + 2
+    if not chroma and small and abs(p[2] - p[0]) < beta:
+        px[n - 1] = (p[2] + 2 * p[1] + 2 * p[0] + 2 * q[0] + q[1] + 4) >> 3
+        px[n - 2] = (p[2] + p[1] + p[0] + q[0] + 2) >> 2
+        px[n - 3] = (2 * p[3] + 3 * p[2] + p[1] + p[0] + q[0] + 4) >> 3
+    else:
+        px[n - 1] = (2 * p[1] + p[0] + q[1] + 2) >> 2
+    if not chroma and small and abs(q[2] - q[0]) < beta:
+        px[n] = (p[1] + 2 * p[0] + 2 * q[0] + 2 * q[1] + q[2] + 4) >> 3
+        px[n + 1] = (p[0] + q[0] + q[1] + q[2] + 2) >> 2
+        px[n + 2] = (2 * q[3] + 3 * q[2] + q[1] + q[0] + p[0] + 4) >> 3
+    else:
+        px[n] = (2 * q[1] + q[0] + p[1] + 2) >> 2
+
+
+def _plain_deblock(y, cb, cr, qpy, intra, nnz, mv):
+    """A picture of one slice a macroblock row under
+    disable_deblocking_filter_idc 2, macroblock by macroblock in raster order
+    (8.7): left macroblock edge and inner vertical edges, then the inner
+    horizontal ones (the top edge is a slice boundary); bS by 8.7.2.1 with
+    one vector a macroblock; thresholds by qPav of the two sides' QPY (chroma:
+    of their QPC)."""
+    y, cb, cr = (a.astype(np.int64).copy() for a in (y, cb, cr))
+    nr, nc = qpy.shape
+
+    def bs_of(r, c, r2, c2, blk, blk2, mb_edge):
+        if intra[r, c] or intra[r2, c2]:
+            return 4 if mb_edge else 3
+        if nnz[r, c][blk] or nnz[r2, c2][blk2]:
+            return 2
+        return int(mb_edge and (abs(mv[r, c] - mv[r2, c2]) >= 4).any())
+
+    for r in range(nr):
+        for c in range(nc):
+            for x4 in range(4):                    # vertical edges
+                if x4 == 0 and c == 0:
+                    continue
+                cp = c - 1 if x4 == 0 else c
+                qp = (int(qpy[r, cp]) + int(qpy[r, c]) + 1) >> 1
+                qc = (_QPC[qpy[r, cp]] + _QPC[qpy[r, c]] + 1) >> 1
+                for line in range(16):
+                    b4 = line // 4
+                    bs = bs_of(r, cp, r, c, (b4, 3 if x4 == 0 else x4 - 1),
+                               (b4, x4), x4 == 0)
+                    x = 16 * c + 4 * x4
+                    _plain_line(y[16 * r + line, x - 4:x + 4], bs, qp, False)
+                    if x4 % 2 == 0 and line % 2 == 0:
+                        for pl in (cb, cr):
+                            _plain_line(pl[8 * r + line // 2,
+                                           x // 2 - 2:x // 2 + 2], bs, qc, True)
+            qp, qc = int(qpy[r, c]), _QPC[qpy[r, c]]
+            for y4 in (1, 2, 3):                   # inner horizontal edges
+                for col in range(16):
+                    b4 = col // 4
+                    bs = bs_of(r, c, r, c, (y4 - 1, b4), (y4, b4), False)
+                    yy = 16 * r + 4 * y4
+                    _plain_line(y[yy - 4:yy + 4, 16 * c + col], bs, qp, False)
+                    if y4 == 2 and col % 2 == 0:
+                        for pl in (cb, cr):
+                            _plain_line(pl[yy // 2 - 2:yy // 2 + 2,
+                                           8 * c + col // 2], bs, qc, True)
+    return tuple(a.astype(np.uint8) for a in (y, cb, cr))
+
+
+class TestLoopFilterPerEdge:
+    H, W = 48, 160                     # 3 x 10 macroblocks: a block of 8 + 2
+
+    def _picture(self, rng):
+        h, w = self.H, self.W
+        y = (np.add.outer(np.arange(h), np.arange(w)) // 3
+             + rng.integers(0, 9, (h, w))).astype(np.uint8)
+        y[:, : w // 2] = rng.integers(60, 120, (h, w // 2))
+        cb = rng.integers(100, 140, (h // 2, w // 2)).astype(np.uint8)
+        cr = rng.integers(90, 160, (h // 2, w // 2)).astype(np.uint8)
+        nr, nc = h // 16, w // 16
+        nnz = rng.integers(0, 2, (nr, nc, 4, 4)).astype(bool)
+        mv = rng.integers(-9, 10, (nr, nc, 2)).astype(np.int32)
+        qpy = rng.integers(22, 46, (nr, nc)).astype(np.int32)
+        intra = rng.integers(0, 4, (nr, nc)) == 0
+        return y, cb, cr, nnz, mv, qpy, intra
+
+    def test_the_written_out_tables_are_the_recovered_ones(self):
+        from docker_nvidia_glx_desktop_tpu.ops import h264_deblock as d
+        alpha, beta, tc0 = d.load_tables()
+        assert alpha.tolist() == _ALPHA and beta.tolist() == _BETA
+        assert [tuple(t) for t in tc0.tolist()] == _TC0
+        assert quant.QPC_TABLE.tolist() == _QPC
+
+    @pytest.mark.parametrize("schedule", ["scan", "kernel"])
+    @pytest.mark.parametrize("kind", ["p", "intra"])
+    def test_a_plane_and_intra_flags_against_the_plain_filter(
+            self, rng, monkeypatch, schedule, kind):
+        """Thresholds by each edge's qPav over the plane, bS 4 and 3 at and
+        inside an intra macroblock: both schedules (the Pallas kernel in
+        interpret mode) against a per-line filter by the spec's text."""
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental.pallas import tpu as pltpu
+
+        from docker_nvidia_glx_desktop_tpu.ops import h264_deblock as d
+
+        y, cb, cr, nnz, mv, qpy, intra = self._picture(rng)
+        if kind == "intra":
+            intra[:] = True
+            kw = {"qp_eff": jnp.asarray(qpy)}
+        else:
+            kw = {"nnz_blk": jnp.asarray(nnz), "mv": jnp.asarray(mv),
+                  "qp_eff": jnp.asarray(qpy), "mb_intra": jnp.asarray(intra)}
+        want = _plain_deblock(y, cb, cr, qpy, intra, nnz, mv)
+        assert (want[0] != y).mean() > 0.03          # the filter did work
+        body = jax.jit(lambda *a, **k: d.deblock_frame.__wrapped__(*a, **k))
+        if schedule == "scan":
+            got = body(y, cb, cr, jnp.int32(30), **kw)
+        else:
+            with monkeypatch.context() as mp, \
+                    pltpu.force_tpu_interpret_mode():
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                got = body(y, cb, cr, jnp.int32(30), **kw)
+        for g, w_ in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), w_)
+
+    @pytest.mark.parametrize("kind", ["p", "intra"])
+    def test_with_neither_the_output_is_todays_bit_for_bit(self, rng, kind):
+        """No plane and no flags: the numpy reference of the one-qp filter
+        (what every tune=off cell runs), and the same bytes as a flat plane
+        with no intra macroblock gives."""
+        import jax.numpy as jnp
+
+        from docker_nvidia_glx_desktop_tpu.ops import h264_deblock as d
+
+        y, cb, cr, nnz, mv, _, _ = self._picture(rng)
+        nr, nc = self.H // 16, self.W // 16
+        if kind == "p":
+            kw = {"nnz_blk": jnp.asarray(nnz), "mv": jnp.asarray(mv)}
+            bs = d.p_bs(nnz, mv)
+            flags = {"mb_intra": jnp.zeros((nr, nc), bool)}
+        else:
+            kw, bs, flags = {}, d.intra_bs(nr, nc), {}
+        want = d.deblock_frame_ref(y, cb, cr, 33, quant.chroma_qp(33), *bs)
+        got = d.deblock_frame(y, cb, cr, 33, **kw)
+        flat = d.deblock_frame_dynqp(
+            y, cb, cr, jnp.int32(20), **kw, **flags,
+            qp_eff=jnp.full((nr, nc), 33, jnp.int32))
+        for g, f, w_ in zip(got, flat, want):
+            np.testing.assert_array_equal(np.asarray(g), w_)
+            np.testing.assert_array_equal(np.asarray(f), w_)

@@ -503,11 +503,49 @@ SCOPES = {
                    "dngd.deblock_edges", "dngd.deblock_v", "dngd.deblock_h",
                    "dngd.mask_scatter"),
     "binarize_band": ("dngd.binarize",),
+    # ENCODER_TUNE=hq served whole under the loop filter (ISSUE 48): the two
+    # encode programs with the plane and the chain under ``aq``, the P
+    # program's decisions, the filter's threshold tile
+    "hq_p": ("dngd.ingest", "dngd.aq", "dngd.me_int", "dngd.me_subpel",
+             "dngd.mc", "dngd.tq", "dngd.recon", "dngd.mode_decision",
+             "dngd.slots", "dngd.pack", "dngd.deblock_bs"),
+    "hq_intra": ("dngd.intra", "dngd.aq", "dngd.slots", "dngd.pack"),
+    "hq_deblock": ("dngd.deblock_bs", "dngd.deblock_thr",
+                   "dngd.deblock_tile", "dngd.deblock_edges",
+                   "dngd.deblock_v", "dngd.deblock_h"),
 }
 
 
 @pytest.fixture(scope="module")
-def lowered():
+def lowered(tmp_path_factory):
+    """:func:`compiled_programs` from a process of its own, asked twice if
+    the first one dies: XLA:CPU took a worker down inside
+    ``backend_compile_and_load`` under this fixture (a segmentation fault
+    and an abort, in two whole runs of PR 48's tree out of two; none when
+    the module ran alone on six workers), and a worker that dies fails the
+    run whatever the tests say.  A fresh process has compiled nothing
+    before, which is what the fixture wants anyway."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    out = tmp_path_factory.mktemp("lowered") / "programs.json"
+    here = os.path.dirname(os.path.abspath(__file__))
+    code = (f"import sys, json; sys.path[:0] = [{here!r}, "
+            f"{os.path.dirname(here)!r}]; "
+            "import conftest, test_stage_spans as t; "
+            f"json.dump(t.compiled_programs(), open({str(out)!r}, 'w'))")
+    for _ in range(2):
+        r = subprocess.run([sys.executable, "-c", code], cwd=here,
+                           capture_output=True, text=True, timeout=1200)
+        if r.returncode == 0:
+            break
+    assert r.returncode == 0, r.stderr[-3000:]
+    return {k: tuple(v) for k, v in json.loads(out.read_text()).items()}
+
+
+def compiled_programs() -> dict:
     """The served programs (the ``_dynqp`` twins: the bodies are shared):
     the lowered text with debug info, and the operations' names in the
     compiled program.  At a geometry no other test compiles: within a
@@ -601,18 +639,70 @@ def lowered():
             jax.config.update(f, v)
 
 
+@pytest.fixture(scope="module")
+def lowered_hq():
+    """H264Encoder._submit_p_device / _submit_device / _deblock where hq is
+    served whole (the plane of effective qps and the intra flags go from the
+    P program's levels to the filter), LOWERED and not compiled: the text
+    with debug info and the name stack of every equation in it (the
+    ``op_name`` a compiled operation inherits).  Every worker that meets one
+    of these tests builds this, and a compile of the P program is the dear
+    part of the fixture above."""
+    from docker_nvidia_glx_desktop_tpu.ops import (cavlc_device,
+                                                   cavlc_p_device,
+                                                   h264_deblock)
+
+    w, h = 176, 112
+    nr, nc = h // 16, w // 16
+    y = np.zeros((h, w), np.uint8)
+    c = np.zeros((h // 2, w // 2), np.uint8)
+    qp = np.int32(30)
+    hv, hl = cavlc_device.slice_header_slots(nr, nc, frame_num=0)
+    pv, pl = cavlc_device.slice_header_slots(
+        nr, nc, frame_num=1, slice_type=5, idr=False)
+    progs = {
+        "hq_p": cavlc_p_device.encode_p_cavlc_frame_dynqp.lower(
+            y, c, c, y, c, c, pv, pl, qp, "hq", None, True, True),
+        "hq_intra": cavlc_device.encode_intra_cavlc_frame_yuv_dynqp.lower(
+            y, c, c, hv, hl, qp, with_recon=True, i16_modes="auto",
+            tune="hq", with_qp_eff=True),
+        "hq_deblock": h264_deblock.deblock_frame_dynqp.lower(
+            y, c, c, qp, nnz_blk=np.zeros((nr, nc, 4, 4), bool),
+            mv=np.zeros((nr, nc, 2), np.int8),
+            qp_eff=np.full((nr, nc), 30, np.int32),
+            mb_intra=np.zeros((nr, nc), bool)),
+    }
+    out = {}
+    for k, low in progs.items():
+        text = low.as_text(debug_info=True)
+        out[k] = (text, re.findall(r'loc\("(jit\([^"]*)"', text))
+    return out
+
+
+HQ_PROGRAMS = sorted(p for p in SCOPES if p.startswith("hq_"))
+
+
+@pytest.fixture
+def either(request, program):
+    """The compiled programs' fixture, or the hq programs' lowered one."""
+    return request.getfixturevalue(
+        "lowered_hq" if program in HQ_PROGRAMS else "lowered")
+
+
 @pytest.mark.parametrize("program,scope", [
     (p, s) for p, scopes in SCOPES.items() for s in scopes])
-def test_lowered_program_carries_the_scope(lowered, program, scope):
+def test_lowered_program_carries_the_scope(either, program, scope):
+    lowered = either
     # (a loop body's names start anew at the scope inside it)
     assert re.search(rf'["/]{re.escape(scope)}/', lowered[program][0])
 
 
 @pytest.mark.parametrize("program", sorted(SCOPES))
-def test_every_operation_lies_inside_a_scope(lowered, program):
+def test_every_operation_lies_inside_a_scope(either, program):
     """In the compiled program every operation's name stack passes
-    through some ``dngd.`` scope."""
-    names = lowered[program][1]
+    through some ``dngd.`` scope (the hq programs: every equation's, in
+    the lowered text)."""
+    names = either[program][1]
     assert len(names) > 10
     # (a name with no stack at all is a parameter or the body of a
     # reduction, which is no operation of its own)

@@ -238,11 +238,28 @@ class TestOffTierOptOut:
             enc.encode(f)
         assert enc._take_mean_qp() is None   # no qp plane was produced
 
-    def test_hq_with_deblock_degrades_to_noaq_no_pintra(self):
-        enc = H264Encoder(W, H, qp=30, entropy="device",
-                          gop=4, deblock=True, tune="hq")
-        assert enc._ktune == "hq_noaq"
-        assert not enc._p_intra      # intra bS is not modeled in v1
+    @pytest.mark.parametrize("kw,served", [
+        ({}, True),
+        ({"entropy": "cabac"}, False),
+        ({"superstep_chunk": 4}, False),
+        ({"spatial_shards": 2}, False),
+        ({"damage_mask": True, "host_color": True}, False),
+    ], ids=["per_frame", "cabac", "ring", "mesh", "mask"])
+    def test_hq_with_deblock_degrades_to_noaq_no_pintra(self, kw, served):
+        """Under the loop filter the tier is served WHOLE on the per-frame
+        one-chip device-CAVLC path (the qp plane and the intra escape on:
+        the filter takes the effective qps and the intra flags) and
+        degrades, as it did everywhere, on the paths whose filter calls
+        hand over one qp a slice."""
+        enc = H264Encoder(W, H, qp=30, gop=4, deblock=True, tune="hq",
+                          **{"entropy": "device", **kw})
+        assert enc.tune == "hq"
+        if served:
+            assert enc._ktune == "hq" and enc._p_intra and enc._dyn_qp
+        else:
+            assert enc._ktune == "hq_noaq"
+            assert not enc._p_intra  # no intra bS in that path's filter
+            assert not enc._dyn_qp   # compile-time lambdas: a program a qp
 
 
 class TestRateControllerMeanQp:
@@ -305,3 +322,145 @@ class TestHqRetrace:
             for f in frames[7:]:
                 enc.encode(f)
         tw.assert_quiet()
+
+
+SW, SH = 192, 112
+
+
+def _served_cfg(monkeypatch, **env):
+    """The configuration ``desk1080-hq`` states, at the tests' size."""
+    from docker_nvidia_glx_desktop_tpu.utils.config import from_env
+    for k, v in {"SIZEW": str(SW), "SIZEH": str(SH), "REFRESH": "60",
+                 "WEBRTC_ENCODER": "nvh264enc", "ENCODER_ENTROPY": "device",
+                 "ENCODER_GOP": "60", "ENCODER_BITRATE_KBPS": "8000",
+                 "ENCODER_TUNE": "hq", "PASSWD": "x", **env}.items():
+        monkeypatch.setenv(k, v)
+    return from_env()
+
+
+def _busy_frames(n, w=SW, h=SH, seed=5):
+    """A panned texture with one fresh macroblock in 8 (what the cell's
+    ``fulldamage`` traffic does) beside a flat half: the search loses the
+    fresh ones, the plane takes both signs."""
+    r = np.random.default_rng(seed)
+    tex = r.integers(0, 256, (h + 128, w + 128, 3), np.uint8)
+    tex = cv2.GaussianBlur(tex, (0, 0), 2.0)
+    out = []
+    for i in range(n):
+        f = tex[3 * i:3 * i + h, 7 * i:7 * i + w].copy()
+        f[:, : w // 4] = 90 + (np.arange(h)[:, None, None] // 8) % 3
+        for k in range((h // 16) * (w // 16)):
+            if (k + i) % 8 == 0:
+                my, mx = divmod(k, w // 16)
+                f[16 * my:16 * my + 16, 16 * mx:16 * mx + 16] = r.integers(
+                    0, 256, (16, 16, 3), np.uint8)
+        out.append(f)
+    return out
+
+
+class TestHqServedUnderLoopFilter:
+    """ENCODER_TUNE=hq through ``make_encoder`` (loop filter on, CBR): the
+    deployment ``desk1080-hq`` at the tests' size."""
+
+    def test_served_gop_is_the_decoders_picture_bit_for_bit(
+            self, tmp_path, monkeypatch):
+        """Every reference picture the encoder keeps is the decoder's
+        picture, with a plane that is not flat and I_16x16 macroblocks in
+        the P slices: a wrong qPav or bS is a wrong sample at the first
+        edge it touches, and every later frame predicts from it."""
+        from docker_nvidia_glx_desktop_tpu.models import make_encoder
+        from docker_nvidia_glx_desktop_tpu.models import h264 as m
+
+        enc, name = make_encoder(_served_cfg(monkeypatch), SW, SH)
+        assert name == "h264_cavlc" and enc.deblock
+        assert enc._hq_loop and enc._ktune == "hq" and enc._p_intra
+        before = {c: c.value for c in (m._M_P_MBS, m._M_P_INTRA_MBS,
+                                       m._M_CODED_QP_SUM, m._M_SLICE_QP_SUM)}
+        n = 6
+        data, refs, planes = enc.headers(), [], []
+        for f in _busy_frames(n):
+            tok = enc.encode_submit(f)
+            if tok[0] == "p":
+                planes.append((np.asarray(tok[4][2]["qp_map"]),
+                               np.asarray(tok[4][2]["mb_intra"])))
+            data += enc.encode_collect(tok).data
+            refs.append(np.array(enc.export_state()["ref"][0][:SH, :SW]))
+        assert len(planes) == n - 1
+        assert any(len(np.unique(q)) > 2 for q, _ in planes)
+        assert sum(int(i.sum()) for _, i in planes) > 0
+        p = tmp_path / "hq.264"
+        p.write_bytes(data)
+        cap = cv2.VideoCapture(str(p))
+        cap.set(cv2.CAP_PROP_CONVERT_RGB, 0)
+        for i, ref in enumerate(refs):
+            ok, img = cap.read()
+            assert ok, f"picture {i} did not decode"
+            luma = np.asarray(img).reshape(-1)[:SW * SH].reshape(SH, SW)
+            assert int(np.abs(luma.astype(np.int16) - ref).max()) == 0, (
+                f"picture {i}")
+        # the counters the benchmark's readers take, off the meta words
+        mbs = (SW // 16) * (SH // 16)
+        d = {c: c.value - v for c, v in before.items()}
+        assert d[m._M_P_MBS] == (n - 1) * mbs
+        assert d[m._M_P_INTRA_MBS] == sum(int(i.sum()) for _, i in planes)
+        assert d[m._M_SLICE_QP_SUM] > 0
+        assert d[m._M_CODED_QP_SUM] != d[m._M_SLICE_QP_SUM]
+
+    def test_one_compile_of_each_program_across_five_qps(self, monkeypatch):
+        """The rate ladder's rungs (and the IDR's ``I_QP_BIAS`` twins) are
+        values of a traced scalar: behind one IDR and one P frame nothing
+        compiles at any other qp, and there is no ladder to prewarm."""
+        from docker_nvidia_glx_desktop_tpu.analysis.retrace import (
+            RetraceTripwire, compile_events_supported)
+        from docker_nvidia_glx_desktop_tpu.models import make_encoder
+
+        if not compile_events_supported():
+            pytest.skip("jax.monitoring compile events unavailable")
+        enc, _ = make_encoder(_served_cfg(monkeypatch), SW, SH)
+        assert enc.prewarm() == 0
+        assert all(q in enc.ladder_qps() for q in (20, 26, 44))
+        assert len(enc.ladder_qps()) == len(set(
+            min(51, q + o) for q in {min(51, max(0, enc.qp + s))
+                                     for s in RateController.STEPS}
+            for o in (0,) + enc.DEGRADE_QP_OFFSETS))   # no I_QP_BIAS twins
+        frames = _busy_frames(12)
+        enc._forced_qp = 30
+        enc.encode(frames[0])
+        enc.encode(frames[1])
+        with RetraceTripwire(label="hq under the loop filter") as tw:
+            for i, qp in enumerate((20, 26, 33, 40, 47)):
+                enc._forced_qp = qp
+                enc._force_idr = True
+                enc.encode(frames[2 + 2 * i])
+                enc.encode(frames[3 + 2 * i])
+        tw.assert_quiet()
+
+    @pytest.mark.parametrize("env", [
+        {"ENCODER_ENTROPY": "cabac"}, {"ENCODER_SUPERSTEP_CHUNK": "4"},
+        {"ENCODER_SPATIAL_SHARDS": "2"}, {"DNGD_DAMAGE_MASK": "true"}],
+        ids=["cabac", "ring", "mesh", "mask"])
+    def test_other_paths_keep_the_parents_tier(self, monkeypatch, env):
+        """hq under CABAC, the ring, the mesh and the mask is what it was: the
+        lambda tier at one qp a slice, byte for byte the stream of an
+        encoder BUILT as ``hq_noaq`` (the tier the parent degraded to)."""
+        from docker_nvidia_glx_desktop_tpu.models import make_encoder
+
+        frames = _busy_frames(3, W, H)
+        env = dict(env, ENCODER_BITRATE_KBPS="0")    # one qp: one program
+        enc, _ = make_encoder(_served_cfg(monkeypatch, **env), W, H)
+        assert not enc._hq_loop and enc._ktune == "hq_noaq"
+        want, _ = make_encoder(_served_cfg(
+            monkeypatch, **env, ENCODER_TUNE="hq_noaq"), W, H)
+        assert want._ktune == "hq_noaq"
+
+        def drive(e):
+            out, pend = [], []
+            for f in frames:
+                pend.append(e.encode_submit(f))
+                while len(pend) >= e.pipeline_depth:
+                    out.append(e.encode_collect(pend.pop(0)).data)
+            while pend:
+                out.append(e.encode_collect(pend.pop(0)).data)
+            return out
+
+        assert drive(enc) == drive(want)
